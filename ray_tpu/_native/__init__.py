@@ -3,14 +3,17 @@
 The hot object-plane path (capacity-managed shared-memory store with LRU
 eviction, spilling, restore, and cross-process pinning) is C++
 (cc/store.cc), mirroring the reference's native surface
-(/root/reference/src/ray/object_manager/plasma/). The library is compiled
-on first use with the system toolchain and cached next to the sources;
-callers fall back to the pure-Python store if no compiler is available.
+(/root/reference/src/ray/object_manager/plasma/). The library is never
+committed: it is compiled from cc/store.cc on first use with the system
+toolchain into lib/ (ignored by git), under a name keyed by a hash of
+the source, so a copied tree or an edited source rebuilds. A build that
+fails raises — there is no silent pure-Python store.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,44 +23,32 @@ _CC_DIR = os.path.join(os.path.dirname(__file__), "cc")
 _LIB_DIR = os.path.join(os.path.dirname(__file__), "lib")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_lib_failed = False
 
 
-def _build(src: str, out: str) -> bool:
+def _build(src: str, out: str) -> None:
     os.makedirs(_LIB_DIR, exist_ok=True)
     tmp = out + f".tmp.{os.getpid()}"
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, src]
-    try:
-        r = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+    r = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     if r.returncode != 0:
-        import sys
-
-        print(f"ray_tpu native build failed:\n{r.stderr}", file=sys.stderr)
-        return False
-    os.replace(tmp, out)
-    return True
+        raise RuntimeError(f"ray_tpu native build failed:\n{r.stderr}")
+    os.replace(tmp, out)    # atomic: concurrent builders race harmlessly
 
 
-def store_lib() -> Optional[ctypes.CDLL]:
-    """The store library, building it if missing or stale; None on failure."""
-    global _lib, _lib_failed
+def store_lib() -> ctypes.CDLL:
+    """The store library, built from cc/store.cc on first use. Raises if
+    it cannot be built or loaded."""
+    global _lib
     with _lock:
-        if _lib is not None or _lib_failed:
+        if _lib is not None:
             return _lib
         src = os.path.join(_CC_DIR, "store.cc")
-        out = os.path.join(_LIB_DIR, "libray_tpu_store.so")
-        try:
-            stale = (not os.path.exists(out) or
-                     os.path.getmtime(out) < os.path.getmtime(src))
-            if stale and not _build(src, out):
-                _lib_failed = True
-                return None
-            lib = ctypes.CDLL(out)
-        except OSError:
-            _lib_failed = True
-            return None
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        out = os.path.join(_LIB_DIR, f"libray_tpu_store-{digest}.so")
+        if not os.path.exists(out):
+            _build(src, out)
+        lib = ctypes.CDLL(out)
         # signatures
         lib.rt_store_open.restype = ctypes.c_void_p
         lib.rt_store_open.argtypes = [ctypes.c_char_p, ctypes.c_uint64,

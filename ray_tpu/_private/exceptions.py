@@ -1,6 +1,6 @@
 """User-facing error types.
 
-Capability parity target: the reference's exception taxonomy
+Capability parity target: the reference's exception catalogue
 (/root/reference/python/ray/exceptions.py) — task errors wrapping the remote
 traceback, actor death, object loss, OOM, and cancellation.
 """

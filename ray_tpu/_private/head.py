@@ -641,6 +641,13 @@ class HeadService:
         if labels_hard:
             candidates = [e for e in candidates
                           if self._labels_all(e.labels, labels_hard)]
+        if strategy_kind == "device" and not resources.get("TPU"):
+            # A device-lane task carries no resource demand of its own:
+            # it runs wherever a node process hosts a device lane (the
+            # node-side twin is NodeService._locally_feasible). An
+            # attached driver advertises none.
+            candidates = [e for e in candidates
+                          if e.resources.get("device", 0) > 0]
         if not candidates:
             # A spillback probe excludes its own node, so an empty
             # candidate set is the EXPECTED answer on a lone busy node —

@@ -608,8 +608,7 @@ class NativeObjectStore(SharedMemoryStore):
     """The C++-backed store (ray_tpu/_native/cc/store.cc): same segment
     layout and client API as SharedMemoryStore, plus capacity accounting,
     LRU eviction, disk spilling with transparent restore, and
-    cross-process pinning. Used automatically when the native library
-    builds (see make_store)."""
+    cross-process pinning. The default store (see make_store)."""
 
     def __init__(self, session_id: str, *, capacity_bytes: int | None = None,
                  spill_dir: str | None = None):
@@ -622,8 +621,6 @@ class NativeObjectStore(SharedMemoryStore):
         from .._native import store_lib
 
         self._lib = store_lib()
-        if self._lib is None:
-            raise RuntimeError("native store library unavailable")
         self._ctypes = ctypes
         self._h = self._lib.rt_store_open(
             self.prefix.encode(), self.capacity_bytes,
@@ -769,11 +766,9 @@ class _NativePendingSeal:
 
 
 def make_store(session_id: str) -> SharedMemoryStore:
-    """The node's object store: native (C++) when the library builds,
-    pure-Python otherwise (RT_NATIVE_STORE=0 forces the fallback)."""
+    """The node's object store: the native (C++) one, built on first use
+    (a build failure raises); the pure-Python store only when
+    RT_NATIVE_STORE=0 asks for it."""
     if os.environ.get("RT_NATIVE_STORE", "1") != "0":
-        try:
-            return NativeObjectStore(session_id)
-        except (RuntimeError, OSError):
-            pass
+        return NativeObjectStore(session_id)
     return SharedMemoryStore(session_id)

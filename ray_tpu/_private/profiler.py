@@ -203,21 +203,22 @@ def _collect_jax_trace(tmpdir: str) -> dict:
     return {"error": "no chrome-format trace artifact produced"}
 
 
-def _start_xla_trace():
-    """An XLA profiler session with the PYTHON tracer OFF. The default
-    python tracer (PEP 523 eval hook) permanently hides threads that
-    were alive during the session from ``sys._current_frames()`` —
-    which would blind the host sampling profiler (`rtpu stack --flame`,
-    the ``profile`` RPC) for the rest of the worker's life after one
-    device capture. We carry our own host timeline anyway, so only the
-    C++ host/device tracers run. Returns the session or raises."""
-    from jax._src import xla_bridge
-    from jax._src.lib import xla_client
+def _start_xla_trace(log_dir: str) -> None:
+    """Start a jax.profiler trace into ``log_dir`` with the PYTHON
+    tracer OFF. The default python tracer (PEP 523 eval hook)
+    permanently hides threads that were alive during the session from
+    ``sys._current_frames()`` — which would blind the host sampling
+    profiler (`rtpu stack --flame`, the ``profile`` RPC) for the rest of
+    the worker's life after one device capture. We carry our own host
+    timeline anyway, so only the C++ host/device tracers run. Only the
+    process that holds the chip can trace it."""
+    import jax
 
-    xla_bridge.get_backend()  # libtpu must init before the tracer
-    opts = xla_client.profiler.ProfileOptions()
+    jax.devices()  # the backend must be up before the tracer starts
+    opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
-    return xla_client.profiler.ProfilerSession(opts)
+    jax.profiler.start_trace(log_dir, create_perfetto_trace=True,
+                             profiler_options=opts)
 
 
 def device_profile(duration_s: float = 2.0, hz: float = 99.0,
@@ -232,22 +233,23 @@ def device_profile(duration_s: float = 2.0, hz: float = 99.0,
     from ray_tpu.util import perfmodel
 
     t0_wall = time.time()
-    sess = None
+    tmpdir = None
     jax_err = None
     if include_jax:
-        try:
-            sess = _start_xla_trace()
-        except Exception as e:  # noqa: BLE001 - capture must not kill
-            jax_err = f"xla trace unavailable: {e!r}"
-    host = sample_profile(duration_s, hz, timeline=True)
-    jax_trace: dict = {"error": jax_err or "jax trace disabled"}
-    if sess is not None:
         tmpdir = tempfile.mkdtemp(prefix="rtpu-devprof-")
         try:
-            sess.export(sess.stop(), tmpdir)
-            from jax._src.profiler import _write_perfetto_trace_file
+            _start_xla_trace(tmpdir)
+        except Exception as e:  # noqa: BLE001 - capture must not kill
+            jax_err = f"xla trace unavailable: {e!r}"
+            shutil.rmtree(tmpdir, ignore_errors=True)
+            tmpdir = None
+    host = sample_profile(duration_s, hz, timeline=True)
+    jax_trace: dict = {"error": jax_err or "jax trace disabled"}
+    if tmpdir is not None:
+        try:
+            import jax
 
-            _write_perfetto_trace_file(tmpdir)
+            jax.profiler.stop_trace()
             jax_trace = _collect_jax_trace(tmpdir)
         except Exception as e:  # noqa: BLE001
             jax_trace = {"error": f"trace export failed: {e!r}"}

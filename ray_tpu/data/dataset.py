@@ -560,8 +560,12 @@ class Dataset:
                 from .llm import _operator_spec
 
                 st = payload
-                specs.append(_operator_spec(st.fn, st.pool,
-                                            _remote_opts()))
+                # An engine's params and KV pools live on the chip, and
+                # a chip belongs to one process: the operator's actors
+                # run on the device lane whatever lane the plain map
+                # stages use.
+                specs.append(_operator_spec(
+                    st.fn, st.pool, {"scheduling_strategy": "device"}))
             else:
                 st = payload
                 specs.append(ActorPoolSpec(

@@ -12,7 +12,7 @@ Layering:
 
     fairshare.py   pure stride/DRF math (no clocks, no cluster)
     quota.py       per-tenant caps + idempotent charge/release ledger
-    admission.py   reject-with-reason taxonomy (quota / malformed /
+    admission.py   reject-with-reason catalogue (quota / malformed /
                    infeasible-shape)
     scheduler.py   JobScheduler: the composition, with a decision ledger
     sim.py         virtual-time churn harness: K tenants x M gang jobs

@@ -3,7 +3,7 @@ dict (``{"code": ..., "detail": ...}`` plus code-specific fields) that
 lands verbatim in ``JobInfo.reason`` — clients branch on ``code``, never
 on prose.
 
-Taxonomy:
+Catalogue:
 
     QUOTA_EXCEEDED        a per-tenant cap bars the submission
                           (``quota`` field says which cap)

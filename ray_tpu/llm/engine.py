@@ -121,6 +121,11 @@ class Request:
             yield tok
 
 
+# (program, pool spec, max_batch, q rows) -> "compiled" | "interpret";
+# see LLMEngine._paged_kernel_mode.
+_KERNEL_MODES: dict = {}
+
+
 @functools.lru_cache(maxsize=32)
 def _jit_programs(cfg: GPTConfig, mesh, rules):
     """Process-wide compiled-program cache. jax.jit's executable cache
@@ -167,6 +172,11 @@ class LLMEngine:
         self.kv = pool_cls(cfg, num_blocks=num_blocks,
                            block_size=block_size)
         self._prefix = prefix_cache
+        # Read once: a decode step donates the pools, so between dispatch
+        # and reassignment self.kv.k is a deleted array to other threads.
+        self._device = next(iter(self.kv.k.devices()))
+        self._pool_spec = jax.ShapeDtypeStruct(
+            self.kv.k.shape, self.kv.k.dtype, sharding=self.kv.k.sharding)
         # Sarathi-style chunked prefill admission: at most this many
         # UNCACHED prompt tokens run per step (None = whole prompt at
         # once), so running decode streams emit a token every step even
@@ -211,6 +221,7 @@ class LLMEngine:
         self._token_times: Deque[tuple] = collections.deque()  # (t, n)
         self._thread: Optional[threading.Thread] = None
         self._stop = False
+        self._fatal: Optional[BaseException] = None   # step loop died
         self._gauges = None
         # Shared idle-decay clock (the PR-10 gauge contract, one
         # implementation for the whole repo): touched per busy publish;
@@ -247,6 +258,10 @@ class LLMEngine:
         generator streams the output. Raises if the request could never
         run (so the pool-exhaustion path is always recoverable by
         preemption, never a livelock)."""
+        if self._fatal is not None:
+            raise RuntimeError(
+                f"the engine's step loop died: {self._fatal!r}"
+            ) from self._fatal
         prompt = [int(t) for t in prompt]
         if not prompt:
             raise ValueError("empty prompt")
@@ -568,6 +583,17 @@ class LLMEngine:
                 break
         return True
 
+    def _roofline_attrs(self, cost, device_s: float, dur: float) -> dict:
+        """mfu / hbm_util / verdict span attributes for one step; empty
+        on the CPU backend, which has no peak to price against."""
+        rl = perfmodel.roofline(cost, device_s, max(dur - device_s, 0.0),
+                                hw=self._step_perf.hw)
+        if not rl:
+            return {}
+        return {"mfu": round(rl["mfu"], 4),
+                "hbm_util": round(rl["hbm_util"], 4),
+                "verdict": rl["verdict"]}
+
     def _run_decode(self):
         batch = [r for r in self._active if r.state == RUNNING]
         for req in list(batch):
@@ -628,18 +654,14 @@ class LLMEngine:
         kv_util = self.kv.utilization()
         traced = [r for r in batch if r.trace_ctx is not None]
         if traced:
-            rl = perfmodel.roofline(cost, device_s,
-                                    max(dur - device_s, 0.0),
-                                    hw=self._step_perf.hw)
+            rl = self._roofline_attrs(cost, device_s, dur)
             breakdown = {
                 "step": self._steps + 1,
                 "prefill": self._last_prefill_count,
                 "decode": len(batch), "kv_util": kv_util,
                 "device_ms": round(device_s * 1e3, 3),
                 "host_ms": round(max(dur - device_s, 0.0) * 1e3, 3),
-                "mfu": round(rl["mfu"], 4),
-                "hbm_util": round(rl["hbm_util"], 4),
-                "verdict": rl["verdict"],
+                **rl,
             }
             for req in traced:
                 tracing.emit("llm.decode_step", req.trace_ctx, t0, dur,
@@ -763,9 +785,7 @@ class LLMEngine:
         kv_util = self.kv.utilization()
         traced = [r for r in batch if r.trace_ctx is not None]
         if traced:
-            rl = perfmodel.roofline(cost, device_s,
-                                    max(dur - device_s, 0.0),
-                                    hw=self._step_perf.hw)
+            rl = self._roofline_attrs(cost, device_s, dur)
             breakdown = {
                 "step": self._steps + 1,
                 "prefill": self._last_prefill_count,
@@ -775,9 +795,7 @@ class LLMEngine:
                 "spec_emitted": emitted_total,
                 "device_ms": round(device_s * 1e3, 3),
                 "host_ms": round(max(dur - device_s, 0.0) * 1e3, 3),
-                "mfu": round(rl["mfu"], 4),
-                "hbm_util": round(rl["hbm_util"], 4),
-                "verdict": rl["verdict"],
+                **rl,
             }
             for req in traced:
                 tracing.emit("llm.decode_step", req.trace_ctx, t0, dur,
@@ -826,8 +844,44 @@ class LLMEngine:
         span = max(now - self._token_times[0][0], 1e-3)
         return sum(n for _, n in self._token_times) / span
 
+    def _paged_kernel_mode(self) -> str:
+        """"compiled" if the program this engine steps with (decode, or
+        verify under speculation) carries the Mosaic kernel, "interpret"
+        if the Pallas interpreter's plain ops stand in for it. Observed,
+        not inferred from the backend: the program is lowered once at
+        this engine's own shapes and its text searched for the kernel's
+        custom call."""
+        verify = self._spec is not None
+        prog = self._verify if verify else self._decode
+        B, q = self.max_batch, ((self._spec.k + 1,) if verify else ())
+        key = (prog, self._pool_spec, B, q)
+        mode = _KERNEL_MODES.get(key)
+        if mode is None:
+            def i32(*shape):
+                return jax.ShapeDtypeStruct(shape, np.int32)
+
+            params = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=x.sharding),
+                self.params)
+            per_row = (i32(B),) * (2 if verify else 1)  # context[, q] lens
+            text = prog.lower(
+                params, i32(B, *q), i32(B, *q), self._pool_spec,
+                self._pool_spec, i32(B, self.max_nb), *per_row,
+                i32(B, *q), i32(B, *q)).as_text()
+            mode = _KERNEL_MODES[key] = (
+                "compiled" if "tpu_custom_call" in text else "interpret")
+        return mode
+
     def stats(self) -> dict:
+        dev = self._device
         out = {
+            # Where the pools live, and whether the paged kernel is
+            # compiled for that device or run by the Pallas interpreter
+            # (CPU tests only) — so a caller can see a CPU-served model.
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "paged_kernel": self._paged_kernel_mode(),
             "steps": self._steps,
             "waiting": len(self._waiting),
             "in_flight": len(self._active),
@@ -946,10 +1000,13 @@ class LLMEngine:
             step_ms.set(perf["step_ms"], tags=tags)
             dev_ms.set(perf["device_ms"], tags=tags)
             gap_ms.set(perf["host_gap_ms"], tags=tags)
-            mfu.set(perf["mfu"], tags=tags)
-            hbm.set(perf["hbm_util"], tags=tags)
-            verd.set(_VERDICT_CODE.get(perf.get("verdict"), 0.0),
-                     tags=tags)
+            if self._step_perf.hw is not None:
+                # Utilization and verdict need a peak: the CPU backend
+                # has none and publishes counts and times only.
+                mfu.set(perf["mfu"], tags=tags)
+                hbm.set(perf["hbm_util"], tags=tags)
+                verd.set(_VERDICT_CODE.get(perf.get("verdict"), 0.0),
+                         tags=tags)
         except Exception:  # noqa: BLE001 - telemetry is best-effort
             pass
 
@@ -978,7 +1035,17 @@ class LLMEngine:
                         self._publish_gauges()
                 if self._stop:
                     return
-            self.step()
+            try:
+                self.step()
+            except BaseException as e:
+                # A step that raises (a kernel that fails to compile, a
+                # device out of memory) must not leave the consumers
+                # parked on their queues with a dead loop behind them:
+                # every request ends with finish_reason "error", new
+                # ones are refused, and the exception propagates.
+                self._fatal = e
+                self._release_consumers("error")
+                raise
 
     def stop(self):
         with self._cond:
@@ -987,8 +1054,12 @@ class LLMEngine:
         if self._thread is not None:
             self._thread.join(timeout=10)
             self._thread = None
-        # Release any parked consumers.
+        self._release_consumers("aborted")
+
+    def _release_consumers(self, reason: str):
+        """End every in-flight and waiting request so no consumer stays
+        parked on its queue (shutdown, or a step loop that died)."""
         with self._lock:
             for req in list(self._active) + list(self._waiting):
-                self._finish(req, "aborted")
+                self._finish(req, reason)
             self._waiting.clear()

@@ -178,8 +178,17 @@ def _block(x, p, cfg: GPTConfig, mesh, rules, mlp_remat: bool = False,
         kk = jnp.einsum("bsm,mhd->bhsd", h, p["wk"].astype(dt))
         v = jnp.einsum("bsm,mhd->bhsd", h, p["wv"].astype(dt))
         q = _constrain(q, ("batch", "heads", "seq", None), mesh, rules)
-        o = flash_attention(q, kk, v, causal=True,
-                            block_size=cfg.flash_block, layout="bhsd")
+        attn = functools.partial(flash_attention, causal=True,
+                                 block_size=cfg.flash_block, layout="bhsd")
+        if mesh is not None:
+            # A Mosaic kernel cannot be partitioned automatically: each
+            # device runs it on its own batch rows (data axes) and heads
+            # (tp) with the whole sequence, which causal attention needs.
+            spec = logical_to_mesh_axes(("batch", "heads", None, None),
+                                        rules)
+            attn = jax.shard_map(attn, mesh=mesh, in_specs=(spec,) * 3,
+                                 out_specs=spec, check_vma=False)
+        o = attn(q, kk, v)
         o = jnp.einsum("bhsd,hdm->bsm", o, p["wo"].astype(dt))
         kv = (kk.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     else:
@@ -244,8 +253,7 @@ def forward(params, tokens, cfg: GPTConfig, mesh: Optional[Mesh] = None,
             cp = jax.checkpoint_policies
             name = cfg.remat_policy
             if name == "dots_flash" and not (
-                    cfg.use_flash and jax.default_backend() not in
-                    ("cpu", "gpu", "cuda", "rocm", "METAL")):
+                    cfg.use_flash and jax.default_backend() == "tpu"):
                 # Without the Pallas kernel (flash disabled, or a backend
                 # where flash_attention lowers the blockwise-jnp reference
                 # instead), dots_saveable would save O(seq^2) per-block

@@ -42,6 +42,12 @@ from jax.experimental.pallas import tpu as pltpu
 from ..attention import NEG_INF
 
 
+def interpret_default() -> bool:
+    """The Pallas interpreter runs the kernel only where Mosaic cannot:
+    on the CPU backend (tests). On a TPU the kernel is always compiled."""
+    return jax.default_backend() == "cpu"
+
+
 def _decode_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
                    o_ref, m_ref, l_ref, acc_ref, *, block_size: int,
                    max_nb: int, scale: float, q_len: int, group: int):
@@ -169,7 +175,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
     Returns ``[batch, kv_heads, group, head_dim]`` in q's dtype.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     b, hkv, group, d = q.shape
     hkv_p, num_blocks, block_size, d_p = k_pool.shape
     if (hkv_p, d_p) != (hkv, d):
@@ -206,7 +212,7 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, context_lens,
     Returns ``[batch, q_len, kv_heads, group, head_dim]`` in q's dtype.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     b, q_len, hkv, group, d = q.shape
     hkv_p, num_blocks, block_size, d_p = k_pool.shape
     if (hkv_p, d_p) != (hkv, d):

@@ -754,9 +754,6 @@ def run_serve_llm_mixed(duration_s: float = 8.0, stream_clients: int = 3,
 
 
 def main():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import ray_tpu
 
     duration = float(os.environ.get("RT_SERVE_BENCH_S", "3"))
@@ -767,8 +764,12 @@ def main():
     finally:
         ray_tpu.shutdown()
     doc_fleet = run_fleet(duration_s=duration, clients=clients)
+    import jax
+
     doc = {
         "ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
+        "device": {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind},
         "duration_s": duration,
         "clients": clients,
         **doc,

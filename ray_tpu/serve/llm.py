@@ -160,7 +160,13 @@ class _LLMServer:
             pass
 
 
-LLMServer = deployment(name="LLMServer")(_LLMServer)
+# The engine's params and KV pools live on the chip, and a chip belongs
+# to one process at a time — the one hosting the device lane. So the
+# replica is placed there by default; a CPU-lane worker would serve from
+# the CPU platform through the Pallas interpreter.
+LLMServer = deployment(
+    name="LLMServer",
+    ray_actor_options={"scheduling_strategy": "device"})(_LLMServer)
 
 
 def build_app(cfg=None, **kwargs):

@@ -79,7 +79,6 @@ class _TrainSession:
         self._step_perf = [0.0, 0.0, 0.0, 0]
         self._last_report_t: Optional[float] = None
         self._perf_gauges = None
-        self._hw = None
 
     def record_device(self, seconds: float, cost=None):
         """wrap_step's sink: one timed dispatch->block_until_ready span
@@ -106,27 +105,27 @@ class _TrainSession:
             return None
         from ..util import perfmodel
 
-        if self._hw is None:
-            self._hw = perfmodel.detect_hardware()
         wall = max(wall, device_s)
+        # {} on the CPU backend (no peak): counts and times only there.
         rl = perfmodel.roofline(
             perfmodel.StepCost(flops, hbm_bytes, tokens),
-            device_s, wall - device_s, hw=self._hw)
+            device_s, wall - device_s, hw=perfmodel.detect_hardware())
+        rl.pop("hardware", None)
         out = {
             "train_step_ms": wall * 1e3,
             "train_device_ms": device_s * 1e3,
             "train_host_gap_ms": (wall - device_s) * 1e3,
-            "train_mfu": rl["mfu"],
-            "train_hbm_util": rl["hbm_util"],
-            "train_roofline": rl["verdict"],
         }
+        if rl:
+            out.update(train_mfu=rl["mfu"], train_hbm_util=rl["hbm_util"],
+                       train_roofline=rl["verdict"])
         perfmodel.record_device_step(
             "train.step", time.time() - wall,
             {"step_ms": out["train_step_ms"],
              "device_ms": out["train_device_ms"],
              "host_gap_ms": out["train_host_gap_ms"],
-             "mfu": rl["mfu"], "hbm_util": rl["hbm_util"],
-             "verdict": rl["verdict"], "tokens": tokens},
+             "tokens": tokens, "flops": flops, "hbm_bytes": hbm_bytes,
+             **rl},
             {"trial": self.ctx.trial_name})
         self._publish_perf_gauges(out)
         return out
@@ -163,7 +162,8 @@ class _TrainSession:
                 }
             tags = {"trial": self.ctx.trial_name or "?"}
             for key, gauge in self._perf_gauges.items():
-                gauge.set(float(perf[key]), tags=tags)
+                if key in perf:     # no MFU / HBM util on the CPU backend
+                    gauge.set(float(perf[key]), tags=tags)
         except Exception:  # noqa: BLE001 - telemetry is best-effort
             pass
 

@@ -8,9 +8,7 @@ it so they can never disagree:
     telemetry series,
   * the train session (train/session.py) prices wrapped train steps
     into ``train_*`` equivalents,
-  * bench.py's offline MFU report routes through the same formulas
-    (previously a duplicated ``197e12 if on_tpu else 1e12`` constant +
-    ``GPTConfig.flops_per_token``).
+  * bench.py's offline MFU report routes through the same formulas.
 
 Cost formulas (decoder-only transformer, GPTConfig shapes):
 
@@ -42,9 +40,10 @@ KV-bound, not compute-bound):
                   remat) — a documented approximation, good to the
                   factor-of-two a roofline verdict needs.
 
-Hardware peaks are per chip: dense bf16 FLOP/s and HBM GB/s from the
-public TPU spec sheets, with a ``cpu-interpret`` fallback matching the
-1e12 figure bench.py always used for non-TPU runs.
+Hardware peaks are per chip and keyed by the ``device_kind`` jax
+reports. An accelerator that is not in the table is an error, and the
+CPU backend has no peak at all: a CPU run publishes counts and times,
+never an MFU, an HBM utilization or a roofline verdict.
 """
 
 from __future__ import annotations
@@ -67,54 +66,34 @@ class HardwarePeak:
     hbm_bytes_per_s: float   # HBM bandwidth, per chip
 
 
+# Keyed by ``jax.devices()[0].device_kind``. Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s).
+V5E = "TPU v5 lite"
 HARDWARE_PEAKS: Dict[str, HardwarePeak] = {
-    # v5e: 197 TFLOP/s bf16, 819 GB/s HBM2 (16 GB).
-    "v5e": HardwarePeak("v5e", 197e12, 819e9),
-    # v5p: 459 TFLOP/s bf16, 2765 GB/s HBM2e (95 GB).
-    "v5p": HardwarePeak("v5p", 459e12, 2765e9),
-    # v4: 275 TFLOP/s bf16, 1228 GB/s.
-    "v4": HardwarePeak("v4", 275e12, 1228e9),
-    # v6e (Trillium): 918 TFLOP/s bf16, 1640 GB/s.
-    "v6e": HardwarePeak("v6e", 918e12, 1640e9),
-    # Interpret-mode / CPU fallback: the nominal 1 TFLOP/s bench.py has
-    # always normalized against off-TPU, with a DDR-class 50 GB/s.
-    "cpu-interpret": HardwarePeak("cpu-interpret", 1e12, 50e9),
+    V5E: HardwarePeak(V5E, 197e12, 819e9),
 }
 
 
-def detect_hardware(device=None) -> HardwarePeak:
-    """Peak entry for the local backend: match jax's device_kind against
-    the table (v5 litepod -> v5e etc.), fall back to cpu-interpret.
-    Never raises — a perf model must not take the engine down."""
+def detect_hardware(device=None) -> Optional[HardwarePeak]:
+    """Peak entry for ``device`` (default: the first local jax device).
+
+    None on the CPU backend — there is no peak to price a CPU run
+    against. An accelerator whose ``device_kind`` is not in the table
+    raises: pricing it as some other chip would publish wrong numbers.
+    """
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
     try:
-        if device is None:
-            import jax
-
-            device = jax.devices()[0]
-        kind = f"{getattr(device, 'platform', '')} " \
-               f"{getattr(device, 'device_kind', '')}".lower()
-        if "tpu" in kind:
-            if "v5 lite" in kind or "v5e" in kind or "v5lite" in kind:
-                return HARDWARE_PEAKS["v5e"]
-            if "v5p" in kind or "v5" in kind:
-                return HARDWARE_PEAKS["v5p"]
-            if "v6" in kind or "trillium" in kind:
-                return HARDWARE_PEAKS["v6e"]
-            if "v4" in kind:
-                return HARDWARE_PEAKS["v4"]
-            return HARDWARE_PEAKS["v5e"]
-    except Exception:  # noqa: BLE001 - no backend at all
-        pass
-    return HARDWARE_PEAKS["cpu-interpret"]
-
-
-def peak_flops(on_tpu: Optional[bool] = None) -> float:
-    """Per-chip FLOP/s peak for MFU denominators (bench.py's old inline
-    ``197e12 if on_tpu else 1e12``)."""
-    if on_tpu is None:
-        return detect_hardware().flops_per_s
-    return (HARDWARE_PEAKS["v5e"] if on_tpu
-            else HARDWARE_PEAKS["cpu-interpret"]).flops_per_s
+        return HARDWARE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown accelerator {device.device_kind!r} (platform "
+            f"{device.platform!r}): add its published peaks to "
+            f"perfmodel.HARDWARE_PEAKS") from None
 
 
 # ---------------------------------------------------------------------------
@@ -272,9 +251,9 @@ def train_step_cost(cfg, batch: int, seq: Optional[int] = None, *,
 
 
 def roofline(cost: StepCost, device_s: float, host_gap_s: float = 0.0,
-             *, hw: Optional[HardwarePeak] = None,
-             n_chips: int = 1) -> dict:
-    """Classify where a step's wall time went.
+             *, hw: Optional[HardwarePeak], n_chips: int = 1) -> dict:
+    """Classify where a step's wall time went; ``{}`` when ``hw`` is
+    None (a CPU run has no peak, so no utilization and no verdict).
 
     mfu       achieved / peak FLOP rate over the DEVICE span
     hbm_util  achieved / peak HBM bandwidth over the device span
@@ -284,7 +263,8 @@ def roofline(cost: StepCost, device_s: float, host_gap_s: float = 0.0,
               'compute' if mfu >= hbm_util (closer to the compute roof),
               'hbm'     otherwise (bandwidth is the binding roof).
     """
-    hw = hw or detect_hardware()
+    if hw is None:
+        return {}
     device_s = max(float(device_s), 1e-9)
     chips = max(int(n_chips), 1)
     mfu = cost.flops / (device_s * hw.flops_per_s * chips)
@@ -316,6 +296,8 @@ class StepAccounting:
 
     def __init__(self, hw: Optional[HardwarePeak] = None,
                  n_chips: int = 1):
+        # None on the CPU backend: the breakdown then carries counts and
+        # times only.
         self.hw = hw or detect_hardware()
         self.n_chips = max(int(n_chips), 1)
         self._wall0 = 0.0
@@ -347,18 +329,18 @@ class StepAccounting:
             return None
         wall_s = max(time.perf_counter() - self._wall0, self._device_s)
         host_gap_s = wall_s - self._device_s
-        rl = roofline(
-            StepCost(self._flops, self._hbm_bytes, self._tokens),
-            self._device_s, host_gap_s, hw=self.hw, n_chips=self.n_chips)
         out = {
             "step_ms": wall_s * 1e3,
             "device_ms": self._device_s * 1e3,
             "host_gap_ms": host_gap_s * 1e3,
-            "mfu": rl["mfu"],
-            "hbm_util": rl["hbm_util"],
-            "verdict": rl["verdict"],
-            "hardware": rl["hardware"],
             "tokens": self._tokens,
+            "flops": self._flops,
+            "hbm_bytes": self._hbm_bytes,
+            # mfu / hbm_util / verdict / hardware: only with a peak.
+            **roofline(
+                StepCost(self._flops, self._hbm_bytes, self._tokens),
+                self._device_s, host_gap_s, hw=self.hw,
+                n_chips=self.n_chips),
         }
         self.last = out
         if record_as is not None:

@@ -429,16 +429,19 @@ def test_e2e_ttft_breach_incident_with_evidence_and_autoresolve(capsys):
 
         for i in range(3):
             hit(i)
-        # Wait for the TTFT and roofline-verdict series to exist before
-        # declaring, so the incident opens with full evidence.
+        # Wait for the TTFT and step-time series and a finalized trace
+        # of the deployment to exist before declaring, so the incident
+        # opens with full evidence (the evidence is a snapshot at open).
         want = {"serve_p95_ms:LLMServer:ttft",
-                "llm_roofline_verdict:LLMServer"}
+                "llm_device_ms:LLMServer"}
         deadline = time.monotonic() + 60
         while time.monotonic() < deadline:
-            if want <= set(state.timeseries_metrics()):
+            if want <= set(state.timeseries_metrics()) \
+                    and state.list_traces(deployment="LLMServer"):
                 break
             time.sleep(0.2)
         assert want <= set(state.timeseries_metrics())
+        assert state.list_traces(deployment="LLMServer")
 
         row = state.declare_slo({
             "name": "e2e-ttft", "metric": "serve_p95_ms:LLMServer:ttft",
@@ -470,7 +473,8 @@ def test_e2e_ttft_breach_incident_with_evidence_and_autoresolve(capsys):
         alerts = {a["name"]: a for a in state.list_alerts()}
         assert alerts["e2e-ttft"]["state"] == "firing"
 
-        # Evidence bundle: trace_id resolves, roofline verdicts decode.
+        # Evidence bundle: trace_id resolves. (Roofline evidence from
+        # synthetic series: test_incident_evidence_* above.)
         inc = state.get_incident(incident["id"])
         ev = inc["evidence"]
         assert ev["deployment"] == "LLMServer"
@@ -478,9 +482,9 @@ def test_e2e_ttft_breach_incident_with_evidence_and_autoresolve(capsys):
         assert ev["exemplar"] and ev["exemplar"]["trace_id"]
         spans = state.get_trace(ev["exemplar"]["trace_id"])
         assert spans, "exemplar trace_id must resolve via state.get_trace"
-        assert ev["roofline"] and ev["roofline"]["verdicts"]
-        assert all(v in ("compute", "hbm", "host")
-                   for v in ev["roofline"]["verdicts"])
+        # The CPU backend has no peak: the engine publishes no MFU and
+        # no verdict, so there is no roofline evidence to attach.
+        assert not ev.get("roofline")
         assert inc["events"][0]["kind"] == "open"
 
         # Ledger: the breach landed in the job-plane decision ledger.
@@ -509,7 +513,7 @@ def test_e2e_ttft_breach_incident_with_evidence_and_autoresolve(capsys):
             address=None, temp_dir=None, json=False, id=incident["id"]))
         out = capsys.readouterr().out
         assert incident["id"] in out
-        assert "roofline" in out
+        assert "roofline" not in out    # no peak on the CPU backend
         assert "serve.request" in out   # exemplar waterfall rendered
 
         # Surface 2: dashboard pane data.

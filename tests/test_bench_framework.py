@@ -11,5 +11,9 @@ def test_framework_bench_path_runs():
     import bench
 
     result = bench.run_bench_framework()
-    assert result["metric"].endswith("_framework")
+    assert "framework" in result["metric"]
     assert result["value"] > 0
+    # A CPU run names its device and never borrows a device metric's
+    # name or unit.
+    assert result["device"]["platform"] == "cpu"
+    assert "cpu" in result["metric"] and "chip" not in result["unit"]

@@ -1,6 +1,6 @@
 """Pure-unit tests for the multi-tenant job plane's decision cores:
 stride/DRF fair-share math, quota accounting across finish/crash/stop
-races, and the admission-rejection taxonomy. No cluster, no clocks —
+races, and the admission-rejection catalogue. No cluster, no clocks —
 everything here is deterministic arithmetic.
 """
 
@@ -205,7 +205,7 @@ def test_quota_release_is_idempotent_across_races():
 
 
 # ---------------------------------------------------------------------------
-# Admission taxonomy
+# Admission catalogue
 # ---------------------------------------------------------------------------
 ENVELOPE = [{"name": "v5e-2x2", "resources": {"TPU": 4, "CPU": 8},
              "hosts": 1},

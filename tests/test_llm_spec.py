@@ -154,6 +154,8 @@ def test_spec_stats_and_gauge_surface():
     assert s["spec_tokens_per_step"] >= 1.0
     assert s["spec"]["mode"] == "ngram"
     assert s["spec"]["verify_steps"] == eng._spec.verify_steps
+    # Read off the lowered verify program (the one this engine steps with).
+    assert s["paged_kernel"] == "interpret"
     kinds = {k for _, k, _ in eng._spec.events}
     assert {"propose", "verify", "accept"} <= kinds
 

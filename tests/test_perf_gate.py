@@ -235,7 +235,7 @@ def test_step_accounting_overhead_gate():
 
     cal = _calibrate()
     acc = perfmodel.StepAccounting(
-        hw=perfmodel.HARDWARE_PEAKS["cpu-interpret"])
+        hw=perfmodel.HARDWARE_PEAKS[perfmodel.V5E])
     ctx = [100, 200, 300, 400, 500, 600, 700, 800]
     # Warm the per-config shape cache out of the measured region.
     perfmodel.decode_step_cost(GPT2_SMALL, ctx)

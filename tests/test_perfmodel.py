@@ -102,19 +102,41 @@ def test_step_cost_addition():
 # ---------------------------------------------------------------------------
 # Hardware table + roofline verdicts
 # ---------------------------------------------------------------------------
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
 def test_hardware_table_and_detection():
-    assert HARDWARE_PEAKS["v5e"].flops_per_s == 197e12
-    assert HARDWARE_PEAKS["cpu-interpret"].flops_per_s == 1e12
-    # CPU backend (the test environment) falls back, never raises.
-    assert detect_hardware().name in HARDWARE_PEAKS
-    assert detect_hardware(device=object()).name == "cpu-interpret"
-    # bench.py's historical on_tpu toggle maps to v5e / cpu-interpret.
-    assert perfmodel.peak_flops(on_tpu=True) == 197e12
-    assert perfmodel.peak_flops(on_tpu=False) == 1e12
+    # Keyed by the device_kind jax reports for a v5e chip.
+    assert HARDWARE_PEAKS["TPU v5 lite"].flops_per_s == 197e12
+    assert HARDWARE_PEAKS["TPU v5 lite"].hbm_bytes_per_s == 819e9
+    assert detect_hardware(_Dev("tpu", "TPU v5 lite")) \
+        is HARDWARE_PEAKS[perfmodel.V5E]
+    # The CPU backend (the test environment) has no peak at all.
+    assert detect_hardware() is None
+    assert detect_hardware(_Dev("cpu", "cpu")) is None
+    # An accelerator outside the table is an error, never a default.
+    with pytest.raises(ValueError, match="unknown accelerator 'TPU v9'"):
+        detect_hardware(_Dev("tpu", "TPU v9"))
+
+
+def test_cpu_runs_get_no_utilization():
+    """Without a peak a step publishes counts and times — no MFU, no
+    HBM utilization, no verdict."""
+    assert roofline(StepCost(1e9, 1e6, 4), 0.01, 0.0, hw=None) == {}
+    acc = StepAccounting()          # CPU backend: hw is None
+    assert acc.hw is None
+    acc.begin()
+    acc.add_device(0.002, StepCost(1e9, 1e6, 4))
+    out = acc.finish()
+    assert out["tokens"] == 4 and out["flops"] == 1e9
+    assert out["hbm_bytes"] == 1e6 and out["device_ms"] > 0
+    assert not {"mfu", "hbm_util", "verdict", "hardware"} & set(out)
 
 
 def test_roofline_verdicts():
-    hw = HARDWARE_PEAKS["v5e"]
+    hw = HARDWARE_PEAKS[perfmodel.V5E]
     # Pure compute: lots of flops, no bytes.
     r = roofline(StepCost(197e12 * 0.5, 0.0), 1.0, 0.0, hw=hw)
     assert r["mfu"] == pytest.approx(0.5)
@@ -137,7 +159,7 @@ def test_roofline_verdicts():
 # StepAccounting + the device-step ring
 # ---------------------------------------------------------------------------
 def test_step_accounting_lifecycle():
-    acc = StepAccounting(hw=HARDWARE_PEAKS["v5e"])
+    acc = StepAccounting(hw=HARDWARE_PEAKS[perfmodel.V5E])
     acc.begin()
     out = acc.finish()
     assert out is None and acc.last is None  # idle tick: not a step
@@ -164,7 +186,7 @@ def test_step_accounting_lifecycle():
 def test_device_step_ring_records_and_filters():
     perfmodel.clear_device_steps()
     t0 = time.time()
-    acc = StepAccounting(hw=HARDWARE_PEAKS["cpu-interpret"])
+    acc = StepAccounting(hw=HARDWARE_PEAKS[perfmodel.V5E])
     acc.begin()
     acc.add_device(0.001, StepCost(1e6, 1e5, 1))
     acc.finish(record_as="llm.step", attrs={"deployment": "d1"})

@@ -196,3 +196,80 @@ def test_late_join_and_preemption_through_serve(rt_llm):
     # have preempted at least once, and output still matched exactly.
     total_preempt = sum(fr[-1]["preemptions"] for fr in frames.values())
     assert total_preempt > 0, frames
+
+
+_DETACHED_DRIVER = """
+import json, os, urllib.request
+import ray_tpu
+from ray_tpu import serve
+from ray_tpu.models import gpt
+from ray_tpu.serve.llm import build_app
+from ray_tpu.util import state
+
+ray_tpu.init(address=os.environ["RT_ADDRESS"])
+h = serve.run(build_app(gpt.TINY, num_blocks=64, block_size=8,
+                        max_batch=4), name="llm")
+proxy = serve.start(http_port=0)
+st = h.options(method_name="engine_stats").remote().result(timeout=120)
+req = urllib.request.Request(
+    f"http://127.0.0.1:{proxy.port}/",
+    data=json.dumps({"prompt": [1, 2, 3, 4], "max_tokens": 4}).encode(),
+    headers={"Content-Type": "application/json"})
+with urllib.request.urlopen(req, timeout=120) as r:
+    frames = [json.loads(x) for x in r.read().splitlines() if x.strip()]
+print("RESULT " + json.dumps({
+    "platform": st["platform"], "paged_kernel": st["paged_kernel"],
+    "frames": frames, "actors": state.list_actors(),
+    "nodes": state.list_nodes()}, default=str))
+serve.shutdown()
+ray_tpu.shutdown()
+"""
+
+
+def test_readme_recipe_on_detached_cluster_lands_on_the_node_daemon(
+        tmp_path):
+    """`serve.run(build_app(...))` from a driver attached to an
+    `rtpu start --head` cluster: the attached driver hosts no device
+    lane, so the replica must be placed on the node daemon's (the
+    process that owns the host's chips), construct there from a class
+    the daemon never saw, and answer `engine_stats` and a stream."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    temp = str(tmp_path / "rtpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=repo + os.pathsep + os.environ.get(
+                   "PYTHONPATH", ""))
+    for k in ("RT_SESSION_TOKEN", "RT_ADDRESS"):
+        env.pop(k, None)
+    cli = [sys.executable, "-m", "ray_tpu.scripts.cli", "--temp-dir", temp]
+    subprocess.run(cli + ["start", "--head", "--num-cpus", "2"], env=env,
+                   check=True, timeout=90, capture_output=True)
+    try:
+        with open(os.path.join(temp, "head_address")) as f:
+            addr = f.read().strip()
+        out = subprocess.run(
+            [sys.executable, "-u", "-c", _DETACHED_DRIVER],
+            env=dict(env, RT_ADDRESS=addr,
+                     RT_TOKEN_FILE=os.path.join(temp, "session_token")),
+            capture_output=True, text=True, timeout=240)
+        with open(os.path.join(temp, "node.log")) as f:
+            node_log = f.read()
+    finally:
+        subprocess.run(cli + ["stop"], env=env, timeout=60,
+                       capture_output=True)
+    assert out.returncode == 0, out.stderr[-3000:]
+    got = json.loads(next(line for line in out.stdout.splitlines()
+                          if line.startswith("RESULT "))[7:])
+    assert got["platform"] == "cpu" and got["paged_kernel"] == "interpret"
+    assert [f["token"] for f in got["frames"][:-1]] and \
+        got["frames"][-1]["done"] and got["frames"][-1]["num_tokens"] == 4
+    daemon = next(n for n in got["nodes"] if not n["is_driver"])
+    assert daemon["resources"]["device"] >= 1, node_log[-2000:]
+    driver = next(n for n in got["nodes"] if n["is_driver"])
+    assert driver["resources"]["device"] == 0
+    replica = next(a for a in got["actors"]
+                   if a["name"].startswith("SERVE:LLMServer"))
+    assert replica["is_device"] and replica["node_id"] == daemon["node_id"]

@@ -1,0 +1,136 @@
+"""The main path's kernels compile for the chip, at real widths.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (/opt/skills/guides/on-chip-measurement §2), so
+these tests refuse what the chip's compiler would refuse — a misaligned
+tile, too much VMEM, a kernel that cannot be partitioned over a mesh —
+without chip time. Nothing runs: a pass here is a compiler verdict, not
+a chip result.
+
+The topology is described inside a module-scoped fixture and only there:
+one process at a time may load libtpu, so under xdist the call must not
+happen at import or collection, and these tests stay in this one file.
+"""
+
+import dataclasses
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import gpt
+from ray_tpu.ops.pallas.flash import flash_attention_pallas
+from ray_tpu.ops.pallas.paged_decode import (paged_decode_attention,
+                                             paged_verify_attention)
+from ray_tpu.parallel import MeshSpec
+
+# GPT-2-small serving widths: 12 KV heads x head_dim 64, block 16.
+HKV, HD, BS, NB = 12, 64, 16, 4096
+MAX_NB = 1024 // BS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu / topology here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The program picks kernel vs reference and compiled vs interpreted
+    from jax.default_backend(), which here still says cpu: steer it in
+    the test, not through an option of the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def test_flash_forward_and_backward_compile(one_chip):
+    """The bench shape: 24 rows x 12 heads x seq 1024 x head_dim 64,
+    bf16, block 512, heads-major."""
+    q = jax.ShapeDtypeStruct((24, 12, 1024, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    attn = functools.partial(flash_attention_pallas, causal=True,
+                             block_q=512, block_k=512, interpret=False,
+                             layout="bhsd")
+    fwd = _compile(attn, q, q, q)
+    assert "tpu_custom_call" in fwd.as_text()
+    bwd = _compile(
+        jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2)), q, q, q)
+    assert "tpu_custom_call" in bwd.as_text()
+
+
+@pytest.mark.parametrize("q_len", [1, 5], ids=["decode", "verify_q5"])
+def test_paged_kernel_compiles(one_chip, q_len):
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    B = 32
+    pool = S((HKV, NB, BS, HD), jnp.bfloat16)
+    tables, lens = S((B, MAX_NB), jnp.int32), S((B,), jnp.int32)
+    if q_len == 1:
+        c = _compile(
+            functools.partial(paged_decode_attention, interpret=False),
+            S((B, HKV, 1, HD), jnp.bfloat16), pool, pool, tables, lens)
+    else:
+        c = _compile(
+            functools.partial(paged_verify_attention, interpret=False),
+            S((B, q_len, HKV, 1, HD), jnp.bfloat16), pool, pool, tables,
+            lens, lens)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_flash_compiles_under_a_four_device_mesh(topo, as_tpu):
+    """A Mosaic kernel cannot be partitioned automatically: without the
+    shard_map in models/gpt.py this raises NotImplementedError. Full
+    GPT-2-small widths, depth cut to 2, over MeshSpec.auto(4) (fsdp 4),
+    forward and backward."""
+    cfg = dataclasses.replace(gpt.GPT2_SMALL, n_layer=2, remat=True,
+                              use_flash=True)
+    mesh = MeshSpec.auto(4).build(list(topo.devices))
+    params = jax.tree_util.tree_map(
+        lambda leaf, spec: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec)),
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)),
+        gpt.params_pspecs(cfg))
+    tokens = jax.ShapeDtypeStruct(
+        (8, cfg.max_seq), jnp.int32,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"))))
+    c = _compile(jax.grad(lambda p, t: gpt.loss_fn(p, t, cfg, mesh)),
+                 params, tokens)
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text or "all-reduce" in text
+
+
+def test_whole_decode_step_compiles_with_the_kernel(one_chip, as_tpu):
+    """forward_decode at max_batch 32 through the engine's own entry:
+    the kernel must be in the program, compiled, not interpreted."""
+    cfg = gpt.GPT2_SMALL
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
+    pool = S((cfg.n_layer, HKV, NB, BS, HD), jnp.bfloat16)
+    B, i32 = 32, jnp.int32
+    step = jax.jit(functools.partial(gpt.forward_decode, cfg=cfg),
+                   donate_argnums=(3, 4))
+    c = step.lower(params, S((B,), i32), S((B,), i32), pool, pool,
+                   S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
+                   S((B,), i32)).compile()
+    assert "tpu_custom_call" in c.as_text()
