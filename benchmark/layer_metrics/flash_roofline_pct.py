@@ -1,0 +1,22 @@
+"""Share of the compute roofline the flash kernels reach: the least
+time the chip could take for the attention operations one step needs
+(benchmark/flops.py ``flash_flops_per_step``: causal scores and values
+forward, the backward's four products and one recomputation of the
+scores) at the published bf16 peak, over the kernels' device time in
+the trace. Compute-bound: at these shapes the operations take ~50x
+longer at peak than moving q, k, v, o and their gradients at 819 GB/s."""
+
+from benchmark import flops, kernels
+
+
+def read(c):
+    t = c.get("trace")
+    if not t:
+        return None
+    per_step = kernels.mosaic_s_per_step(t, kernels.flash_operand(c))
+    if per_step is None:
+        return None
+    need = flops.flash_flops_per_step(c["model_fields"],
+                                      c["batch"] // c["chips"], c["seq"])
+    peak = flops.peaks(c["device"]["kind"])["bf16_flops_per_s"]
+    return 100.0 * (need / peak) / per_step
