@@ -161,49 +161,273 @@ def render_flamegraph_svg(folded: str, title: str = "rtpu flamegraph",
 # ---------------------------------------------------------------------------
 # Gang-coordinated device capture (the `rtpu profile --device` unit)
 # ---------------------------------------------------------------------------
-# Each process answers a ``device_profile`` RPC with three layers for
+# Each process answers a ``device_profile`` RPC with four layers for
 # the window:
 #   * device_steps — the deterministic spine: every accounted engine /
 #     train step from the perfmodel ring (name, wall time, device/host
-#     split, MFU, verdict). Always present, backend or not.
+#     split, phases, counts, MFU, verdict). Always present, backend or
+#     not.
 #   * host.timeline — sampling-profiler leaf frames with timestamps
 #     (what the host was doing between device spans) + folded stacks.
-#   * jax_trace — raw Chrome events from a ``jax.profiler`` trace
-#     session when the backend supports it (best-effort: interpret-mode
-#     CPU runs and jax-less workers degrade to the layers above).
+#   * idle_gaps — the device's idle intervals put down to the host
+#     phase (util/perfmodel.PHASES) that covers them, read from ALL
+#     rows of the ``jax.profiler`` session's ``.xplane.pb``: device ops
+#     and the program's TraceAnnotations lie on one clock there.
+#   * jax_trace — the same rows as Chrome events for the merged export
+#     (best-effort: interpret-mode CPU runs and jax-less workers degrade
+#     to the layers above).
 # The driver merges windows from every process into one Chrome/Perfetto
 # export, aligning each host's wall clock by RPC-measured RTT offsets.
 
-_MAX_JAX_EVENTS = 20000
+# Lines of a device plane that do not hold operations (the TPU's op
+# line is ``XLA Ops``; other backends' are whatever is not one of
+# these).
+_NOT_OP_LINES = ("Steps", "XLA Modules", "XLA TraceMe", "Framework Ops",
+                 "Framework Name Scope", "Source code", "Sparse Core Steps")
+# The merged Chrome export keeps this many device ops a process, the
+# longest ones; every reduction reads all rows.
+_EXPORT_OPS = 20000
 
 
-def _collect_jax_trace(tmpdir: str) -> dict:
-    """Locate + parse the Chrome-format artifact a jax.profiler trace
-    session left under ``tmpdir`` (perfetto_trace.json.gz or
-    *.trace.json.gz). Returns {"events": [...]} or {"error": ...}."""
+def _is_device_plane(name: str) -> bool:
+    return name.startswith("/device:") and "CUSTOM" not in name \
+        and "host" not in name.lower()
+
+
+def read_xplane(trace_dir: str) -> tuple:
+    """``(rows, start_wall)`` of the newest ``.xplane.pb`` under
+    ``trace_dir``. ``rows``: every event as ``(plane, line, name,
+    start_ns, duration_ns)``, host planes and device planes alike, never
+    truncated; times are the profiler's own, nanoseconds from the
+    session's start, one clock for all planes. ``start_wall``: that
+    start on the wall clock (seconds; the session's own record of it),
+    None where the file does not say."""
     import glob
-    import gzip
-    import json as _json
     import os
 
-    paths = sorted(
-        glob.glob(os.path.join(tmpdir, "**", "*.json.gz"), recursive=True),
-        key=lambda p: ("perfetto" not in p, p))
-    for path in paths:
-        try:
-            with gzip.open(path, "rt") as f:
-                data = _json.load(f)
-        except Exception:  # noqa: BLE001 - partial/foreign artifact
-            continue
-        events = (data.get("traceEvents", [])
-                  if isinstance(data, dict) else data)
-        if isinstance(events, list):
-            return {"events": events[:_MAX_JAX_EVENTS],
-                    "file": os.path.basename(path)}
-    return {"error": "no chrome-format trace artifact produced"}
+    from jax.profiler import ProfileData
+
+    found = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    rows, start_wall = [], None
+    for plane in ProfileData.from_file(found[-1]).planes:
+        if plane.name == "Task Environment":
+            start_ns = dict(plane.stats).get("profile_start_time")
+            if start_ns:
+                start_wall = float(start_ns) / 1e9
+        for line in plane.lines:
+            for ev in line.events:
+                rows.append((plane.name, line.name, ev.name,
+                             float(ev.start_ns), float(ev.duration_ns)))
+    return rows, start_wall
 
 
-def _start_xla_trace(log_dir: str) -> None:
+def _op_intervals(rows: list) -> dict:
+    """{device plane: [(start_ns, end_ns)] of its op lines' events}."""
+    lines: dict = {}
+    for plane, line, _, start, dur in rows:
+        if _is_device_plane(plane) and dur > 0:
+            lines.setdefault(plane, {}).setdefault(line, []).append(
+                (start, start + dur))
+    out = {}
+    for plane, by_line in lines.items():
+        keep = (["XLA Ops"] if "XLA Ops" in by_line
+                else [n for n in by_line if n not in _NOT_OP_LINES])
+        out[plane] = sorted(iv for n in keep for iv in by_line[n])
+    return out
+
+
+def _gaps(intervals: list) -> list:
+    """The complement of the union of sorted (start, end) intervals,
+    from the first start to the last end."""
+    gaps, reach = [], None
+    for a, b in intervals:
+        if reach is not None and a > reach:
+            gaps.append((reach, a))
+        reach = b if reach is None else max(reach, b)
+    return gaps
+
+
+def _cover(a: float, b: float, spans: list, max_end: list) -> dict:
+    """{name: ns} of the gap (a, b) under ``spans`` (sorted (start, end,
+    name); ``max_end`` their running maximum end). Where spans overlap
+    one another the one that started last, the innermost, has the
+    instant."""
+    from bisect import bisect_left, bisect_right
+
+    lo = bisect_right(max_end, a)
+    hi = bisect_left(spans, (b,))
+    hits = [(max(s, a), min(e, b), s, name)
+            for s, e, name in spans[lo:hi] if e > a]
+    if len(hits) == 1:          # the common case: one phase holds it
+        return {hits[0][3]: hits[0][1] - hits[0][0]}
+    cuts = sorted({t for h in hits for t in h[:2]})
+    out: dict = {}
+    for x, y in zip(cuts, cuts[1:]):
+        over = [h for h in hits if h[0] <= x and h[1] >= y]
+        if over:
+            name = max(over, key=lambda h: h[2])[3]
+            out[name] = out.get(name, 0.0) + (y - x)
+    return out
+
+
+def idle_gaps(rows: list, names=None, top: int = 10) -> dict:
+    """The device's idle time by what the host was doing in it.
+
+    ``rows`` are ``read_xplane``'s. An idle interval is a gap in
+    the union of a device plane's op intervals, first to last op (with
+    several device planes, every plane's gaps: idle chip-seconds). The
+    host events named in ``names`` (default: the span registry,
+    util/perfmodel.PHASES) cover it or do not:
+
+      idle_s       all gaps
+      by_phase_s   {name: seconds of gaps lying under that span}
+      uncovered_s  seconds of gaps under no named span
+      longest      the ``top`` longest gaps as [gap_ms, the name that
+                   covers most of it (None: none does), that name's
+                   share of the gap]
+
+    A device span's name (``llm.decode.device``, ``train.wait``) over a
+    gap says the host was only waiting there; a host phase's says the
+    device waited for that work."""
+    if names is None:
+        from ray_tpu.util import perfmodel
+
+        names = perfmodel.PHASES
+    spans = sorted((start, start + dur, name)
+                   for plane, _, name, start, dur in rows
+                   if name in names and dur > 0
+                   and not _is_device_plane(plane))
+    max_end, reach = [], float("-inf")
+    for _, e, _ in spans:
+        reach = max(reach, e)
+        max_end.append(reach)
+    by_phase: dict = {}
+    idle = uncovered = 0.0
+    longest = []
+    for intervals in _op_intervals(rows).values():
+        for a, b in _gaps(intervals):
+            cover = _cover(a, b, spans, max_end)
+            for name, ns in cover.items():
+                by_phase[name] = by_phase.get(name, 0.0) + ns
+            idle += b - a
+            uncovered += (b - a) - sum(cover.values())
+            name = max(cover, key=cover.get) if cover else None
+            longest.append([(b - a) / 1e6, name,
+                            cover[name] / (b - a) if cover else 0.0])
+    longest.sort(key=lambda g: -g[0])
+    return {"idle_s": idle / 1e9,
+            "by_phase_s": {k: v / 1e9 for k, v in sorted(
+                by_phase.items(), key=lambda kv: -kv[1])},
+            "uncovered_s": uncovered / 1e9,
+            "longest": longest[:top]}
+
+
+def format_idle_gaps(table: dict) -> str:
+    """The idle-gap table as `rtpu profile --device` prints it."""
+    idle = table["idle_s"]
+    if idle <= 0:
+        return "  no idle gap between device ops in this window"
+    lines = [f"  device idle {idle * 1e3:.1f} ms between its first and "
+             f"last op, by the host span over each gap:"]
+    for name, s in list(table["by_phase_s"].items()) + [
+            ("(no named span)", table["uncovered_s"])]:
+        lines.append(f"    {name:<22} {s * 1e3:9.2f} ms  "
+                     f"{100 * s / idle:5.1f}%")
+    lines.append("  longest gaps (ms, span over most of it, its share):")
+    for gap_ms, name, share in table["longest"]:
+        lines.append(f"    {gap_ms:9.3f}  {name or '-':<22} "
+                     f"{100 * share:5.1f}%")
+    return "\n".join(lines)
+
+
+def format_device_steps(steps: list) -> str:
+    """A window's accounted steps (``device_steps``: the perfmodel
+    ring's entries) as `rtpu profile --device` prints them, one block a
+    step name and owner: the mean step split into its device spans by
+    kind and its host phases by name, and an engine's own counts."""
+    groups: dict = {}
+    for ev in steps:
+        owner = ev.get("deployment") or ev.get("trial") or ""
+        groups.setdefault((ev["name"], owner), []).append(ev)
+    lines = []
+    for (name, owner), evs in sorted(groups.items()):
+        n = len(evs)
+
+        def mean(key, sub=None):
+            vals = [(e.get(key) or {}).get(sub, 0.0) if sub else
+                    e.get(key, 0.0) for e in evs]
+            return sum(vals) / n
+
+        def names(key):
+            return sorted({k for e in evs for k in e.get(key) or {}},
+                          key=lambda k: -mean(key, k))
+
+        by = ", ".join(f"{k} {mean('device_ms_by', k):.2f}"
+                       for k in names("device_ms_by"))
+        lines.append(
+            f"  {name} x {n}{' (' + owner + ')' if owner else ''}: "
+            f"{mean('step_ms'):.2f} ms a step = device "
+            f"{mean('device_ms'):.2f}{' (' + by + ')' if by else ''} + "
+            f"host {mean('host_gap_ms'):.2f}")
+        phases = [f"{k} {mean('phases_ms', k):.2f}"
+                  for k in names("phases_ms")]
+        if phases:
+            lines.append(f"    host by phase: {', '.join(phases)}, "
+                         f"other {mean('other_ms'):.2f}")
+        if "lanes" in evs[0]:
+            lines.append(
+                f"    lanes {mean('lanes'):.1f} of {evs[0]['max_batch']}"
+                f" over {mean('context_tokens'):.0f} context tokens; "
+                f"tokens computed: decode "
+                f"{sum(e['decode_tokens'] for e in evs)}, prefill "
+                f"{sum(e['prefill_tokens'] for e in evs)} in "
+                f"{sum(len(e['prefill_chunks']) for e in evs)} chunk(s); "
+                f"waiting {max(e['waiting'] for e in evs)} at most; "
+                f"preempted {sum(e['preempted'] for e in evs)}")
+    return "\n".join(lines)
+
+
+def _trace_events(rows: list, t0_wall: float) -> list:
+    """``rows`` as Chrome "X" events on the wall clock (the session
+    started at ``t0_wall``): the program's named spans and steps from
+    the host planes, and the device planes' modules, steps and ops (the
+    ``_EXPORT_OPS`` longest, names cut to a line)."""
+    from ray_tpu.util import perfmodel
+
+    named = set(perfmodel.PHASES) | set(perfmodel.STEPS)
+    ops = [r for r in rows if _is_device_plane(r[0])
+           and r[1] not in _NOT_OP_LINES]
+    if len(ops) > _EXPORT_OPS:
+        ops = sorted(ops, key=lambda r: -r[4])[:_EXPORT_OPS]
+    keep = ops + [r for r in rows
+                  if (_is_device_plane(r[0])
+                      and r[1] in ("XLA Modules", "Steps"))
+                  or (not _is_device_plane(r[0]) and r[2] in named)]
+    pids: dict = {}
+    tids: dict = {}
+    events = []
+    for plane, line, name, start, dur in keep:
+        if plane not in pids:
+            pids[plane] = len(pids) + 1
+            events.append({"ph": "M", "pid": pids[plane], "tid": 0,
+                           "name": "process_name",
+                           "args": {"name": plane}})
+        if (plane, line) not in tids:
+            tids[plane, line] = len(tids) + 1
+            events.append({"ph": "M", "pid": pids[plane],
+                           "tid": tids[plane, line],
+                           "name": "thread_name", "args": {"name": line}})
+        events.append({"ph": "X", "pid": pids[plane],
+                       "tid": tids[plane, line], "name": name[:120],
+                       "ts": t0_wall * 1e6 + start / 1e3,
+                       "dur": max(dur / 1e3, 0.001)})
+    return events
+
+
+def _start_xla_trace(log_dir: str) -> float:
     """Start a jax.profiler trace into ``log_dir`` with the PYTHON
     tracer OFF. The default python tracer (PEP 523 eval hook)
     permanently hides threads that were alive during the session from
@@ -217,15 +441,18 @@ def _start_xla_trace(log_dir: str) -> None:
     jax.devices()  # the backend must be up before the tracer starts
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
-    jax.profiler.start_trace(log_dir, create_perfetto_trace=True,
-                             profiler_options=opts)
+    # The wall clock just before the session starts: the anchor of its
+    # rows where the trace does not record its own start.
+    t_wall = time.time()
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+    return t_wall
 
 
 def device_profile(duration_s: float = 2.0, hz: float = 99.0,
                    include_jax: bool = True) -> dict:
     """One capture window for THIS process: start an XLA profiler trace
     session, run the host sampling profiler for the window, stop the
-    trace, and return all three layers plus the process's wall clock at
+    trace, and return all four layers plus the process's wall clock at
     the window edges (the driver's clock-alignment anchors)."""
     import shutil
     import tempfile
@@ -238,19 +465,24 @@ def device_profile(duration_s: float = 2.0, hz: float = 99.0,
     if include_jax:
         tmpdir = tempfile.mkdtemp(prefix="rtpu-devprof-")
         try:
-            _start_xla_trace(tmpdir)
+            t_trace_wall = _start_xla_trace(tmpdir)
         except Exception as e:  # noqa: BLE001 - capture must not kill
             jax_err = f"xla trace unavailable: {e!r}"
             shutil.rmtree(tmpdir, ignore_errors=True)
             tmpdir = None
     host = sample_profile(duration_s, hz, timeline=True)
     jax_trace: dict = {"error": jax_err or "jax trace disabled"}
+    gaps = None
     if tmpdir is not None:
         try:
             import jax
 
             jax.profiler.stop_trace()
-            jax_trace = _collect_jax_trace(tmpdir)
+            rows, start_wall = read_xplane(tmpdir)
+            gaps = idle_gaps(rows)
+            jax_trace = {"events": _trace_events(
+                             rows, start_wall or t_trace_wall),
+                         "rows": len(rows)}
         except Exception as e:  # noqa: BLE001
             jax_trace = {"error": f"trace export failed: {e!r}"}
         finally:
@@ -260,6 +492,7 @@ def device_profile(duration_s: float = 2.0, hz: float = 99.0,
         "t1_wall": time.time(),
         "host": host,
         "device_steps": perfmodel.device_step_events(since=t0_wall - 1.0),
+        "idle_gaps": gaps,
         "jax_trace": jax_trace,
     }
 
@@ -278,8 +511,9 @@ def build_merged_trace(profiles: dict, offsets: dict | None = None,
 
     Tracks per process: ``device-steps`` (accounted engine/train steps,
     colored by roofline verdict), ``host-cpu`` (sampling-profiler leaf
-    frames), and the raw jax trace events re-based onto the aligned
-    clock. Times are Chrome-trace microseconds."""
+    frames), and the jax trace events (device ops, programs and the
+    program's named host spans) re-based onto the aligned clock. Times
+    are Chrome-trace microseconds."""
     offsets = offsets or {}
     events: list = []
     pids: dict = {}

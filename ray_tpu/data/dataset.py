@@ -34,6 +34,8 @@ import numpy as np
 from . import block as B
 from .context import DataContext
 
+_NO_BATCH = object()    # next()'s default where a batch may be anything
+
 
 # ---------------------------------------------------------------------------
 # Logical stages (fused at execution time)
@@ -639,7 +641,29 @@ class Dataset:
         """Re-batched iteration. batch_format: "numpy" | "rows" |
         "jax" | "pandas" | "pyarrow". With ``sharding`` (a
         jax.sharding.Sharding), batches are device_put — the TPU ingest
-        path (batch dim must divide the data axes)."""
+        path (batch dim must divide the data axes).
+
+        On a training loop's thread each wait for a batch is the
+        ``data.next_batch`` phase of the step it falls in
+        (util/perfmodel.py), which ``train.report`` hands on as
+        ``train_data_wait_ms``."""
+        from ..util import perfmodel
+
+        batches = self._batches(batch_size, batch_format, sharding,
+                                drop_last, dtypes)
+        acc = perfmodel.bound_accounting()
+        if acc is None:
+            yield from batches
+            return
+        while True:
+            with acc.phase("data.next_batch"):
+                batch = next(batches, _NO_BATCH)
+            if batch is _NO_BATCH:
+                return
+            yield batch
+
+    def _batches(self, batch_size, batch_format, sharding, drop_last,
+                 dtypes) -> Iterator[Any]:
         if batch_format in ("rows", "pandas", "pyarrow") and (
                 sharding is not None or dtypes):
             raise ValueError(

@@ -103,6 +103,7 @@ class Request:
     emitted: int = 0              # tokens already pushed to the consumer
     finish_reason: Optional[str] = None
     submit_t: float = 0.0
+    admit_t: Optional[float] = None     # first admission into the batch
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     preemptions: int = 0
@@ -136,15 +137,20 @@ def _jit_programs(cfg: GPTConfig, mesh, rules):
     (cfg, mesh, rules) share one set of wrappers instead; donation is
     per-call, so two live engines sharing a program donate only their
     own pools."""
+    def program(name, fn, **jit_kwargs):
+        # The name is what a device trace's ``XLA Modules`` line shows
+        # (``jit_<name>``); a functools.partial has none of its own.
+        def named(*args):
+            return fn(*args, cfg=cfg, mesh=mesh, rules=rules)
+
+        named.__name__ = named.__qualname__ = name
+        return jax.jit(named, **jit_kwargs)
+
     return (
-        jax.jit(functools.partial(forward_decode, cfg=cfg, mesh=mesh,
-                                  rules=rules), donate_argnums=(3, 4)),
-        jax.jit(functools.partial(forward_prefill, cfg=cfg, mesh=mesh,
-                                  rules=rules)),
-        jax.jit(functools.partial(forward_prefill_chunk, cfg=cfg,
-                                  mesh=mesh, rules=rules)),
-        jax.jit(functools.partial(forward_verify, cfg=cfg, mesh=mesh,
-                                  rules=rules), donate_argnums=(3, 4)),
+        program("llm_decode", forward_decode, donate_argnums=(3, 4)),
+        program("llm_prefill", forward_prefill),
+        program("llm_prefill_chunk", forward_prefill_chunk),
+        program("llm_verify", forward_verify, donate_argnums=(3, 4)),
     )
 
 
@@ -237,7 +243,14 @@ class LLMEngine:
         # the shared cost model (util/perfmodel.py) into MFU / HBM-util /
         # roofline-verdict series. The concurrency-net lint holds
         # _run_prefills/_run_decode/step to feeding it.
+        # Named host phases and device spans by kind ride the same
+        # object (perfmodel.PHASES), so a step's ring entry says which
+        # part of the host gap was admission, input building, sampling,
+        # emission or publishing, and what the loop did between steps.
         self._step_perf = perfmodel.StepAccounting()
+        self._preempt_count = 0       # preemptions, all steps
+        self._chunk_log: List[list] = []    # this step's prefill chunks
+        self._counts = (0, 0, 0)    # lanes, context tokens, decode tokens
 
     # -- events ------------------------------------------------------------
 
@@ -336,6 +349,10 @@ class LLMEngine:
             self._waiting.popleft()
             self._active.append(req)
             self._event(req, PREFILL)
+            if req.admit_t is None:
+                # The end of the request's queue wait (a resume after
+                # preemption is not a second arrival).
+                req.admit_t = time.time()
             if req.preemptions and req.trace_ctx is not None:
                 # Resume after preemption: an instant on the victim's
                 # own trace closing the preempt->resume gap.
@@ -378,6 +395,7 @@ class LLMEngine:
         req.prefilled_upto = 0
         req.cached_tokens = 0
         req.preemptions += 1
+        self._preempt_count += 1
         self._waiting.appendleft(req)
         self._event(req, PREEMPTED)
         if req.trace_ctx is not None:
@@ -401,14 +419,18 @@ class LLMEngine:
         self._event(req, FINISHED)
         req.out_q.put(None)
 
+    def _sample(self, req: Request, logits_row) -> int:
+        """The next token at the request's current absolute position
+        (keyed by (seed, position) alone, so lanes sample in any
+        order)."""
+        return sample(logits_row, temperature=req.temperature,
+                      top_k=req.top_k, seed=req.seed,
+                      position=len(req.prompt) + len(req.output))
+
     def _sample_into(self, req: Request, logits_row) -> bool:
-        """Sample the next token at the request's current absolute
-        position; emit it; apply stop conditions. Returns True if the
-        request finished."""
-        pos = len(req.prompt) + len(req.output)
-        tok = sample(logits_row, temperature=req.temperature,
-                     top_k=req.top_k, seed=req.seed, position=pos)
-        return self._emit_token(req, tok)
+        """Sample the next token; emit it; apply stop conditions.
+        Returns True if the request finished."""
+        return self._emit_token(req, self._sample(req, logits_row))
 
     def _emit_token(self, req: Request, tok: int) -> bool:
         """Append an already-decided token (sampled, or an accepted/
@@ -450,92 +472,104 @@ class LLMEngine:
         self._last_prefill_count = len(prefills)
         bs = self.kv.block_size
         budget = self.prefill_chunk_tokens
+        perf = self._step_perf
         for req in prefills:
-            t0 = time.time()
-            seq = req.prompt + req.output
-            T = len(seq)
-            if req.prefilled_upto >= T:
-                # Full prefix-cache hit: zero prefill compute.
-                self._activate(req, None)
-                if req.trace_ctx is not None:
-                    tracing.emit("llm.prefill", req.trace_ctx, t0, 0.0,
-                                 {"rid": req.rid, "tokens": T,
-                                  "cached": req.cached_tokens,
-                                  "resumed": bool(req.preemptions),
-                                  "device_ms": 0.0, "host_ms": 0.0})
-                continue
-            if budget is not None and budget <= 0:
-                break       # out of chunk budget; cursor resumes next step
-            upto = req.prefilled_upto
-            rem = T - upto
-            c = rem if budget is None else min(rem, budget)
-            if c < rem:
-                # Mid-prompt chunks stay block-aligned (write_prefill
-                # scatters whole blocks); a budget below one block still
-                # makes one block of progress.
-                c = (c // bs) * bs or min(bs, rem)
-            if budget is not None:
-                budget -= c
-            pad = -c % bs or 0
-            t_disp = time.perf_counter()
-            if upto == 0 and c == T:
-                # Cold whole-prompt prefill: the classic one-shot path.
-                toks = np.zeros((1, T + pad), np.int32)
-                toks[0, :T] = seq
-                logits, k, v = self._prefill(self.params, toks)
-            else:
-                # Incremental span [upto, upto+c) attending resident
-                # context (earlier chunks and/or prefix-cache hits).
-                toks = np.zeros((1, c + pad), np.int32)
-                toks[0, :c] = seq[upto:upto + c]
-                positions = np.minimum(
-                    upto + np.arange(c + pad, dtype=np.int32),
-                    self.cfg.max_seq - 1)
-                table = np.zeros((self.max_nb,), np.int32)
-                table[:len(req.block_table)] = req.block_table
-                logits, k, v = self._prefill_chunk(
-                    self.params, toks, positions, self.kv.k, self.kv.v,
-                    table, np.int32(upto))
-            # Export the chunk's cache: [L, 1, c, Hkv, d] -> pool blocks
-            # upto/bs onward (upto is block-aligned by construction).
-            self.kv.write_prefill(
-                k[:, 0, :c], v[:, 0, :c],
-                req.block_table[upto // bs: upto // bs + (c + pad) // bs])
-            req.prefilled_upto = upto + c
-            req.context_len = req.prefilled_upto
-            self._prefill_chunks += 1
-            done = req.prefilled_upto >= T
-            if done:
-                row = np.asarray(jax.device_get(logits[0, c - 1]),
-                                 np.float32)
-            else:
-                jax.block_until_ready(logits)
+            with perf.phase("llm.prefill.host"):
+                t0 = time.time()
+                seq = req.prompt + req.output
+                T = len(seq)
+                if req.prefilled_upto >= T:
+                    # Full prefix-cache hit: zero prefill compute.
+                    self._activate(req, None)
+                    if req.trace_ctx is not None:
+                        tracing.emit("llm.prefill", req.trace_ctx, t0, 0.0,
+                                     {"rid": req.rid, "tokens": T,
+                                      "cached": req.cached_tokens,
+                                      "resumed": bool(req.preemptions),
+                                      "device_ms": 0.0, "host_ms": 0.0})
+                    continue
+                if budget is not None and budget <= 0:
+                    break   # out of chunk budget; cursor resumes next step
+                upto = req.prefilled_upto
+                rem = T - upto
+                c = rem if budget is None else min(rem, budget)
+                if c < rem:
+                    # Mid-prompt chunks stay block-aligned (write_prefill
+                    # scatters whole blocks); a budget below one block
+                    # still makes one block of progress.
+                    c = (c // bs) * bs or min(bs, rem)
+                if budget is not None:
+                    budget -= c
+                pad = -c % bs or 0
+                whole = upto == 0 and c == T
+                if whole:
+                    # Cold whole-prompt prefill: the classic one-shot path.
+                    toks = np.zeros((1, T + pad), np.int32)
+                    toks[0, :T] = seq
+                else:
+                    # Incremental span [upto, upto+c) attending resident
+                    # context (earlier chunks and/or prefix-cache hits).
+                    toks = np.zeros((1, c + pad), np.int32)
+                    toks[0, :c] = seq[upto:upto + c]
+                    positions = np.minimum(
+                        upto + np.arange(c + pad, dtype=np.int32),
+                        self.cfg.max_seq - 1)
+                    table = np.zeros((self.max_nb,), np.int32)
+                    table[:len(req.block_table)] = req.block_table
+                done = upto + c >= T
             # Dispatch-to-logits-ready is the device span (the pool
-            # write may still overlap the host work that follows —
-            # deliberately uncounted, it hides behind sampling). Only
-            # the UNCACHED span is priced: ctx_tokens covers what was
-            # skipped or ran in earlier chunks, keeping MFU honest.
-            device_s = time.perf_counter() - t_disp
-            self._step_perf.add_device(
-                device_s, perfmodel.prefill_cost(self.cfg, c + pad,
-                                                 ctx_tokens=upto))
-            if done:
-                if self._prefix:
-                    # Index the prompt's chunks for later arrivals
-                    # (shared system prompts hit from here on).
-                    self.kv.register(seq, req.block_table)
-                self._activate(req, row)
-            if req.trace_ctx is not None:
-                dur = time.time() - t0
-                tracing.emit("llm.prefill", req.trace_ctx, t0, dur,
-                             {"rid": req.rid, "tokens": c,
-                              "upto": req.prefilled_upto, "total": T,
-                              "cached": req.cached_tokens,
-                              "done": done,
-                              "resumed": bool(req.preemptions),
-                              "device_ms": round(device_s * 1e3, 3),
-                              "host_ms": round(
-                                  max(dur - device_s, 0.0) * 1e3, 3)})
+            # write is dispatched inside it and may still overlap the
+            # host work that follows — deliberately uncounted, it hides
+            # behind sampling).
+            with perf.device("llm.prefill.device") as dev:
+                if whole:
+                    logits, k, v = self._prefill(self.params, toks)
+                else:
+                    logits, k, v = self._prefill_chunk(
+                        self.params, toks, positions, self.kv.k, self.kv.v,
+                        table, np.int32(upto))
+                # Export the chunk's cache: [L, 1, c, Hkv, d] -> pool
+                # blocks upto/bs onward (upto is block-aligned by
+                # construction).
+                self.kv.write_prefill(
+                    k[:, 0, :c], v[:, 0, :c],
+                    req.block_table[upto // bs:
+                                    upto // bs + (c + pad) // bs])
+                if done:
+                    row = np.asarray(jax.device_get(logits[0, c - 1]),
+                                     np.float32)
+                else:
+                    jax.block_until_ready(logits)
+            device_s = dev.seconds
+            with perf.phase("llm.prefill.host"):
+                req.prefilled_upto = upto + c
+                req.context_len = req.prefilled_upto
+                self._prefill_chunks += 1
+                # Only the UNCACHED span is priced: ctx_tokens covers
+                # what was skipped or ran in earlier chunks, keeping MFU
+                # honest.
+                perf.add_cost(perfmodel.prefill_cost(
+                    self.cfg, c + pad, ctx_tokens=upto))
+                # [positions computed (padded to whole blocks, as
+                # priced), context tokens resident before them, ms].
+                self._chunk_log.append([c + pad, upto, device_s * 1e3])
+                if done:
+                    if self._prefix:
+                        # Index the prompt's chunks for later arrivals
+                        # (shared system prompts hit from here on).
+                        self.kv.register(seq, req.block_table)
+                    self._activate(req, row)
+                if req.trace_ctx is not None:
+                    dur = time.time() - t0
+                    tracing.emit("llm.prefill", req.trace_ctx, t0, dur,
+                                 {"rid": req.rid, "tokens": c,
+                                  "upto": req.prefilled_upto, "total": T,
+                                  "cached": req.cached_tokens,
+                                  "done": done,
+                                  "resumed": bool(req.preemptions),
+                                  "device_ms": round(device_s * 1e3, 3),
+                                  "host_ms": round(
+                                      max(dur - device_s, 0.0) * 1e3, 3)})
 
     def _preempt_for(self, req: Request) -> bool:
         """Free pool blocks by preempting a LIFO victim; req itself is
@@ -595,77 +629,96 @@ class LLMEngine:
                 "verdict": rl["verdict"]}
 
     def _run_decode(self):
-        batch = [r for r in self._active if r.state == RUNNING]
-        for req in list(batch):
-            if req.state == RUNNING:
-                self._ensure_slots(req, 1)
-        # An ensure call may have preempted requests anywhere in the
-        # batch (LIFO victims) — only still-RUNNING sequences decode.
-        batch = [r for r in batch if r.state == RUNNING]
+        perf = self._step_perf
+        with perf.phase("llm.slots"):
+            batch = [r for r in self._active if r.state == RUNNING]
+            for req in list(batch):
+                if req.state == RUNNING:
+                    self._ensure_slots(req, 1)
+            # An ensure call may have preempted requests anywhere in the
+            # batch (LIFO victims) — only still-RUNNING sequences decode.
+            batch = [r for r in batch if r.state == RUNNING]
         if not batch:
             return
-        t0 = time.time()
-        B = self.max_batch
-        bs = self.kv.block_size
-        tokens = np.zeros((B,), np.int32)
-        positions = np.zeros((B,), np.int32)
-        slot_blocks = np.zeros((B,), np.int32)
-        slot_offsets = np.zeros((B,), np.int32)
-        # Padded lanes: scratch block 0, context 1 — attention over the
-        # scratch block's garbage is masked-in but their logits are
-        # never sampled.
-        context_lens = np.ones((B,), np.int32)
-        tables = np.zeros((B, self.max_nb), np.int32)
-        for i, req in enumerate(batch):
-            slot = req.context_len
-            # Steady-state lanes feed their last sampled token; a FULL
-            # prefix-cache hit enters decode holding the last sequence
-            # position back (nothing was computed at admission), so its
-            # first step re-feeds that token — write-then-attend then
-            # recomputes its logits for the first sample.
-            tokens[i] = (req.prompt[slot] if slot < len(req.prompt)
-                         else req.output[slot - len(req.prompt)])
-            positions[i] = slot
-            slot_blocks[i] = req.block_table[slot // bs]
-            slot_offsets[i] = slot % bs
-            context_lens[i] = slot + 1
-            tables[i, :len(req.block_table)] = req.block_table
-        t_disp = time.perf_counter()
-        logits, self.kv.k, self.kv.v = self._decode(
-            self.params, tokens, positions, self.kv.k, self.kv.v,
-            tables, context_lens, slot_blocks, slot_offsets)
+        with perf.phase("llm.decode.build"):
+            t0 = time.time()
+            B = self.max_batch
+            bs = self.kv.block_size
+            tokens = np.zeros((B,), np.int32)
+            positions = np.zeros((B,), np.int32)
+            slot_blocks = np.zeros((B,), np.int32)
+            slot_offsets = np.zeros((B,), np.int32)
+            # Padded lanes: scratch block 0, context 1 — attention over
+            # the scratch block's garbage is masked-in but their logits
+            # are never sampled.
+            context_lens = np.ones((B,), np.int32)
+            tables = np.zeros((B, self.max_nb), np.int32)
+            for i, req in enumerate(batch):
+                slot = req.context_len
+                # Steady-state lanes feed their last sampled token; a
+                # FULL prefix-cache hit enters decode holding the last
+                # sequence position back (nothing was computed at
+                # admission), so its first step re-feeds that token —
+                # write-then-attend then recomputes its logits for the
+                # first sample.
+                tokens[i] = (req.prompt[slot] if slot < len(req.prompt)
+                             else req.output[slot - len(req.prompt)])
+                positions[i] = slot
+                slot_blocks[i] = req.block_table[slot // bs]
+                slot_offsets[i] = slot % bs
+                context_lens[i] = slot + 1
+                tables[i, :len(req.block_table)] = req.block_table
+            ctx = [r.context_len + 1 for r in batch]
+            cost = perfmodel.decode_step_cost(self.cfg, ctx)
+            self._counts = (len(batch), sum(ctx), len(batch))
         # block_until_ready bounds the DEVICE span; the device_get that
         # follows is then a cheap copy, so sampling/queue pushes below
         # are charged to the host, not smeared into device time.
-        jax.block_until_ready(logits)
-        device_s = time.perf_counter() - t_disp
-        cost = perfmodel.decode_step_cost(
-            self.cfg, [r.context_len + 1 for r in batch])
-        self._step_perf.add_device(device_s, cost)
-        rows = np.asarray(jax.device_get(logits), np.float32)
+        with perf.device("llm.decode.device") as dev:
+            logits, self.kv.k, self.kv.v = self._decode(
+                self.params, tokens, positions, self.kv.k, self.kv.v,
+                tables, context_lens, slot_blocks, slot_offsets)
+            jax.block_until_ready(logits)
+        device_s = dev.seconds
+        perf.add_cost(cost)
+        sampling, emitting = perf.phase("llm.sample"), perf.phase("llm.emit")
+        with sampling:
+            rows = np.asarray(jax.device_get(logits), np.float32)
+        # Lane by lane, each token out the moment it is decided: a
+        # finish that reaches its caller before the step ends lets a
+        # closed-loop caller's next request make the coming step's
+        # admission (emitting after all lanes were sampled read 0.8 of
+        # a step more TTFT on the chip; PERF.md section 6, PR 24).
         for i, req in enumerate(batch):
             req.context_len += 1
-            self._sample_into(req, rows[i])
-        # One decode-step slice per TRACED sequence in the batch: the
-        # request's waterfall shows its token cadence, and every slice
-        # carries the step's batch composition + pool pressure + the
-        # device-vs-host split and roofline verdict for THIS step.
+            with sampling:
+                tok = self._sample(req, rows[i])
+            with emitting:
+                self._emit_token(req, tok)
         dur = time.time() - t0
-        kv_util = self.kv.utilization()
+        with perf.phase("llm.trace"):
+            self._trace_decode_step(batch, t0, dur, cost, device_s)
+
+    def _trace_decode_step(self, batch, t0, dur, cost, device_s, **extra):
+        """One decode-step slice per TRACED sequence in the batch: the
+        request's waterfall shows its token cadence, and every slice
+        carries the step's batch composition + pool pressure + the
+        device-vs-host split and roofline verdict for THIS step."""
         traced = [r for r in batch if r.trace_ctx is not None]
-        if traced:
-            rl = self._roofline_attrs(cost, device_s, dur)
-            breakdown = {
-                "step": self._steps + 1,
-                "prefill": self._last_prefill_count,
-                "decode": len(batch), "kv_util": kv_util,
-                "device_ms": round(device_s * 1e3, 3),
-                "host_ms": round(max(dur - device_s, 0.0) * 1e3, 3),
-                **rl,
-            }
-            for req in traced:
-                tracing.emit("llm.decode_step", req.trace_ctx, t0, dur,
-                             dict(breakdown, rid=req.rid))
+        if not traced:
+            return
+        breakdown = {
+            "step": self._steps + 1,
+            "prefill": self._last_prefill_count,
+            "decode": len(batch), "kv_util": self.kv.utilization(),
+            **extra,
+            "device_ms": round(device_s * 1e3, 3),
+            "host_ms": round(max(dur - device_s, 0.0) * 1e3, 3),
+            **self._roofline_attrs(cost, device_s, dur),
+        }
+        for req in traced:
+            tracing.emit("llm.decode_step", req.trace_ctx, t0, dur,
+                         dict(breakdown, rid=req.rid))
 
     def _run_verify(self):
         """Speculative decode step: propose up to k tokens per lane,
@@ -684,122 +737,122 @@ class LLMEngine:
         if not batch:
             return
         spec = self._spec
+        perf = self._step_perf
         props: Dict[int, List[int]] = {}
-        for req in batch:
-            # Proposal budget: never past max_tokens (the final token
-            # is sampled, not proposed), never past the block span the
-            # admission check guaranteed, never past max_seq positions.
-            budget = min(
-                req.max_tokens - len(req.output) - 1,
-                len(req.prompt) + req.max_tokens - req.context_len - 1,
-                self.cfg.max_seq - req.context_len - 1)
-            props[req.rid] = spec.propose(
-                req.rid, req.prompt + req.output, budget)
-        for req in list(batch):
-            if req.state == RUNNING:
-                self._ensure_slots(req, 1 + len(props[req.rid]))
-        batch = [r for r in batch if r.state == RUNNING]
+        with perf.phase("llm.decode.build"):
+            for req in batch:
+                # Proposal budget: never past max_tokens (the final
+                # token is sampled, not proposed), never past the block
+                # span the admission check guaranteed, never past
+                # max_seq positions.
+                budget = min(
+                    req.max_tokens - len(req.output) - 1,
+                    len(req.prompt) + req.max_tokens - req.context_len - 1,
+                    self.cfg.max_seq - req.context_len - 1)
+                props[req.rid] = spec.propose(
+                    req.rid, req.prompt + req.output, budget)
+        with perf.phase("llm.slots"):
+            for req in list(batch):
+                if req.state == RUNNING:
+                    self._ensure_slots(req, 1 + len(props[req.rid]))
+            batch = [r for r in batch if r.state == RUNNING]
         if not batch:
             return
-        t0 = time.time()
-        B = self.max_batch
-        Q = spec.k + 1
-        bs = self.kv.block_size
-        tokens = np.zeros((B, Q), np.int32)
-        positions = np.zeros((B, Q), np.int32)
-        slot_blocks = np.zeros((B, Q), np.int32)
-        slot_offsets = np.zeros((B, Q), np.int32)
-        context_lens = np.ones((B,), np.int32)
-        q_lens = np.ones((B,), np.int32)
-        tables = np.zeros((B, self.max_nb), np.int32)
-        for i, req in enumerate(batch):
-            slot = req.context_len
-            p = props[req.rid]
-            n = 1 + len(p)
-            # Row 0 feeds the last sampled token (a FULL prefix-cache
-            # hit re-feeds its held-back last position — the verify
-            # fast start: its FIRST step already carries proposals);
-            # rows 1..n-1 feed the proposals. Rows n..Q-1 are padding:
-            # scratch block 0, positions clipped in range — their
-            # logits are garbage and never read (q_lens masks them in
-            # the kernel and the host loop stops at n).
-            tokens[i, 0] = (req.prompt[slot] if slot < len(req.prompt)
-                            else req.output[slot - len(req.prompt)])
-            tokens[i, 1:n] = p
-            positions[i] = np.minimum(slot + np.arange(Q, dtype=np.int32),
-                                      self.cfg.max_seq - 1)
-            for j in range(n):
-                slot_blocks[i, j] = req.block_table[(slot + j) // bs]
-                slot_offsets[i, j] = (slot + j) % bs
-            context_lens[i] = slot + n
-            q_lens[i] = n
-            tables[i, :len(req.block_table)] = req.block_table
-            spec.verify(req.rid, len(p))
-        spec.verify_steps += 1
-        t_disp = time.perf_counter()
-        logits, self.kv.k, self.kv.v = self._verify(
-            self.params, tokens, positions, self.kv.k, self.kv.v,
-            tables, context_lens, q_lens, slot_blocks, slot_offsets)
-        jax.block_until_ready(logits)
-        device_s = time.perf_counter() - t_disp
-        # Verify pricing is honest about speculation's bet: k+1 rows of
-        # FLOPs are burned regardless of how many tokens are accepted.
-        cost = perfmodel.verify_step_cost(
-            self.cfg, [int(context_lens[i]) for i in range(len(batch))],
-            [int(q_lens[i]) for i in range(len(batch))])
-        self._step_perf.add_device(device_s, cost)
-        rows = np.asarray(jax.device_get(logits), np.float32)
+        with perf.phase("llm.decode.build"):
+            t0 = time.time()
+            B = self.max_batch
+            Q = spec.k + 1
+            bs = self.kv.block_size
+            tokens = np.zeros((B, Q), np.int32)
+            positions = np.zeros((B, Q), np.int32)
+            slot_blocks = np.zeros((B, Q), np.int32)
+            slot_offsets = np.zeros((B, Q), np.int32)
+            context_lens = np.ones((B,), np.int32)
+            q_lens = np.ones((B,), np.int32)
+            tables = np.zeros((B, self.max_nb), np.int32)
+            for i, req in enumerate(batch):
+                slot = req.context_len
+                p = props[req.rid]
+                n = 1 + len(p)
+                # Row 0 feeds the last sampled token (a FULL
+                # prefix-cache hit re-feeds its held-back last position
+                # — the verify fast start: its FIRST step already
+                # carries proposals); rows 1..n-1 feed the proposals.
+                # Rows n..Q-1 are padding: scratch block 0, positions
+                # clipped in range — their logits are garbage and never
+                # read (q_lens masks them in the kernel and the host
+                # loop stops at n).
+                tokens[i, 0] = (req.prompt[slot] if slot < len(req.prompt)
+                                else req.output[slot - len(req.prompt)])
+                tokens[i, 1:n] = p
+                positions[i] = np.minimum(
+                    slot + np.arange(Q, dtype=np.int32),
+                    self.cfg.max_seq - 1)
+                for j in range(n):
+                    slot_blocks[i, j] = req.block_table[(slot + j) // bs]
+                    slot_offsets[i, j] = (slot + j) % bs
+                context_lens[i] = slot + n
+                q_lens[i] = n
+                tables[i, :len(req.block_table)] = req.block_table
+                spec.verify(req.rid, len(p))
+            spec.verify_steps += 1
+            # Verify pricing is honest about speculation's bet: k+1 rows
+            # of FLOPs are burned regardless of how many tokens are
+            # accepted.
+            ctx = [int(context_lens[i]) for i in range(len(batch))]
+            rows_per_lane = [int(q_lens[i]) for i in range(len(batch))]
+            cost = perfmodel.verify_step_cost(self.cfg, ctx, rows_per_lane)
+            self._counts = (len(batch), sum(ctx), sum(rows_per_lane))
+        with perf.device("llm.decode.device") as dev:
+            logits, self.kv.k, self.kv.v = self._verify(
+                self.params, tokens, positions, self.kv.k, self.kv.v,
+                tables, context_lens, q_lens, slot_blocks, slot_offsets)
+            jax.block_until_ready(logits)
+        device_s = dev.seconds
+        perf.add_cost(cost)
+        sampling, emitting = perf.phase("llm.sample"), perf.phase("llm.emit")
+        with sampling:
+            rows = np.asarray(jax.device_get(logits), np.float32)
         emitted_total = 0
-        for i, req in enumerate(batch):
+        for i, req in enumerate(batch):     # lane by lane, as in decode
             p = props[req.rid]
-            n = 1 + len(p)
             slot = req.context_len
-            start_pos = len(req.prompt) + len(req.output)
-            n_acc, emitted = verify_tokens(
-                rows[i, :n], p, temperature=req.temperature,
-                top_k=req.top_k, seed=req.seed, start_pos=start_pos)
-            spec.accept(req.rid, n_acc, len(p), len(emitted))
-            emitted_total += len(emitted)
-            for idx, tok in enumerate(emitted):
-                # Bookkeeping BEFORE emitting: an accepted token IS
-                # resident (its slot was written this step), the final
-                # corrected/bonus token is NOT (its draw replaced a
-                # rejected row / was never written) — so a mid-stream
-                # finish registers exactly the resident span.
-                if idx < n_acc:
-                    req.context_len = slot + 2 + idx
-                else:
-                    req.context_len = slot + 1 + n_acc
-                if self._emit_token(req, tok):
-                    break
-            n_rej = len(p) - n_acc
-            if n_rej:
-                # Rejected slots past the accept cursor: any whole
-                # blocks they spilled into go back to the pool (a
-                # finished lane already released everything).
-                freed = (self.kv.truncate(req.block_table,
-                                          req.context_len)
-                         if req.block_table else [])
-                spec.rollback(req.rid, n_rej, len(freed))
+            with sampling:
+                n_acc, emitted = verify_tokens(
+                    rows[i, :1 + len(p)], p, temperature=req.temperature,
+                    top_k=req.top_k, seed=req.seed,
+                    start_pos=len(req.prompt) + len(req.output))
+            with emitting:
+                spec.accept(req.rid, n_acc, len(p), len(emitted))
+                emitted_total += len(emitted)
+                for idx, tok in enumerate(emitted):
+                    # Bookkeeping BEFORE emitting: an accepted token IS
+                    # resident (its slot was written this step), the
+                    # final corrected/bonus token is NOT (its draw
+                    # replaced a rejected row / was never written) — so
+                    # a mid-stream finish registers exactly the resident
+                    # span.
+                    if idx < n_acc:
+                        req.context_len = slot + 2 + idx
+                    else:
+                        req.context_len = slot + 1 + n_acc
+                    if self._emit_token(req, tok):
+                        break
+                n_rej = len(p) - n_acc
+                if n_rej:
+                    # Rejected slots past the accept cursor: any whole
+                    # blocks they spilled into go back to the pool (a
+                    # finished lane already released everything).
+                    freed = (self.kv.truncate(req.block_table,
+                                              req.context_len)
+                             if req.block_table else [])
+                    spec.rollback(req.rid, n_rej, len(freed))
         dur = time.time() - t0
-        kv_util = self.kv.utilization()
-        traced = [r for r in batch if r.trace_ctx is not None]
-        if traced:
-            rl = self._roofline_attrs(cost, device_s, dur)
-            breakdown = {
-                "step": self._steps + 1,
-                "prefill": self._last_prefill_count,
-                "decode": len(batch), "kv_util": kv_util,
-                "spec_proposed": int(sum(len(props[r.rid])
-                                         for r in batch)),
-                "spec_emitted": emitted_total,
-                "device_ms": round(device_s * 1e3, 3),
-                "host_ms": round(max(dur - device_s, 0.0) * 1e3, 3),
-                **rl,
-            }
-            for req in traced:
-                tracing.emit("llm.decode_step", req.trace_ctx, t0, dur,
-                             dict(breakdown, rid=req.rid))
+        with perf.phase("llm.trace"):
+            self._trace_decode_step(
+                batch, t0, dur, cost, device_s,
+                spec_proposed=int(sum(len(props[r.rid]) for r in batch)),
+                spec_emitted=emitted_total)
 
     def step(self) -> int:
         """One scheduler iteration: admit -> prefill -> decode one token
@@ -807,30 +860,50 @@ class LLMEngine:
         a verify step that may emit several). Returns the number of
         in-flight sequences after the step."""
         with self._lock:
-            self._step_perf.begin()
-            self._admit()
-            # High-water utilization INSIDE the step: post-admission and
-            # post-decode, before finishes drain it — the end-of-run
-            # stats() reading alone always relaxes back to ~0 (every
-            # block freed), which is why SERVE_BENCH read 0.0 for years.
-            util_hw = self.kv.utilization()
-            self._run_prefills()
-            if self._spec is not None:
-                self._run_verify()
-            else:
-                self._run_decode()
-            self._kv_util_peak = max(self._kv_util_peak, util_hw,
-                                     self.kv.utilization())
-            self._steps += 1
+            perf = self._step_perf
+            perf.begin()
+            self._chunk_log = []
+            self._counts = (0, 0, 0)
+            preempted0 = self._preempt_count
+            with perf.step("llm.step", self._steps + 1):
+                with perf.phase("llm.admit"):
+                    self._admit()
+                # High-water utilization INSIDE the step: post-admission
+                # and post-decode, before finishes drain it — the
+                # end-of-run stats() reading alone always relaxes back
+                # to ~0 (every block freed), which is why SERVE_BENCH
+                # read 0.0 for years.
+                util_hw = self.kv.utilization()
+                self._run_prefills()
+                if self._spec is not None:
+                    self._run_verify()
+                else:
+                    self._run_decode()
+                self._kv_util_peak = max(self._kv_util_peak, util_hw,
+                                         self.kv.utilization())
+                self._steps += 1
+                with perf.phase("llm.publish"):
+                    self.step_log.append(
+                        (self._steps, tuple(r.rid for r in self._active)))
+                    # The step-derived gauges carry the last CLOSED
+                    # step: publishing is part of the step it ends.
+                    self._publish_gauges()
             # Finalize the step breakdown (None on a no-work step) into
-            # the process-local device-step ring, where the gang
-            # profiler (`rtpu profile --device`) collects it.
-            self._step_perf.finish(
+            # the process-local device-step ring, where the benchmark
+            # and the gang profiler (`rtpu profile --device`) read it.
+            # Counts are the scheduler's own, taken where it has them.
+            lanes, context_tokens, decode_tokens = self._counts
+            chunks = self._chunk_log
+            perf.finish(
                 record_as="llm.step",
-                attrs={"deployment": self.name, "step": self._steps})
-            self.step_log.append(
-                (self._steps, tuple(r.rid for r in self._active)))
-            self._publish_gauges()
+                attrs={"deployment": self.name, "step": self._steps,
+                       "lanes": lanes, "max_batch": self.max_batch,
+                       "context_tokens": context_tokens,
+                       "decode_tokens": decode_tokens,
+                       "prefill_tokens": sum(c[0] for c in chunks),
+                       "prefill_chunks": chunks,
+                       "waiting": len(self._waiting),
+                       "preempted": self._preempt_count - preempted0})
             return len(self._active)
 
     # -- introspection / telemetry ----------------------------------------
@@ -1025,6 +1098,7 @@ class LLMEngine:
             with self._cond:
                 while not self._stop and not self._waiting \
                         and not self._active:
+                    self._step_perf.mark_idle()
                     self._cond.wait(timeout=0.5)
                     # Idle tick: keep publishing so the telemetry series
                     # (tokens/s, batch size, step breakdown) fall to

@@ -38,8 +38,10 @@ import jax.numpy as jnp
 from ..models.gpt import GPTConfig
 
 
+# A device trace's ``XLA Modules`` line names each program after its
+# function: ``jit_kv_scatter_blocks``, ``jit_kv_copy_block``.
 @functools.partial(jax.jit, donate_argnums=(0, 1))
-def _scatter_blocks(k_pool, v_pool, k_blocks, v_blocks, ids):
+def kv_scatter_blocks(k_pool, v_pool, k_blocks, v_blocks, ids):
     """Write whole blocks: pools [L, Hkv, NB, BS, d], blocks
     [L, Hkv, nb, BS, d], ids [nb] int32."""
     return (k_pool.at[:, :, ids].set(k_blocks),
@@ -47,7 +49,7 @@ def _scatter_blocks(k_pool, v_pool, k_blocks, v_blocks, ids):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
-def _copy_block(k_pool, v_pool, src, dst):
+def kv_copy_block(k_pool, v_pool, src, dst):
     """Copy-on-write split: duplicate one block's K/V (src/dst are
     traced scalars, so every split shares one compile)."""
     return (k_pool.at[:, :, dst].set(k_pool[:, :, src]),
@@ -154,7 +156,7 @@ class PagedKVCache:
         vb = v.reshape(L, nb, self.block_size, hkv, d).transpose(
             0, 3, 1, 2, 4).astype(self.dtype)
         ids = jnp.asarray(block_ids, jnp.int32)
-        self.k, self.v = _scatter_blocks(self.k, self.v, kb, vb, ids)
+        self.k, self.v = kv_scatter_blocks(self.k, self.v, kb, vb, ids)
 
     def gather_tokens(self, block_ids: List[int], length: int):
         """Read back ``length`` tokens' K/V as ``[L, length, Hkv, d]``
@@ -423,7 +425,7 @@ class PrefixPool(PagedKVCache):
         if grant is None:
             return None
         dst = grant[0]
-        self.k, self.v = _copy_block(
+        self.k, self.v = kv_copy_block(
             self.k, self.v, jnp.asarray(bid, jnp.int32),
             jnp.asarray(dst, jnp.int32))
         self.cow_splits += 1

@@ -608,7 +608,9 @@ def make_train_step(cfg: GPTConfig, optimizer, mesh: Optional[Mesh] = None,
     (/root/reference/python/ray/train/torch/config.py:106).
     """
 
-    def step(state, tokens):
+    # The function's name is the program's on a device trace's ``XLA
+    # Modules`` line: ``jit_train_step``.
+    def train_step(state, tokens):
         loss, grads = jax.value_and_grad(loss_fn)(
             state["params"], tokens, cfg, mesh, rules
         )
@@ -623,7 +625,7 @@ def make_train_step(cfg: GPTConfig, optimizer, mesh: Optional[Mesh] = None,
         return new_state, {"loss": loss}
 
     donate_argnums = (0,) if donate else ()
-    return jax.jit(step, donate_argnums=donate_argnums)
+    return jax.jit(train_step, donate_argnums=donate_argnums)
 
 
 def params_pspecs(cfg: GPTConfig, rules=None) -> dict:

@@ -337,7 +337,7 @@ def _fwd(q, k, v, *, causal: bool, blk_q: int, blk_k: int, interpret: bool,
             functools.partial(_fwd_kernel, **opts),
             grid=(b, h, nq), in_specs=in_specs, out_specs=o_spec,
             out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-            interpret=interpret,
+            interpret=interpret, name="flash_fwd",
         )(qp, kp, vp)
         return out_layout(out), None
     out, lse = pl.pallas_call(
@@ -353,7 +353,7 @@ def _fwd(q, k, v, *, causal: bool, blk_q: int, blk_k: int, interpret: bool,
             jax.ShapeDtypeStruct(qp.shape, q.dtype),
             jax.ShapeDtypeStruct((b, h, sq_p, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_fwd",
     )(qp, kp, vp)
     # checkpoint_name lets a names-aware remat policy SAVE the kernel's
     # outputs: with them (and q/k/v via dots_saveable) every backward
@@ -401,7 +401,7 @@ def _bwd(res, g, *, causal: bool, blk_q: int, blk_k: int, interpret: bool,
                        jax.ShapeDtypeStruct(kp.shape, kp.dtype),
                        jax.ShapeDtypeStruct(kp.shape, kp.dtype)],
             scratch_shapes=[pltpu.VMEM((sq_p, d), jnp.float32)],
-            interpret=interpret,
+            interpret=interpret, name="flash_bwd_fused",
         )(qp, kp, vp, gp, lse, delta)
 
         def unpad(x, s):
@@ -422,7 +422,7 @@ def _bwd(res, g, *, causal: bool, blk_q: int, blk_k: int, interpret: bool,
         in_specs=[q_spec, kfull, kfull, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(qp.shape, qp.dtype),
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dq",
     )(qp, kp, vp, gp, lse, delta)
 
     k_spec = pl.BlockSpec((1, 1, blk_k, d), lambda bi, hi, ki: (bi, hi, ki, 0))
@@ -438,7 +438,7 @@ def _bwd(res, g, *, causal: bool, blk_q: int, blk_k: int, interpret: bool,
         out_specs=[k_spec, k_spec],
         out_shape=[jax.ShapeDtypeStruct(kp.shape, kp.dtype),
                    jax.ShapeDtypeStruct(kp.shape, kp.dtype)],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dkv",
     )(qp, kp, vp, gp, lse, delta)
 
     def unpad(x, s):

@@ -149,7 +149,7 @@ def _make_decode_call(b: int, hkv: int, group: int, d: int,
                           group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q_dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_decode",
     )
 
 

@@ -681,7 +681,9 @@ def cmd_profile(args):
 
     import json
 
-    from ray_tpu._private.profiler import build_merged_trace
+    from ray_tpu._private.profiler import (build_merged_trace,
+                                           format_device_steps,
+                                           format_idle_gaps)
     from ray_tpu.util import state
 
     t0 = time.time()
@@ -712,6 +714,19 @@ def cmd_profile(args):
           f"{len(captured)} process(es), {n_steps} accounted device "
           f"step(s), {len(spans)} request span(s)")
     print("open in chrome://tracing or https://ui.perfetto.dev")
+    # Where the steps went and why the chip waited: each process that
+    # ran steps splits them by span (util/perfmodel.PHASES), and each
+    # that traced a device names the host span over its idle gaps.
+    for source in captured:
+        steps = profs[source].get("device_steps")
+        gaps = profs[source].get("idle_gaps")
+        if not steps and not (gaps and gaps["idle_s"] > 0):
+            continue
+        print(f"{source}:")
+        if steps:
+            print(format_device_steps(steps))
+        if gaps and gaps["idle_s"] > 0:
+            print(format_idle_gaps(gaps))
 
 
 def cmd_heap(args):

@@ -129,6 +129,11 @@ class _LLMServer:
                     first = False
                     slo.record_phase("ttft", time.time() - req.submit_t,
                                      dep, trace_id=trace_id)
+                    # The part of it spent waiting for a lane:
+                    # add_request -> first admission into the batch.
+                    slo.record_phase("engine_queue",
+                                     req.admit_t - req.submit_t, dep,
+                                     trace_id=trace_id)
                 yield {"token": tok}
             if req.first_token_t and req.finish_t \
                     and len(req.output) > 1:
@@ -148,7 +153,11 @@ class _LLMServer:
     def engine_stats(self) -> dict:
         """Engine introspection over the handle
         (``h.options(method_name="engine_stats")``)."""
-        return self.engine.stats()
+        out = self.engine.stats()
+        # Cumulative sum/count a phase (ttft, tpot, engine_queue,
+        # stream_hold, ...): two readings give a window's mean.
+        out["phase_hist"] = slo.phase_hist(self.engine.name)
+        return out
 
     def check_health(self) -> bool:
         return True

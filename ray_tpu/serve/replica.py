@@ -156,17 +156,27 @@ class Replica:
         gen = self._streams.get(sid)
         if gen is None:
             return [], True
-        out = []
+        t_pull = time.perf_counter()
+        out, yielded = [], []
+        done = False
         try:
             for _ in range(max_chunks):
                 out.append(next(gen))
+                yielded.append(time.perf_counter())
         except StopIteration:
             self._streams.pop(sid, None)
-            return out, True
+            done = True
         except BaseException:
             self._streams.pop(sid, None)
             raise
-        return out, False
+        # How long each chunk waited here for the pull to fill: a pull
+        # blocks until it holds max_chunks, so the first chunk of a
+        # 16-chunk pull of a token stream sits through 15 decode steps.
+        t_ret = time.perf_counter()
+        slo.record_phase("stream_pull", t_ret - t_pull, self._deployment)
+        slo.record_phases("stream_hold", [t_ret - t for t in yielded],
+                          self._deployment)
+        return out, done
 
     def stream_cancel(self, sid: int):
         gen = self._streams.pop(sid, None)

@@ -17,6 +17,16 @@ continuous-batching autoscaler consumes (ROADMAP item 1):
                           isolates pure compute)
   * ``ttft`` / ``tpot`` — generation deployments only (serve/llm.py):
                           time-to-first-token and time-per-output-token
+  * ``engine_queue``    — generation deployments only (serve/llm.py,
+                          beside ``ttft``, from the request's own
+                          stamps): add_request -> first admission into
+                          the engine's batch
+  * ``stream_pull`` / ``stream_hold`` — streaming responses
+                          (Replica.stream_next): one pull's duration
+                          (its count is the number of pulls), and, once
+                          a chunk, how long the chunk sat in the replica
+                          between the generator yielding it and the pull
+                          that carries it returning
 
 Two sinks per observation, both cheap (a bucket increment under one
 lock):
@@ -50,7 +60,7 @@ PHASE_BOUNDS: List[float] = [
 # token from request arrival at the engine, and per-output-token latency
 # (decode cadence) — the two numbers an LLM serving SLO is written in.
 PHASES = ("proxy_queue", "replica_queue", "batch_wait", "execute",
-          "ttft", "tpot")
+          "ttft", "tpot", "engine_queue", "stream_pull", "stream_hold")
 
 _lock = threading.Lock()
 # Deployment hosted by THIS process (set by Replica.__init__).
@@ -101,6 +111,18 @@ def current_deployment() -> str:
     return _deployment
 
 
+def _observe_locked(key: tuple, seconds) -> None:
+    """Add observations to one (deployment, phase) cell; ``_lock`` is
+    held by the caller."""
+    cell = _local.get(key)
+    if cell is None:
+        cell = _local[key] = [[0] * (len(PHASE_BOUNDS) + 1), 0.0, 0]
+    for x in seconds:
+        cell[0][bisect_left(PHASE_BOUNDS, x)] += 1
+        cell[1] += x
+    cell[2] += len(seconds)
+
+
 def record_phase(phase: str, seconds: float,
                  deployment: Optional[str] = None,
                  trace_id: Optional[str] = None):
@@ -108,12 +130,7 @@ def record_phase(phase: str, seconds: float,
     seconds = max(0.0, float(seconds))
     key = (dep, phase)
     with _lock:
-        cell = _local.get(key)
-        if cell is None:
-            cell = _local[key] = [[0] * (len(PHASE_BOUNDS) + 1), 0.0, 0]
-        cell[0][bisect_left(PHASE_BOUNDS, seconds)] += 1
-        cell[1] += seconds
-        cell[2] += 1
+        _observe_locked(key, (seconds,))
         if trace_id:
             import time as _time
 
@@ -125,6 +142,26 @@ def record_phase(phase: str, seconds: float,
     try:
         hist, _, _ = _metrics()
         hist.observe(seconds, tags={"deployment": dep, "phase": phase})
+    except Exception:  # noqa: BLE001 - SLO recording is best-effort
+        pass
+
+
+def record_phases(phase: str, seconds: List[float],
+                  deployment: Optional[str] = None):
+    """``record_phase`` for many untraced observations of one phase at
+    once (a stream pull's chunks): one pass under each lock, so a burst
+    does not hand the interpreter back and forth with the threads it
+    shares a process with."""
+    if not seconds:
+        return
+    dep = deployment if deployment else (_deployment or "?")
+    seconds = [max(0.0, float(x)) for x in seconds]
+    with _lock:
+        _observe_locked((dep, phase), seconds)
+    try:
+        hist, _, _ = _metrics()
+        tags = hist.normalized_tags({"deployment": dep, "phase": phase})
+        hist.observe_normalized([(tags, x) for x in seconds])
     except Exception:  # noqa: BLE001 - SLO recording is best-effort
         pass
 

@@ -10,11 +10,14 @@ polling.
 Device-step performance plane: ``wrap_step`` instruments a jitted train
 step (dispatch-to-``block_until_ready`` timed apart from the host work
 around it, FLOPs/bytes priced by util/perfmodel.py) and ``report``
-folds the accumulated spans into a host-vs-device breakdown — reported
-metrics gain ``train_step_ms``/``train_device_ms``/``train_host_gap_ms``/
-``train_mfu``/``train_hbm_util``, the same values ride the worker
-metrics flusher into head telemetry series (``train_mfu:<trial>``, ...),
-and every step lands in the perfmodel device-step ring where
+closes the step, report to report, on the session's
+``perfmodel.StepAccounting`` — reported metrics gain
+``train_step_ms``/``train_device_ms``/``train_host_gap_ms``/
+``train_mfu``/``train_hbm_util`` and the split of the device span
+(``train_dispatch_ms`` + ``train_ready_wait_ms``) and of the host's part
+(``train_data_wait_ms``), the same values ride the worker metrics
+flusher into head telemetry series (``train_mfu:<trial>``, ...), and
+every step lands in the perfmodel device-step ring where
 ``rtpu profile --device`` collects it.
 """
 
@@ -22,7 +25,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -74,59 +76,57 @@ class _TrainSession:
         self.ctx = ctx
         self.reports: queue.Queue = queue.Queue()
         self.stop_event = threading.Event()
-        # Device spans recorded by wrap_step() since the last report:
-        # [accumulated device seconds, flops, hbm bytes, tokens].
-        self._step_perf = [0.0, 0.0, 0.0, 0]
-        self._last_report_t: Optional[float] = None
-        self._perf_gauges = None
-
-    def record_device(self, seconds: float, cost=None):
-        """wrap_step's sink: one timed dispatch->block_until_ready span
-        (plus its priced StepCost) folded into the next report()."""
-        sp = self._step_perf
-        sp[0] += float(seconds)
-        if cost is not None:
-            sp[1] += cost.flops
-            sp[2] += cost.hbm_bytes
-            sp[3] += cost.tokens
-
-    def _drain_step_perf(self) -> Optional[dict]:
-        """Fold the device spans since the last report into a host-vs-
-        device breakdown (None when nothing was recorded — loops that
-        don't use wrap_step report exactly as before)."""
-        now = time.perf_counter()
-        wall, self._last_report_t = (
-            (now - self._last_report_t) if self._last_report_t is not None
-            else None, now)
-        sp = self._step_perf
-        device_s, flops, hbm_bytes, tokens = sp
-        self._step_perf = [0.0, 0.0, 0.0, 0]
-        if device_s <= 0.0 or wall is None:
-            return None
+        # One step runs from report() to report(): wrap_step()'s device
+        # spans, the batch iterator's waits and report() itself land on
+        # this accounting (perfmodel.PHASES) and are closed into a ring
+        # entry and the report's train_* keys by the next report().
         from ..util import perfmodel
 
-        wall = max(wall, device_s)
-        # {} on the CPU backend (no peak): counts and times only there.
-        rl = perfmodel.roofline(
-            perfmodel.StepCost(flops, hbm_bytes, tokens),
-            device_s, wall - device_s, hw=perfmodel.detect_hardware())
-        rl.pop("hardware", None)
+        self._step_perf = perfmodel.StepAccounting()
+        self._step_open = False
+        self._steps = 0         # wrapped steps, for the step annotation
+        self._perf_gauges = None
+
+    def record_device(self, cost=None):
+        """wrap_step's sink: one wrapped step under its annotation,
+        whose ``train.dispatch`` / ``train.wait`` spans the caller holds
+        on the returned accounting; the priced StepCost is folded into
+        the next report()."""
+        acc = self._step_perf
+        if cost is not None:
+            acc.add_cost(cost)
+        self._steps += 1
+        return acc
+
+    def _drain_step_perf(self) -> Optional[dict]:
+        """Close the step that ran since the last report into a host-
+        vs-device breakdown and open the next (None when nothing was
+        recorded — loops that don't use wrap_step report exactly as
+        before — and on the first report, which has no step behind
+        it)."""
+        acc = self._step_perf
+        step = (acc.finish(record_as="train.step",
+                           attrs={"trial": self.ctx.trial_name})
+                if self._step_open else None)
+        acc.begin()
+        self._step_open = True
+        if step is None:
+            return None
         out = {
-            "train_step_ms": wall * 1e3,
-            "train_device_ms": device_s * 1e3,
-            "train_host_gap_ms": (wall - device_s) * 1e3,
+            "train_step_ms": step["step_ms"],
+            "train_device_ms": step["device_ms"],
+            "train_host_gap_ms": step["host_gap_ms"],
+            # The device span's two halves: the jitted call until it
+            # returns, and the wait for its outputs.
+            "train_dispatch_ms": step["device_ms_by"].get("dispatch", 0.0),
+            "train_ready_wait_ms": step["device_ms_by"].get("wait", 0.0),
+            "train_data_wait_ms": step["phases_ms"].get(
+                "data.next_batch", 0.0),
         }
-        if rl:
-            out.update(train_mfu=rl["mfu"], train_hbm_util=rl["hbm_util"],
-                       train_roofline=rl["verdict"])
-        perfmodel.record_device_step(
-            "train.step", time.time() - wall,
-            {"step_ms": out["train_step_ms"],
-             "device_ms": out["train_device_ms"],
-             "host_gap_ms": out["train_host_gap_ms"],
-             "tokens": tokens, "flops": flops, "hbm_bytes": hbm_bytes,
-             **rl},
-            {"trial": self.ctx.trial_name})
+        if "mfu" in step:       # only with a peak: never on the CPU
+            out.update(train_mfu=step["mfu"],
+                       train_hbm_util=step["hbm_util"],
+                       train_roofline=step["verdict"])
         self._publish_perf_gauges(out)
         return out
 
@@ -170,21 +170,30 @@ class _TrainSession:
     def report(self, metrics: dict, checkpoint: Optional[Checkpoint] = None):
         metrics = dict(metrics)
         perf = self._drain_step_perf()  # _step_perf -> breakdown
-        if perf is not None:
-            for k, v in perf.items():
-                metrics.setdefault(k, v)
-        self.reports.put(("report", metrics, checkpoint))
+        with self._step_perf.phase("train.report"):
+            if perf is not None:
+                for k, v in perf.items():
+                    metrics.setdefault(k, v)
+            self.reports.put(("report", metrics, checkpoint))
         if self.stop_event.is_set():
             raise StopIteration("training stopped by the controller")
 
 
 def _bind(session: "_TrainSession"):
+    from ..util import perfmodel
+
     _tls.session = session
+    # Code under the loop that does not know the session (Data's batch
+    # iterator) finds the step's accounting through perfmodel.
+    perfmodel.bind_accounting(session._step_perf)
     return session
 
 
 def _unbind():
+    from ..util import perfmodel
+
     _tls.session = None
+    perfmodel.bind_accounting(None)
 
 
 def _get() -> Optional[_TrainSession]:
@@ -238,32 +247,32 @@ def wrap_step(step_fn, cfg=None):
     nowhere — safe for bench/offline use."""
 
     def timed_step(*args, **kwargs):
-        import contextlib
-
         import jax
 
-        from ..util import perfmodel
-
         s = _get()
-        if s is not None:
-            from ..parallel import flightrec
-
-            rec = flightrec.record_op(
-                f"step/{s.ctx.experiment_name or 'train'}", "train_step")
-        else:
-            rec = contextlib.nullcontext()
-        with rec:
-            t0 = time.perf_counter()
+        if s is None:
+            # No session, nowhere to record: the same blocking call.
             out = step_fn(*args, **kwargs)
             jax.block_until_ready(out)
-            device_s = time.perf_counter() - t0
+            return out
+        from ..parallel import flightrec
+        from ..util import perfmodel
+
+        rec = flightrec.record_op(
+            f"step/{s.ctx.experiment_name or 'train'}", "train_step")
         cost = None
         if cfg is not None:
             shape = _token_batch_shape(args)
             if shape is not None:
                 cost = perfmodel.train_step_cost(cfg, shape[0], shape[1])
-        if s is not None:
-            s.record_device(device_s, cost)
+        acc = s.record_device(cost)
+        with rec, acc.step("train.step", s._steps):
+            # Two halves of one device span: a slow step shows which
+            # grew, the host's dispatch or the wait for the device.
+            with acc.device("train.dispatch"):
+                out = step_fn(*args, **kwargs)
+            with acc.device("train.wait"):
+                jax.block_until_ready(out)
         return out
 
     return timed_step

@@ -49,6 +49,8 @@ never an MFU, an HBM utilization or a roofline verdict.
 from __future__ import annotations
 
 import collections
+import contextlib
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -283,56 +285,246 @@ def roofline(cost: StepCost, device_s: float, host_gap_s: float = 0.0,
 # Per-step accounting (the engine/train instrumentation hook)
 # ---------------------------------------------------------------------------
 
+#: The registry of span names on the two step paths: name -> (kind,
+#: what it covers). ``kind`` None is a HOST phase (``phase()``: its
+#: milliseconds land in ``phases_ms``); any other kind is a DEVICE span
+#: (``device()``: dispatch to ready, its milliseconds land in
+#: ``device_ms`` and ``device_ms_by[kind]``). Host phases of one step
+#: never nest in one another or in a device span, so they partition the
+#: step's host time. While a ``jax.profiler`` session is open every
+#: name is also a ``TraceAnnotation`` for the same interval, so the
+#: span lies on the trace's host plane beside the device ops. A name
+#: that is not here is an error.
+PHASES: Dict[str, tuple] = {
+    "llm.admit": (None, "admission of waiting requests: prefix lookup, "
+                        "block grants"),
+    "llm.prefill.host": (None, "a prefill chunk's host side: input "
+                               "arrays, prefix register, first-token "
+                               "sample and emit, its request span"),
+    "llm.prefill.device": ("prefill", "one prefill chunk, dispatch to "
+                                      "logits ready (the pool write is "
+                                      "dispatched inside it)"),
+    "llm.slots": (None, "writable KV slots for every decode lane: block "
+                        "grants, copy-on-write, preemption"),
+    "llm.decode.build": (None, "the decode (or verify) program's input "
+                               "arrays for max_batch lanes; proposals "
+                               "under speculation"),
+    "llm.decode.device": ("decode", "the decode or verify program, "
+                                    "dispatch to logits ready"),
+    "llm.sample": (None, "device_get of the logits and the sampler (or "
+                         "verify_tokens) for every lane"),
+    "llm.emit": (None, "tokens onto the request queues, finishes, block "
+                       "release, speculative rollback"),
+    "llm.trace": (None, "the per-request llm.decode_step span copies, "
+                        "one a traced lane (util/tracing ring)"),
+    "llm.publish": (None, "step_log and the rtpu_llm_* gauge writes"),
+    "train.dispatch": ("dispatch", "the jitted train step's call, until "
+                                   "it returns its futures"),
+    "train.wait": ("wait", "block_until_ready on the step's outputs"),
+    "train.report": (None, "train.report(): metrics onto the session's "
+                           "queue, gauge writes"),
+    "data.next_batch": (None, "one next() of the dataset shard's batch "
+                              "iterator inside a training loop"),
+}
+#: Annotated whole steps (``StepAccounting.step``): ring entry names.
+STEPS = ("llm.step", "train.step")
+_DEVICE_KINDS = frozenset(k for k, _ in PHASES.values() if k) | {"device"}
+_UNDETECTED = object()      # StepAccounting's peaks, before first use
+
+
+def _session_open() -> bool:
+    """Is a ``jax.profiler`` session open in this process? False where
+    jax is not imported: a CPU-lane worker must not pay the import for
+    a span nobody reads."""
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
+
+
+class _Span:
+    """One registry name's reusable context manager: host clock and,
+    in a step that began with a profiler session open, a
+    TraceAnnotation over the same interval (the annotation starts when
+    it is built, so each interval builds its own). Not re-entrant; one
+    thread drives a StepAccounting (the engine under its lock, a
+    training loop's thread)."""
+
+    __slots__ = ("_acc", "name", "_kind", "_t0", "_ann", "seconds")
+
+    def __init__(self, acc: "StepAccounting", name: str):
+        self._acc = acc
+        self.name = name
+        self._kind = PHASES[name][0]
+        self._t0 = 0.0
+        self._ann = None
+        self.seconds = 0.0
+
+    def __enter__(self):
+        if self._acc._traced:
+            self._ann = sys.modules["jax"].profiler.TraceAnnotation(
+                self.name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.seconds = s = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        acc = self._acc
+        if self._kind is None:
+            acc._phase_s[self.name] = acc._phase_s.get(self.name, 0.0) + s
+        else:
+            acc.add_device(s, kind=self._kind)
+        return False
+
 
 class StepAccounting:
-    """Accumulates one scheduler step's device spans + priced costs and
-    folds them into a breakdown dict on finish(). Cheap enough for the
-    per-decode-step hot path (see the perf gate): a begin/add/finish
-    cycle is plain float arithmetic, no locks, no allocation beyond the
-    result dict."""
+    """Accumulates one scheduler step's device spans, named host phases
+    and priced costs, and folds them into a breakdown dict on finish().
+    Cheap enough for the per-decode-step hot path (see the perf gate):
+    a begin/add/finish cycle is plain float arithmetic, no locks, no
+    allocation beyond the result dict and one float a name.
 
-    __slots__ = ("hw", "n_chips", "_wall0", "_device_s", "_flops",
-                 "_hbm_bytes", "_tokens", "last")
+    The breakdown (one entry of the device-step ring):
+      step_ms / device_ms / host_gap_ms   begin() to finish(), the sum
+                    of the device spans, and the rest
+      device_ms_by  {kind: ms}; sums to device_ms
+      phases_ms     {host phase: ms}; with other_ms, what no phase
+                    names, they sum to host_gap_ms
+      between_ms    the previous finish() to this begin() (absent on
+                    the first step); idle_wait says the owner slept on
+                    an empty queue in between (mark_idle)
+      tokens / flops / hbm_bytes and, with a peak, mfu / hbm_util /
+      verdict / hardware
+    """
+
+    __slots__ = ("_hw", "n_chips", "_wall0", "_traced", "_device_s",
+                 "_device_by", "_phase_s", "_spans", "_flops",
+                 "_hbm_bytes", "_tokens", "_finish_t", "_between_s",
+                 "_idle", "_idle_wait", "last")
 
     def __init__(self, hw: Optional[HardwarePeak] = None,
                  n_chips: int = 1):
-        # None on the CPU backend: the breakdown then carries counts and
-        # times only.
-        self.hw = hw or detect_hardware()
+        # Resolved on first use: detect_hardware() brings the backend
+        # up, which a session that never dispatches must not do.
+        self._hw = hw if hw is not None else _UNDETECTED
         self.n_chips = max(int(n_chips), 1)
         self._wall0 = 0.0
+        self._traced = False    # a profiler session was open at begin()
         self._device_s = 0.0
+        self._device_by: Dict[str, float] = {}
+        self._phase_s: Dict[str, float] = {}
+        self._spans: Dict[str, _Span] = {}
         self._flops = 0.0
         self._hbm_bytes = 0.0
         self._tokens = 0
+        self._finish_t: Optional[float] = None
+        self._between_s: Optional[float] = None
+        self._idle = self._idle_wait = False
         self.last: Optional[dict] = None
 
+    @property
+    def hw(self) -> Optional[HardwarePeak]:
+        """The chip's peaks; None on the CPU backend: the breakdown
+        then carries counts and times only."""
+        if self._hw is _UNDETECTED:
+            self._hw = detect_hardware()
+        return self._hw
+
+    def mark_idle(self):
+        """The owner is about to sleep on an empty queue: the next
+        step's between_ms is a wait for work, not a hand-over."""
+        self._idle = True
+
     def begin(self):
-        self._wall0 = time.perf_counter()
+        self._traced = _session_open()
+        self._wall0 = now = time.perf_counter()
+        self._between_s = (None if self._finish_t is None
+                           else now - self._finish_t)
+        self._idle_wait, self._idle = self._idle, False
         self._device_s = 0.0
+        self._device_by.clear()
+        self._phase_s.clear()
         self._flops = 0.0
         self._hbm_bytes = 0.0
         self._tokens = 0
 
-    def add_device(self, seconds: float, cost: StepCost = ZERO_COST):
-        self._device_s += seconds
+    def _span(self, name: str) -> _Span:
+        span = self._spans.get(name)
+        if span is None:
+            if name not in PHASES:
+                raise KeyError(
+                    f"{name!r} is not in perfmodel.PHASES, the registry "
+                    f"of span names")
+            span = self._spans[name] = _Span(self, name)
+        return span
+
+    def phase(self, name: str) -> _Span:
+        """Context manager over a HOST phase of the step."""
+        span = self._span(name)
+        if span._kind is not None:
+            raise KeyError(f"{name!r} is a device span: use device()")
+        return span
+
+    def device(self, name: str) -> _Span:
+        """Context manager over a DEVICE span, dispatch to ready; its
+        ``seconds`` can be read after it closes. Price the work with
+        add_cost()."""
+        span = self._span(name)
+        if span._kind is None:
+            raise KeyError(f"{name!r} is a host phase: use phase()")
+        return span
+
+    def step(self, name: str, step_num: int):
+        """``jax.profiler.StepTraceAnnotation`` around the whole step
+        while a profiler session is open, else a null context."""
+        if name not in STEPS:
+            raise KeyError(f"{name!r} is not in perfmodel.STEPS")
+        # Looked up again here: a training step begins at the report
+        # before it, which may lie before the session's start.
+        self._traced = _session_open()
+        if not self._traced:
+            return contextlib.nullcontext()
+        return sys.modules["jax"].profiler.StepTraceAnnotation(
+            name, step_num=int(step_num))
+
+    def add_cost(self, cost: StepCost):
         self._flops += cost.flops
         self._hbm_bytes += cost.hbm_bytes
         self._tokens += cost.tokens
+
+    def add_device(self, seconds: float, cost: StepCost = ZERO_COST,
+                   kind: str = "device"):
+        if kind not in _DEVICE_KINDS:
+            raise KeyError(f"{kind!r} is not a device kind of "
+                           f"perfmodel.PHASES")
+        self._device_s += seconds
+        self._device_by[kind] = self._device_by.get(kind, 0.0) + seconds
+        if cost is not ZERO_COST:
+            self.add_cost(cost)
 
     def finish(self, *, record_as: Optional[str] = None,
                attrs: Optional[dict] = None) -> Optional[dict]:
         """Close the step. Returns None (and records nothing) if no
         device work ran — an idle scheduler tick is not a step."""
+        now = time.perf_counter()
+        self._finish_t = now
         if self._device_s <= 0.0 and self._flops <= 0.0:
             self.last = None
             return None
-        wall_s = max(time.perf_counter() - self._wall0, self._device_s)
+        wall_s = max(now - self._wall0, self._device_s)
         host_gap_s = wall_s - self._device_s
+        host_gap_ms = host_gap_s * 1e3
+        phases_ms = {k: v * 1e3 for k, v in self._phase_s.items()}
         out = {
             "step_ms": wall_s * 1e3,
             "device_ms": self._device_s * 1e3,
-            "host_gap_ms": host_gap_s * 1e3,
+            "host_gap_ms": host_gap_ms,
+            "device_ms_by": {k: v * 1e3
+                             for k, v in self._device_by.items()},
+            "phases_ms": phases_ms,
+            "other_ms": host_gap_ms - sum(phases_ms.values()),
+            "idle_wait": self._idle_wait,
             "tokens": self._tokens,
             "flops": self._flops,
             "hbm_bytes": self._hbm_bytes,
@@ -342,11 +534,27 @@ class StepAccounting:
                 self._device_s, host_gap_s, hw=self.hw,
                 n_chips=self.n_chips),
         }
+        if self._between_s is not None:
+            out["between_ms"] = self._between_s * 1e3
         self.last = out
         if record_as is not None:
             record_device_step(record_as, time.time() - wall_s, out,
                               attrs)
         return out
+
+
+# The StepAccounting of the training loop running on this thread, for
+# code that sits under the loop without knowing the session (Data's
+# batch iterator). None outside a training loop.
+_tls = threading.local()
+
+
+def bind_accounting(acc: Optional[StepAccounting]):
+    _tls.acc = acc
+
+
+def bound_accounting() -> Optional[StepAccounting]:
+    return getattr(_tls, "acc", None)
 
 
 # ---------------------------------------------------------------------------

@@ -134,3 +134,45 @@ def test_whole_decode_step_compiles_with_the_kernel(one_chip, as_tpu):
                    S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
                    S((B,), i32)).compile()
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("seq, names", [
+    (1024, ("flash_fwd", "flash_bwd_fused")),
+    (16384, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+], ids=["fused_backward", "split_backward"])
+def test_flash_kernels_carry_their_names(one_chip, seq, names):
+    """A device trace finds a kernel by the name on its pallas_call
+    (benchmark/kernels.py still goes by target and operand): lowered
+    for the TPU, forward and both forms of the backward."""
+    q = jax.ShapeDtypeStruct((2, 12, seq, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    attn = functools.partial(flash_attention_pallas, causal=True,
+                             block_q=512, block_k=512, interpret=False,
+                             layout="bhsd")
+    text = jax.jit(jax.grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).lower(q, q, q).as_text()
+    assert "tpu_custom_call" in text
+    for name in names:
+        assert name in text, name
+
+
+def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
+                                                           as_tpu):
+    """The engine's own decode program, lowered for the TPU: the
+    module is ``jit_llm_decode`` and its Mosaic call ``paged_decode``."""
+    from ray_tpu.llm.engine import _jit_programs
+
+    cfg = gpt.GPT2_SMALL
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
+    pool = S((cfg.n_layer, HKV, NB, BS, HD), jnp.bfloat16)
+    B, i32 = 32, jnp.int32
+    decode = _jit_programs(cfg, None, None)[0]
+    text = decode.lower(params, S((B,), i32), S((B,), i32), pool, pool,
+                        S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
+                        S((B,), i32)).as_text()
+    assert "module @jit_llm_decode " in text
+    assert "tpu_custom_call" in text and "paged_decode" in text
