@@ -9,21 +9,49 @@ admitting/finishing/preempting sequences costs allocator bookkeeping,
 not device copies.
 
 Mechanics: the block tables and context lengths ride in as
-scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``), so the K/V
-BlockSpec index maps can address ``k_pool[head, table[b, j]]`` before
-each grid step's DMA is issued — the gather happens in the pipeline's
-index computation, not as a materialized reorder. Grid is
-``(batch, kv_head, blocks_per_seq)`` with the block dimension innermost:
-TPU grid steps execute sequentially, so the running online-softmax state
-(max / denominator / accumulator) carries across key blocks in VMEM
-scratch and the output is written once at the last block, exactly like
-the training flash kernel's inner loop (ops/pallas/flash.py) unrolled
-onto the grid.
+scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``), so a K/V
+BlockSpec index map can address ``k_pool[:, table[b, j]]`` before the
+step's DMA is issued — the gather happens in the pipeline's index
+computation, not as a materialized reorder.
+
+How the work is cut: the grid is ``(batch, compute_blocks)`` and one
+grid step folds ALL KV heads of a lane over ``pages`` table slots at
+once. A Pallas block can only name one run of pages and a lane's pages
+are scattered, so the pool operand is passed ``pages`` times for K and
+``pages`` times for V, each with a window of one page
+``(kv_heads, block_size, head_dim)`` — one strided transfer moves that
+page of every head — and an index map of its own slot. The body joins
+the windows into one ``(kv_heads, pages * block_size, head_dim)`` block:
+two batched matmuls and one online-softmax update a step, the running
+max / denominator / accumulator per head in VMEM scratch across the
+lane's steps, the output written at the last. XLA passes one buffer
+for all the windows: the custom call still takes the layer's pool, in
+its own shape, and is still one call. (The kernel cannot copy pages
+itself with ``make_async_copy`` from a ``pl.ANY`` pool: Mosaic in jax
+0.9.0 sees a pool whose minor dimension is 64 padded to 128 lanes in
+HBM and refuses any slice of it, "must be aligned to tiling (128)".)
+
+Nothing is fetched or computed past a lane's context. The pipeline
+copies a window only when its block index changes, so the index maps
+make every dead slot repeat an index: a step past the lane's last live
+compute block names that block again, a slot past the last live slot
+names the page the window held one block earlier. Such steps skip the
+body (``pl.when``); the ragged tail inside the last live block is
+masked. A padded lane (context 1, table of zeros) costs the scratch
+page once.
+
+``pages`` follows from the shapes (``_pages_per_block``): the largest
+power of two for which the K and V windows of all KV heads (double-
+buffered by the pipeline, joined once more in the body, the minor
+dimension padded to 128 lanes) and the block's f32 scores fit
+``_VMEM_BUDGET`` = 6 MiB, and never more than the table has. 12 heads
+x 64 at block 16 in bf16: 16 pages, 256 keys a step; the table need not
+be a whole number of compute blocks.
 
 GQA is native here (unlike the training kernel, which expands KV): query
 heads arrive grouped per KV head as [batch, kv_heads, group, head_dim],
-so the pool stores only ``kv_heads`` copies and each grid step's q block
-is the whole group — no repeat, no extra HBM.
+so the pool stores only ``kv_heads`` copies and a grid step's q block
+is every head's whole group — no repeat, no extra HBM.
 
 ``interpret=None`` auto-selects interpreter mode off-TPU so tier-1 runs
 the SAME kernel under ``JAX_PLATFORMS=cpu`` (the e2e serving tests and
@@ -48,66 +76,90 @@ def interpret_default() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _decode_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_ref, l_ref, acc_ref, *, block_size: int,
-                   max_nb: int, scale: float, q_len: int, group: int):
-    """One grid step: fold KV block ``j`` of sequence ``b`` (kv head
-    ``h``) into the online softmax. The BlockSpec index maps already
-    resolved ``tables_ref[b, j]`` to a pool block, so ``k_ref``/``v_ref``
-    hold the gathered block; this body only masks and accumulates.
+# VMEM one grid step's pages may take: K and V of every KV head, each
+# page double-buffered by the pipeline and gathered once more into the
+# compute block, plus the block's f32 scores. v5e's scoped default is
+# 16 MiB; the rest stays with the q/out blocks and the compiler.
+_VMEM_BUDGET = 6 * 1024 * 1024
+
+
+def _pages_per_block(hkv: int, rows: int, d: int, block_size: int,
+                     max_nb: int, itemsize: int) -> int:
+    """Pages one grid step carries: the largest power of two whose K
+    and V pages for all KV heads, with the scores they give, fit
+    ``_VMEM_BUDGET``; never more than a table has."""
+    lanes = -(-d // 128) * 128          # VMEM pads the minor dim
+    kv = 2 * 3 * hkv * block_size * lanes * itemsize
+    scores = 3 * hkv * (-(-rows // 8) * 8) * block_size * 4
+    fit = max(1, min(_VMEM_BUDGET // (kv + scores), max_nb))
+    return 1 << (fit.bit_length() - 1)
+
+
+def _decode_kernel(tables_ref, lens_ref, qlens_ref, q_ref, *refs,
+                   block_size: int, pages: int, n_blocks: int,
+                   scale: float, group: int):
+    """One grid step: fold compute block ``blk`` of lane ``b`` — all KV
+    heads, ``pages`` table slots — into the online softmax. The index
+    maps already resolved the slots to pool pages, so ``refs`` opens
+    with ``pages`` K pages then ``pages`` V pages, each
+    ``(kv_heads, block_size, d)``; this body joins them, masks and
+    accumulates. A block wholly past the lane's context is skipped.
 
     Generalized to ``q_len`` query rows per sequence (speculative
-    verify): the q block is the flattened [q_len * group, d] span, row
-    ``r`` belonging to query token ``r // group`` at absolute position
-    ``ctx - q_lens[b] + r // group`` — causal within the span, so each
-    query sees the resident context plus the speculative tokens at or
-    before itself. Lanes with fewer than q_len real rows (short
+    verify): the q block is the flattened [q_len * group, d] span per KV
+    head, row ``r`` belonging to query token ``r // group`` at absolute
+    position ``ctx - q_lens[b] + r // group`` — causal within the span,
+    so each query sees the resident context plus the speculative tokens
+    at or before itself. Lanes with fewer than q_len real rows (short
     proposals, batch padding) clamp to the plain context mask; their
     rows are well-defined garbage the engine never reads."""
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    blk = pl.program_id(1)
+    span = pages * block_size
+    ctx = lens_ref[b]
+    qn = qlens_ref[b]
 
-    @pl.when(j == 0)
+    @pl.when(blk == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                              # (q_len * group, d)
-    k_blk = k_ref[0, 0]                          # (block_size, d)
-    v_blk = v_ref[0, 0]
-    ctx = lens_ref[b]
-    qn = qlens_ref[b]
+    @pl.when(blk * span < ctx)
+    def _fold():
+        q = q_ref[0]                             # (hkv, q_len*group, d)
+        k_blk = jnp.concatenate([r[...] for r in k_refs], axis=1)
+        v_blk = jnp.concatenate([r[...] for r in v_refs], axis=1)
+        s = jax.lax.dot_general(
+            q, k_blk, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # (hkv, rows, span)
+        # Key positions beyond the context are masked: the ragged tail
+        # of the last live page and the block's slots past it (they hold
+        # some live page of the lane again, see the index maps). With
+        # q_len > 1 the bound is additionally causal per query row:
+        # query i's last visible key is its own write slot ctx - qn + i.
+        k_pos = blk * span + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2)
+        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // group
+        bound = jnp.minimum(ctx, ctx - qn + 1 + qi)
+        s = jnp.where(k_pos < bound, s, NEG_INF)
 
-    s = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (q_len*group, bs)
-    # Key positions beyond the context are masked — this covers both the
-    # ragged tail of the last real block and whole padded table entries
-    # (their table slot points at the reserved scratch block; the mask
-    # makes the gathered garbage contribute exp(NEG_INF) ≈ 0). With
-    # q_len > 1 the bound is additionally causal per query row: query
-    # i's last visible key is its own write slot ctx - qn + i.
-    k_pos = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
-    qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-    bound = jnp.minimum(ctx, ctx - qn + 1 + qi)
-    s = jnp.where(k_pos < bound, s, NEG_INF)
+        m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l * corr + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc * corr + jax.lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
 
-    m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
-    m_new = jnp.maximum(m, s.max(-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m - m_new)
-    m_ref[...] = m_new
-    l_ref[...] = l * corr + p.sum(-1, keepdims=True)
-    acc_ref[...] = acc * corr + jax.lax.dot_general(
-        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == max_nb - 1)
+    @pl.when(blk == n_blocks - 1)
     def _write():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -116,41 +168,51 @@ def _make_decode_call(b: int, hkv: int, group: int, d: int,
                       q_dtype, p_dtype, interpret: bool, q_len: int = 1):
     scale = d ** -0.5
     rows = q_len * group
+    pages = _pages_per_block(hkv, rows, d, block_size, max_nb,
+                             jnp.dtype(p_dtype).itemsize)
+    n_blocks = pl.cdiv(max_nb, pages)
+
+    def page(i):
+        """The paged gather: the pool page under slot ``i`` of compute
+        block ``blk``. A step past the lane's last live block names that
+        block again, and a slot past its last live slot the page this
+        operand held a block earlier (in the lane's first block: the
+        last live page), so the pipeline, which copies a block only
+        when its index changes, fetches nothing past the context. Every
+        slot read is live, so a table's padding is never followed."""
+        def index(bi, blk, tables, lens, qlens):
+            last = jnp.maximum(lens[bi] - 1, 0) // block_size
+            blk = jnp.minimum(blk, last // pages)
+            j = blk * pages + i
+            j = jnp.where(j <= last, j,
+                          jnp.where(blk > 0, j - pages, last))
+            return (0, tables[bi, j], 0, 0)
+        return pl.BlockSpec((hkv, None, block_size, d), index)
+
+    lane = pl.BlockSpec((1, hkv, rows, d),
+                        lambda bi, blk, tables, lens, qlens: (bi, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,   # block tables + context lens + q lens
-        grid=(b, hkv, max_nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, d),
-                         lambda bi, hi, j, tables, lens, qlens:
-                         (bi, hi, 0, 0)),
-            # The paged gather: the pool block for grid step (bi, ·, j)
-            # is whatever the sequence's table names. Padded table slots
-            # hold 0 (the pool's reserved scratch block) so the index is
-            # always in range; the kernel masks their keys out.
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda bi, hi, j, tables, lens, qlens:
-                         (hi, tables[bi, j], 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d),
-                         lambda bi, hi, j, tables, lens, qlens:
-                         (hi, tables[bi, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, rows, d),
-            lambda bi, hi, j, tables, lens, qlens: (bi, hi, 0, 0)),
+        grid=(b, n_blocks),
+        in_specs=[lane] + 2 * [page(i) for i in range(pages)],
+        out_specs=lane,
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),   # running max
-            pltpu.VMEM((rows, 1), jnp.float32),   # running denominator
-            pltpu.VMEM((rows, d), jnp.float32),   # output accumulator
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),   # running max
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),   # denominator
+            pltpu.VMEM((hkv, rows, d), jnp.float32),   # accumulator
         ],
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=block_size,
-                          max_nb=max_nb, scale=scale, q_len=q_len,
+                          pages=pages, n_blocks=n_blocks, scale=scale,
                           group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q_dtype),
         interpret=interpret, name="paged_decode",
     )
+    # One pool, ``pages`` windows onto it: the same operand per slot.
+    return lambda tables, lens, qlens, q, k_pool, v_pool: call(
+        tables, lens, qlens, q, *pages * [k_pool], *pages * [v_pool])
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,

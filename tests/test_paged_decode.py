@@ -19,6 +19,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.ops.attention import causal_attention  # noqa: E402
 from ray_tpu.ops.pallas.paged_decode import (  # noqa: E402
+    _pages_per_block,
     paged_decode_attention,
     paged_decode_attention_reference,
     paged_verify_attention,
@@ -216,3 +217,61 @@ def test_scratch_block_garbage_is_masked():
     out2 = paged_decode_attention(q, k2, v2, tables, lens, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                atol=ATOL_F32, rtol=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("d", [64, 128], ids=["d64", "d128"])
+@pytest.mark.parametrize("group", [1, 4], ids=["g1", "g4"])
+@pytest.mark.parametrize("q_len", [1, 5], ids=["decode", "verify_q5"])
+def test_cutting_into_compute_blocks_matches_reference(q_len, group, d,
+                                                       dtype):
+    """What the cut into grid steps of several pages can get wrong, one
+    lane each, against the plain references: contexts of 1, one under,
+    at and one over a compute block's edge, and the whole table; a
+    table that is not a whole number of compute blocks; page ids that
+    fall and repeat, within a lane and across lanes; padded lanes
+    (context 1, table of zeros) between live ones; and, for verify,
+    lanes with fewer real rows than q_len."""
+    hkv, block_size, max_nb, num_blocks = 2, 8, 6, 40
+    pages = _pages_per_block(hkv, q_len * group, d, block_size, max_nb,
+                             jnp.dtype(dtype).itemsize)
+    span = pages * block_size
+    assert 1 < pages < max_nb and max_nb % pages, pages
+    full = max_nb * block_size
+    lens = np.array([1, span - 1, 1, span, span + 1, 1, full, full - 3],
+                    np.int32)
+    live = lambda n: -(-int(n) // block_size)
+    tables = np.zeros((len(lens), max_nb), np.int32)
+    tables[0, :1] = [17]
+    tables[1, :live(lens[1])] = np.arange(30, 30 - live(lens[1]), -1)
+    tables[3, :live(lens[3])] = np.arange(9, 9 + live(lens[3]))
+    # Lane 4 shares lane 3's pages and names its last one twice.
+    tables[4, :live(lens[4])] = np.r_[tables[3, :live(lens[3])],
+                                      tables[3, live(lens[3]) - 1]]
+    tables[6] = [5, 4, 3, 5, 4, 3]          # falls, then repeats
+    tables[7] = np.arange(39, 39 - max_nb, -1)
+    # Lanes 2 and 5 are padding: context 1, table of zeros.
+    q_lens = np.minimum(lens, [1, 5, 1, 3, 5, 1, 2, 4]).astype(np.int32)
+
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(q_len + group + d),
+                                  3)
+    k_pool = jax.random.normal(kk, (hkv, num_blocks, block_size, d),
+                               dtype)
+    v_pool = jax.random.normal(kv, (hkv, num_blocks, block_size, d),
+                               dtype)
+    args = (k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lens))
+    if q_len == 1:
+        q = jax.random.normal(kq, (len(lens), hkv, group, d), dtype)
+        out = paged_decode_attention(q, *args, interpret=True)
+        ref = paged_decode_attention_reference(q, *args)
+    else:
+        q = jax.random.normal(kq, (len(lens), q_len, hkv, group, d),
+                              dtype)
+        out = paged_verify_attention(q, *args, jnp.asarray(q_lens),
+                                     interpret=True)
+        ref = paged_verify_attention_reference(q, *args,
+                                               jnp.asarray(q_lens))
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        atol=ATOL_F32 if dtype == jnp.float32 else ATOL_BF16, rtol=0)

@@ -78,20 +78,26 @@ def test_flash_forward_and_backward_compile(one_chip):
 
 
 @pytest.mark.parametrize("q_len", [1, 5], ids=["decode", "verify_q5"])
-def test_paged_kernel_compiles(one_chip, q_len):
+@pytest.mark.parametrize("batch, hkv, group, hd, nb, max_nb", [
+    (32, HKV, 1, HD, NB, MAX_NB),
+    (64, HKV, 1, HD, 2560, MAX_NB),     # gpt2s-serve-chat's own shapes
+    (16, 8, 8, 128, 2048, 128),         # head_dim 128, group 8
+], ids=["b32_4096_blocks", "chat_cell", "hd128_group8"])
+def test_paged_kernel_compiles(one_chip, q_len, batch, hkv, group, hd, nb,
+                               max_nb):
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    B = 32
-    pool = S((HKV, NB, BS, HD), jnp.bfloat16)
-    tables, lens = S((B, MAX_NB), jnp.int32), S((B,), jnp.int32)
+    pool = S((hkv, nb, BS, hd), jnp.bfloat16)
+    tables, lens = S((batch, max_nb), jnp.int32), S((batch,), jnp.int32)
     if q_len == 1:
         c = _compile(
             functools.partial(paged_decode_attention, interpret=False),
-            S((B, HKV, 1, HD), jnp.bfloat16), pool, pool, tables, lens)
+            S((batch, hkv, group, hd), jnp.bfloat16), pool, pool, tables,
+            lens)
     else:
         c = _compile(
             functools.partial(paged_verify_attention, interpret=False),
-            S((B, q_len, HKV, 1, HD), jnp.bfloat16), pool, pool, tables,
-            lens, lens)
+            S((batch, q_len, hkv, group, hd), jnp.bfloat16), pool, pool,
+            tables, lens, lens)
     assert "tpu_custom_call" in c.as_text()
 
 
@@ -159,8 +165,13 @@ def test_flash_kernels_carry_their_names(one_chip, seq, names):
 
 def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
                                                            as_tpu):
-    """The engine's own decode program, lowered for the TPU: the
-    module is ``jit_llm_decode`` and its Mosaic call ``paged_decode``."""
+    """The engine's own decode program, lowered for the TPU at the chat
+    cell's shapes: the module is ``jit_llm_decode`` and it holds exactly
+    one Mosaic call, ``paged_decode``, which takes the layer's pool as
+    an operand in the pool's own shape. benchmark/kernels.py finds the
+    kernel by that operand and divides its seconds by calls x layers: a
+    second Mosaic call on the pool, or a reshaped or stacked pool, would
+    move ``paged_kernel_ms`` without moving the kernel."""
     from ray_tpu.llm.engine import _jit_programs
 
     cfg = gpt.GPT2_SMALL
@@ -168,11 +179,17 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
     params = jax.tree_util.tree_map(
         lambda leaf: S(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
-    pool = S((cfg.n_layer, HKV, NB, BS, HD), jnp.bfloat16)
-    B, i32 = 32, jnp.int32
+    B, nb, i32 = 64, 2560, jnp.int32
+    pool = S((cfg.n_layer, HKV, nb, BS, HD), jnp.bfloat16)
     decode = _jit_programs(cfg, None, None)[0]
     text = decode.lower(params, S((B,), i32), S((B,), i32), pool, pool,
                         S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
                         S((B,), i32)).as_text()
     assert "module @jit_llm_decode " in text
-    assert "tpu_custom_call" in text and "paged_decode" in text
+    calls = [line for line in text.splitlines()
+             if "@tpu_custom_call" in line]
+    assert len(calls) == 1, len(calls)
+    assert 'kernel_name = "paged_decode"' in calls[0]
+    assert f"tensor<{HKV}x{nb}x{BS}x{HD}xbf16>" in calls[0]
+    assert f"tensor<{cfg.n_layer}x{HKV}x{nb}x{BS}x{HD}xbf16>" \
+        not in calls[0]
