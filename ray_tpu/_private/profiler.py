@@ -348,6 +348,8 @@ def format_device_steps(steps: list) -> str:
     ring's entries) as `rtpu profile --device` prints them, one block a
     step name and owner: the mean step split into its device spans by
     kind and its host phases by name, and an engine's own counts."""
+    from ray_tpu.util import perfmodel
+
     groups: dict = {}
     for ev in steps:
         owner = ev.get("deployment") or ev.get("trial") or ""
@@ -378,8 +380,10 @@ def format_device_steps(steps: list) -> str:
             lines.append(f"    host by phase: {', '.join(phases)}, "
                          f"other {mean('other_ms'):.2f}")
         if "lanes" in evs[0]:
+            on_device = mean(perfmodel.DEVICE_SAMPLED)
             lines.append(
                 f"    lanes {mean('lanes'):.1f} of {evs[0]['max_batch']}"
+                f" ({on_device:.1f} decided on the device)"
                 f" over {mean('context_tokens'):.0f} context tokens; "
                 f"tokens computed: decode "
                 f"{sum(e['decode_tokens'] for e in evs)}, prefill "

@@ -69,7 +69,7 @@ from ..models.gpt import (GPTConfig, forward_decode, forward_prefill,
                           forward_prefill_chunk, forward_verify)
 from ..util import perfmodel, tracing
 from .kv_cache import PagedKVCache, PrefixPool
-from .sampling import sample, verify_tokens
+from .sampling import accept_draws, is_greedy, sample, verify_tokens
 from .spec import make_spec
 
 # Roofline verdict -> coded gauge value for the telemetry plane
@@ -111,6 +111,12 @@ class Request:
     # span this generation belongs to); None outside traced requests.
     trace_ctx: Optional[dict] = None
     out_q: "queue.Queue" = field(default_factory=queue.Queue)
+
+    @property
+    def greedy(self) -> bool:
+        """Takes the argmax: its decode tokens are the ids the program
+        returns, and no logits of its lane leave the device."""
+        return is_greedy(self.temperature, self.top_k)
 
     def tokens(self):
         """Blocking generator over this request's output tokens (the
@@ -250,7 +256,13 @@ class LLMEngine:
         self._step_perf = perfmodel.StepAccounting()
         self._preempt_count = 0       # preemptions, all steps
         self._chunk_log: List[list] = []    # this step's prefill chunks
-        self._counts = (0, 0, 0)    # lanes, context tokens, decode tokens
+        # lanes, context tokens, decode tokens, lanes decided on the device
+        self._counts = (0, 0, 0, 0)
+        # Output tokens by where they were decided: the program's own
+        # argmax (greedy lanes of a decode or verify step) or a logits
+        # row sampled on the host (lanes with a temperature, and every
+        # first token after a prefill).
+        self._decided = {"device": 0, "host": 0}
 
     # -- events ------------------------------------------------------------
 
@@ -428,8 +440,9 @@ class LLMEngine:
                       position=len(req.prompt) + len(req.output))
 
     def _sample_into(self, req: Request, logits_row) -> bool:
-        """Sample the next token; emit it; apply stop conditions.
-        Returns True if the request finished."""
+        """Sample the next token on the host; emit it; apply stop
+        conditions. Returns True if the request finished."""
+        self._decided["host"] += 1
         return self._emit_token(req, self._sample(req, logits_row))
 
     def _emit_token(self, req: Request, tok: int) -> bool:
@@ -628,6 +641,19 @@ class LLMEngine:
                 "hbm_util": round(rl["hbm_util"], 4),
                 "verdict": rl["verdict"]}
 
+    def _fetch_decisions(self, logits, ids, all_greedy: bool):
+        """What the host needs of a decode or verify program's outputs
+        to decide every lane's tokens, fetched once a step (the caller
+        has blocked on ``ids``, so these are copies, not waits).
+        Returns ``(ids, rows)``: the program's argmax ids as Python
+        ints, which a greedy lane takes as they are; and the logits,
+        or None when every lane is greedy and they stay on the device.
+        A lane that samples with a temperature hands sample() its own
+        row of them with its (seed, position) key, so no lane's tokens
+        depend on what the others asked for."""
+        return (jax.device_get(ids).tolist(),
+                None if all_greedy else jax.device_get(logits))
+
     def _run_decode(self):
         perf = self._step_perf
         with perf.phase("llm.slots"):
@@ -670,31 +696,44 @@ class LLMEngine:
                 tables[i, :len(req.block_table)] = req.block_table
             ctx = [r.context_len + 1 for r in batch]
             cost = perfmodel.decode_step_cost(self.cfg, ctx)
-            self._counts = (len(batch), sum(ctx), len(batch))
-        # block_until_ready bounds the DEVICE span; the device_get that
-        # follows is then a cheap copy, so sampling/queue pushes below
-        # are charged to the host, not smeared into device time.
+            on_device = sum(r.greedy for r in batch)
+            self._counts = (len(batch), sum(ctx), len(batch), on_device)
+        # block_until_ready on the ids bounds the DEVICE span (they are
+        # the program's last output: the argmax of its logits); the
+        # fetch that follows is then a copy of max_batch ints, charged
+        # to the host, and the logits stay where they are unless a lane
+        # samples with a temperature.
         with perf.device("llm.decode.device") as dev:
-            logits, self.kv.k, self.kv.v = self._decode(
+            logits, ids, self.kv.k, self.kv.v = self._decode(
                 self.params, tokens, positions, self.kv.k, self.kv.v,
                 tables, context_lens, slot_blocks, slot_offsets)
-            jax.block_until_ready(logits)
+            jax.block_until_ready(ids)
         device_s = dev.seconds
         perf.add_cost(cost)
         sampling, emitting = perf.phase("llm.sample"), perf.phase("llm.emit")
         with sampling:
-            rows = np.asarray(jax.device_get(logits), np.float32)
-        # Lane by lane, each token out the moment it is decided: a
-        # finish that reaches its caller before the step ends lets a
-        # closed-loop caller's next request make the coming step's
-        # admission (emitting after all lanes were sampled read 0.8 of
-        # a step more TTFT on the chip; PERF.md section 6, PR 24).
+            ids, rows = self._fetch_decisions(logits, ids,
+                                              on_device == len(batch))
+        # Lane by lane, each token out the moment it is decided: for a
+        # greedy lane that is now, so for an all-greedy batch this loop
+        # is emission alone (~1 ms for 64 lanes on the chip) and every
+        # finish leaves in the step's last milliseconds; a closed-loop
+        # caller's next request then waits out the coming step, which
+        # handing finishing lanes their token first did not change
+        # (PERF.md section 6, PR 29). A lane with a temperature is
+        # sampled in its turn, so its draw delays only the lanes after
+        # it.
         for i, req in enumerate(batch):
             req.context_len += 1
-            with sampling:
-                tok = self._sample(req, rows[i])
+            if req.greedy:
+                tok = ids[i]
+            else:
+                with sampling:
+                    tok = self._sample(req, rows[i])
             with emitting:
                 self._emit_token(req, tok)
+        self._decided["device"] += on_device
+        self._decided["host"] += len(batch) - on_device
         dur = time.time() - t0
         with perf.phase("llm.trace"):
             self._trace_decode_step(batch, t0, dur, cost, device_s)
@@ -802,29 +841,40 @@ class LLMEngine:
             ctx = [int(context_lens[i]) for i in range(len(batch))]
             rows_per_lane = [int(q_lens[i]) for i in range(len(batch))]
             cost = perfmodel.verify_step_cost(self.cfg, ctx, rows_per_lane)
-            self._counts = (len(batch), sum(ctx), sum(rows_per_lane))
+            on_device = sum(r.greedy for r in batch)
+            self._counts = (len(batch), sum(ctx), sum(rows_per_lane),
+                            on_device)
         with perf.device("llm.decode.device") as dev:
-            logits, self.kv.k, self.kv.v = self._verify(
+            logits, ids, self.kv.k, self.kv.v = self._verify(
                 self.params, tokens, positions, self.kv.k, self.kv.v,
                 tables, context_lens, q_lens, slot_blocks, slot_offsets)
-            jax.block_until_ready(logits)
+            jax.block_until_ready(ids)
         device_s = dev.seconds
         perf.add_cost(cost)
         sampling, emitting = perf.phase("llm.sample"), perf.phase("llm.emit")
         with sampling:
-            rows = np.asarray(jax.device_get(logits), np.float32)
+            ids, rows = self._fetch_decisions(logits, ids,
+                                              on_device == len(batch))
         emitted_total = 0
         for i, req in enumerate(batch):     # lane by lane, as in decode
             p = props[req.rid]
             slot = req.context_len
             with sampling:
-                n_acc, emitted = verify_tokens(
-                    rows[i, :1 + len(p)], p, temperature=req.temperature,
-                    top_k=req.top_k, seed=req.seed,
-                    start_pos=len(req.prompt) + len(req.output))
+                if req.greedy:
+                    # The target's greedy draw at row j is the id the
+                    # program returned for it: acceptance is the same
+                    # equality check, on integers.
+                    n_acc, emitted = accept_draws(ids[i].__getitem__, p)
+                else:
+                    n_acc, emitted = verify_tokens(
+                        rows[i, :1 + len(p)], p,
+                        temperature=req.temperature, top_k=req.top_k,
+                        seed=req.seed,
+                        start_pos=len(req.prompt) + len(req.output))
             with emitting:
                 spec.accept(req.rid, n_acc, len(p), len(emitted))
                 emitted_total += len(emitted)
+                n_out = len(req.output)
                 for idx, tok in enumerate(emitted):
                     # Bookkeeping BEFORE emitting: an accepted token IS
                     # resident (its slot was written this step), the
@@ -837,7 +887,9 @@ class LLMEngine:
                     else:
                         req.context_len = slot + 1 + n_acc
                     if self._emit_token(req, tok):
-                        break
+                        break       # a stop token: the rest is dropped
+                self._decided["device" if req.greedy else "host"] += \
+                    len(req.output) - n_out
                 n_rej = len(p) - n_acc
                 if n_rej:
                     # Rejected slots past the accept cursor: any whole
@@ -863,7 +915,7 @@ class LLMEngine:
             perf = self._step_perf
             perf.begin()
             self._chunk_log = []
-            self._counts = (0, 0, 0)
+            self._counts = (0, 0, 0, 0)
             preempted0 = self._preempt_count
             with perf.step("llm.step", self._steps + 1):
                 with perf.phase("llm.admit"):
@@ -892,12 +944,13 @@ class LLMEngine:
             # the process-local device-step ring, where the benchmark
             # and the gang profiler (`rtpu profile --device`) read it.
             # Counts are the scheduler's own, taken where it has them.
-            lanes, context_tokens, decode_tokens = self._counts
+            lanes, context_tokens, decode_tokens, on_device = self._counts
             chunks = self._chunk_log
             perf.finish(
                 record_as="llm.step",
                 attrs={"deployment": self.name, "step": self._steps,
                        "lanes": lanes, "max_batch": self.max_batch,
+                       perfmodel.DEVICE_SAMPLED: on_device,
                        "context_tokens": context_tokens,
                        "decode_tokens": decode_tokens,
                        "prefill_tokens": sum(c[0] for c in chunks),
@@ -964,6 +1017,9 @@ class LLMEngine:
             "kv_free_blocks": self.kv.num_free,
             "tokens_per_s": self.tokens_per_s(),
             "prefill_chunks": self._prefill_chunks,
+            # Output tokens by where they were decided (see __init__).
+            "tokens_decided_on_device": self._decided["device"],
+            "tokens_decided_on_host": self._decided["host"],
         }
         if self._prefix:
             ps = self.kv.prefix_stats()

@@ -1,13 +1,22 @@
 """Deterministic token sampling for the generation engine.
 
-Sampling runs on the host (numpy) over a single token's logits row —
-the device step ends at logits, so the engine can preempt/resume a
-sequence and REPLAY its sampling exactly: the RNG for a draw is derived
-from ``(seed, position)`` alone, never from how many times the engine
-has stepped. That is what makes recompute-on-resume (llm/kv_cache.py's
-preemption story) bit-identical — a resumed sequence re-prefills its
-prompt + generated-so-far and then draws the same tokens it would have
-drawn uninterrupted.
+A token is decided where it is cheapest to decide it exactly. A GREEDY
+request (``is_greedy``: temperature 0 or top_k 1) takes the argmax the
+decode or verify program computed beside its logits (models/gpt.py):
+the engine fetches one int a lane and the logits stay on the device.
+``numpy.argmax`` and ``jnp.argmax`` both return the first index of the
+maximum and bf16 -> float32 is exact, so that id is the token sample()
+would return for the row, ties included. A request that samples with a
+temperature has its logits row brought to the host and drawn here
+(numpy), as does every request's first token after a prefill.
+
+Either way the engine can preempt/resume a sequence and REPLAY its
+sampling exactly: the RNG for a draw is derived from
+``(seed, position)`` alone, never from how many times the engine has
+stepped, and an argmax needs none. That is what makes
+recompute-on-resume (llm/kv_cache.py's preemption story) bit-identical
+— a resumed sequence re-prefills its prompt + generated-so-far and then
+draws the same tokens it would have drawn uninterrupted.
 """
 
 from __future__ import annotations
@@ -15,6 +24,12 @@ from __future__ import annotations
 from typing import Optional
 
 import numpy as np
+
+
+def is_greedy(temperature: float, top_k: int) -> bool:
+    """Does a request with these settings take the argmax? The one rule
+    sample(), target_probs() and the engine's on-device path share."""
+    return temperature <= 0.0 or top_k == 1
 
 
 def sample(logits, *, temperature: float = 0.0, top_k: int = 0,
@@ -26,7 +41,7 @@ def sample(logits, *, temperature: float = 0.0, top_k: int = 0,
     with an RNG keyed by (seed, position) only — see module docstring.
     """
     logits = np.asarray(logits, np.float32)
-    if temperature <= 0.0 or top_k == 1:
+    if is_greedy(temperature, top_k):
         return int(logits.argmax())
     if top_k > 0 and top_k < logits.shape[-1]:
         kth = np.partition(logits, -top_k)[-top_k]
@@ -62,27 +77,31 @@ def verify_tokens(rows, proposed, *, temperature: float = 0.0,
     (``len(emitted) == n_accepted + 1``; requires
     ``len(rows) >= len(proposed) + 1``).
     """
-    proposed = [int(t) for t in proposed]
     if len(rows) < len(proposed) + 1:
         raise ValueError(
             f"need {len(proposed) + 1} logits rows to verify "
             f"{len(proposed)} proposals, got {len(rows)}")
+    return accept_draws(
+        lambda j: sample(rows[j], temperature=temperature, top_k=top_k,
+                         seed=seed, position=start_pos + j), proposed)
+
+
+def accept_draws(draw, proposed):
+    """verify_tokens' acceptance over the target's draws themselves:
+    ``draw(j)`` is the token the target emits at row j (sample() on the
+    row, or for a greedy lane the id the verify program returned), asked
+    for only as far as the proposals keep matching. Returns
+    ``(n_accepted, emitted)`` as verify_tokens does."""
     emitted = []
-    n_accepted = 0
     for j, prop in enumerate(proposed):
-        tok = sample(rows[j], temperature=temperature, top_k=top_k,
-                     seed=seed, position=start_pos + j)
-        if tok != prop:
-            emitted.append(tok)          # the corrected draw
-            return n_accepted, emitted
-        n_accepted += 1
+        tok = draw(j)
         emitted.append(tok)
+        if tok != int(prop):
+            return j, emitted            # the corrected draw
     # Every proposal matched: the last row scores the position after
     # them — a free bonus token.
-    emitted.append(sample(rows[len(proposed)], temperature=temperature,
-                          top_k=top_k, seed=seed,
-                          position=start_pos + len(proposed)))
-    return n_accepted, emitted
+    emitted.append(draw(len(proposed)))
+    return len(proposed), emitted
 
 
 def target_probs(logits, *, temperature: float = 0.0,
@@ -91,7 +110,7 @@ def target_probs(logits, *, temperature: float = 0.0,
     probability vector (greedy = a point mass at the argmax)."""
     logits = np.asarray(logits, np.float32)
     V = logits.shape[-1]
-    if temperature <= 0.0 or top_k == 1:
+    if is_greedy(temperature, top_k):
         p = np.zeros(V, np.float32)
         p[int(logits.argmax())] = 1.0
         return p

@@ -325,6 +325,12 @@ def forward_prefill(params, tokens, cfg: GPTConfig,
     return logits, k, v
 
 
+def _greedy_ids(logits):
+    """Argmax over the vocabulary, int32: the token a greedy lane takes
+    (llm/sampling.py's rule, first index of the maximum on ties)."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def forward_decode(params, tokens, positions, k_pool, v_pool,
                    block_tables, context_lens, slot_blocks, slot_offsets,
                    cfg: GPTConfig, mesh: Optional[Mesh] = None,
@@ -349,7 +355,10 @@ def forward_decode(params, tokens, positions, k_pool, v_pool,
       slot_blocks / slot_offsets: [b] int32 — the pool block and
         in-block offset of each lane's CURRENT token.
 
-    Returns (logits [b, vocab], k_pool, v_pool).
+    Returns (logits [b, vocab], ids [b] int32, k_pool, v_pool): ``ids``
+    is the argmax of each logits row (the first index of the maximum,
+    as ``numpy.argmax`` on the same row), so a greedy lane's token is
+    decided here and the host fetches ids, not logits.
     """
     from ..ops.pallas.paged_decode import paged_decode_attention
 
@@ -389,7 +398,7 @@ def forward_decode(params, tokens, positions, k_pool, v_pool,
         scan_body, x, (params["blocks"], k_pool, v_pool))
     x = _layernorm(x, params["ln_f"])
     logits = jnp.einsum("bm,vm->bv", x, params["wte"].astype(dt))
-    return logits, k_pool, v_pool
+    return logits, _greedy_ids(logits), k_pool, v_pool
 
 
 def forward_verify(params, tokens, positions, k_pool, v_pool,
@@ -419,7 +428,8 @@ def forward_verify(params, tokens, positions, k_pool, v_pool,
       q_lens: [b] int32 — real rows per lane (1 = plain decode lane).
       slot_blocks / slot_offsets: [b, q] int32 write sites per row.
 
-    Returns (logits [b, q, vocab], k_pool, v_pool) — donate the pools.
+    Returns (logits [b, q, vocab], ids [b, q] int32, k_pool, v_pool)
+    — donate the pools; ``ids`` as in forward_decode, row by row.
     """
     from ..ops.pallas.paged_decode import paged_verify_attention
 
@@ -459,7 +469,7 @@ def forward_verify(params, tokens, positions, k_pool, v_pool,
         scan_body, x, (params["blocks"], k_pool, v_pool))
     x = _layernorm(x, params["ln_f"])
     logits = jnp.einsum("bqm,vm->bqv", x, params["wte"].astype(dt))
-    return logits, k_pool, v_pool
+    return logits, _greedy_ids(logits), k_pool, v_pool
 
 
 def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len):

@@ -310,9 +310,11 @@ PHASES: Dict[str, tuple] = {
                                "arrays for max_batch lanes; proposals "
                                "under speculation"),
     "llm.decode.device": ("decode", "the decode or verify program, "
-                                    "dispatch to logits ready"),
-    "llm.sample": (None, "device_get of the logits and the sampler (or "
-                         "verify_tokens) for every lane"),
+                                    "dispatch to its argmax ids ready"),
+    "llm.sample": (None, "device_get of the program's ids, which greedy "
+                         "lanes take; for lanes with a temperature also "
+                         "of the logits, and the sampler (or "
+                         "verify_tokens) on their rows"),
     "llm.emit": (None, "tokens onto the request queues, finishes, block "
                        "release, speculative rollback"),
     "llm.trace": (None, "the per-request llm.decode_step span copies, "
@@ -328,6 +330,10 @@ PHASES: Dict[str, tuple] = {
 }
 #: Annotated whole steps (``StepAccounting.step``): ring entry names.
 STEPS = ("llm.step", "train.step")
+#: Key of an ``llm.step`` ring entry beside ``lanes``: how many of the
+#: step's decode lanes took their token from the program's own argmax
+#: (greedy requests) and not from a logits row sampled on the host.
+DEVICE_SAMPLED = "device_sampled"
 _DEVICE_KINDS = frozenset(k for k, _ in PHASES.values() if k) | {"device"}
 _UNDETECTED = object()      # StepAccounting's peaks, before first use
 

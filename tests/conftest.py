@@ -103,6 +103,29 @@ def traced(rt):
     tracing.drain_request_spans()
 
 
+@pytest.fixture
+def watch_device_get(monkeypatch):
+    """``watch()`` starts recording the element count of every array
+    ``jax.device_get`` fetches from then on (the LLM engine's only way
+    of bringing a device array to the host) and returns the list."""
+    import jax
+    import numpy as np
+
+    def watch():
+        fetched = []
+        real = jax.device_get
+
+        def device_get(x):
+            fetched.extend(int(np.size(leaf))
+                           for leaf in jax.tree_util.tree_leaves(x))
+            return real(x)
+
+        monkeypatch.setattr(jax, "device_get", device_get)
+        return fetched
+
+    return watch
+
+
 @pytest.fixture(scope="session")
 def shared_rt():
     """A session-scoped runtime for cheap read-only tests."""

@@ -380,3 +380,238 @@ def test_step_loop_death_fails_requests_instead_of_hanging(monkeypatch):
     with pytest.raises(RuntimeError, match="step loop died"):
         eng.add_request([1, 2], max_tokens=2)
     eng.stop()
+
+
+# ---------------------------------------------------------------------------
+# Tokens decided on the device: greedy lanes take the program's own argmax
+# ---------------------------------------------------------------------------
+def _bf16(values):
+    return jnp.asarray(np.asarray(values, np.float32), jnp.bfloat16)
+
+
+def _tie_rows():
+    """bf16 logits rows whose argmax is a matter of tie-breaking or lies
+    at the vocabulary's padded end, by case."""
+    V = 640
+    base = np.random.default_rng(0).standard_normal(V).astype(np.float32)
+    top = float(np.abs(base).max()) + 1.0
+
+    def row(*at):
+        r = base.copy()
+        r[list(at)] = top
+        return r
+
+    # A tie only after rounding: top * (1 + 2**-10) is top in bf16 (8
+    # significant bits), and the larger float32 comes second.
+    rounded = row(9)
+    rounded[400] = top * (1 + 2.0 ** -10)
+    return {
+        "tie_at_the_maximum": row(17, 300, 301),
+        "maximum_in_the_padded_tail": row(V - 1),
+        "tie_inside_the_padded_tail": row(V - 3, V - 1),
+        "tie_of_head_and_tail": row(5, V - 1),
+        "tie_made_by_bf16_rounding": rounded,
+        "all_equal": np.zeros(V, np.float32),
+        "all_minus_infinity": np.full(V, -np.inf, np.float32),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_tie_rows()))
+def test_device_argmax_breaks_ties_as_the_host_sampler_does(case):
+    """The id the decode and verify programs return for a row is the
+    token sampling.sample(row, temperature=0) returns for it: both take
+    the FIRST index of the maximum, and bf16 -> float32 is exact."""
+    from ray_tpu.llm.sampling import sample
+    from ray_tpu.models.gpt import _greedy_ids
+
+    row = _bf16(_tie_rows()[case])
+    want = sample(np.asarray(row), temperature=0.0)
+    assert want == sample(np.asarray(row), temperature=0.7, top_k=1)
+    ids = jax.jit(_greedy_ids)(jnp.stack([row, row[::-1], row]))
+    assert ids.dtype == jnp.int32 and ids.shape == (3,)
+    assert [int(ids[0]), int(ids[2])] == [want, want]
+    assert int(ids[1]) == sample(np.asarray(row)[::-1], temperature=0.0)
+    if case.startswith("tie") or case.startswith("all"):
+        # The construction did make a tie at the maximum.
+        r = np.asarray(row, np.float32)
+        assert (r == r.max()).sum() > 1
+
+
+BF16 = GPTConfig(vocab_size=128, max_seq=64, d_model=64, n_layer=2,
+                 n_head=4, dtype=jnp.bfloat16)
+
+
+def _tied_params(kind):
+    """bf16-served weights whose vocabulary rows repeat, so every logits
+    row of the program ties at its maximum ("ties": the upper half of
+    the vocabulary copies the lower), or holds its maximum, tied, in the
+    last rows ("tail": the rest scaled down)."""
+    params = init(jax.random.PRNGKey(3), BF16)
+    wte = np.array(params["wte"], np.float32)
+    if kind == "ties":
+        wte[64:] = wte[:64]
+    else:
+        wte[:-8] *= 0.01
+        wte[-8] = -wte[-7]      # one of the two scores above zero
+        wte[-4:] = wte[-8:-4]
+    return dict(params, wte=jnp.asarray(wte, params["wte"].dtype))
+
+
+@pytest.mark.parametrize("kind", ["ties", "tail"])
+@pytest.mark.parametrize("program", ["decode", "verify"])
+def test_program_ids_equal_host_greedy_sample_of_its_logits(program, kind):
+    """forward_decode / forward_verify through the engine's own jitted
+    programs: the ids output equals sample(row, temperature=0) on every
+    row of the logits output, where rows tie at the maximum and where
+    the maximum lies at the vocabulary's end."""
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.llm.sampling import sample
+
+    params = _tied_params(kind)
+    decode, _, _, verify = _jit_programs(BF16, None, None)
+    B, Q, bs, nb = 4, 3, 8, 16
+    max_nb = BF16.max_seq // bs
+    rng = np.random.default_rng(1)
+    pool = jnp.asarray(rng.standard_normal(
+        (BF16.n_layer, BF16.kv_heads, nb, bs, BF16.head_dim)), BF16.dtype)
+    tables = np.zeros((B, max_nb), np.int32)
+    tables[:, 0] = 1 + np.arange(B)
+    if program == "decode":
+        slot = 2 + np.arange(B, dtype=np.int32)
+        logits, ids, _, _ = decode(
+            params, rng.integers(0, 60, (B,), dtype=np.int32), slot,
+            pool, pool + 0, tables, slot + 1, tables[:, 0], slot)
+    else:
+        slot = (1 + np.arange(B, dtype=np.int32))[:, None] + np.arange(
+            Q, dtype=np.int32)
+        logits, ids, _, _ = verify(
+            params, rng.integers(0, 60, (B, Q), dtype=np.int32), slot,
+            pool, pool + 0, tables, slot[:, -1] + 1,
+            np.full((B,), Q, np.int32),
+            np.broadcast_to(tables[:, :1], (B, Q)), slot)
+    assert ids.dtype == jnp.int32 and ids.shape == logits.shape[:-1]
+    rows = np.asarray(logits, np.float32).reshape(-1, BF16.vocab_size)
+    got = np.asarray(ids).reshape(-1)
+    assert [sample(r, temperature=0.0) for r in rows] == got.tolist()
+    if kind == "ties":
+        assert all((r == r.max()).sum() >= 2 for r in rows)
+        assert (got < 64).all()       # the first of the tied pair
+    else:
+        assert (got >= BF16.vocab_size - 8).all()
+        assert (got < BF16.vocab_size - 4).all()
+
+
+def test_all_greedy_decode_step_fetches_ids_not_logits(watch_device_get):
+    """Every lane greedy: a decode step brings max_batch ints to the
+    host and nothing else; the logits stay on the device."""
+    eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8, max_batch=4)
+    hs = [eng.add_request([1 + i, 2, 3], max_tokens=6) for i in range(3)]
+    eng.step()                      # prefills fetch their last row each
+    fetched = watch_device_get()
+    eng.step()
+    eng.step()
+    assert fetched == [eng.max_batch] * 2, fetched
+    assert max(fetched) < CFG.vocab_size
+    _drain(eng)
+    assert all(h.finish_reason == "length" for h in hs)
+
+
+SAMPLED = dict(prompt=[7, 3, 9, 4], max_tokens=10, temperature=0.8,
+               top_k=40, seed=1234)
+GREEDY = [dict(prompt=[20, 21, 22], max_tokens=9),
+          dict(prompt=[5, 6], max_tokens=12, temperature=0.7, top_k=1)]
+
+
+def _dense_reference(req):
+    """The request's tokens by plain teacher forcing: gpt.forward over
+    prompt + output so far, sampling.sample on the last row with the
+    request's own (seed, position) key. No engine, no pool, no batch."""
+    from ray_tpu.llm.sampling import sample
+    from ray_tpu.models.gpt import forward
+
+    seq = list(req["prompt"])
+    for _ in range(req["max_tokens"]):
+        logits = forward(PARAMS, jnp.asarray([seq], jnp.int32), CFG)
+        seq.append(sample(
+            np.asarray(logits[0, -1]), seed=req.get("seed", 0),
+            temperature=req.get("temperature", 0.0),
+            top_k=req.get("top_k", 0), position=len(seq)))
+    return seq[len(req["prompt"]):]
+
+
+def test_sampled_lane_beside_greedy_lanes_keeps_its_host_draws(
+        monkeypatch):
+    """A lane with a temperature in a batch of greedy lanes: its tokens
+    are sampling.sample's draws on its own logits rows under its own
+    (seed, position) keys, the same after a forced preemption; the
+    sampler is never asked about a greedy lane in a decode step; and the
+    greedy lanes' tokens do not depend on the sampled lane's presence."""
+    import ray_tpu.llm.engine as engine_mod
+
+    calls = []
+    real = engine_mod.sample
+
+    def spy(row, **kw):
+        tok = real(row, **kw)
+        calls.append((kw["seed"], kw["position"], kw["temperature"],
+                      kw["top_k"], tok))
+        return tok
+
+    monkeypatch.setattr(engine_mod, "sample", spy)
+    _, mixed = _run_once(64, [SAMPLED] + GREEDY)
+    lane, others = mixed[0], mixed[1:]
+    n0 = len(SAMPLED["prompt"])
+    mine = [c for c in calls if c[2] == 0.8]
+    # One host draw a token, keyed by the request's seed and position.
+    assert [c[:4] for c in mine] == [
+        (1234, n0 + j, 0.8, 40) for j in range(SAMPLED["max_tokens"])]
+    assert [c[4] for c in mine] == lane.output
+    # Greedy lanes reach the sampler for their first token only (the
+    # prefill's row); their decode tokens are the program's ids.
+    assert len(calls) - len(mine) == len(GREEDY)
+    assert lane.output == _dense_reference(SAMPLED)
+    for h, r in zip(others, GREEDY):
+        assert h.output == _dense_reference(r)
+
+    _, alone = _run_once(64, GREEDY)
+    assert [h.output for h in alone] == [h.output for h in others]
+
+    # capacity 3 blocks = 24 tokens for 18 + 12 + 14: someone is evicted
+    eng, tight = _run_once(4, [SAMPLED] + GREEDY)
+    assert sum(h.preemptions for h in tight) > 0
+    assert [h.output for h in tight] == [h.output for h in mixed]
+
+
+@pytest.mark.parametrize("reqs, host_lanes", [
+    (GREEDY, 0), ([SAMPLED] + GREEDY, 1)], ids=["all_greedy", "mixed"])
+def test_device_sampled_count_in_the_ring_and_stats(reqs, host_lanes):
+    """Each llm.step ring entry says how many lanes took their token
+    from the device; stats() totals tokens by where they were decided,
+    first tokens (sampled from the prefill's row) on the host's side."""
+    from ray_tpu.util import perfmodel
+
+    perfmodel.clear_device_steps()
+    eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8,
+                    max_batch=4, name="decided")
+    hs = [eng.add_request(**r) for r in reqs]
+    eng.step()
+    ring = perfmodel.device_step_events()
+    assert ring[-1]["lanes"] == len(reqs)
+    assert ring[-1][perfmodel.DEVICE_SAMPLED] == len(reqs) - host_lanes
+    _drain(eng)
+    ring = [e for e in perfmodel.device_step_events()
+            if e.get("deployment") == "decided"]
+    for e in ring:
+        live_host = sum(1 for h in hs[:host_lanes]
+                        if e["step"] < h.max_tokens)
+        assert e[perfmodel.DEVICE_SAMPLED] == e["lanes"] - live_host
+    s = eng.stats()
+    on_device = sum(e[perfmodel.DEVICE_SAMPLED] for e in ring)
+    assert s["tokens_decided_on_device"] == on_device
+    assert s["tokens_decided_on_device"] + s["tokens_decided_on_host"] \
+        == sum(len(h.output) for h in hs)
+    # The host's share: one first token a request, and every decode
+    # token of a lane with a temperature.
+    assert s["tokens_decided_on_host"] == len(reqs) + sum(
+        h.max_tokens - 1 for h in hs[:host_lanes])
+    perfmodel.clear_device_steps()

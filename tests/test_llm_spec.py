@@ -18,6 +18,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.llm import LLMEngine, PagedKVCache, PrefixPool  # noqa: E402
 from ray_tpu.llm.sampling import (  # noqa: E402
+    accept_draws,
     rejection_sample,
     sample,
     target_probs,
@@ -248,6 +249,79 @@ def test_verify_matches_sequential_sampling_under_temperature():
 def test_verify_requires_one_extra_row():
     with pytest.raises(ValueError):
         verify_tokens(_keyed_rows([1, 2]), [1, 2])
+
+
+@pytest.mark.parametrize("proposed", [
+    [3, 7, 1], [3, 2, 1], [8, 7, 1], [3, 7, 5], []],
+    ids=["all_match", "reject_second", "reject_first", "reject_last",
+         "no_proposals"])
+def test_accept_draws_on_ids_is_verify_tokens_on_the_rows(proposed):
+    """A greedy lane's acceptance over the verify program's ids is
+    verify_tokens over the rows those ids are the argmax of, and asks
+    for no draw past the first mismatch."""
+    target = [3, 7, 1, 9]
+    asked = []
+
+    def draw(j):
+        asked.append(j)
+        return target[j]
+
+    got = accept_draws(draw, proposed)
+    assert got == verify_tokens(_keyed_rows(target)[:len(proposed) + 1],
+                                proposed)
+    assert asked == list(range(got[0] + 1))
+
+
+# ---------------------------------------------------------------------------
+# The verify step decides greedy lanes' tokens on the device
+# ---------------------------------------------------------------------------
+def test_all_greedy_verify_step_fetches_ids_not_logits(watch_device_get):
+    """Every lane greedy: a verify step brings max_batch x (k + 1) ints
+    to the host and nothing else; the logits stay on the device."""
+    eng = LLMEngine(PARAMS, CFG, num_blocks=32, block_size=8, max_batch=4,
+                    speculative=NGRAM)
+    hs = [eng.add_request(LOOPY, max_tokens=24),
+          eng.add_request(UNIQ, max_tokens=12)]
+    eng.step()                      # prefills fetch their last row each
+    fetched = watch_device_get()
+    eng.step()
+    eng.step()
+    assert fetched == [eng.max_batch * (NGRAM["k"] + 1)] * 2, fetched
+    _drain(eng)
+    assert [h.finish_reason for h in hs] == ["length", "length"]
+    assert eng.stats()["spec"]["accepted"] > 0
+
+
+@pytest.mark.parametrize("host_lanes", [0, 1], ids=["all_greedy", "mixed"])
+def test_verify_step_counts_tokens_by_where_they_were_decided(host_lanes):
+    """Under speculation the ring's device_sampled counts greedy lanes,
+    stats() counts their tokens (several a step), and a lane with a
+    temperature beside them draws on the host exactly what it draws
+    without speculation."""
+    from ray_tpu.util import perfmodel
+
+    reqs = [dict(prompt=UNIQ, max_tokens=8, seed=5, temperature=0.9,
+                 top_k=40)][:host_lanes] + [
+        dict(prompt=LOOPY, max_tokens=16),
+        dict(prompt=[20, 21, 20, 21, 20], max_tokens=10)]
+    _, base = _run(None, reqs)
+    perfmodel.clear_device_steps()
+    eng, hs = _run(NGRAM, reqs)
+    assert [h.output for h in hs] == [h.output for h in base]
+    ring = [e for e in perfmodel.device_step_events()
+            if e["name"] == "llm.step" and e["lanes"]]
+    assert ring and ring[0]["lanes"] == len(reqs)
+    assert ring[0][perfmodel.DEVICE_SAMPLED] == len(reqs) - host_lanes
+    assert all(e[perfmodel.DEVICE_SAMPLED] <= e["lanes"] for e in ring)
+    s = eng.stats()
+    # First tokens come from the prefill's row, on the host; so does
+    # every token of the lane with a temperature.
+    assert s["tokens_decided_on_host"] == len(reqs) + sum(
+        h.max_tokens - 1 for h in hs[:host_lanes])
+    assert s["tokens_decided_on_device"] == sum(
+        h.max_tokens - 1 for h in hs[host_lanes:])
+    assert s["spec_tokens_per_step"] > 1.0
+    perfmodel.clear_device_steps()
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,8 @@ from ray_tpu.parallel import MeshSpec
 # GPT-2-small serving widths: 12 KV heads x head_dim 64, block 16.
 HKV, HD, BS, NB = 12, 64, 16, 4096
 MAX_NB = 1024 // BS
+# The chat cell's deployment: 64 lanes over 2,560 blocks.
+CELL_B, CELL_NB = 64, 2560
 
 
 @pytest.fixture(scope="module")
@@ -163,6 +165,24 @@ def test_flash_kernels_carry_their_names(one_chip, seq, names):
         assert name in text, name
 
 
+def _chat_cell_decode_lowered(one_chip):
+    """The engine's own decode program lowered at the chat cell's shapes
+    (GPT-2-small, 64 lanes, 2,560 blocks of 16)."""
+    from ray_tpu.llm.engine import _jit_programs
+
+    cfg = gpt.GPT2_SMALL
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
+    B, i32 = CELL_B, jnp.int32
+    pool = S((cfg.n_layer, HKV, CELL_NB, BS, HD), jnp.bfloat16)
+    decode = _jit_programs(cfg, None, None)[0]
+    return decode.lower(params, S((B,), i32), S((B,), i32), pool, pool,
+                        S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
+                        S((B,), i32))
+
+
 def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
                                                            as_tpu):
     """The engine's own decode program, lowered for the TPU at the chat
@@ -172,19 +192,8 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
     kernel by that operand and divides its seconds by calls x layers: a
     second Mosaic call on the pool, or a reshaped or stacked pool, would
     move ``paged_kernel_ms`` without moving the kernel."""
-    from ray_tpu.llm.engine import _jit_programs
-
-    cfg = gpt.GPT2_SMALL
-    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    params = jax.tree_util.tree_map(
-        lambda leaf: S(leaf.shape, leaf.dtype),
-        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
-    B, nb, i32 = 64, 2560, jnp.int32
-    pool = S((cfg.n_layer, HKV, nb, BS, HD), jnp.bfloat16)
-    decode = _jit_programs(cfg, None, None)[0]
-    text = decode.lower(params, S((B,), i32), S((B,), i32), pool, pool,
-                        S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
-                        S((B,), i32)).as_text()
+    cfg, nb = gpt.GPT2_SMALL, CELL_NB
+    text = _chat_cell_decode_lowered(one_chip).as_text()
     assert "module @jit_llm_decode " in text
     calls = [line for line in text.splitlines()
              if "@tpu_custom_call" in line]
@@ -193,3 +202,36 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
     assert f"tensor<{HKV}x{nb}x{BS}x{HD}xbf16>" in calls[0]
     assert f"tensor<{cfg.n_layer}x{HKV}x{nb}x{BS}x{HD}xbf16>" \
         not in calls[0]
+
+
+def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
+        one_chip, as_tpu):
+    """Compiled for the described v5e at the chat cell's shapes, the
+    decode program returns the lanes' argmax ids, ``s32[64]``, beside
+    its logits (the engine fetches those 64 ints and leaves the logits
+    on the device), and its one Mosaic call is still ``paged_decode``
+    on operands of the layer pool's shape ``[12,2560,16,64]``: what
+    benchmark/kernels.py's ``paged_operand`` looks for in a device
+    trace's op names. A refactor that moves either fails here, not in
+    the benchmark's traced run."""
+    import re
+
+    cfg = gpt.GPT2_SMALL
+    text = _chat_cell_decode_lowered(one_chip).compile().as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    entry = text[text.index("\nENTRY "):]
+    root = next(line for line in entry.splitlines()
+                if line.lstrip().startswith("ROOT "))
+    outputs = re.findall(r"(\w+\[[\d,]*\])", root.split(" tuple(")[0])
+    pools = f"bf16[{cfg.n_layer},{HKV},{CELL_NB},{BS},{HD}]"
+    assert outputs == [f"bf16[{CELL_B},{cfg.vocab_size}]",
+                       f"s32[{CELL_B}]", pools, pools], root[:300]
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "%paged_decode" in calls[0].split("=")[0]
+    operands = re.findall(r"%[\w.\-]+", calls[0].split("custom-call(")[1]
+                          .split(")")[0])
+    layer_pool = f"bf16[{HKV},{CELL_NB},{BS},{HD}]"
+    shapes = [re.search(rf"^\s*(?:ROOT )?{re.escape(o)} = (\S+?)\{{",
+                        text, re.M).group(1) for o in set(operands)]
+    assert shapes.count(layer_pool) == 2, shapes      # K's and V's pool
