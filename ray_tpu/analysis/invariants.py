@@ -330,7 +330,6 @@ REF_SITE_TABLES = (
 PERF_SITE_TABLES = (
     ("ray_tpu/llm/engine.py", "_step_perf", (
         "LLMEngine._run_prefills", "LLMEngine._run_decode",
-        "LLMEngine._run_verify",
         "LLMEngine.step", "LLMEngine._publish_gauges",
     ), "device-dispatch site bypasses the step accounting — the "
        "MFU/step-breakdown series go stale or misattribute the step "

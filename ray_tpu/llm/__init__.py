@@ -9,7 +9,9 @@ itself, built from the repo's own layers:
                             ref-counted prefix cache with COW)
   * ops/pallas/paged_decode.py — decode-attention kernel gathering K/V
                             through block tables (interpret mode on CPU)
-  * models/gpt.py        — forward_prefill / forward_decode modes
+  * models/gpt.py        — forward_step / forward_prefill_chunk: the
+                            training layer around a paged or a chunk
+                            attention sublayer
   * llm/engine.py        — Orca-style iteration-level scheduler
   * llm/spec.py          — speculative decoding (n-gram / small-draft
                             proposers verified in one paged-attention

@@ -43,8 +43,8 @@ Two admission-path optimizations (both on by default for serving):
 
 And one decode-path optimization (opt-in, ``speculative=...``):
 SPECULATIVE DECODING (llm/spec.py) — a proposer guesses up to k next
-tokens per sequence and ONE verify forward scores k+1 positions per
-lane through the generalized paged-attention kernel; the accepted
+tokens per sequence and the decode step scores k+1 rows per lane, not
+one, through the same program and paged-attention kernel; the accepted
 prefix plus one corrected/bonus token emit in a single step. Because
 sampling is keyed by (seed, position) alone, acceptance is an equality
 check against the replayed keyed draw — the output token stream is
@@ -65,8 +65,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..models.gpt import (GPTConfig, forward_decode, forward_prefill,
-                          forward_prefill_chunk, forward_verify)
+from ..models.gpt import GPTConfig, forward_prefill_chunk, forward_step
 from ..util import perfmodel, tracing
 from .kv_cache import PagedKVCache, PrefixPool
 from .sampling import accept_draws, is_greedy, sample, verify_tokens
@@ -128,36 +127,34 @@ class Request:
             yield tok
 
 
-# (program, pool spec, max_batch, q rows) -> "compiled" | "interpret";
-# see LLMEngine._paged_kernel_mode.
+# (pool spec, max_batch, q rows) -> "compiled" | "interpret"; see
+# LLMEngine._paged_kernel_mode.
 _KERNEL_MODES: dict = {}
 
 
 @functools.lru_cache(maxsize=32)
-def _jit_programs(cfg: GPTConfig, mesh, rules):
-    """Process-wide compiled-program cache. jax.jit's executable cache
-    is keyed by the wrapped callable's identity, so per-engine
-    ``jax.jit(partial(...))`` wrappers re-trace and re-compile the same
-    (cfg, shapes) program for every engine instance — per-block data
-    workers, serve redeploys, and tests all pay it. Engines with equal
-    (cfg, mesh, rules) share one set of wrappers instead; donation is
-    per-call, so two live engines sharing a program donate only their
-    own pools."""
+def _jit_programs(cfg: GPTConfig):
+    """Process-wide compiled-program cache: (decode step, prefill
+    chunk). jax.jit's executable cache is keyed by the wrapped
+    callable's identity, so per-engine ``jax.jit(partial(...))``
+    wrappers re-trace and re-compile the same (cfg, shapes) program for
+    every engine instance — per-block data workers, serve redeploys,
+    and tests all pay it. Engines with equal cfg share one pair of
+    wrappers instead; donation is per-call, so two live engines sharing
+    a program donate only their own pools."""
     def program(name, fn, **jit_kwargs):
         # The name is what a device trace's ``XLA Modules`` line shows
         # (``jit_<name>``); a functools.partial has none of its own.
         def named(*args):
-            return fn(*args, cfg=cfg, mesh=mesh, rules=rules)
+            return fn(*args, cfg=cfg)
 
         named.__name__ = named.__qualname__ = name
         return jax.jit(named, **jit_kwargs)
 
-    return (
-        program("llm_decode", forward_decode, donate_argnums=(3, 4)),
-        program("llm_prefill", forward_prefill),
-        program("llm_prefill_chunk", forward_prefill_chunk),
-        program("llm_verify", forward_verify, donate_argnums=(3, 4)),
-    )
+    # The step program is ``jit_llm_decode`` at every q (one row a lane,
+    # or 1 + k under speculation).
+    return (program("llm_decode", forward_step, donate_argnums=(3, 4)),
+            program("llm_prefill_chunk", forward_prefill_chunk))
 
 
 class LLMEngine:
@@ -171,8 +168,7 @@ class LLMEngine:
                  block_size: int = 16, max_batch: int = 8,
                  prefill_chunk_tokens: Optional[int] = None,
                  prefix_cache: bool = True,
-                 speculative=None,
-                 mesh=None, rules=None, name: str = "llm"):
+                 speculative=None, name: str = "llm"):
         self.cfg = cfg
         self.name = name
         self.max_batch = int(max_batch)
@@ -199,23 +195,28 @@ class LLMEngine:
         # Fixed decode shapes — one compile: batch padded to max_batch,
         # tables padded to the worst-case blocks/sequence. Prefill
         # recompiles per length bucket (lengths are padded to a block
-        # multiple, so at most max_seq/block_size variants). Programs
-        # come from the process-wide cache above when the key is
-        # hashable (unhashable mesh/rules fall back to per-instance).
+        # multiple, so at most max_seq/block_size variants), with or
+        # without a table. Programs come from the process-wide cache
+        # above.
         self.max_nb = self.kv.blocks_for_tokens(cfg.max_seq)
-        try:
-            progs = _jit_programs(cfg, mesh, rules)
-        except TypeError:
-            progs = _jit_programs.__wrapped__(cfg, mesh, rules)
-        self._decode, self._prefill, self._prefill_chunk, verify = progs
-        # Speculative decoding (llm/spec.py): when enabled, decode runs
-        # through ONE verify forward scoring k+1 positions per lane
-        # (fixed q shape, one compile) and the accepted prefix + one
-        # corrected/bonus token all land in a single step. None keeps
-        # the plain one-token decode path — zero cost when off.
+        self._decode, self._prefill_chunk = _jit_programs(cfg)
+        # Speculative decoding (llm/spec.py): when enabled, a decode
+        # step scores k+1 rows per lane (fixed q shape, one compile)
+        # and the accepted prefix + one corrected/bonus token all land
+        # in a single step. None is one row a lane and no proposer.
         self._spec = make_spec(speculative, target_params=params,
-                               target_cfg=cfg, mesh=mesh, rules=rules)
-        self._verify = verify if self._spec is not None else None
+                               target_cfg=cfg)
+        self._q_rows = 1 if self._spec is None else self._spec.k + 1
+        # Without a proposer every lane scores one row in every step:
+        # that q_lens lives on the device. Each host array handed to
+        # the program is a copy to the device and, beside the serving
+        # threads, a hand-over of the interpreter lock: one array more
+        # read 0.85 ms a step in the chat cell (PERF.md section 6, PR
+        # 30). Made like the pools, uncommitted: a committed input
+        # would commit the pools the step returns, and every program
+        # that takes them would compile once more.
+        self._one_row_each = None if self._spec is not None else \
+            jax.numpy.ones((self.max_batch,), np.int32)
 
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -259,7 +260,7 @@ class LLMEngine:
         # lanes, context tokens, decode tokens, lanes decided on the device
         self._counts = (0, 0, 0, 0)
         # Output tokens by where they were decided: the program's own
-        # argmax (greedy lanes of a decode or verify step) or a logits
+        # argmax (greedy lanes of a decode step) or a logits
         # row sampled on the host (lanes with a temperature, and every
         # first token after a prefill).
         self._decided = {"device": 0, "host": 0}
@@ -431,19 +432,16 @@ class LLMEngine:
         self._event(req, FINISHED)
         req.out_q.put(None)
 
-    def _sample(self, req: Request, logits_row) -> int:
-        """The next token at the request's current absolute position
-        (keyed by (seed, position) alone, so lanes sample in any
-        order)."""
-        return sample(logits_row, temperature=req.temperature,
-                      top_k=req.top_k, seed=req.seed,
-                      position=len(req.prompt) + len(req.output))
-
     def _sample_into(self, req: Request, logits_row) -> bool:
-        """Sample the next token on the host; emit it; apply stop
-        conditions. Returns True if the request finished."""
+        """Sample the next token on the host at the request's current
+        absolute position (keyed by (seed, position) alone, so lanes
+        sample in any order); emit it; apply stop conditions. Returns
+        True if the request finished."""
         self._decided["host"] += 1
-        return self._emit_token(req, self._sample(req, logits_row))
+        tok = sample(logits_row, temperature=req.temperature,
+                     top_k=req.top_k, seed=req.seed,
+                     position=len(req.prompt) + len(req.output))
+        return self._emit_token(req, tok)
 
     def _emit_token(self, req: Request, tok: int) -> bool:
         """Append an already-decided token (sampled, or an accepted/
@@ -513,34 +511,30 @@ class LLMEngine:
                     c = (c // bs) * bs or min(bs, rem)
                 if budget is not None:
                     budget -= c
-                pad = -c % bs or 0
-                whole = upto == 0 and c == T
-                if whole:
-                    # Cold whole-prompt prefill: the classic one-shot path.
-                    toks = np.zeros((1, T + pad), np.int32)
-                    toks[0, :T] = seq
-                else:
-                    # Incremental span [upto, upto+c) attending resident
-                    # context (earlier chunks and/or prefix-cache hits).
-                    toks = np.zeros((1, c + pad), np.int32)
-                    toks[0, :c] = seq[upto:upto + c]
-                    positions = np.minimum(
-                        upto + np.arange(c + pad, dtype=np.int32),
-                        self.cfg.max_seq - 1)
+                pad = -c % bs
+                # Span [upto, upto+c) attending resident context (earlier
+                # chunks and/or prefix-cache hits).
+                toks = np.zeros((1, c + pad), np.int32)
+                toks[0, :c] = seq[upto:upto + c]
+                positions = np.minimum(
+                    upto + np.arange(c + pad, dtype=np.int32),
+                    self.cfg.max_seq - 1)
+                if upto:
                     table = np.zeros((self.max_nb,), np.int32)
                     table[:len(req.block_table)] = req.block_table
+                else:
+                    # A span from the prompt's start has no context: an
+                    # empty table, and it attends over itself alone.
+                    table = np.zeros((0,), np.int32)
                 done = upto + c >= T
             # Dispatch-to-logits-ready is the device span (the pool
             # write is dispatched inside it and may still overlap the
             # host work that follows — deliberately uncounted, it hides
             # behind sampling).
             with perf.device("llm.prefill.device") as dev:
-                if whole:
-                    logits, k, v = self._prefill(self.params, toks)
-                else:
-                    logits, k, v = self._prefill_chunk(
-                        self.params, toks, positions, self.kv.k, self.kv.v,
-                        table, np.int32(upto))
+                logits, k, v = self._prefill_chunk(
+                    self.params, toks, positions, self.kv.k, self.kv.v,
+                    table, np.int32(upto))
                 # Export the chunk's cache: [L, 1, c, Hkv, d] -> pool
                 # blocks upto/bs onward (upto is block-aligned by
                 # construction).
@@ -597,8 +591,8 @@ class LLMEngine:
 
     def _ensure_slots(self, req: Request, n: int = 1) -> bool:
         """Guarantee req's next ``n`` tokens have WRITABLE pool slots
-        (n = 1 for plain decode; 1 + proposals for a speculative verify
-        row), preempting LIFO victims if the pool is dry. With the
+        (n = 1 for plain decode; 1 + proposals under speculation),
+        preempting LIFO victims if the pool is dry. With the
         prefix pool each touched block must also be private: a block
         with co-readers, or one whose registered span covers a write
         offset (the shared partially-filled tail a diverging request
@@ -642,8 +636,8 @@ class LLMEngine:
                 "verdict": rl["verdict"]}
 
     def _fetch_decisions(self, logits, ids, all_greedy: bool):
-        """What the host needs of a decode or verify program's outputs
-        to decide every lane's tokens, fetched once a step (the caller
+        """What the host needs of the decode program's outputs to
+        decide every lane's tokens, fetched once a step (the caller
         has blocked on ``ids``, so these are copies, not waits).
         Returns ``(ids, rows)``: the program's argmax ids as Python
         ints, which a greedy lane takes as they are; and the logits,
@@ -655,12 +649,43 @@ class LLMEngine:
                 None if all_greedy else jax.device_get(logits))
 
     def _run_decode(self):
+        """One decode step for every RUNNING sequence: Q rows a lane in
+        ONE batched paged-attention forward (models/gpt.py
+        forward_step), Q = 1 + k under speculation and 1 without. Row 0
+        of a lane feeds its current token and rows 1.. its proposals
+        (none without a proposer); all are written into their pool
+        slots and scored together. The longest proposal prefix equal to
+        the target's own keyed draws is accepted and one corrected/bonus
+        token follows it — several output tokens a step at exactly the
+        non-speculative token stream (the sampler is keyed by (seed,
+        position) alone, so acceptance is an equality check, not a new
+        random process), and with no proposals just the step's one
+        token. Rejected slots are rolled back with kv.truncate(); the
+        fixed [max_batch, Q] shapes compile ONCE, rows and lanes that
+        are not live padding onto scratch block 0."""
+        spec = self._spec
         perf = self._step_perf
         with perf.phase("llm.slots"):
             batch = [r for r in self._active if r.state == RUNNING]
+        props = dict.fromkeys([r.rid for r in batch], ())
+        if spec is not None:
+            with perf.phase("llm.decode.build"):
+                for req in batch:
+                    # Proposal budget: never past max_tokens (the final
+                    # token is sampled, not proposed), never past the
+                    # block span the admission check guaranteed, never
+                    # past max_seq positions.
+                    budget = min(
+                        req.max_tokens - len(req.output) - 1,
+                        len(req.prompt) + req.max_tokens
+                        - req.context_len - 1,
+                        self.cfg.max_seq - req.context_len - 1)
+                    props[req.rid] = spec.propose(
+                        req.rid, req.prompt + req.output, budget)
+        with perf.phase("llm.slots"):
             for req in list(batch):
                 if req.state == RUNNING:
-                    self._ensure_slots(req, 1)
+                    self._ensure_slots(req, 1 + len(props[req.rid]))
             # An ensure call may have preempted requests anywhere in the
             # batch (LIFO victims) — only still-RUNNING sequences decode.
             batch = [r for r in batch if r.state == RUNNING]
@@ -668,52 +693,74 @@ class LLMEngine:
             return
         with perf.phase("llm.decode.build"):
             t0 = time.time()
-            B = self.max_batch
+            B, n_live = self.max_batch, len(batch)
+            Q = self._q_rows
             bs = self.kv.block_size
-            tokens = np.zeros((B,), np.int32)
-            positions = np.zeros((B,), np.int32)
-            slot_blocks = np.zeros((B,), np.int32)
-            slot_offsets = np.zeros((B,), np.int32)
-            # Padded lanes: scratch block 0, context 1 — attention over
-            # the scratch block's garbage is masked-in but their logits
-            # are never sampled.
+            # Padded lanes, and rows past a lane's q_lens: scratch block
+            # 0, offset 0, position 0; a padded lane is one row of
+            # context 1 — attention over the scratch block's garbage is
+            # masked-in but its logits are never read.
+            tokens = np.zeros((B, Q), np.int32)
+            positions = np.zeros((B, Q), np.int32)
+            slot_blocks = np.zeros((B, Q), np.int32)
+            slot_offsets = np.zeros((B, Q), np.int32)
             context_lens = np.ones((B,), np.int32)
+            q_lens = np.ones((B,), np.int32)
             tables = np.zeros((B, self.max_nb), np.int32)
+            ctx, rows_per_lane = [], []
             for i, req in enumerate(batch):
                 slot = req.context_len
-                # Steady-state lanes feed their last sampled token; a
-                # FULL prefix-cache hit enters decode holding the last
-                # sequence position back (nothing was computed at
-                # admission), so its first step re-feeds that token —
+                table = req.block_table
+                p = props[req.rid]
+                n = 1 + len(p)
+                # Row 0: steady-state lanes feed their last sampled
+                # token; a FULL prefix-cache hit enters decode holding
+                # the last sequence position back (nothing was computed
+                # at admission), so its first step re-feeds that token —
                 # write-then-attend then recomputes its logits for the
-                # first sample.
-                tokens[i] = (req.prompt[slot] if slot < len(req.prompt)
-                             else req.output[slot - len(req.prompt)])
-                positions[i] = slot
-                slot_blocks[i] = req.block_table[slot // bs]
-                slot_offsets[i] = slot % bs
-                context_lens[i] = slot + 1
-                tables[i, :len(req.block_table)] = req.block_table
-            ctx = [r.context_len + 1 for r in batch]
-            cost = perfmodel.decode_step_cost(self.cfg, ctx)
-            on_device = sum(r.greedy for r in batch)
-            self._counts = (len(batch), sum(ctx), len(batch), on_device)
+                # first sample. Rows 1..n-1 feed the lane's proposals
+                # (the proposal budget keeps them inside max_seq).
+                tokens[i, 0] = (req.prompt[slot] if slot < len(req.prompt)
+                                else req.output[slot - len(req.prompt)])
+                if p:
+                    tokens[i, 1:n] = p
+                for j in range(n):
+                    positions[i, j] = slot + j
+                    slot_blocks[i, j] = table[(slot + j) // bs]
+                    slot_offsets[i, j] = (slot + j) % bs
+                context_lens[i] = slot + n
+                q_lens[i] = n
+                tables[i, :len(table)] = table
+                ctx.append(slot + n)
+                rows_per_lane.append(n)
+                if spec is not None:
+                    spec.verify(req.rid, len(p))
+            if spec is not None:
+                spec.verify_steps += 1
+            # Priced honestly about speculation's bet: every scored row
+            # burns its FLOPs whether or not its token is accepted.
+            cost = perfmodel.decode_step_cost(self.cfg, ctx, rows_per_lane)
+            greedy = [r.greedy for r in batch]
+            on_device = sum(greedy)
+            self._counts = (n_live, sum(ctx), sum(rows_per_lane), on_device)
         # block_until_ready on the ids bounds the DEVICE span (they are
         # the program's last output: the argmax of its logits); the
-        # fetch that follows is then a copy of max_batch ints, charged
-        # to the host, and the logits stay where they are unless a lane
-        # samples with a temperature.
+        # fetch that follows is then a copy of max_batch x Q ints,
+        # charged to the host, and the logits stay where they are unless
+        # a lane samples with a temperature.
         with perf.device("llm.decode.device") as dev:
             logits, ids, self.kv.k, self.kv.v = self._decode(
                 self.params, tokens, positions, self.kv.k, self.kv.v,
-                tables, context_lens, slot_blocks, slot_offsets)
+                tables, context_lens,
+                q_lens if spec is not None else self._one_row_each,
+                slot_blocks, slot_offsets)
             jax.block_until_ready(ids)
         device_s = dev.seconds
         perf.add_cost(cost)
         sampling, emitting = perf.phase("llm.sample"), perf.phase("llm.emit")
         with sampling:
             ids, rows = self._fetch_decisions(logits, ids,
-                                              on_device == len(batch))
+                                              on_device == n_live)
         # Lane by lane, each token out the moment it is decided: for a
         # greedy lane that is now, so for an all-greedy batch this loop
         # is emission alone (~1 ms for 64 lanes on the chip) and every
@@ -723,20 +770,57 @@ class LLMEngine:
         # (PERF.md section 6, PR 29). A lane with a temperature is
         # sampled in its turn, so its draw delays only the lanes after
         # it.
+        emitted_total = 0
+        decided = [0, 0]                # by the host, by the device
         for i, req in enumerate(batch):
-            req.context_len += 1
-            if req.greedy:
-                tok = ids[i]
+            p = props[req.rid]
+            slot = req.context_len
+            if greedy[i]:
+                # The target's greedy draw at row j is the id the
+                # program returned for it: acceptance is the same
+                # equality check, on integers.
+                n_acc, emitted = accept_draws(ids[i].__getitem__, p)
             else:
                 with sampling:
-                    tok = self._sample(req, rows[i])
+                    n_acc, emitted = verify_tokens(
+                        rows[i, :1 + len(p)], p,
+                        temperature=req.temperature, top_k=req.top_k,
+                        seed=req.seed,
+                        start_pos=len(req.prompt) + len(req.output))
             with emitting:
-                self._emit_token(req, tok)
-        self._decided["device"] += on_device
-        self._decided["host"] += len(batch) - on_device
+                if spec is not None:
+                    spec.accept(req.rid, n_acc, len(p), len(emitted))
+                    emitted_total += len(emitted)
+                for idx, tok in enumerate(emitted):
+                    # Bookkeeping BEFORE emitting: an accepted token IS
+                    # resident (its slot was written this step), the
+                    # final corrected/bonus token is NOT (its draw
+                    # replaced a rejected row / was never written) — so
+                    # a mid-stream finish registers exactly the resident
+                    # span.
+                    if idx < n_acc:
+                        req.context_len = slot + 2 + idx
+                    else:
+                        req.context_len = slot + 1 + n_acc
+                    decided[greedy[i]] += 1
+                    if self._emit_token(req, tok):
+                        break       # a stop token: the rest is dropped
+                if len(p) > n_acc:
+                    # Rejected slots past the accept cursor: any whole
+                    # blocks they spilled into go back to the pool (a
+                    # finished lane already released everything).
+                    freed = (self.kv.truncate(req.block_table,
+                                              req.context_len)
+                             if req.block_table else [])
+                    spec.rollback(req.rid, len(p) - n_acc, len(freed))
+        self._decided["host"] += decided[0]
+        self._decided["device"] += decided[1]
         dur = time.time() - t0
+        extra = {} if spec is None else {
+            "spec_proposed": sum(len(props[r.rid]) for r in batch),
+            "spec_emitted": emitted_total}
         with perf.phase("llm.trace"):
-            self._trace_decode_step(batch, t0, dur, cost, device_s)
+            self._trace_decode_step(batch, t0, dur, cost, device_s, **extra)
 
     def _trace_decode_step(self, batch, t0, dur, cost, device_s, **extra):
         """One decode-step slice per TRACED sequence in the batch: the
@@ -759,158 +843,11 @@ class LLMEngine:
             tracing.emit("llm.decode_step", req.trace_ctx, t0, dur,
                          dict(breakdown, rid=req.rid))
 
-    def _run_verify(self):
-        """Speculative decode step: propose up to k tokens per lane,
-        write current + proposals into their pool slots, and score all
-        q = k+1 positions in ONE batched paged-attention forward
-        (models/gpt.py forward_verify). verify_tokens then accepts the
-        longest proposal prefix matching the target's keyed draws and
-        emits one corrected/bonus token — several output tokens per
-        step at exactly the non-speculative token stream (the sampler
-        is keyed by (seed, position) alone, so acceptance is an
-        equality check, not a new random process). Rejected slots are
-        rolled back with kv.truncate(); the fixed [max_batch, k+1]
-        shapes compile ONCE, lanes with fewer live rows padding onto
-        scratch block 0 exactly like padded decode lanes."""
-        batch = [r for r in self._active if r.state == RUNNING]
-        if not batch:
-            return
-        spec = self._spec
-        perf = self._step_perf
-        props: Dict[int, List[int]] = {}
-        with perf.phase("llm.decode.build"):
-            for req in batch:
-                # Proposal budget: never past max_tokens (the final
-                # token is sampled, not proposed), never past the block
-                # span the admission check guaranteed, never past
-                # max_seq positions.
-                budget = min(
-                    req.max_tokens - len(req.output) - 1,
-                    len(req.prompt) + req.max_tokens - req.context_len - 1,
-                    self.cfg.max_seq - req.context_len - 1)
-                props[req.rid] = spec.propose(
-                    req.rid, req.prompt + req.output, budget)
-        with perf.phase("llm.slots"):
-            for req in list(batch):
-                if req.state == RUNNING:
-                    self._ensure_slots(req, 1 + len(props[req.rid]))
-            batch = [r for r in batch if r.state == RUNNING]
-        if not batch:
-            return
-        with perf.phase("llm.decode.build"):
-            t0 = time.time()
-            B = self.max_batch
-            Q = spec.k + 1
-            bs = self.kv.block_size
-            tokens = np.zeros((B, Q), np.int32)
-            positions = np.zeros((B, Q), np.int32)
-            slot_blocks = np.zeros((B, Q), np.int32)
-            slot_offsets = np.zeros((B, Q), np.int32)
-            context_lens = np.ones((B,), np.int32)
-            q_lens = np.ones((B,), np.int32)
-            tables = np.zeros((B, self.max_nb), np.int32)
-            for i, req in enumerate(batch):
-                slot = req.context_len
-                p = props[req.rid]
-                n = 1 + len(p)
-                # Row 0 feeds the last sampled token (a FULL
-                # prefix-cache hit re-feeds its held-back last position
-                # — the verify fast start: its FIRST step already
-                # carries proposals); rows 1..n-1 feed the proposals.
-                # Rows n..Q-1 are padding: scratch block 0, positions
-                # clipped in range — their logits are garbage and never
-                # read (q_lens masks them in the kernel and the host
-                # loop stops at n).
-                tokens[i, 0] = (req.prompt[slot] if slot < len(req.prompt)
-                                else req.output[slot - len(req.prompt)])
-                tokens[i, 1:n] = p
-                positions[i] = np.minimum(
-                    slot + np.arange(Q, dtype=np.int32),
-                    self.cfg.max_seq - 1)
-                for j in range(n):
-                    slot_blocks[i, j] = req.block_table[(slot + j) // bs]
-                    slot_offsets[i, j] = (slot + j) % bs
-                context_lens[i] = slot + n
-                q_lens[i] = n
-                tables[i, :len(req.block_table)] = req.block_table
-                spec.verify(req.rid, len(p))
-            spec.verify_steps += 1
-            # Verify pricing is honest about speculation's bet: k+1 rows
-            # of FLOPs are burned regardless of how many tokens are
-            # accepted.
-            ctx = [int(context_lens[i]) for i in range(len(batch))]
-            rows_per_lane = [int(q_lens[i]) for i in range(len(batch))]
-            cost = perfmodel.verify_step_cost(self.cfg, ctx, rows_per_lane)
-            on_device = sum(r.greedy for r in batch)
-            self._counts = (len(batch), sum(ctx), sum(rows_per_lane),
-                            on_device)
-        with perf.device("llm.decode.device") as dev:
-            logits, ids, self.kv.k, self.kv.v = self._verify(
-                self.params, tokens, positions, self.kv.k, self.kv.v,
-                tables, context_lens, q_lens, slot_blocks, slot_offsets)
-            jax.block_until_ready(ids)
-        device_s = dev.seconds
-        perf.add_cost(cost)
-        sampling, emitting = perf.phase("llm.sample"), perf.phase("llm.emit")
-        with sampling:
-            ids, rows = self._fetch_decisions(logits, ids,
-                                              on_device == len(batch))
-        emitted_total = 0
-        for i, req in enumerate(batch):     # lane by lane, as in decode
-            p = props[req.rid]
-            slot = req.context_len
-            with sampling:
-                if req.greedy:
-                    # The target's greedy draw at row j is the id the
-                    # program returned for it: acceptance is the same
-                    # equality check, on integers.
-                    n_acc, emitted = accept_draws(ids[i].__getitem__, p)
-                else:
-                    n_acc, emitted = verify_tokens(
-                        rows[i, :1 + len(p)], p,
-                        temperature=req.temperature, top_k=req.top_k,
-                        seed=req.seed,
-                        start_pos=len(req.prompt) + len(req.output))
-            with emitting:
-                spec.accept(req.rid, n_acc, len(p), len(emitted))
-                emitted_total += len(emitted)
-                n_out = len(req.output)
-                for idx, tok in enumerate(emitted):
-                    # Bookkeeping BEFORE emitting: an accepted token IS
-                    # resident (its slot was written this step), the
-                    # final corrected/bonus token is NOT (its draw
-                    # replaced a rejected row / was never written) — so
-                    # a mid-stream finish registers exactly the resident
-                    # span.
-                    if idx < n_acc:
-                        req.context_len = slot + 2 + idx
-                    else:
-                        req.context_len = slot + 1 + n_acc
-                    if self._emit_token(req, tok):
-                        break       # a stop token: the rest is dropped
-                self._decided["device" if req.greedy else "host"] += \
-                    len(req.output) - n_out
-                n_rej = len(p) - n_acc
-                if n_rej:
-                    # Rejected slots past the accept cursor: any whole
-                    # blocks they spilled into go back to the pool (a
-                    # finished lane already released everything).
-                    freed = (self.kv.truncate(req.block_table,
-                                              req.context_len)
-                             if req.block_table else [])
-                    spec.rollback(req.rid, n_rej, len(freed))
-        dur = time.time() - t0
-        with perf.phase("llm.trace"):
-            self._trace_decode_step(
-                batch, t0, dur, cost, device_s,
-                spec_proposed=int(sum(len(props[r.rid]) for r in batch)),
-                spec_emitted=emitted_total)
-
     def step(self) -> int:
-        """One scheduler iteration: admit -> prefill -> decode one token
-        for every running sequence (with speculation on, the decode is
-        a verify step that may emit several). Returns the number of
-        in-flight sequences after the step."""
+        """One scheduler iteration: admit -> prefill -> one decode step
+        for every running sequence (one token each; with speculation on
+        it may emit several). Returns the number of in-flight sequences
+        after the step."""
         with self._lock:
             perf = self._step_perf
             perf.begin()
@@ -927,10 +864,7 @@ class LLMEngine:
                 # read 0.0 for years.
                 util_hw = self.kv.utilization()
                 self._run_prefills()
-                if self._spec is not None:
-                    self._run_verify()
-                else:
-                    self._run_decode()
+                self._run_decode()
                 self._kv_util_peak = max(self._kv_util_peak, util_hw,
                                          self.kv.utilization())
                 self._steps += 1
@@ -971,16 +905,14 @@ class LLMEngine:
         return sum(n for _, n in self._token_times) / span
 
     def _paged_kernel_mode(self) -> str:
-        """"compiled" if the program this engine steps with (decode, or
-        verify under speculation) carries the Mosaic kernel, "interpret"
-        if the Pallas interpreter's plain ops stand in for it. Observed,
-        not inferred from the backend: the program is lowered once at
-        this engine's own shapes and its text searched for the kernel's
-        custom call."""
-        verify = self._spec is not None
-        prog = self._verify if verify else self._decode
-        B, q = self.max_batch, ((self._spec.k + 1,) if verify else ())
-        key = (prog, self._pool_spec, B, q)
+        """"compiled" if the decode program this engine steps with
+        carries the Mosaic kernel, "interpret" if the Pallas
+        interpreter's plain ops stand in for it. Observed, not inferred
+        from the backend: the program is lowered once at this engine's
+        own shapes and its text searched for the kernel's custom
+        call."""
+        B, Q = self.max_batch, self._q_rows
+        key = (self._decode, self._pool_spec, B, Q)
         mode = _KERNEL_MODES.get(key)
         if mode is None:
             def i32(*shape):
@@ -990,11 +922,10 @@ class LLMEngine:
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                                sharding=x.sharding),
                 self.params)
-            per_row = (i32(B),) * (2 if verify else 1)  # context[, q] lens
-            text = prog.lower(
-                params, i32(B, *q), i32(B, *q), self._pool_spec,
-                self._pool_spec, i32(B, self.max_nb), *per_row,
-                i32(B, *q), i32(B, *q)).as_text()
+            text = self._decode.lower(
+                params, i32(B, Q), i32(B, Q), self._pool_spec,
+                self._pool_spec, i32(B, self.max_nb), i32(B), i32(B),
+                i32(B, Q), i32(B, Q)).as_text()
             mode = _KERNEL_MODES[key] = (
                 "compiled" if "tpu_custom_call" in text else "interpret")
         return mode

@@ -140,7 +140,7 @@ class PagedKVCache:
     def write_prefill(self, k, v, block_ids: List[int]):
         """Scatter a prefill's K/V into the pool. k, v:
         ``[L, T, kv_heads, head_dim]`` (the stacked per-layer tensors
-        forward_prefill emits); the tail of the last block is zero-
+        forward_prefill_chunk emits); the tail of the last block is zero-
         padded (masked by context_lens at read time)."""
         L, T, hkv, d = k.shape
         nb = len(block_ids)

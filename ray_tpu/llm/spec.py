@@ -4,9 +4,9 @@ Reference layer map: the draft-then-verify scheme of Leviathan et al.
 ("Fast Inference from Transformers via Speculative Decoding") and the
 model-free self-speculation of lookahead/prompt-lookup decoding. The
 engine emits exactly one token per scheduler step per sequence; a
-proposer guesses the next k tokens for (almost) free and ONE verify
-forward (models/gpt.py forward_verify, k+1 query rows per sequence
-through the generalized paged-attention kernel) scores them all. The
+proposer guesses the next k tokens for (almost) free and ONE decode
+step (models/gpt.py forward_step, k+1 query rows per sequence through
+the paged-attention kernel) scores them all. The
 accepted prefix plus one corrected/bonus token land in a single step —
 decode throughput multiplies by the acceptance rate without changing a
 single output token.
@@ -109,11 +109,10 @@ class NgramProposer(Proposer):
 
 
 @functools.lru_cache(maxsize=16)
-def _draft_forward(cfg, mesh, rules):
+def _draft_forward(cfg):
     from ..models.gpt import forward
 
-    return jax.jit(functools.partial(forward, cfg=cfg, mesh=mesh,
-                                     rules=rules))
+    return jax.jit(functools.partial(forward, cfg=cfg))
 
 
 class DraftProposer(Proposer):
@@ -127,17 +126,14 @@ class DraftProposer(Proposer):
 
     name = "draft"
 
-    def __init__(self, params, cfg, mesh=None, rules=None):
+    def __init__(self, params, cfg):
         self.params = params
         self.cfg = cfg
         # Process-wide program share (same rationale as the engine's
-        # _jit_programs cache): drafts with equal (cfg, mesh, rules)
-        # reuse one jit wrapper, so per-engine proposers don't
-        # re-compile the forward per instance.
-        try:
-            self._fwd = _draft_forward(cfg, mesh, rules)
-        except TypeError:
-            self._fwd = _draft_forward.__wrapped__(cfg, mesh, rules)
+        # _jit_programs cache): drafts with equal cfg reuse one jit
+        # wrapper, so per-engine proposers don't re-compile the forward
+        # per instance.
+        self._fwd = _draft_forward(cfg)
 
     def _greedy_next(self, toks: List[int]) -> int:
         """One greedy draft token: pad-to-bucket forward, argmax on
@@ -283,8 +279,8 @@ class SpecDecoder:
         }
 
 
-def make_spec(speculative, *, target_params, target_cfg, mesh=None,
-              rules=None) -> Optional[SpecDecoder]:
+def make_spec(speculative, *, target_params,
+              target_cfg) -> Optional[SpecDecoder]:
     """Build the engine's SpecDecoder (or None when disabled)."""
     cfg = resolve_spec_config(speculative)
     if cfg is None:
@@ -301,5 +297,5 @@ def make_spec(speculative, *, target_params, target_cfg, mesh=None,
                 f"draft vocab {d_cfg.vocab_size} != target vocab "
                 f"{target_cfg.vocab_size} — proposals would be "
                 f"untranslatable token ids")
-        proposer = DraftProposer(d_params, d_cfg, mesh=mesh, rules=rules)
+        proposer = DraftProposer(d_params, d_cfg)
     return SpecDecoder(cfg, proposer)

@@ -91,7 +91,6 @@ class GPTConfig:
 # Tiny/small presets used by tests, bench and the graft entry.
 TINY = GPTConfig(vocab_size=512, max_seq=128, d_model=128, n_layer=2, n_head=4)
 GPT2_SMALL = GPTConfig()  # 124M
-GPT2_MEDIUM = GPTConfig(d_model=1024, n_layer=24, n_head=16)
 
 
 def param_axes(cfg: GPTConfig) -> dict:
@@ -157,14 +156,44 @@ def _constrain(x, logical, mesh, rules):
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def _block(x, p, cfg: GPTConfig, mesh, rules, mlp_remat: bool = False,
-           return_kv: bool = False):
-    """One transformer block. p: per-layer slice of the stacked block
-    params. ``return_kv=True`` additionally returns this layer's
-    (k, v) projections as [b, s, kv_heads, head_dim] — the prefill
-    path hands them to the paged KV pool (llm/kv_cache.py)."""
+def _block(x, p, cfg: GPTConfig, attend, mesh=None, rules=None,
+           mlp_remat: bool = False):
+    """The one transformer layer: norm -> attention sublayer -> residual
+    -> norm -> MLP -> residual, on activations [batch, rows, d_model]
+    (a decode step is rows = 1). p: per-layer slice of the stacked block
+    params. ``attend(h, p)`` is the mode's attention sublayer: it
+    returns the attention output already projected back to d_model and
+    whatever the mode carries out of the layer (nothing when training,
+    the updated layer pools in a decode step, a prefill chunk's K/V).
+    Returns (x, carried)."""
     dt = cfg.dtype
-    h = _layernorm(x, p["ln1"])
+    o, carried = attend(_layernorm(x, p["ln1"]), p)
+    x = x + _constrain(o, ("batch", "seq", "embed_act"), mesh, rules)
+
+    def mlp(xin):
+        h2 = _layernorm(xin, p["ln2"])
+        ff = jax.nn.gelu(jnp.einsum("bsm,mf->bsf", h2, p["wi"].astype(dt)))
+        ff = _constrain(ff, ("batch", "seq", "mlp"), mesh, rules)
+        return jnp.einsum("bsf,fm->bsm", ff, p["wm"].astype(dt))
+
+    if mlp_remat:
+        mlp = jax.checkpoint(mlp)
+    x = x + _constrain(mlp(x), ("batch", "seq", "embed_act"), mesh, rules)
+    return x, carried
+
+
+def _qkv(h, p, dt):
+    """This layer's projections of h [b, rows, m], each
+    [b, rows, heads (kv heads for k and v), head_dim]."""
+    return (jnp.einsum("bsm,mhd->bshd", h, p["wq"].astype(dt)),
+            jnp.einsum("bsm,mhd->bshd", h, p["wk"].astype(dt)),
+            jnp.einsum("bsm,mhd->bshd", h, p["wv"].astype(dt)))
+
+
+def _causal_sublayer(h, p, cfg: GPTConfig, mesh, rules):
+    """The training path's attention: causal over the rows themselves,
+    nothing carried out of the layer."""
+    dt = cfg.dtype
     if cfg.use_flash:
         # Heads-major end to end: q/k/v are emitted in the kernel's native
         # [b, heads, seq, d] layout, so there are no transposes around the
@@ -189,30 +218,11 @@ def _block(x, p, cfg: GPTConfig, mesh, rules, mlp_remat: bool = False,
             attn = jax.shard_map(attn, mesh=mesh, in_specs=(spec,) * 3,
                                  out_specs=spec, check_vma=False)
         o = attn(q, kk, v)
-        o = jnp.einsum("bhsd,hdm->bsm", o, p["wo"].astype(dt))
-        kv = (kk.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-    else:
-        q = jnp.einsum("bsm,mhd->bshd", h, p["wq"].astype(dt))
-        kk = jnp.einsum("bsm,mhd->bshd", h, p["wk"].astype(dt))
-        v = jnp.einsum("bsm,mhd->bshd", h, p["wv"].astype(dt))
-        q = _constrain(q, ("batch", "seq", "heads", None), mesh, rules)
-        o = causal_attention(q, kk, v)
-        o = jnp.einsum("bshd,hdm->bsm", o, p["wo"].astype(dt))
-        kv = (kk, v)
-    x = x + _constrain(o, ("batch", "seq", "embed_act"), mesh, rules)
-
-    def mlp(xin):
-        h2 = _layernorm(xin, p["ln2"])
-        ff = jax.nn.gelu(jnp.einsum("bsm,mf->bsf", h2, p["wi"].astype(dt)))
-        ff = _constrain(ff, ("batch", "seq", "mlp"), mesh, rules)
-        return jnp.einsum("bsf,fm->bsm", ff, p["wm"].astype(dt))
-
-    if mlp_remat:
-        mlp = jax.checkpoint(mlp)
-    x = x + _constrain(mlp(x), ("batch", "seq", "embed_act"), mesh, rules)
-    if return_kv:
-        return x, kv
-    return x
+        return jnp.einsum("bhsd,hdm->bsm", o, p["wo"].astype(dt)), None
+    q, kk, v = _qkv(h, p, dt)
+    q = _constrain(q, ("batch", "seq", "heads", None), mesh, rules)
+    o = causal_attention(q, kk, v)
+    return jnp.einsum("bshd,hdm->bsm", o, p["wo"].astype(dt)), None
 
 
 # Activation rules: batch over data axes, seq over sp, hidden replicated
@@ -220,12 +230,11 @@ def _block(x, p, cfg: GPTConfig, mesh, rules, mlp_remat: bool = False,
 ACT_RULES = {"embed_act": None}
 
 
-def forward(params, tokens, cfg: GPTConfig, mesh: Optional[Mesh] = None,
-            rules: Optional[dict] = None) -> jax.Array:
-    """tokens [b, s] int32 -> logits [b, s, vocab] (cfg.dtype)."""
-    rules = {**DEFAULT_RULES, **ACT_RULES, **(rules or {})}
+def _embed(params, tokens, positions, cfg: GPTConfig, mesh=None, rules=None):
+    """Token plus learned position embeddings, [batch, rows, d_model].
+    ``positions`` indexes wpe's rows: an int array that broadcasts
+    against ``tokens``, or a slice."""
     dt = cfg.dtype
-    b, s = tokens.shape
     wte = params["wte"].astype(dt)
     if mesh is not None:
         tokens = _constrain(tokens, ("batch", "seq"), mesh, rules)
@@ -234,95 +243,72 @@ def forward(params, tokens, cfg: GPTConfig, mesh: Optional[Mesh] = None,
         # without XLA's "involuntary full rematerialization" of the
         # activation; one hoisted all-gather of the (modest) table is the
         # cheap way to cross that sharding boundary. The logits matmul
-        # below still consumes the sharded table.
-        wte_lookup = jax.lax.with_sharding_constraint(
+        # in _head still consumes the sharded table.
+        wte = jax.lax.with_sharding_constraint(
             wte, NamedSharding(mesh, P(None, None)))
-    else:
-        wte_lookup = wte
-    x = wte_lookup[tokens] + params["wpe"].astype(dt)[:s]
-    x = _constrain(x, ("batch", "seq", "embed_act"), mesh, rules)
+    x = wte[tokens] + params["wpe"].astype(dt)[positions]
+    return _constrain(x, ("batch", "seq", "embed_act"), mesh, rules)
 
-    if cfg.remat and cfg.remat_policy == "mlp_only":
-        # Checkpoint lives INSIDE the block (around the MLP); the block
-        # itself — attention included — keeps its residuals.
-        block_fn = functools.partial(_block, cfg=cfg, mesh=mesh,
-                                     rules=rules, mlp_remat=True)
-    else:
-        block_fn = functools.partial(_block, cfg=cfg, mesh=mesh, rules=rules)
-        if cfg.remat:
-            cp = jax.checkpoint_policies
-            name = cfg.remat_policy
-            if name == "dots_flash" and not (
-                    cfg.use_flash and jax.default_backend() == "tpu"):
-                # Without the Pallas kernel (flash disabled, or a backend
-                # where flash_attention lowers the blockwise-jnp reference
-                # instead), dots_saveable would save O(seq^2) per-block
-                # score/probability matmul outputs; those paths need the
-                # aggressive policy.
-                name = "dots_no_batch"
-            policies = {
-                "dots_no_batch": cp.dots_with_no_batch_dims_saveable,
-                "dots": cp.dots_saveable,
-                "dots_flash": cp.save_from_both_policies(
-                    cp.dots_saveable, cp.save_only_these_names("flash")),
-            }
-            if name not in policies:
-                raise ValueError(
-                    f"remat_policy={cfg.remat_policy!r}; valid: "
-                    f"{sorted(policies)} or 'mlp_only'")
-            block_fn = jax.checkpoint(block_fn, policy=policies[name])
 
-    def scan_body(x, layer_params):
-        return block_fn(x, layer_params), None
-
-    x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+def _head(params, x, cfg: GPTConfig, mesh=None, rules=None):
+    """Final norm and the tied vocabulary head: [batch, rows, vocab]."""
     x = _layernorm(x, params["ln_f"])
-    logits = jnp.einsum("bsm,vm->bsv", x, params["wte"].astype(dt))
+    logits = jnp.einsum("bsm,vm->bsv", x, params["wte"].astype(cfg.dtype))
     return _constrain(logits, ("batch", "seq", "vocab"), mesh, rules)
 
 
+def forward(params, tokens, cfg: GPTConfig, mesh: Optional[Mesh] = None,
+            rules: Optional[dict] = None) -> jax.Array:
+    """tokens [b, s] int32 -> logits [b, s, vocab] (cfg.dtype)."""
+    rules = {**DEFAULT_RULES, **ACT_RULES, **(rules or {})}
+    x = _embed(params, tokens, slice(tokens.shape[1]), cfg, mesh, rules)
+    attend = functools.partial(_causal_sublayer, cfg=cfg, mesh=mesh,
+                               rules=rules)
+    block_fn = functools.partial(_block, cfg=cfg, attend=attend, mesh=mesh,
+                                 rules=rules)
+    if cfg.remat and cfg.remat_policy == "mlp_only":
+        # Checkpoint lives INSIDE the block (around the MLP); the block
+        # itself — attention included — keeps its residuals.
+        block_fn = functools.partial(block_fn, mlp_remat=True)
+    elif cfg.remat:
+        cp = jax.checkpoint_policies
+        name = cfg.remat_policy
+        if name == "dots_flash" and not (
+                cfg.use_flash and jax.default_backend() == "tpu"):
+            # Without the Pallas kernel (flash disabled, or a backend
+            # where flash_attention lowers the blockwise-jnp reference
+            # instead), dots_saveable would save O(seq^2) per-block
+            # score/probability matmul outputs; those paths need the
+            # aggressive policy.
+            name = "dots_no_batch"
+        policies = {
+            "dots_no_batch": cp.dots_with_no_batch_dims_saveable,
+            "dots": cp.dots_saveable,
+            "dots_flash": cp.save_from_both_policies(
+                cp.dots_saveable, cp.save_only_these_names("flash")),
+        }
+        if name not in policies:
+            raise ValueError(
+                f"remat_policy={cfg.remat_policy!r}; valid: "
+                f"{sorted(policies)} or 'mlp_only'")
+        block_fn = jax.checkpoint(block_fn, policy=policies[name])
+
+    x, _ = jax.lax.scan(block_fn, x, params["blocks"])
+    return _head(params, x, cfg, mesh, rules)
+
+
 # ---------------------------------------------------------------------------
-# Inference forward modes (continuous-batching engine, llm/engine.py).
+# Inference entry points (continuous-batching engine, llm/engine.py).
 #
 # Reference layer map: the reference runtime serves external inference
-# engines; here the decode path is native. forward_prefill runs the full
-# prompt once and EXPORTS each layer's K/V for the paged pool
-# (llm/kv_cache.py); forward_decode runs one token per sequence against
-# that pool through the paged-attention kernel (ops/pallas/paged_decode).
-# Both reuse the training blocks' params and parallelism rules verbatim —
-# there is no separate "inference model".
+# engines; here the decode path is native. forward_prefill_chunk runs a
+# span of a prompt against whatever of it already sits in the paged pool
+# (llm/kv_cache.py) and EXPORTS the span's K/V for it; forward_step runs
+# the next rows of every in-flight sequence against that pool through
+# the paged-attention kernel (ops/pallas/paged_decode). Both are the
+# training layer (_block) around an attention sublayer of their own, on
+# the training params — there is no separate "inference model".
 # ---------------------------------------------------------------------------
-
-
-def forward_prefill(params, tokens, cfg: GPTConfig,
-                    mesh: Optional[Mesh] = None,
-                    rules: Optional[dict] = None):
-    """Prompt pass that also exports the KV cache.
-
-    tokens [b, s] int32 -> (logits [b, s, vocab],
-                            k [L, b, s, kv_heads, head_dim], v like k).
-
-    Same math as forward() (so decode continues exactly the training
-    model's distribution); remat is ignored — inference keeps no
-    backward residuals worth trading compute for.
-    """
-    rules = {**DEFAULT_RULES, **ACT_RULES, **(rules or {})}
-    dt = cfg.dtype
-    b, s = tokens.shape
-    wte = params["wte"].astype(dt)
-    x = wte[tokens] + params["wpe"].astype(dt)[:s]
-    x = _constrain(x, ("batch", "seq", "embed_act"), mesh, rules)
-    block_fn = functools.partial(_block, cfg=cfg, mesh=mesh, rules=rules,
-                                 return_kv=True)
-
-    def scan_body(x, layer_params):
-        x, kv = block_fn(x, layer_params)
-        return x, kv
-
-    x, (k, v) = jax.lax.scan(scan_body, x, params["blocks"])
-    x = _layernorm(x, params["ln_f"])
-    logits = jnp.einsum("bsm,vm->bsv", x, params["wte"].astype(dt))
-    return logits, k, v
 
 
 def _greedy_ids(logits):
@@ -331,144 +317,83 @@ def _greedy_ids(logits):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
-def forward_decode(params, tokens, positions, k_pool, v_pool,
-                   block_tables, context_lens, slot_blocks, slot_offsets,
-                   cfg: GPTConfig, mesh: Optional[Mesh] = None,
-                   rules: Optional[dict] = None):
-    """One decode step for a batch of in-flight sequences.
+def _paged_sublayer(h, p, kp, vp, block_tables, context_lens, q_lens,
+                    slot_blocks, slot_offsets, cfg: GPTConfig):
+    """A decode step's attention over one layer's pools kp / vp
+    [kv_heads, num_blocks, block_size, head_dim]: project every row,
+    write its K/V at (slot_blocks, slot_offsets), THEN attend over the
+    lane's block table — the write-then-attend convention of
+    ops/pallas/paged_decode, so a row sees itself. Carries the updated
+    pools out of the layer."""
+    from ..ops.pallas.paged_decode import paged_verify_attention
 
-    Each lane projects its token's K/V, writes them into the paged pool
-    at (slot_blocks[lane], slot_offsets[lane]) — the cache write at the
-    sequence's positional offset — and THEN attends over its block table
-    (context_lens include the new token, so it sees itself; this is the
-    write-then-attend convention of ops/pallas/paged_decode).
+    dt = cfg.dtype
+    B, Q = h.shape[:2]
+    hkv, group = cfg.kv_heads, cfg.n_head // cfg.kv_heads
+    q, k_tok, v_tok = _qkv(h, p, dt)
+    # Real rows have unique slots by construction; padding rows and
+    # padded lanes collide on the scratch block, which is never read
+    # unmasked.
+    kp = kp.at[:, slot_blocks, slot_offsets].set(
+        k_tok.astype(kp.dtype).transpose(2, 0, 1, 3))
+    vp = vp.at[:, slot_blocks, slot_offsets].set(
+        v_tok.astype(vp.dtype).transpose(2, 0, 1, 3))
+    o = paged_verify_attention(
+        q.reshape(B, Q, hkv, group, cfg.head_dim), kp, vp,
+        block_tables, context_lens, q_lens)
+    o = jnp.einsum("bqhd,hdm->bqm",
+                   o.reshape(B, Q, cfg.n_head, cfg.head_dim),
+                   p["wo"].astype(dt))
+    return o, (kp, vp)
+
+
+def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
+                 context_lens, q_lens, slot_blocks, slot_offsets,
+                 cfg: GPTConfig):
+    """One decode step for a batch of in-flight sequences: ``q`` rows a
+    lane against the paged pool in ONE batched paged-attention forward.
+
+    Row 0 of a lane is its current (last sampled, not yet written) token
+    and rows 1..q-1 a proposed continuation (speculative verify); plain
+    decoding is q = 1. The engine decides the q_lens[lane] leading rows
+    (accepting or rejecting proposals); the pool writes of rejected rows
+    are rolled back host-side (kv_cache.truncate) — garbage beyond
+    context_lens is never attended.
 
     Args:
-      tokens / positions: [b] int32 — last sampled token + its absolute
-        position per lane. Padded lanes point at the pool's reserved
-        scratch block 0 with context_lens 1; their logits are garbage
-        the engine never samples.
+      tokens / positions: [b, q] int32 — each row's token and absolute
+        position. Rows past q_lens[lane], and every row of a padded
+        lane, are padding: their slots point at the pool's reserved
+        scratch block 0 and their logits are garbage the engine never
+        reads.
       k_pool / v_pool: [L, kv_heads, num_blocks, block_size, head_dim]
         (donate these in the caller's jit — steady-state decode then
         updates the pool in place).
       block_tables: [b, max_nb] int32, 0-padded.
-      slot_blocks / slot_offsets: [b] int32 — the pool block and
-        in-block offset of each lane's CURRENT token.
-
-    Returns (logits [b, vocab], ids [b] int32, k_pool, v_pool): ``ids``
-    is the argmax of each logits row (the first index of the maximum,
-    as ``numpy.argmax`` on the same row), so a greedy lane's token is
-    decided here and the host fetches ids, not logits.
-    """
-    from ..ops.pallas.paged_decode import paged_decode_attention
-
-    rules = {**DEFAULT_RULES, **ACT_RULES, **(rules or {})}
-    dt = cfg.dtype
-    B = tokens.shape[0]
-    hkv, group = cfg.kv_heads, cfg.n_head // cfg.kv_heads
-    wte = params["wte"].astype(dt)
-    x = wte[tokens] + params["wpe"].astype(dt)[positions]   # [b, m]
-
-    def scan_body(x, layer):
-        p, kp, vp = layer
-        h = _layernorm(x, p["ln1"])
-        q = jnp.einsum("bm,mhd->bhd", h, p["wq"].astype(dt))
-        k_tok = jnp.einsum("bm,mhd->bhd", h, p["wk"].astype(dt))
-        v_tok = jnp.einsum("bm,mhd->bhd", h, p["wv"].astype(dt))
-        # Cache write at the positional offset, before attending. Lanes
-        # have unique slots by construction (padded lanes collide on the
-        # scratch block, which is never read unmasked).
-        kp = kp.at[:, slot_blocks, slot_offsets].set(
-            k_tok.astype(kp.dtype).transpose(1, 0, 2))
-        vp = vp.at[:, slot_blocks, slot_offsets].set(
-            v_tok.astype(vp.dtype).transpose(1, 0, 2))
-        o = paged_decode_attention(
-            q.reshape(B, hkv, group, cfg.head_dim), kp, vp,
-            block_tables, context_lens)
-        o = jnp.einsum("bhd,hdm->bm",
-                       o.reshape(B, cfg.n_head, cfg.head_dim),
-                       p["wo"].astype(dt))
-        x = x + o
-        h2 = _layernorm(x, p["ln2"])
-        ff = jax.nn.gelu(jnp.einsum("bm,mf->bf", h2, p["wi"].astype(dt)))
-        x = x + jnp.einsum("bf,fm->bm", ff, p["wm"].astype(dt))
-        return x, (kp, vp)
-
-    x, (k_pool, v_pool) = jax.lax.scan(
-        scan_body, x, (params["blocks"], k_pool, v_pool))
-    x = _layernorm(x, params["ln_f"])
-    logits = jnp.einsum("bm,vm->bv", x, params["wte"].astype(dt))
-    return logits, _greedy_ids(logits), k_pool, v_pool
-
-
-def forward_verify(params, tokens, positions, k_pool, v_pool,
-                   block_tables, context_lens, q_lens, slot_blocks,
-                   slot_offsets, cfg: GPTConfig,
-                   mesh: Optional[Mesh] = None,
-                   rules: Optional[dict] = None):
-    """Speculative-verify step: score q = k+1 positions per sequence in
-    ONE batched paged-attention forward.
-
-    The decode step generalized to ``q`` query rows per lane: row 0 is
-    the lane's current (last sampled, not yet written) token and rows
-    1..q-1 its proposed continuation. Each layer projects all rows' K/V,
-    writes them into the paged pool at (slot_blocks, slot_offsets)
-    — write-then-attend, like decode — then attends with the q_len>1
-    kernel, causal within the speculative span. The engine samples the
-    q_lens[lane] leading logits rows to accept/reject proposals; the
-    pool writes of rejected rows are rolled back host-side
-    (kv_cache.truncate) — garbage beyond context_lens is never attended.
-
-    Args:
-      tokens / positions: [b, q] int32. Rows past q_lens[lane] are
-        padding: their slots point at the reserved scratch block 0 and
-        their logits are garbage the engine never reads.
       context_lens: [b] int32 — resident tokens per lane INCLUDING its
-        q_lens real rows.
+        q_lens real rows (1 for a padded lane).
       q_lens: [b] int32 — real rows per lane (1 = plain decode lane).
-      slot_blocks / slot_offsets: [b, q] int32 write sites per row.
+      slot_blocks / slot_offsets: [b, q] int32 — the pool block and
+        in-block offset each row is written at.
 
-    Returns (logits [b, q, vocab], ids [b, q] int32, k_pool, v_pool)
-    — donate the pools; ``ids`` as in forward_decode, row by row.
+    Returns (logits [b, q, vocab], ids [b, q] int32, k_pool, v_pool):
+    ``ids`` is the argmax of each logits row (the first index of the
+    maximum, as ``numpy.argmax`` on the same row), so a greedy lane's
+    tokens are decided here and the host fetches ids, not logits.
     """
-    from ..ops.pallas.paged_decode import paged_verify_attention
+    x = _embed(params, tokens, positions, cfg)
 
-    rules = {**DEFAULT_RULES, **ACT_RULES, **(rules or {})}
-    dt = cfg.dtype
-    B, Q = tokens.shape
-    hkv, group = cfg.kv_heads, cfg.n_head // cfg.kv_heads
-    wte = params["wte"].astype(dt)
-    x = wte[tokens] + params["wpe"].astype(dt)[positions]   # [b, q, m]
-
-    def scan_body(x, layer):
-        p, kp, vp = layer
-        h = _layernorm(x, p["ln1"])
-        q = jnp.einsum("bqm,mhd->bqhd", h, p["wq"].astype(dt))
-        k_tok = jnp.einsum("bqm,mhd->bqhd", h, p["wk"].astype(dt))
-        v_tok = jnp.einsum("bqm,mhd->bqhd", h, p["wv"].astype(dt))
-        # Cache write for every row before attending (real rows land in
-        # their sequence slots; padding rows collide harmlessly on the
-        # scratch block).
-        kp = kp.at[:, slot_blocks, slot_offsets].set(
-            k_tok.astype(kp.dtype).transpose(2, 0, 1, 3))
-        vp = vp.at[:, slot_blocks, slot_offsets].set(
-            v_tok.astype(vp.dtype).transpose(2, 0, 1, 3))
-        o = paged_verify_attention(
-            q.reshape(B, Q, hkv, group, cfg.head_dim), kp, vp,
-            block_tables, context_lens, q_lens)
-        o = jnp.einsum("bqhd,hdm->bqm",
-                       o.reshape(B, Q, cfg.n_head, cfg.head_dim),
-                       p["wo"].astype(dt))
-        x = x + o
-        h2 = _layernorm(x, p["ln2"])
-        ff = jax.nn.gelu(jnp.einsum("bqm,mf->bqf", h2, p["wi"].astype(dt)))
-        x = x + jnp.einsum("bqf,fm->bqm", ff, p["wm"].astype(dt))
-        return x, (kp, vp)
+    def layer(x, xs):
+        p, kp, vp = xs
+        attend = functools.partial(
+            _paged_sublayer, kp=kp, vp=vp, block_tables=block_tables,
+            context_lens=context_lens, q_lens=q_lens,
+            slot_blocks=slot_blocks, slot_offsets=slot_offsets, cfg=cfg)
+        return _block(x, p, cfg, attend)
 
     x, (k_pool, v_pool) = jax.lax.scan(
-        scan_body, x, (params["blocks"], k_pool, v_pool))
-    x = _layernorm(x, params["ln_f"])
-    logits = jnp.einsum("bqm,vm->bqv", x, params["wte"].astype(dt))
+        layer, x, (params["blocks"], k_pool, v_pool))
+    logits = _head(params, x, cfg)
     return logits, _greedy_ids(logits), k_pool, v_pool
 
 
@@ -505,11 +430,28 @@ def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _chunk_sublayer(h, p, kp, vp, block_table, ctx_len, cfg: GPTConfig):
+    """A prefill chunk's attention over [its sequence's pool context ++
+    the chunk]; the layer's pools kp / vp are read only. Carries the
+    chunk's own K/V out of the layer."""
+    dt = cfg.dtype
+    hkv, hd = cfg.kv_heads, cfg.head_dim
+    q, k_tok, v_tok = _qkv(h, p, dt)
+    # This sequence's pool context: [hkv, nb, BS, d] gathered by table,
+    # flattened to slot order [1, S, hkv, d] (S = 0 for an empty table).
+    k_ctx = kp[:, block_table]
+    v_ctx = vp[:, block_table]
+    nb, bs = k_ctx.shape[1], k_ctx.shape[2]
+    k_ctx = k_ctx.transpose(1, 2, 0, 3).reshape(1, nb * bs, hkv, hd)
+    v_ctx = v_ctx.transpose(1, 2, 0, 3).reshape(1, nb * bs, hkv, hd)
+    o = _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len)
+    return jnp.einsum("bshd,hdm->bsm", o, p["wo"].astype(dt)), (k_tok, v_tok)
+
+
 def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
-                          block_table, ctx_len, cfg: GPTConfig,
-                          mesh: Optional[Mesh] = None,
-                          rules: Optional[dict] = None):
-    """One chunk of an incremental prefill.
+                          block_table, ctx_len, cfg: GPTConfig):
+    """One span of a prompt: a whole cold prompt, or one chunk of an
+    incremental prefill.
 
     Sarathi-style chunked admission and prefix-cache hits both land
     here: run ``tokens`` [1, c] whose context — earlier prompt chunks,
@@ -519,50 +461,30 @@ def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
     Args:
       positions: [c] int32 absolute positions (ctx_len + arange(c),
         clipped to max_seq - 1 on the padded tail).
-      block_table: [max_nb] int32, 0-padded like decode's tables.
+      block_table: [nb] int32, 0-padded like decode's tables. It may be
+        EMPTY (nb = 0, with ctx_len 0): a span with no resident context
+        attends over itself alone, at the cost of a plain causal
+        forward, and gives the logits of ``forward`` on its tokens.
       ctx_len: scalar int32 — tokens already resident in the pool.
 
     The pools are READ-ONLY here (no donation): the chunk's K/V comes
-    back like forward_prefill's and the caller writes it into the pool
-    afterwards — shared blocks must be COW-split before that write.
+    back and the caller writes it into the pool afterwards — shared
+    blocks must be COW-split before that write.
 
     Returns (logits [1, c, vocab], k [L, 1, c, kv_heads, head_dim],
     v like k).
     """
-    rules = {**DEFAULT_RULES, **ACT_RULES, **(rules or {})}
-    dt = cfg.dtype
-    hkv, hd = cfg.kv_heads, cfg.head_dim
-    b, c = tokens.shape
-    wte = params["wte"].astype(dt)
-    x = wte[tokens] + params["wpe"].astype(dt)[positions]
-    x = _constrain(x, ("batch", "seq", "embed_act"), mesh, rules)
+    x = _embed(params, tokens, positions, cfg)
 
-    def scan_body(x, layer):
-        p, kp, vp = layer
-        h = _layernorm(x, p["ln1"])
-        q = jnp.einsum("bsm,mhd->bshd", h, p["wq"].astype(dt))
-        k_tok = jnp.einsum("bsm,mhd->bshd", h, p["wk"].astype(dt))
-        v_tok = jnp.einsum("bsm,mhd->bshd", h, p["wv"].astype(dt))
-        # This sequence's pool context: [hkv, max_nb, BS, d] gathered by
-        # table, flattened to slot order [1, S, hkv, d].
-        k_ctx = kp[:, block_table]
-        v_ctx = vp[:, block_table]
-        nb, bs = k_ctx.shape[1], k_ctx.shape[2]
-        k_ctx = k_ctx.transpose(1, 2, 0, 3).reshape(1, nb * bs, hkv, hd)
-        v_ctx = v_ctx.transpose(1, 2, 0, 3).reshape(1, nb * bs, hkv, hd)
-        o = _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len)
-        o = jnp.einsum("bshd,hdm->bsm", o, p["wo"].astype(dt))
-        x = x + o
-        h2 = _layernorm(x, p["ln2"])
-        ff = jax.nn.gelu(jnp.einsum("bsm,mf->bsf", h2, p["wi"].astype(dt)))
-        x = x + jnp.einsum("bsf,fm->bsm", ff, p["wm"].astype(dt))
-        return x, (k_tok, v_tok)
+    def layer(x, xs):
+        p, kp, vp = xs
+        attend = functools.partial(_chunk_sublayer, kp=kp, vp=vp,
+                                   block_table=block_table, ctx_len=ctx_len,
+                                   cfg=cfg)
+        return _block(x, p, cfg, attend)
 
-    x, (k, v) = jax.lax.scan(scan_body, x,
-                             (params["blocks"], k_pool, v_pool))
-    x = _layernorm(x, params["ln_f"])
-    logits = jnp.einsum("bsm,vm->bsv", x, params["wte"].astype(dt))
-    return logits, k, v
+    x, (k, v) = jax.lax.scan(layer, x, (params["blocks"], k_pool, v_pool))
+    return _head(params, x, cfg), k, v
 
 
 @jax.custom_vjp

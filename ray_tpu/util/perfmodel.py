@@ -162,39 +162,26 @@ def train_flops_per_token(cfg, seq: Optional[int] = None) -> float:
     return 6.0 * s["num_params"] + 12.0 * s["L"] * s["m"] * seq
 
 
-def decode_step_cost(cfg, context_lens: Sequence[int], *,
+def decode_step_cost(cfg, context_lens: Sequence[int],
+                     q_lens: Optional[Sequence[int]] = None, *,
                      kv_dtype_bytes: int = 2,
                      param_bytes: int = 4) -> StepCost:
-    """One decode step over a batch of lanes with the given attention
-    context lengths (tokens resident per sequence INCLUDING the one
-    being decoded). Weights stream from HBM once for the whole batch —
-    this is why batching lifts decode MFU."""
-    s = _shape(cfg)
-    total_ctx = float(sum(context_lens))
-    n = len(context_lens)
-    flops = 2.0 * s["matmul_weights"] * n + s["attn_per_ctx"] * total_ctx
-    kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
-    hbm = (s["num_params"] * param_bytes          # weight read, once
-           + total_ctx * kvb                      # KV read per lane
-           + n * kvb)                             # KV write (new token)
-    return StepCost(flops, hbm, n)
-
-
-def verify_step_cost(cfg, context_lens: Sequence[int],
-                     q_lens: Sequence[int], *,
-                     kv_dtype_bytes: int = 2,
-                     param_bytes: int = 4) -> StepCost:
-    """One speculative verify step: each lane scores ``q_lens[i]`` rows
-    (current token + its proposals) against ``context_lens[i]`` resident
-    tokens (INCLUDING those rows). Priced honestly: every scored row
-    costs full matmul + attention FLOPs whether its proposal is later
-    accepted or rolled back — speculation buys steps, not FLOPs. Row j
-    of lane i attends ctx - q + 1 + j keys (causal within the span), so
-    the per-lane attention term is q*ctx - q*(q-1)/2 contexts. HBM: one
+    """One decode step over a batch of lanes: lane i scores
+    ``q_lens[i]`` rows (its current token, plus its proposals under
+    speculation; one row a lane when omitted) against
+    ``context_lens[i]`` resident tokens (INCLUDING those rows). Weights
+    stream from HBM once for the whole batch — this is why batching
+    lifts decode MFU. Priced honestly: every scored row costs full
+    matmul + attention FLOPs whether its proposal is later accepted or
+    rolled back — speculation buys steps, not FLOPs. Row j of lane i
+    attends ctx - q + 1 + j keys (causal within the span), so the
+    per-lane attention term is q*ctx - q*(q-1)/2 contexts. HBM: one
     weight stream for the batch, one read of each lane's context KV
     (the kernel's block gather serves all rows in a lane), one write
     per scored row."""
     s = _shape(cfg)
+    if q_lens is None:
+        q_lens = [1] * len(context_lens)
     n_rows = float(sum(q_lens))
     attn_ctx = 0.0
     total_ctx = 0.0

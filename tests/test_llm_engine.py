@@ -458,37 +458,31 @@ def _tied_params(kind):
 
 
 @pytest.mark.parametrize("kind", ["ties", "tail"])
-@pytest.mark.parametrize("program", ["decode", "verify"])
-def test_program_ids_equal_host_greedy_sample_of_its_logits(program, kind):
-    """forward_decode / forward_verify through the engine's own jitted
-    programs: the ids output equals sample(row, temperature=0) on every
-    row of the logits output, where rows tie at the maximum and where
-    the maximum lies at the vocabulary's end."""
+@pytest.mark.parametrize("Q", [1, 3], ids=["decode", "verify"])
+def test_program_ids_equal_host_greedy_sample_of_its_logits(Q, kind):
+    """forward_step through the engine's own jitted program, at one row
+    a lane and at three: the ids output equals sample(row,
+    temperature=0) on every row of the logits output, where rows tie at
+    the maximum and where the maximum lies at the vocabulary's end."""
     from ray_tpu.llm.engine import _jit_programs
     from ray_tpu.llm.sampling import sample
 
     params = _tied_params(kind)
-    decode, _, _, verify = _jit_programs(BF16, None, None)
-    B, Q, bs, nb = 4, 3, 8, 16
+    decode = _jit_programs(BF16)[0]
+    B, bs, nb = 4, 8, 16
     max_nb = BF16.max_seq // bs
     rng = np.random.default_rng(1)
     pool = jnp.asarray(rng.standard_normal(
         (BF16.n_layer, BF16.kv_heads, nb, bs, BF16.head_dim)), BF16.dtype)
     tables = np.zeros((B, max_nb), np.int32)
     tables[:, 0] = 1 + np.arange(B)
-    if program == "decode":
-        slot = 2 + np.arange(B, dtype=np.int32)
-        logits, ids, _, _ = decode(
-            params, rng.integers(0, 60, (B,), dtype=np.int32), slot,
-            pool, pool + 0, tables, slot + 1, tables[:, 0], slot)
-    else:
-        slot = (1 + np.arange(B, dtype=np.int32))[:, None] + np.arange(
-            Q, dtype=np.int32)
-        logits, ids, _, _ = verify(
-            params, rng.integers(0, 60, (B, Q), dtype=np.int32), slot,
-            pool, pool + 0, tables, slot[:, -1] + 1,
-            np.full((B,), Q, np.int32),
-            np.broadcast_to(tables[:, :1], (B, Q)), slot)
+    slot = (1 + np.arange(B, dtype=np.int32))[:, None] + np.arange(
+        Q, dtype=np.int32)
+    logits, ids, _, _ = decode(
+        params, rng.integers(0, 60, (B, Q), dtype=np.int32), slot,
+        pool, pool + 0, tables, slot[:, -1] + 1,
+        np.full((B,), Q, np.int32),
+        np.broadcast_to(tables[:, :1], (B, Q)), slot)
     assert ids.dtype == jnp.int32 and ids.shape == logits.shape[:-1]
     rows = np.asarray(logits, np.float32).reshape(-1, BF16.vocab_size)
     got = np.asarray(ids).reshape(-1)
@@ -547,9 +541,10 @@ def test_sampled_lane_beside_greedy_lanes_keeps_its_host_draws(
     sampler is never asked about a greedy lane in a decode step; and the
     greedy lanes' tokens do not depend on the sampled lane's presence."""
     import ray_tpu.llm.engine as engine_mod
+    import ray_tpu.llm.sampling as sampling_mod
 
     calls = []
-    real = engine_mod.sample
+    real = sampling_mod.sample
 
     def spy(row, **kw):
         tok = real(row, **kw)
@@ -557,7 +552,10 @@ def test_sampled_lane_beside_greedy_lanes_keeps_its_host_draws(
                       kw["top_k"], tok))
         return tok
 
+    # The engine calls it for a prefill's first token; a decode step's
+    # sampled lane reaches it through sampling.verify_tokens.
     monkeypatch.setattr(engine_mod, "sample", spy)
+    monkeypatch.setattr(sampling_mod, "sample", spy)
     _, mixed = _run_once(64, [SAMPLED] + GREEDY)
     lane, others = mixed[0], mixed[1:]
     n0 = len(SAMPLED["prompt"])
@@ -615,3 +613,126 @@ def test_device_sampled_count_in_the_ring_and_stats(reqs, host_lanes):
     assert s["tokens_decided_on_host"] == len(reqs) + sum(
         h.max_tokens - 1 for h in hs[:host_lanes])
     perfmodel.clear_device_steps()
+
+
+# ---------------------------------------------------------------------------
+# One step function, one chunk function (models/gpt.py)
+# ---------------------------------------------------------------------------
+def test_step_at_one_row_is_row_zero_of_a_wider_step_with_padding_rows():
+    """forward_step at q = 1 against the same lanes at q = 3 with
+    q_lens = 1: rows 1 and 2 are padding (scratch block 0, masked by
+    q_lens), so row 0's logits and ids and the pool outside the scratch
+    block are what the one-row step gives. Plain decoding is the
+    speculative step's shape at one row, not a second program."""
+    from ray_tpu.llm.engine import _jit_programs
+
+    step = _jit_programs(CFG)[0]
+    B, bs, nb = 4, 8, 16
+    max_nb = CFG.max_seq // bs
+    rng = np.random.default_rng(7)
+    pool = jnp.asarray(rng.standard_normal(
+        (CFG.n_layer, CFG.kv_heads, nb, bs, CFG.head_dim)), CFG.dtype)
+    tables = np.zeros((B, max_nb), np.int32)
+    tables[:, 0] = 1 + np.arange(B)
+    tok = rng.integers(0, CFG.vocab_size, (B, 1), dtype=np.int32)
+    slot = (2 + np.arange(B, dtype=np.int32))[:, None]
+    ones = np.ones((B,), np.int32)
+
+    def run(q):
+        pad = np.zeros((B, q - 1), np.int32)
+        return step(PARAMS, np.hstack([tok, pad]),
+                    np.hstack([slot, slot + 1 + np.arange(q - 1)]),
+                    pool + 0, pool + 0, tables, slot[:, 0] + 1, ones,
+                    np.hstack([tables[:, :1], pad]),    # padding: block 0
+                    np.hstack([slot, pad]))
+
+    l1, i1, k1, v1 = run(1)
+    l3, i3, k3, v3 = run(3)
+    assert l1.shape == (B, 1, CFG.vocab_size) and i3.shape == (B, 3)
+    np.testing.assert_allclose(np.asarray(l3[:, 0]), np.asarray(l1[:, 0]),
+                               atol=2e-5, rtol=0)
+    assert np.asarray(i3[:, 0]).tolist() == np.asarray(i1[:, 0]).tolist()
+    for wide, one in ((k3, k1), (v3, v1)):
+        np.testing.assert_allclose(np.asarray(wide[:, :, 1:]),
+                                   np.asarray(one[:, :, 1:]), atol=1e-6,
+                                   rtol=0)
+    # and the one-row step did write each lane's token where it was told
+    assert not np.allclose(np.asarray(k1[:, :, 1:]),
+                           np.asarray(pool[:, :, 1:]))
+
+
+def test_chunk_with_an_empty_table_is_the_plain_causal_forward():
+    """forward_prefill_chunk with no block table and ctx_len 0 attends
+    over the span alone: its logits are gpt.forward's on the same
+    tokens, and logits and K/V are those of the same span under a
+    full-length table at ctx_len 0, whose pool slots are all masked."""
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.models.gpt import forward
+
+    chunk = _jit_programs(CFG)[1]
+    bs, nb, T = 8, 16, 24
+    rng = np.random.default_rng(3)
+    pool = jnp.asarray(rng.standard_normal(
+        (CFG.n_layer, CFG.kv_heads, nb, bs, CFG.head_dim)), CFG.dtype)
+    toks = rng.integers(0, CFG.vocab_size, (1, T), dtype=np.int32)
+    pos = np.arange(T, dtype=np.int32)
+    logits, k, v = chunk(PARAMS, toks, pos, pool, pool,
+                         np.zeros((0,), np.int32), np.int32(0))
+    assert k.shape == (CFG.n_layer, 1, T, CFG.kv_heads, CFG.head_dim)
+    np.testing.assert_allclose(
+        np.asarray(logits), np.asarray(forward(PARAMS, toks, CFG)),
+        atol=2e-5, rtol=0)
+    full = np.zeros((CFG.max_seq // bs,), np.int32)
+    full[:3] = [4, 5, 6]
+    for got, want in zip((logits, k, v),
+                         chunk(PARAMS, toks, pos, pool, pool, full,
+                               np.int32(0))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=0)
+
+
+def test_cold_whole_prompt_prefills_through_the_chunk_program_tableless():
+    """prefill_chunk_tokens=None, nothing cached: the whole prompt is
+    one span from position 0, dispatched to the chunk program with an
+    EMPTY table (there is no other prefill program), and the stream is
+    greedy decoding over gpt.forward."""
+    req = dict(prompt=[int(t) for t in
+                       np.random.default_rng(5).integers(1, 120, 19)],
+               max_tokens=7)
+    eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8, max_batch=4,
+                    prefill_chunk_tokens=None)
+    seen = []
+    real = eng._prefill_chunk
+
+    def spy(params, toks, positions, k, v, table, upto):
+        seen.append((toks.shape, table.shape, int(upto)))
+        return real(params, toks, positions, k, v, table, upto)
+
+    eng._prefill_chunk = spy
+    h = eng.add_request(**req)
+    _drain(eng)
+    assert seen == [((1, 24), (0,), 0)]      # 19 tokens padded to 3 blocks
+    assert h.output == _dense_reference(req)
+    # A second, longer prompt behind the cached first block resumes at
+    # the block boundary with the request's table, padded to max_nb.
+    seen.clear()
+    more = dict(prompt=req["prompt"][:8] + [3, 1, 4, 1, 5], max_tokens=3)
+    h2 = eng.add_request(**more)
+    _drain(eng)
+    assert seen == [((1, 8), (CFG.max_seq // 8,), 8)]
+    assert h2.cached_tokens == 8 and h2.output == _dense_reference(more)
+
+
+def test_a_decode_step_returns_the_pools_as_it_got_them_uncommitted():
+    """The q_lens the engine keeps on the device is made like the pools,
+    uncommitted. A committed input would commit the pools the step
+    returns, and every program that takes the pools (each chunk length,
+    the pool writes) would then compile a second time: 15-22 more
+    compilations in the chat cell's set-up when it was (PERF.md section
+    6, PR 30)."""
+    eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8, max_batch=4)
+    assert not eng.kv.k.committed and not eng._one_row_each.committed
+    eng.add_request([1, 2, 3], max_tokens=4)
+    eng.step()
+    eng.step()
+    assert not eng.kv.k.committed and not eng.kv.v.committed

@@ -26,6 +26,7 @@ from ray_tpu.llm.sampling import (  # noqa: E402
 )
 from ray_tpu.llm.spec import (  # noqa: E402
     NgramProposer,
+    Proposer,
     SpecConfig,
     resolve_spec_config,
 )
@@ -148,6 +149,76 @@ def test_preempt_resume_on_tight_pool_is_token_identical():
     assert eng.kv.num_free == eng.kv.capacity
 
 
+class _Replay(Proposer):
+    """Proposes what is known to come: the continuation of whichever
+    finished sequence ``tokens`` is a prefix of. Every proposal is
+    accepted, so a step's span is always the full k + 1 tokens."""
+
+    name = "replay"
+
+    def __init__(self, finished):
+        self.finished = [list(seq) for seq in finished]
+
+    def propose(self, tokens, k):
+        toks = list(tokens)
+        seq = next(s for s in self.finished if s[:len(toks)] == toks)
+        return seq[len(toks):len(toks) + k]
+
+
+def test_one_builder_with_and_without_a_proposer_emits_the_same_tokens():
+    """The decode step has one builder: rows a lane are 1 + proposals,
+    and with no proposer that is the one-token step. Greedy and seeded
+    sampled requests stream the same tokens with an n-gram proposer and
+    with none, on a roomy pool and through preemption and resume on a
+    tight one; and through a lane that finishes on a stop token in the
+    MIDDLE of an accepted span (what the step decided past the stop is
+    dropped, and only the resident span is released)."""
+    free = dict(prompt=LOOPY, max_tokens=16)
+    _, (probe,) = _run(None, [free])
+    # The token the greedy stream turns to a few tokens in.
+    stop = probe.output[-1]
+    at = probe.output.index(stop)
+    assert at >= 3, probe.output
+    reqs = [dict(free, stop_tokens=(stop,)),
+            dict(prompt=UNIQ, max_tokens=10, temperature=0.9, seed=5,
+                 top_k=16),
+            dict(prompt=[20, 21, 20, 21, 20], max_tokens=8)]
+    _, base = _run(None, reqs, num_blocks=64)
+    ref = [list(h.output) for h in base]
+    assert ref[0] == probe.output[:at + 1]
+    reasons = [h.finish_reason for h in base]
+    assert reasons == ["stop", "length", "length"]
+
+    for speculative, num_blocks in ((NGRAM, 64), (None, 5), (NGRAM, 5)):
+        eng, hs = _run(speculative, reqs, num_blocks=num_blocks)
+        assert [list(h.output) for h in hs] == ref, (speculative, num_blocks)
+        assert [h.finish_reason for h in hs] == reasons
+        if num_blocks == 5:
+            assert sum(h.preemptions for h in hs) > 0, \
+                "expected preemption on the tight pool"
+        assert eng.kv.num_free == eng.kv.capacity
+
+    # Every proposal right and k = 2: a decode step emits three tokens,
+    # output[0] is the prefill's, so the stop token at index `at` has a
+    # decided token behind it in its span unless it is the span's last.
+    assert (at - 1) % 3 != 2, at
+    eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8, max_batch=4,
+                    speculative={"mode": "ngram", "k": 2})
+    unstopped = [dict(r, stop_tokens=()) for r in reqs]
+    eng._spec.proposer = _Replay(
+        r["prompt"] + list(h.output)
+        for r, h in zip(reqs, _run(None, unstopped, num_blocks=64)[1]))
+    hs = [eng.add_request(**r) for r in reqs]
+    _drain(eng)
+    assert [list(h.output) for h in hs] == ref
+    assert [h.finish_reason for h in hs] == reasons
+    assert eng._spec.accept_rate() == 1.0
+    decided = sum(a["emitted"] for _, kind, a in eng._spec.events
+                  if kind == "accept" and a["rid"] == hs[0].rid)
+    assert decided > len(ref[0]) - 1       # something was dropped
+    assert eng.kv.num_free == eng.kv.capacity
+
+
 def test_spec_stats_and_gauge_surface():
     eng, _ = _run(NGRAM, [dict(prompt=LOOPY, max_tokens=16)])
     s = eng.stats()
@@ -155,7 +226,7 @@ def test_spec_stats_and_gauge_surface():
     assert s["spec_tokens_per_step"] >= 1.0
     assert s["spec"]["mode"] == "ngram"
     assert s["spec"]["verify_steps"] == eng._spec.verify_steps
-    # Read off the lowered verify program (the one this engine steps with).
+    # Read off the lowered decode program at this engine's k + 1 rows.
     assert s["paged_kernel"] == "interpret"
     kinds = {k for _, k, _ in eng._spec.events}
     assert {"propose", "verify", "accept"} <= kinds
@@ -163,7 +234,7 @@ def test_spec_stats_and_gauge_surface():
 
 def test_spec_off_has_no_spec_surface():
     eng, _ = _run(None, [dict(prompt=LOOPY, max_tokens=4)])
-    assert eng._spec is None and eng._verify is None
+    assert eng._spec is None
     assert "spec_accept_rate" not in eng.stats()
 
 

@@ -211,3 +211,27 @@ def test_shape_cache_handles_id_reuse():
         got = perfmodel._shape(cfg)["num_params"]
         assert got == cfg.num_params()
     assert perfmodel._shape(TINY)["num_params"] == TINY.num_params()
+
+
+def test_one_step_cost_prices_decode_and_verify_as_it_always_did():
+    """decode_step_cost is the one step cost. With q_lens omitted it
+    gives the figures it gave before the verify step's cost was folded
+    into it; with q_lens, that cost's own (both pinned from the tree
+    before the merge); and q_lens of ones is q_lens omitted."""
+    from ray_tpu.models.gpt import TINY
+
+    def figures(c):
+        return (c.flops, c.hbm_bytes, c.tokens)
+
+    ctx = [100, 200, 300]
+    assert figures(decode_step_cost(GPT2_SMALL, ctx)) == (
+        763527168.0, 519724032.0, 3)
+    assert figures(decode_step_cost(GPT2_SMALL, ctx, [1, 1, 1])) == (
+        763527168.0, 519724032.0, 3)
+    assert figures(decode_step_cost(GPT2_SMALL, [104, 205, 301],
+                                    [5, 5, 1])) == (
+        2785812480.0, 520387584.0, 11)
+    assert figures(decode_step_cost(TINY, [7, 33])) == (
+        1875968.0, 1946112.0, 2)
+    assert figures(decode_step_cost(TINY, [9, 36], q_lens=[3, 4])) == (
+        6588416.0, 1956352.0, 7)

@@ -407,21 +407,21 @@ def test_program_names_in_the_lowered_text():
     from ray_tpu.llm import kv_cache
     from ray_tpu.llm.engine import _jit_programs
 
-    decode, prefill, chunk, verify = _jit_programs(CFG, None, None)
+    decode, chunk = _jit_programs(CFG)
     bs, nb, B = 8, 16, 2
     max_nb = CFG.max_seq // bs
     pool = jnp.zeros((CFG.n_layer, CFG.kv_heads, nb, bs, CFG.head_dim),
                      CFG.dtype)
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    def step(q):
+        return _lowered(decode, PARAMS, i32(B, q), i32(B, q), pool, pool,
+                        i32(B, max_nb), i32(B) + q, i32(B) + q, i32(B, q),
+                        i32(B, q))
+
     texts = {
-        "llm_decode": _lowered(decode, PARAMS, i32(B), i32(B), pool, pool,
-                               i32(B, max_nb), i32(B) + 1, i32(B), i32(B)),
-        "llm_prefill": _lowered(prefill, PARAMS, i32(1, 16)),
+        "llm_decode": step(1),
         "llm_prefill_chunk": _lowered(chunk, PARAMS, i32(1, 8), i32(8),
                                       pool, pool, i32(max_nb), jnp.int32(8)),
-        "llm_verify": _lowered(verify, PARAMS, i32(B, 3), i32(B, 3), pool,
-                               pool, i32(B, max_nb), i32(B) + 3, i32(B) + 3,
-                               i32(B, 3), i32(B, 3)),
         "kv_scatter_blocks": _lowered(
             kv_cache.kv_scatter_blocks, pool, pool, pool[:, :, :2],
             pool[:, :, :2], i32(2)),
@@ -437,7 +437,9 @@ def test_program_names_in_the_lowered_text():
     # The paged kernel's name rides its call even where the
     # interpreter stands in for it.
     assert "paged_decode" in texts["llm_decode"]
-    assert "paged_decode" in texts["llm_verify"]
+    # Under speculation (three rows a lane) the step is the same program.
+    verify = step(3)
+    assert "module @jit_llm_decode " in verify and "paged_decode" in verify
 
 
 # ---------------------------------------------------------------------------

@@ -127,8 +127,8 @@ def test_flash_compiles_under_a_four_device_mesh(topo, as_tpu):
 
 
 def test_whole_decode_step_compiles_with_the_kernel(one_chip, as_tpu):
-    """forward_decode at max_batch 32 through the engine's own entry:
-    the kernel must be in the program, compiled, not interpreted."""
+    """forward_step at max_batch 32, one row a lane: the kernel must be
+    in the program, compiled, not interpreted."""
     cfg = gpt.GPT2_SMALL
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     params = jax.tree_util.tree_map(
@@ -136,11 +136,11 @@ def test_whole_decode_step_compiles_with_the_kernel(one_chip, as_tpu):
         jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
     pool = S((cfg.n_layer, HKV, NB, BS, HD), jnp.bfloat16)
     B, i32 = 32, jnp.int32
-    step = jax.jit(functools.partial(gpt.forward_decode, cfg=cfg),
+    step = jax.jit(functools.partial(gpt.forward_step, cfg=cfg),
                    donate_argnums=(3, 4))
-    c = step.lower(params, S((B,), i32), S((B,), i32), pool, pool,
+    c = step.lower(params, S((B, 1), i32), S((B, 1), i32), pool, pool,
                    S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
-                   S((B,), i32)).compile()
+                   S((B, 1), i32), S((B, 1), i32)).compile()
     assert "tpu_custom_call" in c.as_text()
 
 
@@ -167,7 +167,7 @@ def test_flash_kernels_carry_their_names(one_chip, seq, names):
 
 def _chat_cell_decode_lowered(one_chip):
     """The engine's own decode program lowered at the chat cell's shapes
-    (GPT-2-small, 64 lanes, 2,560 blocks of 16)."""
+    (GPT-2-small, 64 lanes of one row, 2,560 blocks of 16)."""
     from ray_tpu.llm.engine import _jit_programs
 
     cfg = gpt.GPT2_SMALL
@@ -177,10 +177,10 @@ def _chat_cell_decode_lowered(one_chip):
         jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
     B, i32 = CELL_B, jnp.int32
     pool = S((cfg.n_layer, HKV, CELL_NB, BS, HD), jnp.bfloat16)
-    decode = _jit_programs(cfg, None, None)[0]
-    return decode.lower(params, S((B,), i32), S((B,), i32), pool, pool,
+    decode = _jit_programs(cfg)[0]
+    return decode.lower(params, S((B, 1), i32), S((B, 1), i32), pool, pool,
                         S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
-                        S((B,), i32))
+                        S((B, 1), i32), S((B, 1), i32))
 
 
 def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
@@ -204,10 +204,19 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
         not in calls[0]
 
 
+@pytest.fixture(scope="module")
+def chat_decode_hlo(one_chip):
+    """The chat cell's decode program compiled for the described v5e,
+    as text (one ~4 s compile for the tests that read it)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return _chat_cell_decode_lowered(one_chip).compile().as_text()
+
+
 def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
-        one_chip, as_tpu):
+        chat_decode_hlo):
     """Compiled for the described v5e at the chat cell's shapes, the
-    decode program returns the lanes' argmax ids, ``s32[64]``, beside
+    decode program returns the lanes' argmax ids, ``s32[64,1]``, beside
     its logits (the engine fetches those 64 ints and leaves the logits
     on the device), and its one Mosaic call is still ``paged_decode``
     on operands of the layer pool's shape ``[12,2560,16,64]``: what
@@ -217,15 +226,15 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     import re
 
     cfg = gpt.GPT2_SMALL
-    text = _chat_cell_decode_lowered(one_chip).compile().as_text()
+    text = chat_decode_hlo
     assert text.startswith("HloModule jit_llm_decode")
     entry = text[text.index("\nENTRY "):]
     root = next(line for line in entry.splitlines()
                 if line.lstrip().startswith("ROOT "))
     outputs = re.findall(r"(\w+\[[\d,]*\])", root.split(" tuple(")[0])
     pools = f"bf16[{cfg.n_layer},{HKV},{CELL_NB},{BS},{HD}]"
-    assert outputs == [f"bf16[{CELL_B},{cfg.vocab_size}]",
-                       f"s32[{CELL_B}]", pools, pools], root[:300]
+    assert outputs == [f"bf16[{CELL_B},1,{cfg.vocab_size}]",
+                       f"s32[{CELL_B},1]", pools, pools], root[:300]
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1 and "%paged_decode" in calls[0].split("=")[0]
@@ -235,3 +244,22 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     shapes = [re.search(rf"^\s*(?:ROOT )?{re.escape(o)} = (\S+?)\{{",
                         text, re.M).group(1) for o in set(operands)]
     assert shapes.count(layer_pool) == 2, shapes      # K's and V's pool
+
+
+def test_compiled_decode_program_copies_the_pool_no_more_than_it_did(
+        chat_decode_hlo):
+    """The pin ROADMAP A1 will tighten to zero: compiled for the
+    described v5e at the chat cell's shapes, the decode program (one
+    row a lane through forward_step) holds no more whole-pool ``copy``
+    instructions than the one-token decode forward's program did before
+    it and the verify forward became one, eight (``[1,12,2560,16,64]`` x 4, ``[12,2560,16,64]`` x
+    2, ``[12,12,2560,16,64]`` x 2: the layout conversions around the
+    Mosaic call and the scan's stacked outputs; PERF.md section 5 has
+    them at 41.3 of the program's 54.5 ms on the chip). A compiler's
+    count, not a time."""
+    import re
+
+    copies = [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = (\S+?)\{\S* copy\(", chat_decode_hlo,
+        re.M) if m.group(1).endswith(f"{HKV},{CELL_NB},{BS},{HD}]")]
+    assert 0 < len(copies) <= 8, copies
