@@ -3,10 +3,11 @@
 Reference layer map: this is the TPU-native analogue of vLLM's
 PagedAttention block manager (Kwon et al., SOSP '23) sitting where the
 reference runtime would hold framework-external model state. KV for
-every in-flight sequence lives in ONE device-resident pool per layer —
-``[kv_heads, num_blocks, block_size, head_dim]`` stacked over layers —
-and a sequence owns an ordered list of block ids (its *block table*)
-rather than a contiguous region. Consequences:
+every in-flight sequence lives in ONE device-resident pool, token-major:
+``[layers, num_blocks, block_size, kv_heads * head_dim]``, a token's K
+(or V) of every head in one row. A sequence owns an ordered list of
+block ids (its *block table*) rather than a contiguous region.
+Consequences:
 
   * admission/finish/preempt are allocator ops (list pushes), never
     device copies or compactions;
@@ -22,6 +23,16 @@ masked writes need no bounds branch. The allocator never hands it out.
 
 Writes are functional jnp scatters under jit with the pool donated —
 XLA aliases the buffers so steady-state decode does not copy the pool.
+The shape is what lets it: the indexed dimensions (layer, block, offset)
+are the major ones, which is how XLA's scatter wants them, and a row of
+``kv_heads * head_dim`` is a whole number of 128-lane tiles at every
+served width (768 = 6 x 128 at GPT-2-small), so the TPU runtime keeps
+the array at rest row-major and unpadded and a write lands where it
+is. (Head-major, ``[..., kv_heads, num_blocks, block_size, head_dim]``
+with a 64-wide minor dimension, the runtime kept it in a compact layout
+that no reader or writer wanted, and every program converted the whole
+pool there and back: PERF.md section 6, PR 31.) Only the paged kernel
+still reads a layer head-major (models/gpt.py makes that view).
 """
 
 from __future__ import annotations
@@ -42,18 +53,18 @@ from ..models.gpt import GPTConfig
 # function: ``jit_kv_scatter_blocks``, ``jit_kv_copy_block``.
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def kv_scatter_blocks(k_pool, v_pool, k_blocks, v_blocks, ids):
-    """Write whole blocks: pools [L, Hkv, NB, BS, d], blocks
-    [L, Hkv, nb, BS, d], ids [nb] int32."""
-    return (k_pool.at[:, :, ids].set(k_blocks),
-            v_pool.at[:, :, ids].set(v_blocks))
+    """Write whole blocks: pools [L, NB, BS, Hkv * d], blocks
+    [L, nb, BS, Hkv * d], ids [nb] int32."""
+    return (k_pool.at[:, ids].set(k_blocks),
+            v_pool.at[:, ids].set(v_blocks))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def kv_copy_block(k_pool, v_pool, src, dst):
     """Copy-on-write split: duplicate one block's K/V (src/dst are
     traced scalars, so every split shares one compile)."""
-    return (k_pool.at[:, :, dst].set(k_pool[:, :, src]),
-            v_pool.at[:, :, dst].set(v_pool[:, :, src]))
+    return (k_pool.at[:, dst].set(k_pool[:, src]),
+            v_pool.at[:, dst].set(v_pool[:, src]))
 
 
 class PagedKVCache:
@@ -69,8 +80,8 @@ class PagedKVCache:
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.dtype = dtype if dtype is not None else cfg.dtype
-        shape = (cfg.n_layer, cfg.kv_heads, num_blocks, block_size,
-                 cfg.head_dim)
+        shape = (cfg.n_layer, num_blocks, block_size,
+                 cfg.kv_heads * cfg.head_dim)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
         # LIFO free list (hot blocks rotate), block 0 reserved.
@@ -140,8 +151,10 @@ class PagedKVCache:
     def write_prefill(self, k, v, block_ids: List[int]):
         """Scatter a prefill's K/V into the pool. k, v:
         ``[L, T, kv_heads, head_dim]`` (the stacked per-layer tensors
-        forward_prefill_chunk emits); the tail of the last block is zero-
-        padded (masked by context_lens at read time)."""
+        forward_prefill_chunk emits), which is the pool's own order: a
+        reshape away from ``[L, nb, block_size, kv_heads * head_dim]``.
+        The tail of the last block is zero-padded (masked by
+        context_lens at read time)."""
         L, T, hkv, d = k.shape
         nb = len(block_ids)
         pad = nb * self.block_size - T
@@ -150,11 +163,8 @@ class PagedKVCache:
         if pad:
             k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
             v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        # [L, T', Hkv, d] -> [L, Hkv, nb, BS, d]
-        kb = k.reshape(L, nb, self.block_size, hkv, d).transpose(
-            0, 3, 1, 2, 4).astype(self.dtype)
-        vb = v.reshape(L, nb, self.block_size, hkv, d).transpose(
-            0, 3, 1, 2, 4).astype(self.dtype)
+        kb = k.reshape(L, nb, self.block_size, hkv * d).astype(self.dtype)
+        vb = v.reshape(L, nb, self.block_size, hkv * d).astype(self.dtype)
         ids = jnp.asarray(block_ids, jnp.int32)
         self.k, self.v = kv_scatter_blocks(self.k, self.v, kb, vb, ids)
 
@@ -162,11 +172,12 @@ class PagedKVCache:
         """Read back ``length`` tokens' K/V as ``[L, length, Hkv, d]``
         (tests / debugging — the decode path never materializes this)."""
         ids = jnp.asarray(block_ids, jnp.int32)
-        k = jnp.take(self.k, ids, axis=2)   # [L, Hkv, nb, BS, d]
-        v = jnp.take(self.v, ids, axis=2)
-        L, hkv, nb, bs, d = k.shape
-        k = k.transpose(0, 2, 3, 1, 4).reshape(L, nb * bs, hkv, d)
-        v = v.transpose(0, 2, 3, 1, 4).reshape(L, nb * bs, hkv, d)
+        hkv, d = self.cfg.kv_heads, self.cfg.head_dim
+        k = jnp.take(self.k, ids, axis=1)   # [L, nb, BS, Hkv * d]
+        v = jnp.take(self.v, ids, axis=1)
+        L, nb, bs, _ = k.shape
+        k = k.reshape(L, nb * bs, hkv, d)
+        v = v.reshape(L, nb * bs, hkv, d)
         return k[:, :length], v[:, :length]
 
 

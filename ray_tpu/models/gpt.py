@@ -317,14 +317,31 @@ def _greedy_ids(logits):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
-def _paged_sublayer(h, p, kp, vp, block_tables, context_lens, q_lens,
-                    slot_blocks, slot_offsets, cfg: GPTConfig):
-    """A decode step's attention over one layer's pools kp / vp
-    [kv_heads, num_blocks, block_size, head_dim]: project every row,
-    write its K/V at (slot_blocks, slot_offsets), THEN attend over the
-    lane's block table — the write-then-attend convention of
-    ops/pallas/paged_decode, so a row sees itself. Carries the updated
-    pools out of the layer."""
+def _head_major(pool, layer, cfg: GPTConfig):
+    """Layer ``layer`` of a stored pool [L, num_blocks, block_size,
+    kv_heads * head_dim] as the paged kernel takes it,
+    [kv_heads, num_blocks, block_size, head_dim]: each head's lanes of
+    every row, sliced from the stacked pool and joined head-major. The
+    one pass over a layer's pool that a decode step makes: for the TPU,
+    XLA writes each slice in place into the one operand buffer (one
+    fusion a head), where a reshape and a transpose of ``pool[layer]``
+    cost it three passes (PERF.md section 6, PR 31)."""
+    num_blocks, block_size = pool.shape[1:3]
+    d = cfg.head_dim
+    return jnp.concatenate([
+        jax.lax.dynamic_slice(pool, (layer, 0, 0, h * d),
+                              (1, num_blocks, block_size, d))
+        for h in range(cfg.kv_heads)])
+
+
+def _paged_sublayer(h, p, layer, k_pool, v_pool, block_tables, context_lens,
+                    q_lens, slot_blocks, slot_offsets, cfg: GPTConfig):
+    """A decode step's attention at layer ``layer`` of the stored pools
+    [L, num_blocks, block_size, kv_heads * head_dim]: project every row,
+    write its K/V in place at (layer, slot_blocks, slot_offsets), THEN
+    attend over the lane's block table in the written pool — the
+    write-then-attend convention of ops/pallas/paged_decode, so a row
+    sees itself. Carries the updated pools out of the layer."""
     from ..ops.pallas.paged_decode import paged_verify_attention
 
     dt = cfg.dtype
@@ -334,17 +351,18 @@ def _paged_sublayer(h, p, kp, vp, block_tables, context_lens, q_lens,
     # Real rows have unique slots by construction; padding rows and
     # padded lanes collide on the scratch block, which is never read
     # unmasked.
-    kp = kp.at[:, slot_blocks, slot_offsets].set(
-        k_tok.astype(kp.dtype).transpose(2, 0, 1, 3))
-    vp = vp.at[:, slot_blocks, slot_offsets].set(
-        v_tok.astype(vp.dtype).transpose(2, 0, 1, 3))
+    k_pool = k_pool.at[layer, slot_blocks, slot_offsets].set(
+        k_tok.astype(k_pool.dtype).reshape(B, Q, -1))
+    v_pool = v_pool.at[layer, slot_blocks, slot_offsets].set(
+        v_tok.astype(v_pool.dtype).reshape(B, Q, -1))
     o = paged_verify_attention(
-        q.reshape(B, Q, hkv, group, cfg.head_dim), kp, vp,
+        q.reshape(B, Q, hkv, group, cfg.head_dim),
+        _head_major(k_pool, layer, cfg), _head_major(v_pool, layer, cfg),
         block_tables, context_lens, q_lens)
     o = jnp.einsum("bqhd,hdm->bqm",
                    o.reshape(B, Q, cfg.n_head, cfg.head_dim),
                    p["wo"].astype(dt))
-    return o, (kp, vp)
+    return o, (k_pool, v_pool)
 
 
 def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
@@ -366,9 +384,10 @@ def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
         lane, are padding: their slots point at the pool's reserved
         scratch block 0 and their logits are garbage the engine never
         reads.
-      k_pool / v_pool: [L, kv_heads, num_blocks, block_size, head_dim]
-        (donate these in the caller's jit — steady-state decode then
-        updates the pool in place).
+      k_pool / v_pool: [L, num_blocks, block_size, kv_heads * head_dim]
+        (donate these in the caller's jit: they ride in the layer
+        scan's carry, so steady-state decode writes the step's rows
+        into the donated buffers and copies nothing of the stack).
       block_tables: [b, max_nb] int32, 0-padded.
       context_lens: [b] int32 — resident tokens per lane INCLUDING its
         q_lens real rows (1 for a padded lane).
@@ -383,16 +402,20 @@ def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
     """
     x = _embed(params, tokens, positions, cfg)
 
-    def layer(x, xs):
-        p, kp, vp = xs
+    def layer(carry, xs):
+        x, k_pool, v_pool = carry
+        p, i = xs
         attend = functools.partial(
-            _paged_sublayer, kp=kp, vp=vp, block_tables=block_tables,
-            context_lens=context_lens, q_lens=q_lens,
-            slot_blocks=slot_blocks, slot_offsets=slot_offsets, cfg=cfg)
-        return _block(x, p, cfg, attend)
+            _paged_sublayer, layer=i, k_pool=k_pool, v_pool=v_pool,
+            block_tables=block_tables, context_lens=context_lens,
+            q_lens=q_lens, slot_blocks=slot_blocks,
+            slot_offsets=slot_offsets, cfg=cfg)
+        x, (k_pool, v_pool) = _block(x, p, cfg, attend)
+        return (x, k_pool, v_pool), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        layer, x, (params["blocks"], k_pool, v_pool))
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        layer, (x, k_pool, v_pool),
+        (params["blocks"], jnp.arange(cfg.n_layer)))
     logits = _head(params, x, cfg)
     return logits, _greedy_ids(logits), k_pool, v_pool
 
@@ -430,20 +453,20 @@ def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _chunk_sublayer(h, p, kp, vp, block_table, ctx_len, cfg: GPTConfig):
-    """A prefill chunk's attention over [its sequence's pool context ++
-    the chunk]; the layer's pools kp / vp are read only. Carries the
-    chunk's own K/V out of the layer."""
+def _chunk_sublayer(h, p, layer, k_pool, v_pool, block_table, ctx_len,
+                    cfg: GPTConfig):
+    """A prefill chunk's attention at layer ``layer`` over [its
+    sequence's pool context ++ the chunk]; the stored pools are read
+    only. Carries the chunk's own K/V out of the layer."""
     dt = cfg.dtype
     hkv, hd = cfg.kv_heads, cfg.head_dim
     q, k_tok, v_tok = _qkv(h, p, dt)
-    # This sequence's pool context: [hkv, nb, BS, d] gathered by table,
-    # flattened to slot order [1, S, hkv, d] (S = 0 for an empty table).
-    k_ctx = kp[:, block_table]
-    v_ctx = vp[:, block_table]
-    nb, bs = k_ctx.shape[1], k_ctx.shape[2]
-    k_ctx = k_ctx.transpose(1, 2, 0, 3).reshape(1, nb * bs, hkv, hd)
-    v_ctx = v_ctx.transpose(1, 2, 0, 3).reshape(1, nb * bs, hkv, hd)
+    # This sequence's pool context, gathered by table from the stacked
+    # pool: [nb, BS, hkv * d], already in slot order (S = nb * BS slots,
+    # 0 for an empty table).
+    slots = block_table.shape[0] * k_pool.shape[2]
+    k_ctx = k_pool[layer, block_table].reshape(1, slots, hkv, hd)
+    v_ctx = v_pool[layer, block_table].reshape(1, slots, hkv, hd)
     o = _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len)
     return jnp.einsum("bshd,hdm->bsm", o, p["wo"].astype(dt)), (k_tok, v_tok)
 
@@ -467,7 +490,8 @@ def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
         forward, and gives the logits of ``forward`` on its tokens.
       ctx_len: scalar int32 — tokens already resident in the pool.
 
-    The pools are READ-ONLY here (no donation): the chunk's K/V comes
+    The pools ([L, num_blocks, block_size, kv_heads * head_dim]) are
+    READ-ONLY here (no donation): the chunk's K/V comes
     back and the caller writes it into the pool afterwards — shared
     blocks must be COW-split before that write.
 
@@ -477,13 +501,14 @@ def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
     x = _embed(params, tokens, positions, cfg)
 
     def layer(x, xs):
-        p, kp, vp = xs
-        attend = functools.partial(_chunk_sublayer, kp=kp, vp=vp,
-                                   block_table=block_table, ctx_len=ctx_len,
-                                   cfg=cfg)
+        p, i = xs
+        attend = functools.partial(_chunk_sublayer, layer=i, k_pool=k_pool,
+                                   v_pool=v_pool, block_table=block_table,
+                                   ctx_len=ctx_len, cfg=cfg)
         return _block(x, p, cfg, attend)
 
-    x, (k, v) = jax.lax.scan(layer, x, (params["blocks"], k_pool, v_pool))
+    x, (k, v) = jax.lax.scan(
+        layer, x, (params["blocks"], jnp.arange(cfg.n_layer)))
     return _head(params, x, cfg), k, v
 
 

@@ -25,8 +25,12 @@ the windows into one ``(kv_heads, pages * block_size, head_dim)`` block:
 two batched matmuls and one online-softmax update a step, the running
 max / denominator / accumulator per head in VMEM scratch across the
 lane's steps, the output written at the last. XLA passes one buffer
-for all the windows: the custom call still takes the layer's pool, in
-its own shape, and is still one call. (The kernel cannot copy pages
+for all the windows: the custom call takes one layer's pool head-major,
+``[kv_heads, num_blocks, block_size, head_dim]``, and is still one
+call. The pool at rest is token-major (llm/kv_cache.py: ``[layers,
+num_blocks, block_size, kv_heads * head_dim]``); models/gpt.py makes
+this operand from it once a layer, each head's lanes of every row, the
+decode step's one pass over a layer's pool. (The kernel cannot copy pages
 itself with ``make_async_copy`` from a ``pl.ANY`` pool: Mosaic in jax
 0.9.0 sees a pool whose minor dimension is 64 padded to 128 lanes in
 HBM and refuses any slice of it, "must be aligned to tiling (128)".)

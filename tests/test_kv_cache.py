@@ -52,7 +52,7 @@ def test_write_prefill_roundtrip_with_ragged_tail():
     np.testing.assert_allclose(np.asarray(k_back), k, atol=1e-6)
     np.testing.assert_allclose(np.asarray(v_back), v, atol=1e-6)
     # The scratch block stayed zero.
-    assert float(jnp.abs(kv.k[:, :, 0]).max()) == 0.0
+    assert float(jnp.abs(kv.k[:, 0]).max()) == 0.0
 
 
 def test_writes_to_disjoint_grants_do_not_interfere():

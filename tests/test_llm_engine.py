@@ -473,7 +473,7 @@ def test_program_ids_equal_host_greedy_sample_of_its_logits(Q, kind):
     max_nb = BF16.max_seq // bs
     rng = np.random.default_rng(1)
     pool = jnp.asarray(rng.standard_normal(
-        (BF16.n_layer, BF16.kv_heads, nb, bs, BF16.head_dim)), BF16.dtype)
+        (BF16.n_layer, nb, bs, BF16.kv_heads * BF16.head_dim)), BF16.dtype)
     tables = np.zeros((B, max_nb), np.int32)
     tables[:, 0] = 1 + np.arange(B)
     slot = (1 + np.arange(B, dtype=np.int32))[:, None] + np.arange(
@@ -631,7 +631,7 @@ def test_step_at_one_row_is_row_zero_of_a_wider_step_with_padding_rows():
     max_nb = CFG.max_seq // bs
     rng = np.random.default_rng(7)
     pool = jnp.asarray(rng.standard_normal(
-        (CFG.n_layer, CFG.kv_heads, nb, bs, CFG.head_dim)), CFG.dtype)
+        (CFG.n_layer, nb, bs, CFG.kv_heads * CFG.head_dim)), CFG.dtype)
     tables = np.zeros((B, max_nb), np.int32)
     tables[:, 0] = 1 + np.arange(B)
     tok = rng.integers(0, CFG.vocab_size, (B, 1), dtype=np.int32)
@@ -653,12 +653,12 @@ def test_step_at_one_row_is_row_zero_of_a_wider_step_with_padding_rows():
                                atol=2e-5, rtol=0)
     assert np.asarray(i3[:, 0]).tolist() == np.asarray(i1[:, 0]).tolist()
     for wide, one in ((k3, k1), (v3, v1)):
-        np.testing.assert_allclose(np.asarray(wide[:, :, 1:]),
-                                   np.asarray(one[:, :, 1:]), atol=1e-6,
+        np.testing.assert_allclose(np.asarray(wide[:, 1:]),
+                                   np.asarray(one[:, 1:]), atol=1e-6,
                                    rtol=0)
     # and the one-row step did write each lane's token where it was told
-    assert not np.allclose(np.asarray(k1[:, :, 1:]),
-                           np.asarray(pool[:, :, 1:]))
+    assert not np.allclose(np.asarray(k1[:, 1:]),
+                           np.asarray(pool[:, 1:]))
 
 
 def test_chunk_with_an_empty_table_is_the_plain_causal_forward():
@@ -673,7 +673,7 @@ def test_chunk_with_an_empty_table_is_the_plain_causal_forward():
     bs, nb, T = 8, 16, 24
     rng = np.random.default_rng(3)
     pool = jnp.asarray(rng.standard_normal(
-        (CFG.n_layer, CFG.kv_heads, nb, bs, CFG.head_dim)), CFG.dtype)
+        (CFG.n_layer, nb, bs, CFG.kv_heads * CFG.head_dim)), CFG.dtype)
     toks = rng.integers(0, CFG.vocab_size, (1, T), dtype=np.int32)
     pos = np.arange(T, dtype=np.int32)
     logits, k, v = chunk(PARAMS, toks, pos, pool, pool,
@@ -689,6 +689,147 @@ def test_chunk_with_an_empty_table_is_the_plain_causal_forward():
                                np.int32(0))):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=0)
+
+
+# The parent's pool layout, kept here as the reference the stored
+# (token-major) pool is held to: [L, kv_heads, num_blocks, block_size,
+# head_dim], written and read as models/gpt.py did before PR 31.
+def _head_major_pool(pool, cfg):
+    L, nb, bs, _ = pool.shape
+    return pool.reshape(L, nb, bs, cfg.kv_heads, cfg.head_dim).transpose(
+        0, 3, 1, 2, 4)
+
+
+def _head_major_layers(params, x, k_pool, v_pool, attend, cfg):
+    """The parent's layer scan: a layer's head-major pools come in as
+    the scan's xs and whatever ``attend(h, p, kp, vp)`` carries goes out
+    stacked."""
+    import functools
+
+    from ray_tpu.models import gpt
+
+    def layer(x, xs):
+        p, kp, vp = xs
+        return gpt._block(x, p, cfg,
+                          functools.partial(attend, kp=kp, vp=vp))
+
+    x, carried = jax.lax.scan(layer, x, (params["blocks"], k_pool, v_pool))
+    return gpt._head(params, x, cfg), carried
+
+
+def _head_major_step(params, tokens, positions, k_pool, v_pool, tables,
+                     context_lens, q_lens, slot_blocks, slot_offsets, cfg):
+    from ray_tpu.models import gpt
+    from ray_tpu.ops.pallas.paged_decode import paged_verify_attention
+
+    def attend(h, p, kp, vp):
+        B, Q = h.shape[:2]
+        q, k_tok, v_tok = gpt._qkv(h, p, cfg.dtype)
+        kp = kp.at[:, slot_blocks, slot_offsets].set(
+            k_tok.astype(kp.dtype).transpose(2, 0, 1, 3))
+        vp = vp.at[:, slot_blocks, slot_offsets].set(
+            v_tok.astype(vp.dtype).transpose(2, 0, 1, 3))
+        o = paged_verify_attention(
+            q.reshape(B, Q, cfg.kv_heads, cfg.n_head // cfg.kv_heads,
+                      cfg.head_dim), kp, vp, tables, context_lens, q_lens)
+        o = jnp.einsum("bqhd,hdm->bqm",
+                       o.reshape(B, Q, cfg.n_head, cfg.head_dim),
+                       p["wo"].astype(cfg.dtype))
+        return o, (kp, vp)
+
+    logits, (k_pool, v_pool) = _head_major_layers(
+        params, gpt._embed(params, tokens, positions, cfg), k_pool, v_pool,
+        attend, cfg)
+    return logits, gpt._greedy_ids(logits), k_pool, v_pool
+
+
+def _head_major_chunk(params, tokens, positions, k_pool, v_pool, table,
+                      ctx_len, cfg):
+    from ray_tpu.models import gpt
+
+    def attend(h, p, kp, vp):
+        q, k_tok, v_tok = gpt._qkv(h, p, cfg.dtype)
+        k_ctx, v_ctx = kp[:, table], vp[:, table]
+        nb, bs = k_ctx.shape[1:3]
+        k_ctx = k_ctx.transpose(1, 2, 0, 3).reshape(
+            1, nb * bs, cfg.kv_heads, cfg.head_dim)
+        v_ctx = v_ctx.transpose(1, 2, 0, 3).reshape(
+            1, nb * bs, cfg.kv_heads, cfg.head_dim)
+        o = gpt._chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len)
+        return (jnp.einsum("bshd,hdm->bsm", o, p["wo"].astype(cfg.dtype)),
+                (k_tok, v_tok))
+
+    logits, (k, v) = _head_major_layers(
+        params, gpt._embed(params, tokens, positions, cfg), k_pool, v_pool,
+        attend, cfg)
+    return logits, k, v
+
+
+@pytest.mark.parametrize("cfg", [CFG, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", ["decode", "verify_3_rows", "chunk_context",
+                                  "chunk_empty_table"])
+def test_token_major_pool_gives_the_head_major_pools_bits(case, cfg):
+    """The stored pool [L, num_blocks, block_size, kv_heads * head_dim]
+    against the parent's [L, kv_heads, num_blocks, block_size,
+    head_dim], re-derived from it by reshape and transpose: a decode
+    step, a three-row verify step and a chunk with and without context
+    give the same logits and ids bit for bit, the pools they return are
+    the same pool in the two layouts, and gather_tokens reads back what
+    the head-major pool holds under the same table."""
+    import functools
+
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.llm.kv_cache import PagedKVCache
+
+    params = PARAMS if cfg is CFG else init(jax.random.PRNGKey(2), cfg)
+    step, chunk = _jit_programs(cfg)
+    B, bs, nb = 4, 8, 16
+    max_nb = cfg.max_seq // bs
+    rng = np.random.default_rng(11)
+    draw = lambda: jnp.asarray(rng.standard_normal(
+        (cfg.n_layer, nb, bs, cfg.kv_heads * cfg.head_dim)), cfg.dtype)
+    k_pool, v_pool = draw(), draw()
+    k_old, v_old = (_head_major_pool(k_pool, cfg),
+                    _head_major_pool(v_pool, cfg))
+    equal = lambda a, b: np.array_equal(np.asarray(a, np.float32),
+                                        np.asarray(b, np.float32))
+    if case.startswith("chunk"):
+        T, ctx = 16, 0 if case == "chunk_empty_table" else 20
+        table = np.zeros((max_nb if ctx else 0,), np.int32)
+        table[:3] = [4, 9, 2][:table.size]
+        args = (rng.integers(0, cfg.vocab_size, (1, T), dtype=np.int32),
+                ctx + np.arange(T, dtype=np.int32))
+        got = chunk(params, *args, k_pool, v_pool, table, np.int32(ctx))
+        want = jax.jit(functools.partial(_head_major_chunk, cfg=cfg))(
+            params, *args, k_old, v_old, table, np.int32(ctx))
+        assert all(equal(g, w) for g, w in zip(got, want))
+        return
+    Q = 1 if case == "decode" else 3
+    tables = np.zeros((B, max_nb), np.int32)
+    tables[:, :2] = 1 + np.arange(2 * B).reshape(B, 2)
+    first = np.array([3, 6, 7, 10], np.int32)[:, None]   # rows' positions
+    pos = first + np.arange(Q, dtype=np.int32)           # cross a block
+    args = (rng.integers(0, cfg.vocab_size, (B, Q), dtype=np.int32), pos)
+    rest = (tables, pos[:, -1] + 1, np.full((B,), Q, np.int32),
+            np.take_along_axis(tables, pos // bs, axis=1), pos % bs)
+    logits, ids, k_new, v_new = step(params, *args, k_pool + 0, v_pool + 0,
+                                     *rest)
+    l_old, i_old, k_want, v_want = jax.jit(
+        functools.partial(_head_major_step, cfg=cfg))(
+            params, *args, k_old, v_old, *rest)
+    assert equal(logits, l_old)
+    assert np.asarray(ids).tolist() == np.asarray(i_old).tolist()
+    assert equal(_head_major_pool(k_new, cfg), k_want)
+    assert equal(_head_major_pool(v_new, cfg), v_want)
+    assert not equal(k_new, k_pool)               # the rows were written
+    kv = PagedKVCache(cfg, num_blocks=nb, block_size=bs)
+    kv.k, kv.v = k_new, v_new
+    n = int(pos[1, -1]) + 1
+    k_back, v_back = kv.gather_tokens([int(b) for b in tables[1, :2]], n)
+    for back, old in ((k_back, k_want), (v_back, v_want)):
+        rows = old[:, :, tables[1, :2]].transpose(0, 2, 3, 1, 4).reshape(
+            cfg.n_layer, 2 * bs, cfg.kv_heads, cfg.head_dim)[:, :n]
+        assert equal(back, rows)
 
 
 def test_cold_whole_prompt_prefills_through_the_chunk_program_tableless():

@@ -116,13 +116,13 @@ def test_cow_splits_shared_tail_without_corrupting_parent():
     # sole owner, no COW needed. Offset 1 is INSIDE it: COW required.
     assert not p.needs_cow(tail, 2)
     assert p.needs_cow(tail, 1)
-    before = np.asarray(p.k[:, :, tail])
+    before = np.asarray(p.k[:, tail])
     nb = p.cow(tail)
     assert nb is not None and nb != tail
     # The private copy carries the parent's content; the parent block
     # itself is untouched and still matchable (parked in LRU).
-    assert np.array_equal(np.asarray(p.k[:, :, nb]), before)
-    assert np.array_equal(np.asarray(p.k[:, :, tail]), before)
+    assert np.array_equal(np.asarray(p.k[:, nb]), before)
+    assert np.array_equal(np.asarray(p.k[:, tail]), before)
     assert p.cow_splits == 1
     assert tail in p._lru
     t3, c3 = p.admit(seq, len(seq) + 1)        # chain STILL fully hits

@@ -410,7 +410,7 @@ def test_program_names_in_the_lowered_text():
     decode, chunk = _jit_programs(CFG)
     bs, nb, B = 8, 16, 2
     max_nb = CFG.max_seq // bs
-    pool = jnp.zeros((CFG.n_layer, CFG.kv_heads, nb, bs, CFG.head_dim),
+    pool = jnp.zeros((CFG.n_layer, nb, bs, CFG.kv_heads * CFG.head_dim),
                      CFG.dtype)
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
     def step(q):
@@ -423,8 +423,8 @@ def test_program_names_in_the_lowered_text():
         "llm_prefill_chunk": _lowered(chunk, PARAMS, i32(1, 8), i32(8),
                                       pool, pool, i32(max_nb), jnp.int32(8)),
         "kv_scatter_blocks": _lowered(
-            kv_cache.kv_scatter_blocks, pool, pool, pool[:, :, :2],
-            pool[:, :, :2], i32(2)),
+            kv_cache.kv_scatter_blocks, pool, pool, pool[:, :2],
+            pool[:, :2], i32(2)),
         "kv_copy_block": _lowered(kv_cache.kv_copy_block, pool, pool,
                                   jnp.int32(1), jnp.int32(2)),
     }

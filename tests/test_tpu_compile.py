@@ -134,7 +134,7 @@ def test_whole_decode_step_compiles_with_the_kernel(one_chip, as_tpu):
     params = jax.tree_util.tree_map(
         lambda leaf: S(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
-    pool = S((cfg.n_layer, HKV, NB, BS, HD), jnp.bfloat16)
+    pool = S((cfg.n_layer, NB, BS, HKV * HD), jnp.bfloat16)
     B, i32 = 32, jnp.int32
     step = jax.jit(functools.partial(gpt.forward_step, cfg=cfg),
                    donate_argnums=(3, 4))
@@ -176,7 +176,7 @@ def _chat_cell_decode_lowered(one_chip):
         lambda leaf: S(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
     B, i32 = CELL_B, jnp.int32
-    pool = S((cfg.n_layer, HKV, CELL_NB, BS, HD), jnp.bfloat16)
+    pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
     decode = _jit_programs(cfg)[0]
     return decode.lower(params, S((B, 1), i32), S((B, 1), i32), pool, pool,
                         S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
@@ -188,7 +188,8 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
     """The engine's own decode program, lowered for the TPU at the chat
     cell's shapes: the module is ``jit_llm_decode`` and it holds exactly
     one Mosaic call, ``paged_decode``, which takes the layer's pool as
-    an operand in the pool's own shape. benchmark/kernels.py finds the
+    an operand, head-major (models/gpt.py makes that view of the stored
+    pool). benchmark/kernels.py finds the
     kernel by that operand and divides its seconds by calls x layers: a
     second Mosaic call on the pool, or a reshaped or stacked pool, would
     move ``paged_kernel_ms`` without moving the kernel."""
@@ -205,16 +206,16 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
 
 
 @pytest.fixture(scope="module")
-def chat_decode_hlo(one_chip):
-    """The chat cell's decode program compiled for the described v5e,
-    as text (one ~4 s compile for the tests that read it)."""
+def chat_decode(one_chip):
+    """The chat cell's decode program compiled for the described v5e
+    (one ~4 s compile for the tests that read it)."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        return _chat_cell_decode_lowered(one_chip).compile().as_text()
+        return _chat_cell_decode_lowered(one_chip).compile()
 
 
 def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
-        chat_decode_hlo):
+        chat_decode):
     """Compiled for the described v5e at the chat cell's shapes, the
     decode program returns the lanes' argmax ids, ``s32[64,1]``, beside
     its logits (the engine fetches those 64 ints and leaves the logits
@@ -226,13 +227,13 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     import re
 
     cfg = gpt.GPT2_SMALL
-    text = chat_decode_hlo
+    text = chat_decode.as_text()
     assert text.startswith("HloModule jit_llm_decode")
     entry = text[text.index("\nENTRY "):]
     root = next(line for line in entry.splitlines()
                 if line.lstrip().startswith("ROOT "))
     outputs = re.findall(r"(\w+\[[\d,]*\])", root.split(" tuple(")[0])
-    pools = f"bf16[{cfg.n_layer},{HKV},{CELL_NB},{BS},{HD}]"
+    pools = f"bf16[{cfg.n_layer},{CELL_NB},{BS},{HKV * HD}]"
     assert outputs == [f"bf16[{CELL_B},1,{cfg.vocab_size}]",
                        f"s32[{CELL_B},1]", pools, pools], root[:300]
     calls = [line for line in text.splitlines()
@@ -246,20 +247,74 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     assert shapes.count(layer_pool) == 2, shapes      # K's and V's pool
 
 
-def test_compiled_decode_program_copies_the_pool_no_more_than_it_did(
-        chat_decode_hlo):
-    """The pin ROADMAP A1 will tighten to zero: compiled for the
-    described v5e at the chat cell's shapes, the decode program (one
-    row a lane through forward_step) holds no more whole-pool ``copy``
-    instructions than the one-token decode forward's program did before
-    it and the verify forward became one, eight (``[1,12,2560,16,64]`` x 4, ``[12,2560,16,64]`` x
-    2, ``[12,12,2560,16,64]`` x 2: the layout conversions around the
-    Mosaic call and the scan's stacked outputs; PERF.md section 5 has
-    them at 41.3 of the program's 54.5 ms on the chip). A compiler's
-    count, not a time."""
+LAYER_POOL = HKV * CELL_NB * BS * HD      # elements of one layer's K
+
+
+def _results(text, *opcodes):
+    """(opcode, result shape) of every instruction of ``text``, fused
+    bodies included, that has one of ``opcodes`` and a result of at
+    least one layer's pool."""
+    import math
     import re
 
-    copies = [m.group(1) for m in re.finditer(
-        r"^\s*(?:ROOT )?%[\w.\-]+ = (\S+?)\{\S* copy\(", chat_decode_hlo,
-        re.M) if m.group(1).endswith(f"{HKV},{CELL_NB},{BS},{HD}]")]
-    assert 0 < len(copies) <= 8, copies
+    found = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?%[\w.\-]+ = (\w+\[([\d,]+)\])\S* ([\w\-]+)\(",
+            text, re.M):
+        shape, dims, op = m.groups()
+        if op in opcodes and math.prod(map(int, dims.split(","))) \
+                >= LAYER_POOL:
+            found.append((op, shape))
+    return found
+
+
+def test_compiled_decode_program_copies_the_pool_no_more_than_it_did(
+        chat_decode):
+    """ROADMAP A1's pin, tightened by PR 31. Compiled for the described
+    v5e at the chat cell's shapes, the decode program (one row a lane
+    through forward_step) (a) copies the stacked pool nowhere: the
+    pools ride in the layer scan's carry and the step's rows are
+    scattered into the donated buffers; and (b) holds no ``copy`` or
+    ``transpose`` of a layer's pool or more, N = 0, in the loop body or
+    outside it. The one pass a layer's pool still costs is the paged
+    kernel's head-major operand: ``bf16[12,2560,16,64]``, allocated
+    once a pool and filled in place by one ``dynamic-update-slice``
+    fusion a head from that head's lanes of the stored pool, 2 x 12
+    fusions a layer that each write a twelfth. (The parent held eight
+    whole-pool copies, six in the loop body and two of the stack, and
+    2.93 GB of temporaries: 41.3 of its program's 54.5 ms on the chip,
+    PERF.md section 6.) A compiler's count, not a time."""
+    text = chat_decode.as_text()
+    assert _results(text, "copy", "transpose", "copy-start") == []
+    stack = f"bf16[{gpt.GPT2_SMALL.n_layer},{CELL_NB},{BS},{HKV * HD}]"
+    operand = f"bf16[{HKV},{CELL_NB},{BS},{HD}]"
+    assert _results(text, "scatter") == [("scatter", stack)] * 2
+    assert _results(text, "dynamic-update-slice") == \
+        [("dynamic-update-slice", operand)] * (2 * HKV)
+    assert _results(text, "dynamic-slice", "concatenate", "pad") == []
+    assert chat_decode.memory_analysis().temp_size_in_bytes < 700e6
+
+
+@pytest.mark.parametrize("program", ["kv_scatter_blocks", "kv_copy_block"])
+def test_compiled_pool_writers_update_the_pool_in_place(one_chip, program):
+    """A chunk's pool write (32 blocks, a 512-token chunk) and the
+    copy-on-write split, compiled for the described v5e at the chat
+    cell's pool: no copy of the pool and under 64 MB of temporaries
+    (the parent's scatter held four whole-stack copies and 1.51 GB, and
+    took 14.2 ms on the chip for 0.1 ms of writing)."""
+    from ray_tpu.llm import kv_cache
+
+    L, i32 = gpt.GPT2_SMALL.n_layer, jnp.int32
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = S((L, CELL_NB, BS, HKV * HD), jnp.bfloat16)
+    if program == "kv_scatter_blocks":
+        blocks = S((L, 32, BS, HKV * HD), jnp.bfloat16)
+        c = kv_cache.kv_scatter_blocks.lower(pool, pool, blocks, blocks,
+                                             S((32,), i32)).compile()
+    else:
+        c = kv_cache.kv_copy_block.lower(pool, pool, S((), i32),
+                                         S((), i32)).compile()
+    assert c.as_text().startswith(f"HloModule jit_{program}")
+    assert _results(c.as_text(), "copy", "transpose", "copy-start",
+                    "dynamic-slice") == []
+    assert c.memory_analysis().temp_size_in_bytes < 64e6
