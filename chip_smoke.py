@@ -63,6 +63,14 @@ MESH_GRAD_REL_TOL = 0.005   # the same gradients' per-head norms, 4 chips
                             # vs 1, over the largest norm [0.00075]
 GRAD_ROWS = 8               # rows of batch 0 those gradients are taken on
 LOGIT_MARGIN_EPS = 0.05     # top-two margin under which bf16 may flip argmax
+# Laguna's served bfloat16 logits against its float32 reference, largest
+# absolute difference over every compared row (logits have spread ~0.9).
+# Not a rounding-sized limit: a top-k router turns bfloat16 rounding into
+# swapped experts on about one token-layer in ten, and a swapped expert
+# moves a row by 0.1-0.6 (benchmark/reference_laguna.py has the readings;
+# the typical row, reported beside it, differs by rounding alone). A
+# wrong mask, rotary or expert reads ~4.
+LAGUNA_LOGIT_TOL = 1.5
 
 
 _T0 = time.perf_counter()
@@ -619,7 +627,117 @@ def phase_serve(args) -> None:
         serve.shutdown()
     finally:
         ray_tpu.shutdown()
-    _finish(info, ttft_s=ttfts, tokens_per_s=8 * n_out / wall)
+    # -- Laguna-XS.2: logits through both pools against the reference
+    import gc
+
+    gc.collect()
+    worst, typical = _laguna_logits(args.rehearse, args.seed)
+    _check(worst < LAGUNA_LOGIT_TOL,
+           f"laguna: served logits (chunked prefill, then decode through "
+           f"the full and the window pool) equal the plain float32 "
+           f"reference's within {LAGUNA_LOGIT_TOL} (largest difference "
+           f"{worst:.5f}, the median row's {typical:.5f})")
+    _finish(info, ttft_s=ttfts, tokens_per_s=8 * n_out / wall,
+            laguna_logit_diff=worst)
+
+
+def _laguna_logits(rehearse: bool, seed: int) -> tuple:
+    """Laguna-XS.2 at the benchmark cell's cut (published widths, layer
+    0 and one period; tiny when rehearsing) through LLMEngine alone: a
+    prompt prefilled in chunks across window boundaries, then decode
+    steps through both pools, beside a second lane. Every logits row
+    the engine decides a token from is compared with the plain
+    reference's full forward pass (models/laguna_ref.py). Returns the
+    largest absolute difference and the median row's."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.models import laguna, laguna_ref
+
+    kinds = (laguna.FULL,) + (laguna.SLIDING,) * 3 + (laguna.FULL,)
+    mlps = (laguna.DENSE,) + (laguna.SPARSE,) * 4
+    if rehearse:
+        cfg = laguna.LagunaConfig(
+            vocab_size=512, hidden_size=64, intermediate_size=128,
+            num_hidden_layers=5, num_key_value_heads=2, head_dim=16,
+            num_attention_heads_per_layer=(4, 8, 8, 8, 4), num_experts=8,
+            num_experts_per_tok=2, moe_intermediate_size=32,
+            shared_expert_intermediate_size=32, sliding_window=32,
+            layer_types=kinds, mlp_layer_types=mlps, max_seq=256,
+            dtype="float32")
+        pool, n_prompt, n_out = dict(num_blocks=64, block_size=8,
+                                     prefill_chunk_tokens=32), 90, 6
+    else:
+        cfg = laguna.LagunaConfig(
+            num_hidden_layers=5, layer_types=kinds, mlp_layer_types=mlps,
+            num_attention_heads_per_layer=(48, 64, 64, 64, 48),
+            max_seq=2048)
+        pool, n_prompt, n_out = dict(num_blocks=1024, block_size=16,
+                                     prefill_chunk_tokens=512), 1300, 12
+    params = laguna.init(jax.random.PRNGKey(seed), cfg)
+    eng = LLMEngine(params, cfg, max_batch=8, **pool)
+    rows = {}
+    activate, fetch = eng._activate, eng._fetch_decisions
+
+    def on_activate(req, row):
+        if row is not None:
+            rows.setdefault(req.rid, []).append(np.asarray(row, np.float32))
+        return activate(req, row)
+
+    def on_fetch(logits, ids, all_greedy):
+        got = np.asarray(jax.device_get(logits), np.float32)
+        for i, r in enumerate(x for x in eng._active
+                              if x.state == "RUNNING"):
+            rows.setdefault(r.rid, []).append(got[i, 0])
+        return fetch(logits, ids, all_greedy)
+
+    eng._activate, eng._fetch_decisions = on_activate, on_fetch
+    rng = np.random.default_rng(seed + 7)
+    reqs = [eng.add_request(rng.integers(0, cfg.vocab_size, n).tolist(),
+                            max_tokens=n_out)
+            for n in (n_prompt, n_prompt // 3)]
+    while eng.step():
+        pass
+    st = eng.stats()
+    log(f"  laguna: paged kernel {st['paged_kernel']}, window kind peak "
+        f"{st['kv_window_util_peak']:.3f}, "
+        f"{st['kv_window_blocks_slid']} blocks slid out")
+    # The router alone, on identical inputs: the served route() against
+    # plain float32 softmax + top-k. Tokens cannot show this (a top-k
+    # router turns rounding into swapped experts whatever computes the
+    # scores; benchmark/reference_laguna.py), the router itself can.
+    from ray_tpu.ops import moe
+
+    import jax.numpy as jnp
+
+    routed = next(p for p in params["layers"] if "router" in p)
+    h = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                          (2048, cfg.hidden_size)).astype(cfg.dtype)
+    k = cfg.num_experts_per_tok
+    _, served, _ = moe.route(h, routed["router"], k)
+    with jax.default_matmul_precision("highest"):
+        scores = h.astype(jnp.float32) @ routed["router"].astype(jnp.float32)
+    plain = jax.lax.top_k(jax.nn.softmax(scores, -1), k)[1]
+    low = jax.lax.top_k(jax.nn.softmax(
+        (h @ routed["router"]).astype(jnp.bfloat16), -1), k)[1]
+    same = lambda a, b: float((jnp.sort(a, -1) == jnp.sort(b, -1))
+                              .all(-1).mean())
+    _check(same(served, plain) >= 0.999,
+           f"laguna: route() picks the float32 reference's experts on "
+           f"{same(served, plain):.4f} of 2,048 tokens (at least 0.999; "
+           f"scores in bfloat16 agree on {same(low, plain):.4f})")
+    by_row = []
+    del eng
+    for r in reqs:
+        want = np.asarray(laguna_ref.forward(
+            params, r.prompt + r.output, cfg))[
+            len(r.prompt) - 1:len(r.prompt) - 1 + len(r.output)]
+        got = np.stack(rows[r.rid])
+        _check(got.shape == want.shape, f"laguna: {got.shape[0]} logits "
+               f"rows compared for a {len(r.prompt)}-token prompt")
+        by_row += np.abs(got - want).max(axis=1).tolist()
+    return max(by_row), float(np.median(by_row))
 
 
 # ---------------------------------------------------------------------------

@@ -6,12 +6,17 @@ itself, built from the repo's own layers:
 
   * llm/kv_cache.py      — paged KV pool (PagedAttention block
                             manager) + PrefixPool (hash-indexed,
-                            ref-counted prefix cache with COW)
+                            ref-counted prefix cache with COW) +
+                            WindowPool (layers with a window: a lane
+                            keeps the blocks that cover its window)
   * ops/pallas/paged_decode.py — decode-attention kernel gathering K/V
                             through block tables (interpret mode on CPU)
-  * models/gpt.py        — forward_step / forward_prefill_chunk: the
-                            training layer around a paged or a chunk
-                            attention sublayer
+  * models/__init__.py   — the serving seam: a model's step and chunk
+                            functions, cache and cost descriptions
+  * models/gpt.py, models/laguna.py — forward_step /
+                            forward_prefill_chunk: a model's layer
+                            around a paged or a chunk attention
+                            sublayer
   * llm/engine.py        — Orca-style iteration-level scheduler
   * llm/spec.py          — speculative decoding (n-gram / small-draft
                             proposers verified in one paged-attention

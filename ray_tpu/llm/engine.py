@@ -65,9 +65,10 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..models.gpt import GPTConfig, forward_prefill_chunk, forward_step
+from ..models import serving
 from ..util import perfmodel, tracing
-from .kv_cache import PagedKVCache, PrefixPool
+from .kv_cache import (PagedKVCache, PrefixPool, WindowPool,
+                       window_table_len)
 from .sampling import accept_draws, is_greedy, sample, verify_tokens
 from .spec import make_spec
 
@@ -95,6 +96,10 @@ class Request:
     stop_tokens: Tuple[int, ...] = ()
     state: str = WAITING
     block_table: List[int] = field(default_factory=list)
+    # The table of the kind of layer with a window, where the model has
+    # one: the sequence's blocks from ``window_first`` on.
+    window_table: List[int] = field(default_factory=list)
+    window_first: int = 0
     context_len: int = 0          # tokens resident in the KV pool
     prefilled_upto: int = 0       # prompt tokens computed OR cache-hit
     cached_tokens: int = 0        # prefix-cache hit span at admission
@@ -133,7 +138,7 @@ _KERNEL_MODES: dict = {}
 
 
 @functools.lru_cache(maxsize=32)
-def _jit_programs(cfg: GPTConfig):
+def _jit_programs(cfg):
     """Process-wide compiled-program cache: (decode step, prefill
     chunk). jax.jit's executable cache is keyed by the wrapped
     callable's identity, so per-engine ``jax.jit(partial(...))``
@@ -142,6 +147,12 @@ def _jit_programs(cfg: GPTConfig):
     and tests all pay it. Engines with equal cfg share one pair of
     wrappers instead; donation is per-call, so two live engines sharing
     a program donate only their own pools."""
+    model = serving(cfg)
+    # The full kind's pools are arguments 3 and 4 of the step; a kind
+    # with a window brings its own after the ten arguments every model
+    # has, and they are donated with them.
+    pools = (3, 4) + ((10, 11) if len(model.kinds) > 1 else ())
+
     def program(name, fn, **jit_kwargs):
         # The name is what a device trace's ``XLA Modules`` line shows
         # (``jit_<name>``); a functools.partial has none of its own.
@@ -153,8 +164,8 @@ def _jit_programs(cfg: GPTConfig):
 
     # The step program is ``jit_llm_decode`` at every q (one row a lane,
     # or 1 + k under speculation).
-    return (program("llm_decode", forward_step, donate_argnums=(3, 4)),
-            program("llm_prefill_chunk", forward_prefill_chunk))
+    return (program("llm_decode", model.step, donate_argnums=pools),
+            program("llm_prefill_chunk", model.chunk))
 
 
 class LLMEngine:
@@ -164,12 +175,17 @@ class LLMEngine:
     replicas run requests on a thread pool); step() is driven either by
     the background loop (start()) or manually (tests)."""
 
-    def __init__(self, params, cfg: GPTConfig, *, num_blocks: int = 64,
+    def __init__(self, params, cfg, *, num_blocks: int = 64,
+                 window_blocks: Optional[int] = None,
                  block_size: int = 16, max_batch: int = 8,
                  prefill_chunk_tokens: Optional[int] = None,
                  prefix_cache: bool = True,
                  speculative=None, name: str = "llm"):
         self.cfg = cfg
+        # What the model's module says of serving it: the two programs,
+        # the kinds of layer its cache has, its costs (the seam,
+        # models/__init__.py). Nothing below names a model's fields.
+        self.model = serving(cfg)
         self.name = name
         self.max_batch = int(max_batch)
         # prefix_cache -> PrefixPool: freed blocks keep their content
@@ -198,7 +214,7 @@ class LLMEngine:
         # multiple, so at most max_seq/block_size variants), with or
         # without a table. Programs come from the process-wide cache
         # above.
-        self.max_nb = self.kv.blocks_for_tokens(cfg.max_seq)
+        self.max_nb = self.kv.blocks_for_tokens(self.model.max_seq)
         self._decode, self._prefill_chunk = _jit_programs(cfg)
         # Speculative decoding (llm/spec.py): when enabled, a decode
         # step scores k+1 rows per lane (fixed q shape, one compile)
@@ -207,6 +223,27 @@ class LLMEngine:
         self._spec = make_spec(speculative, target_params=params,
                                target_cfg=cfg)
         self._q_rows = 1 if self._spec is None else self._spec.k + 1
+        # A kind of layer with a window has a pool of its own, in which
+        # a lane holds only the blocks that cover its window: a second
+        # block table a lane. ``window_blocks`` must hold every lane's
+        # window at once, so a grant there never waits on a preemption
+        # (parked prefix tails are evicted for it).
+        self.kv_window: Optional[WindowPool] = None
+        if len(self.model.kinds) > 1:
+            nbw = window_table_len(self.model.kinds[1].window, block_size,
+                                   self._q_rows)
+            if window_blocks is None:
+                window_blocks = 2 * self.max_batch * nbw + 1
+            if window_blocks - 1 < self.max_batch * nbw:
+                raise ValueError(
+                    f"window_blocks {window_blocks} cannot hold "
+                    f"{self.max_batch} lanes of {nbw} blocks")
+            self.kv_window = WindowPool(cfg, num_blocks=window_blocks,
+                                        block_size=block_size)
+            self._win_len = nbw
+        self._kv_window_util_peak = 0.0
+        self._window_live = 0         # window blocks lanes hold, last step
+        self._counters = {}           # the step program's own, last step
         # Without a proposer every lane scores one row in every step:
         # that q_lens lives on the device. Each host array handed to
         # the program is a copy to the device and, beside the serving
@@ -291,10 +328,10 @@ class LLMEngine:
         prompt = [int(t) for t in prompt]
         if not prompt:
             raise ValueError("empty prompt")
-        if len(prompt) + max_tokens > self.cfg.max_seq:
+        if len(prompt) + max_tokens > self.model.max_seq:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) "
-                f"exceeds max_seq {self.cfg.max_seq}")
+                f"exceeds max_seq {self.model.max_seq}")
         need = self.kv.blocks_for_tokens(len(prompt) + max_tokens)
         if need > self.kv.capacity:
             raise ValueError(
@@ -330,12 +367,22 @@ class LLMEngine:
             req = self._waiting[0]
             seq = req.prompt + req.output
             if self._prefix:
-                got = self.kv.admit(seq, len(seq) + 1)
+                # A prefix can be taken up only where every kind of
+                # layer still holds what a query there reads: all of it
+                # in the full kind, the window behind it in the other.
+                upto, tail = None, (0, [])
+                if self.kv_window is not None:
+                    upto, *tail = self.kv_window.match_tail(
+                        seq, self.kv.match(seq))
+                got = self.kv.admit(seq, len(seq) + 1, upto=upto)
                 if got is None:
                     break
                 grant, cached = got
                 req.block_table = grant
                 req.cached_tokens = cached
+                if self.kv_window is not None:
+                    req.window_first, req.window_table = tail
+                    self.kv_window.acquire(req.window_table)
                 if cached >= len(seq):
                     # Full hit: every token is already resident. Hold
                     # the LAST position back — there is no prefill
@@ -390,11 +437,16 @@ class LLMEngine:
         j < context_len — is registered first, so a resumed (or
         identical later) request re-acquires those blocks as cache hits
         instead of recomputing them."""
+        seq = None
         if self._prefix:
             seq = (req.prompt + req.output)[:req.context_len]
             self.kv.release(req.block_table, seq=seq)
         else:
             self.kv.free(req.block_table)
+        if self.kv_window is not None:
+            self.kv_window.release(req.window_table, seq=seq,
+                                   first=req.window_first)
+            req.window_table, req.window_first = [], 0
 
     def _preempt(self, req: Request):
         """Evict req from the batch, release its blocks (registered in
@@ -518,7 +570,7 @@ class LLMEngine:
                 toks[0, :c] = seq[upto:upto + c]
                 positions = np.minimum(
                     upto + np.arange(c + pad, dtype=np.int32),
-                    self.cfg.max_seq - 1)
+                    self.model.max_seq - 1)
                 if upto:
                     table = np.zeros((self.max_nb,), np.int32)
                     table[:len(req.block_table)] = req.block_table
@@ -527,14 +579,23 @@ class LLMEngine:
                     # empty table, and it attends over itself alone.
                     table = np.zeros((0,), np.int32)
                 done = upto + c >= T
+                window = ()
+                if self.kv_window is not None:
+                    # The window kind's table as the chunk reads it: the
+                    # blocks behind the chunk's first token, and the
+                    # first one's index in the sequence.
+                    win = np.zeros((self._win_len + 1,), np.int32)
+                    win[:len(req.window_table)] = req.window_table
+                    win[-1] = req.window_first
+                    window = (self.kv_window.k, self.kv_window.v, win)
             # Dispatch-to-logits-ready is the device span (the pool
             # write is dispatched inside it and may still overlap the
             # host work that follows — deliberately uncounted, it hides
             # behind sampling).
             with perf.device("llm.prefill.device") as dev:
-                logits, k, v = self._prefill_chunk(
+                logits, k, v, *kv_win = self._prefill_chunk(
                     self.params, toks, positions, self.kv.k, self.kv.v,
-                    table, np.int32(upto))
+                    table, np.int32(upto), *window)
                 # Export the chunk's cache: [L, 1, c, Hkv, d] -> pool
                 # blocks upto/bs onward (upto is block-aligned by
                 # construction).
@@ -542,6 +603,8 @@ class LLMEngine:
                     k[:, 0, :c], v[:, 0, :c],
                     req.block_table[upto // bs:
                                     upto // bs + (c + pad) // bs])
+                if kv_win:
+                    self._write_window(req, *kv_win, upto, c, pad)
                 if done:
                     row = np.asarray(jax.device_get(logits[0, c - 1]),
                                      np.float32)
@@ -565,6 +628,9 @@ class LLMEngine:
                         # Index the prompt's chunks for later arrivals
                         # (shared system prompts hit from here on).
                         self.kv.register(seq, req.block_table)
+                        if self.kv_window is not None:
+                            self.kv_window.register_tail(
+                                seq, req.window_table, req.window_first)
                     self._activate(req, row)
                 if req.trace_ctx is not None:
                     dur = time.time() - t0
@@ -577,6 +643,24 @@ class LLMEngine:
                                   "device_ms": round(device_s * 1e3, 3),
                                   "host_ms": round(
                                       max(dur - device_s, 0.0) * 1e3, 3)})
+
+    def _write_window(self, req: Request, k, v, upto: int, c: int,
+                      pad: int):
+        """A chunk's K/V into the window kind's pool: the lane's window
+        slides to where its next query sits (after the chunk, which has
+        been dispatched and read what it needed), and only the chunk's
+        blocks that are still inside it are granted and written."""
+        kvw, bs = self.kv_window, self.kv.block_size
+        req.window_first = kvw.slide(req.window_table, req.window_first,
+                                     upto + c)
+        b0 = max(upto // bs, kvw.keep_from(upto + c))
+        grant = kvw.alloc((upto + c + pad) // bs - b0)
+        if grant is None:
+            raise RuntimeError("the window pool cannot hold a lane's "
+                               "window: window_blocks is too small")
+        req.window_table.extend(grant)
+        skip = b0 * bs - upto
+        kvw.write_prefill(k[:, 0, skip:c], v[:, 0, skip:c], grant)
 
     def _preempt_for(self, req: Request) -> bool:
         """Free pool blocks by preempting a LIFO victim; req itself is
@@ -601,27 +685,37 @@ class LLMEngine:
         itself was preempted (the last resort when it is the newest —
         and possibly only — sequence)."""
         bs = self.kv.block_size
+        kinds = [(self.kv, req.block_table, 0)]
+        if self.kv_window is not None:
+            # The window slides first: blocks the next query no longer
+            # keeps go back before new ones are granted, so a lane never
+            # holds more than its window's worth.
+            req.window_first = self.kv_window.slide(
+                req.window_table, req.window_first, req.context_len)
+            kinds.append((self.kv_window, req.window_table,
+                          req.window_first))
         for j in range(n):
             slot = req.context_len + j
-            bi = slot // bs
-            while True:
-                if bi >= len(req.block_table):
-                    grant = self.kv.alloc(1)
-                    if grant is None:
-                        if not self._preempt_for(req):
-                            return False
-                        continue
-                    req.block_table.extend(grant)
-                if self._prefix:
-                    bid = req.block_table[bi]
-                    if self.kv.needs_cow(bid, slot % bs):
-                        nb = self.kv.cow(bid)
-                        if nb is None:
+            for kv, table, first in kinds:
+                bi = slot // bs - first
+                while True:
+                    if bi >= len(table):
+                        grant = kv.alloc(1)
+                        if grant is None:
                             if not self._preempt_for(req):
                                 return False
                             continue
-                        req.block_table[bi] = nb
-                break
+                        table.extend(grant)
+                    if self._prefix:
+                        bid = table[bi]
+                        if kv.needs_cow(bid, slot % bs):
+                            nb = kv.cow(bid)
+                            if nb is None:
+                                if not self._preempt_for(req):
+                                    return False
+                                continue
+                            table[bi] = nb
+                    break
         return True
 
     def _roofline_attrs(self, cost, device_s: float, dur: float) -> dict:
@@ -679,7 +773,7 @@ class LLMEngine:
                         req.max_tokens - len(req.output) - 1,
                         len(req.prompt) + req.max_tokens
                         - req.context_len - 1,
-                        self.cfg.max_seq - req.context_len - 1)
+                        self.model.max_seq - req.context_len - 1)
                     props[req.rid] = spec.propose(
                         req.rid, req.prompt + req.output, budget)
         with perf.phase("llm.slots"):
@@ -707,6 +801,13 @@ class LLMEngine:
             context_lens = np.ones((B,), np.int32)
             q_lens = np.ones((B,), np.int32)
             tables = np.zeros((B, self.max_nb), np.int32)
+            # The window kind's one array a step: a lane's table, the
+            # table's first block in the sequence, a slot block a row.
+            kvw = self.kv_window
+            win = None
+            if kvw is not None:
+                win = np.zeros((B, self._win_len + 1 + Q), np.int32)
+                self._window_live = kvw.capacity - kvw.num_free
             ctx, rows_per_lane = [], []
             for i, req in enumerate(batch):
                 slot = req.context_len
@@ -731,6 +832,13 @@ class LLMEngine:
                 context_lens[i] = slot + n
                 q_lens[i] = n
                 tables[i, :len(table)] = table
+                if win is not None:
+                    wt, first = req.window_table, req.window_first
+                    win[i, :len(wt)] = wt
+                    win[i, self._win_len] = first
+                    for j in range(n):
+                        win[i, self._win_len + 1 + j] = \
+                            wt[(slot + j) // bs - first]
                 ctx.append(slot + n)
                 rows_per_lane.append(n)
                 if spec is not None:
@@ -749,11 +857,14 @@ class LLMEngine:
         # charged to the host, and the logits stay where they are unless
         # a lane samples with a temperature.
         with perf.device("llm.decode.device") as dev:
-            logits, ids, self.kv.k, self.kv.v = self._decode(
+            window = () if kvw is None else (kvw.k, kvw.v, win)
+            logits, ids, self.kv.k, self.kv.v, *kv_win = self._decode(
                 self.params, tokens, positions, self.kv.k, self.kv.v,
                 tables, context_lens,
                 q_lens if spec is not None else self._one_row_each,
-                slot_blocks, slot_offsets)
+                slot_blocks, slot_offsets, *window)
+            if kv_win:
+                kvw.k, kvw.v = kv_win
             jax.block_until_ready(ids)
         device_s = dev.seconds
         perf.add_cost(cost)
@@ -761,6 +872,10 @@ class LLMEngine:
         with sampling:
             ids, rows = self._fetch_decisions(logits, ids,
                                               on_device == n_live)
+            # The program's own counters ride in that fetch, as the
+            # rows after the lanes'.
+            self._counters = {name: ids[B + i][0] for i, name
+                              in enumerate(self.model.counters)}
         # Lane by lane, each token out the moment it is decided: for a
         # greedy lane that is now, so for an all-greedy batch this loop
         # is emission alone (~1 ms for 64 lanes on the chip) and every
@@ -812,6 +927,9 @@ class LLMEngine:
                     freed = (self.kv.truncate(req.block_table,
                                               req.context_len)
                              if req.block_table else [])
+                    if kvw is not None and req.window_table:
+                        kvw.truncate(req.window_table, req.context_len,
+                                     req.window_first)
                     spec.rollback(req.rid, len(p) - n_acc, len(freed))
         self._decided["host"] += decided[0]
         self._decided["device"] += decided[1]
@@ -853,6 +971,7 @@ class LLMEngine:
             perf.begin()
             self._chunk_log = []
             self._counts = (0, 0, 0, 0)
+            self._counters = {}
             preempted0 = self._preempt_count
             with perf.step("llm.step", self._steps + 1):
                 with perf.phase("llm.admit"):
@@ -867,6 +986,13 @@ class LLMEngine:
                 self._run_decode()
                 self._kv_util_peak = max(self._kv_util_peak, util_hw,
                                          self.kv.utilization())
+                window = {}
+                if self.kv_window is not None:
+                    # Taken before the step's finishes release theirs.
+                    self._kv_window_util_peak = max(
+                        self._kv_window_util_peak, self._window_live
+                        / max(1, self.kv_window.capacity))
+                    window["window_blocks_live"] = self._window_live
                 self._steps += 1
                 with perf.phase("llm.publish"):
                     self.step_log.append(
@@ -890,8 +1016,16 @@ class LLMEngine:
                        "prefill_tokens": sum(c[0] for c in chunks),
                        "prefill_chunks": chunks,
                        "waiting": len(self._waiting),
-                       "preempted": self._preempt_count - preempted0})
+                       "preempted": self._preempt_count - preempted0,
+                       **window, **self._step_counters()})
             return len(self._active)
+
+    def _step_counters(self) -> dict:
+        """The step program's counters as the ring entry carries them:
+        a name that ends in ``_x1000`` is a ratio, sent as an integer."""
+        return {(k[:-6] if k.endswith("_x1000") else k):
+                (v / 1000.0 if k.endswith("_x1000") else v)
+                for k, v in self._counters.items()}
 
     # -- introspection / telemetry ----------------------------------------
 
@@ -922,10 +1056,15 @@ class LLMEngine:
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                                sharding=x.sharding),
                 self.params)
+            window = ()
+            if self.kv_window is not None:
+                pool = jax.ShapeDtypeStruct(self.kv_window.k.shape,
+                                            self.kv_window.k.dtype)
+                window = (pool, pool, i32(B, self._win_len + 1 + Q))
             text = self._decode.lower(
                 params, i32(B, Q), i32(B, Q), self._pool_spec,
                 self._pool_spec, i32(B, self.max_nb), i32(B), i32(B),
-                i32(B, Q), i32(B, Q)).as_text()
+                i32(B, Q), i32(B, Q), *window).as_text()
             mode = _KERNEL_MODES[key] = (
                 "compiled" if "tpu_custom_call" in text else "interpret")
         return mode
@@ -946,6 +1085,11 @@ class LLMEngine:
             "kv_utilization": self.kv.utilization(),
             "kv_util_peak": self._kv_util_peak,
             "kv_free_blocks": self.kv.num_free,
+            **({} if self.kv_window is None else {
+                # The kind of layer with a window: its own pool.
+                "kv_window_utilization": self.kv_window.utilization(),
+                "kv_window_util_peak": self._kv_window_util_peak,
+                "kv_window_blocks_slid": self.kv_window.slid_blocks}),
             "tokens_per_s": self.tokens_per_s(),
             "prefill_chunks": self._prefill_chunks,
             # Output tokens by where they were decided (see __init__).

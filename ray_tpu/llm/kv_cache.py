@@ -28,11 +28,17 @@ are the major ones, which is how XLA's scatter wants them, and a row of
 ``kv_heads * head_dim`` is a whole number of 128-lane tiles at every
 served width (768 = 6 x 128 at GPT-2-small), so the TPU runtime keeps
 the array at rest row-major and unpadded and a write lands where it
-is. (Head-major, ``[..., kv_heads, num_blocks, block_size, head_dim]``
+is. The shape comes from the model's cache description (the serving
+seam, models/__init__.py): one pool a KIND of layer, ``[layers of the
+kind, num_blocks, block_size, kv_heads * head_dim]``. A model whose
+layers all keep every token has one kind and one pool
+(``PagedKVCache`` / ``PrefixPool``); a kind with a window keeps only
+the blocks that cover a sequence's last ``window`` tokens in a pool of
+its own (``WindowPool``), so a lane holds two block tables. (Head-major, ``[..., kv_heads, num_blocks, block_size, head_dim]``
 with a 64-wide minor dimension, the runtime kept it in a compact layout
 that no reader or writer wanted, and every program converted the whole
-pool there and back: PERF.md section 6, PR 31.) Only the paged kernel
-still reads a layer head-major (models/gpt.py makes that view).
+pool there and back: PERF.md section 6, PR 31.) Only models/gpt.py's
+paged call still reads a layer head-major (it makes that view).
 """
 
 from __future__ import annotations
@@ -46,7 +52,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import GPTConfig
+from ..models import serving
 
 
 # A device trace's ``XLA Modules`` line names each program after its
@@ -67,21 +73,30 @@ def kv_copy_block(k_pool, v_pool, src, dst):
             v_pool.at[:, dst].set(v_pool[:, src]))
 
 
+def window_table_len(window: int, block_size: int, rows: int = 1) -> int:
+    """Most blocks a lane holds of a kind of layer with a window while
+    ``rows`` new tokens are written (``WindowPool``: the window, one
+    block's worth of positions of slack, wherever they start in a
+    block): window / block_size + 2 for one row."""
+    return -(-(window - 1 + rows) // block_size) + 2
+
+
 class PagedKVCache:
     """The pool + its free-list allocator. Sequence bookkeeping (block
     tables, context lengths) belongs to the engine; this class owns the
     device arrays and which blocks are free."""
 
-    def __init__(self, cfg: GPTConfig, num_blocks: int = 64,
-                 block_size: int = 16, dtype=None):
+    def __init__(self, cfg, num_blocks: int = 64, block_size: int = 16,
+                 dtype=None, kind: int = 0):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is reserved)")
-        self.cfg = cfg
+        # Which of the model's kinds of layer this pool holds.
+        self.kind = serving(cfg).kinds[kind]
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        self.dtype = dtype if dtype is not None else cfg.dtype
-        shape = (cfg.n_layer, num_blocks, block_size,
-                 cfg.kv_heads * cfg.head_dim)
+        self.dtype = dtype if dtype is not None else self.kind.dtype
+        shape = (len(self.kind.layers), num_blocks, block_size,
+                 self.kind.kv_width)
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
         # LIFO free list (hot blocks rotate), block 0 reserved.
@@ -172,7 +187,7 @@ class PagedKVCache:
         """Read back ``length`` tokens' K/V as ``[L, length, Hkv, d]``
         (tests / debugging — the decode path never materializes this)."""
         ids = jnp.asarray(block_ids, jnp.int32)
-        hkv, d = self.cfg.kv_heads, self.cfg.head_dim
+        hkv, d = self.kind.kv_heads, self.kind.head_dim
         k = jnp.take(self.k, ids, axis=1)   # [L, nb, BS, Hkv * d]
         v = jnp.take(self.v, ids, axis=1)
         L, nb, bs, _ = k.shape
@@ -214,10 +229,10 @@ class PrefixPool(PagedKVCache):
     ``events`` — the I408 lint row holds these sites to it.
     """
 
-    def __init__(self, cfg: GPTConfig, num_blocks: int = 64,
-                 block_size: int = 16, dtype=None):
+    def __init__(self, cfg, num_blocks: int = 64, block_size: int = 16,
+                 dtype=None, kind: int = 0):
         super().__init__(cfg, num_blocks=num_blocks,
-                         block_size=block_size, dtype=dtype)
+                         block_size=block_size, dtype=dtype, kind=kind)
         self._ref: Dict[int, int] = {}        # bid -> live references
         self._keys_of: Dict[int, List[int]] = {}  # bid -> index keys
         # key -> (parent_key, chunk_tokens, bid, span)
@@ -288,6 +303,10 @@ class PrefixPool(PagedKVCache):
 
     # -- prefix index ------------------------------------------------------
 
+    def match(self, seq: List[int]) -> int:
+        """Tokens of ``seq`` the index covers (nothing acquired)."""
+        return self._match(seq)[1]
+
     def _match(self, seq: List[int]) -> Tuple[List[int], int]:
         """Longest cached chain for ``seq``: (block ids, tokens
         covered). Full block-sized chunks must match contiguously; the
@@ -327,13 +346,19 @@ class PrefixPool(PagedKVCache):
         self._match_cache[sh] = (seqt, tuple(bids), covered)
         return bids, covered
 
-    def admit(self, seq: List[int],
-              need_tokens: int) -> Optional[Tuple[List[int], int]]:
+    def admit(self, seq: List[int], need_tokens: int,
+              upto: Optional[int] = None
+              ) -> Optional[Tuple[List[int], int]]:
         """Build a block table for a sequence: cached-chain blocks are
         acquired (ref++), the remainder freshly allocated. Returns
         (block_table, cached_tokens) or None if the pool cannot cover
-        the fresh remainder (nothing acquired in that case)."""
+        the fresh remainder (nothing acquired in that case). ``upto``
+        (a whole number of blocks below the match) cuts the match
+        short: another kind of layer holds less of this prefix
+        (``WindowPool.match_tail``)."""
         bids, cached = self._match(seq)
+        if upto is not None and upto < cached:
+            bids, cached = bids[:upto // self.block_size], upto
         self.lookup_tokens += len(seq)
         ref, lru = self._ref, self._lru
         for b in bids:
@@ -464,3 +489,180 @@ class PrefixPool(PagedKVCache):
             "shared_blocks": self.shared_blocks(),
             "cached_blocks": len(self._lru),
         }
+
+
+class WindowPool(PrefixPool):
+    """The pool of a kind of layer with a window: a sequence keeps only
+    the blocks that cover its last ``window`` tokens, so its table here
+    is ``(first, blocks)``: ``blocks[i]`` holds the sequence's block
+    ``first + i``, and blocks before ``first`` have slid out and gone
+    back to the pool. Keys are stored after rotary, so a block's
+    content does not depend on where in a table it is read.
+
+    What a lane keeps (``keep_from``): the blocks a query at its next
+    position sees, and one block's worth of positions more, so that a
+    sequence released at ``n`` tokens can be taken up again at the
+    block boundary below ``n`` (preempt and resume). That is at most
+    ``window / block_size + 2`` blocks a lane (``table_len``).
+
+    The prefix index is the ``PrefixPool``'s, under the same chain
+    keys, with another answer to "how much of this prefix is cached":
+    a prefix of n tokens can be taken up only if the blocks covering
+    the window behind position n are all still held (``match_tail``).
+    Tails are indexed when a sequence is released (``release``), so a
+    prefix is reusable at the ends of earlier sequences: a shared
+    context that was sent alone, a conversation's last turn, a
+    preempted lane. Blocks that slide out of a live lane's window
+    while indexed park on the LRU list like any released block.
+    """
+
+    def __init__(self, cfg, num_blocks: int = 64, block_size: int = 16,
+                 dtype=None, kind: int = 1):
+        super().__init__(cfg, num_blocks=num_blocks,
+                         block_size=block_size, dtype=dtype, kind=kind)
+        if self.kind.window is None:
+            raise ValueError(f"kind {self.kind.name!r} has no window")
+        self.window = int(self.kind.window)
+        self.slid_blocks = 0        # blocks that left a live window
+        # Parked blocks that no later sequence has taken up yet, oldest
+        # first: the first to be evicted. A tail that was matched once
+        # (a shared context's) outlives the tails of finished requests,
+        # which park 30-odd blocks each and are mostly never asked for
+        # again: under plain LRU they pushed the contexts' tails out
+        # between two requests of a context, and an 8,192-token prefix
+        # was computed anew (PERF.md section 6, PR 32).
+        self._cold: "collections.OrderedDict[int, None]" = \
+            collections.OrderedDict()
+        self._taken: set = set()    # blocks some sequence took up
+
+    def keep_from(self, position: int) -> int:
+        """First block a lane keeps when its next query sits at
+        ``position``: the oldest block that query sees, less one
+        block's worth of positions (class docstring)."""
+        return max(0, position - self.window + 1 - self.block_size) \
+            // self.block_size
+
+    def slide(self, table: List[int], first: int, position: int) -> int:
+        """Drop (IN PLACE) the blocks of ``table`` that a lane whose
+        next query sits at ``position`` no longer keeps; returns the
+        new ``first``."""
+        n = min(self.keep_from(position) - first, len(table))
+        if n <= 0:
+            return first
+        self._unref(table[:n])
+        del table[:n]
+        self.slid_blocks += n
+        return first + n
+
+    # -- prefix index ------------------------------------------------------
+
+    def _chain(self, seq) -> List[Tuple]:
+        """(key, parent, chunk) a block of ``seq``, the ragged tail
+        last: the PrefixPool's chain keys."""
+        bs = self.block_size
+        seqt = tuple(seq)
+        out, parent = [], 0
+        for i in range(0, len(seqt), bs):
+            chunk = seqt[i:i + bs]
+            key = hash((parent, chunk))
+            out.append((key, parent, chunk))
+            parent = key
+        return out
+
+    def match_tail(self, seq: List[int], cached: int
+                   ) -> Tuple[int, int, List[int]]:
+        """The longest prefix of ``seq``, at most ``cached`` tokens (the
+        full kind's match: whole blocks, or all of ``seq``), whose
+        window tail is held here: ``(n, first, blocks)``, the blocks
+        covering the window behind position n (not acquired:
+        ``acquire``), or ``(0, 0, [])``. Below ``cached`` only block
+        boundaries are tried."""
+        bs, index = self.block_size, self._index
+        chain = self._chain(seq[:cached])
+        n = cached
+        while n > 0:
+            last = -(-n // bs) - 1
+            first = max(0, n - self.window) // bs
+            bids = []
+            for i in range(last, first - 1, -1):
+                key, parent, chunk = chain[i]
+                e = index.get(key)
+                if e is None or e[0] != parent or e[1] != chunk \
+                        or e[3] != len(chunk):
+                    break
+                bids.append(e[2])
+            else:
+                return n, first, bids[::-1]
+            n = (n - 1) // bs * bs
+            chain = chain[:n // bs]
+        return 0, 0, []
+
+    def _unref(self, blocks: List[int]) -> None:
+        super()._unref(blocks)
+        for b in blocks:
+            if b in self._lru and b not in self._taken:
+                self._cold[b] = None
+
+    def alloc(self, n: int) -> Optional[List[int]]:
+        grant = super().alloc(n)
+        for b in grant or ():
+            self._taken.discard(b)      # a new life for the block
+        return grant
+
+    def _evict_one(self) -> None:
+        """Never-taken parked blocks go first, oldest first; then the
+        PrefixPool's order."""
+        if self._cold:
+            bid, _ = self._cold.popitem(last=False)
+            self._lru.move_to_end(bid, last=False)
+        super()._evict_one()
+
+    def acquire(self, blocks: List[int]) -> None:
+        """One reference more on each of ``blocks`` (a matched tail)."""
+        for b in blocks:
+            r = self._ref.get(b, 0)
+            if r == 0:
+                self._lru.pop(b, None)
+                self._cold.pop(b, None)
+            self._taken.add(b)
+            self._ref[b] = r + 1
+        if blocks:
+            self._event("share", blocks=len(blocks),
+                        tokens=len(blocks) * self.block_size)
+
+    def register_tail(self, seq: List[int], table: List[int],
+                      first: int) -> None:
+        """Index the blocks of ``table`` (the sequence's blocks from
+        ``first`` on) that ``seq`` fills. First writer wins a key."""
+        chain = self._chain(seq)
+        newly = 0
+        for i in range(first, min(len(chain), first + len(table))):
+            key, parent, chunk = chain[i]
+            if key not in self._index:
+                bid = table[i - first]
+                self._index[key] = (parent, chunk, bid, len(chunk))
+                self._keys_of.setdefault(bid, []).append(key)
+                newly += 1
+        if newly:
+            self.registrations += newly
+            self._event("register", blocks=newly, tokens=len(seq))
+
+    def release(self, blocks: List[int], seq: Optional[List[int]] = None,
+                first: int = 0) -> None:
+        """Drop one reference a block; ``seq`` (the resident tokens)
+        indexes the tail first."""
+        if seq:
+            self.register_tail(seq, blocks, first)
+        self._unref(blocks)
+
+    def truncate(self, table: List[int], keep_tokens: int,
+                 first: int = 0) -> List[int]:
+        """``PagedKVCache.truncate`` for a table that starts at the
+        sequence's block ``first``."""
+        nb = max(self.blocks_for_tokens(keep_tokens) - first, 0)
+        if nb >= len(table):
+            return []
+        surplus = table[nb:]
+        del table[nb:]
+        self.free(surplus)
+        return surplus

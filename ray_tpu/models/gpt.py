@@ -512,6 +512,40 @@ def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
     return _head(params, x, cfg), k, v
 
 
+def cost_shape(cfg: GPTConfig) -> dict:
+    """The cost description util/perfmodel.py prices this model's steps
+    from: matmul weights a token passes (W), the attention coefficient
+    a context position, parameters in all and as stored, KV elements a
+    token."""
+    m, f, L = cfg.d_model, cfg.ff, cfg.n_layer
+    h, hk, d = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    per_layer = m * h * d + 2 * m * hk * d + h * d * m + 2 * m * f
+    n = cfg.num_params()
+    return {
+        "matmul_weights": L * per_layer + cfg.vocab_size * m,
+        "attn_per_ctx": 4.0 * m * L,     # flops per token per context pos
+        "attn_windows": (),              # no layer with a window
+        "num_params": n,
+        "streamed_params": lambda rows: n,   # every weight, every step
+        "param_bytes": 4,                # f32 parameters
+        "kv_bytes_per_token": 2 * L * hk * d,   # k+v elements per token
+        "m": m, "L": L,
+    }
+
+
+def serving(cfg: GPTConfig):
+    """This model behind the serving seam (models/__init__.py): one
+    kind of layer, every layer keeps every token."""
+    from . import LayerKind, Serving
+
+    full = LayerKind("full", tuple(range(cfg.n_layer)), cfg.kv_heads,
+                     cfg.head_dim, None, cfg.dtype)
+    return Serving(init=init, step=forward_step,
+                   chunk=forward_prefill_chunk, kinds=(full,),
+                   cost=cost_shape(cfg), max_seq=cfg.max_seq,
+                   vocab_size=cfg.vocab_size)
+
+
 @jax.custom_vjp
 def _xent(logits, targets):
     """Mean next-token cross-entropy with a hand-written VJP.

@@ -1,0 +1,555 @@
+"""Laguna (poolside, https://huggingface.co/poolside/Laguna-XS.2): a
+decoder whose layers are of two kinds of attention and two kinds of
+MLP, served through the generation engine (llm/engine.py). No loss and
+no train step: this model is served, not trained, here.
+
+The layer (x is [T, hidden]; layer l has kind ``layer_types[l]`` and
+``H_l = num_attention_heads_per_layer[l]`` query heads over
+``num_key_value_heads`` KV heads of ``head_dim``; no biases; RMSNorm):
+
+  h = RMSNorm(x); q = h W_q [T, H_l, d]; k = h W_k, v = h W_v [T, kv, d];
+  g = sigmoid(h W_g) [T, H_l]                      one gate a head
+  rotary on q and k. "full_attention": the first ``partial_rotary_factor``
+    of a head's dims, YaRN inverse frequencies, cos and sin scaled by
+    ``attention_factor``; "sliding_attention": every dim, unscaled.
+    Pairs are (i, i + rot/2), the ``rotate_half`` convention.
+  query head h reads KV head h // (H_l / kv); scores q k^T / sqrt(d),
+    causal; on a sliding layer query i sees key j only where
+    i - j < sliding_window. o = softmax(scores) v;
+  x <- x + concat_h(g_h * o_h) W_o
+  h2 = RMSNorm(x). "dense": (silu(h2 W_gate) * h2 W_up) W_down.
+    "sparse": p = softmax(h2 W_r) over all experts in float32; the
+    ``num_experts_per_tok`` largest; w = moe_routed_scaling_factor *
+    p_top / sum(p_top); out = sum_e w_e Expert_e(h2) + Shared(h2), each
+    a SwiGLU, the weight on the expert's output (ops/moe.py).
+  x <- x + out
+Final RMSNorm, untied head.
+
+Keys are stored AFTER rotary, so a cached key needs no position again
+and a window layer's table needs no order. ``models/laguna_ref.py`` is
+the plain float32 reference of the same equations.
+
+Behind the serving seam (models/__init__.py) the cache has two kinds:
+"full" layers keep every token, "window" layers the blocks that cover
+a sequence's last ``sliding_window`` tokens. The layers are unrolled,
+the kinds interleaved as published; layer i of a kind lives at index i
+of that kind's pool ``[layers_of_kind, num_blocks, block_size,
+kv_heads * head_dim]``. The decode step's paged call
+(ops/pallas/paged_decode.py ``paged_attention_stored``) takes its page
+windows from that pool as stored: at head_dim 128 a row is whole lane
+tiles, and no head-major view is made.
+
+The window kind's int32 array (``win``), one row a lane in a decode
+step: ``[table (window_table_len entries), first block, slot block a
+row]``: the blocks from the window's oldest on, that block's index in
+the sequence, and where each row's K/V is written. A chunk's is
+``[table, first block]``.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from dataclasses import dataclass
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops import moe
+from ..ops.attention import NEG_INF
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+DENSE, SPARSE = "dense", "sparse"
+
+
+@dataclass(frozen=True)
+class LagunaConfig:
+    """Field names are the published config.json's; ``max_seq`` is the
+    deployment's limit and ``dtype`` what weights, activations and KV
+    are held in. Lists arrive from JSON and are frozen to tuples; the
+    two ``rope_*`` entries are (key, value) pairs of the published
+    ``rope_parameters`` groups."""
+    vocab_size: int = 100352
+    hidden_size: int = 2048
+    intermediate_size: int = 8192
+    num_hidden_layers: int = 40
+    num_attention_heads_per_layer: Tuple[int, ...] = ()
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    rms_norm_eps: float = 1e-6
+    num_experts: int = 256
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    moe_routed_scaling_factor: float = 2.5
+    sliding_window: int = 512
+    layer_types: Tuple[str, ...] = ()
+    mlp_layer_types: Tuple[str, ...] = ()
+    rope_full: Any = (("rope_theta", 500000.0), ("rope_type", "yarn"),
+                      ("factor", 64.0),
+                      ("original_max_position_embeddings", 4096),
+                      ("beta_slow", 1.0), ("beta_fast", 64.0),
+                      ("attention_factor", 1.4158883083359672),
+                      ("partial_rotary_factor", 0.5))
+    rope_sliding: Any = (("rope_type", "default"), ("rope_theta", 10000.0),
+                         ("partial_rotary_factor", 1.0))
+    max_seq: int = 9216
+    dtype: Any = "bfloat16"
+
+    def __post_init__(self):
+        def freeze(v):
+            if isinstance(v, dict):
+                return tuple(sorted((k, freeze(x)) for k, x in v.items()))
+            if isinstance(v, (list, tuple)):
+                return tuple(freeze(x) for x in v)
+            return v
+
+        for name in ("num_attention_heads_per_layer", "layer_types",
+                     "mlp_layer_types", "rope_full", "rope_sliding"):
+            object.__setattr__(self, name, freeze(getattr(self, name)))
+        object.__setattr__(self, "dtype", jnp.dtype(self.dtype))
+        L = self.num_hidden_layers
+        for name in ("num_attention_heads_per_layer", "layer_types",
+                     "mlp_layer_types"):
+            if len(getattr(self, name)) != L:
+                raise ValueError(f"{name} needs {L} entries, one a layer")
+
+    def layers_of(self, kind: str) -> Tuple[int, ...]:
+        return tuple(l for l, t in enumerate(self.layer_types) if t == kind)
+
+    def num_params(self) -> int:
+        m, d, kv = self.hidden_size, self.head_dim, self.num_key_value_heads
+        n = 2 * self.vocab_size * m + m
+        for h, mlp in zip(self.num_attention_heads_per_layer,
+                          self.mlp_layer_types):
+            n += 2 * m + m * h * d * 2 + 2 * m * kv * d + m * h
+            if mlp == DENSE:
+                n += 3 * m * self.intermediate_size
+            else:
+                n += (m * self.num_experts
+                      + 3 * m * self.moe_intermediate_size * self.num_experts
+                      + 3 * m * self.shared_expert_intermediate_size)
+        return n
+
+
+# ---------------------------------------------------------------------------
+# Rotary positions
+# ---------------------------------------------------------------------------
+
+
+def rope_inv_freq(rope, head_dim: int):
+    """(inverse frequencies [rot/2] float64, rotated dims, cos/sin
+    scale) of one ``rope_parameters`` group. ``yarn`` blends, per
+    frequency, the interpolated (1 / (factor * base^(2i/rot))) and the
+    extrapolated (1 / base^(2i/rot)) frequency by a linear ramp between
+    the dims whose wavelength fits ``beta_fast`` and ``beta_slow``
+    turns into the original context."""
+    r = dict(rope)
+    rot = int(head_dim * r.get("partial_rotary_factor", 1.0))
+    base = float(r["rope_theta"])
+    pos = base ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+    if r.get("rope_type", "default") == "default":
+        return 1.0 / pos, rot, 1.0
+    if r["rope_type"] != "yarn":
+        raise ValueError(f"rope_type {r['rope_type']!r}")
+    orig = r["original_max_position_embeddings"]
+
+    def correction_dim(turns):
+        return rot * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(r["beta_fast"])), 0)
+    high = min(math.ceil(correction_dim(r["beta_slow"])), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    inv = (1.0 / (r["factor"] * pos)) * ramp + (1.0 / pos) * (1.0 - ramp)
+    return inv, rot, float(r["attention_factor"])
+
+
+def _rotary(x, positions, rope, head_dim: int):
+    """Rotate the leading ``rot`` dims of x [..., heads, d] at
+    ``positions`` (x's leading dims); float32 angles, x's dtype out."""
+    inv, rot, scale = rope_inv_freq(rope, head_dim)
+    ang = positions[..., None].astype(jnp.float32) \
+        * jnp.asarray(inv, jnp.float32)
+    cos = (jnp.cos(ang) * scale)[..., None, :]
+    sin = (jnp.sin(ang) * scale)[..., None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2, rest = (x32[..., :rot // 2], x32[..., rot // 2:rot],
+                    x32[..., rot:])
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+        axis=-1).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+
+def init(key, cfg: LagunaConfig) -> dict:
+    """Seeded random parameters in ``cfg.dtype``, a layer at a time
+    (``init_layer``), so that a reader that cannot hold the model twice
+    can make one layer again from the same key. Each distinct layer
+    shape is one compiled program (a layer's tensors made leaf by leaf
+    took 42 s for this model's 3.9 B parameters on the chip)."""
+    return {
+        **_init_ends(jax.random.fold_in(key, 1 << 20), cfg),
+        "layers": [init_layer(key, cfg, l)
+                   for l in range(cfg.num_hidden_layers)],
+    }
+
+
+def _normal(key, shape, std, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _init_ends(key, cfg: LagunaConfig) -> dict:
+    m, V = cfg.hidden_size, cfg.vocab_size
+    ke, kh = jax.random.split(key)
+    return {"embed": _normal(ke, (V, m), 0.02, cfg.dtype),
+            "head": _normal(kh, (m, V), 0.02, cfg.dtype),
+            "norm_f": jnp.ones((m,), cfg.dtype)}
+
+
+def init_layer(key, cfg: LagunaConfig, l: int) -> dict:
+    """Layer ``l``'s parameters, from ``fold_in(key, l)``."""
+    return _init_layer(jax.random.fold_in(key, l), cfg,
+                       cfg.num_attention_heads_per_layer[l],
+                       cfg.mlp_layer_types[l])
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "h", "mlp"))
+def _init_layer(key, cfg: LagunaConfig, h: int, mlp: str) -> dict:
+    m, d, kv = cfg.hidden_size, cfg.head_dim, cfg.num_key_value_heads
+    dt, std = cfg.dtype, 0.02
+    out_std = std / math.sqrt(2 * cfg.num_hidden_layers)
+    k = iter(jax.random.split(key, 12))
+    p = {
+        "ln1": jnp.ones((m,), dt), "ln2": jnp.ones((m,), dt),
+        "wq": _normal(next(k), (m, h, d), std, dt),
+        "wk": _normal(next(k), (m, kv, d), std, dt),
+        "wv": _normal(next(k), (m, kv, d), std, dt),
+        "wg": _normal(next(k), (m, h), std, dt),
+        "wo": _normal(next(k), (h, d, m), out_std, dt),
+    }
+    if mlp == DENSE:
+        f = cfg.intermediate_size
+        p["w_gu"] = _normal(next(k), (m, 2 * f), std, dt)
+        p["w_down"] = _normal(next(k), (f, m), out_std, dt)
+    else:
+        E, f = cfg.num_experts, cfg.moe_intermediate_size
+        fs = cfg.shared_expert_intermediate_size
+        p["router"] = _normal(next(k), (m, E), std, dt)
+        p["w1"] = _normal(next(k), (E, m, 2 * f), std, dt)
+        p["w2"] = _normal(next(k), (E, f, m), out_std, dt)
+        p["s_gu"] = _normal(next(k), (m, 2 * fs), std, dt)
+        p["s_down"] = _normal(next(k), (fs, m), out_std, dt)
+    return p
+
+
+# ---------------------------------------------------------------------------
+# The layer
+# ---------------------------------------------------------------------------
+
+
+def _rmsnorm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    out = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+    return (out * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _swiglu(h, w_gu, w_down):
+    gu = jnp.dot(h, w_gu)
+    f = gu.shape[-1] // 2
+    act = (jax.nn.silu(gu[..., :f].astype(jnp.float32))
+           * gu[..., f:].astype(jnp.float32)).astype(h.dtype)
+    return jnp.dot(act, w_down)
+
+
+def _mlp(h2, p, cfg: LagunaConfig, program: str):
+    """h2 [T, m] -> (out [T, m], (experts hit, busiest expert's tokens)
+    or None for a dense layer). The grouped product's kernel is
+    ``moe_experts_<program>`` on a device trace, so a reader can tell a
+    decode step's from a chunk's by name."""
+    if "router" not in p:
+        return _swiglu(h2, p["w_gu"], p["w_down"]), None
+    with jax.named_scope("moe_route"):
+        _, experts, weights = moe.route(
+            h2, p["router"], cfg.num_experts_per_tok,
+            cfg.moe_routed_scaling_factor)
+    with jax.named_scope("moe_experts"):
+        y, sizes = moe.routed_experts(h2, experts, weights, p["w1"], p["w2"],
+                                      name=f"moe_experts_{program}")
+    out = y + _swiglu(h2, p["s_gu"], p["s_down"])
+    return out, ((sizes > 0).sum().astype(jnp.int32), sizes.max())
+
+
+def _block(x, p, cfg: LagunaConfig, attend, program: str):
+    """The one layer, on [batch, rows, m]: norm -> gated attention ->
+    residual -> norm -> MLP -> residual. ``attend(q, k, v)`` is the
+    mode's attention: it rotates q [b, r, H, d] and this call's own k
+    [b, r, kv, d], keeps k and v where the mode keeps them, and returns
+    o [b, r, H, d]. Returns (x, routing counts or None)."""
+    b, r, m = x.shape
+    h = _rmsnorm(x, p["ln1"], cfg.rms_norm_eps)
+    q = jnp.einsum("brm,mhd->brhd", h, p["wq"])
+    k = jnp.einsum("brm,mhd->brhd", h, p["wk"])
+    v = jnp.einsum("brm,mhd->brhd", h, p["wv"])
+    gate = jax.nn.sigmoid(jnp.einsum("brm,mh->brh", h, p["wg"])
+                          .astype(jnp.float32))
+    o = attend(q, k, v)
+    o = (o.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
+    x = x + jnp.einsum("brhd,hdm->brm", o, p["wo"])
+    h2 = _rmsnorm(x, p["ln2"], cfg.rms_norm_eps)
+    out, counts = _mlp(h2.reshape(b * r, m), p, cfg, program)
+    return x + out.reshape(b, r, m), counts
+
+
+def _kind_index(cfg: LagunaConfig):
+    """layer -> (is_window, index in its kind's pool)."""
+    seen = {FULL: 0, SLIDING: 0}
+    out = []
+    for t in cfg.layer_types:
+        out.append((t == SLIDING, seen[t]))
+        seen[t] += 1
+    return out
+
+
+def _head(params, x, cfg: LagunaConfig):
+    x = _rmsnorm(x, params["norm_f"], cfg.rms_norm_eps)
+    return jnp.einsum("brm,mv->brv", x, params["head"])
+
+
+def _counters(counts, n_assignments: int, n_experts: int, q: int):
+    """The step's two counter rows [2, q] int32: experts hit (mean over
+    the routed layers) and 1000 x the busiest expert's tokens over the
+    mean (the worst layer)."""
+    if not counts:
+        return jnp.zeros((2, q), jnp.int32)
+    hit = sum(c[0] for c in counts) // len(counts)
+    load = jnp.max(jnp.stack([c[1] for c in counts]))
+    load = (load * (1000 * n_experts)) // n_assignments
+    return jnp.broadcast_to(jnp.stack([hit, load])[:, None],
+                            (2, q)).astype(jnp.int32)
+
+
+COUNTERS = ("moe_experts_hit", "moe_load_max_x1000")
+
+
+def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
+                 context_lens, q_lens, slot_blocks, slot_offsets,
+                 k_win, v_win, win, cfg: LagunaConfig):
+    """One decode step (models/gpt.py ``forward_step``'s contract) over
+    two kinds of pool. ``win`` [b, nbw + 1 + q] is the window kind's
+    array (module docstring); a window layer writes each row at
+    (its index, win slot block, slot_offsets) and attends over the
+    lane's window table, whose context counts from the table's first
+    block.
+
+    Returns (logits [b, q, vocab], ids [b + 2, q] int32, k_pool, v_pool,
+    k_win, v_win): rows b and b + 1 of ``ids`` are ``COUNTERS``."""
+    from ..ops.pallas.paged_decode import paged_attention_stored
+
+    B, Q = tokens.shape
+    kv, d = cfg.num_key_value_heads, cfg.head_dim
+    bs = k_pool.shape[2]
+    nbw = win.shape[1] - 1 - Q
+    win_tables, win_first = win[:, :nbw], win[:, nbw]
+    win_slots = win[:, nbw + 1:]
+    win_lens = context_lens - win_first * bs
+    # Row i of a lane sits at ctx - q_len + i and sees keys from that
+    # less (window - 1) on, in the window table's own coordinates.
+    win_starts = win_lens - q_lens - (cfg.sliding_window - 1)
+    no_start = jnp.zeros_like(context_lens)
+    x = params["embed"][tokens]
+    counts = []
+    for (window, li), p in zip(_kind_index(cfg), params["layers"]):
+        rope = cfg.rope_sliding if window else cfg.rope_full
+
+        def attend(q, k, v, window=window, li=li, rope=rope):
+            nonlocal k_pool, v_pool, k_win, v_win
+            q = _rotary(q, positions, rope, d)
+            k = _rotary(k, positions, rope, d).reshape(B, Q, kv * d)
+            v = v.reshape(B, Q, kv * d)
+            H = q.shape[2]
+            qg = q.reshape(B, Q, kv, H // kv, d)
+            if window:
+                k_win = k_win.at[li, win_slots, slot_offsets].set(k)
+                v_win = v_win.at[li, win_slots, slot_offsets].set(v)
+                with jax.named_scope("attn_window"):
+                    o = paged_attention_stored(
+                        qg, k_win, v_win, li, win_tables, win_lens, q_lens,
+                        win_starts, name="attn_window")
+            else:
+                k_pool = k_pool.at[li, slot_blocks, slot_offsets].set(k)
+                v_pool = v_pool.at[li, slot_blocks, slot_offsets].set(v)
+                with jax.named_scope("attn_full"):
+                    o = paged_attention_stored(
+                        qg, k_pool, v_pool, li, block_tables, context_lens,
+                        q_lens, no_start, name="attn_full")
+            return o.reshape(B, Q, H, d)
+
+        x, c = _block(x, p, cfg, attend, "decode")
+        if c is not None:
+            counts.append(c)
+    logits = _head(params, x, cfg)
+    ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    ids = jnp.concatenate([ids, _counters(
+        counts, B * Q * cfg.num_experts_per_tok, cfg.num_experts, Q)])
+    return logits, ids, k_pool, v_pool, k_win, v_win
+
+
+def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len, base,
+                     window: Optional[int]):
+    """A chunk's attention over [pool context ++ chunk], one KV head at
+    a time (a head's scores are [group, c, S + c] float32; all heads at
+    once would be the whole step's largest temporary).
+
+    q [c, H, d]; k_tok, v_tok [c, kv, d]: the chunk, whose query i sits
+    at absolute position ctx_len + i. k_ctx, v_ctx [S, kv, d]: the
+    sequence's gathered pool slots, slot s at absolute position
+    base + s, real where that is below ctx_len. With a ``window`` a
+    query sees only keys less than ``window`` positions behind it."""
+    c, H, d = q.shape
+    S, kv = k_ctx.shape[:2]
+    g = H // kv
+    k = jnp.concatenate([k_ctx.astype(q.dtype), k_tok], axis=0)
+    v = jnp.concatenate([v_ctx.astype(q.dtype), v_tok], axis=0)
+    q_pos = ctx_len + jnp.arange(c)
+    k_pos = jnp.concatenate([base + jnp.arange(S), q_pos])
+    seen = jnp.concatenate([k_pos[:S] < ctx_len, jnp.ones((c,), bool)])
+    mask = seen[None, :] & (k_pos[None, :] <= q_pos[:, None])
+    if window is not None:
+        mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
+
+    def one_head(args):
+        qh, kh, vh = args                       # [g, c, d], [S+c, d] x 2
+        s = jnp.einsum("gcd,kd->gck", qh, kh,
+                       preferred_element_type=jnp.float32) * (d ** -0.5)
+        s = jnp.where(mask[None], s, NEG_INF)
+        pr = jax.nn.softmax(s, axis=-1).astype(qh.dtype)
+        return jnp.einsum("gck,kd->gcd", pr, vh)
+
+    o = jax.lax.map(one_head, (
+        q.reshape(c, kv, g, d).transpose(1, 2, 0, 3),
+        k.transpose(1, 0, 2), v.transpose(1, 0, 2)))      # [kv, g, c, d]
+    return o.transpose(2, 0, 1, 3).reshape(c, H, d)
+
+
+def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
+                          block_table, ctx_len, k_win, v_win, win,
+                          cfg: LagunaConfig):
+    """One span of a prompt (models/gpt.py ``forward_prefill_chunk``'s
+    contract): ``tokens`` [1, c] at ``positions`` [c], whose context
+    sits in the pools, read only here. ``block_table`` [nb] (empty for
+    a span from the prompt's start) is the full kind's; ``win`` [nbw +
+    1] the window kind's table and its first block's index: the blocks
+    that cover the window behind the chunk's first token.
+
+    Returns (logits [1, c, vocab], k, v [full layers, 1, c, kv, d],
+    k, v [window layers, 1, c, kv, d]): the caller writes them into the
+    pools, the window kind's only where the blocks are still in a
+    window."""
+    kv, d = cfg.num_key_value_heads, cfg.head_dim
+    bs = k_pool.shape[2]
+    win_table, win_base = win[:-1], win[-1] * bs
+    pos = positions[None]
+    x = params["embed"][tokens]
+    new = {False: ([], []), True: ([], [])}
+    for (window, li), p in zip(_kind_index(cfg), params["layers"]):
+        rope = cfg.rope_sliding if window else cfg.rope_full
+
+        def attend(q, k, v, window=window, li=li, rope=rope):
+            q = _rotary(q, pos, rope, d)
+            k = _rotary(k, pos, rope, d)
+            pool_k, pool_v, table, base = (
+                (k_win, v_win, win_table, win_base) if window
+                else (k_pool, v_pool, block_table, 0))
+            slots = table.shape[0] * bs
+            k_ctx = pool_k[li, table].reshape(slots, kv, d)
+            v_ctx = pool_v[li, table].reshape(slots, kv, d)
+            with jax.named_scope("attn_window" if window else "attn_full"):
+                o = _chunk_attention(
+                    q[0], k[0], v[0], k_ctx, v_ctx, ctx_len, base,
+                    cfg.sliding_window if window else None)
+            new[window][0].append(k)
+            new[window][1].append(v)
+            return o[None]
+
+        x, _ = _block(x, p, cfg, attend, "chunk")
+    return (_head(params, x, cfg),
+            *(jnp.stack(new[w][i]) for w in (False, True) for i in (0, 1)))
+
+
+# ---------------------------------------------------------------------------
+# The serving seam
+# ---------------------------------------------------------------------------
+
+
+def cost_shape(cfg: LagunaConfig) -> dict:
+    """The cost description util/perfmodel.py prices steps from. A
+    token passes the attention and shared weights, the router, its
+    ``num_experts_per_tok`` experts and the head; a step of n rows
+    streams those and, of each routed layer, the experts that n * k
+    uniform choices are expected to hit."""
+    m, d, kv = cfg.hidden_size, cfg.head_dim, cfg.num_key_value_heads
+    E, k = cfg.num_experts, cfg.num_experts_per_tok
+    expert = 3 * m * cfg.moe_intermediate_size
+    active = cfg.vocab_size * m
+    always = 2 * cfg.vocab_size * m
+    full_coef, windows, routed = 0.0, [], 0
+    for h, kind, mlp in zip(cfg.num_attention_heads_per_layer,
+                            cfg.layer_types, cfg.mlp_layer_types):
+        attn = 2 * m * h * d + 2 * m * kv * d + m * h
+        if mlp == DENSE:
+            mlp_w = 3 * m * cfg.intermediate_size
+            active += attn + mlp_w
+            always += attn + mlp_w
+        else:
+            shared = 3 * m * cfg.shared_expert_intermediate_size + m * E
+            active += attn + shared + k * expert
+            always += attn + shared
+            routed += 1
+        if kind == SLIDING:
+            windows.append((4.0 * h * d, cfg.sliding_window))
+        else:
+            full_coef += 4.0 * h * d
+    n_full = len(cfg.layers_of(FULL))
+
+    def streamed(rows):
+        hit = E * (1.0 - (1.0 - k / E) ** max(rows, 0))
+        return always + routed * hit * expert
+
+    return {
+        "matmul_weights": active,
+        "attn_per_ctx": full_coef,
+        "attn_windows": tuple(windows),
+        "num_params": cfg.num_params(),
+        "streamed_params": streamed,
+        "param_bytes": cfg.dtype.itemsize,
+        # K+V elements a token in the layers that keep every token (the
+        # window layers' reads are bounded and left out).
+        "kv_bytes_per_token": 2 * n_full * kv * d,
+        "m": m, "L": cfg.num_hidden_layers,
+    }
+
+
+def serving(cfg: LagunaConfig):
+    from . import LayerKind, Serving
+
+    kv, d = cfg.num_key_value_heads, cfg.head_dim
+    kinds = (LayerKind("full", cfg.layers_of(FULL), kv, d, None, cfg.dtype),
+             LayerKind("window", cfg.layers_of(SLIDING), kv, d,
+                       cfg.sliding_window, cfg.dtype))
+    if not kinds[0].layers or not kinds[1].layers:
+        raise ValueError("the served Laguna needs layers of both kinds")
+    return Serving(init=init, step=forward_step,
+                   chunk=forward_prefill_chunk, kinds=kinds,
+                   cost=cost_shape(cfg), max_seq=cfg.max_seq,
+                   vocab_size=cfg.vocab_size, counters=COUNTERS)

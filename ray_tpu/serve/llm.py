@@ -50,7 +50,9 @@ class _LLMServer:
     ``build_app()`` for the common case)."""
 
     def __init__(self, cfg=None, params=None, *, seed: int = 0,
-                 num_blocks: int = 64, block_size: int = 16,
+                 num_blocks: int = 64,
+                 window_blocks: Optional[int] = None,
+                 block_size: int = 16,
                  max_batch: int = 8, default_max_tokens: int = 32,
                  prefill_chunk_tokens: Optional[int] = 32,
                  prefix_cache: bool = True,
@@ -59,11 +61,14 @@ class _LLMServer:
         import jax
 
         from ..llm.engine import LLMEngine
-        from ..models.gpt import TINY, init
+        from ..models import serving
+        from ..models.gpt import TINY
 
+        # Any configuration whose module stands behind the serving seam
+        # (models/__init__.py); the default is the tiny GPT preset.
         cfg = cfg if cfg is not None else TINY
         if params is None:
-            params = init(jax.random.PRNGKey(seed), cfg)
+            params = serving(cfg).init(jax.random.PRNGKey(seed), cfg)
         # Replica.__init__ sets the process deployment name before
         # constructing us — tag the engine's gauges with it.
         name = slo.current_deployment() or "llm"
@@ -80,7 +85,11 @@ class _LLMServer:
         # ``speculative`` (None | dict | SpecConfig — llm/spec.py) turns
         # decode steps into k+1-position verify steps; output tokens are
         # bit-identical either way, so it is purely a throughput knob.
+        # ``window_blocks`` sizes the second pool of a model that has a
+        # kind of layer with a window (None: twice what max_batch lanes
+        # hold); a model without one has no such pool.
         self.engine = LLMEngine(params, cfg, num_blocks=num_blocks,
+                                window_blocks=window_blocks,
                                 block_size=block_size,
                                 max_batch=max_batch,
                                 prefill_chunk_tokens=prefill_chunk_tokens,

@@ -103,33 +103,27 @@ def detect_hardware(device=None) -> Optional[HardwarePeak]:
 # these every step)
 # ---------------------------------------------------------------------------
 
-_shape_cache: Dict[int, dict] = {}
-
-
 def _shape(cfg) -> dict:
-    """Per-config constants: matmul-weight count W, per-layer attention
-    coefficient, total params N, KV bytes/token. cfg is any object with
-    GPTConfig's shape fields (d_model/n_layer/ff/kv_heads/head_dim/
-    n_head/vocab_size/num_params)."""
-    key = id(cfg)
-    cached = _shape_cache.get(key)
-    if cached is not None and cached["cfg"] is cfg:
-        return cached
-    m, f, L = cfg.d_model, cfg.ff, cfg.n_layer
-    h, hk, d = cfg.n_head, cfg.kv_heads, cfg.head_dim
-    per_layer = m * h * d + 2 * m * hk * d + h * d * m + 2 * m * f
-    out = {
-        "cfg": cfg,
-        "matmul_weights": L * per_layer + cfg.vocab_size * m,
-        "attn_per_ctx": 4.0 * m * L,     # flops per token per context pos
-        "num_params": cfg.num_params(),
-        "kv_bytes_per_token": 2 * L * hk * d,   # k+v elements per token
-        "m": m, "L": L,
-    }
-    if len(_shape_cache) > 64:
-        _shape_cache.clear()
-    _shape_cache[key] = out
-    return out
+    """The model's cost description, from the serving seam
+    (models/__init__.py: ``serving(cfg).cost``, cached there per
+    configuration): matmul weights a token passes, the attention
+    coefficient a context position (and, for layers with a window,
+    ``attn_windows``: (coefficient, window) pairs), parameters in all,
+    parameters a step of n rows streams, bytes a parameter, KV elements
+    a token."""
+    from ..models import serving
+
+    return serving(cfg).cost
+
+
+def _attn_flops(s: dict, q: float, ctx: float) -> float:
+    """Attention FLOPs of q rows that end a context of ctx tokens
+    (causal within the rows): layers that keep every token see
+    q*ctx - q*(q-1)/2 contexts, a layer with a window at most its
+    window a row."""
+    seen = q * ctx - q * (q - 1) / 2.0
+    return s["attn_per_ctx"] * seen + sum(
+        coef * min(seen, q * w) for coef, w in s["attn_windows"])
 
 
 # ---------------------------------------------------------------------------
@@ -165,7 +159,7 @@ def train_flops_per_token(cfg, seq: Optional[int] = None) -> float:
 def decode_step_cost(cfg, context_lens: Sequence[int],
                      q_lens: Optional[Sequence[int]] = None, *,
                      kv_dtype_bytes: int = 2,
-                     param_bytes: int = 4) -> StepCost:
+                     param_bytes: Optional[int] = None) -> StepCost:
     """One decode step over a batch of lanes: lane i scores
     ``q_lens[i]`` rows (its current token, plus its proposals under
     speculation; one row a lane when omitted) against
@@ -183,14 +177,18 @@ def decode_step_cost(cfg, context_lens: Sequence[int],
     if q_lens is None:
         q_lens = [1] * len(context_lens)
     n_rows = float(sum(q_lens))
-    attn_ctx = 0.0
+    attn = 0.0
     total_ctx = 0.0
     for ctx, q in zip(context_lens, q_lens):
-        attn_ctx += q * ctx - q * (q - 1) / 2.0
+        attn += _attn_flops(s, q, ctx)
         total_ctx += ctx
-    flops = 2.0 * s["matmul_weights"] * n_rows + s["attn_per_ctx"] * attn_ctx
+    flops = 2.0 * s["matmul_weights"] * n_rows + attn
     kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
-    hbm = (s["num_params"] * param_bytes
+    if param_bytes is None:
+        param_bytes = s["param_bytes"]
+    # Weights stream once a step: all of a dense model's, and of a
+    # routed layer the experts the step's rows are expected to hit.
+    hbm = (s["streamed_params"](n_rows) * param_bytes
            + total_ctx * kvb                 # context KV read per lane
            + n_rows * kvb)                   # KV write per scored row
     return StepCost(flops, hbm, int(n_rows))
@@ -198,7 +196,7 @@ def decode_step_cost(cfg, context_lens: Sequence[int],
 
 def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
                  kv_dtype_bytes: int = 2,
-                 param_bytes: int = 4) -> StepCost:
+                 param_bytes: Optional[int] = None) -> StepCost:
     """Prefill of a T-token span whose first ``ctx_tokens`` of context
     already sit in the KV pool (prefix-cache hit or an earlier chunk of
     a chunked prefill — those spans are NOT priced here, so MFU stays
@@ -210,10 +208,13 @@ def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
     s = _shape(cfg)
     T = int(n_tokens)
     ctx = int(ctx_tokens)
-    flops = (2.0 * s["matmul_weights"] * T
-             + s["attn_per_ctx"] * (ctx * T + T * (T + 1) / 2.0))
+    seen = ctx * T + T * (T + 1) / 2.0
+    flops = (2.0 * s["matmul_weights"] * T + s["attn_per_ctx"] * seen
+             + sum(coef * min(seen, T * w) for coef, w in s["attn_windows"]))
     kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
-    hbm = s["num_params"] * param_bytes + (2.0 * T + ctx) * kvb
+    if param_bytes is None:
+        param_bytes = s["param_bytes"]
+    hbm = s["streamed_params"](T) * param_bytes + (2.0 * T + ctx) * kvb
     return StepCost(flops, hbm, T)
 
 

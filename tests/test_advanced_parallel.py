@@ -8,6 +8,7 @@ schedules).
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -126,20 +127,23 @@ def test_pipeline_grad_matches_sequential():
 
 
 def _moe_dense_reference(params, x, cfg):
-    """All-expert dense compute weighted by top-k gates (no capacity)."""
-    logits = x @ params["wg"]
-    gates = jax.nn.softmax(logits, -1)
-    topk_idx = jax.lax.top_k(gates, cfg.k)[1]
-    mask = jax.nn.one_hot(topk_idx, cfg.n_experts).sum(1)
-    wts = gates * mask
-    h = jax.nn.gelu(jnp.einsum("td,edf->tef", x, params["w1"]))
-    eo = jnp.einsum("tef,efd->ted", h, params["w2"])
-    return jnp.einsum("te,ted->td", wts, eo)
+    """A loop over the experts: every expert (SwiGLU) on every token,
+    kept by the router's weight (softmax over all experts, the top k
+    renormalised to ``cfg.scale``, 0 where not chosen). No capacity."""
+    gates = jax.nn.softmax(x @ params["wg"], -1)
+    top, topk_idx = jax.lax.top_k(gates, cfg.k)
+    wts = cfg.scale * top / top.sum(-1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(cfg.n_experts):
+        gu = x @ params["w1"][e]
+        out = (jax.nn.silu(gu[:, :cfg.d_ff]) * gu[:, cfg.d_ff:]) \
+            @ params["w2"][e]
+        y = y + jnp.where(topk_idx == e, wts, 0).sum(-1)[:, None] * out
+    return y
 
 
 def test_moe_local_matches_dense():
-    cfg = MoEConfig(d_model=16, d_ff=32, n_experts=4, k=2,
-                    capacity_factor=8.0)
+    cfg = MoEConfig(d_model=16, d_ff=32, n_experts=4, k=2)
     params = moe_init(jax.random.key(0), cfg)
     x = jax.random.normal(jax.random.key(1), (64, cfg.d_model))
     ref = _moe_dense_reference(params, x, cfg)
@@ -149,8 +153,10 @@ def test_moe_local_matches_dense():
 
 
 def test_moe_expert_parallel_matches_dense():
-    cfg = MoEConfig(d_model=16, d_ff=32, n_experts=4, k=2,
-                    capacity_factor=8.0)
+    """Each ep shard holds a quarter of the experts, routes over all of
+    them and adds what its own give: the shards' parts sum to the
+    uncut layer."""
+    cfg = MoEConfig(d_model=16, d_ff=32, n_experts=4, k=2)
     params = moe_init(jax.random.key(0), cfg)
     B, S = 8, 8
     x = jax.random.normal(jax.random.key(1), (B, S, cfg.d_model))
@@ -171,31 +177,51 @@ def test_moe_expert_parallel_matches_dense():
     assert jnp.isfinite(aux)
 
 
-def test_moe_capacity_drops_tokens():
-    """With a tight capacity factor some tokens are dropped, never crashing."""
-    cfg = MoEConfig(d_model=8, d_ff=16, n_experts=4, k=1,
-                    capacity_factor=0.5)
+def test_moe_drops_no_token_under_skewed_routing():
+    """A router that sends every token to the same two of eight experts
+    (the old fixed-capacity layer dropped most of them): every token
+    still gets both of its experts, and its two weights sum to the
+    scale."""
+    from ray_tpu.ops.moe import TILE_ROWS, dispatch_plan, route
+
+    cfg = MoEConfig(d_model=8, d_ff=16, n_experts=8, k=2, scale=2.5)
     params = moe_init(jax.random.key(0), cfg)
-    x = jax.random.normal(jax.random.key(1), (32, cfg.d_model))
-    y, aux = moe_apply(params, x, cfg)
-    assert y.shape == x.shape
-    # Dropped tokens produce zero output rows; at least some survive.
-    assert jnp.abs(y).sum() > 0
+    params["wg"] = params["wg"].at[:, 2].add(40.0).at[:, 5].add(39.0)
+    x = jnp.abs(jax.random.normal(jax.random.key(1), (96, cfg.d_model)))
+    _, experts, weights = route(x, params["wg"], cfg.k, cfg.scale)
+    assert set(np.asarray(experts).ravel().tolist()) == {2, 5}
+    assert jnp.abs(weights.sum(-1) - 2.5).max() < 1e-5
+    sizes, dest, _, _, n_used = dispatch_plan(experts, cfg.n_experts)
+    assert sizes.tolist() == [0, 0, 96, 0, 0, 96, 0, 0]
+    assert len(set(np.asarray(dest).tolist())) == 192     # a row each
+    assert int(n_used) == 2 * 96 // TILE_ROWS
+    y, _ = moe_apply(params, x, cfg)
+    ref = _moe_dense_reference(params, x, cfg)
+    assert jnp.abs(y - ref).max() < 2e-5
+    assert (jnp.abs(y).sum(-1) > 0).all()                 # no zero row
 
 
 def test_moe_grad():
-    cfg = MoEConfig(d_model=8, d_ff=16, n_experts=4, k=2,
-                    capacity_factor=2.0)
+    cfg = MoEConfig(d_model=8, d_ff=16, n_experts=4, k=2)
     params = moe_init(jax.random.key(0), cfg)
     x = jax.random.normal(jax.random.key(1), (32, cfg.d_model))
 
-    def loss(p):
-        y, aux = moe_apply(p, x, cfg)
-        return (y ** 2).mean() + 0.01 * aux
+    def loss(apply):
+        def f(p):
+            y, aux = apply(p)
+            return (y ** 2).mean() + 0.01 * aux
+        return f
 
-    g = jax.grad(loss)(params)
+    g = jax.grad(loss(lambda p: moe_apply(p, x, cfg)))(params)
     for leaf in jax.tree_util.tree_leaves(g):
         assert jnp.isfinite(leaf).all()
+    # ... and equal to the loop's, through the grouped product's own
+    # backward (aux aside: it has no gradient in the loop).
+    want = jax.grad(lambda p: (_moe_dense_reference(p, x, cfg) ** 2)
+                    .mean())(params)
+    got = jax.grad(lambda p: (moe_apply(p, x, cfg)[0] ** 2).mean())(params)
+    for k in ("w1", "w2", "wg"):
+        assert jnp.abs(got[k] - want[k]).max() < 1e-5, k
 
 
 def test_flash_noncausal_padding_masked():

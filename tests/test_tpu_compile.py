@@ -318,3 +318,146 @@ def test_compiled_pool_writers_update_the_pool_in_place(one_chip, program):
     assert _results(c.as_text(), "copy", "transpose", "copy-start",
                     "dynamic-slice") == []
     assert c.memory_analysis().temp_size_in_bytes < 64e6
+
+
+# -- Laguna-XS.2 at the cell's shapes (benchmark/configs/laguna-xs2-serve) ----
+
+LAGUNA_FULL_BLOCKS, LAGUNA_WINDOW_BLOCKS = 22528, 4096
+LAGUNA_MAX_SEQ = 9216
+
+
+def _laguna_cell():
+    from ray_tpu.models import laguna
+
+    kinds = ("full_attention", "sliding_attention", "sliding_attention",
+             "sliding_attention", "full_attention")
+    return laguna.LagunaConfig(
+        num_hidden_layers=5,
+        num_attention_heads_per_layer=(48, 64, 64, 64, 48),
+        layer_types=kinds, max_seq=LAGUNA_MAX_SEQ,
+        mlp_layer_types=("dense", "sparse", "sparse", "sparse", "sparse"))
+
+
+@pytest.fixture(scope="module")
+def laguna_programs(one_chip):
+    """The engine's own decode and 512-token chunk programs for
+    Laguna-XS.2, compiled for the described v5e at the cell's shapes:
+    64 lanes, a full pool of 22,528 blocks (2 layers), a window pool of
+    4,096 (3 layers), tables for 9,216 tokens. ~25 s for the pair."""
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.llm.kv_cache import window_table_len
+    from ray_tpu.models import laguna
+
+    cfg = _laguna_cell()
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: laguna.init(jax.random.key(0), cfg)))
+    B, i32, bf16 = CELL_B, jnp.int32, jnp.bfloat16
+    full = S((2, LAGUNA_FULL_BLOCKS, BS, 8 * 128), bf16)
+    window = S((3, LAGUNA_WINDOW_BLOCKS, BS, 8 * 128), bf16)
+    max_nb = LAGUNA_MAX_SEQ // BS
+    nbw = window_table_len(cfg.sliding_window, BS)
+    decode, chunk = _jit_programs(cfg)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "decode": decode.lower(
+                params, S((B, 1), i32), S((B, 1), i32), full, full,
+                S((B, max_nb), i32), S((B,), i32), S((B,), i32),
+                S((B, 1), i32), S((B, 1), i32), window, window,
+                S((B, nbw + 2), i32)).compile(),
+            "chunk": chunk.lower(
+                params, S((1, 512), i32), S((512,), i32), full, full,
+                S((max_nb,), i32), S((), i32), window, window,
+                S((nbw + 1,), i32)).compile(),
+        }
+
+
+def _mosaic_calls(text):
+    import collections
+    import re
+
+    return collections.Counter(
+        re.sub(r"\.\d+$", "", line.split("=")[0].strip().lstrip("%"))
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line)
+
+
+def _pool_sized(text, *opcodes):
+    """``_results`` at the size of the window kind's pool of ONE layer
+    (the smaller of the two kinds')."""
+    global LAYER_POOL
+    keep, LAYER_POOL = LAYER_POOL, LAGUNA_WINDOW_BLOCKS * BS * 8 * 128
+    try:
+        return _results(text, *opcodes)
+    finally:
+        LAYER_POOL = keep
+
+
+def test_laguna_decode_program_reads_the_pools_as_stored(laguna_programs):
+    """The decode program at the cell's shapes: five paged calls named
+    by kind (``attn_full`` x 2, ``attn_window`` x 3) and the grouped
+    product twice a routed layer (``moe_experts_decode`` x 8), which is
+    how the benchmark's readers find them; no ``copy`` or ``transpose``
+    of a layer's pool (the paged call takes its page windows from the
+    pool as stored: no head-major view, which at this pool would move
+    ~6 GB a step); each pool written by one in-place scatter a layer;
+    the ids come back with the two counter rows; and the step's
+    temporaries are a few MB, not a pool's worth."""
+    c = laguna_programs["decode"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert _mosaic_calls(text) == {"attn_full": 2, "attn_window": 3,
+                                   "moe_experts_decode": 8}
+    assert _pool_sized(text, "copy", "transpose", "copy-start",
+                       "dynamic-update-slice", "concatenate", "pad") == []
+    full = f"bf16[2,{LAGUNA_FULL_BLOCKS},{BS},1024]"
+    window = f"bf16[3,{LAGUNA_WINDOW_BLOCKS},{BS},1024]"
+    assert sorted(_pool_sized(text, "scatter")) == sorted(
+        [("scatter", full)] * 4 + [("scatter", window)] * 6)
+    entry = text[text.index("\nENTRY "):]
+    root = next(line for line in entry.splitlines()
+                if line.lstrip().startswith("ROOT "))
+    assert f"s32[{CELL_B + 2},1]" in root and f"bf16[{CELL_B},1,100352]" \
+        in root
+    assert c.memory_analysis().temp_size_in_bytes < 100e6
+
+
+def test_laguna_chunk_program_pays_for_routed_experts_only(laguna_programs):
+    """A 512-token chunk behind a 9,216-token table: the grouped
+    product runs as the ``moe_experts_chunk`` kernel (4,096 assignments
+    in tiles of 16 rows, not 256 dense experts: 825 GFLOP a layer), the
+    pools are read only (no copy, no scatter of a pool: the caller
+    writes the chunk's K/V afterwards), and the temporaries stay under
+    half a GB (a KV head's scores at a time)."""
+    c = laguna_programs["chunk"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_prefill_chunk")
+    assert _mosaic_calls(text) == {"moe_experts_chunk": 8}
+    assert _pool_sized(text, "copy", "transpose", "copy-start", "scatter",
+                       "dynamic-update-slice") == []
+    assert c.memory_analysis().temp_size_in_bytes < 500e6
+    cost = c.cost_analysis()
+    flops = (cost[0] if isinstance(cost, (list, tuple)) else cost).get(
+        "flops", 0.0)
+    assert flops < 1.5e12, flops       # XLA's own count, kernels aside
+
+
+def test_stored_paged_kernel_compiles_with_grouped_queries(one_chip):
+    """``paged_attention_stored`` alone at head_dim 128: 6 query heads
+    a KV head (full layers, 48 heads) and 8 (window layers, 64), one
+    row a lane and a speculative 3, on the pool as stored."""
+    from ray_tpu.ops.pallas.paged_decode import paged_attention_stored
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = S((2, 2048, BS, 8 * 128), jnp.bfloat16)
+    for group, q_len, nb in ((6, 1, 576), (8, 1, 34), (8, 3, 35)):
+        lanes = S((16,), jnp.int32)
+        c = _compile(
+            lambda q, k, v, tables, lens, qlens, starts:
+            paged_attention_stored(q, k, v, 1, tables, lens, qlens, starts,
+                                   name="attn_test", interpret=False),
+            S((16, q_len, 8, group, 128), jnp.bfloat16), pool, pool,
+            S((16, nb), jnp.int32), lanes, lanes, lanes)
+        assert "attn_test" in c.as_text()
