@@ -677,13 +677,20 @@ def _laguna_logits(rehearse: bool, seed: int) -> tuple:
                                      prefill_chunk_tokens=512), 1300, 12
     params = laguna.init(jax.random.PRNGKey(seed), cfg)
     eng = LLMEngine(params, cfg, max_batch=8, **pool)
-    rows = {}
-    activate, fetch = eng._activate, eng._fetch_decisions
+    rows, last = {}, []
+    activate, fetch, chunk = (eng._activate, eng._fetch_decisions,
+                              eng._prefill_chunk)
 
-    def on_activate(req, row):
-        if row is not None:
-            rows.setdefault(req.rid, []).append(np.asarray(row, np.float32))
-        return activate(req, row)
+    def on_chunk(*args):
+        out = chunk(*args)
+        last[:] = [out[0]]      # the row the chunk program hands back
+        return out
+
+    def on_activate(req, first):
+        if first is not None:
+            rows.setdefault(req.rid, []).append(
+                np.asarray(jax.device_get(last[0]), np.float32))
+        return activate(req, first)
 
     def on_fetch(logits, ids, all_greedy):
         got = np.asarray(jax.device_get(logits), np.float32)
@@ -693,6 +700,7 @@ def _laguna_logits(rehearse: bool, seed: int) -> tuple:
         return fetch(logits, ids, all_greedy)
 
     eng._activate, eng._fetch_decisions = on_activate, on_fetch
+    eng._prefill_chunk = on_chunk
     rng = np.random.default_rng(seed + 7)
     reqs = [eng.add_request(rng.integers(0, cfg.vocab_size, n).tolist(),
                             max_tokens=n_out)

@@ -16,7 +16,10 @@ itself, built from the repo's own layers:
   * models/gpt.py, models/laguna.py — forward_step /
                             forward_prefill_chunk: a model's layer
                             around a paged or a chunk attention
-                            sublayer
+                            sublayer; each is one program that is
+                            donated the pools, writes its rows' K/V
+                            into them and returns the token ids the
+                            host needs
   * llm/engine.py        — Orca-style iteration-level scheduler
   * llm/spec.py          — speculative decoding (n-gram / small-draft
                             proposers verified in one paged-attention

@@ -65,7 +65,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..models import serving
+from ..models import pack_span, serving
 from ..util import perfmodel, tracing
 from .kv_cache import (PagedKVCache, PrefixPool, WindowPool,
                        window_table_len)
@@ -148,10 +148,13 @@ def _jit_programs(cfg):
     wrappers instead; donation is per-call, so two live engines sharing
     a program donate only their own pools."""
     model = serving(cfg)
-    # The full kind's pools are arguments 3 and 4 of the step; a kind
-    # with a window brings its own after the ten arguments every model
-    # has, and they are donated with them.
-    pools = (3, 4) + ((10, 11) if len(model.kinds) > 1 else ())
+    # Both programs are donated the pools and return them written. The
+    # full kind's are arguments 3 and 4 of the step and 2 and 3 of the
+    # chunk; a kind with a window brings its own after the arguments
+    # every model has (ten and five).
+    window = len(model.kinds) > 1
+    step_pools = (3, 4) + ((10, 11) if window else ())
+    chunk_pools = (2, 3) + ((5, 6) if window else ())
 
     def program(name, fn, **jit_kwargs):
         # The name is what a device trace's ``XLA Modules`` line shows
@@ -164,8 +167,9 @@ def _jit_programs(cfg):
 
     # The step program is ``jit_llm_decode`` at every q (one row a lane,
     # or 1 + k under speculation).
-    return (program("llm_decode", model.step, donate_argnums=pools),
-            program("llm_prefill_chunk", model.chunk))
+    return (program("llm_decode", model.step, donate_argnums=step_pools),
+            program("llm_prefill_chunk", model.chunk,
+                    donate_argnums=chunk_pools))
 
 
 class LLMEngine:
@@ -296,10 +300,10 @@ class LLMEngine:
         self._chunk_log: List[list] = []    # this step's prefill chunks
         # lanes, context tokens, decode tokens, lanes decided on the device
         self._counts = (0, 0, 0, 0)
-        # Output tokens by where they were decided: the program's own
-        # argmax (greedy lanes of a decode step) or a logits
-        # row sampled on the host (lanes with a temperature, and every
-        # first token after a prefill).
+        # Output tokens by where they were decided: a program's own
+        # argmax (a greedy request's decode tokens and the first one,
+        # from its last prefill chunk) or a logits row sampled on the
+        # host (every token of a request with a temperature).
         self._decided = {"device": 0, "host": 0}
 
     # -- events ------------------------------------------------------------
@@ -421,15 +425,23 @@ class LLMEngine:
                              {"rid": req.rid,
                               "preemptions": req.preemptions})
 
-    def _activate(self, req: Request, logits_row):
-        """Prefill done: sample the first (or first-since-resume) token
-        and enter the decode batch. ``logits_row=None`` marks a FULL
-        prefix-cache hit — nothing was computed, so there is nothing to
-        sample yet; the same step's decode recomputes the last
-        position's logits and samples there."""
+    def _activate(self, req: Request, first):
+        """Prefill done: the first (or first-since-resume) token, and
+        the request enters the decode batch. ``first`` is what was
+        fetched of the chunk program's results for the prompt's last
+        token: its argmax id for a greedy request, which takes it as it
+        is; the logits row for a request that samples. ``None`` marks a
+        FULL prefix-cache hit — nothing was computed, so there is
+        nothing to decide yet; the same step's decode recomputes the
+        last position's logits and decides there."""
         self._event(req, RUNNING)
-        if logits_row is not None:
-            self._sample_into(req, logits_row)
+        if first is None:
+            return
+        if req.greedy:
+            self._decided["device"] += 1
+            self._emit_token(req, first)
+        else:
+            self._sample_into(req, first)
 
     def _release_blocks(self, req: Request):
         """Return req's blocks to the pool. With the prefix pool the
@@ -530,6 +542,11 @@ class LLMEngine:
             uncached tokens run per STEP across all prefilling
             requests, the cursor carrying over — decode lanes keep
             emitting a token every step under long-prompt arrivals.
+
+        A chunk is ONE dispatch of the chunk program (``Serving.chunk``),
+        which is donated the pools, writes the span's K/V into them and
+        returns the last row's logits and argmax; the host builds two
+        arrays before it and fetches at most one result after.
         """
         prefills = [r for r in self._active if r.state == PREFILL]
         self._last_prefill_count = len(prefills)
@@ -557,59 +574,52 @@ class LLMEngine:
                 rem = T - upto
                 c = rem if budget is None else min(rem, budget)
                 if c < rem:
-                    # Mid-prompt chunks stay block-aligned (write_prefill
-                    # scatters whole blocks); a budget below one block
-                    # still makes one block of progress.
+                    # Mid-prompt chunks stay block-aligned (the chunk
+                    # program writes whole blocks); a budget below one
+                    # block still makes one block of progress.
                     c = (c // bs) * bs or min(bs, rem)
                 if budget is not None:
                     budget -= c
                 pad = -c % bs
                 # Span [upto, upto+c) attending resident context (earlier
-                # chunks and/or prefix-cache hits).
+                # chunks and/or prefix-cache hits). Two host arrays a
+                # chunk (each is a hand-over of the interpreter lock
+                # beside the serving threads): the tokens, and the
+                # table the chunk reads with the blocks it writes, its
+                # context length and its last real row behind it
+                # (``pack_span``). A span from the prompt's start has
+                # no context: an empty table, and it attends over itself
+                # alone.
                 toks = np.zeros((1, c + pad), np.int32)
                 toks[0, :c] = seq[upto:upto + c]
-                positions = np.minimum(
-                    upto + np.arange(c + pad, dtype=np.int32),
-                    self.model.max_seq - 1)
+                read = np.zeros((self.max_nb if upto else 0,), np.int32)
                 if upto:
-                    table = np.zeros((self.max_nb,), np.int32)
-                    table[:len(req.block_table)] = req.block_table
-                else:
-                    # A span from the prompt's start has no context: an
-                    # empty table, and it attends over itself alone.
-                    table = np.zeros((0,), np.int32)
+                    read[:len(req.block_table)] = req.block_table
+                b0 = upto // bs
+                table = pack_span(
+                    read, req.block_table[b0:b0 + (c + pad) // bs],
+                    upto, c - 1)
                 done = upto + c >= T
                 window = ()
                 if self.kv_window is not None:
-                    # The window kind's table as the chunk reads it: the
-                    # blocks behind the chunk's first token, and the
-                    # first one's index in the sequence.
-                    win = np.zeros((self._win_len + 1,), np.int32)
-                    win[:len(req.window_table)] = req.window_table
-                    win[-1] = req.window_first
-                    window = (self.kv_window.k, self.kv_window.v, win)
-            # Dispatch-to-logits-ready is the device span (the pool
-            # write is dispatched inside it and may still overlap the
-            # host work that follows — deliberately uncounted, it hides
-            # behind sampling).
+                    window = (self.kv_window.k, self.kv_window.v,
+                              self._slide_window(req, upto, c, pad))
+            # Dispatch to results ready is the device span: ONE program,
+            # which writes the chunk's K/V into the pools it is donated,
+            # and at most one fetch: the program's argmax id for a
+            # greedy request whose prompt ends here, that row of logits
+            # for one that samples, nothing for a mid-prompt chunk.
             with perf.device("llm.prefill.device") as dev:
-                logits, k, v, *kv_win = self._prefill_chunk(
-                    self.params, toks, positions, self.kv.k, self.kv.v,
-                    table, np.int32(upto), *window)
-                # Export the chunk's cache: [L, 1, c, Hkv, d] -> pool
-                # blocks upto/bs onward (upto is block-aligned by
-                # construction).
-                self.kv.write_prefill(
-                    k[:, 0, :c], v[:, 0, :c],
-                    req.block_table[upto // bs:
-                                    upto // bs + (c + pad) // bs])
+                row, tok, self.kv.k, self.kv.v, *kv_win = \
+                    self._prefill_chunk(self.params, toks, self.kv.k,
+                                        self.kv.v, table, *window)
                 if kv_win:
-                    self._write_window(req, *kv_win, upto, c, pad)
+                    self.kv_window.k, self.kv_window.v = kv_win
                 if done:
-                    row = np.asarray(jax.device_get(logits[0, c - 1]),
-                                     np.float32)
+                    first = jax.device_get(tok if req.greedy else row)
                 else:
-                    jax.block_until_ready(logits)
+                    first = None
+                    jax.block_until_ready(self.kv.k)
             device_s = dev.seconds
             with perf.phase("llm.prefill.host"):
                 req.prefilled_upto = upto + c
@@ -631,7 +641,7 @@ class LLMEngine:
                         if self.kv_window is not None:
                             self.kv_window.register_tail(
                                 seq, req.window_table, req.window_first)
-                    self._activate(req, row)
+                    self._activate(req, first)
                 if req.trace_ctx is not None:
                     dur = time.time() - t0
                     tracing.emit("llm.prefill", req.trace_ctx, t0, dur,
@@ -644,23 +654,38 @@ class LLMEngine:
                                   "host_ms": round(
                                       max(dur - device_s, 0.0) * 1e3, 3)})
 
-    def _write_window(self, req: Request, k, v, upto: int, c: int,
-                      pad: int):
-        """A chunk's K/V into the window kind's pool: the lane's window
-        slides to where its next query sits (after the chunk, which has
-        been dispatched and read what it needed), and only the chunk's
-        blocks that are still inside it are granted and written."""
+    def _slide_window(self, req: Request, upto: int, c: int, pad: int):
+        """The window kind's side of a chunk, on the host and BEFORE
+        the chunk's dispatch: returns the kind's array for the chunk
+        program (models/laguna.py: the table as the chunk reads it, its
+        first block's index, the block each of the chunk's blocks is
+        written to). The lane's window then slides to where its next
+        query sits, after the chunk, and only the chunk's blocks that
+        are still inside it are granted; the leading ones land in the
+        scratch block 0. The slide may free a block that the grant
+        hands straight back: the program reads every layer's context
+        before it writes (its docstring), so the chunk still sees what
+        the block held."""
         kvw, bs = self.kv_window, self.kv.block_size
+        nd = (c + pad) // bs
+        win = np.zeros((self._win_len + 1 + nd,), np.int32)
+        win[:len(req.window_table)] = req.window_table
+        win[self._win_len] = req.window_first
         req.window_first = kvw.slide(req.window_table, req.window_first,
                                      upto + c)
         b0 = max(upto // bs, kvw.keep_from(upto + c))
+        if not req.window_table:
+            # Everything before the chunk slid out; where the chunk is
+            # longer than the window, so did its own leading blocks,
+            # and the table starts again at the first block kept.
+            req.window_first = b0
         grant = kvw.alloc((upto + c + pad) // bs - b0)
         if grant is None:
             raise RuntimeError("the window pool cannot hold a lane's "
                                "window: window_blocks is too small")
         req.window_table.extend(grant)
-        skip = b0 * bs - upto
-        kvw.write_prefill(k[:, 0, skip:c], v[:, 0, skip:c], grant)
+        win[len(win) - len(grant):] = grant
+        return win
 
     def _preempt_for(self, req: Request) -> bool:
         """Free pool blocks by preempting a LIFO victim; req itself is

@@ -23,6 +23,9 @@ masked writes need no bounds branch. The allocator never hands it out.
 
 Writes are functional jnp scatters under jit with the pool donated —
 XLA aliases the buffers so steady-state decode does not copy the pool.
+Both served programs make their own (a decode step its rows, a prefill
+chunk its span through ``scatter_span``): no write is dispatched from
+the host between them.
 The shape is what lets it: the indexed dimensions (layer, block, offset)
 are the major ones, which is how XLA's scatter wants them, and a row of
 ``kv_heads * head_dim`` is a whole number of 128-lane tiles at every
@@ -55,14 +58,48 @@ import jax.numpy as jnp
 from ..models import serving
 
 
+def scatter_span(k_pool, v_pool, k, v, ids, rows=None):
+    """THE pool write of a prefill span, a pure function: the chunk
+    program (a model's ``forward_prefill_chunk``) calls it on the pools
+    it was donated, and ``PagedKVCache.write_prefill`` through the
+    jitted ``kv_scatter_blocks`` below.
+
+    Pools ``[L, NB, BS, W]`` (W = kv_heads * head_dim). ``k``, ``v``:
+    the span's K/V, ``[L, T, kv_heads, head_dim]``, which is the pool's
+    own order (any shape that flattens to ``[L, T, W]`` will do: whole
+    blocks ``[L, nb, BS, W]`` too), T <= len(ids) * BS. ``ids`` [nb]
+    int32: the blocks written, in the span's order. The rows from
+    ``rows`` on (a traced scalar or an int; None = T) and the tail
+    past T are written as ZEROS, masked by context_lens at read time,
+    so a pool's contents do not depend on what a chunk was padded
+    with. Several ids may name the scratch block 0 (a window kind's
+    blocks that slid out before they were written): which of them
+    lands there is nobody's business, the block is never read
+    unmasked. One in-place scatter a pool when the pools are donated:
+    the indexed dimension is the pool's major one after the layers."""
+    L, _, bs, W = k_pool.shape
+    n = ids.shape[0] * bs
+
+    def blocks(x, pool):
+        x = x.reshape(L, -1, W)
+        if n > x.shape[1]:
+            x = jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))
+        if rows is not None:
+            x = jnp.where((jnp.arange(n) < rows)[None, :, None], x, 0)
+        return x.reshape(L, -1, bs, W).astype(pool.dtype)
+
+    return (k_pool.at[:, ids].set(blocks(k, k_pool)),
+            v_pool.at[:, ids].set(blocks(v, v_pool)))
+
+
 # A device trace's ``XLA Modules`` line names each program after its
-# function: ``jit_kv_scatter_blocks``, ``jit_kv_copy_block``.
+# function: ``jit_kv_scatter_blocks``, ``jit_kv_copy_block``. A served
+# step runs the first no more (the chunk program writes its own span);
+# it is ``write_prefill``'s, for tests and tools.
 @functools.partial(jax.jit, donate_argnums=(0, 1))
-def kv_scatter_blocks(k_pool, v_pool, k_blocks, v_blocks, ids):
-    """Write whole blocks: pools [L, NB, BS, Hkv * d], blocks
-    [L, nb, BS, Hkv * d], ids [nb] int32."""
-    return (k_pool.at[:, ids].set(k_blocks),
-            v_pool.at[:, ids].set(v_blocks))
+def kv_scatter_blocks(k_pool, v_pool, k, v, ids):
+    """``scatter_span`` as a program of its own, the pools donated."""
+    return scatter_span(k_pool, v_pool, k, v, ids)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -164,24 +201,16 @@ class PagedKVCache:
     # -- writes ------------------------------------------------------------
 
     def write_prefill(self, k, v, block_ids: List[int]):
-        """Scatter a prefill's K/V into the pool. k, v:
-        ``[L, T, kv_heads, head_dim]`` (the stacked per-layer tensors
-        forward_prefill_chunk emits), which is the pool's own order: a
-        reshape away from ``[L, nb, block_size, kv_heads * head_dim]``.
-        The tail of the last block is zero-padded (masked by
-        context_lens at read time)."""
-        L, T, hkv, d = k.shape
-        nb = len(block_ids)
-        pad = nb * self.block_size - T
-        if pad < 0:
-            raise ValueError(f"{nb} blocks cannot hold {T} tokens")
-        if pad:
-            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kb = k.reshape(L, nb, self.block_size, hkv * d).astype(self.dtype)
-        vb = v.reshape(L, nb, self.block_size, hkv * d).astype(self.dtype)
-        ids = jnp.asarray(block_ids, jnp.int32)
-        self.k, self.v = kv_scatter_blocks(self.k, self.v, kb, vb, ids)
+        """Scatter a prefill's K/V into the pool (``scatter_span``, in a
+        program of its own: a served chunk writes its span inside the
+        chunk program instead). k, v: ``[L, T, kv_heads, head_dim]``,
+        which is the pool's own order. The tail of the last block is
+        zero-padded (masked by context_lens at read time)."""
+        if len(block_ids) * self.block_size < k.shape[1]:
+            raise ValueError(
+                f"{len(block_ids)} blocks cannot hold {k.shape[1]} tokens")
+        self.k, self.v = kv_scatter_blocks(
+            self.k, self.v, k, v, jnp.asarray(block_ids, jnp.int32))
 
     def gather_tokens(self, block_ids: List[int], length: int):
         """Read back ``length`` tokens' K/V as ``[L, length, Hkv, d]``

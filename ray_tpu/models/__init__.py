@@ -17,16 +17,23 @@ for what the configuration's own module says of it, a ``Serving``:
             v_pool, block_tables, context_lens, q_lens, slot_blocks,
             slot_offsets, *window, cfg=)`` ->
             ``(logits, ids, k_pool, v_pool, *window pools)``
-  chunk     one span of a prompt, ``(params, tokens, positions, k_pool,
-            v_pool, block_table, ctx_len, *window, cfg=)`` ->
-            ``(logits, k, v, *window k and v)``
+  chunk     one span of a prompt as ONE program, ``(params, tokens,
+            k_pool, v_pool, table, *window, cfg=)`` ->
+            ``(row, id, k_pool, v_pool, *window pools)``. It writes the
+            span's K/V into the pools (donated, like the step's) and
+            hands back the logits of the span's last real token and
+            their argmax. ``table`` is one int32 array, ``[block table
+            | destination blocks | ctx_len | last]`` (``pack_span``): a
+            chunk's whole bookkeeping in one hand-over
   kinds     the cache description: one ``LayerKind`` a kind of layer.
             ``kinds[0]`` keeps every token of a sequence (its pools are
             ``k_pool`` / ``v_pool`` above); a second kind, if there is
             one, has a ``window`` and keeps only the blocks that cover a
             sequence's last ``window`` tokens. Its pools and its int32
             array ride after the full kind's arguments (``*window``:
-            ``k_win, v_win, win``; see models/laguna.py for the array).
+            ``k_win, v_win, win``; see models/laguna.py for the array,
+            which in a chunk also names the blocks the span is written
+            to).
   cost      the cost description util/perfmodel.py prices steps from
   counters  names of the int32 counters the step program appends to its
             ``ids`` as rows ``[max_batch + i]``: they ride in the one
@@ -42,6 +49,8 @@ import functools
 import importlib
 from dataclasses import dataclass
 from typing import Any, Callable, Optional, Tuple
+
+import numpy as np
 
 
 @dataclass(frozen=True)
@@ -70,6 +79,26 @@ class Serving:
     max_seq: int
     vocab_size: int
     counters: Tuple[str, ...] = ()
+
+
+def pack_span(block_table, dest, ctx_len: int, last: int):
+    """A chunk's bookkeeping as the ONE int32 array ``Serving.chunk``
+    takes: ``[block table (nb, 0-padded; nb = 0 for a span from the
+    prompt's start) | destination blocks (one a block of the span) |
+    ctx_len | last]``. Each host array handed to a program is a
+    hand-over of the interpreter lock beside the serving threads
+    (PERF.md section 6, PR 30), so the four ride in one."""
+    return np.concatenate([block_table, dest, (ctx_len, last)],
+                          dtype=np.int32)
+
+
+def unpack_span(table, n: int, block_size: int):
+    """``pack_span``'s array, inside the program, back into its four
+    parts. ``n`` is the span's padded length, so the block table's
+    length follows from the array's own: a shape, not a value."""
+    nd = n // block_size
+    nb = table.shape[0] - nd - 2
+    return table[:nb], table[nb:nb + nd], table[-2], table[-1]
 
 
 @functools.lru_cache(maxsize=64)

@@ -113,8 +113,13 @@ def param_axes(cfg: GPTConfig) -> dict:
     }
 
 
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def init(key, cfg: GPTConfig) -> dict:
-    """Initialize params (f32). GPT-2-style scaled init."""
+    """Initialize params (f32). GPT-2-style scaled init. One program:
+    leaf by leaf it was 19, and on a machine with no compile cache a
+    served replica spent its first 40-odd seconds compiling them while
+    its first request ran into the proxy's 60 s limit (PERF.md section
+    6, PR 33). The values are the eager ones, bit for bit."""
     m, d, h, hk, f, L = (cfg.d_model, cfg.head_dim, cfg.n_head, cfg.kv_heads,
                          cfg.ff, cfg.n_layer)
     k = iter(jax.random.split(key, 16))
@@ -122,7 +127,11 @@ def init(key, cfg: GPTConfig) -> dict:
     resid_std = std / np.sqrt(2 * L)
 
     def rnd(key, shape, s):
-        return (jax.random.normal(key, shape) * s).astype(jnp.float32)
+        # The barrier keeps the draw and its scaling apart, as two
+        # eager calls had them: fused, the compiler folds the two
+        # constants into one and the weights differ in their last bit.
+        draw = jax.lax.optimization_barrier(jax.random.normal(key, shape))
+        return (draw * s).astype(jnp.float32)
 
     return {
         "wte": rnd(next(k), (cfg.vocab_size, m), std),
@@ -303,7 +312,7 @@ def forward(params, tokens, cfg: GPTConfig, mesh: Optional[Mesh] = None,
 # Reference layer map: the reference runtime serves external inference
 # engines; here the decode path is native. forward_prefill_chunk runs a
 # span of a prompt against whatever of it already sits in the paged pool
-# (llm/kv_cache.py) and EXPORTS the span's K/V for it; forward_step runs
+# (llm/kv_cache.py) and WRITES the span's K/V into it; forward_step runs
 # the next rows of every in-flight sequence against that pool through
 # the paged-attention kernel (ops/pallas/paged_decode). Both are the
 # training layer (_block) around an attention sublayer of their own, on
@@ -471,33 +480,12 @@ def _chunk_sublayer(h, p, layer, k_pool, v_pool, block_table, ctx_len,
     return jnp.einsum("bshd,hdm->bsm", o, p["wo"].astype(dt)), (k_tok, v_tok)
 
 
-def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
-                          block_table, ctx_len, cfg: GPTConfig):
-    """One span of a prompt: a whole cold prompt, or one chunk of an
-    incremental prefill.
-
-    Sarathi-style chunked admission and prefix-cache hits both land
-    here: run ``tokens`` [1, c] whose context — earlier prompt chunks,
-    possibly computed by ANOTHER request and shared through the prefix
-    pool — already sits in the paged pool under ``block_table``.
-
-    Args:
-      positions: [c] int32 absolute positions (ctx_len + arange(c),
-        clipped to max_seq - 1 on the padded tail).
-      block_table: [nb] int32, 0-padded like decode's tables. It may be
-        EMPTY (nb = 0, with ctx_len 0): a span with no resident context
-        attends over itself alone, at the cost of a plain causal
-        forward, and gives the logits of ``forward`` on its tokens.
-      ctx_len: scalar int32 — tokens already resident in the pool.
-
-    The pools ([L, num_blocks, block_size, kv_heads * head_dim]) are
-    READ-ONLY here (no donation): the chunk's K/V comes
-    back and the caller writes it into the pool afterwards — shared
-    blocks must be COW-split before that write.
-
-    Returns (logits [1, c, vocab], k [L, 1, c, kv_heads, head_dim],
-    v like k).
-    """
+def _chunk_layers(params, tokens, positions, k_pool, v_pool, block_table,
+                  ctx_len, cfg: GPTConfig):
+    """A span's layers: ``tokens`` [1, n] at ``positions`` [n] against
+    the sequence's context in the pools, which are only read. Returns
+    (x [1, n, d_model] before the final norm and head, k
+    [L, 1, n, kv_heads, head_dim], v like k): the span's own K/V."""
     x = _embed(params, tokens, positions, cfg)
 
     def layer(x, xs):
@@ -509,13 +497,67 @@ def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
 
     x, (k, v) = jax.lax.scan(
         layer, x, (params["blocks"], jnp.arange(cfg.n_layer)))
-    return _head(params, x, cfg), k, v
+    return x, k, v
+
+
+def forward_prefill_chunk(params, tokens, k_pool, v_pool, table,
+                          cfg: GPTConfig):
+    """One span of a prompt, as ONE program: a whole cold prompt, or
+    one chunk of an incremental prefill. It attends over the resident
+    context, writes its own K/V into the pools and hands back the one
+    row the host may need.
+
+    Sarathi-style chunked admission and prefix-cache hits both land
+    here: run ``tokens`` [1, n] (n a whole number of blocks, the real
+    tokens first) whose context — earlier prompt chunks, possibly
+    computed by ANOTHER request and shared through the prefix pool —
+    already sits in the paged pool.
+
+    Args:
+      k_pool / v_pool: [L, num_blocks, block_size, kv_heads * head_dim]
+        (donate these in the caller's jit, as the decode step's).
+      table: int32 ``[block table (nb) | destination (n / block_size) |
+        ctx_len | last]`` (models/__init__.py ``pack_span``). The block
+        table is
+        0-padded like decode's. It may be EMPTY (nb = 0, with ctx_len
+        0): a span with no resident context attends over itself alone,
+        at the cost of a plain causal forward, and row ``last`` is
+        ``forward``'s on its tokens. The destination names the blocks
+        the span's n tokens are written to (granted to this request and
+        private: shared blocks must be COW-split before). ``ctx_len``:
+        tokens already resident. ``last``: the index of the span's last
+        real token; the rows after it are padding, and are written as
+        zeros. Token i sits at position min(ctx_len + i, max_seq - 1).
+
+    Every layer READS the pools as they came in; the span is written
+    after the last layer (``llm/kv_cache.py`` ``scatter_span``), so a
+    destination block may be one the block table still names.
+
+    Returns (row [vocab]: the logits of token ``last``; id: their
+    argmax, int32, which a greedy request takes as its token; k_pool,
+    v_pool). The head runs on that one row.
+    """
+    from ..llm.kv_cache import scatter_span
+    from . import unpack_span
+
+    n = tokens.shape[1]
+    block_table, dest, ctx_len, last = unpack_span(table, n,
+                                                   k_pool.shape[2])
+    positions = jnp.minimum(ctx_len + jnp.arange(n, dtype=jnp.int32),
+                            cfg.max_seq - 1)
+    x, k, v = _chunk_layers(params, tokens, positions, k_pool, v_pool,
+                            block_table, ctx_len, cfg)
+    k_pool, v_pool = scatter_span(k_pool, v_pool, k[:, 0], v[:, 0], dest,
+                                  last + 1)
+    row = _head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
+                cfg)[0, 0]
+    return row, _greedy_ids(row), k_pool, v_pool
 
 
 def cost_shape(cfg: GPTConfig) -> dict:
     """The cost description util/perfmodel.py prices this model's steps
-    from: matmul weights a token passes (W), the attention coefficient
-    a context position, parameters in all and as stored, KV elements a
+    from: matmul weights a token passes (W) and the vocabulary head's
+    share of them, the attention coefficient a context position, parameters in all and as stored, KV elements a
     token."""
     m, f, L = cfg.d_model, cfg.ff, cfg.n_layer
     h, hk, d = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -523,6 +565,7 @@ def cost_shape(cfg: GPTConfig) -> dict:
     n = cfg.num_params()
     return {
         "matmul_weights": L * per_layer + cfg.vocab_size * m,
+        "head_weights": cfg.vocab_size * m,   # of those, the head's
         "attn_per_ctx": 4.0 * m * L,     # flops per token per context pos
         "attn_windows": (),              # no layer with a window
         "num_params": n,

@@ -43,7 +43,7 @@ The window kind's int32 array (``win``), one row a lane in a decode
 step: ``[table (window_table_len entries), first block, slot block a
 row]``: the blocks from the window's oldest on, that block's index in
 the sequence, and where each row's K/V is written. A chunk's is
-``[table, first block]``.
+``[table, first block, destination block a block of the chunk]``.
 """
 
 from __future__ import annotations
@@ -442,23 +442,19 @@ def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len, base,
     return o.transpose(2, 0, 1, 3).reshape(c, H, d)
 
 
-def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
-                          block_table, ctx_len, k_win, v_win, win,
-                          cfg: LagunaConfig):
-    """One span of a prompt (models/gpt.py ``forward_prefill_chunk``'s
-    contract): ``tokens`` [1, c] at ``positions`` [c], whose context
-    sits in the pools, read only here. ``block_table`` [nb] (empty for
-    a span from the prompt's start) is the full kind's; ``win`` [nbw +
-    1] the window kind's table and its first block's index: the blocks
-    that cover the window behind the chunk's first token.
-
-    Returns (logits [1, c, vocab], k, v [full layers, 1, c, kv, d],
-    k, v [window layers, 1, c, kv, d]): the caller writes them into the
-    pools, the window kind's only where the blocks are still in a
-    window."""
+def _chunk_layers(params, tokens, positions, k_pool, v_pool, block_table,
+                  ctx_len, k_win, v_win, win_table, win_first,
+                  cfg: LagunaConfig):
+    """A span's layers: ``tokens`` [1, n] at ``positions`` [n] against
+    the sequence's context in the pools of both kinds, which are only
+    read. ``win_table`` [nbw] holds the window kind's blocks behind the
+    span's first token, the first of them the sequence's block
+    ``win_first``. Returns (x [1, n, hidden] before the final norm and
+    head, k, v [full layers, 1, n, kv, d], k, v [window layers, 1, n,
+    kv, d]): the span's own K/V."""
     kv, d = cfg.num_key_value_heads, cfg.head_dim
     bs = k_pool.shape[2]
-    win_table, win_base = win[:-1], win[-1] * bs
+    win_base = win_first * bs
     pos = positions[None]
     x = params["embed"][tokens]
     new = {False: ([], []), True: ([], [])}
@@ -483,8 +479,51 @@ def forward_prefill_chunk(params, tokens, positions, k_pool, v_pool,
             return o[None]
 
         x, _ = _block(x, p, cfg, attend, "chunk")
-    return (_head(params, x, cfg),
-            *(jnp.stack(new[w][i]) for w in (False, True) for i in (0, 1)))
+    return (x, *(jnp.stack(new[w][i]) for w in (False, True)
+                 for i in (0, 1)))
+
+
+def forward_prefill_chunk(params, tokens, k_pool, v_pool, table,
+                          k_win, v_win, win, cfg: LagunaConfig):
+    """One span of a prompt as one program (models/gpt.py
+    ``forward_prefill_chunk``'s contract, over two kinds of pool):
+    ``tokens`` [1, n] and ``table`` = ``[block table | destination |
+    ctx_len | last]`` are the full kind's, as there. ``win`` is the
+    window kind's array, ``[table (nbw) | first block | destination
+    (n / block_size)]``: the blocks that cover the window behind the
+    span's first token AS THEY STOOD BEFORE the lane's window slid past
+    the span, that table's first block's index in the sequence, and
+    where each of the span's blocks is written: the scratch block 0 for
+    a leading block that is already out of the window the next query
+    keeps, a granted block for the rest.
+
+    The host slides the window and grants before it dispatches, so a
+    block the slide freed may be granted again at once and appear in
+    the table (read) and in the destination (written) of the same span.
+    That is safe only because every layer reads the pools as they came
+    in and the span is written after the last layer.
+
+    Returns (row [vocab], id, k_pool, v_pool, k_win, v_win)."""
+    from ..llm.kv_cache import scatter_span
+    from . import unpack_span
+
+    n = tokens.shape[1]
+    bs = k_pool.shape[2]
+    block_table, dest, ctx_len, last = unpack_span(table, n, bs)
+    nbw = win.shape[0] - 1 - n // bs
+    positions = jnp.minimum(ctx_len + jnp.arange(n, dtype=jnp.int32),
+                            cfg.max_seq - 1)
+    x, k, v, kw, vw = _chunk_layers(
+        params, tokens, positions, k_pool, v_pool, block_table, ctx_len,
+        k_win, v_win, win[:nbw], win[nbw], cfg)
+    k_pool, v_pool = scatter_span(k_pool, v_pool, k[:, 0], v[:, 0], dest,
+                                  last + 1)
+    k_win, v_win = scatter_span(k_win, v_win, kw[:, 0], vw[:, 0],
+                                win[nbw + 1:], last + 1)
+    row = _head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
+                cfg)[0, 0]
+    return (row, jnp.argmax(row).astype(jnp.int32), k_pool, v_pool,
+            k_win, v_win)
 
 
 # ---------------------------------------------------------------------------
@@ -528,6 +567,7 @@ def cost_shape(cfg: LagunaConfig) -> dict:
 
     return {
         "matmul_weights": active,
+        "head_weights": cfg.vocab_size * m,
         "attn_per_ctx": full_coef,
         "attn_windows": tuple(windows),
         "num_params": cfg.num_params(),

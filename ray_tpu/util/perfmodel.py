@@ -203,13 +203,16 @@ def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
     honest when cached work is skipped).
 
     Causal attention: span position i attends ctx + i + 1 keys, so the
-    attention term is ctx*T + T*(T+1)/2 contexts. HBM adds one read of
-    the resident context's KV on top of the span's own write+read."""
+    attention term is ctx*T + T*(T+1)/2 contexts. The vocabulary head
+    runs on ONE row, the span's last token (the chunk program hands
+    back that row alone). HBM adds one read of the resident context's
+    KV on top of the span's own write+read."""
     s = _shape(cfg)
     T = int(n_tokens)
     ctx = int(ctx_tokens)
     seen = ctx * T + T * (T + 1) / 2.0
-    flops = (2.0 * s["matmul_weights"] * T + s["attn_per_ctx"] * seen
+    flops = (2.0 * (s["matmul_weights"] - s["head_weights"]) * T
+             + 2.0 * s["head_weights"] + s["attn_per_ctx"] * seen
              + sum(coef * min(seen, T * w) for coef, w in s["attn_windows"]))
     kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
     if param_bytes is None:

@@ -56,15 +56,23 @@ def _engine(params, **kw):
 
 def _logits_of(eng):
     """Record every logits row the engine decides a token from:
-    {rid: [row, ...]}, the prefill's row first, then one a decode step
-    (the requests sample with a temperature, so the rows are fetched)."""
-    rows = {}
-    activate, fetch = eng._activate, eng._fetch_decisions
+    {rid: [row, ...]}: the row its last prefill chunk's program hands
+    back (fetched here whether or not the request is greedy), then one
+    a decode step."""
+    rows, last = {}, []
+    activate, fetch, chunk = (eng._activate, eng._fetch_decisions,
+                              eng._prefill_chunk)
 
-    def on_activate(req, row):
-        if row is not None:
-            rows.setdefault(req.rid, []).append(np.asarray(row))
-        return activate(req, row)
+    def on_chunk(*args):
+        out = chunk(*args)
+        last[:] = [out[0]]
+        return out
+
+    def on_activate(req, first):
+        if first is not None:
+            rows.setdefault(req.rid, []).append(
+                np.asarray(jax.device_get(last[0]), np.float32))
+        return activate(req, first)
 
     def on_fetch(logits, ids, all_greedy):
         got = np.asarray(jax.device_get(logits), np.float32)
@@ -74,6 +82,7 @@ def _logits_of(eng):
         return fetch(logits, ids, all_greedy)
 
     eng._activate, eng._fetch_decisions = on_activate, on_fetch
+    eng._prefill_chunk = on_chunk
     return rows
 
 
@@ -87,13 +96,19 @@ def _reference_rows(params, prompt, out):
     return logits[len(prompt) - 1:len(prompt) - 1 + len(out)]
 
 
-def test_engine_logits_equal_the_plain_reference(params):
+@pytest.mark.parametrize("budget", [16, 48], ids=[
+    "chunks_of_16", "a_chunk_longer_than_the_window"])
+def test_engine_logits_equal_the_plain_reference(params, budget):
     """A 70-token prompt prefilled in chunks of 16 across a window of
     24 (so chunks start inside, at and past a window boundary and
     blocks slide out mid-prompt), then 12 decode steps through both
     pools: every logits row the engine samples from equals the
-    reference's full forward pass."""
-    eng = _engine(params)
+    reference's full forward pass. And in a chunk of 48, whose own
+    leading blocks are out of the window before they are written (they
+    go to the scratch block, and the lane's table starts at the first
+    block kept: before PR 33 it kept its old start and the next decode
+    step ran off its end)."""
+    eng = _engine(params, prefill_chunk_tokens=budget)
     rows = _logits_of(eng)
     prompt = _prompt(0, 70)
     req = eng.add_request(prompt, max_tokens=12, temperature=0.7, seed=3)

@@ -564,9 +564,9 @@ def test_sampled_lane_beside_greedy_lanes_keeps_its_host_draws(
     assert [c[:4] for c in mine] == [
         (1234, n0 + j, 0.8, 40) for j in range(SAMPLED["max_tokens"])]
     assert [c[4] for c in mine] == lane.output
-    # Greedy lanes reach the sampler for their first token only (the
-    # prefill's row); their decode tokens are the program's ids.
-    assert len(calls) - len(mine) == len(GREEDY)
+    # Greedy lanes never reach the sampler: their first token is the
+    # chunk program's id, their decode tokens the step program's.
+    assert len(calls) == len(mine)
     assert lane.output == _dense_reference(SAMPLED)
     for h, r in zip(others, GREEDY):
         assert h.output == _dense_reference(r)
@@ -585,7 +585,8 @@ def test_sampled_lane_beside_greedy_lanes_keeps_its_host_draws(
 def test_device_sampled_count_in_the_ring_and_stats(reqs, host_lanes):
     """Each llm.step ring entry says how many lanes took their token
     from the device; stats() totals tokens by where they were decided,
-    first tokens (sampled from the prefill's row) on the host's side."""
+    a greedy request's first token (the chunk program's id) on the
+    device's side like its decode tokens."""
     from ray_tpu.util import perfmodel
 
     perfmodel.clear_device_steps()
@@ -605,13 +606,13 @@ def test_device_sampled_count_in_the_ring_and_stats(reqs, host_lanes):
         assert e[perfmodel.DEVICE_SAMPLED] == e["lanes"] - live_host
     s = eng.stats()
     on_device = sum(e[perfmodel.DEVICE_SAMPLED] for e in ring)
-    assert s["tokens_decided_on_device"] == on_device
+    assert s["tokens_decided_on_device"] == \
+        on_device + len(reqs) - host_lanes
     assert s["tokens_decided_on_device"] + s["tokens_decided_on_host"] \
         == sum(len(h.output) for h in hs)
-    # The host's share: one first token a request, and every decode
-    # token of a lane with a temperature.
-    assert s["tokens_decided_on_host"] == len(reqs) + sum(
-        h.max_tokens - 1 for h in hs[:host_lanes])
+    # The host's share: every token of a lane with a temperature.
+    assert s["tokens_decided_on_host"] == sum(
+        h.max_tokens for h in hs[:host_lanes])
     perfmodel.clear_device_steps()
 
 
@@ -661,15 +662,30 @@ def test_step_at_one_row_is_row_zero_of_a_wider_step_with_padding_rows():
                            np.asarray(pool[:, 1:]))
 
 
+def _layers_and_head(cfg):
+    """A span's layers and the head on every row, without the write:
+    ``(params, tokens, positions, k_pool, v_pool, table, ctx_len) ->
+    (logits [1, n, vocab], k, v [L, 1, n, kv_heads, head_dim])``, what
+    the chunk program was before it wrote its own span (PR 33), from
+    the model's own parts."""
+    from ray_tpu.models import gpt
+
+    def fn(params, tokens, positions, k_pool, v_pool, table, ctx_len):
+        x, k, v = gpt._chunk_layers(params, tokens, positions, k_pool,
+                                    v_pool, table, ctx_len, cfg)
+        return gpt._head(params, x, cfg), k, v
+
+    return jax.jit(fn)
+
+
 def test_chunk_with_an_empty_table_is_the_plain_causal_forward():
-    """forward_prefill_chunk with no block table and ctx_len 0 attends
-    over the span alone: its logits are gpt.forward's on the same
-    tokens, and logits and K/V are those of the same span under a
-    full-length table at ctx_len 0, whose pool slots are all masked."""
-    from ray_tpu.llm.engine import _jit_programs
+    """A span's layers with no block table and ctx_len 0 attend over
+    the span alone: the logits are gpt.forward's on the same tokens,
+    and logits and K/V are those of the same span under a full-length
+    table at ctx_len 0, whose pool slots are all masked."""
     from ray_tpu.models.gpt import forward
 
-    chunk = _jit_programs(CFG)[1]
+    chunk = _layers_and_head(CFG)
     bs, nb, T = 8, 16, 24
     rng = np.random.default_rng(3)
     pool = jnp.asarray(rng.standard_normal(
@@ -782,7 +798,7 @@ def test_token_major_pool_gives_the_head_major_pools_bits(case, cfg):
     from ray_tpu.llm.kv_cache import PagedKVCache
 
     params = PARAMS if cfg is CFG else init(jax.random.PRNGKey(2), cfg)
-    step, chunk = _jit_programs(cfg)
+    step, chunk = _jit_programs(cfg)[0], _layers_and_head(cfg)
     B, bs, nb = 4, 8, 16
     max_nb = cfg.max_seq // bs
     rng = np.random.default_rng(11)
@@ -845,9 +861,11 @@ def test_cold_whole_prompt_prefills_through_the_chunk_program_tableless():
     seen = []
     real = eng._prefill_chunk
 
-    def spy(params, toks, positions, k, v, table, upto):
-        seen.append((toks.shape, table.shape, int(upto)))
-        return real(params, toks, positions, k, v, table, upto)
+    def spy(params, toks, k, v, table):
+        # table: [block table | 3 or 1 destination blocks | upto | last]
+        seen.append((toks.shape, (table.size - toks.size // 8 - 2,),
+                     int(table[-2])))
+        return real(params, toks, k, v, table)
 
     eng._prefill_chunk = spy
     h = eng.add_request(**req)

@@ -385,12 +385,13 @@ def test_verify_step_counts_tokens_by_where_they_were_decided(host_lanes):
     assert ring[0][perfmodel.DEVICE_SAMPLED] == len(reqs) - host_lanes
     assert all(e[perfmodel.DEVICE_SAMPLED] <= e["lanes"] for e in ring)
     s = eng.stats()
-    # First tokens come from the prefill's row, on the host; so does
-    # every token of the lane with a temperature.
-    assert s["tokens_decided_on_host"] == len(reqs) + sum(
-        h.max_tokens - 1 for h in hs[:host_lanes])
+    # Every token of the lane with a temperature is drawn on the host;
+    # a greedy lane's come from the programs' own argmax, the first
+    # one (its last prefill chunk's) included.
+    assert s["tokens_decided_on_host"] == sum(
+        h.max_tokens for h in hs[:host_lanes])
     assert s["tokens_decided_on_device"] == sum(
-        h.max_tokens - 1 for h in hs[host_lanes:])
+        h.max_tokens for h in hs[host_lanes:])
     assert s["spec_tokens_per_step"] > 1.0
     perfmodel.clear_device_steps()
 

@@ -79,8 +79,11 @@ def test_decode_step_cost_hand_computed():
 def test_prefill_cost_hand_computed():
     T = 128
     c = prefill_cost(GPT2_SMALL, T)
-    # Causal: position i attends i+1 keys -> sum = T*(T+1)/2.
-    assert c.flops == 2.0 * W_MATMUL * T + 4.0 * M * L * T * (T + 1) / 2
+    # Causal: position i attends i+1 keys -> sum = T*(T+1)/2. The head
+    # runs on the span's last row alone.
+    head = GPT2_SMALL.vocab_size * M
+    assert c.flops == 2.0 * (W_MATMUL - head) * T + 2.0 * head \
+        + 4.0 * M * L * T * (T + 1) / 2
     kvb = 2 * L * HK * D * 2
     assert c.hbm_bytes == N_PARAMS * 4 + 2.0 * T * kvb
     assert c.tokens == T

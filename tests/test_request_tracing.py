@@ -545,8 +545,10 @@ def test_preemption_links_victim_trace(rt_trace):
     tids: dict = {}
 
     def worker(i):
+        # 5 + 30 tokens fill the pool's 5 blocks: any two streams in
+        # flight together preempt (tests/test_serve_llm.py has why).
         tids[i], frames = _stream_http(
-            url, {"prompt": [3, 1, 4, 1, 5], "max_tokens": 10,
+            url, {"prompt": [3, 1, 4, 1, 5], "max_tokens": 30,
                   "seed": i, "temperature": 0.9})
         assert frames[-1]["done"]
 

@@ -162,8 +162,14 @@ def test_late_join_and_preemption_through_serve(rt_llm):
         out, threads = {}, []
 
         def worker(i):
+            # 5 + 30 tokens are all 5 blocks of the tight pool, so ANY
+            # two streams in flight together force a preemption. (At 10
+            # tokens only three in lock-step did, which hung on which
+            # thread won the engine's lock after the first step's
+            # compilations: one run in two here, before PR 33 and
+            # after.)
             out[i] = _stream_http(
-                url, {"prompt": [3, 1, 4, 1, 5], "max_tokens": 10,
+                url, {"prompt": [3, 1, 4, 1, 5], "max_tokens": 30,
                       "seed": i, "temperature": 0.9})
 
         for i in seeds:
@@ -180,7 +186,7 @@ def test_late_join_and_preemption_through_serve(rt_llm):
 
     url = _deploy(serve, num_blocks=64, block_size=8, max_batch=4)
     # Second app, tiny pool, side by side at its own route prefix:
-    # capacity 5 blocks = 40 tokens < 3 sequences x (5 prompt + 10 out).
+    # capacity 5 blocks = 40 tokens < 2 sequences x (5 prompt + 30 out).
     serve.run(LLMServer.options(name="LLMTight").bind(
         CFG, num_blocks=6, block_size=8, max_batch=4),
         name="llm-tight", route_prefix="/tight")

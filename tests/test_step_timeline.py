@@ -420,8 +420,8 @@ def test_program_names_in_the_lowered_text():
 
     texts = {
         "llm_decode": step(1),
-        "llm_prefill_chunk": _lowered(chunk, PARAMS, i32(1, 8), i32(8),
-                                      pool, pool, i32(max_nb), jnp.int32(8)),
+        "llm_prefill_chunk": _lowered(chunk, PARAMS, i32(1, 8), pool, pool,
+                                      i32(max_nb + 1 + 2)),
         "kv_scatter_blocks": _lowered(
             kv_cache.kv_scatter_blocks, pool, pool, pool[:, :2],
             pool[:, :2], i32(2)),

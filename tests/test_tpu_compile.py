@@ -362,15 +362,16 @@ def laguna_programs(one_chip):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
         return {
+            "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode": decode.lower(
                 params, S((B, 1), i32), S((B, 1), i32), full, full,
                 S((B, max_nb), i32), S((B,), i32), S((B,), i32),
                 S((B, 1), i32), S((B, 1), i32), window, window,
                 S((B, nbw + 2), i32)).compile(),
             "chunk": chunk.lower(
-                params, S((1, 512), i32), S((512,), i32), full, full,
-                S((max_nb,), i32), S((), i32), window, window,
-                S((nbw + 1,), i32)).compile(),
+                params, S((1, 512), i32), full, full,
+                S((max_nb + 512 // BS + 2,), i32), window, window,
+                S((nbw + 1 + 512 // BS,), i32)).compile(),
         }
 
 
@@ -424,24 +425,87 @@ def test_laguna_decode_program_reads_the_pools_as_stored(laguna_programs):
     assert c.memory_analysis().temp_size_in_bytes < 100e6
 
 
+def _aliased(text):
+    """{parameter index: output index} of the module's
+    ``input_output_alias``."""
+    import re
+
+    head = text[:text.index("entry_computation_layout")]
+    return {int(p): int(o) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", head)}
+
+
 def test_laguna_chunk_program_pays_for_routed_experts_only(laguna_programs):
-    """A 512-token chunk behind a 9,216-token table: the grouped
-    product runs as the ``moe_experts_chunk`` kernel (4,096 assignments
-    in tiles of 16 rows, not 256 dense experts: 825 GFLOP a layer), the
-    pools are read only (no copy, no scatter of a pool: the caller
-    writes the chunk's K/V afterwards), and the temporaries stay under
-    half a GB (a KV head's scores at a time)."""
+    """A 512-token chunk behind a 9,216-token table, ONE program: the
+    grouped product runs as the ``moe_experts_chunk`` kernel (4,096
+    assignments in tiles of 16 rows, not 256 dense experts: 825 GFLOP a
+    layer); the four pools are donated, aliased to the outputs and
+    written by one in-place scatter each, after every layer has read
+    its context (no copy of a layer's pool, no other writer); the head
+    runs on the one row that comes back (no ``[512, vocab]`` logits);
+    and the temporaries stay under half a GB (a KV head's scores at a
+    time)."""
     c = laguna_programs["chunk"]
     text = c.as_text()
     assert text.startswith("HloModule jit_llm_prefill_chunk")
     assert _mosaic_calls(text) == {"moe_experts_chunk": 8}
-    assert _pool_sized(text, "copy", "transpose", "copy-start", "scatter",
-                       "dynamic-update-slice") == []
+    assert _pool_sized(text, "copy", "transpose", "copy-start",
+                       "dynamic-slice", "dynamic-update-slice") == []
+    full = f"bf16[2,{LAGUNA_FULL_BLOCKS},{BS},1024]"
+    window = f"bf16[3,{LAGUNA_WINDOW_BLOCKS},{BS},1024]"
+    assert sorted(_pool_sized(text, "scatter")) == sorted(
+        [("scatter", full)] * 2 + [("scatter", window)] * 2)
+    # params' leaves come first: the pools are the arguments after the
+    # tokens, and outputs 2-5 behind the row and its id.
+    n = laguna_programs["param_leaves"]
+    assert _aliased(text) == {n + 1: 2, n + 2: 3, n + 4: 4, n + 5: 5}
+    assert "[512,100352]" not in text and "[1,512,100352]" not in text
     assert c.memory_analysis().temp_size_in_bytes < 500e6
     cost = c.cost_analysis()
     flops = (cost[0] if isinstance(cost, (list, tuple)) else cost).get(
         "flops", 0.0)
     assert flops < 1.5e12, flops       # XLA's own count, kernels aside
+
+
+@pytest.mark.parametrize("n, table", [(512, MAX_NB), (320, 0)],
+                         ids=["behind_context", "cold_prompt"])
+def test_chat_cell_chunk_program_writes_its_span_in_place(one_chip, as_tpu,
+                                                          n, table):
+    """The chat cell's chunk program (a 512-token chunk behind a
+    64-slot table; a cold 320-token prompt with none), ONE program a
+    chunk: named ``jit_llm_prefill_chunk``; both pools donated and
+    aliased to the outputs; each written by exactly one in-place
+    scatter and touched by no pool-sized ``copy``, ``transpose``,
+    ``copy-start`` or ``dynamic-slice`` (the context is gathered by
+    table); what comes back is one row of logits and its argmax, the
+    head on that row alone (the parent wrote ``f32``/``bf16[1,512,
+    50304]`` for one row's sake); temporaries under 256 MB."""
+    from ray_tpu.llm.engine import _jit_programs
+
+    cfg = gpt.GPT2_SMALL
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
+    pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
+    c = _jit_programs(cfg)[1].lower(
+        params, S((1, n), jnp.int32), pool, pool,
+        S((table + n // BS + 2,), jnp.int32)).compile()
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_prefill_chunk")
+    leaves = len(jax.tree_util.tree_leaves(params))
+    assert _aliased(text) == {leaves + 1: 2, leaves + 2: 3}
+    stack = f"bf16[{cfg.n_layer},{CELL_NB},{BS},{HKV * HD}]"
+    assert _results(text, "scatter") == [("scatter", stack)] * 2
+    assert _results(text, "copy", "transpose", "copy-start",
+                    "dynamic-slice", "dynamic-update-slice",
+                    "concatenate", "pad") == []
+    entry = text[text.index("\nENTRY "):]
+    root = next(line for line in entry.splitlines()
+                if line.lstrip().startswith("ROOT "))
+    assert f"bf16[{cfg.vocab_size}]" in root and "s32[]" in root
+    assert f"[{n},{cfg.vocab_size}]" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 256e6
 
 
 def test_stored_paged_kernel_compiles_with_grouped_queries(one_chip):
