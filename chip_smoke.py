@@ -44,9 +44,9 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 RESULT_TAG = "PHASE_RESULT "
-# Per-phase wall limits, compilation included; the whole default run
-# stays inside the driver's 1200 s.
-PHASE_TIMEOUT_S = {"train": 360, "serve": 540, "detached": 280,
+# Per-phase wall limits, compilation included. The serve phase took
+# 597 s with two Kimi comparisons (my chip run, PR 34), ~510 s with one.
+PHASE_TIMEOUT_S = {"train": 360, "serve": 600, "detached": 280,
                    "train4": 900}
 
 # Stated tolerances (bf16 activations, f32 softmax statistics and loss).
@@ -71,6 +71,14 @@ LOGIT_MARGIN_EPS = 0.05     # top-two margin under which bf16 may flip argmax
 # the typical row, reported beside it, differs by rounding alone). A
 # wrong mask, rotary or expert reads ~4.
 LAGUNA_LOGIT_TOL = 1.5
+# Kimi-K2.5's served share against its float32 non-absorbed reference,
+# the same way: the largest difference read 0.14 and the median row's
+# 0.11 (my chip run, PR 34; logits have spread ~1.7), the bfloat16
+# residual's rounding through five layers. The router swaps less here
+# (a token's 8 experts fall on the 12 held ones a quarter of the time);
+# a missing rope term or softmax scale moves a row by 2.5-10
+# (benchmark/reference_kimi_k2.py has the readings).
+KIMI_LOGIT_TOL = 0.6
 
 
 _T0 = time.perf_counter()
@@ -637,8 +645,67 @@ def phase_serve(args) -> None:
            f"the full and the window pool) equal the plain float32 "
            f"reference's within {LAGUNA_LOGIT_TOL} (largest difference "
            f"{worst:.5f}, the median row's {typical:.5f})")
+    # -- Kimi-K2.5's share: logits through the latent pool, both paths
+    gc.collect()
+    for a in jax.live_arrays():
+        a.delete()
+    k_worst, k_typical = _kimi_logits(args.rehearse, args.seed)
+    _check(k_worst < KIMI_LOGIT_TOL,
+           f"kimi: served logits (a prefix hit, a chunked body, then decode "
+           f"through the latent pool) equal the plain float32 non-absorbed "
+           f"reference's within {KIMI_LOGIT_TOL} (largest difference "
+           f"{k_worst:.5f}, the median row's {k_typical:.5f})")
     _finish(info, ttft_s=ttfts, tokens_per_s=8 * n_out / wall,
-            laguna_logit_diff=worst)
+            laguna_logit_diff=worst, kimi_logit_diff=k_worst)
+
+
+def _record_logits(eng) -> dict:
+    """Record every logits row ``eng`` decides a token from, {rid:
+    [row, ...]}: the row its last prefill chunk's program hands back,
+    then one a decode step."""
+    import jax
+    import numpy as np
+
+    rows, last = {}, []
+    activate, fetch, chunk = (eng._activate, eng._fetch_decisions,
+                              eng._prefill_chunk)
+
+    def on_chunk(*args):
+        out = chunk(*args)
+        last[:] = [out[0]]      # the row the chunk program hands back
+        return out
+
+    def on_activate(req, first):
+        if first is not None:
+            rows.setdefault(req.rid, []).append(
+                np.asarray(jax.device_get(last[0]), np.float32))
+        return activate(req, first)
+
+    def on_fetch(logits, ids, all_greedy):
+        got = np.asarray(jax.device_get(logits), np.float32)
+        for i, r in enumerate(x for x in eng._active
+                              if x.state == "RUNNING"):
+            rows.setdefault(r.rid, []).append(got[i, 0])
+        return fetch(logits, ids, all_greedy)
+
+    eng._activate, eng._fetch_decisions = on_activate, on_fetch
+    eng._prefill_chunk = on_chunk
+    return rows
+
+
+def _logit_diffs(reqs, rows, forward, what: str) -> tuple:
+    """(largest, median) over rows of |served logits - reference's|."""
+    import numpy as np
+
+    by_row = []
+    for r in reqs:
+        want = np.asarray(forward(r.prompt + r.output))[
+            len(r.prompt) - 1:len(r.prompt) - 1 + len(r.output)]
+        got = np.stack(rows[r.rid])
+        _check(got.shape == want.shape, f"{what}: {got.shape[0]} logits "
+               f"rows compared for a {len(r.prompt)}-token prompt")
+        by_row += np.abs(got - want).max(axis=1).tolist()
+    return max(by_row), float(np.median(by_row))
 
 
 def _laguna_logits(rehearse: bool, seed: int) -> tuple:
@@ -677,30 +744,7 @@ def _laguna_logits(rehearse: bool, seed: int) -> tuple:
                                      prefill_chunk_tokens=512), 1300, 12
     params = laguna.init(jax.random.PRNGKey(seed), cfg)
     eng = LLMEngine(params, cfg, max_batch=8, **pool)
-    rows, last = {}, []
-    activate, fetch, chunk = (eng._activate, eng._fetch_decisions,
-                              eng._prefill_chunk)
-
-    def on_chunk(*args):
-        out = chunk(*args)
-        last[:] = [out[0]]      # the row the chunk program hands back
-        return out
-
-    def on_activate(req, first):
-        if first is not None:
-            rows.setdefault(req.rid, []).append(
-                np.asarray(jax.device_get(last[0]), np.float32))
-        return activate(req, first)
-
-    def on_fetch(logits, ids, all_greedy):
-        got = np.asarray(jax.device_get(logits), np.float32)
-        for i, r in enumerate(x for x in eng._active
-                              if x.state == "RUNNING"):
-            rows.setdefault(r.rid, []).append(got[i, 0])
-        return fetch(logits, ids, all_greedy)
-
-    eng._activate, eng._fetch_decisions = on_activate, on_fetch
-    eng._prefill_chunk = on_chunk
+    rows = _record_logits(eng)
     rng = np.random.default_rng(seed + 7)
     reqs = [eng.add_request(rng.integers(0, cfg.vocab_size, n).tolist(),
                             max_tokens=n_out)
@@ -735,17 +779,90 @@ def _laguna_logits(rehearse: bool, seed: int) -> tuple:
            f"laguna: route() picks the float32 reference's experts on "
            f"{same(served, plain):.4f} of 2,048 tokens (at least 0.999; "
            f"scores in bfloat16 agree on {same(low, plain):.4f})")
-    by_row = []
     del eng
-    for r in reqs:
-        want = np.asarray(laguna_ref.forward(
-            params, r.prompt + r.output, cfg))[
-            len(r.prompt) - 1:len(r.prompt) - 1 + len(r.output)]
-        got = np.stack(rows[r.rid])
-        _check(got.shape == want.shape, f"laguna: {got.shape[0]} logits "
-               f"rows compared for a {len(r.prompt)}-token prompt")
-        by_row += np.abs(got - want).max(axis=1).tolist()
-    return max(by_row), float(np.median(by_row))
+    return _logit_diffs(
+        reqs, rows, lambda seq: laguna_ref.forward(params, seq, cfg),
+        "laguna")
+
+
+def _kimi_logits(rehearse: bool, seed: int) -> tuple:
+    """Kimi-K2.5's served share at the benchmark cell's cut (published
+    widths, layer 0 and four routed layers, 12 of 384 experts, a slice
+    of the vocabulary; tiny when rehearsing) through LLMEngine alone: a
+    prefix sent alone, then the prefix with a body prefilled in chunks
+    against the cached latent rows (the up-projecting form), then
+    decode steps through the latent pool (the absorbed kernel), beside
+    a second lane. Every logits row the engine decides a token from is
+    compared with the plain reference's NON-absorbed full forward pass
+    (models/kimi_k2_ref.py), given the same share. Returns the largest
+    absolute difference and the median row's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.models import kimi_k2, kimi_k2_ref
+    from ray_tpu.ops import moe
+
+    if rehearse:
+        cfg = kimi_k2.KimiK2Config(
+            vocab_size=512, hidden_size=64, intermediate_size=128,
+            moe_intermediate_size=32, num_hidden_layers=3,
+            num_attention_heads=4, q_lora_rank=48, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            n_routed_experts=16, num_experts_per_tok=2, experts_held=4,
+            max_seq=256, dtype="float32")
+        pool, n_prefix, n_body, n_out = dict(
+            num_blocks=64, block_size=8, prefill_chunk_tokens=32), 64, 40, 6
+    else:
+        cfg = kimi_k2.KimiK2Config(num_hidden_layers=5, vocab_size=20480,
+                                   experts_held=12, max_seq=2048)
+        pool, n_prefix, n_body, n_out = dict(
+            num_blocks=1024, block_size=16,
+            prefill_chunk_tokens=512), 1024, 384, 12
+    params = kimi_k2.init(jax.random.PRNGKey(seed), cfg)
+    eng = LLMEngine(params, cfg, max_batch=8, **pool)
+    rng = np.random.default_rng(seed + 11)
+    draw = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()
+    prefix = draw(n_prefix)
+    eng.add_request(prefix, max_tokens=1)
+    while eng.step():
+        pass
+    rows = _record_logits(eng)
+    reqs = [eng.add_request(prefix + draw(n_body), max_tokens=n_out),
+            eng.add_request(draw(n_body), max_tokens=n_out)]
+    while eng.step():
+        pass
+    st = eng.stats()
+    _check(reqs[0].cached_tokens == n_prefix,
+           f"kimi: the {n_prefix}-token prefix was a cache hit")
+    log(f"  kimi: paged kernel {st['paged_kernel']}, latent pool peak "
+        f"{st['kv_util_peak']:.3f}")
+    # The router alone, on identical inputs: the served route_sigmoid()
+    # against plain float32 sigmoid + bias + top-k.
+    routed = next(p for p in params["layers"] if "router" in p)
+    h = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                          (2048, cfg.hidden_size)).astype(cfg.dtype)
+    k, b = cfg.num_experts_per_tok, routed["router_bias"]
+    _, served, _ = moe.route_sigmoid(h, routed["router"], b, k)
+    with jax.default_matmul_precision("highest"):
+        scores = h.astype(jnp.float32) @ routed["router"].astype(jnp.float32)
+    plain = jax.lax.top_k(jax.nn.sigmoid(scores) + b, k)[1]
+    low = jax.lax.top_k(jax.nn.sigmoid(
+        (h @ routed["router"]).astype(jnp.bfloat16)) + b.astype(
+            jnp.bfloat16), k)[1]
+    same = lambda a, b: float((jnp.sort(a, -1) == jnp.sort(b, -1))
+                              .all(-1).mean())
+    _check(same(served, plain) >= 0.999,
+           f"kimi: route_sigmoid() picks the float32 reference's experts "
+           f"on {same(served, plain):.4f} of 2,048 tokens (at least 0.999; "
+           f"scores in bfloat16 agree on {same(low, plain):.4f})")
+    del eng
+    # The request behind the prefix alone: its rows passed all three
+    # paths, and a second float32 pass costs the phase ~85 s on the chip.
+    return _logit_diffs(
+        reqs[:1], rows, lambda seq: kimi_k2_ref.forward(params, seq, cfg),
+        "kimi")
 
 
 # ---------------------------------------------------------------------------

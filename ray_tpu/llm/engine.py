@@ -149,12 +149,15 @@ def _jit_programs(cfg):
     a program donate only their own pools."""
     model = serving(cfg)
     # Both programs are donated the pools and return them written. The
-    # full kind's are arguments 3 and 4 of the step and 2 and 3 of the
-    # chunk; a kind with a window brings its own after the arguments
-    # every model has (ten and five).
-    window = len(model.kinds) > 1
-    step_pools = (3, 4) + ((10, 11) if window else ())
-    chunk_pools = (2, 3) + ((5, 6) if window else ())
+    # full kind's follow the step's three leading arguments and the
+    # chunk's two, as many as the kind says it has (``LayerKind.rows``);
+    # a kind with a window brings its own after the arguments every
+    # model has (five more in the step, one in the chunk).
+    n = [len(kind.rows) for kind in model.kinds] + [0]
+    step_pools = tuple(range(3, 3 + n[0])) \
+        + tuple(range(8 + n[0], 8 + n[0] + n[1]))
+    chunk_pools = tuple(range(2, 2 + n[0])) \
+        + tuple(range(3 + n[0], 3 + n[0] + n[1]))
 
     def program(name, fn, **jit_kwargs):
         # The name is what a device trace's ``XLA Modules`` line shows
@@ -201,10 +204,11 @@ class LLMEngine:
                            block_size=block_size)
         self._prefix = prefix_cache
         # Read once: a decode step donates the pools, so between dispatch
-        # and reassignment self.kv.k is a deleted array to other threads.
-        self._device = next(iter(self.kv.k.devices()))
-        self._pool_spec = jax.ShapeDtypeStruct(
-            self.kv.k.shape, self.kv.k.dtype, sharding=self.kv.k.sharding)
+        # and reassignment a pool is a deleted array to other threads.
+        self._device = next(iter(self.kv.pools[0].devices()))
+        self._pool_specs = tuple(
+            jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=p.sharding)
+            for p in self.kv.pools)
         # Sarathi-style chunked prefill admission: at most this many
         # UNCACHED prompt tokens run per step (None = whole prompt at
         # once), so running decode streams emit a token every step even
@@ -602,7 +606,7 @@ class LLMEngine:
                 done = upto + c >= T
                 window = ()
                 if self.kv_window is not None:
-                    window = (self.kv_window.k, self.kv_window.v,
+                    window = (*self.kv_window.pools,
                               self._slide_window(req, upto, c, pad))
             # Dispatch to results ready is the device span: ONE program,
             # which writes the chunk's K/V into the pools it is donated,
@@ -610,16 +614,14 @@ class LLMEngine:
             # greedy request whose prompt ends here, that row of logits
             # for one that samples, nothing for a mid-prompt chunk.
             with perf.device("llm.prefill.device") as dev:
-                row, tok, self.kv.k, self.kv.v, *kv_win = \
-                    self._prefill_chunk(self.params, toks, self.kv.k,
-                                        self.kv.v, table, *window)
-                if kv_win:
-                    self.kv_window.k, self.kv_window.v = kv_win
+                row, tok, *pools = self._prefill_chunk(
+                    self.params, toks, *self.kv.pools, table, *window)
+                self._take_back(pools)
                 if done:
                     first = jax.device_get(tok if req.greedy else row)
                 else:
                     first = None
-                    jax.block_until_ready(self.kv.k)
+                    jax.block_until_ready(self.kv.pools[0])
             device_s = dev.seconds
             with perf.phase("llm.prefill.host"):
                 req.prefilled_upto = upto + c
@@ -653,6 +655,14 @@ class LLMEngine:
                                   "device_ms": round(device_s * 1e3, 3),
                                   "host_ms": round(
                                       max(dur - device_s, 0.0) * 1e3, 3)})
+
+    def _take_back(self, pools):
+        """The pools a program was donated, as it returned them written:
+        the full kind's, then the window kind's."""
+        n = len(self.kv.pools)
+        self.kv.pools = tuple(pools[:n])
+        if self.kv_window is not None:
+            self.kv_window.pools = tuple(pools[n:])
 
     def _slide_window(self, req: Request, upto: int, c: int, pad: int):
         """The window kind's side of a chunk, on the host and BEFORE
@@ -882,14 +892,13 @@ class LLMEngine:
         # charged to the host, and the logits stay where they are unless
         # a lane samples with a temperature.
         with perf.device("llm.decode.device") as dev:
-            window = () if kvw is None else (kvw.k, kvw.v, win)
-            logits, ids, self.kv.k, self.kv.v, *kv_win = self._decode(
-                self.params, tokens, positions, self.kv.k, self.kv.v,
+            window = () if kvw is None else (*kvw.pools, win)
+            logits, ids, *pools = self._decode(
+                self.params, tokens, positions, *self.kv.pools,
                 tables, context_lens,
                 q_lens if spec is not None else self._one_row_each,
                 slot_blocks, slot_offsets, *window)
-            if kv_win:
-                kvw.k, kvw.v = kv_win
+            self._take_back(pools)
             jax.block_until_ready(ids)
         device_s = dev.seconds
         perf.add_cost(cost)
@@ -1071,7 +1080,7 @@ class LLMEngine:
         own shapes and its text searched for the kernel's custom
         call."""
         B, Q = self.max_batch, self._q_rows
-        key = (self._decode, self._pool_spec, B, Q)
+        key = (self._decode, self._pool_specs, B, Q)
         mode = _KERNEL_MODES.get(key)
         if mode is None:
             def i32(*shape):
@@ -1083,12 +1092,12 @@ class LLMEngine:
                 self.params)
             window = ()
             if self.kv_window is not None:
-                pool = jax.ShapeDtypeStruct(self.kv_window.k.shape,
-                                            self.kv_window.k.dtype)
-                window = (pool, pool, i32(B, self._win_len + 1 + Q))
+                window = (*(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                            for p in self.kv_window.pools),
+                          i32(B, self._win_len + 1 + Q))
             text = self._decode.lower(
-                params, i32(B, Q), i32(B, Q), self._pool_spec,
-                self._pool_spec, i32(B, self.max_nb), i32(B), i32(B),
+                params, i32(B, Q), i32(B, Q), *self._pool_specs,
+                i32(B, self.max_nb), i32(B), i32(B),
                 i32(B, Q), i32(B, Q), *window).as_text()
             mode = _KERNEL_MODES[key] = (
                 "compiled" if "tpu_custom_call" in text else "interpret")

@@ -2,10 +2,11 @@
 
 Reference layer map: this is the TPU-native analogue of vLLM's
 PagedAttention block manager (Kwon et al., SOSP '23) sitting where the
-reference runtime would hold framework-external model state. KV for
-every in-flight sequence lives in ONE device-resident pool, token-major:
-``[layers, num_blocks, block_size, kv_heads * head_dim]``, a token's K
-(or V) of every head in one row. A sequence owns an ordered list of
+reference runtime would hold framework-external model state. What every
+in-flight sequence keeps of its tokens lives in device-resident pools,
+token-major: ``[layers, num_blocks, block_size, row]``, for keys and
+values ``kv_heads * head_dim`` wide, a token's K (or V) of every head
+in one row. A sequence owns an ordered list of
 block ids (its *block table*) rather than a contiguous region.
 Consequences:
 
@@ -32,11 +33,18 @@ are the major ones, which is how XLA's scatter wants them, and a row of
 served width (768 = 6 x 128 at GPT-2-small), so the TPU runtime keeps
 the array at rest row-major and unpadded and a write lands where it
 is. The shape comes from the model's cache description (the serving
-seam, models/__init__.py): one pool a KIND of layer, ``[layers of the
-kind, num_blocks, block_size, kv_heads * head_dim]``. A model whose
-layers all keep every token has one kind and one pool
-(``PagedKVCache`` / ``PrefixPool``); a kind with a window keeps only
-the blocks that cover a sequence's last ``window`` tokens in a pool of
+seam, models/__init__.py): a KIND of layer says what a token leaves
+there (``LayerKind.rows``), one pool an entry, ``[layers of the kind,
+num_blocks, block_size, row width]``: keys and values are two pools of
+``kv_heads * head_dim``, latent attention ONE pool of the token's
+latent row (models/kimi_k2.py pads it to whole lane tiles itself, 576
+-> 640, for the reason above). A cache holds its kind's pools as a
+tuple (``PagedKVCache.pools``), the writers below write each of them,
+and the programs are handed the tuple and hand it back: nothing here or
+in the engine names a key or a value. A model whose
+layers all keep every token has one kind (``PagedKVCache`` /
+``PrefixPool``); a kind with a window keeps only
+the blocks that cover a sequence's last ``window`` tokens in pools of
 its own (``WindowPool``), so a lane holds two block tables. (Head-major, ``[..., kv_heads, num_blocks, block_size, head_dim]``
 with a 64-wide minor dimension, the runtime kept it in a compact layout
 that no reader or writer wanted, and every program converted the whole
@@ -58,17 +66,18 @@ import jax.numpy as jnp
 from ..models import serving
 
 
-def scatter_span(k_pool, v_pool, k, v, ids, rows=None):
+def scatter_span(pools, spans, ids, rows=None):
     """THE pool write of a prefill span, a pure function: the chunk
     program (a model's ``forward_prefill_chunk``) calls it on the pools
     it was donated, and ``PagedKVCache.write_prefill`` through the
     jitted ``kv_scatter_blocks`` below.
 
-    Pools ``[L, NB, BS, W]`` (W = kv_heads * head_dim). ``k``, ``v``:
-    the span's K/V, ``[L, T, kv_heads, head_dim]``, which is the pool's
-    own order (any shape that flattens to ``[L, T, W]`` will do: whole
-    blocks ``[L, nb, BS, W]`` too), T <= len(ids) * BS. ``ids`` [nb]
-    int32: the blocks written, in the span's order. The rows from
+    ``pools``: a kind's pools (``LayerKind.rows``: keys and values, or
+    one pool of latent rows), each ``[L, NB, BS, W_i]``. ``spans``: the
+    span's rows for each pool, in the pool's own order: any shape that
+    flattens to ``[L, T, W_i]`` will do (``[L, T, kv_heads, head_dim]``,
+    whole blocks ``[L, nb, BS, W_i]``), T <= len(ids) * BS. ``ids``
+    [nb] int32: the blocks written, in the span's order. The rows from
     ``rows`` on (a traced scalar or an int; None = T) and the tail
     past T are written as ZEROS, masked by context_lens at read time,
     so a pool's contents do not depend on what a chunk was padded
@@ -76,11 +85,12 @@ def scatter_span(k_pool, v_pool, k, v, ids, rows=None):
     blocks that slid out before they were written): which of them
     lands there is nobody's business, the block is never read
     unmasked. One in-place scatter a pool when the pools are donated:
-    the indexed dimension is the pool's major one after the layers."""
-    L, _, bs, W = k_pool.shape
-    n = ids.shape[0] * bs
+    the indexed dimension is the pool's major one after the layers.
+    Returns the pools, written, as a tuple."""
+    n = ids.shape[0] * pools[0].shape[2]
 
     def blocks(x, pool):
+        L, _, bs, W = pool.shape
         x = x.reshape(L, -1, W)
         if n > x.shape[1]:
             x = jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))
@@ -88,26 +98,42 @@ def scatter_span(k_pool, v_pool, k, v, ids, rows=None):
             x = jnp.where((jnp.arange(n) < rows)[None, :, None], x, 0)
         return x.reshape(L, -1, bs, W).astype(pool.dtype)
 
-    return (k_pool.at[:, ids].set(blocks(k, k_pool)),
-            v_pool.at[:, ids].set(blocks(v, v_pool)))
+    return tuple(pool.at[:, ids].set(blocks(x, pool))
+                 for pool, x in zip(pools, spans))
 
 
 # A device trace's ``XLA Modules`` line names each program after its
 # function: ``jit_kv_scatter_blocks``, ``jit_kv_copy_block``. A served
 # step runs the first no more (the chunk program writes its own span);
-# it is ``write_prefill``'s, for tests and tools.
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def kv_scatter_blocks(k_pool, v_pool, k, v, ids):
-    """``scatter_span`` as a program of its own, the pools donated."""
-    return scatter_span(k_pool, v_pool, k, v, ids)
+# it is ``write_prefill``'s, for tests and tools. One program a count
+# of pools, the pools donated.
+@functools.lru_cache(maxsize=None)
+def _scatter_program(n: int):
+    def kv_scatter_blocks(*args):
+        """``scatter_span`` as a program of its own: ``(*pools, *spans,
+        ids)``."""
+        return scatter_span(args[:n], args[n:2 * n], args[2 * n])
+
+    return jax.jit(kv_scatter_blocks, donate_argnums=tuple(range(n)))
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def kv_copy_block(k_pool, v_pool, src, dst):
-    """Copy-on-write split: duplicate one block's K/V (src/dst are
-    traced scalars, so every split shares one compile)."""
-    return (k_pool.at[:, dst].set(k_pool[:, src]),
-            v_pool.at[:, dst].set(v_pool[:, src]))
+@functools.lru_cache(maxsize=None)
+def _copy_program(n: int):
+    def kv_copy_block(*args):
+        """Copy-on-write split: duplicate one block's rows in every
+        pool, ``(*pools, src, dst)`` (src/dst are traced scalars, so
+        every split shares one compile)."""
+        src, dst = args[n:]
+        return tuple(pool.at[:, dst].set(pool[:, src]) for pool in args[:n])
+
+    return jax.jit(kv_copy_block, donate_argnums=tuple(range(n)))
+
+
+# The programs of a kind of keys and values, under the names they have
+# always had: ``(k_pool, v_pool, k, v, ids)`` and ``(k_pool, v_pool,
+# src, dst)``.
+kv_scatter_blocks = _scatter_program(2)
+kv_copy_block = _copy_program(2)
 
 
 def window_table_len(window: int, block_size: int, rows: int = 1) -> int:
@@ -132,12 +158,23 @@ class PagedKVCache:
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.dtype = dtype if dtype is not None else self.kind.dtype
-        shape = (len(self.kind.layers), num_blocks, block_size,
-                 self.kind.kv_width)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        # One pool an entry of the kind's ``rows``, in their order: what
+        # the served programs are handed and hand back, as a tuple.
+        self.pools = tuple(
+            jnp.zeros((len(self.kind.layers), num_blocks, block_size, w),
+                      self.dtype) for w in self.kind.rows)
         # LIFO free list (hot blocks rotate), block 0 reserved.
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
+
+    # A kind of keys and values' two pools by name (tests and tools; the
+    # engine hands ``pools`` over whole).
+    k = property(lambda self: self.pools[0],
+                 lambda self, x: self._set_pool(0, x))
+    v = property(lambda self: self.pools[1],
+                 lambda self, x: self._set_pool(1, x))
+
+    def _set_pool(self, i: int, x):
+        self.pools = self.pools[:i] + (x,) + self.pools[i + 1:]
 
     # -- allocator ---------------------------------------------------------
 
@@ -200,29 +237,32 @@ class PagedKVCache:
 
     # -- writes ------------------------------------------------------------
 
-    def write_prefill(self, k, v, block_ids: List[int]):
-        """Scatter a prefill's K/V into the pool (``scatter_span``, in a
-        program of its own: a served chunk writes its span inside the
-        chunk program instead). k, v: ``[L, T, kv_heads, head_dim]``,
-        which is the pool's own order. The tail of the last block is
-        zero-padded (masked by context_lens at read time)."""
-        if len(block_ids) * self.block_size < k.shape[1]:
-            raise ValueError(
-                f"{len(block_ids)} blocks cannot hold {k.shape[1]} tokens")
-        self.k, self.v = kv_scatter_blocks(
-            self.k, self.v, k, v, jnp.asarray(block_ids, jnp.int32))
+    def write_prefill(self, *spans_then_block_ids):
+        """Scatter a prefill's rows into the pools (``scatter_span``, in
+        a program of its own: a served chunk writes its span inside the
+        chunk program instead): ``write_prefill(*spans, block_ids)``, a
+        span a pool, each ``[L, T, ...]`` in the pool's own order (keys
+        and values: ``[L, T, kv_heads, head_dim]`` twice). The tail of
+        the last block is zero-padded (masked by context_lens at read
+        time)."""
+        *spans, block_ids = spans_then_block_ids
+        if len(spans) != len(self.pools):
+            raise ValueError(f"{len(self.pools)} pools, {len(spans)} spans")
+        if len(block_ids) * self.block_size < spans[0].shape[1]:
+            raise ValueError(f"{len(block_ids)} blocks cannot hold "
+                             f"{spans[0].shape[1]} tokens")
+        self.pools = _scatter_program(len(self.pools))(
+            *self.pools, *spans, jnp.asarray(block_ids, jnp.int32))
 
     def gather_tokens(self, block_ids: List[int], length: int):
-        """Read back ``length`` tokens' K/V as ``[L, length, Hkv, d]``
-        (tests / debugging — the decode path never materializes this)."""
+        """Read back ``length`` tokens' rows, ``[L, length, W_i]`` a
+        pool (tests / debugging — the decode path never materializes
+        this)."""
         ids = jnp.asarray(block_ids, jnp.int32)
-        hkv, d = self.kind.kv_heads, self.kind.head_dim
-        k = jnp.take(self.k, ids, axis=1)   # [L, nb, BS, Hkv * d]
-        v = jnp.take(self.v, ids, axis=1)
-        L, nb, bs, _ = k.shape
-        k = k.reshape(L, nb * bs, hkv, d)
-        v = v.reshape(L, nb * bs, hkv, d)
-        return k[:, :length], v[:, :length]
+        return tuple(
+            jnp.take(pool, ids, axis=1).reshape(
+                pool.shape[0], -1, pool.shape[3])[:, :length]
+            for pool in self.pools)
 
 
 class PrefixPool(PagedKVCache):
@@ -490,8 +530,8 @@ class PrefixPool(PagedKVCache):
         if grant is None:
             return None
         dst = grant[0]
-        self.k, self.v = kv_copy_block(
-            self.k, self.v, jnp.asarray(bid, jnp.int32),
+        self.pools = _copy_program(len(self.pools))(
+            *self.pools, jnp.asarray(bid, jnp.int32),
             jnp.asarray(dst, jnp.int32))
         self.cow_splits += 1
         self._event("cow", src=bid, dst=dst,

@@ -1,6 +1,8 @@
-"""Model families: the GPT decoder (models/gpt.py, trained and served)
-and Laguna (models/laguna.py, served: window and full attention layers,
-routed experts).
+"""Model families: the GPT decoder (models/gpt.py, trained and served),
+Laguna (models/laguna.py, served: window and full attention layers,
+routed experts) and Kimi-K2 (models/kimi_k2.py, served: latent
+attention over one pool of latent rows, a share of sigmoid-routed
+experts).
 
 Models are pure-JAX functional: ``init(key, cfg)`` returns the param pytree;
 ``param_axes(cfg)`` returns the matching pytree of logical-axis annotations
@@ -13,27 +15,33 @@ Serve deployment (serve/llm.py) know no model: they ask ``serving(cfg)``
 for what the configuration's own module says of it, a ``Serving``:
 
   init      ``init(key, cfg)``: the parameters as served
-  step      the decode step, ``(params, tokens, positions, k_pool,
-            v_pool, block_tables, context_lens, q_lens, slot_blocks,
+  step      the decode step, ``(params, tokens, positions, *pools,
+            block_tables, context_lens, q_lens, slot_blocks,
             slot_offsets, *window, cfg=)`` ->
-            ``(logits, ids, k_pool, v_pool, *window pools)``
+            ``(logits, ids, *pools, *window pools)``
   chunk     one span of a prompt as ONE program, ``(params, tokens,
-            k_pool, v_pool, table, *window, cfg=)`` ->
-            ``(row, id, k_pool, v_pool, *window pools)``. It writes the
-            span's K/V into the pools (donated, like the step's) and
-            hands back the logits of the span's last real token and
-            their argmax. ``table`` is one int32 array, ``[block table
-            | destination blocks | ctx_len | last]`` (``pack_span``): a
+            *pools, table, *window, cfg=)`` ->
+            ``(row, id, *pools, *window pools)``. It writes the span's
+            rows into the pools (donated, like the step's) and hands
+            back the logits of the span's last real token and their
+            argmax. ``table`` is one int32 array, ``[block table |
+            destination blocks | ctx_len | last]`` (``pack_span``): a
             chunk's whole bookkeeping in one hand-over
-  kinds     the cache description: one ``LayerKind`` a kind of layer.
-            ``kinds[0]`` keeps every token of a sequence (its pools are
-            ``k_pool`` / ``v_pool`` above); a second kind, if there is
-            one, has a ``window`` and keeps only the blocks that cover a
+  kinds     the cache description: one ``LayerKind`` a kind of layer,
+            which says what a token leaves in the cache there: how many
+            pools the kind has and how wide a row of each is
+            (``LayerKind.rows``). Keys and values are two pools of
+            ``kv_heads * head_dim`` (models/gpt.py, models/laguna.py);
+            latent attention is ONE pool of ``kv_lora_rank +
+            qk_rope_head_dim`` (models/kimi_k2.py). ``kinds[0]`` keeps
+            every token of a sequence (``*pools`` above are its pools,
+            in ``rows``' order); a second kind, if there is one, has a
+            ``window`` and keeps only the blocks that cover a
             sequence's last ``window`` tokens. Its pools and its int32
             array ride after the full kind's arguments (``*window``:
-            ``k_win, v_win, win``; see models/laguna.py for the array,
-            which in a chunk also names the blocks the span is written
-            to).
+            its pools, then ``win``; see models/laguna.py for the
+            array, which in a chunk also names the blocks the span is
+            written to).
   cost      the cost description util/perfmodel.py prices steps from
   counters  names of the int32 counters the step program appends to its
             ``ids`` as rows ``[max_batch + i]``: they ride in the one
@@ -58,15 +66,26 @@ class LayerKind:
     """One kind of attention layer, as the cache manager sees it."""
     name: str                   # "full" | "window"
     layers: Tuple[int, ...]     # the model's layers of this kind, in order
-    kv_heads: int
-    head_dim: int
+    # What a token leaves in a layer of this kind: one pool an entry,
+    # the entry the width of the token's row there. Keys and values:
+    # (kv_heads * head_dim,) * 2; a latent row: (rank + rope dims,).
+    rows: Tuple[int, ...]
     window: Optional[int]       # tokens a layer attends; None = all
     dtype: Any
 
     @property
     def kv_width(self) -> int:
-        """A token's K (or V) of every head: one row of the pool."""
-        return self.kv_heads * self.head_dim
+        """A row of the kind's first pool (of a kind of keys and
+        values: a token's K of every head, and its V is as wide)."""
+        return self.rows[0]
+
+
+def keys_and_values(name: str, layers, kv_heads: int, head_dim: int,
+                    window: Optional[int], dtype) -> LayerKind:
+    """The kind of layer that keeps a token's keys and its values of
+    whole heads: two pools of ``kv_heads * head_dim``."""
+    return LayerKind(name, tuple(layers), (kv_heads * head_dim,) * 2,
+                     window, dtype)
 
 
 @dataclass(frozen=True)
@@ -107,4 +126,4 @@ def serving(cfg) -> Serving:
     return importlib.import_module(type(cfg).__module__).serving(cfg)
 
 
-from . import gpt, laguna, resnet  # noqa: E402,F401
+from . import gpt, kimi_k2, laguna, resnet  # noqa: E402,F401

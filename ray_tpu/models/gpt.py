@@ -547,8 +547,8 @@ def forward_prefill_chunk(params, tokens, k_pool, v_pool, table,
                             cfg.max_seq - 1)
     x, k, v = _chunk_layers(params, tokens, positions, k_pool, v_pool,
                             block_table, ctx_len, cfg)
-    k_pool, v_pool = scatter_span(k_pool, v_pool, k[:, 0], v[:, 0], dest,
-                                  last + 1)
+    k_pool, v_pool = scatter_span((k_pool, v_pool), (k[:, 0], v[:, 0]),
+                                  dest, last + 1)
     row = _head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
                 cfg)[0, 0]
     return row, _greedy_ids(row), k_pool, v_pool
@@ -567,6 +567,8 @@ def cost_shape(cfg: GPTConfig) -> dict:
         "matmul_weights": L * per_layer + cfg.vocab_size * m,
         "head_weights": cfg.vocab_size * m,   # of those, the head's
         "attn_per_ctx": 4.0 * m * L,     # flops per token per context pos
+        "chunk_attn_per_ctx": 4.0 * m * L,   # a chunk's rows cost the same
+        "chunk_ctx_ops": 0.0,            # a cached key is read as it is
         "attn_windows": (),              # no layer with a window
         "num_params": n,
         "streamed_params": lambda rows: n,   # every weight, every step
@@ -579,10 +581,10 @@ def cost_shape(cfg: GPTConfig) -> dict:
 def serving(cfg: GPTConfig):
     """This model behind the serving seam (models/__init__.py): one
     kind of layer, every layer keeps every token."""
-    from . import LayerKind, Serving
+    from . import Serving, keys_and_values
 
-    full = LayerKind("full", tuple(range(cfg.n_layer)), cfg.kv_heads,
-                     cfg.head_dim, None, cfg.dtype)
+    full = keys_and_values("full", range(cfg.n_layer), cfg.kv_heads,
+                           cfg.head_dim, None, cfg.dtype)
     return Serving(init=init, step=forward_step,
                    chunk=forward_prefill_chunk, kinds=(full,),
                    cost=cost_shape(cfg), max_seq=cfg.max_seq,

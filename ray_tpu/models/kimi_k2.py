@@ -1,0 +1,565 @@
+"""Kimi-K2 (moonshotai, https://huggingface.co/moonshotai/Kimi-K2.5;
+``model_type`` ``kimi_k2``, the DeepSeek-V3 block): a decoder with
+latent attention (MLA) and sigmoid-routed experts, served through the
+generation engine (llm/engine.py). The language model only: requests
+carry token ids, no vision tower is built. No loss and no train step.
+
+The layer (x is [T, hidden]; H heads; no biases; RMSNorm; layers below
+``first_k_dense_replace`` have a dense MLP, the rest a routed one):
+
+  h = RMSNorm(x)
+  c_q = RMSNorm(h W_dq) [T, q_lora_rank]; q = c_q W_uq [T, H, nope + rope]
+  [c_kv | k_r] = h W_dkv [T, kv_lora_rank + rope]; c_kv <- RMSNorm(c_kv)
+  k_rope = RoPE(k_r): ONE rotary key a token, shared by every head;
+  q_rope <- RoPE(q_rope). YaRN inverse frequencies over the rope dims,
+    pairs (i, i + rope/2), cos and sin unscaled (mscale / mscale_all_dim
+    = 1).
+  [k_nope | v] = c_kv W_ukv [T, H, nope + v]
+  scores (q_nope . k_nope + q_rope . k_rope) * softmax_scale, causal,
+    softmax_scale = (nope + rope)^-0.5 * mscale(factor, mscale_all_dim)^2
+  o = softmax(scores) v; x <- x + concat_h(o_h) W_o
+  h2 = RMSNorm(x). dense: (silu(h2 W_gate) * h2 W_up) W_down.
+    routed: s = sigmoid(h2 W_r) over all experts in float32; the
+    ``num_experts_per_tok`` largest of s + b (b the router's correction
+    bias); w = routed_scaling_factor * s_top / sum(s_top), s WITHOUT b;
+    out = sum_e w_e Expert_e(h2) + Shared(h2), each a SwiGLU, the
+    weight on the expert's output (ops/moe.py ``route_sigmoid``).
+  x <- x + out
+Final RMSNorm, untied head. ``models/kimi_k2_ref.py`` is the plain
+float32 reference of these equations.
+
+**The cache** keeps, a token a layer, ONE latent row: ``[c_kv after its
+norm | k_rope after rotary | zeros]``, ``kv_lora_rank + rope`` values
+padded to whole 128-lane tiles (576 -> 640): a minor dimension that is
+not whole tiles gets an at-rest layout from the TPU runtime that no
+reader or writer wants (PERF.md section 6, PR 31 and PR 34), and padded
+by hand the row costs what the runtime would have made it cost. Behind
+the serving seam (models/__init__.py) that is a kind of layer with one
+pool.
+
+**Two paths over it.** A decode step attends in the ABSORBED form: a
+head's ``q_nope`` goes through ``W_uk`` into the latent space, the
+kernel (ops/pallas/paged_decode.py ``paged_attention_latent``, named
+``attn_latent``) scores the query against latent rows as stored and
+sums the same rows as values, and the output comes back through
+``W_uv``; no key or value of a head is ever made. A prefill chunk goes
+the other way round: it takes the context's latent rows UP through
+``W_ukv`` to keys and values, a block of context at a time, and attends
+with nope+rope / v wide heads, which at a 512-token span costs about
+half the absorbed form's operations (the two cross near 170 tokens).
+Context blocks wholly past the sequence's context are skipped.
+
+**A share of the experts.** A configuration says which experts of the
+``n_routed_experts`` this chip holds (``experts_held`` from
+``first_expert``): the router scores all of them, the layer computes
+its own experts' part for the tokens routed to them, adds the shared
+expert and hands that partial result on. What the other chips of the
+deployment would add is theirs; nothing here stands in for them or for
+the exchange.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from dataclasses import dataclass
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import moe
+from ..ops.attention import NEG_INF
+from .laguna import _rmsnorm, _rotary, _swiglu
+
+# Context slots a chunk takes up to keys and values at a time: the
+# block's scores [heads, span, slots] float32 are the chunk program's
+# largest temporary, 134 MB at 64 heads x 512 x 1,024.
+CTX_BLOCK = 1024
+
+
+@dataclass(frozen=True)
+class KimiK2Config:
+    """Field names are the published config.json's; ``experts_held`` /
+    ``first_expert`` say which routed experts this chip holds,
+    ``max_seq`` is the deployment's limit and ``dtype`` what weights,
+    activations and the cache are held in. ``rope_scaling`` arrives
+    from JSON as a dict and is frozen to (key, value) pairs."""
+    vocab_size: int = 163840
+    hidden_size: int = 7168
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    num_hidden_layers: int = 61
+    num_attention_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_routed_experts: int = 384
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    first_k_dense_replace: int = 1
+    moe_layer_freq: int = 1
+    routed_scaling_factor: float = 2.827
+    norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    n_group: int = 1
+    topk_group: int = 1
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 50000.0
+    rope_scaling: Any = (("beta_fast", 32), ("beta_slow", 1), ("factor", 64),
+                         ("mscale", 1), ("mscale_all_dim", 1),
+                         ("original_max_position_embeddings", 4096),
+                         ("type", "yarn"))
+    experts_held: int = 384
+    first_expert: int = 0
+    max_seq: int = 17408
+    dtype: Any = "bfloat16"
+
+    def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        object.__setattr__(self, "dtype", jnp.dtype(self.dtype))
+        # The forms this module builds; another value is another model.
+        built = {"scoring_func": "sigmoid", "topk_method": "noaux_tc",
+                 "norm_topk_prob": True, "n_group": 1, "topk_group": 1,
+                 "moe_layer_freq": 1}
+        for name, want in built.items():
+            if getattr(self, name) != want:
+                raise ValueError(f"{name}={getattr(self, name)!r}: only "
+                                 f"{want!r} is built")
+        if dict(self.rope_scaling)["type"] != "yarn":
+            raise ValueError("only YaRN rope_scaling is built")
+        if not 0 <= self.first_expert <= \
+                self.n_routed_experts - self.experts_held:
+            raise ValueError("the held experts lie outside the routed ones")
+
+    def routed(self, l: int) -> bool:
+        return l >= self.first_k_dense_replace
+
+    @property
+    def latent_width(self) -> int:
+        """What a token leaves in the cache a layer: c_kv and k_rope."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def row_width(self) -> int:
+        """A row of the latent pool: ``latent_width`` in whole 128-lane
+        tiles (module docstring)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def softmax_scale(self) -> float:
+        r = dict(self.rope_scaling)
+        head = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return head ** -0.5 * yarn_mscale(r["factor"],
+                                          r["mscale_all_dim"]) ** 2
+
+    @property
+    def rope(self) -> tuple:
+        """The rotary dims' ``rope_parameters`` group as
+        models/laguna.py ``rope_inv_freq`` takes it."""
+        r = dict(self.rope_scaling)
+        return (("rope_type", "yarn"), ("rope_theta", self.rope_theta),
+                ("factor", r["factor"]),
+                ("original_max_position_embeddings",
+                 r["original_max_position_embeddings"]),
+                ("beta_slow", r["beta_slow"]), ("beta_fast", r["beta_fast"]),
+                ("attention_factor",
+                 yarn_mscale(r["factor"], r["mscale"])
+                 / yarn_mscale(r["factor"], r["mscale_all_dim"])),
+                ("partial_rotary_factor", 1.0))
+
+    def num_params(self) -> int:
+        """Parameters held here (the share's experts, not all)."""
+        m, H = self.hidden_size, self.num_attention_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        attn = (m * self.q_lora_rank + self.q_lora_rank * H * qk
+                + m * self.latent_width + self.kv_lora_rank * H
+                * (self.qk_nope_head_dim + self.v_head_dim)
+                + H * self.v_head_dim * m
+                + 2 * m + self.q_lora_rank + self.kv_lora_rank)
+        expert = 3 * m * self.moe_intermediate_size
+        n = 2 * self.vocab_size * m + m
+        for l in range(self.num_hidden_layers):
+            n += attn
+            if self.routed(l):
+                n += (m * self.n_routed_experts + self.n_routed_experts
+                      + (self.experts_held + self.n_shared_experts) * expert)
+            else:
+                n += 3 * m * self.intermediate_size
+        return n
+
+
+def yarn_mscale(factor: float, m: float) -> float:
+    """YaRN's attention scale: ``0.1 m ln(factor) + 1`` above factor 1."""
+    return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+# The router's correction bias is drawn from the seed at this size
+# (``assumed``: the published one is learned): sigmoid scores of a
+# token's 8th and 9th expert of 384 lie ~0.005 apart, so a bias of this
+# size changes the chosen set for most tokens, and "choose by s + b,
+# weigh by s" is exercised.
+ROUTER_BIAS_STD = 0.02
+
+
+def init(key, cfg: KimiK2Config) -> dict:
+    """Seeded random parameters in ``cfg.dtype`` (normal, std 0.02;
+    norms 1), a layer at a time (``init_layer``). A routed expert's
+    weights depend on the key and the expert's GLOBAL id alone, so every
+    share of one model holds slices of the same experts."""
+    return {
+        **_init_ends(jax.random.fold_in(key, 1 << 20), cfg),
+        "layers": [init_layer(key, cfg, l)
+                   for l in range(cfg.num_hidden_layers)],
+    }
+
+
+def _normal(key, shape, dtype, std=0.02):
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _init_ends(key, cfg: KimiK2Config) -> dict:
+    m, V = cfg.hidden_size, cfg.vocab_size
+    ke, kh = jax.random.split(key)
+    return {"embed": _normal(ke, (V, m), cfg.dtype),
+            "head": _normal(kh, (m, V), cfg.dtype),
+            "norm_f": jnp.ones((m,), cfg.dtype)}
+
+
+def init_layer(key, cfg: KimiK2Config, l: int) -> dict:
+    """Layer ``l``'s parameters, from ``fold_in(key, l)``."""
+    return _init_layer(jax.random.fold_in(key, l), cfg, cfg.routed(l))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "routed"))
+def _init_layer(key, cfg: KimiK2Config, routed: bool) -> dict:
+    m, H, dt = cfg.hidden_size, cfg.num_attention_heads, cfg.dtype
+    nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    k = iter(jax.random.split(key, 12))
+    p = {
+        "ln1": jnp.ones((m,), dt), "ln2": jnp.ones((m,), dt),
+        "q_norm": jnp.ones((rq,), dt), "kv_norm": jnp.ones((rkv,), dt),
+        "w_dq": _normal(next(k), (m, rq), dt),
+        "w_uq": _normal(next(k), (rq, H, nope + rope), dt),
+        "w_dkv": _normal(next(k), (m, rkv + rope), dt),
+        # [k_nope | v] side by side: W_uk and W_uv of the absorbed form.
+        "w_ukv": _normal(next(k), (rkv, H, nope + dv), dt),
+        "w_o": _normal(next(k), (H, dv, m), dt),
+    }
+    if not routed:
+        f = cfg.intermediate_size
+        p["w_gu"] = _normal(next(k), (m, 2 * f), dt)
+        p["w_down"] = _normal(next(k), (f, m), dt)
+        return p
+    E, f = cfg.n_routed_experts, cfg.moe_intermediate_size
+    fs = cfg.n_shared_experts * f
+    p["router"] = _normal(next(k), (m, E), dt)
+    p["router_bias"] = _normal(next(k), (E,), jnp.float32, ROUTER_BIAS_STD)
+    k1, k2 = next(k), next(k)
+    held = cfg.first_expert + jnp.arange(cfg.experts_held)
+    p["w1"] = jax.vmap(lambda e: _normal(
+        jax.random.fold_in(k1, e), (m, 2 * f), dt))(held)
+    p["w2"] = jax.vmap(lambda e: _normal(
+        jax.random.fold_in(k2, e), (f, m), dt))(held)
+    p["s_gu"] = _normal(next(k), (m, 2 * fs), dt)
+    p["s_down"] = _normal(next(k), (fs, m), dt)
+    return p
+
+
+# ---------------------------------------------------------------------------
+# The layer
+# ---------------------------------------------------------------------------
+
+
+def _mlp(h2, p, cfg: KimiK2Config, program: str):
+    """h2 [T, m] -> (out [T, m], the held experts' tokens [held] or
+    None for a dense layer). The grouped product's kernel is
+    ``moe_experts_<program>`` on a device trace."""
+    if "router" not in p:
+        return _swiglu(h2, p["w_gu"], p["w_down"]), None
+    with jax.named_scope("moe_route"):
+        _, experts, weights = moe.route_sigmoid(
+            h2, p["router"], p["router_bias"], cfg.num_experts_per_tok,
+            cfg.routed_scaling_factor)
+    with jax.named_scope("moe_experts"):
+        y, sizes = moe.routed_experts(
+            h2, experts, weights, p["w1"], p["w2"], first=cfg.first_expert,
+            name=f"moe_experts_{program}")
+    return y + _swiglu(h2, p["s_gu"], p["s_down"]), sizes
+
+
+def _project(h, p, positions, cfg: KimiK2Config):
+    """The attention sublayer's projections of h [b, r, m] at
+    ``positions`` [b, r]: (q_nope [b, r, H, nope], q_rope [b, r, H,
+    rope] rotated, the rows' latent rows [b, r, row_width] as the cache
+    keeps them)."""
+    nope, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    c_q = _rmsnorm(jnp.dot(h, p["w_dq"]), p["q_norm"], cfg.rms_norm_eps)
+    q = jnp.einsum("brc,chd->brhd", c_q, p["w_uq"])
+    q_rope = _rotary(q[..., nope:], positions, cfg.rope,
+                     cfg.qk_rope_head_dim)
+    ckv = jnp.dot(h, p["w_dkv"])
+    c_kv = _rmsnorm(ckv[..., :rkv], p["kv_norm"], cfg.rms_norm_eps)
+    k_rope = _rotary(ckv[..., None, rkv:], positions, cfg.rope,
+                     cfg.qk_rope_head_dim)[..., 0, :]
+    pad = jnp.zeros(ckv.shape[:-1] + (cfg.row_width - cfg.latent_width,),
+                    ckv.dtype)
+    return q[..., :nope], q_rope, jnp.concatenate([c_kv, k_rope, pad], -1)
+
+
+def _block(x, p, cfg: KimiK2Config, attend, program: str):
+    """The one layer, on [batch, rows, m]: norm -> latent attention ->
+    residual -> norm -> MLP -> residual. ``attend(h, p)`` is the mode's
+    attention sublayer on the normed rows: it projects them
+    (``_project``), keeps their latent rows where the mode keeps them,
+    and returns o [b, r, H, v]. Returns (x, the held experts' tokens or
+    None)."""
+    b, r, m = x.shape
+    h = _rmsnorm(x, p["ln1"], cfg.rms_norm_eps)
+    o = attend(h, p)
+    x = x + jnp.einsum("brhd,hdm->brm", o, p["w_o"])
+    h2 = _rmsnorm(x, p["ln2"], cfg.rms_norm_eps)
+    out, sizes = _mlp(h2.reshape(b * r, m), p, cfg, program)
+    return x + out.reshape(b, r, m), sizes
+
+
+def _head(params, x, cfg: KimiK2Config):
+    x = _rmsnorm(x, params["norm_f"], cfg.rms_norm_eps)
+    return jnp.einsum("brm,mv->brv", x, params["head"])
+
+
+COUNTERS = ("moe_experts_hit", "moe_load_max_x1000", "moe_held_rows")
+
+
+def _counters(sizes, rows: int, cfg: KimiK2Config, q: int):
+    """The step's counter rows [3, q] int32 (``COUNTERS``): held
+    experts that got a token (a routed layer's mean), 1000 x the busiest
+    held expert's tokens over the DEPLOYMENT's mean an expert (rows x
+    experts a token / all routed experts; the worst layer), and the
+    assignments that fell on the held experts (a layer's mean)."""
+    if not sizes:
+        return jnp.zeros((len(COUNTERS), q), jnp.int32)
+    s = jnp.stack(sizes)                                  # [layers, held]
+    hit = (s > 0).sum() // len(sizes)
+    load = (s.max() * (1000 * cfg.n_routed_experts)) \
+        // (rows * cfg.num_experts_per_tok)
+    held = s.sum() // len(sizes)
+    return jnp.broadcast_to(jnp.stack([hit, load, held])[:, None],
+                            (len(COUNTERS), q)).astype(jnp.int32)
+
+
+def forward_step(params, tokens, positions, pool, block_tables,
+                 context_lens, q_lens, slot_blocks, slot_offsets,
+                 cfg: KimiK2Config):
+    """One decode step (models/gpt.py ``forward_step``'s contract) over
+    ONE pool of latent rows ``[layers, num_blocks, block_size,
+    row_width]``, in the absorbed form: each row's latent row is
+    written at ``(layer, slot_blocks, slot_offsets)``, then the lane's
+    queries, taken into the latent space, attend the pool as stored.
+
+    Returns (logits [b, q, vocab], ids [b + 3, q] int32, pool): rows b
+    on of ``ids`` are ``COUNTERS``."""
+    from ..ops.pallas.paged_decode import paged_attention_latent
+
+    B, Q = tokens.shape
+    nope, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    x = params["embed"][tokens]
+    sizes = []
+    for li, p in enumerate(params["layers"]):
+
+        def attend(h, p, li=li):
+            nonlocal pool
+            q_nope, q_rope, rows = _project(h, p, positions, cfg)
+            pool = pool.at[li, slot_blocks, slot_offsets].set(rows)
+            w_uk, w_uv = p["w_ukv"][..., :nope], p["w_ukv"][..., nope:]
+            q_lat = jnp.einsum("brhd,chd->brhc", q_nope, w_uk)
+            pad = jnp.zeros(q_lat.shape[:-1]
+                            + (cfg.row_width - cfg.latent_width,), q_lat.dtype)
+            with jax.named_scope("attn_latent"):
+                o_lat = paged_attention_latent(
+                    jnp.concatenate([q_lat, q_rope, pad], -1), pool, li,
+                    block_tables, context_lens, q_lens, rank=rkv,
+                    scale=cfg.softmax_scale, name="attn_latent")
+            return jnp.einsum("brhc,chd->brhd", o_lat, w_uv)
+
+        x, s = _block(x, p, cfg, attend, "decode")
+        if s is not None:
+            sizes.append(s)
+    logits = _head(params, x, cfg)
+    ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    ids = jnp.concatenate([ids, _counters(sizes, B * Q, cfg, Q)])
+    return logits, ids, pool
+
+
+def _ctx_blocks(nb: int, block_size: int) -> int:
+    """Table entries a context block of a chunk spans: the largest
+    divisor of the table's length within ``CTX_BLOCK`` slots."""
+    want = max(CTX_BLOCK // block_size, 1)
+    return max(d for d in range(1, min(want, nb) + 1) if nb % d == 0)
+
+
+def _chunk_attention(q_nope, q_rope, rows, ctx, ctx_len, w_ukv, block: int,
+                     cfg: KimiK2Config):
+    """A span's attention over [pool context ++ span], the UP-PROJECTING
+    form: latent rows go through ``W_ukv`` to a head's keys and values,
+    ``block`` context slots at a time under one online softmax; a block
+    wholly past ``ctx_len`` is skipped. q_nope [n, H, nope], q_rope
+    [n, H, rope]; rows [n, W]: the span's own latent rows (query i at
+    position ctx_len + i); ctx [S, W]: the sequence's gathered pool
+    slots, slot s at position s, real below ctx_len. Returns
+    [n, H, v]."""
+    n, H, nope = q_nope.shape
+    rkv, rope, dv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    scale, dt = cfg.softmax_scale, q_nope.dtype
+
+    def fold(carry, lat, mask):
+        """Fold keys ``lat`` [s, W] (mask [n, s]: who sees whom)."""
+        m, l, acc = carry
+        kv = jnp.einsum("sc,chd->hsd", lat[:, :rkv].astype(dt), w_ukv)
+        s = (jnp.einsum("nhd,hsd->hns", q_nope, kv[..., :nope],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("nhd,sd->hns", q_rope,
+                          lat[:, rkv:rkv + rope].astype(dt),
+                          preferred_element_type=jnp.float32)) * scale
+        s = jnp.where(mask[None], s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "hns,hsd->hnd", p.astype(dt), kv[..., nope:],
+            preferred_element_type=jnp.float32)
+        return m_new, l * corr + p.sum(-1), acc
+
+    carry = (jnp.full((H, n), NEG_INF, jnp.float32),
+             jnp.zeros((H, n), jnp.float32),
+             jnp.zeros((H, n, dv), jnp.float32))
+    if ctx.shape[0]:
+        def step(carry, xs):
+            lat, start = xs
+            seen = jnp.broadcast_to(
+                (start + jnp.arange(block) < ctx_len)[None], (n, block))
+            return jax.lax.cond(start < ctx_len,
+                                lambda c: fold(c, lat, seen),
+                                lambda c: c, carry), None
+
+        carry, _ = jax.lax.scan(step, carry, (
+            ctx.reshape(-1, block, ctx.shape[1]),
+            jnp.arange(0, ctx.shape[0], block, dtype=jnp.int32)))
+    causal = jnp.arange(n)[None, :] <= jnp.arange(n)[:, None]
+    _, l, acc = fold(carry, rows, causal)
+    return (acc / l[..., None]).transpose(1, 0, 2).astype(dt)
+
+
+def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config):
+    """One span of a prompt as one program (models/gpt.py
+    ``forward_prefill_chunk``'s contract, over one latent pool):
+    ``tokens`` [1, n], ``table`` = ``[block table | destination |
+    ctx_len | last]``. Every layer reads the pool as it came in; the
+    span's latent rows are written after the last layer.
+
+    Returns (row [vocab], id, pool)."""
+    from ..llm.kv_cache import scatter_span
+    from . import unpack_span
+
+    n = tokens.shape[1]
+    bs = pool.shape[2]
+    block_table, dest, ctx_len, last = unpack_span(table, n, bs)
+    nb = block_table.shape[0]
+    positions = jnp.minimum(ctx_len + jnp.arange(n, dtype=jnp.int32),
+                            cfg.max_seq - 1)[None]
+    block = _ctx_blocks(nb, bs) * bs if nb else 0
+    x = params["embed"][tokens]
+    new = []
+    for li, p in enumerate(params["layers"]):
+
+        def attend(h, p, li=li):
+            q_nope, q_rope, rows = _project(h, p, positions, cfg)
+            new.append(rows)
+            ctx = pool[li, block_table].reshape(nb * bs, pool.shape[3])
+            with jax.named_scope("attn_latent_chunk"):
+                o = _chunk_attention(q_nope[0], q_rope[0], rows[0], ctx,
+                                     ctx_len, p["w_ukv"], block, cfg)
+            return o[None]
+
+        x, _ = _block(x, p, cfg, attend, "chunk")
+    pool, = scatter_span((pool,), (jnp.stack(new)[:, 0],), dest, last + 1)
+    row = _head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
+                cfg)[0, 0]
+    return row, jnp.argmax(row).astype(jnp.int32), pool
+
+
+# ---------------------------------------------------------------------------
+# The serving seam
+# ---------------------------------------------------------------------------
+
+
+def cost_shape(cfg: KimiK2Config) -> dict:
+    """The cost description util/perfmodel.py prices steps from. A
+    token passes attention's projections, the router, the shared expert,
+    the head and, of its ``num_experts_per_tok`` experts, the share
+    held here; a step streams the always-read weights and the held
+    experts its rows are expected to hit. A context token costs a
+    decode row ``2 x heads x (row + rank)`` operations a layer (the
+    absorbed form) and ``latent_width`` cache values; a chunk pays the
+    up-projection once a context token and attends with whole heads."""
+    m, H, L = cfg.hidden_size, cfg.num_attention_heads, cfg.num_hidden_layers
+    nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    E, k, held = cfg.n_routed_experts, cfg.num_experts_per_tok, \
+        cfg.experts_held
+    attn = (m * rq + rq * H * (nope + rope) + m * cfg.latent_width
+            + rkv * H * (nope + dv) + H * dv * m)
+    expert = 3 * m * cfg.moe_intermediate_size
+    dense = sum(not cfg.routed(l) for l in range(L))
+    routed = L - dense
+    always = (2 * cfg.vocab_size * m + L * attn
+              + dense * 3 * m * cfg.intermediate_size
+              + routed * (m * E + cfg.n_shared_experts * expert))
+    active = always - cfg.vocab_size * m + routed * expert * k * held / E
+
+    def streamed(rows):
+        hit = held * (1.0 - (1.0 - k / E) ** max(rows, 0))
+        return always + routed * hit * expert
+
+    return {
+        "matmul_weights": active,
+        "head_weights": cfg.vocab_size * m,
+        # A decode row against a context token, the absorbed form:
+        # scores over the latent row, values over its rank columns.
+        "attn_per_ctx": 2.0 * L * H * (cfg.latent_width + rkv),
+        # A chunk's row against a context token, whole heads; and what
+        # taking a context token up to keys and values costs a chunk.
+        "chunk_attn_per_ctx": 2.0 * L * H * (nope + rope + dv),
+        "chunk_ctx_ops": 2.0 * L * rkv * H * (nope + dv),
+        "attn_windows": (),
+        "num_params": cfg.num_params(),
+        "streamed_params": streamed,
+        "param_bytes": cfg.dtype.itemsize,
+        # Cache elements a token: one latent row a layer, as stored.
+        "kv_bytes_per_token": L * cfg.row_width,
+        "m": m, "L": L,
+    }
+
+
+def serving(cfg: KimiK2Config):
+    from . import LayerKind, Serving
+
+    latent = LayerKind("full", tuple(range(cfg.num_hidden_layers)),
+                       (cfg.row_width,), None, cfg.dtype)
+    return Serving(init=init, step=forward_step,
+                   chunk=forward_prefill_chunk, kinds=(latent,),
+                   cost=cost_shape(cfg), max_seq=cfg.max_seq,
+                   vocab_size=cfg.vocab_size, counters=COUNTERS)

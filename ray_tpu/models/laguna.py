@@ -516,9 +516,9 @@ def forward_prefill_chunk(params, tokens, k_pool, v_pool, table,
     x, k, v, kw, vw = _chunk_layers(
         params, tokens, positions, k_pool, v_pool, block_table, ctx_len,
         k_win, v_win, win[:nbw], win[nbw], cfg)
-    k_pool, v_pool = scatter_span(k_pool, v_pool, k[:, 0], v[:, 0], dest,
-                                  last + 1)
-    k_win, v_win = scatter_span(k_win, v_win, kw[:, 0], vw[:, 0],
+    k_pool, v_pool = scatter_span((k_pool, v_pool), (k[:, 0], v[:, 0]),
+                                  dest, last + 1)
+    k_win, v_win = scatter_span((k_win, v_win), (kw[:, 0], vw[:, 0]),
                                 win[nbw + 1:], last + 1)
     row = _head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
                 cfg)[0, 0]
@@ -569,6 +569,8 @@ def cost_shape(cfg: LagunaConfig) -> dict:
         "matmul_weights": active,
         "head_weights": cfg.vocab_size * m,
         "attn_per_ctx": full_coef,
+        "chunk_attn_per_ctx": full_coef,   # a chunk's rows cost the same
+        "chunk_ctx_ops": 0.0,              # a cached key is read as it is
         "attn_windows": tuple(windows),
         "num_params": cfg.num_params(),
         "streamed_params": streamed,
@@ -581,12 +583,13 @@ def cost_shape(cfg: LagunaConfig) -> dict:
 
 
 def serving(cfg: LagunaConfig):
-    from . import LayerKind, Serving
+    from . import Serving, keys_and_values
 
     kv, d = cfg.num_key_value_heads, cfg.head_dim
-    kinds = (LayerKind("full", cfg.layers_of(FULL), kv, d, None, cfg.dtype),
-             LayerKind("window", cfg.layers_of(SLIDING), kv, d,
-                       cfg.sliding_window, cfg.dtype))
+    kinds = (keys_and_values("full", cfg.layers_of(FULL), kv, d, None,
+                             cfg.dtype),
+             keys_and_values("window", cfg.layers_of(SLIDING), kv, d,
+                             cfg.sliding_window, cfg.dtype))
     if not kinds[0].layers or not kinds[1].layers:
         raise ValueError("the served Laguna needs layers of both kinds")
     return Serving(init=init, step=forward_step,

@@ -7,7 +7,9 @@ capacity: the bound below is the worst case, so nothing is ever
 dropped):
 
   route     ``x @ wg`` in float32, softmax over every expert, the k
-            largest, renormalised to sum to ``scale``.
+            largest, renormalised to sum to ``scale``. ``route_sigmoid``
+            is the other scoring served: a sigmoid an expert, chosen by
+            score plus a learned bias, weighed by the score without it.
   plan      a counting sort of the T*k assignments by expert
             (``dispatch_plan``): each expert's rows are padded to whole
             tiles of ``TILE_ROWS``, so a tile belongs to ONE expert. The
@@ -35,7 +37,8 @@ the result its own experts give for every token of its ep group
 (tokens all-gathered over ``ep``, partial results summed and scattered
 back): the layer is told which experts it holds, and what the others
 add is the other shards'. models/laguna.py serves this layer on one
-chip with every expert local.
+chip with every expert local; models/kimi_k2.py serves one chip's
+share of it (``routed_experts(..., first=)`` with 12 of 384 experts).
 """
 
 from __future__ import annotations
@@ -95,6 +98,22 @@ def route(x, wg, k: int, scale: float = 1.0):
     top, experts = jax.lax.top_k(probs, k)
     weights = scale * top / top.sum(-1, keepdims=True)
     return probs, experts.astype(jnp.int32), weights
+
+
+def route_sigmoid(x, wg, bias, k: int, scale: float = 1.0):
+    """The router that scores with a sigmoid and chooses with a learned
+    bias (DeepSeek-V3's ``noaux_tc`` with one group): x [T, d] ->
+    (scores [T, E] float32, experts [T, k] int32, weights [T, k]
+    float32). ``s = sigmoid(x @ wg)`` in float32; the k largest of
+    ``s + bias`` are chosen; their weights are their ``s`` WITHOUT the
+    bias, renormalised to sum to ``scale``."""
+    logits = jnp.dot(x.astype(jnp.float32), wg.astype(jnp.float32),
+                     precision=HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    top = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = scale * top / top.sum(-1, keepdims=True)
+    return scores, experts.astype(jnp.int32), weights
 
 
 def plan_rows(n_assignments: int, n_experts: int) -> int:

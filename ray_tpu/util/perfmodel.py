@@ -107,10 +107,14 @@ def _shape(cfg) -> dict:
     """The model's cost description, from the serving seam
     (models/__init__.py: ``serving(cfg).cost``, cached there per
     configuration): matmul weights a token passes, the attention
-    coefficient a context position (and, for layers with a window,
-    ``attn_windows``: (coefficient, window) pairs), parameters in all,
-    parameters a step of n rows streams, bytes a parameter, KV elements
-    a token."""
+    coefficient a context position of a decode row and of a chunk's
+    row (``attn_per_ctx``, ``chunk_attn_per_ctx``: a model with latent
+    attention serves the two in different forms) and what a chunk pays
+    once a context token (``chunk_ctx_ops``: taking a latent row up to
+    keys and values; 0 where the cache holds keys), for layers with a
+    window ``attn_windows`` ((coefficient, window) pairs), parameters in
+    all, parameters a step of n rows streams, bytes a parameter, cache
+    elements a token."""
     from ..models import serving
 
     return serving(cfg).cost
@@ -212,7 +216,8 @@ def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
     ctx = int(ctx_tokens)
     seen = ctx * T + T * (T + 1) / 2.0
     flops = (2.0 * (s["matmul_weights"] - s["head_weights"]) * T
-             + 2.0 * s["head_weights"] + s["attn_per_ctx"] * seen
+             + 2.0 * s["head_weights"] + s["chunk_attn_per_ctx"] * seen
+             + s["chunk_ctx_ops"] * ctx
              + sum(coef * min(seen, T * w) for coef, w in s["attn_windows"]))
     kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
     if param_bytes is None:

@@ -49,8 +49,11 @@ def test_write_prefill_roundtrip_with_ragged_tail():
     v = rng.normal(size=k.shape).astype(np.float32)
     kv.write_prefill(jnp.asarray(k), jnp.asarray(v), grant)
     k_back, v_back = kv.gather_tokens(grant, T)
-    np.testing.assert_allclose(np.asarray(k_back), k, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(v_back), v, atol=1e-6)
+    # Rows come back as the pool holds them: a token's heads side by side.
+    np.testing.assert_allclose(np.asarray(k_back).reshape(k.shape), k,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(v_back).reshape(v.shape), v,
+                               atol=1e-6)
     # The scratch block stayed zero.
     assert float(jnp.abs(kv.k[:, 0]).max()) == 0.0
 
@@ -66,8 +69,10 @@ def test_writes_to_disjoint_grants_do_not_interfere():
     kv.write_prefill(k2, v2, g2)
     k1b, _ = kv.gather_tokens(g1, 8)
     k2b, _ = kv.gather_tokens(g2, 8)
-    np.testing.assert_allclose(np.asarray(k1b), np.asarray(k1), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(k2b), np.asarray(k2), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(k1b).reshape(k1.shape),
+                               np.asarray(k1), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(k2b).reshape(k2.shape),
+                               np.asarray(k2), atol=1e-6)
 
 
 def test_double_free_raises_and_pool_stays_usable():

@@ -250,10 +250,10 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
 LAYER_POOL = HKV * CELL_NB * BS * HD      # elements of one layer's K
 
 
-def _results(text, *opcodes):
+def _results(text, *opcodes, at_least=LAYER_POOL):
     """(opcode, result shape) of every instruction of ``text``, fused
     bodies included, that has one of ``opcodes`` and a result of at
-    least one layer's pool."""
+    least ``at_least`` elements (one layer's pool)."""
     import math
     import re
 
@@ -263,7 +263,7 @@ def _results(text, *opcodes):
             text, re.M):
         shape, dims, op = m.groups()
         if op in opcodes and math.prod(map(int, dims.split(","))) \
-                >= LAYER_POOL:
+                >= at_least:
             found.append((op, shape))
     return found
 
@@ -388,12 +388,8 @@ def _mosaic_calls(text):
 def _pool_sized(text, *opcodes):
     """``_results`` at the size of the window kind's pool of ONE layer
     (the smaller of the two kinds')."""
-    global LAYER_POOL
-    keep, LAYER_POOL = LAYER_POOL, LAGUNA_WINDOW_BLOCKS * BS * 8 * 128
-    try:
-        return _results(text, *opcodes)
-    finally:
-        LAYER_POOL = keep
+    return _results(text, *opcodes,
+                    at_least=LAGUNA_WINDOW_BLOCKS * BS * 8 * 128)
 
 
 def test_laguna_decode_program_reads_the_pools_as_stored(laguna_programs):
@@ -525,3 +521,127 @@ def test_stored_paged_kernel_compiles_with_grouped_queries(one_chip):
             S((16, q_len, 8, group, 128), jnp.bfloat16), pool, pool,
             S((16, nb), jnp.int32), lanes, lanes, lanes)
         assert "attn_test" in c.as_text()
+
+
+# -- Kimi-K2.5 at the cell's shapes (benchmark/configs/kimi-k25-serve) --------
+
+KIMI_BLOCKS, KIMI_MAX_SEQ, KIMI_ROW = 24576, 17408, 640
+
+
+@pytest.fixture(scope="module")
+def kimi_programs(one_chip):
+    """The engine's own decode and chunk programs for the served share
+    of Kimi-K2.5 (5 layers, 12 of 384 experts, a 20,480-token slice of
+    the vocabulary), compiled for the described v5e at the cell's
+    shapes: 64 lanes over ONE latent pool of 24,576 blocks of 16 rows
+    of 640, tables for 17,408 tokens. ~25 s for the three."""
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.models import kimi_k2
+
+    cfg = kimi_k2.KimiK2Config(num_hidden_layers=5, vocab_size=20480,
+                               experts_held=12, max_seq=KIMI_MAX_SEQ)
+    assert cfg.row_width == KIMI_ROW
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: kimi_k2.init(jax.random.key(0), cfg)))
+    B, i32 = CELL_B, jnp.int32
+    pool = S((5, KIMI_BLOCKS, BS, KIMI_ROW), jnp.bfloat16)
+    max_nb = KIMI_MAX_SEQ // BS
+    decode, chunk = _jit_programs(cfg)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "param_leaves": len(jax.tree_util.tree_leaves(params)),
+            "decode": decode.lower(
+                params, S((B, 1), i32), S((B, 1), i32), pool,
+                S((B, max_nb), i32), S((B,), i32), S((B,), i32),
+                S((B, 1), i32), S((B, 1), i32)).compile(),
+            "chunk": chunk.lower(
+                params, S((1, 512), i32), pool,
+                S((max_nb + 512 // BS + 2,), i32)).compile(),
+            "cold_chunk": chunk.lower(
+                params, S((1, 512), i32), pool,
+                S((512 // BS + 2,), i32)).compile(),
+        }
+
+
+def _kimi_pool_sized(text, *opcodes):
+    """``_results`` at the size of ONE layer of the latent pool."""
+    return _results(text, *opcodes, at_least=KIMI_BLOCKS * BS * KIMI_ROW)
+
+
+def test_kimi_decode_program_attends_the_latent_pool_as_stored(
+        kimi_programs):
+    """The decode program at the cell's shapes: the absorbed kernel
+    once a layer under its name (``attn_latent`` x 5) and the grouped
+    product twice a routed layer (``moe_experts_decode`` x 8), which is
+    how the benchmark's readers find them; the ONE pool is donated and
+    aliased to its output, written by one in-place scatter a layer, and
+    nothing pool-sized is copied, transposed or padded (a row of 640 is
+    five whole lane tiles: with rows of 576 the runtime keeps the pool
+    at rest in a layout of its own and this program copies all 2.3 GB
+    of it in and out, 2.56 GB of temporaries; PERF.md section 6, PR
+    34); the ids come back with the three counter rows; temporaries
+    are tens of MB."""
+    c = kimi_programs["decode"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert _mosaic_calls(text) == {"attn_latent": 5, "moe_experts_decode": 8}
+    assert _kimi_pool_sized(text, "copy", "transpose", "copy-start",
+                            "dynamic-update-slice", "concatenate",
+                            "pad") == []
+    pool = f"bf16[5,{KIMI_BLOCKS},{BS},{KIMI_ROW}]"
+    assert _kimi_pool_sized(text, "scatter") == [("scatter", pool)] * 5
+    # params' leaves, tokens, positions, then the pool: output 2 behind
+    # the logits and the ids.
+    assert _aliased(text) == {kimi_programs["param_leaves"] + 2: 2}
+    entry = text[text.index("\nENTRY "):]
+    root = next(line for line in entry.splitlines()
+                if line.lstrip().startswith("ROOT "))
+    assert f"s32[{CELL_B + 3},1]" in root and f"bf16[{CELL_B},1,20480]" \
+        in root
+    assert c.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("which", ["chunk", "cold_chunk"])
+def test_kimi_chunk_program_writes_its_span_in_place(kimi_programs, which):
+    """A 512-token chunk behind a 17,408-token table (and one from a
+    prompt's start, with no table), ONE program: the context's latent
+    rows go up to keys and values a block of 1,024 slots at a time, so
+    the temporaries stay under half a GB (all 17,408 slots at once are
+    0.57 GB of keys and values alone); the pool is donated, aliased and
+    written by ONE in-place scatter after every layer has read it; the
+    routed experts are the ``moe_experts_chunk`` kernel; the head runs
+    on the one row that comes back."""
+    c = kimi_programs[which]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_prefill_chunk")
+    assert _mosaic_calls(text) == {"moe_experts_chunk": 8}
+    assert _kimi_pool_sized(text, "copy", "transpose", "copy-start",
+                            "dynamic-slice", "dynamic-update-slice",
+                            "concatenate", "pad") == []
+    assert _kimi_pool_sized(text, "scatter") == [
+        ("scatter", f"bf16[5,{KIMI_BLOCKS},{BS},{KIMI_ROW}]")]
+    assert _aliased(text) == {kimi_programs["param_leaves"] + 1: 2}
+    assert "[512,20480]" not in text and "[1,512,20480]" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 500e6
+
+
+@pytest.mark.parametrize("q_len", [1, 4], ids=["decode", "verify_q4"])
+def test_latent_kernel_compiles_at_the_published_widths(one_chip, q_len):
+    """``paged_attention_latent`` alone: 64 heads against rows of 640
+    (512 latent + 64 rotary + padding), values the first 512 columns,
+    one row a lane and a speculative 4."""
+    from ray_tpu.ops.pallas.paged_decode import paged_attention_latent
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    lanes = S((16,), jnp.int32)
+    c = _compile(
+        lambda q, pool, tables, lens, qlens: paged_attention_latent(
+            q, pool, 1, tables, lens, qlens, rank=512, scale=0.1447,
+            interpret=False),
+        S((16, q_len, 64, KIMI_ROW), jnp.bfloat16),
+        S((2, 2048, BS, KIMI_ROW), jnp.bfloat16),
+        S((16, KIMI_MAX_SEQ // BS), jnp.int32), lanes, lanes)
+    assert "attn_latent" in c.as_text()
