@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import random
 import threading
+import uuid
+from collections import deque
 from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Optional
 
@@ -154,42 +156,56 @@ class DeploymentResponse:
         finally:
             self._settle()
 
+    def _open_stream(self, result, chunk_batch: int):
+        """The handle's end of the stream that ``result`` names, or None
+        where the deployment did not return a generator."""
+        from .replica import STREAM_MARKER
+
+        if not (isinstance(result, dict) and STREAM_MARKER in result):
+            return None
+        return self._router.open_stream(
+            self._replica_key, result[STREAM_MARKER], chunk_batch)
+
     def iter_stream(self, timeout: Optional[float] = None,
                     chunk_batch: int = 16):
         """Iterate a STREAMING response (deployment returned a generator):
-        yields chunks pulled from the serving replica. A non-streaming
-        result is yielded as the single item (reference:
+        yields each chunk as the serving replica's generator yields it
+        (the router's poller for that replica brings them, one long-poll
+        for all of this process's streams there). ``timeout`` bounds the
+        wait for each chunk; ``chunk_batch`` is how many chunks the
+        generator may run ahead of this iterator. A non-streaming result
+        is yielded as the single item (reference:
         handle.options(stream=True) -> DeploymentResponseGenerator)."""
-        from .replica import STREAM_MARKER
-
         result = self.result(timeout=timeout)
-        if not (isinstance(result, dict) and STREAM_MARKER in result):
+        stream = self._open_stream(result, chunk_batch)
+        if stream is None:
             yield result
             return
-        import ray_tpu
-
-        sid = result[STREAM_MARKER]
-        actor = self._router.actor_for_key(self._replica_key)
-        if actor is None:
-            raise RuntimeError("streaming replica is gone")
         try:
-            # Ramp the pull batch from 1: time-to-first-chunk tracks the
-            # generator's first item, not a full batch of them.
-            batch = 1
-            while True:
-                chunks, done = ray_tpu.get(
-                    actor.stream_next.remote(sid, batch),
-                    timeout=timeout)
-                batch = min(chunk_batch, batch * 2)
-                yield from chunks
-                if done:
-                    return
+            while (chunk := stream.take(timeout)) is not _STREAM_END:
+                yield chunk
         finally:
             # Early consumer exit: free the parked generator.
-            try:
-                actor.stream_cancel.remote(sid)
-            except Exception:  # lint: allow-swallow(cancel on a gone replica)
-                pass
+            stream.close()
+
+    async def aiter_stream(self, timeout: Optional[float] = None,
+                           chunk_batch: int = 16):
+        """``iter_stream`` for an event loop: waiting for a chunk holds
+        no thread."""
+        import asyncio
+
+        result = await asyncio.get_running_loop().run_in_executor(
+            None, lambda: self.result(timeout=timeout))
+        stream = self._open_stream(result, chunk_batch)
+        if stream is None:
+            yield result
+            return
+        try:
+            while (chunk := await stream.atake(timeout)) \
+                    is not _STREAM_END:
+                yield chunk
+        finally:
+            stream.close()
 
     def _to_object_ref(self):
         self._settle()  # ref handed off; router stops tracking it
@@ -208,6 +224,131 @@ class DeploymentResponse:
             self._settle()
         except Exception:  # lint: allow-swallow(__del__ during interpreter teardown)
             pass
+
+
+_STREAM_END = object()
+
+
+class _StreamEnd:
+    """The handle's end of one stream: the router's poller deals what
+    the replica sent into it, one consumer (a thread in ``take`` or a
+    task in ``atake``) takes it out, and what the consumer has taken is
+    what lets the replica's generator run on."""
+
+    def __init__(self, router: "Router", key, actor, sid: int,
+                 run_ahead: int):
+        self._router = router
+        self._key = key
+        self._actor = actor
+        self.sid = sid
+        self._run_ahead = max(1, run_ahead)
+        self._cond = threading.Condition()
+        self._chunks: deque = deque()  # dealt, not yet taken
+        self._ended = False  # the replica has sent this stream's end
+        self._error: Optional[BaseException] = None  # ... and it was this
+        self._waker: Optional[Callable] = None
+        self._consumed = 0
+        self._granted = 0
+
+    def deal(self, chunks, done: bool, error):
+        """Poller side: append one reply's share of this stream."""
+        with self._cond:
+            self._chunks.extend(chunks)
+            if done or error is not None:
+                self._ended, self._error = True, error
+            waker, self._waker = self._waker, None
+            self._cond.notify_all()
+        if waker is not None:
+            waker()
+
+    def grant(self, explicit: bool = False) -> Optional[int]:
+        """How far the generator may yield, if that is further than the
+        replica has been told; None otherwise. The poller asks before
+        each poll and carries the answer; a consumer asks with
+        ``explicit`` and gets one only when the replica's word is half a
+        run-ahead behind, which is when no poll has gone out for a while
+        because the generator waits for this reader."""
+        with self._cond:
+            upto = self._consumed + self._run_ahead
+            behind = upto - self._granted
+            if self._ended or behind < (
+                    max(1, self._run_ahead // 2) if explicit else 1):
+                return None
+            self._granted = upto
+            return upto
+
+    def _has_next(self) -> bool:
+        return bool(self._chunks) or self._ended
+
+    def _pop(self):
+        """The next chunk, counted as consumed; behind the last one the
+        stream's error, raised, or _STREAM_END (``_cond`` is held and
+        ``_has_next()`` true)."""
+        if self._chunks:
+            self._consumed += 1
+            return self._chunks.popleft()
+        if self._error is not None:
+            raise self._error
+        return _STREAM_END
+
+    def _after_take(self, item):
+        if item is not _STREAM_END \
+                and (upto := self.grant(explicit=True)) is not None:
+            try:
+                self._actor.stream_grant.remote(self.sid, upto)
+            except Exception:  # lint: allow-swallow(grant to a gone replica; the poller reports it)
+                pass
+        return item
+
+    def take(self, timeout: Optional[float]):
+        """Next chunk, or _STREAM_END; raises the stream's error, or
+        GetTimeoutError after ``timeout`` seconds without one."""
+        with self._cond:
+            if not self._cond.wait_for(self._has_next, timeout):
+                from ray_tpu._private.exceptions import GetTimeoutError
+
+                raise GetTimeoutError(
+                    f"no stream chunk within {timeout}s")
+            item = self._pop()
+        return self._after_take(item)
+
+    async def atake(self, timeout: Optional[float]):
+        """``take`` for an event loop: the poller wakes the waiting task
+        through ``call_soon_threadsafe``."""
+        import asyncio
+
+        loop = asyncio.get_running_loop()
+        while True:
+            with self._cond:
+                if self._has_next():
+                    item = self._pop()
+                    break
+                fut = loop.create_future()
+                self._waker = lambda: loop.call_soon_threadsafe(
+                    _resolve, fut)
+            try:
+                await asyncio.wait_for(fut, timeout)
+            finally:
+                with self._cond:
+                    self._waker = None
+        return self._after_take(item)
+
+    def close(self):
+        """Consumer side, always: leave the poller's books, and free the
+        replica's generator unless its end has already arrived."""
+        self._router.close_stream(self._key, self.sid)
+        with self._cond:
+            ended = self._ended
+        if not ended:
+            try:
+                self._actor.stream_cancel.remote(self.sid)
+            except Exception:  # lint: allow-swallow(cancel on a gone replica)
+                pass
+
+
+def _resolve(fut):
+    if not fut.done():
+        fut.set_result(None)
 
 
 def _replica_key(replica):
@@ -250,6 +391,11 @@ class Router:
         # lists one (its health loop lags the observation) must not
         # resurrect it. key -> monotonic expiry.
         self._tombstones: dict = {}
+        # Streaming: the replica keeps what its generators yield for
+        # this router under ``caller_id``, and one poller thread a
+        # replica with live streams brings it here.
+        self.caller_id = uuid.uuid4().hex
+        self._stream_ends: dict = {}  # replica key -> {sid: _StreamEnd}
 
     def maybe_refresh(self, force: bool = False):
         """Pull the replica set from the controller if stale (or forced).
@@ -341,6 +487,66 @@ class Router:
                 if k == key:
                     return r
         return None
+
+    def open_stream(self, key, sid: int, run_ahead: int) -> _StreamEnd:
+        """Register stream ``sid`` of the replica behind ``key`` and
+        return this side's end of it. The first live stream of a replica
+        starts its poller."""
+        actor = self.actor_for_key(key)
+        if actor is None:
+            raise RuntimeError("streaming replica is gone")
+        end = _StreamEnd(self, key, actor, sid, run_ahead)
+        first_grant = end.grant()
+        with self._lock:
+            ends = self._stream_ends.get(key)
+            if ends is None:
+                ends = self._stream_ends[key] = {}
+                threading.Thread(
+                    target=self._poll_streams, args=(key, actor),
+                    daemon=True, name="serve-stream-poll").start()
+            ends[sid] = end
+        # On the books first, then the word to the replica: a poll in
+        # flight may bring the stream's chunks the moment it lands.
+        actor.stream_grant.remote(sid, first_grant, self.caller_id)
+        return end
+
+    def close_stream(self, key, sid: int):
+        with self._lock:
+            self._stream_ends.get(key, {}).pop(sid, None)
+
+    def _poll_streams(self, key, actor):
+        """The poller of one replica: one long-poll at a time carries
+        every stream's ready chunks, dealt to each stream's end here. It
+        ends when the replica has no live stream of this router."""
+        import ray_tpu
+
+        while True:
+            with self._lock:
+                ends = self._stream_ends.get(key)
+                if not ends:
+                    self._stream_ends.pop(key, None)
+                    return
+                live = list(ends.values())
+            grants = {e.sid: upto for e in live
+                      if (upto := e.grant()) is not None}
+            try:
+                reply = ray_tpu.get(
+                    actor.stream_poll.remote(self.caller_id, grants))
+            except Exception as e:  # noqa: BLE001 - every waiting consumer raises it
+                with self._lock:
+                    ends = self._stream_ends.pop(key, {})
+                for end in ends.values():
+                    end.deal((), True, e)
+                return
+            with self._lock:
+                dealt = [(ends.get(sid), share)
+                         for sid, share in reply.items()]
+                for sid, (_, done, error) in reply.items():
+                    if done or error is not None:
+                        ends.pop(sid, None)
+            for end, share in dealt:
+                if end is not None:
+                    end.deal(*share)
 
     def remove_replica(self, key):
         """Drop a replica observed dead so the retry (and subsequent

@@ -15,7 +15,6 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
-from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 
@@ -25,11 +24,6 @@ class HTTPProxy:
         self.controller = controller
         self.routes: dict[str, str] = {}  # prefix -> app name
         self.request_timeout_s = request_timeout_s
-        # Streaming chunk pulls block a thread each; a dedicated bounded
-        # pool keeps a slow deployment generator from exhausting the
-        # loop's shared default executor (ADVICE r3).
-        self._stream_pool = ThreadPoolExecutor(
-            max_workers=32, thread_name_prefix="serve-stream")
         self._loop = asyncio.new_event_loop()
         self._runner = None
         started = threading.Event()
@@ -215,54 +209,40 @@ class HTTPProxy:
         sr = web.StreamResponse(headers=headers)
         sr.enable_chunked_encoding()
         await sr.prepare(request)
-        it = resp.iter_stream(timeout=self.request_timeout_s)
+        # A waiting stream holds no thread: the handle's poller wakes
+        # this task when the replica has sent the stream's next chunk.
+        it = resp.aiter_stream(timeout=self.request_timeout_s)
         timed_out = False
         first_chunk = True
-        cf = None
         try:
-            while True:
+            try:
                 # Per-chunk deadline: a generator that stalls mid-stream
-                # must not tie up a pool thread forever past the request
-                # timeout (ADVICE r3). The blocked thread itself cannot be
-                # cancelled, but the bounded dedicated pool contains the
-                # damage and the client sees an ABORTED (not cleanly
-                # completed) stream.
-                cf = self._stream_pool.submit(lambda: next(it, _END))
-                try:
-                    chunk = await asyncio.wait_for(
-                        asyncio.wrap_future(cf), self.request_timeout_s)
-                except (TimeoutError, asyncio.TimeoutError):
-                    timed_out = True
-                    break
-                if chunk is _END:
-                    break
-                if first_chunk and root is not None:
-                    # TTFT on the root span: arrival -> first streamed
-                    # chunk reaches the proxy.
-                    root.add_event(
-                        "ttft",
-                        ms=(_time.time() - root.start) * 1e3)
-                    first_chunk = False
-                if isinstance(chunk, (bytes, bytearray)):
-                    await sr.write(bytes(chunk))
-                else:
-                    await sr.write((json.dumps(chunk) + "\n").encode())
+                # past the request timeout ends the response, and the
+                # client sees an ABORTED (not cleanly completed) stream.
+                async for chunk in it:
+                    if first_chunk and root is not None:
+                        # TTFT on the root span: arrival -> first
+                        # streamed chunk reaches the proxy.
+                        root.add_event(
+                            "ttft",
+                            ms=(_time.time() - root.start) * 1e3)
+                        first_chunk = False
+                    if isinstance(chunk, (bytes, bytearray)):
+                        await sr.write(bytes(chunk))
+                    else:
+                        await sr.write((json.dumps(chunk) + "\n").encode())
+            except (TimeoutError, asyncio.TimeoutError):
+                timed_out = True
             if root is not None and not first_chunk:
                 root.add_event(
                     "last_token",
                     ms=(_time.time() - root.start) * 1e3,
                     aborted=timed_out)
         finally:
-            # Free the replica-side generator. If a pull is still
-            # executing in the pool thread (timeout above, or the client
-            # disconnected cancelling this handler mid-await),
-            # generator.close() from here would raise "generator already
-            # executing" — defer it to the pool thread via the future's
-            # completion instead.
-            if cf is not None and not cf.done():
-                cf.add_done_callback(lambda f: _safe_close(it))
-            else:
-                _safe_close(it)
+            # Free the replica-side generator (a no-op once the stream
+            # has ended): also when the client disconnected, cancelling
+            # this handler mid-await.
+            await it.aclose()
         if timed_out:
             # In-band error frame, then abort the connection WITHOUT the
             # terminating chunk: a truncated stream must not look like a
@@ -317,14 +297,3 @@ class HTTPProxy:
             self._thread.join(timeout=5)
         except Exception:  # lint: allow-swallow(best-effort shutdown)
             pass
-        self._stream_pool.shutdown(wait=False)
-
-
-_END = object()
-
-
-def _safe_close(it):
-    try:
-        it.close()
-    except Exception:  # noqa: BLE001 - best-effort release
-        pass

@@ -115,7 +115,7 @@ class _LLMServer:
         # batch at the next step even though the generator body below
         # only runs when the stream is first pulled. The replica span's
         # trace context is captured HERE (this thread) because gen()
-        # executes later on stream_next threads with no context set.
+        # executes later on the stream's feeder thread with no context set.
         from ray_tpu.util import tracing
 
         trace_ctx = tracing.current_context.get()

@@ -13,21 +13,32 @@ from __future__ import annotations
 import threading
 import time
 import types
+import weakref
 from typing import Any, Optional
 
 from . import slo
 from .multiplex import _set_request_model_id
 
 # A request whose user code returned a generator answers with this marker;
-# the caller pulls chunks from the SAME replica via stream_next
+# the caller reads its chunks from the SAME replica via stream_poll
 # (reference: streaming responses through the handle,
 # python/ray/serve/handle.py DeploymentResponseGenerator).
 STREAM_MARKER = "__rtpu_stream__"
 
+# How far a parked generator runs ahead of its reader: a feeder stops
+# while its stream has yielded this many chunks past what the reader has
+# said it consumed (iter_stream's ``chunk_batch``), so a caller that
+# stops reading still stops a generator that has side effects.
+STREAM_RUN_AHEAD = 16
+
+# A stream_poll with nothing to carry returns empty after this long, so
+# a caller whose last stream went away stops holding a call slot.
+STREAM_POLL_LIMIT_S = 1.0
+
 
 def _with_model_id(gen, model_id: str):
     """Run each next() of a parked generator under the request's
-    multiplex id (the body executes lazily on stream_next threads)."""
+    multiplex id (the body executes lazily on the stream's feeder)."""
     while True:
         _set_request_model_id(model_id)
         try:
@@ -40,6 +51,74 @@ def _with_model_id(gen, model_id: str):
         yield v
 
 
+class _Stream:
+    """One parked generator and the chunks it has yielded that no caller
+    has taken yet. Every field is read and written under the replica's
+    stream condition."""
+
+    __slots__ = ("sid", "gen", "caller", "ready", "yielded", "limit",
+                 "ended", "error", "cancelled")
+
+    def __init__(self, sid: int, gen):
+        self.sid = sid
+        self.gen = gen
+        # Whose polls carry its chunks: nobody's until its reader has
+        # said (stream_grant) that it is there to be dealt them.
+        self.caller: Optional[str] = None
+        self.ready: list = []  # (chunk, t_yield), in the order yielded
+        self.yielded = 0
+        self.limit = STREAM_RUN_AHEAD  # yield no chunk past this count
+        self.ended = False  # the generator returned, raised or was closed
+        self.error: Optional[BaseException] = None
+        self.cancelled = False
+
+
+def _feed(stream: _Stream, cond: threading.Condition, deployment: str):
+    """A stream's feeder thread: run the generator ahead of its caller,
+    as far as ``limit`` allows, and hand each chunk on as it is yielded.
+    Holds no reference to the replica, so a dropped replica is collected
+    (and its finalizer ends the feeders that wait here)."""
+    error = None
+    try:
+        while True:
+            with cond:
+                while stream.yielded >= stream.limit \
+                        and not stream.cancelled:
+                    cond.wait(STREAM_POLL_LIMIT_S)
+                if stream.cancelled:
+                    break
+            try:
+                chunk = next(stream.gen)
+            except StopIteration:
+                break
+            t_yield = time.perf_counter()
+            with cond:
+                stream.ready.append((chunk, t_yield))
+                stream.yielded += 1
+                cond.notify_all()
+    except BaseException as e:  # noqa: BLE001 - a thread has no caller to raise to: it travels to the stream's reader, behind its chunks
+        from ray_tpu._private.exceptions import TaskError
+
+        error = TaskError.from_exception(e, f"{deployment}.stream")
+    finally:
+        try:
+            stream.gen.close()
+        except Exception:  # noqa: BLE001 - a generator that refuses to close
+            pass
+        with cond:
+            stream.error = error
+            stream.ended = True
+            cond.notify_all()
+
+
+def _abandon(streams: dict, cond: threading.Condition):
+    """The replica is gone: end the feeders that wait for a reader."""
+    with cond:
+        for stream in streams.values():
+            stream.cancelled = True
+        cond.notify_all()
+
+
 class Replica:
     def __init__(self, cls_or_fn, init_args, init_kwargs,
                  user_config: Optional[dict] = None,
@@ -48,8 +127,13 @@ class Replica:
         self._ongoing = 0
         self._total = 0
         self._window: list[float] = []  # request-arrival timestamps
-        self._streams: dict[int, Any] = {}
+        # sid -> _Stream, until a caller has taken its last chunk. The
+        # condition guards the dict and every stream in it; feeders wait
+        # on it for room, polls for something to carry.
+        self._streams: dict[int, _Stream] = {}
+        self._stream_cond = threading.Condition()
         self._stream_counter = 0
+        weakref.finalize(self, _abandon, self._streams, self._stream_cond)
         self._deployment = deployment_name or getattr(
             cls_or_fn, "__name__", "deployment")
         # One replica actor per worker process: the module-global lets
@@ -121,16 +205,21 @@ class Replica:
                 target = getattr(self.instance, method)
             result = target(*args, **kwargs)
             if isinstance(result, types.GeneratorType):
-                # Streaming response: park the generator; the caller
-                # drains it chunk-at-a-time from THIS replica. The body
-                # runs lazily inside stream_next, so the request's
-                # multiplex id must travel with it.
+                # Streaming response: park the generator; a feeder
+                # thread of its own (not one of the actor's call slots)
+                # runs it, and the caller reads what it yields from THIS
+                # replica. The body runs on that thread, so the
+                # request's multiplex id must travel with it.
                 if multiplexed_model_id:
                     result = _with_model_id(result, multiplexed_model_id)
-                with self._lock:
+                with self._stream_cond:
                     self._stream_counter += 1
                     sid = self._stream_counter
-                    self._streams[sid] = result
+                    stream = self._streams[sid] = _Stream(sid, result)
+                threading.Thread(
+                    target=_feed, daemon=True, name=f"serve-feed-{sid}",
+                    args=(stream, self._stream_cond, self._deployment),
+                ).start()
                 return {STREAM_MARKER: sid}
             return result
         except BaseException as e:
@@ -151,37 +240,108 @@ class Replica:
             slo.set_queue_depth(self._ongoing + len(self._streams),
                                 self._deployment)
 
-    def stream_next(self, sid: int, max_chunks: int = 16):
-        """(chunks, done) — up to max_chunks items of stream ``sid``."""
-        gen = self._streams.get(sid)
-        if gen is None:
-            return [], True
-        t_pull = time.perf_counter()
-        out, yielded = [], []
-        done = False
-        try:
-            for _ in range(max_chunks):
-                out.append(next(gen))
-                yielded.append(time.perf_counter())
-        except StopIteration:
-            self._streams.pop(sid, None)
-            done = True
-        except BaseException:
-            self._streams.pop(sid, None)
-            raise
-        # How long each chunk waited here for the pull to fill: a pull
-        # blocks until it holds max_chunks, so the first chunk of a
-        # 16-chunk pull of a token stream sits through 15 decode steps.
-        t_ret = time.perf_counter()
-        slo.record_phase("stream_pull", t_ret - t_pull, self._deployment)
-        slo.record_phases("stream_hold", [t_ret - t for t in yielded],
+    def _take(self, stream: _Stream, n: int):
+        """(chunks, yield times, done, error) — the stream's first ``n``
+        ready chunks, and its end once they are all taken. The stream
+        condition is held by the caller."""
+        taken, stream.ready = stream.ready[:n], stream.ready[n:]
+        done = stream.ended and not stream.ready
+        if done:
+            self._streams.pop(stream.sid, None)
+        return ([c for c, _ in taken], [t for _, t in taken], done,
+                stream.error if done else None)
+
+    def _record_reply(self, t_call: float, yielded: list):
+        """One reply left: its duration, and how long each chunk it
+        carries sat here since the generator yielded it."""
+        t_reply = time.perf_counter()
+        slo.record_phase("stream_pull", t_reply - t_call, self._deployment)
+        slo.record_phases("stream_hold", [t_reply - t for t in yielded],
                           self._deployment)
-        return out, done
+
+    def stream_poll(self, caller_id: str, grants: Optional[dict] = None):
+        """{sid: (chunks, done, error)} — everything the streams of
+        ``caller_id`` have ready, as soon as any of them has anything
+        (empty after STREAM_POLL_LIMIT_S with nothing). ``grants`` is
+        {sid: count}: how far each stream may now yield (what its reader
+        has consumed plus its run-ahead bound)."""
+        t_call = time.perf_counter()
+        deadline = t_call + STREAM_POLL_LIMIT_S
+        reply, yielded = {}, []
+        with self._stream_cond:
+            for sid, upto in (grants or {}).items():
+                self._grant(sid, upto)
+            while True:
+                ready = [s for s in self._streams.values()
+                         if s.caller == caller_id
+                         and (s.ready or s.ended)]
+                wait = deadline - time.perf_counter()
+                if ready or wait <= 0:
+                    break
+                self._stream_cond.wait(wait)
+            for stream in ready:
+                chunks, times, done, error = self._take(
+                    stream, len(stream.ready))
+                reply[stream.sid] = (chunks, done, error)
+                yielded += times
+        self._record_reply(t_call, yielded)
+        return reply
+
+    def stream_next(self, sid: int, max_chunks: int = 16):
+        """(chunks, done) — what stream ``sid`` has ready, up to
+        max_chunks items: blocks for the first chunk only. The one-stream
+        reading of what stream_poll carries; what it returns counts as
+        consumed."""
+        t_call = time.perf_counter()
+        with self._stream_cond:
+            stream = self._streams.get(sid)
+            if stream is None:
+                return [], True
+            while not (stream.ready or stream.ended):
+                self._stream_cond.wait(STREAM_POLL_LIMIT_S)
+            chunks, times, done, error = self._take(stream, max_chunks)
+            if error is not None and chunks:
+                # An error waits behind the chunks yielded before it:
+                # the next call finds nothing ready, and raises.
+                self._streams[sid] = stream
+                done = False
+            self._grant(sid, stream.yielded - len(stream.ready)
+                        + STREAM_RUN_AHEAD)
+        self._record_reply(t_call, times)
+        if error is not None and not chunks:
+            raise error
+        return chunks, done
+
+    def _grant(self, sid: int, upto: int):
+        """Let stream ``sid`` yield up to ``upto`` chunks in all (the
+        stream condition is held by the caller)."""
+        stream = self._streams.get(sid)
+        if stream is not None and upto > stream.limit:
+            stream.limit = upto
+            self._stream_cond.notify_all()
+
+    def stream_grant(self, sid: int, upto: int,
+                     caller_id: Optional[str] = None):
+        """A reader's word, outside a poll: it has consumed enough for
+        stream ``sid`` to yield up to ``upto`` chunks, and, with
+        ``caller_id`` (a reader's first word), the polls of that caller
+        carry the stream's chunks from now on."""
+        with self._stream_cond:
+            stream = self._streams.get(sid)
+            if stream is not None and caller_id is not None:
+                stream.caller = caller_id
+                self._stream_cond.notify_all()
+            self._grant(sid, upto)
+        return True
 
     def stream_cancel(self, sid: int):
-        gen = self._streams.pop(sid, None)
-        if gen is not None:
-            gen.close()
+        """Free stream ``sid``: its feeder closes the generator, now if
+        it waits for a reader, else when the running next() returns."""
+        with self._stream_cond:
+            stream = self._streams.pop(sid, None)
+            if stream is not None:
+                stream.cancelled = True
+                self._stream_cond.notify_all()
         return True
 
     def stats(self) -> dict:

@@ -22,11 +22,12 @@ continuous-batching autoscaler consumes (ROADMAP item 1):
                           stamps): add_request -> first admission into
                           the engine's batch
   * ``stream_pull`` / ``stream_hold`` — streaming responses
-                          (Replica.stream_next): one pull's duration
-                          (its count is the number of pulls), and, once
-                          a chunk, how long the chunk sat in the replica
-                          between the generator yielding it and the pull
-                          that carries it returning
+                          (Replica.stream_poll, stream_next): one
+                          reply's duration from its call (its count is
+                          the number of replies), and, once a chunk, how
+                          long the chunk sat in the replica between the
+                          generator yielding it and the reply that
+                          carries it leaving
 
 Two sinks per observation, both cheap (a bucket increment under one
 lock):
@@ -149,7 +150,7 @@ def record_phase(phase: str, seconds: float,
 def record_phases(phase: str, seconds: List[float],
                   deployment: Optional[str] = None):
     """``record_phase`` for many untraced observations of one phase at
-    once (a stream pull's chunks): one pass under each lock, so a burst
+    once (a stream reply's chunks): one pass under each lock, so a burst
     does not hand the interpreter back and forth with the threads it
     shares a process with."""
     if not seconds:

@@ -94,3 +94,325 @@ def test_http_plain_json_still_works(rt):
     with urllib.request.urlopen(req, timeout=60) as r:
         body = r.read()
     assert json.loads(body.splitlines()[0]) == {"i": 0, "sq": 0}
+
+
+# ---------------------------------------------------------------------------
+# A chunk leaves the replica when it is yielded (PR 35): feeders, one
+# poll a handle, run-ahead bounded by what the reader has consumed.
+# ---------------------------------------------------------------------------
+import http.client
+import threading
+import time
+
+PERIOD = 0.05
+
+
+@serve.deployment
+class Ticker:
+    """Generators that yield on a clock: alone every PERIOD, or all
+    together on a shared tick (as an engine's step emits for every
+    lane)."""
+
+    def __init__(self):
+        self.tick = 0
+        self.cond = threading.Condition()
+        self.yielded = 0
+        self.closed = 0
+        threading.Thread(target=self._clock, daemon=True).start()
+
+    def _clock(self):
+        while True:
+            time.sleep(PERIOD)
+            with self.cond:
+                self.tick += 1
+                self.cond.notify_all()
+
+    def __call__(self, req):
+        n, tag = int(req["n"]), req.get("tag")
+        together, fail_at = req.get("together"), req.get("fail_at")
+
+        def gen():
+            try:
+                for i in range(n):
+                    if i == fail_at:
+                        raise ValueError(f"boom at {i}")
+                    if together:
+                        with self.cond:
+                            seen = self.tick
+                            self.cond.wait_for(lambda: self.tick > seen)
+                    elif req.get("period", PERIOD):
+                        time.sleep(req.get("period", PERIOD))
+                    self.yielded += 1
+                    yield {"tag": tag, "i": i, "t": time.time()}
+            finally:
+                self.closed += 1
+
+        return gen()
+
+    def state(self, req):
+        return {"yielded": self.yielded, "closed": self.closed,
+                "feeders": sum(t.name.startswith("serve-feed")
+                               for t in threading.enumerate())}
+
+
+def _ticker():
+    serve.run(Ticker.bind(), name="default")
+    return serve.get_app_handle("default")
+
+
+def _state(h):
+    return h.options(method_name="state").remote({}).result(timeout=30)
+
+
+def _replica_hist(name="Ticker"):
+    from ray_tpu.serve.deployment import _router_for
+
+    actor = _router_for(name).replica(0)
+    hist = ray_tpu.get(actor.stats.remote(), timeout=30)["phase_hist"]
+    return {p: hist.get(p, {"count": 0})["count"]
+            for p in ("stream_pull", "stream_hold")}
+
+
+def _wait(cond, what, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out waiting for {what}"
+        time.sleep(0.05)
+
+
+def _pollers():
+    return [t for t in threading.enumerate()
+            if t.name == "serve-stream-poll"]
+
+
+def test_chunks_arrive_as_yielded(rt):
+    """(a) A chunk every PERIOD reaches the consumer every PERIOD: no gap
+    of three periods, and the first sixteen are not handed over
+    together."""
+    h = _ticker()
+    arrived = []
+    for chunk in h.remote({"n": 40}).iter_stream(timeout=60):
+        arrived.append((time.time(), chunk))
+    assert [c["i"] for _, c in arrived] == list(range(40))
+    times = [t for t, _ in arrived]
+    yields = [c["t"] for _, c in arrived]
+    # A gap at the consumer, less what the generator itself was late by
+    # (a loaded box stalls its sleep too).
+    gaps = [(b - a) - max(0.0, (d - c) - PERIOD) for a, b, c, d in
+            zip(times, times[1:], yields, yields[1:])]
+    assert max(gaps) < 3 * PERIOD, sorted(gaps)[-5:]
+    assert times[15] - times[0] > 10 * PERIOD
+    assert max(t - y for t, y in zip(times, yields)) < 3 * PERIOD
+
+
+def test_64_http_streams_none_waits_for_a_slot(rt):
+    """(b) 64 streams at once through the proxy, twice the replica's 32
+    call slots: every chunk of every stream reaches its client within a
+    few periods of its yield, once, in order."""
+    _ticker()
+    proxy = serve.start(http_port=0)
+    n_streams, n_chunks = 64, 20
+    got = [None] * n_streams
+    start = threading.Barrier(n_streams)
+
+    def client(k):
+        conn = http.client.HTTPConnection("127.0.0.1", proxy.port,
+                                          timeout=60)
+        start.wait(30)
+        conn.request("POST", "/", body=json.dumps(
+            {"n": n_chunks, "tag": k}),
+            headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        frames = []
+        while line := resp.readline():
+            frames.append((time.time(), json.loads(line)))
+        conn.close()
+        got[k] = frames
+
+    threads = [threading.Thread(target=client, args=(k,))
+               for k in range(n_streams)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(90)
+    assert not any(t.is_alive() for t in threads)
+    late = []
+    for k, frames in enumerate(got):
+        assert [(f["tag"], f["i"]) for _, f in frames] == \
+            [(k, i) for i in range(n_chunks)]
+        late += [t - f["t"] for t, f in frames]
+    late.sort()
+    # With pulls of sixteen in two shifts of 32 a chunk waited up to
+    # sixteen periods and four as a rule; handed on as yielded, a
+    # fraction of one, and a few at the very worst on a loaded box.
+    assert late[len(late) // 2] < PERIOD
+    assert late[len(late) * 99 // 100] < 5 * PERIOD, late[-20:]
+    assert late[-1] < 10 * PERIOD, late[-5:]
+
+
+def _drain_all(h, requests, timeout=60):
+    """Read ``requests`` streams at once, one thread each; returns each
+    stream's chunks."""
+    out = [None] * len(requests)
+
+    def read(k):
+        out[k] = list(h.remote(requests[k]).iter_stream(timeout=timeout))
+
+    threads = [threading.Thread(target=read, args=(k,))
+               for k in range(len(requests))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout + 30)
+    assert not any(t.is_alive() for t in threads)
+    return out
+
+
+def test_one_reply_carries_every_streams_chunks(rt):
+    """(c) Streams that yield together share their replies; a stream
+    alone gets a reply a chunk."""
+    h = _ticker()
+    out = _drain_all(h, [{"n": 20, "tag": k, "together": True}
+                         for k in range(16)])
+    assert all(len(chunks) == 20 for chunks in out)
+    shared = _replica_hist()
+    assert shared["stream_hold"] == 16 * 20
+    assert shared["stream_pull"] * 4 <= shared["stream_hold"], shared
+    assert len(list(h.remote({"n": 20}).iter_stream(timeout=60))) == 20
+    alone = _replica_hist()
+    chunks = alone["stream_hold"] - shared["stream_hold"]
+    replies = alone["stream_pull"] - shared["stream_pull"]
+    # One reply a chunk and at most one more for the stream's end (on a
+    # loaded box a reply may be slow enough to carry two).
+    assert chunks == 20 and 15 <= replies <= 22, (shared, alone)
+
+
+def test_a_reader_that_stops_stops_the_generator(rt):
+    """(d) An unbounded generator runs sixteen chunks ahead of what its
+    reader has consumed, and no further."""
+    h = _ticker()
+    it = h.remote({"n": 10 ** 9, "period": 0}).iter_stream(timeout=60)
+    assert [next(it)["i"] for _ in range(3)] == [0, 1, 2]
+    _wait(lambda: _state(h)["yielded"] >= 19, "the run-ahead to fill")
+    time.sleep(0.5)
+    assert _state(h)["yielded"] == 3 + 16
+    # Reading on lets it run on, still sixteen ahead.
+    assert [next(it)["i"] for _ in range(40)] == list(range(3, 43))
+    _wait(lambda: _state(h)["yielded"] >= 43 + 16, "the next run-ahead")
+    time.sleep(0.3)
+    assert _state(h)["yielded"] == 43 + 16
+    it.close()
+    _wait(lambda: _state(h)["closed"] == 1, "the generator to close")
+
+
+def test_early_exit_frees_generator_feeder_and_poller(rt):
+    """(e) Closing the iterator, or stream_cancel itself, closes the
+    generator and ends its feeder; the poller ends with the handle's
+    last stream."""
+    from ray_tpu.serve.deployment import _router_for
+    from ray_tpu.serve.replica import STREAM_MARKER
+
+    h = _ticker()
+    first = h.remote({"n": 10 ** 9}).iter_stream(timeout=60)
+    second = h.remote({"n": 10 ** 9, "period": 0}).iter_stream(timeout=60)
+    assert next(first)["i"] == 0 and next(second)["i"] == 0
+    assert _state(h)["feeders"] == 2 and len(_pollers()) == 1
+    first.close()  # its feeder is inside next(): ends when that returns
+    _wait(lambda: _state(h)["closed"] == 1 and _state(h)["feeders"] == 1,
+          "the first stream to close")
+    assert len(_pollers()) == 1  # the second stream still needs it
+    second.close()  # its feeder waits for its reader: ends at once
+    _wait(lambda: _state(h)["closed"] == 2 and not _state(h)["feeders"],
+          "the second stream to close")
+    _wait(lambda: not _pollers(), "the poller to end")
+    # stream_cancel on a stream nobody iterates.
+    actor = _router_for("Ticker").replica(0)
+    sid = h.remote({"n": 10 ** 9}).result(timeout=30)[STREAM_MARKER]
+    _wait(lambda: _state(h)["feeders"] == 1, "the feeder to start")
+    ray_tpu.get(actor.stream_cancel.remote(sid), timeout=30)
+    _wait(lambda: _state(h)["closed"] == 3 and not _state(h)["feeders"],
+          "the cancelled stream to close")
+    assert ray_tpu.get(actor.stream_next.remote(sid), timeout=30) == \
+        ([], True)
+
+
+@pytest.mark.parametrize("k", [0, 5])
+def test_an_error_arrives_behind_the_chunks_before_it(rt, k):
+    """(f) A generator that raises after k chunks delivers those k, then
+    the error."""
+    h = _ticker()
+    it = h.remote({"n": 10, "fail_at": k, "period": 0}).iter_stream(
+        timeout=60)
+    assert [next(it)["i"] for _ in range(k)] == list(range(k))
+    with pytest.raises(ray_tpu.TaskError, match=f"boom at {k}") as ei:
+        next(it)
+    assert isinstance(ei.value.cause, ValueError)
+    _wait(lambda: _state(h)["closed"] == 1 and not _state(h)["feeders"]
+          and not _pollers(), "the failed stream to be freed")
+
+
+@pytest.mark.parametrize("lane", ["worker", "device"])
+def test_order_and_exactly_once_over_64_streams(rt, lane):
+    """(g) 64 streams that yield as fast as their readers allow, so
+    every stream runs into its run-ahead bound again and again: each
+    reader gets its own chunks, all of them, once, in order. On the
+    device lane feeders, polls, poller and readers are threads of this
+    process, switched every 10 us."""
+    import sys
+
+    if lane == "device":
+        serve.run(Ticker.options(ray_actor_options={
+            "scheduling_strategy": "device"}).bind(), name="default")
+        h = serve.get_app_handle("default")
+    else:
+        h = _ticker()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        out = _drain_all(h, [{"n": 60, "tag": k, "period": 0}
+                             for k in range(64)])
+    finally:
+        sys.setswitchinterval(interval)
+    for k, chunks in enumerate(out):
+        assert [(c["tag"], c["i"]) for c in chunks] == \
+            [(k, i) for i in range(60)]
+    hist = _replica_hist()
+    assert hist["stream_hold"] == 64 * 60
+    _wait(lambda: not _pollers() and not _state(h)["feeders"],
+          "pollers and feeders to end")
+
+
+def test_http_stream_that_stalls_is_aborted_in_band(rt):
+    """The per-chunk deadline: a stream that stalls past the request
+    timeout gets the in-band error frame and no terminating chunk."""
+    h = _ticker()
+    assert len(list(h.remote({"n": 1}).iter_stream(timeout=60))) == 1
+    proxy = serve.start(http_port=0, request_timeout_s=1.0)
+    conn = http.client.HTTPConnection("127.0.0.1", proxy.port, timeout=30)
+    conn.request("POST", "/", body=json.dumps({"n": 3, "period": 5.0}),
+                 headers={"Content-Type": "application/json"})
+    resp = conn.getresponse()
+    assert resp.status == 200
+    with pytest.raises(http.client.IncompleteRead) as ei:
+        resp.read()
+    assert json.loads(ei.value.partial.splitlines()[-1]) == \
+        {"error": "stream chunk timed out"}
+    conn.close()
+
+
+def test_grpc_drains_a_stream_through_the_same_path(rt):
+    """The unary gRPC ingress answers a streaming deployment with the
+    list of its chunks, read through iter_stream like any other."""
+    import grpc
+
+    h = _ticker()
+    proxy = serve.start_grpc()
+    ch = grpc.insecure_channel(f"127.0.0.1:{proxy.port}")
+    out = ch.unary_unary("/rtpu.serve/PredictJson")(
+        json.dumps({"n": 30, "tag": "g", "period": 0}).encode(),
+        metadata=(("app", "default"),), timeout=60)
+    ch.close()
+    assert [(c["tag"], c["i"]) for c in json.loads(out)] == \
+        [("g", i) for i in range(30)]
+    _wait(lambda: not _pollers() and not _state(h)["feeders"],
+          "the poller and the feeder to end")

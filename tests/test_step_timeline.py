@@ -258,9 +258,12 @@ def test_idle_wait_marks_the_step_after_the_loop_slept():
 # ---------------------------------------------------------------------------
 # Waiting, measured where it happens (serve/slo.py plane)
 # ---------------------------------------------------------------------------
-def test_phase_hist_counts_queue_ttft_and_stream_holds():
+@pytest.mark.parametrize("read", ["stream_next", "stream_poll"])
+def test_phase_hist_counts_queue_ttft_and_stream_holds(read):
     """One replica, no cluster: requests through handle_request, their
-    streams pulled through stream_next as the proxy pulls them."""
+    streams read one at a time through stream_next, or all at once
+    through stream_poll as a handle's poller reads them. Either way a
+    chunk's hold is counted once."""
     from ray_tpu.serve import slo
     from ray_tpu.serve.llm import _LLMServer
     from ray_tpu.serve.replica import STREAM_MARKER, Replica
@@ -274,16 +277,31 @@ def test_phase_hist_counts_queue_ttft_and_stream_holds():
         sids = [rep.handle_request(
             "__call__", ({"prompt": [1, 2, 3, i + 4], "max_tokens": n},),
             {})[STREAM_MARKER] for i, n in enumerate(answers)]
-        pulls = chunks = 0
-        for sid, n in zip(sids, answers):
-            frames, done = [], False
-            while not done:
+        frames = {sid: [] for sid in sids}
+        open_sids = set(sids)
+        replies = 0
+        if read == "stream_poll":
+            for sid in sids:
+                rep.stream_grant(sid, 16, "me")
+        while open_sids:
+            if read == "stream_next":
+                sid = min(open_sids)
                 got, done = rep.stream_next(sid, max_chunks=4)
-                pulls += 1
-                frames += got
-            assert [f for f in frames if "token" in f] and \
-                len(frames) == n + 1 and frames[-1]["done"]
-            chunks += len(frames)
+                reply = {sid: (got, done, None)}
+            else:
+                reply = rep.stream_poll("me")
+            replies += 1
+            for sid, (got, done, error) in reply.items():
+                assert error is None and sid in open_sids
+                frames[sid] += got
+                if done:
+                    open_sids.discard(sid)
+        for sid, n in zip(sids, answers):
+            assert [f for f in frames[sid] if "token" in f] and \
+                len(frames[sid]) == n + 1 and frames[sid][-1]["done"]
+        chunks = sum(len(f) for f in frames.values())
+        # A caller with no stream left: a poll returns empty at its limit.
+        assert rep.stream_poll("me") == {} and not rep._streams
         hist = rep.instance.engine_stats()["phase_hist"]
     finally:
         rep.instance.engine.stop()
@@ -294,10 +312,8 @@ def test_phase_hist_counts_queue_ttft_and_stream_holds():
     assert hist["ttft"]["count"] == len(answers)
     # One hold a chunk: every token frame and each stream's last frame.
     assert hist["stream_hold"]["count"] == chunks == sum(answers) + 3
-    assert hist["stream_pull"]["count"] == pulls
-    # A chunk is held no longer than the pull that carries it lasts.
-    assert hist["stream_hold"]["sum"] <= \
-        hist["stream_pull"]["sum"] * 4 + 1e-6
+    # One pull a reply, the empty one too.
+    assert hist["stream_pull"]["count"] == replies + 1
 
 
 def test_a_resumed_request_is_not_a_second_arrival():
