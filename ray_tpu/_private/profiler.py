@@ -280,7 +280,8 @@ def idle_gaps(rows: list, names=None, top: int = 10) -> dict:
     the union of a device plane's op intervals, first to last op (with
     several device planes, every plane's gaps: idle chip-seconds). The
     host events named in ``names`` (default: the span registry,
-    util/perfmodel.PHASES) cover it or do not:
+    util/perfmodel.PHASES, and behind it the steps, perfmodel.STEPS)
+    cover it or do not:
 
       idle_s       all gaps
       by_phase_s   {name: seconds of gaps lying under that span}
@@ -291,11 +292,13 @@ def idle_gaps(rows: list, names=None, top: int = 10) -> dict:
 
     A device span's name (``llm.decode.device``, ``train.wait``) over a
     gap says the host was only waiting there; a host phase's says the
-    device waited for that work."""
+    device waited for that work; a step's own name (``llm.step``) has
+    what lies inside a step under none of its spans: the innermost
+    span has the instant, so that is the step's ``other_ms``."""
     if names is None:
         from ray_tpu.util import perfmodel
 
-        names = perfmodel.PHASES
+        names = set(perfmodel.PHASES) | set(perfmodel.STEPS)
     spans = sorted((start, start + dur, name)
                    for plane, _, name, start, dur in rows
                    if name in names and dur > 0
@@ -327,6 +330,8 @@ def idle_gaps(rows: list, names=None, top: int = 10) -> dict:
 
 def format_idle_gaps(table: dict) -> str:
     """The idle-gap table as `rtpu profile --device` prints it."""
+    from ray_tpu.util import perfmodel
+
     idle = table["idle_s"]
     if idle <= 0:
         return "  no idle gap between device ops in this window"
@@ -334,6 +339,8 @@ def format_idle_gaps(table: dict) -> str:
              f"last op, by the host span over each gap:"]
     for name, s in list(table["by_phase_s"].items()) + [
             ("(no named span)", table["uncovered_s"])]:
+        if name in perfmodel.STEPS:
+            name += " (no phase)"
         lines.append(f"    {name:<22} {s * 1e3:9.2f} ms  "
                      f"{100 * s / idle:5.1f}%")
     lines.append("  longest gaps (ms, span over most of it, its share):")
@@ -343,11 +350,40 @@ def format_idle_gaps(table: dict) -> str:
     return "\n".join(lines)
 
 
+def _interval_line(e: dict) -> str:
+    """One step interval (previous finish() to this one) by its parts,
+    all ms: the gap before the step, its device spans by kind
+    (dispatch / wait where the span was cut), its host phases over
+    1 ms, the collector's passes that ended in it, the thread's CPU
+    time and the time it had work and was not running, and the step's
+    load."""
+    parts = [f"between {e.get('between_ms', 0.0):.1f} (lock "
+             f"{e['lock_wait_ms']:.1f}, idle {e['idle_ms']:.1f})"]
+    dispatch = e["dispatch_ms_by"]
+    for kind, ms in sorted(e["device_ms_by"].items(), key=lambda kv: -kv[1]):
+        cut = (f" ({dispatch[kind]:.1f}/{ms - dispatch[kind]:.1f})"
+               if kind in dispatch else "")
+        parts.append(f"{kind} {ms:.1f}{cut}")
+    parts += [f"{k} {ms:.1f}" for k, ms in sorted(
+        e["phases_ms"].items(), key=lambda kv: -kv[1]) if ms > 1.0]
+    line = f"      {e['interval_ms']:8.1f} = {' + '.join(parts)}"
+    if e.get("gc_gen") is not None:
+        line += f"; gc {e['gc_ms']:.1f} (gen {e['gc_gen']})"
+    line += f"; cpu {e['cpu_ms']:.1f}, stall {e['stall_ms']:.1f}"
+    if "lanes" in e:
+        line += (f"; lanes {e['lanes']}, chunk tokens "
+                 f"{e['prefill_tokens']}, arrived {e.get('arrived', 0)}")
+    return line
+
+
 def format_device_steps(steps: list) -> str:
     """A window's accounted steps (``device_steps``: the perfmodel
     ring's entries) as `rtpu profile --device` prints them, one block a
     step name and owner: the mean step split into its device spans by
-    kind and its host phases by name, and an engine's own counts."""
+    kind (and the dispatch inside each) and its host phases by name,
+    an engine's own counts, what lies between steps, and the window's
+    five longest step intervals, each by its parts
+    (``_interval_line``)."""
     from ray_tpu.util import perfmodel
 
     groups: dict = {}
@@ -367,8 +403,12 @@ def format_device_steps(steps: list) -> str:
             return sorted({k for e in evs for k in e.get(key) or {}},
                           key=lambda k: -mean(key, k))
 
-        by = ", ".join(f"{k} {mean('device_ms_by', k):.2f}"
-                       for k in names("device_ms_by"))
+        cut = set(names("dispatch_ms_by"))
+        by = ", ".join(
+            f"{k} {mean('device_ms_by', k):.2f}"
+            + (f" [dispatch {mean('dispatch_ms_by', k):.2f}]"
+               if k in cut else "")
+            for k in names("device_ms_by"))
         lines.append(
             f"  {name} x {n}{' (' + owner + ')' if owner else ''}: "
             f"{mean('step_ms'):.2f} ms a step = device "
@@ -391,6 +431,16 @@ def format_device_steps(steps: list) -> str:
                 f"{sum(len(e['prefill_chunks']) for e in evs)} chunk(s); "
                 f"waiting {max(e['waiting'] for e in evs)} at most; "
                 f"preempted {sum(e['preempted'] for e in evs)}")
+        timed = [e for e in evs if "interval_ms" in e]
+        if timed:
+            lines.append(
+                f"    between steps {mean('between_ms'):.2f} (lock "
+                f"{mean('lock_wait_ms'):.2f}, idle {mean('idle_ms'):.2f}),"
+                f" cpu {mean('cpu_ms'):.2f}, stall {mean('stall_ms'):.2f},"
+                f" gc {mean('gc_ms'):.2f} ms a step; the longest intervals, "
+                f"finish to finish (ms):")
+            lines += [_interval_line(e) for e in sorted(
+                timed, key=lambda e: -e["interval_ms"])[:5]]
     return "\n".join(lines)
 
 

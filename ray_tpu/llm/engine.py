@@ -298,8 +298,10 @@ class LLMEngine:
         # Named host phases and device spans by kind ride the same
         # object (perfmodel.PHASES), so a step's ring entry says which
         # part of the host gap was admission, input building, sampling,
-        # emission or publishing, and what the loop did between steps.
-        self._step_perf = perfmodel.StepAccounting()
+        # emission or publishing, and what the loop did between steps
+        # (its wait for this lock, its sleep on an empty engine).
+        self._step_perf = perfmodel.StepAccounting(between="llm.between")
+        self._arrived = 0       # add_request calls since the last ring entry
         self._preempt_count = 0       # preemptions, all steps
         self._chunk_log: List[list] = []    # this step's prefill chunks
         # lanes, context tokens, decode tokens, lanes decided on the device
@@ -360,6 +362,7 @@ class LLMEngine:
         with self._cond:
             self._requests[req.rid] = req
             self._waiting.append(req)
+            self._arrived += 1
             self._event(req, WAITING)
             self._cond.notify()
         return req
@@ -616,6 +619,7 @@ class LLMEngine:
             with perf.device("llm.prefill.device") as dev:
                 row, tok, *pools = self._prefill_chunk(
                     self.params, toks, *self.kv.pools, table, *window)
+                dev.dispatched()
                 self._take_back(pools)
                 if done:
                     first = jax.device_get(tok if req.greedy else row)
@@ -633,8 +637,10 @@ class LLMEngine:
                 perf.add_cost(perfmodel.prefill_cost(
                     self.cfg, c + pad, ctx_tokens=upto))
                 # [positions computed (padded to whole blocks, as
-                # priced), context tokens resident before them, ms].
-                self._chunk_log.append([c + pad, upto, device_s * 1e3])
+                # priced), context tokens resident before them, ms, of
+                # them the host's dispatch].
+                self._chunk_log.append([c + pad, upto, device_s * 1e3,
+                                        dev.dispatch_seconds * 1e3])
                 if done:
                     if self._prefix:
                         # Index the prompt's chunks for later arrivals
@@ -898,6 +904,7 @@ class LLMEngine:
                 tables, context_lens,
                 q_lens if spec is not None else self._one_row_each,
                 slot_blocks, slot_offsets, *window)
+            dev.dispatched()
             self._take_back(pools)
             jax.block_until_ready(ids)
         device_s = dev.seconds
@@ -1000,8 +1007,10 @@ class LLMEngine:
         for every running sequence (one token each; with speculation on
         it may emit several). Returns the number of in-flight sequences
         after the step."""
+        perf = self._step_perf
+        t_lock = time.perf_counter()
         with self._lock:
-            perf = self._step_perf
+            perf.lock_waited(t_lock)
             perf.begin()
             self._chunk_log = []
             self._counts = (0, 0, 0, 0)
@@ -1040,9 +1049,10 @@ class LLMEngine:
             # Counts are the scheduler's own, taken where it has them.
             lanes, context_tokens, decode_tokens, on_device = self._counts
             chunks = self._chunk_log
-            perf.finish(
+            entry = perf.finish(
                 record_as="llm.step",
                 attrs={"deployment": self.name, "step": self._steps,
+                       "arrived": self._arrived,
                        "lanes": lanes, "max_batch": self.max_batch,
                        perfmodel.DEVICE_SAMPLED: on_device,
                        "context_tokens": context_tokens,
@@ -1052,6 +1062,8 @@ class LLMEngine:
                        "waiting": len(self._waiting),
                        "preempted": self._preempt_count - preempted0,
                        **window, **self._step_counters()})
+            if entry is not None:
+                self._arrived = 0       # callers wait for this lock
             return len(self._active)
 
     def _step_counters(self) -> dict:
@@ -1129,6 +1141,12 @@ class LLMEngine:
             # Output tokens by where they were decided (see __init__).
             "tokens_decided_on_device": self._decided["device"],
             "tokens_decided_on_host": self._decided["host"],
+            # Cumulative: how long, and how often, the loop slept on an
+            # empty engine; the process's collector, {generation:
+            # [passes, seconds]}. Two readings give a window's.
+            "idle_s": self._step_perf.idle_total_s,
+            "idle_waits": self._step_perf.idle_waits,
+            "gc": perfmodel.gc_totals(),
         }
         if self._prefix:
             ps = self.kv.prefix_stats()
@@ -1259,12 +1277,15 @@ class LLMEngine:
         self._thread.start()
 
     def _loop(self):
+        perf = self._step_perf
         while True:
+            t_lock = time.perf_counter()
             with self._cond:
+                perf.lock_waited(t_lock)
                 while not self._stop and not self._waiting \
                         and not self._active:
-                    self._step_perf.mark_idle()
-                    self._cond.wait(timeout=0.5)
+                    with perf.idle("llm.idle"):
+                        self._cond.wait(timeout=0.5)
                     # Idle tick: keep publishing so the telemetry series
                     # (tokens/s, batch size, step breakdown) fall to
                     # zero when the engine drains instead of freezing at

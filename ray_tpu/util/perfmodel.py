@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import sys
 import threading
 import time
@@ -290,7 +291,11 @@ def roofline(cost: StepCost, device_s: float, host_gap_s: float = 0.0,
 #: step's host time. While a ``jax.profiler`` session is open every
 #: name is also a ``TraceAnnotation`` for the same interval, so the
 #: span lies on the trace's host plane beside the device ops. A name
-#: that is not here is an error.
+#: that is not here is an error. The names in ``ANNOTATIONS`` are the
+#: accounting's own: an interval it times itself (the gap between two
+#: steps, the two halves of a device span, a collection) and annotates
+#: while a session is open; they lie over or inside the phases and
+#: spans, so none is a key of ``phases_ms`` or ``device_ms_by``.
 PHASES: Dict[str, tuple] = {
     "llm.admit": (None, "admission of waiting requests: prefix lookup, "
                         "block grants"),
@@ -324,6 +329,25 @@ PHASES: Dict[str, tuple] = {
     "data.next_batch": (None, "one next() of the dataset shard's batch "
                               "iterator inside a training loop"),
 }
+_OWN_INTERVALS = {
+    "llm.between": "one step's finish() to the next one's begin(): the "
+                   "loop's turn-around and its wait for the engine's lock "
+                   "while work was waiting",
+    "llm.idle": "the loop asleep on an empty engine, inside llm.between",
+    "llm.decode.dispatch": "llm.decode.device until the jitted call has "
+                           "returned its futures: argument hand-over and "
+                           "enqueue",
+    "llm.decode.wait": "the rest of llm.decode.device: the wait for the "
+                       "ids and the wake-up after it",
+    "llm.prefill.dispatch": "llm.prefill.device until the chunk program's "
+                            "call has returned",
+    "llm.prefill.wait": "the rest of llm.prefill.device: the wait for its "
+                        "result and the wake-up",
+    "py.gc": "one pass of the interpreter's collector, on the thread that "
+             "ran it (gc.callbacks)",
+}
+PHASES.update((name, (None, what)) for name, what in _OWN_INTERVALS.items())
+ANNOTATIONS = frozenset(_OWN_INTERVALS)
 #: Annotated whole steps (``StepAccounting.step``): ring entry names.
 STEPS = ("llm.step", "train.step")
 #: Key of an ``llm.step`` ring entry beside ``lanes``: how many of the
@@ -342,41 +366,135 @@ def _session_open() -> bool:
     return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
 
 
+def _annotation(name: str):
+    """A ``TraceAnnotation`` that starts now (it starts when it is
+    built); its ``__exit__`` ends it."""
+    return sys.modules["jax"].profiler.TraceAnnotation(name)
+
+
+class _Collector:
+    """The interpreter's collector, process-wide: one ``gc.callbacks``
+    hook, put in by the first step any StepAccounting begins. A pass
+    costs two clock readings, and nothing between passes. Passes do not
+    nest and run with the interpreter lock held, so one open pass is
+    all there is."""
+
+    def __init__(self):
+        # {generation: [passes, seconds]} since the hook went in.
+        self.totals: Dict[int, list] = {0: [0, 0.0], 1: [0, 0.0],
+                                        2: [0, 0.0]}
+        # The last passes, (count so far, generation, seconds, objects
+        # collected), for the step that ends after them: more than an
+        # interval holds.
+        self.passes: collections.deque = collections.deque(maxlen=128)
+        self.count = 0
+        self._t0 = 0.0      # the running pass's start
+        self._ann = None    # and, under a session, its annotation
+
+    def hook(self, phase: str, info: dict):
+        if phase == "start":
+            self._ann = _annotation("py.gc") if _session_open() else None
+            self._t0 = time.perf_counter()
+            return
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if not self._t0:
+            return      # a pass that began before the hook went in
+        s = time.perf_counter() - self._t0
+        self._t0 = 0.0
+        self.count += 1
+        total = self.totals[info["generation"]]
+        total[0] += 1
+        total[1] += s
+        self.passes.append((self.count, info["generation"], s,
+                            info["collected"]))
+
+    def seconds(self) -> float:
+        return sum(s for _, s in self.totals.values())
+
+    def since(self, seen: int) -> tuple:
+        """(longest seconds, oldest generation or None, passes so far)
+        of the passes after the ``seen``-th, from the last 128."""
+        longest, oldest = 0.0, None
+        if self.count != seen:
+            for count, gen, s, _ in list(self.passes):
+                if count > seen:
+                    longest = max(longest, s)
+                    oldest = gen if oldest is None else max(oldest, gen)
+        return longest, oldest, self.count
+
+
+_COLLECTOR = _Collector()
+
+
+def gc_totals() -> Dict[int, list]:
+    """{generation: [passes, seconds]} of the collector since this
+    process's first accounted step (cumulative: two readings give a
+    window's)."""
+    return {gen: list(t) for gen, t in _COLLECTOR.totals.items()}
+
+
 class _Span:
     """One registry name's reusable context manager: host clock and,
     in a step that began with a profiler session open, a
     TraceAnnotation over the same interval (the annotation starts when
-    it is built, so each interval builds its own). Not re-entrant; one
-    thread drives a StepAccounting (the engine under its lock, a
-    training loop's thread)."""
+    it is built, so each interval builds its own). A device span named
+    ``<stem>.device`` can be cut in two by ``dispatched()``. Not
+    re-entrant; one thread drives a StepAccounting (the engine under
+    its lock, a training loop's thread)."""
 
-    __slots__ = ("_acc", "name", "_kind", "_t0", "_ann", "seconds")
+    __slots__ = ("_acc", "name", "_kind", "_t0", "_t_mid", "_ann", "_half",
+                 "_halves", "seconds", "dispatch_seconds")
 
     def __init__(self, acc: "StepAccounting", name: str):
         self._acc = acc
         self.name = name
         self._kind = PHASES[name][0]
-        self._t0 = 0.0
-        self._ann = None
-        self.seconds = 0.0
+        self._t0 = self._t_mid = 0.0
+        self._ann = self._half = None
+        stem = name[:-len(".device")] if name.endswith(".device") else None
+        self._halves = stem and (stem + ".dispatch", stem + ".wait")
+        self.seconds = self.dispatch_seconds = 0.0
 
     def __enter__(self):
         if self._acc._traced:
-            self._ann = sys.modules["jax"].profiler.TraceAnnotation(
-                self.name)
+            self._ann = _annotation(self.name)
+            if self._halves:
+                self._half = _annotation(self._halves[0])
         self._t0 = time.perf_counter()
         return self
 
+    def dispatched(self):
+        """Inside a ``<stem>.device`` span: the jitted call has
+        returned its futures. What came before is the host's dispatch
+        (argument hand-over and enqueue), what follows the wait."""
+        self._t_mid = time.perf_counter()
+        if self._half is not None:
+            self._half.__exit__(None, None, None)
+            self._half = _annotation(self._halves[1])
+
     def __exit__(self, *exc):
         self.seconds = s = time.perf_counter() - self._t0
+        if self._half is not None:
+            self._half.__exit__(*exc)
+            self._half = None
         if self._ann is not None:
             self._ann.__exit__(*exc)
             self._ann = None
         acc = self._acc
-        if self._kind is None:
-            acc._phase_s[self.name] = acc._phase_s.get(self.name, 0.0) + s
-        else:
-            acc.add_device(s, kind=self._kind)
+        kind = self._kind
+        if kind is None:
+            name = self.name
+            acc._phase_s[name] = acc._phase_s.get(name, 0.0) + s
+            return False
+        acc.add_device(s, kind=kind)
+        if self._t_mid:
+            # The dispatch half is the host's work; the wait is not.
+            self.dispatch_seconds = d = self._t_mid - self._t0
+            acc._dispatch_by[kind] = acc._dispatch_by.get(kind, 0.0) + d
+            acc._dispatch_s += d
+            self._t_mid = 0.0
         return False
 
 
@@ -385,44 +503,86 @@ class StepAccounting:
     and priced costs, and folds them into a breakdown dict on finish().
     Cheap enough for the per-decode-step hot path (see the perf gate):
     a begin/add/finish cycle is plain float arithmetic, no locks, no
-    allocation beyond the result dict and one float a name.
+    allocation beyond the result dicts and one float a name.
 
     The breakdown (one entry of the device-step ring):
       step_ms / device_ms / host_gap_ms   begin() to finish(), the sum
                     of the device spans, and the rest
       device_ms_by  {kind: ms}; sums to device_ms
+      dispatch_ms_by  {kind: ms} of the device spans that were cut by
+                    dispatched(): the part before the jitted call
+                    returned; each at most its device_ms_by value
       phases_ms     {host phase: ms}; with other_ms, what no phase
                     names, they sum to host_gap_ms
       between_ms    the previous finish() to this begin() (absent on
-                    the first step); idle_wait says the owner slept on
-                    an empty queue in between (mark_idle)
+                    the first step); of it lock_wait_ms was spent
+                    acquiring the owner's lock (lock_waited) and
+                    idle_ms asleep on an empty queue (idle), which
+                    idle_wait says happened at all
+      interval_ms   between_ms + step_ms: one finish() to the next
+      cpu_ms        the thread's CPU time over that interval: ONE
+                    reading of time.thread_time() a step, in finish()
+                    (a reading is a system call, 6 us on the benchmark's
+                    host, where the clock also ticks at 10 ms: no span
+                    reads it, and a single entry is good to a tick)
+      stall_ms      interval_ms - idle_ms - the device spans' waits
+                    (a span less its dispatch half; a span that was not
+                    cut, whole) - cpu_ms: the thread had work (host
+                    phases, the gap, argument hand-over) and was not
+                    running: it waited for a lock or the interpreter,
+                    another thread ran a collection, or the OS held
+                    it. Not clamped: ticks cancel over a window's sum,
+                    so a window's mean is exact where one entry is not
+      gc_ms / gc_max_ms / gc_gen   the collector's passes that ended
+                    inside the interval, whichever thread ran them:
+                    their sum, the longest, the oldest generation
+                    (None: no pass)
       tokens / flops / hbm_bytes and, with a peak, mfu / hbm_util /
       verdict / hardware
     """
 
-    __slots__ = ("_hw", "n_chips", "_wall0", "_traced", "_device_s",
-                 "_device_by", "_phase_s", "_spans", "_flops",
-                 "_hbm_bytes", "_tokens", "_finish_t", "_between_s",
-                 "_idle", "_idle_wait", "last")
+    __slots__ = ("_hw", "n_chips", "_wall0", "_traced",
+                 "_device_s", "_dispatch_s", "_device_by", "_dispatch_by",
+                 "_phase_s", "_spans", "_flops", "_hbm_bytes",
+                 "_tokens", "_finish_t", "_finish_cpu", "_between_s",
+                 "_between", "_gap_ann", "_idle_s", "_lock_wait_s",
+                 "_gc_seen", "_gc_s", "idle_total_s", "idle_waits", "last")
 
     def __init__(self, hw: Optional[HardwarePeak] = None,
-                 n_chips: int = 1):
+                 n_chips: int = 1, between: Optional[str] = None):
         # Resolved on first use: detect_hardware() brings the backend
         # up, which a session that never dispatches must not do.
         self._hw = hw if hw is not None else _UNDETECTED
         self.n_chips = max(int(n_chips), 1)
+        # The owner's name for the gap between two of its steps, an
+        # annotation from finish() to the next begin() under a session.
+        if between is not None and between not in ANNOTATIONS:
+            raise KeyError(f"{between!r} is not in perfmodel.ANNOTATIONS")
+        self._between = between
+        self._gap_ann = None
         self._wall0 = 0.0
         self._traced = False    # a profiler session was open at begin()
-        self._device_s = 0.0
+        self._device_s = self._dispatch_s = 0.0
         self._device_by: Dict[str, float] = {}
+        self._dispatch_by: Dict[str, float] = {}
         self._phase_s: Dict[str, float] = {}
         self._spans: Dict[str, _Span] = {}
         self._flops = 0.0
         self._hbm_bytes = 0.0
         self._tokens = 0
         self._finish_t: Optional[float] = None
+        # The thread's CPU clock at the last finish() (at the first
+        # begin(), before there was one).
+        self._finish_cpu: Optional[float] = None
         self._between_s: Optional[float] = None
-        self._idle = self._idle_wait = False
+        # Since the last finish(): asleep, and acquiring the lock.
+        self._idle_s = self._lock_wait_s = 0.0
+        self._gc_seen = _COLLECTOR.count
+        self._gc_s = _COLLECTOR.seconds()
+        # Cumulative, so a window's share is a difference of two
+        # readings whatever the ring still holds.
+        self.idle_total_s = 0.0
+        self.idle_waits = 0
         self.last: Optional[dict] = None
 
     @property
@@ -433,19 +593,44 @@ class StepAccounting:
             self._hw = detect_hardware()
         return self._hw
 
-    def mark_idle(self):
-        """The owner is about to sleep on an empty queue: the next
-        step's between_ms is a wait for work, not a hand-over."""
-        self._idle = True
+    @contextlib.contextmanager
+    def idle(self, name: str):
+        """Around the owner's sleep on an empty queue: the next step's
+        between_ms holds a wait for work (idle_ms of it, idle_wait)."""
+        if name not in ANNOTATIONS:
+            raise KeyError(f"{name!r} is not in perfmodel.ANNOTATIONS")
+        ann = _annotation(name) if _session_open() else None
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            s = time.perf_counter() - t0
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self._idle_s += s
+            self.idle_total_s += s
+            self.idle_waits += 1
+
+    def lock_waited(self, t0: float):
+        """The owner has its lock, which it set out to take at ``t0``
+        (time.perf_counter()), between two steps."""
+        self._lock_wait_s += time.perf_counter() - t0
 
     def begin(self):
-        self._traced = _session_open()
+        if _COLLECTOR.hook not in gc.callbacks:
+            gc.callbacks.append(_COLLECTOR.hook)
+        if self._finish_cpu is None:
+            self._finish_cpu = time.thread_time()
         self._wall0 = now = time.perf_counter()
+        if self._gap_ann is not None:
+            self._gap_ann.__exit__(None, None, None)
+            self._gap_ann = None
+        self._traced = _session_open()
         self._between_s = (None if self._finish_t is None
                            else now - self._finish_t)
-        self._idle_wait, self._idle = self._idle, False
-        self._device_s = 0.0
+        self._device_s = self._dispatch_s = 0.0
         self._device_by.clear()
+        self._dispatch_by.clear()
         self._phase_s.clear()
         self._flops = 0.0
         self._hbm_bytes = 0.0
@@ -454,10 +639,10 @@ class StepAccounting:
     def _span(self, name: str) -> _Span:
         span = self._spans.get(name)
         if span is None:
-            if name not in PHASES:
+            if name not in PHASES or name in ANNOTATIONS:
                 raise KeyError(
-                    f"{name!r} is not in perfmodel.PHASES, the registry "
-                    f"of span names")
+                    f"{name!r} is not a phase or a device span of "
+                    f"perfmodel.PHASES, the registry of span names")
             span = self._spans[name] = _Span(self, name)
         return span
 
@@ -470,8 +655,9 @@ class StepAccounting:
 
     def device(self, name: str) -> _Span:
         """Context manager over a DEVICE span, dispatch to ready; its
-        ``seconds`` can be read after it closes. Price the work with
-        add_cost()."""
+        ``seconds`` (and, where ``dispatched()`` cut it, its
+        ``dispatch_seconds``) can be read after it closes. Price the
+        work with add_cost()."""
         span = self._span(name)
         if span._kind is None:
             raise KeyError(f"{name!r} is a host phase: use phase()")
@@ -505,12 +691,27 @@ class StepAccounting:
         if cost is not ZERO_COST:
             self.add_cost(cost)
 
+    def _gc_since_last(self) -> tuple:
+        """(ms, longest ms, oldest generation or None) of the passes
+        that ended since the previous finish()."""
+        total = _COLLECTOR.seconds()
+        gc_s, self._gc_s = total - self._gc_s, total
+        longest, oldest, self._gc_seen = _COLLECTOR.since(self._gc_seen)
+        return gc_s * 1e3, longest * 1e3, oldest
+
     def finish(self, *, record_as: Optional[str] = None,
                attrs: Optional[dict] = None) -> Optional[dict]:
         """Close the step. Returns None (and records nothing) if no
         device work ran — an idle scheduler tick is not a step."""
         now = time.perf_counter()
-        self._finish_t = now
+        cpu = time.thread_time()
+        cpu_s = cpu - self._finish_cpu
+        self._finish_t, self._finish_cpu = now, cpu
+        idle_s, lock_s = self._idle_s, self._lock_wait_s
+        self._idle_s = self._lock_wait_s = 0.0
+        gc_ms, gc_max_ms, gc_gen = self._gc_since_last()
+        if self._traced and self._between is not None:
+            self._gap_ann = _annotation(self._between)
         if self._device_s <= 0.0 and self._flops <= 0.0:
             self.last = None
             return None
@@ -518,15 +719,28 @@ class StepAccounting:
         host_gap_s = wall_s - self._device_s
         host_gap_ms = host_gap_s * 1e3
         phases_ms = {k: v * 1e3 for k, v in self._phase_s.items()}
+        between_s = self._between_s or 0.0
+        interval_s = wall_s + between_s
         out = {
             "step_ms": wall_s * 1e3,
             "device_ms": self._device_s * 1e3,
             "host_gap_ms": host_gap_ms,
             "device_ms_by": {k: v * 1e3
                              for k, v in self._device_by.items()},
+            "dispatch_ms_by": {k: v * 1e3
+                               for k, v in self._dispatch_by.items()},
             "phases_ms": phases_ms,
             "other_ms": host_gap_ms - sum(phases_ms.values()),
-            "idle_wait": self._idle_wait,
+            "idle_wait": idle_s > 0.0,
+            "idle_ms": idle_s * 1e3,
+            "lock_wait_ms": lock_s * 1e3,
+            "interval_ms": interval_s * 1e3,
+            "cpu_ms": cpu_s * 1e3,
+            "stall_ms": (interval_s - min(idle_s, between_s) - cpu_s
+                         - (self._device_s - self._dispatch_s)) * 1e3,
+            "gc_ms": gc_ms,
+            "gc_max_ms": gc_max_ms,
+            "gc_gen": gc_gen,
             "tokens": self._tokens,
             "flops": self._flops,
             "hbm_bytes": self._hbm_bytes,

@@ -7,6 +7,7 @@ CPU, manually stepped where it can be. Structure and counts only: no
 time is asserted as a fact.
 """
 
+import gc
 import threading
 import time
 
@@ -26,7 +27,10 @@ CFG = GPTConfig(vocab_size=128, max_seq=64, d_model=64, n_layer=2,
                 n_head=4, dtype=jnp.float32)
 PARAMS = init(jax.random.PRNGKey(0), CFG)
 
-HOST_PHASES = {n for n, (kind, _) in PHASES.items() if kind is None}
+# Kind None: the host phases, and the accounting's own annotations
+# (perfmodel.ANNOTATIONS), which are never keys of phases_ms.
+HOST_PHASES = {n for n, (kind, _) in PHASES.items() if kind is None} \
+    - perfmodel.ANNOTATIONS
 DEVICE_SPANS = {n: kind for n, (kind, _) in PHASES.items() if kind}
 
 
@@ -89,12 +93,20 @@ def test_phases_partition_the_host_gap_and_kinds_the_device_span():
     ("phase", "llm.decode.device"),     # a device span is not a phase
     ("device", "llm.sample"),           # nor a phase a device span
     ("step", "llm.sample"),
+    # The accounting's own annotations are neither.
+    ("phase", "llm.between"), ("phase", "py.gc"),
+    ("device", "llm.decode.dispatch"), ("idle", "llm.sample"),
 ])
 def test_a_name_outside_the_registry_is_an_error(call, name):
     acc = StepAccounting()
     with pytest.raises(KeyError):
-        getattr(acc, call)(name, 1) if call == "step" \
-            else getattr(acc, call)(name)
+        if call == "step":
+            acc.step(name, 1)
+        elif call == "idle":
+            with acc.idle(name):
+                pass
+        else:
+            getattr(acc, call)(name)
     with pytest.raises(KeyError):
         acc.add_device(0.001, kind="no_such_kind")
 
@@ -106,6 +118,131 @@ def test_registry_names_every_kind_once_and_documents_each_span():
     assert all(what and isinstance(what, str)
                for _, what in PHASES.values())
     assert set(perfmodel.STEPS) == {"llm.step", "train.step"}
+    # The annotations are registry names with kind None and a meaning.
+    assert perfmodel.ANNOTATIONS <= set(PHASES)
+    assert all(PHASES[n][0] is None for n in perfmodel.ANNOTATIONS)
+    with pytest.raises(KeyError):
+        StepAccounting(between="llm.sample")
+
+
+# One tick of the thread's CPU clock, generously: some hosts count it
+# in 10 ms jiffies whatever resolution they report.
+TICK_MS = max(time.get_clock_info("thread_time").resolution * 1e3, 10.0) + 1.0
+
+
+def _check_interval(entry):
+    """One finish() to the next, by its parts."""
+    assert entry["interval_ms"] == pytest.approx(
+        entry.get("between_ms", 0.0) + entry["step_ms"], abs=1e-9)
+    if "between_ms" in entry:
+        assert entry["lock_wait_ms"] + entry["idle_ms"] <= \
+            entry["between_ms"] + 1e-6
+    assert entry["idle_wait"] == (entry["idle_ms"] > 0.0)
+    # The thread cannot have run for longer than the interval lasted,
+    # give or take a tick of its CPU clock; what is left of the
+    # interval after the sleep, the waits for the device and the CPU
+    # time is the stall.
+    assert 0.0 <= entry["cpu_ms"] <= entry["interval_ms"] + TICK_MS
+    waits = sum(entry["device_ms_by"].values()) \
+        - sum(entry["dispatch_ms_by"].values())
+    assert entry["stall_ms"] == pytest.approx(
+        entry["interval_ms"] - min(entry["idle_ms"],
+                                   entry.get("between_ms", 0.0))
+        - waits - entry["cpu_ms"], abs=1e-6)
+    assert entry["stall_ms"] >= -TICK_MS
+    for kind, ms in entry["dispatch_ms_by"].items():
+        assert 0.0 <= ms <= entry["device_ms_by"][kind]
+    assert entry["gc_ms"] >= entry["gc_max_ms"] >= 0.0
+    assert (entry["gc_gen"] is None) == (entry["gc_max_ms"] == 0.0)
+
+
+def _spin(seconds):
+    """Pure-Python work on this thread for ``seconds`` of wall time."""
+    t_end = time.perf_counter() + seconds
+    n = 0
+    while time.perf_counter() < t_end:
+        n += 1
+    return n
+
+
+def test_the_interval_is_a_sum_of_named_parts():
+    acc = StepAccounting()
+    for _ in range(3):
+        acc.begin()
+        with acc.phase("llm.admit"):
+            _spin(0.002)
+        with acc.device("llm.decode.device") as dev:
+            dev.dispatched()
+        assert 0.0 <= dev.dispatch_seconds <= dev.seconds
+        out = acc.finish()
+        _check_partition(out)
+        _check_interval(out)
+        assert set(out["dispatch_ms_by"]) == {"decode"}
+    assert "between_ms" in out and out["lock_wait_ms"] == 0.0
+    # A thread that computes alone is running: CPU time, no stall.
+    acc.begin()
+    with acc.phase("llm.admit"):
+        _spin(0.1)
+    acc.add_device(1e-6)
+    out = acc.finish()
+    assert out["cpu_ms"] > 50.0 and out["stall_ms"] < out["cpu_ms"]
+
+
+def test_a_spinning_neighbour_thread_shows_as_stall():
+    """The thread had work and was not running: another Python thread
+    held the interpreter for its share of the phase."""
+    acc = StepAccounting()
+    acc.begin()
+    acc.add_device(1e-6)
+    acc.finish()
+    stop = threading.Event()
+
+    def neighbour():
+        while not stop.is_set():
+            pass
+
+    t = threading.Thread(target=neighbour, daemon=True)
+    t.start()
+    try:
+        acc.begin()
+        with acc.phase("llm.emit"):
+            _spin(0.2)
+        acc.add_device(1e-6)
+        out = acc.finish()
+    finally:
+        stop.set()
+        t.join(timeout=10)
+    _check_interval(out)
+    wall = out["phases_ms"]["llm.emit"]
+    assert wall >= 200.0 and out["cpu_ms"] < wall - 20.0
+    assert out["stall_ms"] > 20.0 + TICK_MS
+
+
+def test_a_collection_inside_a_phase_is_named_and_leaves_the_partition():
+    acc = StepAccounting()
+    acc.begin()
+    acc.add_device(1e-6)
+    acc.finish()
+    before = perfmodel.gc_totals()
+    acc.begin()
+    with acc.phase("llm.emit"):
+        gc.collect()
+    acc.add_device(1e-6)
+    out = acc.finish()
+    _check_partition(out)
+    _check_interval(out)
+    assert out["gc_ms"] > 0.0 and out["gc_gen"] == 2
+    assert "py.gc" not in out["phases_ms"]
+    after = perfmodel.gc_totals()
+    assert after[2][0] == before[2][0] + 1
+    assert after[2][1] - before[2][1] <= out["gc_ms"] / 1e3 + 1e-9
+    # One hook a process, however many accountings began a step.
+    StepAccounting().begin()
+    assert gc.callbacks.count(perfmodel._COLLECTOR.hook) == 1
+    # The next interval holds no generation-2 pass of its own.
+    acc.begin()
+    acc.add_device(1e-6)
+    assert acc.finish()["gc_gen"] != 2
 
 
 # ---------------------------------------------------------------------------
@@ -124,7 +261,10 @@ def test_engine_ring_entries_partition_and_name_only_registry_phases():
     seen = set()
     for e in ring:
         _check_partition(e)
+        _check_interval(e)
         assert set(e["device_ms_by"]) <= {"prefill", "decode"}
+        # Both served spans are cut where their call returned.
+        assert set(e["dispatch_ms_by"]) == set(e["device_ms_by"])
         seen |= set(e["phases_ms"])
     # Every host phase of the plain decode path ran and was named
     # (llm.trace copies spans of traced requests only: none here).
@@ -165,9 +305,12 @@ def test_ring_counts_agree_with_the_scheduler(prompts):
         assert e["prefill_tokens"] <= budget + \
             len(e["prefill_chunks"]) * (max(n % bs for n in prompts) and
                                         bs - 1)
-        for tokens, ctx_tokens, device_ms in e["prefill_chunks"]:
+        for tokens, ctx_tokens, device_ms, dispatch_ms in \
+                e["prefill_chunks"]:
             assert tokens % bs == 0 and ctx_tokens % bs == 0
-            assert device_ms >= 0.0
+            assert 0.0 <= dispatch_ms <= device_ms
+        assert sum(c[3] for c in e["prefill_chunks"]) == pytest.approx(
+            e["dispatch_ms_by"].get("prefill", 0.0), abs=1e-9)
         # Lanes: what was RUNNING before the step plus what its
         # prefills activated; each lane attends its context + 1.
         lanes = [r for r in reqs
@@ -228,6 +371,67 @@ def test_between_ms_on_a_manually_stepped_engine():
     for e in ring[1:]:
         assert e["idle_wait"] is False      # nobody slept: no loop
         assert e["between_ms"] >= 0.0
+    perfmodel.clear_device_steps()
+
+
+def test_a_held_lock_between_two_manual_steps_is_lock_wait():
+    perfmodel.clear_device_steps()
+    t0 = time.time()
+    eng = LLMEngine(PARAMS, CFG, num_blocks=32, block_size=8)
+    eng.add_request([1, 2, 3], max_tokens=6)
+    eng.step()
+    holding, hold_s = threading.Event(), 0.05
+
+    def holder():
+        with eng._lock:
+            holding.set()
+            time.sleep(hold_s)
+
+    t = threading.Thread(target=holder, daemon=True)
+    t.start()
+    assert holding.wait(timeout=10)
+    eng.step()                      # waits out the holder
+    t.join(timeout=10)
+    eng.step()
+    held, free = _ring("llm.step", t0)[1:3]
+    _check_interval(held)
+    assert held["lock_wait_ms"] >= hold_s * 1e3 * 0.8
+    assert held["between_ms"] >= held["lock_wait_ms"]
+    # Waiting for a lock is not running: it is stall, too.
+    assert held["stall_ms"] >= held["lock_wait_ms"] * 0.8
+    assert free["lock_wait_ms"] < hold_s * 1e3 * 0.5
+    perfmodel.clear_device_steps()
+
+
+def test_idle_seconds_are_totalled_and_arrivals_counted():
+    perfmodel.clear_device_steps()
+    t0 = time.time()
+    eng = LLMEngine(PARAMS, CFG, num_blocks=32, block_size=8, max_batch=4,
+                    name="idle_total_test")
+    assert eng.stats()["idle_s"] == 0.0 and eng.stats()["idle_waits"] == 0
+    eng.start()
+    try:
+        time.sleep(0.7)     # a wait is counted when it ends: 0.5 s
+        first = eng.stats()
+        assert first["idle_waits"] >= 1
+        hs = [eng.add_request([5, 6, 7 + i], max_tokens=3) for i in range(3)]
+        assert all(len(list(h.tokens())) == 3 for h in hs)
+        time.sleep(0.2)
+    finally:
+        eng.stop()
+    last = eng.stats()
+    assert last["idle_s"] > first["idle_s"] > 0.0
+    assert last["idle_s"] >= 0.6 and last["idle_waits"] > first["idle_waits"]
+    assert set(last["gc"]) == {0, 1, 2} and all(
+        n >= 0 and s >= 0.0 for n, s in last["gc"].values())
+    ring = _ring("llm.step", t0)
+    assert sum(e["arrived"] for e in ring) == 3
+    assert ring[0]["arrived"] >= 1 and ring[0]["idle_wait"]
+    assert ring[0]["idle_ms"] >= 600.0
+    for e in ring:
+        _check_interval(e)
+    # What the entries hold of the sleep, the total holds too.
+    assert sum(e["idle_ms"] for e in ring) / 1e3 <= last["idle_s"] + 1e-6
     perfmodel.clear_device_steps()
 
 
@@ -475,7 +679,7 @@ def _rows():
         (H, "python", "llm.emit", 300, 40),         # gap 300-400: 40 ...
         (H, "python", "llm.publish", 340, 70),      # ... and 60
         (H, "python", "PjitFunction(f)", 500, 400),  # gap 500-900: unnamed
-        (H, "python", "llm.step", 0, 1150),         # a step is no phase
+        (H, "python", "llm.step", 0, 1150),   # has what its spans leave
         (H, "python", "llm.decode.device", 990, 200),   # gap 1000-1100
         (H, "other", "llm.decode.build", 1040, 20),     # innermost wins
     ]
@@ -490,18 +694,21 @@ def test_idle_gaps_wholly_inside_split_and_uncovered():
     assert t["by_phase_s"] == pytest.approx({
         "llm.sample": 100 * ns, "llm.publish": 60 * ns,
         "llm.emit": 40 * ns, "llm.decode.device": 80 * ns,
-        "llm.decode.build": 20 * ns})
-    assert t["uncovered_s"] == pytest.approx(400 * ns)
+        "llm.decode.build": 20 * ns,
+        # Inside the step, under none of its spans: its other_ms.
+        "llm.step": 400 * ns})
+    assert t["uncovered_s"] == pytest.approx(0.0, abs=1e-15)
     assert sum(t["by_phase_s"].values()) + t["uncovered_s"] == \
         pytest.approx(t["idle_s"])
     longest = {round(g[0] * 1e6): g[1:] for g in t["longest"]}
-    assert longest[400] == [None, 0.0]
+    assert longest[400] == ["llm.step", 1.0]
     assert longest[100][0] in ("llm.sample", "llm.publish",
                                "llm.decode.device")
     split = [g for g in t["longest"] if g[1] == "llm.publish"]
     assert split and split[0][2] == pytest.approx(0.6)
     text = format_idle_gaps(t)
     assert "llm.sample" in text and "(no named span)" in text
+    assert "llm.step (no phase)" in text
     # Only the names asked for count; no device plane, no gap.
     only = idle_gaps(_rows(), names={"llm.sample"})
     assert set(only["by_phase_s"]) == {"llm.sample"}
@@ -509,6 +716,76 @@ def test_idle_gaps_wholly_inside_split_and_uncovered():
     host_only = idle_gaps([r for r in _rows() if r[0] == H])
     assert host_only["idle_s"] == 0.0 and host_only["longest"] == []
     assert "no idle gap" in format_idle_gaps(host_only)
+
+
+def test_idle_gaps_name_the_gap_between_steps_and_a_spans_halves():
+    """A device gap between two steps lies under llm.between (under
+    llm.idle where the loop slept inside it), one inside a device span
+    under its dispatch or wait half, one inside a collection under
+    py.gc whatever phase it interrupts."""
+    from ray_tpu._private.profiler import idle_gaps
+
+    rows = [(D, "XLA Ops", f"%op.{i}", a, d) for i, (a, d) in enumerate(
+        [(0, 100), (300, 100), (1000, 100), (1300, 100), (1600, 100)])] + [
+        (H, "python", "llm.step", 0, 150),
+        (H, "python", "llm.between", 150, 100),         # gap 100-300
+        (H, "python", "llm.step", 250, 300),
+        (H, "python", "llm.between", 550, 400),         # gap 400-1000 ...
+        (H, "python", "llm.idle", 600, 300),            # ... slept 300 of it
+        (H, "python", "llm.decode.device", 1050, 300),  # gap 1100-1300
+        (H, "python", "llm.decode.dispatch", 1050, 120),
+        (H, "python", "llm.decode.wait", 1170, 180),
+        (H, "python", "llm.emit", 1390, 220),           # gap 1400-1600
+        (H, "other thread", "py.gc", 1450, 100),
+    ]
+    t = idle_gaps(rows)
+    ns = 1e-9
+    assert t["idle_s"] == pytest.approx(1200 * ns)
+    assert t["by_phase_s"] == pytest.approx({
+        "llm.step": (50 + 50 + 150) * ns,
+        "llm.between": (100 + 100) * ns, "llm.idle": 300 * ns,
+        "llm.decode.dispatch": 70 * ns, "llm.decode.wait": 130 * ns,
+        "llm.emit": 100 * ns, "py.gc": 100 * ns})
+    assert t["uncovered_s"] == pytest.approx(50 * ns)
+
+
+def test_device_steps_table_ends_with_the_longest_intervals():
+    from ray_tpu._private.profiler import format_device_steps
+
+    perfmodel.clear_device_steps()
+    t0 = time.time()
+    eng = LLMEngine(PARAMS, CFG, num_blocks=32, block_size=8, max_batch=4,
+                    prefill_chunk_tokens=8, name="longest_test")
+    eng.add_request([5] * 20, max_tokens=8)
+    _drain(eng)
+    ring = _ring("llm.step", t0)
+    perfmodel.clear_device_steps()
+    slow = dict(ring[-1], interval_ms=99999.0, between_ms=900.0,
+                lock_wait_ms=12.5, idle_ms=800.0, gc_ms=30.0, gc_max_ms=30.0,
+                gc_gen=2, stall_ms=45.0, arrived=3,
+                cpu_ms=21.5,
+                phases_ms=dict(ring[-1]["phases_ms"], **{"llm.emit": 18.25}))
+    lines = format_device_steps(ring + [slow]).splitlines()
+    head = next(i for i, ln in enumerate(lines)
+                if "longest intervals" in ln)
+    assert "between steps" in lines[head] and "stall" in lines[head]
+    table = lines[head + 1:]
+    assert len(table) == 5 == len(lines) - head - 1
+    assert table[0].split()[0] == "99999.0"
+    assert "between 900.0 (lock 12.5, idle 800.0)" in table[0]
+    assert "llm.emit 18.2" in table[0] or "llm.emit 18.3" in table[0]
+    assert "; gc 30.0 (gen 2); cpu 21.5, stall 45.0; " in table[0]
+    assert table[0].endswith(f"lanes {slow['lanes']}, chunk tokens "
+                             f"{slow['prefill_tokens']}, arrived 3")
+    d = slow["dispatch_ms_by"]["decode"]
+    assert f"decode {slow['device_ms_by']['decode']:.1f} ({d:.1f}/" in table[0]
+    # Longest first; an interval without a collection names none.
+    firsts = [float(ln.split()[0]) for ln in table]
+    assert firsts == sorted(firsts, reverse=True)
+    assert any("gc " not in ln for ln in table[1:])
+    # Entries of a program from before the interval was timed: none.
+    old = [{k: v for k, v in e.items() if k != "interval_ms"} for e in ring]
+    assert "longest intervals" not in format_device_steps(old)
 
 
 def test_device_steps_table_splits_the_step_and_sums_the_counts():
@@ -530,10 +807,13 @@ def test_device_steps_table_splits_the_step_and_sums_the_counts():
          "device_ms": 8.0, "host_gap_ms": 2.0, "other_ms": 0.5,
          "device_ms_by": {"dispatch": 1.0, "wait": 7.0},
          "phases_ms": {"data.next_batch": 1.5}}])
-    head, phases, counts, train, train_phases = text.splitlines()
+    head, phases, counts, gaps, *longest, train, train_phases = \
+        text.splitlines()
     assert head.startswith(f"  llm.step x {len(ring)} (table_test): ")
     assert "decode " in head and "prefill " in head
+    assert "[dispatch " in head
     assert phases.lstrip().startswith("host by phase: ")
+    assert gaps.lstrip().startswith("between steps ") and len(longest) == 5
     assert set(HOST_PHASES) >= {
         w for w in phases.replace(",", " ").split() if w.startswith("llm.")}
     assert f"decode {sum(e['decode_tokens'] for e in ring)}, " in counts
@@ -586,6 +866,8 @@ def test_a_profiler_session_holds_the_step_and_its_phases_by_name():
         try:
             profiler._start_xla_trace(tmp)
             try:
+                eng.step()
+                gc.collect()        # a pass inside the session
                 _drain(eng)
             finally:
                 jax.profiler.stop_trace()
@@ -607,5 +889,11 @@ def test_a_profiler_session_holds_the_step_and_its_phases_by_name():
     assert {"llm.admit", "llm.prefill.host", "llm.prefill.device",
             "llm.slots", "llm.decode.build", "llm.decode.device",
             "llm.sample", "llm.emit", "llm.publish"} <= host
+    # The accounting's own intervals: the gap between two steps, the
+    # collector's pass, both halves of each device span.
+    assert {"llm.between", "py.gc", "llm.decode.dispatch",
+            "llm.decode.wait", "llm.prefill.dispatch",
+            "llm.prefill.wait"} <= host
+    assert "llm.idle" not in host        # nobody slept: no loop
     # On the CPU backend no device plane exists: nothing to attribute.
     assert profiler.idle_gaps(result["rows"])["idle_s"] == 0.0
