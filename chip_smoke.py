@@ -752,7 +752,8 @@ def _laguna_logits(rehearse: bool, seed: int) -> tuple:
     while eng.step():
         pass
     st = eng.stats()
-    log(f"  laguna: paged kernel {st['paged_kernel']}, window kind peak "
+    log(f"  laguna: paged kernel {st['paged_kernel']}, chunk attention "
+        f"{st['chunk_attention']}, window kind peak "
         f"{st['kv_window_util_peak']:.3f}, "
         f"{st['kv_window_blocks_slid']} blocks slid out")
     # The router alone, on identical inputs: the served route() against
@@ -836,7 +837,8 @@ def _kimi_logits(rehearse: bool, seed: int) -> tuple:
     st = eng.stats()
     _check(reqs[0].cached_tokens == n_prefix,
            f"kimi: the {n_prefix}-token prefix was a cache hit")
-    log(f"  kimi: paged kernel {st['paged_kernel']}, latent pool peak "
+    log(f"  kimi: paged kernel {st['paged_kernel']}, chunk attention "
+        f"{st['chunk_attention']}, latent pool peak "
         f"{st['kv_util_peak']:.3f}")
     # The router alone, on identical inputs: the served route_sigmoid()
     # against plain float32 sigmoid + bias + top-k.

@@ -132,9 +132,13 @@ class Request:
             yield tok
 
 
-# (pool spec, max_batch, q rows) -> "compiled" | "interpret"; see
-# LLMEngine._paged_kernel_mode.
+# (program, pool specs, its shapes) -> how its kernel runs; see
+# LLMEngine._paged_kernel_mode and _chunk_attention_mode.
 _KERNEL_MODES: dict = {}
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, np.int32)
 
 
 @functools.lru_cache(maxsize=32)
@@ -1084,6 +1088,20 @@ class LLMEngine:
         span = max(now - self._token_times[0][0], 1e-3)
         return sum(n for _, n in self._token_times) / span
 
+    def _program_specs(self, *lead, extra: int):
+        """What both programs take, as shapes: (the parameters, the
+        window kind's pools and its int32 array ``[*lead, window table
+        + extra]``, or nothing where the model has no such kind)."""
+        params = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding),
+            self.params)
+        if self.kv_window is None:
+            return params, ()
+        return params, (*(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                          for p in self.kv_window.pools),
+                        _i32(*lead, self._win_len + extra))
+
     def _paged_kernel_mode(self) -> str:
         """"compiled" if the decode program this engine steps with
         carries the Mosaic kernel, "interpret" if the Pallas
@@ -1095,24 +1113,39 @@ class LLMEngine:
         key = (self._decode, self._pool_specs, B, Q)
         mode = _KERNEL_MODES.get(key)
         if mode is None:
-            def i32(*shape):
-                return jax.ShapeDtypeStruct(shape, np.int32)
-
-            params = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=x.sharding),
-                self.params)
-            window = ()
-            if self.kv_window is not None:
-                window = (*(jax.ShapeDtypeStruct(p.shape, p.dtype)
-                            for p in self.kv_window.pools),
-                          i32(B, self._win_len + 1 + Q))
+            params, window = self._program_specs(B, extra=1 + Q)
             text = self._decode.lower(
-                params, i32(B, Q), i32(B, Q), *self._pool_specs,
-                i32(B, self.max_nb), i32(B), i32(B),
-                i32(B, Q), i32(B, Q), *window).as_text()
+                params, _i32(B, Q), _i32(B, Q), *self._pool_specs,
+                _i32(B, self.max_nb), _i32(B), _i32(B),
+                _i32(B, Q), _i32(B, Q), *window).as_text()
             mode = _KERNEL_MODES[key] = (
                 "compiled" if "tpu_custom_call" in text else "interpret")
+        return mode
+
+    def _chunk_attention_mode(self) -> str:
+        """What a prefill chunk's attention runs as: "compiled" if the
+        chunk program this engine dispatches carries the ``chunk_attn``
+        Mosaic kernel (ops/pallas/chunk_attention.py), "interpreted" if
+        the Pallas interpreter's plain ops stand in for it (CPU tests
+        only), "xla" if the model's chunk calls no such kernel
+        (models/gpt.py). Observed as ``_paged_kernel_mode`` observes:
+        the program is traced and lowered once, at this engine's
+        shapes and its shortest chunk (one block)."""
+        chunk = _jit_programs(self.cfg)[1]
+        key = (chunk, self._pool_specs, self.max_nb)
+        mode = _KERNEL_MODES.get(key)
+        if mode is None:
+            params, window = self._program_specs(extra=2)
+            traced = chunk.trace(
+                params, _i32(1, self.kv.block_size), *self._pool_specs,
+                _i32(self.max_nb + 3), *window)
+            if "name=chunk_attn" not in str(traced.jaxpr):
+                mode = "xla"
+            elif 'kernel_name = "chunk_attn"' in traced.lower().as_text():
+                mode = "compiled"
+            else:
+                mode = "interpreted"
+            _KERNEL_MODES[key] = mode
         return mode
 
     def stats(self) -> dict:
@@ -1124,6 +1157,10 @@ class LLMEngine:
             "platform": dev.platform,
             "device_kind": dev.device_kind,
             "paged_kernel": self._paged_kernel_mode(),
+            # And a prefill chunk's attention: the ``chunk_attn`` kernel
+            # compiled or interpreted, or "xla" where the model's chunk
+            # has none.
+            "chunk_attention": self._chunk_attention_mode(),
             "steps": self._steps,
             "waiting": len(self._waiting),
             "in_flight": len(self._active),

@@ -43,11 +43,12 @@ kernel (ops/pallas/paged_decode.py ``paged_attention_latent``, named
 ``attn_latent``) scores the query against latent rows as stored and
 sums the same rows as values, and the output comes back through
 ``W_uv``; no key or value of a head is ever made. A prefill chunk goes
-the other way round: it takes the context's latent rows UP through
-``W_ukv`` to keys and values, a block of context at a time, and attends
-with nope+rope / v wide heads, which at a 512-token span costs about
+the other way round: it takes the latent rows of context and span UP
+through ``W_ukv`` to keys and values, ``HEAD_GROUP`` heads at a time,
+and attends with nope+rope / v wide heads in the ``chunk_attn`` kernel
+(ops/pallas/chunk_attention.py), which at a 512-token span costs about
 half the absorbed form's operations (the two cross near 170 tokens).
-Context blocks wholly past the sequence's context are skipped.
+The kernel skips context blocks wholly past the sequence's context.
 
 **A share of the experts.** A configuration says which experts of the
 ``n_routed_experts`` this chip holds (``experts_held`` from
@@ -69,13 +70,11 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import moe
-from ..ops.attention import NEG_INF
 from .laguna import _rmsnorm, _rotary, _swiglu
 
-# Context slots a chunk takes up to keys and values at a time: the
-# block's scores [heads, span, slots] float32 are the chunk program's
-# largest temporary, 134 MB at 64 heads x 512 x 1,024.
-CTX_BLOCK = 1024
+# Heads whose keys and values a chunk makes at a time: 18,432 keys of
+# 128 and as many values are 151 MB a group (all 64 heads: 0.60 GB).
+HEAD_GROUP = 16
 
 
 @dataclass(frozen=True)
@@ -403,63 +402,41 @@ def forward_step(params, tokens, positions, pool, block_tables,
     return logits, ids, pool
 
 
-def _ctx_blocks(nb: int, block_size: int) -> int:
-    """Table entries a context block of a chunk spans: the largest
-    divisor of the table's length within ``CTX_BLOCK`` slots."""
-    want = max(CTX_BLOCK // block_size, 1)
-    return max(d for d in range(1, min(want, nb) + 1) if nb % d == 0)
-
-
-def _chunk_attention(q_nope, q_rope, rows, ctx, ctx_len, w_ukv, block: int,
+def _chunk_attention(q_nope, q_rope, rows, ctx, ctx_len, w_ukv,
                      cfg: KimiK2Config):
     """A span's attention over [pool context ++ span], the UP-PROJECTING
-    form: latent rows go through ``W_ukv`` to a head's keys and values,
-    ``block`` context slots at a time under one online softmax; a block
-    wholly past ``ctx_len`` is skipped. q_nope [n, H, nope], q_rope
-    [n, H, rope]; rows [n, W]: the span's own latent rows (query i at
-    position ctx_len + i); ctx [S, W]: the sequence's gathered pool
-    slots, slot s at position s, real below ctx_len. Returns
-    [n, H, v]."""
+    form: the latent rows of context and span go through ``W_ukv`` to a
+    head's keys and values, ``HEAD_GROUP`` heads at a time (a plain XLA
+    product), and each group attends in the ``chunk_attn`` kernel
+    (ops/pallas/chunk_attention.py): scores stay in VMEM under one
+    online softmax, the one rotary key a token rides as the part of a
+    key every head shares, context blocks past ``ctx_len`` are neither
+    read nor multiplied. q_nope [n, H, nope], q_rope [n, H, rope]; rows
+    [n, W]: the span's own latent rows (query i at position
+    ctx_len + i); ctx [S, W]: the sequence's gathered pool slots, slot
+    s at position s, real below ctx_len. Returns [n, H, v]."""
+    from ..ops.pallas.chunk_attention import chunk_attention, padded_keys
+
     n, H, nope = q_nope.shape
-    rkv, rope, dv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
-    scale, dt = cfg.softmax_scale, q_nope.dtype
+    rkv, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    S, dt = ctx.shape[0], q_nope.dtype
+    lat = jnp.concatenate([ctx.astype(dt), rows, jnp.zeros(
+        (padded_keys(S + n) - S - n, rows.shape[1]), dt)])
+    c_kv, k_rope = lat[:, :rkv], lat[:, rkv:rkv + rope]
+    hg = max(d for d in range(1, min(HEAD_GROUP, H) + 1) if H % d == 0)
 
-    def fold(carry, lat, mask):
-        """Fold keys ``lat`` [s, W] (mask [n, s]: who sees whom)."""
-        m, l, acc = carry
-        kv = jnp.einsum("sc,chd->hsd", lat[:, :rkv].astype(dt), w_ukv)
-        s = (jnp.einsum("nhd,hsd->hns", q_nope, kv[..., :nope],
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("nhd,sd->hns", q_rope,
-                          lat[:, rkv:rkv + rope].astype(dt),
-                          preferred_element_type=jnp.float32)) * scale
-        s = jnp.where(mask[None], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "hns,hsd->hnd", p.astype(dt), kv[..., nope:],
-            preferred_element_type=jnp.float32)
-        return m_new, l * corr + p.sum(-1), acc
+    def group(args):
+        q, w = args                     # [hg, n, nope + rope], [hg, rkv, .]
+        k = jnp.einsum("sc,hcd->hsd", c_kv, w[..., :nope])
+        v = jnp.einsum("sc,hcd->hsd", c_kv, w[..., nope:])
+        return chunk_attention(q[:, None], k, v, ctx_len, ctx_slots=S,
+                               scale=cfg.softmax_scale, k_shared=k_rope)
 
-    carry = (jnp.full((H, n), NEG_INF, jnp.float32),
-             jnp.zeros((H, n), jnp.float32),
-             jnp.zeros((H, n, dv), jnp.float32))
-    if ctx.shape[0]:
-        def step(carry, xs):
-            lat, start = xs
-            seen = jnp.broadcast_to(
-                (start + jnp.arange(block) < ctx_len)[None], (n, block))
-            return jax.lax.cond(start < ctx_len,
-                                lambda c: fold(c, lat, seen),
-                                lambda c: c, carry), None
-
-        carry, _ = jax.lax.scan(step, carry, (
-            ctx.reshape(-1, block, ctx.shape[1]),
-            jnp.arange(0, ctx.shape[0], block, dtype=jnp.int32)))
-    causal = jnp.arange(n)[None, :] <= jnp.arange(n)[:, None]
-    _, l, acc = fold(carry, rows, causal)
-    return (acc / l[..., None]).transpose(1, 0, 2).astype(dt)
+    o = jax.lax.map(group, (
+        jnp.concatenate([q_nope, q_rope], -1).transpose(1, 0, 2)
+        .reshape(H // hg, hg, n, nope + rope),
+        w_ukv.transpose(1, 0, 2).reshape(H // hg, hg, rkv, -1)))
+    return o.reshape(H, n, -1).transpose(1, 0, 2)   # [groups, hg, 1, n, v]
 
 
 def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config):
@@ -479,7 +456,6 @@ def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config):
     nb = block_table.shape[0]
     positions = jnp.minimum(ctx_len + jnp.arange(n, dtype=jnp.int32),
                             cfg.max_seq - 1)[None]
-    block = _ctx_blocks(nb, bs) * bs if nb else 0
     x = params["embed"][tokens]
     new = []
     for li, p in enumerate(params["layers"]):
@@ -490,7 +466,7 @@ def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config):
             ctx = pool[li, block_table].reshape(nb * bs, pool.shape[3])
             with jax.named_scope("attn_latent_chunk"):
                 o = _chunk_attention(q_nope[0], q_rope[0], rows[0], ctx,
-                                     ctx_len, p["w_ukv"], block, cfg)
+                                     ctx_len, p["w_ukv"], cfg)
             return o[None]
 
         x, _ = _block(x, p, cfg, attend, "chunk")

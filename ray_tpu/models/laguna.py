@@ -37,7 +37,9 @@ of that kind's pool ``[layers_of_kind, num_blocks, block_size,
 kv_heads * head_dim]``. The decode step's paged call
 (ops/pallas/paged_decode.py ``paged_attention_stored``) takes its page
 windows from that pool as stored: at head_dim 128 a row is whole lane
-tiles, and no head-major view is made.
+tiles, and no head-major view is made. A prefill chunk gathers its
+table's slots and attends over [them ++ the chunk] in the
+``chunk_attn`` kernel (ops/pallas/chunk_attention.py), both kinds.
 
 The window kind's int32 array (``win``), one row a lane in a decode
 step: ``[table (window_table_len entries), first block, slot block a
@@ -58,7 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import moe
-from ..ops.attention import NEG_INF
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -407,39 +408,32 @@ def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
 
 def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len, base,
                      window: Optional[int]):
-    """A chunk's attention over [pool context ++ chunk], one KV head at
-    a time (a head's scores are [group, c, S + c] float32; all heads at
-    once would be the whole step's largest temporary).
+    """A chunk's attention over [pool context ++ chunk] as the
+    ``chunk_attn`` kernel (ops/pallas/chunk_attention.py): scores stay
+    in VMEM under one online softmax, a KV head's group of query heads
+    in one tile, context blocks past ``ctx_len`` neither read nor
+    multiplied.
 
     q [c, H, d]; k_tok, v_tok [c, kv, d]: the chunk, whose query i sits
     at absolute position ctx_len + i. k_ctx, v_ctx [S, kv, d]: the
     sequence's gathered pool slots, slot s at absolute position
     base + s, real where that is below ctx_len. With a ``window`` a
     query sees only keys less than ``window`` positions behind it."""
+    from ..ops.pallas.chunk_attention import chunk_attention, padded_keys
+
     c, H, d = q.shape
     S, kv = k_ctx.shape[:2]
-    g = H // kv
-    k = jnp.concatenate([k_ctx.astype(q.dtype), k_tok], axis=0)
-    v = jnp.concatenate([v_ctx.astype(q.dtype), v_tok], axis=0)
-    q_pos = ctx_len + jnp.arange(c)
-    k_pos = jnp.concatenate([base + jnp.arange(S), q_pos])
-    seen = jnp.concatenate([k_pos[:S] < ctx_len, jnp.ones((c,), bool)])
-    mask = seen[None, :] & (k_pos[None, :] <= q_pos[:, None])
-    if window is not None:
-        mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
+    pad = jnp.zeros((padded_keys(S + c) - S - c, kv, d), q.dtype)
 
-    def one_head(args):
-        qh, kh, vh = args                       # [g, c, d], [S+c, d] x 2
-        s = jnp.einsum("gcd,kd->gck", qh, kh,
-                       preferred_element_type=jnp.float32) * (d ** -0.5)
-        s = jnp.where(mask[None], s, NEG_INF)
-        pr = jax.nn.softmax(s, axis=-1).astype(qh.dtype)
-        return jnp.einsum("gck,kd->gcd", pr, vh)
+    def head_major(ctx, tok):                   # -> [kv, keys, d]
+        return jnp.concatenate([ctx.astype(q.dtype), tok, pad],
+                               axis=0).transpose(1, 0, 2)
 
-    o = jax.lax.map(one_head, (
-        q.reshape(c, kv, g, d).transpose(1, 2, 0, 3),
-        k.transpose(1, 0, 2), v.transpose(1, 0, 2)))      # [kv, g, c, d]
-    return o.transpose(2, 0, 1, 3).reshape(c, H, d)
+    o = chunk_attention(
+        q.reshape(c, kv, H // kv, d).transpose(1, 2, 0, 3),
+        head_major(k_ctx, k_tok), head_major(v_ctx, v_tok), ctx_len,
+        ctx_slots=S, scale=d ** -0.5, base=base, window=window)
+    return o.transpose(2, 0, 1, 3).reshape(c, H, d)     # [kv, g, c, d] ->
 
 
 def _chunk_layers(params, tokens, positions, k_pool, v_pool, block_table,

@@ -387,7 +387,9 @@ def test_step_ring_and_stats_carry_the_counters(params):
         assert 0.0 <= e["moe_load_max"] <= E / k
         assert rows >= 1
     assert any(e["moe_held_rows"] > 0 for e in steps)
-    assert eng.stats()["kv_util_peak"] > 0
+    st = eng.stats()
+    assert st["kv_util_peak"] > 0
+    assert st["chunk_attention"] == "interpreted"   # the kernel, off the TPU
 
 
 def test_cost_description_prices_the_latent_cache_and_both_paths():

@@ -118,6 +118,7 @@ def test_engine_logits_equal_the_plain_reference(params, budget):
     assert got.shape == want.shape
     assert np.abs(got - want).max() < LOGIT_TOL
     st = eng.stats()
+    assert st["chunk_attention"] == "interpreted"   # the kernel, off the TPU
     assert st["kv_window_blocks_slid"] > 0
     assert 0 < st["kv_window_util_peak"] <= 1
 

@@ -281,6 +281,8 @@ def test_step_breakdown_in_stats_spans_and_ring():
     # The engine names where its pools live and how the kernel ran.
     assert stats["platform"] == "cpu" and stats["device_kind"]
     assert stats["paged_kernel"] == "interpret"
+    # GPT-2's chunk keeps its XLA-made attention: no chunk_attn kernel.
+    assert stats["chunk_attention"] == "xla"
     last = stats["last_step"]
     for key in ("step_ms", "device_ms", "host_gap_ms", "tokens", "flops",
                 "hbm_bytes"):

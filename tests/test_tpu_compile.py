@@ -439,12 +439,12 @@ def test_laguna_chunk_program_pays_for_routed_experts_only(laguna_programs):
     written by one in-place scatter each, after every layer has read
     its context (no copy of a layer's pool, no other writer); the head
     runs on the one row that comes back (no ``[512, vocab]`` logits);
-    and the temporaries stay under half a GB (a KV head's scores at a
-    time)."""
+    and the temporaries stay under half a GB (no scores at all since
+    PR 38: the attention is the ``chunk_attn`` kernel, once a layer)."""
     c = laguna_programs["chunk"]
     text = c.as_text()
     assert text.startswith("HloModule jit_llm_prefill_chunk")
-    assert _mosaic_calls(text) == {"moe_experts_chunk": 8}
+    assert _mosaic_calls(text) == {"moe_experts_chunk": 8, "chunk_attn": 5}
     assert _pool_sized(text, "copy", "transpose", "copy-start",
                        "dynamic-slice", "dynamic-update-slice") == []
     full = f"bf16[2,{LAGUNA_FULL_BLOCKS},{BS},1024]"
@@ -607,17 +607,18 @@ def test_kimi_decode_program_attends_the_latent_pool_as_stored(
 @pytest.mark.parametrize("which", ["chunk", "cold_chunk"])
 def test_kimi_chunk_program_writes_its_span_in_place(kimi_programs, which):
     """A 512-token chunk behind a 17,408-token table (and one from a
-    prompt's start, with no table), ONE program: the context's latent
-    rows go up to keys and values a block of 1,024 slots at a time, so
-    the temporaries stay under half a GB (all 17,408 slots at once are
-    0.57 GB of keys and values alone); the pool is donated, aliased and
+    prompt's start, with no table), ONE program: the latent rows go up
+    to keys and values 16 heads at a time (``chunk_attn`` once a layer,
+    in the loop over head groups), so the temporaries stay under half a
+    GB (all 64 heads at once are 0.59 GB of keys and values alone); the
+    pool is donated, aliased and
     written by ONE in-place scatter after every layer has read it; the
     routed experts are the ``moe_experts_chunk`` kernel; the head runs
     on the one row that comes back."""
     c = kimi_programs[which]
     text = c.as_text()
     assert text.startswith("HloModule jit_llm_prefill_chunk")
-    assert _mosaic_calls(text) == {"moe_experts_chunk": 8}
+    assert _mosaic_calls(text) == {"moe_experts_chunk": 8, "chunk_attn": 5}
     assert _kimi_pool_sized(text, "copy", "transpose", "copy-start",
                             "dynamic-slice", "dynamic-update-slice",
                             "concatenate", "pad") == []
@@ -645,3 +646,98 @@ def test_latent_kernel_compiles_at_the_published_widths(one_chip, q_len):
         S((2, 2048, BS, KIMI_ROW), jnp.bfloat16),
         S((16, KIMI_MAX_SEQ // BS), jnp.int32), lanes, lanes)
     assert "attn_latent" in c.as_text()
+
+
+
+# -- the prefill chunk's attention kernel (PR 38) ------------------------------
+
+def _f32_ending_in(text, *widths):
+    """float32 results, fused bodies included, of three or more
+    dimensions whose last is one of ``widths`` (a key count): what a
+    chunk's scores would be."""
+    import re
+
+    return re.findall(r"f32\[(?:\d+,){2,}(?:%s)\]"
+                      % "|".join(map(str, widths)), text)
+
+
+@pytest.mark.parametrize("kvh, g, n, dk, dv, ds, slots, window", [
+    (8, 6, 512, 128, 128, 0, LAGUNA_MAX_SEQ, None),
+    (8, 8, 320, 128, 128, 0, LAGUNA_MAX_SEQ, None),
+    (8, 8, 512, 128, 128, 0, 34 * BS, 512),
+    (16, 1, 512, 192, 128, 64, KIMI_MAX_SEQ, None),
+    (16, 1, 128, 192, 128, 64, KIMI_MAX_SEQ, None),
+    (16, 1, 384, 192, 128, 0, 0, None),
+], ids=["laguna_g6", "laguna_g8_320", "laguna_window", "kimi_group",
+        "kimi_group_128", "wide_keys_no_table"])
+def test_chunk_attention_kernel_compiles_at_the_cells_shapes(
+        one_chip, kvh, g, n, dk, dv, ds, slots, window):
+    """``chunk_attn`` alone, for the described v5e: Laguna's grouped
+    queries at head width 128 behind 9,216 slots and behind a window's
+    table, a group of 16 of Kimi's heads (keys of 128 + a shared 64,
+    values of 128) behind 17,408 slots, and keys 192 wide with no table
+    at all. ``ctx_len`` and ``base`` are operands: one compile serves
+    every context."""
+    from ray_tpu.ops.pallas.chunk_attention import (chunk_attention,
+                                                    padded_keys)
+
+    S = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    keys = padded_keys(slots + n)
+
+    def attend(q, k, v, shared, ctx_len, base):
+        return chunk_attention(
+            q, k, v, ctx_len, ctx_slots=slots, scale=dk ** -0.5,
+            k_shared=shared if ds else None, base=base, window=window,
+            interpret=False)
+
+    c = _compile(attend, S((kvh, g, n, dk)), S((kvh, keys, dk - ds)),
+                 S((kvh, keys, dv)), S((keys, max(ds, 1))),
+                 S((), jnp.int32), S((), jnp.int32))
+    calls = [line for line in c.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "%chunk_attn" in calls[0].split("=")[0]
+
+
+def test_laguna_chunk_program_keeps_its_scores_out_of_hbm(laguna_programs):
+    """The 512-token chunk behind 9,216 slots attends in ``chunk_attn``
+    once a layer, both kinds (the window kind's table is 544 slots);
+    the parent's ``f32[6 or 8, 512, 9728]`` scores a KV head, and the
+    window kind's ``[.., 512, 1056]``, are in no buffer and no fused
+    body; the temporaries fell from ~0.4 GB to under 0.15."""
+    c = laguna_programs["chunk"]
+    text = c.as_text()
+    assert _mosaic_calls(text)["chunk_attn"] == 5
+    assert _f32_ending_in(text, LAGUNA_MAX_SEQ + 512, 34 * BS + 512) == []
+    assert c.memory_analysis().temp_size_in_bytes < 150e6
+
+
+@pytest.mark.parametrize("which", ["chunk", "cold_chunk"])
+def test_kimi_chunk_program_keeps_its_scores_out_of_hbm(kimi_programs,
+                                                        which):
+    """The up-projecting chunk attends in ``chunk_attn`` once a layer
+    (the call sits in the loop over groups of 16 heads); the parent's
+    ``f32[64, 512, 1024]`` scores a context block, or any scores over
+    the 17,920 keys, are in no buffer and no fused body."""
+    text = kimi_programs[which].as_text()
+    assert _mosaic_calls(text)["chunk_attn"] == 5
+    assert _f32_ending_in(text, 1024, KIMI_MAX_SEQ, KIMI_MAX_SEQ + 512) == []
+
+
+def test_gpt2_chunk_program_has_no_chunk_kernel(one_chip, as_tpu):
+    """GPT-2's chunk keeps its XLA-made attention (ISSUE 38: 2.45 ms
+    behind a 1,024-slot table, nothing to win): lowered for the TPU at
+    the chat cell's shapes, its program holds no Mosaic call at all."""
+    from ray_tpu.llm.engine import _jit_programs
+
+    cfg = gpt.GPT2_SMALL
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
+    pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
+    text = _jit_programs(cfg)[1].lower(
+        params, S((1, 512), jnp.int32), pool, pool,
+        S((MAX_NB + 512 // BS + 2,), jnp.int32)).as_text()
+    assert "module @jit_llm_prefill_chunk " in text
+    assert "tpu_custom_call" not in text and "chunk_attn" not in text
