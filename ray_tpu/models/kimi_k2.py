@@ -39,9 +39,10 @@ pool.
 
 **Two paths over it.** A decode step attends in the ABSORBED form: a
 head's ``q_nope`` goes through ``W_uk`` into the latent space, the
-kernel (ops/pallas/paged_decode.py ``paged_attention_latent``, named
-``attn_latent``) scores the query against latent rows as stored and
-sums the same rows as values, and the output comes back through
+kernel (ops/pallas/paged_fetch.py ``paged_attention_latent``, named
+``attn_latent``; it copies its own pages out of the pool, a run of
+table-adjacent pages in one copy) scores the query against latent rows
+as stored and sums the same rows as values, and the output comes back through
 ``W_uv``; no key or value of a head is ever made. A prefill chunk goes
 the other way round: it takes the latent rows of context and span UP
 through ``W_ukv`` to keys and values, ``HEAD_GROUP`` heads at a time,
@@ -339,24 +340,29 @@ def _head(params, x, cfg: KimiK2Config):
     return jnp.einsum("brm,mv->brv", x, params["head"])
 
 
-COUNTERS = ("moe_experts_hit", "moe_load_max_x1000", "moe_held_rows")
+COUNTERS = ("moe_experts_hit", "moe_load_max_x1000", "moe_held_rows",
+            "kv_pages_in_runs_x1000")
 
 
-def _counters(sizes, rows: int, cfg: KimiK2Config, q: int):
-    """The step's counter rows [3, q] int32 (``COUNTERS``): held
+def _counters(sizes, rows: int, cfg: KimiK2Config, q: int, in_runs):
+    """The step's counter rows [4, q] int32 (``COUNTERS``): held
     experts that got a token (a routed layer's mean), 1000 x the busiest
     held expert's tokens over the DEPLOYMENT's mean an expert (rows x
-    experts a token / all routed experts; the worst layer), and the
-    assignments that fell on the held experts (a layer's mean)."""
-    if not sizes:
-        return jnp.zeros((len(COUNTERS), q), jnp.int32)
-    s = jnp.stack(sizes)                                  # [layers, held]
-    hit = (s > 0).sum() // len(sizes)
-    load = (s.max() * (1000 * cfg.n_routed_experts)) \
-        // (rows * cfg.num_experts_per_tok)
-    held = s.sum() // len(sizes)
-    return jnp.broadcast_to(jnp.stack([hit, load, held])[:, None],
-                            (len(COUNTERS), q)).astype(jnp.int32)
+    experts a token / all routed experts; the worst layer), the
+    assignments that fell on the held experts (a layer's mean), and
+    ``in_runs``: 1000 x the share of the batch's live cache pages that
+    the paged kernel fetches in whole runs."""
+    moe = jnp.zeros((3,), jnp.int32)
+    if sizes:
+        s = jnp.stack(sizes)                              # [layers, held]
+        moe = jnp.stack([
+            (s > 0).sum() // len(sizes),
+            (s.max() * (1000 * cfg.n_routed_experts))
+            // (rows * cfg.num_experts_per_tok),
+            s.sum() // len(sizes)])
+    return jnp.broadcast_to(
+        jnp.append(moe, in_runs)[:, None],
+        (len(COUNTERS), q)).astype(jnp.int32)
 
 
 def forward_step(params, tokens, positions, pool, block_tables,
@@ -368,9 +374,10 @@ def forward_step(params, tokens, positions, pool, block_tables,
     written at ``(layer, slot_blocks, slot_offsets)``, then the lane's
     queries, taken into the latent space, attend the pool as stored.
 
-    Returns (logits [b, q, vocab], ids [b + 3, q] int32, pool): rows b
+    Returns (logits [b, q, vocab], ids [b + 4, q] int32, pool): rows b
     on of ``ids`` are ``COUNTERS``."""
-    from ..ops.pallas.paged_decode import paged_attention_latent
+    from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
+                                          paged_attention_latent)
 
     B, Q = tokens.shape
     nope, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
@@ -398,7 +405,10 @@ def forward_step(params, tokens, positions, pool, block_tables,
             sizes.append(s)
     logits = _head(params, x, cfg)
     ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    ids = jnp.concatenate([ids, _counters(sizes, B * Q, cfg, Q)])
+    ids = jnp.concatenate([ids, _counters(
+        sizes, B * Q, cfg, Q, kv_pages_in_runs_x1000(
+            block_tables, context_lens, pool,
+            score_rows=Q * cfg.num_attention_heads))])
     return logits, ids, pool
 
 

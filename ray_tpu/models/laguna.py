@@ -35,9 +35,10 @@ a sequence's last ``sliding_window`` tokens. The layers are unrolled,
 the kinds interleaved as published; layer i of a kind lives at index i
 of that kind's pool ``[layers_of_kind, num_blocks, block_size,
 kv_heads * head_dim]``. The decode step's paged call
-(ops/pallas/paged_decode.py ``paged_attention_stored``) takes its page
-windows from that pool as stored: at head_dim 128 a row is whole lane
-tiles, and no head-major view is made. A prefill chunk gathers its
+(ops/pallas/paged_fetch.py ``paged_attention_stored``) copies its
+pages out of that pool as stored, a run of table-adjacent pages in one
+copy: at head_dim 128 a row is whole lane tiles, and no head-major view
+is made. A prefill chunk gathers its
 table's slots and attends over [them ++ the chunk] in the
 ``chunk_attn`` kernel (ops/pallas/chunk_attention.py), both kinds.
 
@@ -327,20 +328,23 @@ def _head(params, x, cfg: LagunaConfig):
     return jnp.einsum("brm,mv->brv", x, params["head"])
 
 
-def _counters(counts, n_assignments: int, n_experts: int, q: int):
-    """The step's two counter rows [2, q] int32: experts hit (mean over
-    the routed layers) and 1000 x the busiest expert's tokens over the
-    mean (the worst layer)."""
-    if not counts:
-        return jnp.zeros((2, q), jnp.int32)
-    hit = sum(c[0] for c in counts) // len(counts)
-    load = jnp.max(jnp.stack([c[1] for c in counts]))
-    load = (load * (1000 * n_experts)) // n_assignments
-    return jnp.broadcast_to(jnp.stack([hit, load])[:, None],
-                            (2, q)).astype(jnp.int32)
+def _counters(counts, n_assignments: int, n_experts: int, q: int, in_runs):
+    """The step's counter rows [3, q] int32 (``COUNTERS``): experts hit
+    (mean over the routed layers), 1000 x the busiest expert's tokens
+    over the mean (the worst layer), and ``in_runs``: 1000 x the share
+    of the batch's live full-kind cache pages that the paged kernel
+    fetches in whole runs."""
+    moe = jnp.zeros((2,), jnp.int32)
+    if counts:
+        load = jnp.max(jnp.stack([c[1] for c in counts]))
+        moe = jnp.stack([sum(c[0] for c in counts) // len(counts),
+                         (load * (1000 * n_experts)) // n_assignments])
+    return jnp.broadcast_to(jnp.append(moe, in_runs)[:, None],
+                            (len(COUNTERS), q)).astype(jnp.int32)
 
 
-COUNTERS = ("moe_experts_hit", "moe_load_max_x1000")
+COUNTERS = ("moe_experts_hit", "moe_load_max_x1000",
+            "kv_pages_in_runs_x1000")
 
 
 def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
@@ -353,9 +357,10 @@ def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
     lane's window table, whose context counts from the table's first
     block.
 
-    Returns (logits [b, q, vocab], ids [b + 2, q] int32, k_pool, v_pool,
-    k_win, v_win): rows b and b + 1 of ``ids`` are ``COUNTERS``."""
-    from ..ops.pallas.paged_decode import paged_attention_stored
+    Returns (logits [b, q, vocab], ids [b + 3, q] int32, k_pool, v_pool,
+    k_win, v_win): rows b on of ``ids`` are ``COUNTERS``."""
+    from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
+                                          paged_attention_stored)
 
     B, Q = tokens.shape
     kv, d = cfg.num_key_value_heads, cfg.head_dim
@@ -401,8 +406,15 @@ def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
             counts.append(c)
     logits = _head(params, x, cfg)
     ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    # The full kind's layer with the most query heads has the most score
+    # rows, so the fewest pages a step: the run size no full layer
+    # exceeds.
+    full_heads = max(h for h, t in zip(cfg.num_attention_heads_per_layer,
+                                       cfg.layer_types) if t == FULL)
     ids = jnp.concatenate([ids, _counters(
-        counts, B * Q * cfg.num_experts_per_tok, cfg.num_experts, Q)])
+        counts, B * Q * cfg.num_experts_per_tok, cfg.num_experts, Q,
+        kv_pages_in_runs_x1000(block_tables, context_lens, k_pool, v_pool,
+                               score_rows=Q * full_heads))])
     return logits, ids, k_pool, v_pool, k_win, v_win
 
 

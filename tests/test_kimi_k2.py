@@ -25,7 +25,7 @@ import pytest
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models import kimi_k2, kimi_k2_ref, laguna, serving
 from ray_tpu.ops import moe
-from ray_tpu.ops.pallas import paged_decode
+from ray_tpu.ops.pallas import paged_fetch
 
 # Records every logits row an engine decides a token from, {rid: [row, ...]}.
 from test_laguna import _logits_of
@@ -187,8 +187,8 @@ def test_latent_kernel_equals_its_jnp_reference(q_len):
     q_lens = np.asarray([1, q_len, max(1, q_len - 2)], np.int32)
     args = (jnp.asarray(q), jnp.asarray(pool), 1, jnp.asarray(tables),
             jnp.asarray(ctx), jnp.asarray(q_lens))
-    got = paged_decode.paged_attention_latent(*args, rank=rank, scale=0.3)
-    want = paged_decode.paged_attention_latent_reference(
+    got = paged_fetch.paged_attention_latent(*args, rank=rank, scale=0.3)
+    want = paged_fetch.paged_attention_latent_reference(
         *args, rank=rank, scale=0.3)
     assert got.shape == (b, q_len, heads, rank)
     for lane in range(b):           # rows past a lane's q_len are padding

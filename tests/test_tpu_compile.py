@@ -205,6 +205,28 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
         not in calls[0]
 
 
+def test_gpt2_paged_wrappers_stand_on_the_lines_the_compile_cache_knows():
+    """A Mosaic kernel's serialized body carries the file and line of
+    every frame that led to its ``pallas_call`` and jax's compile cache
+    hashes it: ``paged_verify_attention`` one line up or down and the
+    chat cell's decode program compiles anew on a machine whose cache
+    holds the parent's, which its driver's set-up may not survive
+    (ROADMAP.md A7). PR 42 took 308 lines out of the file above the
+    wrappers and left them where they stood; whoever moves them does
+    it knowingly (A1b deletes the file whole)."""
+    import inspect
+
+    from ray_tpu.ops.pallas import paged_decode
+
+    firsts = {name: inspect.getsourcelines(getattr(paged_decode, name))[1]
+              for name in ("_decode_kernel", "_make_decode_call",
+                           "paged_decode_attention",
+                           "paged_verify_attention")}
+    assert firsts == {"_decode_kernel": 102, "_make_decode_call": 169,
+                      "paged_decode_attention": 530,
+                      "paged_verify_attention": 566}
+
+
 @pytest.fixture(scope="module")
 def chat_decode(one_chip):
     """The chat cell's decode program compiled for the described v5e
@@ -375,14 +397,37 @@ def laguna_programs(one_chip):
         }
 
 
-def _mosaic_calls(text):
-    import collections
+def _mosaic_lines(text):
+    """(name, instruction) of each Mosaic call of a compiled program,
+    the name without its ``.n`` suffix."""
     import re
 
-    return collections.Counter(
-        re.sub(r"\.\d+$", "", line.split("=")[0].strip().lstrip("%"))
-        for line in text.splitlines()
-        if 'custom_call_target="tpu_custom_call"' in line)
+    return [(re.sub(r"\.\d+$", "", line.split("=")[0].strip().lstrip("%")),
+             line) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _mosaic_calls(text):
+    import collections
+
+    return collections.Counter(name for name, _ in _mosaic_lines(text))
+
+
+def _kernel_operands(text, name):
+    """The operand names of each Mosaic call whose result is ``name``,
+    a list a call."""
+    import re
+
+    return [re.findall(r"%[\w.\-]+",
+                       line.split("custom-call(")[1].split(")")[0])
+            for kernel, line in _mosaic_lines(text) if kernel == name]
+
+
+def _fusions_of(text, shape):
+    """How many fusions of a compiled program give ``shape``."""
+    import re
+
+    return len(re.findall(rf"= {re.escape(shape)}\S* fusion\(", text))
 
 
 def _pool_sized(text, *opcodes):
@@ -397,16 +442,34 @@ def test_laguna_decode_program_reads_the_pools_as_stored(laguna_programs):
     by kind (``attn_full`` x 2, ``attn_window`` x 3) and the grouped
     product twice a routed layer (``moe_experts_decode`` x 8), which is
     how the benchmark's readers find them; no ``copy`` or ``transpose``
-    of a layer's pool (the paged call takes its page windows from the
-    pool as stored: no head-major view, which at this pool would move
-    ~6 GB a step); each pool written by one in-place scatter a layer;
-    the ids come back with the two counter rows; and the step's
-    temporaries are a few MB, not a pool's worth."""
+    of a layer's pool (the paged call copies its pages out of the pool
+    as stored: no head-major view, which at this pool would move ~6 GB
+    a step) and each paged call takes its K pool and its V pool ONCE
+    (it fetches its own pages since PR 42; a page window an operand
+    made 32 of them), the run flags of a kind computed once for its
+    layers; all four pools donated and aliased to their outputs, each
+    written by one in-place scatter a layer; the ids come back with the
+    three counter rows; and the step's temporaries are a few MB, not a
+    pool's worth."""
     c = laguna_programs["decode"]
     text = c.as_text()
     assert text.startswith("HloModule jit_llm_decode")
     assert _mosaic_calls(text) == {"attn_full": 2, "attn_window": 3,
                                    "moe_experts_decode": 8}
+    # tables, context lens, q lens, starts, run flags; q; K, V: distinct.
+    for name in ("attn_full", "attn_window"):
+        for operands in _kernel_operands(text, name):
+            assert len(operands) == len(set(operands)) == 8, operands
+    # A flag a group of 8 table slots: 72 a lane of the full kind's
+    # table, 8 of the window kind's (two steps of 32 pages).
+    assert _fusions_of(text, f"s32[{CELL_B},72]") == 1
+    assert _fusions_of(text, f"s32[{CELL_B},8]") == 1
+    # params' leaves, tokens, positions, then the full kind's K and V
+    # (outputs 2, 3 behind the logits and the ids); the window kind's
+    # follow the five int32 arrays between.
+    leaves = laguna_programs["param_leaves"]
+    assert _aliased(text) == {leaves + 2: 2, leaves + 3: 3,
+                              leaves + 9: 4, leaves + 10: 5}
     assert _pool_sized(text, "copy", "transpose", "copy-start",
                        "dynamic-update-slice", "concatenate", "pad") == []
     full = f"bf16[2,{LAGUNA_FULL_BLOCKS},{BS},1024]"
@@ -416,7 +479,7 @@ def test_laguna_decode_program_reads_the_pools_as_stored(laguna_programs):
     entry = text[text.index("\nENTRY "):]
     root = next(line for line in entry.splitlines()
                 if line.lstrip().startswith("ROOT "))
-    assert f"s32[{CELL_B + 2},1]" in root and f"bf16[{CELL_B},1,100352]" \
+    assert f"s32[{CELL_B + 3},1]" in root and f"bf16[{CELL_B},1,100352]" \
         in root
     assert c.memory_analysis().temp_size_in_bytes < 100e6
 
@@ -508,7 +571,7 @@ def test_stored_paged_kernel_compiles_with_grouped_queries(one_chip):
     """``paged_attention_stored`` alone at head_dim 128: 6 query heads
     a KV head (full layers, 48 heads) and 8 (window layers, 64), one
     row a lane and a speculative 3, on the pool as stored."""
-    from ray_tpu.ops.pallas.paged_decode import paged_attention_stored
+    from ray_tpu.ops.pallas.paged_fetch import paged_attention_stored
 
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     pool = S((2, 2048, BS, 8 * 128), jnp.bfloat16)
@@ -582,12 +645,18 @@ def test_kimi_decode_program_attends_the_latent_pool_as_stored(
     five whole lane tiles: with rows of 576 the runtime keeps the pool
     at rest in a layout of its own and this program copies all 2.3 GB
     of it in and out, 2.56 GB of temporaries; PERF.md section 6, PR
-    34); the ids come back with the three counter rows; temporaries
-    are tens of MB."""
+    34); the kernel takes the pool ONCE (it fetches its own pages since
+    PR 42; a page window an operand made 32 of them) and the five
+    layers share one array of run flags; the ids come back with the
+    four counter rows; temporaries are tens of MB."""
     c = kimi_programs["decode"]
     text = c.as_text()
     assert text.startswith("HloModule jit_llm_decode")
     assert _mosaic_calls(text) == {"attn_latent": 5, "moe_experts_decode": 8}
+    # tables, context lens, q lens, run flags; q; the pool: distinct.
+    for operands in _kernel_operands(text, "attn_latent"):
+        assert len(operands) == len(set(operands)) == 6, operands
+    assert _fusions_of(text, f"s32[{CELL_B},{KIMI_MAX_SEQ // BS // 8}]") == 1
     assert _kimi_pool_sized(text, "copy", "transpose", "copy-start",
                             "dynamic-update-slice", "concatenate",
                             "pad") == []
@@ -599,7 +668,7 @@ def test_kimi_decode_program_attends_the_latent_pool_as_stored(
     entry = text[text.index("\nENTRY "):]
     root = next(line for line in entry.splitlines()
                 if line.lstrip().startswith("ROOT "))
-    assert f"s32[{CELL_B + 3},1]" in root and f"bf16[{CELL_B},1,20480]" \
+    assert f"s32[{CELL_B + 4},1]" in root and f"bf16[{CELL_B},1,20480]" \
         in root
     assert c.memory_analysis().temp_size_in_bytes < 100e6
 
@@ -634,7 +703,7 @@ def test_latent_kernel_compiles_at_the_published_widths(one_chip, q_len):
     """``paged_attention_latent`` alone: 64 heads against rows of 640
     (512 latent + 64 rotary + padding), values the first 512 columns,
     one row a lane and a speculative 4."""
-    from ray_tpu.ops.pallas.paged_decode import paged_attention_latent
+    from ray_tpu.ops.pallas.paged_fetch import paged_attention_latent
 
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     lanes = S((16,), jnp.int32)
