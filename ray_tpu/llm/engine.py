@@ -65,9 +65,9 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..models import pack_span, serving
+from ..models import pack_span, serving, step_columns
 from ..util import perfmodel, tracing
-from .kv_cache import (PagedKVCache, PrefixPool, WindowPool,
+from .kv_cache import (BlockChain, PagedKVCache, PrefixPool, WindowPool,
                        window_table_len)
 from .sampling import accept_draws, is_greedy, sample, verify_tokens
 from .spec import make_spec
@@ -100,6 +100,13 @@ class Request:
     # one: the sequence's blocks from ``window_first`` on.
     window_table: List[int] = field(default_factory=list)
     window_first: int = 0
+    # The prompt's chain of prefix-index keys, made once in
+    # add_request (None where the pool indexes nothing); blocks of
+    # generated tokens join it as they fill.
+    chain: Optional[BlockChain] = None
+    # The row of the engine's packed step array this request holds
+    # while it is RUNNING.
+    lane: Optional[int] = None
     context_len: int = 0          # tokens resident in the KV pool
     prefilled_upto: int = 0       # prompt tokens computed OR cache-hit
     cached_tokens: int = 0        # prefix-cache hit span at admission
@@ -152,29 +159,30 @@ def _jit_programs(cfg):
     wrappers instead; donation is per-call, so two live engines sharing
     a program donate only their own pools."""
     model = serving(cfg)
-    # Both programs are donated the pools and return them written. The
-    # full kind's follow the step's three leading arguments and the
-    # chunk's two, as many as the kind says it has (``LayerKind.rows``);
-    # a kind with a window brings its own after the arguments every
-    # model has (five more in the step, one in the chunk).
+    # Both programs are donated the pools and return them written:
+    # every kind's follow the two leading arguments (the parameters and
+    # the program's array), as many as the kinds say they have
+    # (``LayerKind.rows``); in the chunk a kind with a window has its
+    # own after the full kind's table.
     n = [len(kind.rows) for kind in model.kinds] + [0]
-    step_pools = tuple(range(3, 3 + n[0])) \
-        + tuple(range(8 + n[0], 8 + n[0] + n[1]))
+    step_pools = tuple(range(2, 2 + n[0] + n[1]))
     chunk_pools = tuple(range(2, 2 + n[0])) \
         + tuple(range(3 + n[0], 3 + n[0] + n[1]))
 
     def program(name, fn, **jit_kwargs):
         # The name is what a device trace's ``XLA Modules`` line shows
         # (``jit_<name>``); a functools.partial has none of its own.
-        def named(*args):
-            return fn(*args, cfg=cfg)
+        def named(*args, **shapes):
+            return fn(*args, cfg=cfg, **shapes)
 
         named.__name__ = named.__qualname__ = name
         return jax.jit(named, **jit_kwargs)
 
     # The step program is ``jit_llm_decode`` at every q (one row a lane,
-    # or 1 + k under speculation).
-    return (program("llm_decode", model.step, donate_argnums=step_pools),
+    # or 1 + k under speculation): q is a shape of the program, which
+    # takes its one packed array apart by it.
+    return (program("llm_decode", model.step, donate_argnums=step_pools,
+                    static_argnames=("q",)),
             program("llm_prefill_chunk", model.chunk,
                     donate_argnums=chunk_pools))
 
@@ -241,6 +249,7 @@ class LLMEngine:
         # window at once, so a grant there never waits on a preemption
         # (parked prefix tails are evicted for it).
         self.kv_window: Optional[WindowPool] = None
+        self._win_len = 0
         if len(self.model.kinds) > 1:
             nbw = window_table_len(self.model.kinds[1].window, block_size,
                                    self._q_rows)
@@ -256,16 +265,25 @@ class LLMEngine:
         self._kv_window_util_peak = 0.0
         self._window_live = 0         # window blocks lanes hold, last step
         self._counters = {}           # the step program's own, last step
-        # Without a proposer every lane scores one row in every step:
-        # that q_lens lives on the device. Each host array handed to
-        # the program is a copy to the device and, beside the serving
-        # threads, a hand-over of the interpreter lock: one array more
-        # read 0.85 ms a step in the chat cell (PERF.md section 6, PR
-        # 30). Made like the pools, uncommitted: a committed input
-        # would commit the pools the step returns, and every program
-        # that takes them would compile once more.
-        self._one_row_each = None if self._spec is not None else \
-            jax.numpy.ones((self.max_batch,), np.int32)
+        # The decode program's ONE host array, kept for the engine's
+        # lifetime (models/__init__.py ``step_columns``): a RUNNING
+        # request holds a lane's row of it; a block id goes into the
+        # row where the block is granted and out where it is given
+        # back, a step writes a lane's ``head`` columns (its rows'
+        # tokens, positions and slots, its lengths), and a row goes
+        # back to the scratch lane's values when its request leaves.
+        # So the host's part of a step costs what changed since the
+        # step before, and the jitted call hands over one array: each
+        # is a copy to the device and, beside the serving threads, a
+        # hand-over of the interpreter lock, 0.85 ms apiece in the chat
+        # cell (PERF.md section 6, PRs 30 and 46). A host array, so the
+        # pools the step returns stay uncommitted.
+        self._cols = step_columns(self._q_rows, self._win_len)
+        self._inputs = np.zeros(
+            (self.max_batch, self._cols.table + self.max_nb), np.int32)
+        self._inputs[:, self._cols.context_len:self._cols.head] = 1
+        self._free_lanes = list(range(self.max_batch - 1, -1, -1))
+        self._inputs_written = 0      # elements written, this step
 
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -281,6 +299,7 @@ class LLMEngine:
         self._last_prefill_count = 0
         self._finished_count = 0
         self._token_times: Deque[tuple] = collections.deque()  # (t, n)
+        self._tokens_in_window = 0    # the sum of the deque's n
         self._thread: Optional[threading.Thread] = None
         self._stop = False
         self._fatal: Optional[BaseException] = None   # step loop died
@@ -357,12 +376,18 @@ class LLMEngine:
             from ray_tpu.util import tracing
 
             trace_ctx = tracing.current_context.get()
+        # The prompt's block keys are made here, once, on the caller's
+        # thread and outside the engine's lock; a pool that indexes
+        # nothing has no use for them.
+        chain = (BlockChain(self.kv.block_size, prompt)
+                 if self._prefix else None)
         req = Request(rid=next(self._ids), prompt=prompt,
                       max_tokens=int(max_tokens),
                       temperature=float(temperature), top_k=int(top_k),
                       seed=int(seed),
                       stop_tokens=tuple(int(t) for t in stop_tokens),
-                      submit_t=time.time(), trace_ctx=trace_ctx)
+                      submit_t=time.time(), trace_ctx=trace_ctx,
+                      chain=chain)
         with self._cond:
             self._requests[req.rid] = req
             self._waiting.append(req)
@@ -380,7 +405,7 @@ class LLMEngine:
         capacity check)."""
         while self._waiting and len(self._active) < self.max_batch:
             req = self._waiting[0]
-            seq = req.prompt + req.output
+            seq = self._seq(req)
             if self._prefix:
                 # A prefix can be taken up only where every kind of
                 # layer still holds what a query there reads: all of it
@@ -388,8 +413,9 @@ class LLMEngine:
                 upto, tail = None, (0, [])
                 if self.kv_window is not None:
                     upto, *tail = self.kv_window.match_tail(
-                        seq, self.kv.match(seq))
-                got = self.kv.admit(seq, len(seq) + 1, upto=upto)
+                        seq, self.kv.match(seq, req.chain), req.chain)
+                got = self.kv.admit(seq, len(seq) + 1, upto=upto,
+                                    chain=req.chain)
                 if got is None:
                     break
                 grant, cached = got
@@ -447,12 +473,14 @@ class LLMEngine:
         last position's logits and decides there."""
         self._event(req, RUNNING)
         if first is None:
-            return
-        if req.greedy:
+            pass
+        elif req.greedy:
             self._decided["device"] += 1
             self._emit_token(req, first)
         else:
             self._sample_into(req, first)
+        if req.state == RUNNING:        # its first token did not end it
+            self._take_lane(req)
 
     def _release_blocks(self, req: Request):
         """Return req's blocks to the pool. With the prefix pool the
@@ -462,14 +490,73 @@ class LLMEngine:
         instead of recomputing them."""
         seq = None
         if self._prefix:
-            seq = (req.prompt + req.output)[:req.context_len]
-            self.kv.release(req.block_table, seq=seq)
+            seq = self._seq(req, req.context_len)
+            self.kv.release(req.block_table, seq=seq, chain=req.chain)
         else:
             self.kv.free(req.block_table)
         if self.kv_window is not None:
             self.kv_window.release(req.window_table, seq=seq,
-                                   first=req.window_first)
+                                   first=req.window_first, chain=req.chain)
             req.window_table, req.window_first = [], 0
+        self._drop_lane(req)
+
+    @staticmethod
+    def _seq(req: Request, n: Optional[int] = None) -> List[int]:
+        """The request's tokens, prompt then output, or the first ``n``
+        of them: the prompt itself where that is all of it (read, never
+        written, by every caller)."""
+        if n is None:
+            return req.prompt + req.output if req.output else req.prompt
+        k = len(req.prompt)
+        if n == k:
+            return req.prompt
+        return req.prompt[:n] if n < k else req.prompt + req.output[:n - k]
+
+    # -- the decode program's array ----------------------------------------
+
+    def _take_lane(self, req: Request):
+        """A request that starts to decode takes a free row of the
+        packed array and writes its tables there, once."""
+        req.lane = self._free_lanes.pop()
+        t0 = self._cols.table
+        table = req.block_table
+        self._inputs[req.lane, t0:t0 + len(table)] = table
+        self._inputs_written += len(table)
+        if self.kv_window is not None:
+            self._write_window(req, 0)
+
+    def _write_window(self, req: Request, held: int):
+        """The lane's window table and its first block, whole: the
+        table starts where the window does, so a slide moves every
+        entry. ``held`` is how many it held before."""
+        c, row = self._cols, self._inputs[req.lane]
+        table = req.window_table
+        n = max(len(table), held)
+        row[c.win_first] = req.window_first
+        row[c.win_table:c.win_table + n] = table + [0] * (n - len(table))
+        self._inputs_written += 1 + n
+
+    def _blocks_left(self, req: Request, col: int, table, n: int):
+        """``n`` blocks went back to the pool off the end of ``table``
+        (a rollback's ``truncate``): out of the lane's row too."""
+        if n and req.lane is not None:
+            at = col + len(table)
+            self._inputs[req.lane, at:at + n] = 0
+            self._inputs_written += n
+
+    def _drop_lane(self, req: Request):
+        """The request leaves the decode batch (a finish, a
+        preemption): its row goes back to the scratch lane's values,
+        block 0 and context 1, and to the free lanes."""
+        if req.lane is None:
+            return
+        c, row = self._cols, self._inputs[req.lane]
+        held = c.table + len(req.block_table)
+        row[:held] = 0
+        row[c.context_len:c.head] = 1
+        self._inputs_written += held
+        self._free_lanes.append(req.lane)
+        req.lane = None
 
     def _preempt(self, req: Request):
         """Evict req from the batch, release its blocks (registered in
@@ -501,6 +588,7 @@ class LLMEngine:
         if req.block_table:
             self._release_blocks(req)
             req.block_table = []
+        req.chain = None            # nothing walks it again
         req.finish_reason = reason
         req.finish_t = time.time()
         self._finished_count += 1
@@ -529,6 +617,7 @@ class LLMEngine:
         if req.first_token_t is None:
             req.first_token_t = now
         self._token_times.append((now, 1))
+        self._tokens_in_window += 1
         while req.emitted < len(req.output):
             req.out_q.put(req.output[req.emitted])
             req.emitted += 1
@@ -567,7 +656,7 @@ class LLMEngine:
         for req in prefills:
             with perf.phase("llm.prefill.host"):
                 t0 = time.time()
-                seq = req.prompt + req.output
+                seq = self._seq(req)
                 T = len(seq)
                 if req.prefilled_upto >= T:
                     # Full prefix-cache hit: zero prefill compute.
@@ -649,10 +738,11 @@ class LLMEngine:
                     if self._prefix:
                         # Index the prompt's chunks for later arrivals
                         # (shared system prompts hit from here on).
-                        self.kv.register(seq, req.block_table)
+                        self.kv.register(seq, req.block_table, req.chain)
                         if self.kv_window is not None:
                             self.kv_window.register_tail(
-                                seq, req.window_table, req.window_first)
+                                seq, req.window_table, req.window_first,
+                                req.chain)
                     self._activate(req, first)
                 if req.trace_ctx is not None:
                     dur = time.time() - t0
@@ -730,18 +820,24 @@ class LLMEngine:
         itself was preempted (the last resort when it is the newest —
         and possibly only — sequence)."""
         bs = self.kv.block_size
-        kinds = [(self.kv, req.block_table, 0)]
+        c, row = self._cols, self._inputs[req.lane]
+        kinds = [(self.kv, req.block_table, 0, c.table)]
         if self.kv_window is not None:
             # The window slides first: blocks the next query no longer
             # keeps go back before new ones are granted, so a lane never
             # holds more than its window's worth.
+            held, first = len(req.window_table), req.window_first
             req.window_first = self.kv_window.slide(
-                req.window_table, req.window_first, req.context_len)
+                req.window_table, first, req.context_len)
+            if req.window_first != first:
+                self._write_window(req, held)
             kinds.append((self.kv_window, req.window_table,
-                          req.window_first))
+                          req.window_first, c.win_table))
+        # A block id goes into the lane's row where it is granted (or
+        # split off): the row is the table, kept.
         for j in range(n):
             slot = req.context_len + j
-            for kv, table, first in kinds:
+            for kv, table, first, col in kinds:
                 bi = slot // bs - first
                 while True:
                     if bi >= len(table):
@@ -751,6 +847,8 @@ class LLMEngine:
                                 return False
                             continue
                         table.extend(grant)
+                        row[col + bi] = grant[0]
+                        self._inputs_written += 1
                     if self._prefix:
                         bid = table[bi]
                         if kv.needs_cow(bid, slot % bs):
@@ -759,7 +857,8 @@ class LLMEngine:
                                 if not self._preempt_for(req):
                                     return False
                                 continue
-                            table[bi] = nb
+                            table[bi] = row[col + bi] = nb
+                            self._inputs_written += 1
                     break
         return True
 
@@ -835,30 +934,25 @@ class LLMEngine:
             B, n_live = self.max_batch, len(batch)
             Q = self._q_rows
             bs = self.kv.block_size
-            # Padded lanes, and rows past a lane's q_lens: scratch block
-            # 0, offset 0, position 0; a padded lane is one row of
-            # context 1 — attention over the scratch block's garbage is
-            # masked-in but its logits are never read.
-            tokens = np.zeros((B, Q), np.int32)
-            positions = np.zeros((B, Q), np.int32)
-            slot_blocks = np.zeros((B, Q), np.int32)
-            slot_offsets = np.zeros((B, Q), np.int32)
-            context_lens = np.ones((B,), np.int32)
-            q_lens = np.ones((B,), np.int32)
-            tables = np.zeros((B, self.max_nb), np.int32)
-            # The window kind's one array a step: a lane's table, the
-            # table's first block in the sequence, a slot block a row.
             kvw = self.kv_window
-            win = None
             if kvw is not None:
-                win = np.zeros((B, self._win_len + 1 + Q), np.int32)
                 self._window_live = kvw.capacity - kvw.num_free
-            ctx, rows_per_lane = [], []
-            for i, req in enumerate(batch):
+            # What a step writes of the kept array: each live lane's
+            # ``head`` columns (``step_columns``), in one assignment.
+            # Its tables are in its row already (``_take_lane``,
+            # ``_ensure_slots``); the other lanes' rows, and a lane's
+            # rows past its q_len, are the scratch lane's values
+            # (block 0, offset 0, position 0; a padded lane is one row
+            # of context 1, whose attention over the scratch block's
+            # garbage is masked-in but whose logits are never read).
+            lanes, heads, ctx, rows_per_lane = [], [], [], []
+            for req in batch:
                 slot = req.context_len
                 table = req.block_table
                 p = props[req.rid]
                 n = 1 + len(p)
+                pad = (0,) * (Q - n)
+                rows = range(slot, slot + n)
                 # Row 0: steady-state lanes feed their last sampled
                 # token; a FULL prefix-cache hit enters decode holding
                 # the last sequence position back (nothing was computed
@@ -866,28 +960,23 @@ class LLMEngine:
                 # write-then-attend then recomputes its logits for the
                 # first sample. Rows 1..n-1 feed the lane's proposals
                 # (the proposal budget keeps them inside max_seq).
-                tokens[i, 0] = (req.prompt[slot] if slot < len(req.prompt)
-                                else req.output[slot - len(req.prompt)])
-                if p:
-                    tokens[i, 1:n] = p
-                for j in range(n):
-                    positions[i, j] = slot + j
-                    slot_blocks[i, j] = table[(slot + j) // bs]
-                    slot_offsets[i, j] = (slot + j) % bs
-                context_lens[i] = slot + n
-                q_lens[i] = n
-                tables[i, :len(table)] = table
-                if win is not None:
+                head = [req.prompt[slot] if slot < len(req.prompt)
+                        else req.output[slot - len(req.prompt)], *p, *pad,
+                        *rows, *pad,
+                        *[table[s // bs] for s in rows], *pad,
+                        *[s % bs for s in rows], *pad]
+                if kvw is not None:
                     wt, first = req.window_table, req.window_first
-                    win[i, :len(wt)] = wt
-                    win[i, self._win_len] = first
-                    for j in range(n):
-                        win[i, self._win_len + 1 + j] = \
-                            wt[(slot + j) // bs - first]
+                    head += [*[wt[s // bs - first] for s in rows], *pad]
+                head += [slot + n, n]
+                lanes.append(req.lane)
+                heads.append(head)
                 ctx.append(slot + n)
                 rows_per_lane.append(n)
                 if spec is not None:
                     spec.verify(req.rid, len(p))
+            self._inputs[lanes, :self._cols.head] = heads
+            self._inputs_written += n_live * self._cols.head
             if spec is not None:
                 spec.verify_steps += 1
             # Priced honestly about speculation's bet: every scored row
@@ -900,14 +989,13 @@ class LLMEngine:
         # the program's last output: the argmax of its logits); the
         # fetch that follows is then a copy of max_batch x Q ints,
         # charged to the host, and the logits stay where they are unless
-        # a lane samples with a temperature.
+        # a lane samples with a temperature. ONE host array goes in
+        # beside the parameters and the pools: the kept one, which
+        # nothing writes again before the ids are ready.
         with perf.device("llm.decode.device") as dev:
-            window = () if kvw is None else (*kvw.pools, win)
+            window = () if kvw is None else kvw.pools
             logits, ids, *pools = self._decode(
-                self.params, tokens, positions, *self.kv.pools,
-                tables, context_lens,
-                q_lens if spec is not None else self._one_row_each,
-                slot_blocks, slot_offsets, *window)
+                self.params, self._inputs, *self.kv.pools, *window, q=Q)
             dev.dispatched()
             self._take_back(pools)
             jax.block_until_ready(ids)
@@ -935,15 +1023,16 @@ class LLMEngine:
         for i, req in enumerate(batch):
             p = props[req.rid]
             slot = req.context_len
+            lane = req.lane             # a finish below gives it back
             if greedy[i]:
                 # The target's greedy draw at row j is the id the
                 # program returned for it: acceptance is the same
                 # equality check, on integers.
-                n_acc, emitted = accept_draws(ids[i].__getitem__, p)
+                n_acc, emitted = accept_draws(ids[lane].__getitem__, p)
             else:
                 with sampling:
                     n_acc, emitted = verify_tokens(
-                        rows[i, :1 + len(p)], p,
+                        rows[lane, :1 + len(p)], p,
                         temperature=req.temperature, top_k=req.top_k,
                         seed=req.seed,
                         start_pos=len(req.prompt) + len(req.output))
@@ -972,9 +1061,14 @@ class LLMEngine:
                     freed = (self.kv.truncate(req.block_table,
                                               req.context_len)
                              if req.block_table else [])
+                    self._blocks_left(req, self._cols.table,
+                                      req.block_table, len(freed))
                     if kvw is not None and req.window_table:
-                        kvw.truncate(req.window_table, req.context_len,
-                                     req.window_first)
+                        self._blocks_left(
+                            req, self._cols.win_table, req.window_table,
+                            len(kvw.truncate(
+                                req.window_table, req.context_len,
+                                req.window_first)))
                     spec.rollback(req.rid, len(p) - n_acc, len(freed))
         self._decided["host"] += decided[0]
         self._decided["device"] += decided[1]
@@ -1019,6 +1113,7 @@ class LLMEngine:
             self._chunk_log = []
             self._counts = (0, 0, 0, 0)
             self._counters = {}
+            self._inputs_written = 0
             preempted0 = self._preempt_count
             with perf.step("llm.step", self._steps + 1):
                 with perf.phase("llm.admit"):
@@ -1065,6 +1160,12 @@ class LLMEngine:
                        "prefill_chunks": chunks,
                        "waiting": len(self._waiting),
                        "preempted": self._preempt_count - preempted0,
+                       # Elements of the decode program's kept array
+                       # that the host wrote in this step, and the
+                       # array's size: the step's host side costs what
+                       # changed, not what is live.
+                       "inputs_written": self._inputs_written,
+                       "inputs_size": self._inputs.size,
                        **window, **self._step_counters()})
             if entry is not None:
                 self._arrived = 0       # callers wait for this lock
@@ -1080,27 +1181,30 @@ class LLMEngine:
     # -- introspection / telemetry ----------------------------------------
 
     def tokens_per_s(self, window: float = 5.0) -> float:
+        """Tokens emitted in the last ``window`` seconds over the time
+        since the oldest of them: the deque's sum is kept beside it
+        (added on append, taken off on popleft), so a reading costs
+        what fell out of the window, not what is in it."""
         now = time.time()
-        while self._token_times and self._token_times[0][0] < now - window:
-            self._token_times.popleft()
-        if not self._token_times:
+        times = self._token_times
+        while times and times[0][0] < now - window:
+            self._tokens_in_window -= times.popleft()[1]
+        if not times:
             return 0.0
-        span = max(now - self._token_times[0][0], 1e-3)
-        return sum(n for _, n in self._token_times) / span
+        return self._tokens_in_window / max(now - times[0][0], 1e-3)
 
-    def _program_specs(self, *lead, extra: int):
+    def _program_specs(self):
         """What both programs take, as shapes: (the parameters, the
-        window kind's pools and its int32 array ``[*lead, window table
-        + extra]``, or nothing where the model has no such kind)."""
+        window kind's pools, or none where the model has no such
+        kind)."""
         params = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=x.sharding),
             self.params)
         if self.kv_window is None:
             return params, ()
-        return params, (*(jax.ShapeDtypeStruct(p.shape, p.dtype)
-                          for p in self.kv_window.pools),
-                        _i32(*lead, self._win_len + extra))
+        return params, tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                             for p in self.kv_window.pools)
 
     def _paged_kernel_mode(self) -> str:
         """"compiled" if the decode program this engine steps with
@@ -1113,11 +1217,10 @@ class LLMEngine:
         key = (self._decode, self._pool_specs, B, Q)
         mode = _KERNEL_MODES.get(key)
         if mode is None:
-            params, window = self._program_specs(B, extra=1 + Q)
+            params, window = self._program_specs()
             text = self._decode.lower(
-                params, _i32(B, Q), _i32(B, Q), *self._pool_specs,
-                _i32(B, self.max_nb), _i32(B), _i32(B),
-                _i32(B, Q), _i32(B, Q), *window).as_text()
+                params, _i32(*self._inputs.shape), *self._pool_specs,
+                *window, q=Q).as_text()
             mode = _KERNEL_MODES[key] = (
                 "compiled" if "tpu_custom_call" in text else "interpret")
         return mode
@@ -1135,7 +1238,9 @@ class LLMEngine:
         key = (chunk, self._pool_specs, self.max_nb)
         mode = _KERNEL_MODES.get(key)
         if mode is None:
-            params, window = self._program_specs(extra=2)
+            params, window = self._program_specs()
+            if window:      # the kind's array: its table, first, one block
+                window += (_i32(self._win_len + 2),)
             traced = chunk.trace(
                 params, _i32(1, self.kv.block_size), *self._pool_specs,
                 _i32(self.max_nb + 3), *window)

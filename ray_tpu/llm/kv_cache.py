@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import itertools
 import math
 import time
 from typing import Deque, Dict, List, Optional, Tuple
@@ -265,6 +266,91 @@ class PagedKVCache:
             for pool in self.pools)
 
 
+_SLICES: Dict[int, List[slice]] = {}
+
+
+def _block_slices(block_size: int, n: int) -> List[slice]:
+    """``slice(i * block_size, (i + 1) * block_size)`` for i < n, from
+    one list a block size that only grows."""
+    made = _SLICES.setdefault(block_size, [])
+    for i in range(len(made), n):
+        made.append(slice(i * block_size, (i + 1) * block_size))
+    return made[:n]
+
+
+class BlockChain:
+    """The chain of block keys of ONE token sequence that only grows (a
+    request's prompt, then what it generates behind it): a whole block
+    is ``(key, parent, chunk)`` with ``key = hash((parent, chunk))``,
+    the first block's parent 0, and a ragged tail the same over the
+    remainder: the prefix index's keys. Each whole block is hashed
+    ONCE, when it is first reached: the engine makes a request's chain
+    in ``add_request``, on the caller's thread, and every later
+    ``match`` / ``admit`` / ``register`` / ``match_tail`` /
+    ``register_tail`` of that request reads it (llm/engine.py); blocks
+    of generated tokens join as they fill. The last ragged tail asked
+    for is kept too (a prompt's is asked for again at its admission).
+
+    What a chain KEEPS is two containers, whatever its length: the
+    keys in one list and the hashed tokens in one tuple; a block's
+    ``(key, parent, chunk)`` is made as it is walked and dropped with
+    the step of the walk. A tuple or two a block kept for the request's
+    life is what the collector counts: it read ~2,100 more live
+    containers a 16.8k-token request and ran twice as long a second
+    (PERF.md section 6, PR 46)."""
+
+    __slots__ = ("block_size", "keys", "tokens", "_tail")
+
+    def __init__(self, block_size: int, tokens=()):
+        self.block_size = int(block_size)
+        self.keys: List[int] = []       # a whole block's key, in order
+        self.tokens: Tuple = ()         # the tokens of those blocks
+        self._tail: Tuple = (0, None)   # (length, the tail's entry)
+        if tokens:
+            self.reach(tokens, len(tokens))
+
+    def reach(self, seq, n: int) -> "BlockChain":
+        """Hash the whole blocks of ``seq[:n]`` that are not hashed yet,
+        and its ragged tail if it is not the one kept. ``seq`` must
+        begin with the tokens this chain was made from."""
+        bs, keys = self.block_size, self.keys
+        nfull, have = n // bs, len(keys)
+        if have < nfull:
+            parent = keys[-1] if keys else 0
+            span = tuple(seq[have * bs:nfull * bs])
+            for i in range(0, len(span), bs):
+                parent = hash((parent, span[i:i + bs]))
+                keys.append(parent)
+            self.tokens += span
+        if n % bs and self._tail[0] != n:
+            parent = keys[nfull - 1] if nfull else 0
+            rem = tuple(seq[nfull * bs:n])
+            self._tail = (n, (hash((parent, rem)), parent, rem))
+        return self
+
+    def block(self, i: int, n: int) -> Tuple:
+        """``(key, parent, chunk)`` of block ``i`` of the first ``n``
+        tokens (``reach``ed before): a whole block, or the ragged tail
+        behind the last whole one."""
+        bs, keys = self.block_size, self.keys
+        if i < n // bs:
+            return (keys[i], keys[i - 1] if i else 0,
+                    self.tokens[i * bs:(i + 1) * bs])
+        return self._tail[1]
+
+    def blocks(self, seq, n: Optional[int] = None):
+        """Iterate ``(key, parent, chunk)`` a block of ``seq[:n]`` (all
+        of it by default), the ragged tail last; each is made as the
+        walk comes to it."""
+        n = len(seq) if n is None else n
+        self.reach(seq, n)
+        bs, keys = self.block_size, self.keys
+        walk = zip(keys, itertools.chain((0,), keys),
+                   map(self.tokens.__getitem__,
+                       _block_slices(bs, n // bs)))
+        return itertools.chain(walk, (self._tail[1],)) if n % bs else walk
+
+
 class PrefixPool(PagedKVCache):
     """Ref-counted, hash-indexed prefix cache over the paged pool
     (vLLM-style automatic prefix caching, Kwon et al. SOSP '23).
@@ -309,14 +395,16 @@ class PrefixPool(PagedKVCache):
         # ref-0 registered blocks, eviction order (oldest first).
         self._lru: "collections.OrderedDict[int, None]" = \
             collections.OrderedDict()
-        # Memoized chain walks (verified against the stored tuple, so
-        # hash collisions cannot alias). _match_cache: seq-hash ->
-        # (seqt, bids, covered); _reg_cache: seq-hash -> seqt for
-        # sequences whose FULL chain is known indexed. Both are
-        # invalidated whenever an eviction drops index keys; the match
-        # cache additionally whenever registration adds them.
-        self._match_cache: Dict[int, Tuple] = {}
-        self._reg_cache: Dict[int, Tuple] = {}
+        # The last walk of a request's chain, (chain, tokens, bids,
+        # covered): ``match`` then ``admit`` of one admission walk
+        # once, as does a head-of-line request that waits a step. A hit
+        # is the same chain object at the same length; dropped whenever
+        # an eviction takes index keys away or a registration adds
+        # them.
+        self._matched: Optional[Tuple] = None
+        # Blocks with more than one live reference, counted where a
+        # refcount crosses 1 <-> 2 (the per-step gauge reads it).
+        self._shared = 0
         self.events: Deque[tuple] = collections.deque(maxlen=4096)
         self.hit_tokens = 0
         self.lookup_tokens = 0
@@ -360,8 +448,7 @@ class PrefixPool(PagedKVCache):
             if e is not None and e[2] == bid:
                 del self._index[key]
         self._free.append(bid)
-        self._match_cache.clear()       # cached chains may now be broken
-        self._reg_cache.clear()
+        self._matched = None            # its chain may now be broken
         self.evictions += 1
         self._event("evict", block=bid)
 
@@ -372,51 +459,46 @@ class PrefixPool(PagedKVCache):
 
     # -- prefix index ------------------------------------------------------
 
-    def match(self, seq: List[int]) -> int:
-        """Tokens of ``seq`` the index covers (nothing acquired)."""
-        return self._match(seq)[1]
+    def _chain(self, seq, chain: Optional[BlockChain] = None,
+               n: Optional[int] = None) -> BlockChain:
+        """The chain of block keys of ``seq``, hashed as far as its
+        first ``n`` tokens: the request's own where it has one (no
+        block it holds is hashed again), made from the tokens
+        otherwise."""
+        if chain is None:
+            chain = BlockChain(self.block_size)
+        return chain.reach(seq, len(seq) if n is None else n)
 
-    def _match(self, seq: List[int]) -> Tuple[List[int], int]:
+    def match(self, seq: List[int],
+              chain: Optional[BlockChain] = None) -> int:
+        """Tokens of ``seq`` the index covers (nothing acquired)."""
+        return self._match(seq, chain)[1]
+
+    def _match(self, seq: List[int], chain: Optional[BlockChain] = None
+               ) -> Tuple[List[int], int]:
         """Longest cached chain for ``seq``: (block ids, tokens
         covered). Full block-sized chunks must match contiguously; the
         ragged tail only matches as the exact whole remainder."""
-        bs = self.block_size
         index = self._index
-        seqt = tuple(seq)             # one tuple; slices below are cheap
-        sh = hash(seqt)
-        hit = self._match_cache.get(sh)
-        if hit is not None and hit[0] == seqt:
-            return list(hit[1]), hit[2]
-        parent = 0
+        hit = self._matched
+        if hit is not None and hit[0] is chain and hit[1] == len(seq):
+            return list(hit[2]), hit[3]
         bids: List[int] = []
         covered = 0
-        nfull = len(seqt) // bs
-        for _ in range(nfull):
-            chunk = seqt[covered:covered + bs]
-            key = hash((parent, chunk))
+        for key, parent, chunk in self._chain(seq, chain).blocks(seq):
             e = index.get(key)
             if e is None or e[0] != parent or e[1] != chunk \
-                    or e[3] != bs:
+                    or e[3] != len(chunk):
                 break
             bids.append(e[2])
-            covered += bs
-            parent = key
-        else:
-            rem = seqt[covered:]
-            if rem:
-                key = hash((parent, rem))
-                e = index.get(key)
-                if e is not None and e[0] == parent and e[1] == rem \
-                        and e[3] == len(rem):
-                    bids.append(e[2])
-                    covered += len(rem)
-        if len(self._match_cache) > 256:
-            self._match_cache.clear()
-        self._match_cache[sh] = (seqt, tuple(bids), covered)
+            covered += len(chunk)
+        if chain is not None:
+            self._matched = (chain, len(seq), tuple(bids), covered)
         return bids, covered
 
     def admit(self, seq: List[int], need_tokens: int,
-              upto: Optional[int] = None
+              upto: Optional[int] = None,
+              chain: Optional[BlockChain] = None
               ) -> Optional[Tuple[List[int], int]]:
         """Build a block table for a sequence: cached-chain blocks are
         acquired (ref++), the remainder freshly allocated. Returns
@@ -425,7 +507,7 @@ class PrefixPool(PagedKVCache):
         (a whole number of blocks below the match) cuts the match
         short: another kind of layer holds less of this prefix
         (``WindowPool.match_tail``)."""
-        bids, cached = self._match(seq)
+        bids, cached = self._match(seq, chain)
         if upto is not None and upto < cached:
             bids, cached = bids[:upto // self.block_size], upto
         self.lookup_tokens += len(seq)
@@ -434,6 +516,8 @@ class PrefixPool(PagedKVCache):
             r = ref.get(b, 0)
             if r == 0:
                 lru.pop(b, None)
+            elif r == 1:
+                self._shared += 1
             ref[b] = r + 1
         fresh_n = self.blocks_for_tokens(need_tokens) - len(bids)
         grant = self.alloc(fresh_n) if fresh_n else []
@@ -445,53 +529,34 @@ class PrefixPool(PagedKVCache):
             self._event("share", blocks=len(bids), tokens=cached)
         return bids + grant, cached
 
-    def register(self, seq: List[int], table: List[int]) -> None:
+    def register(self, seq: List[int], table: List[int],
+                 chain: Optional[BlockChain] = None) -> None:
         """Index a sequence's computed chunks so later requests can
         reuse them. First writer wins per key; blocks already indexed
         for this chain are left as-is."""
-        bs = self.block_size
         index = self._index
-        seqt = tuple(seq)
-        sh = hash(seqt)
-        if self._reg_cache.get(sh) == seqt:
-            return                    # full chain known indexed already
-        parent = 0
         newly = 0
-        nfull = len(seqt) // bs
-        complete = True
-        for i in range(nfull + 1):
-            if i >= len(table):
-                complete = False      # table shorter than the chain
-                break
-            if i < nfull:
-                chunk = seqt[i * bs:(i + 1) * bs]
-            else:
-                chunk = seqt[nfull * bs:]
-                if not chunk:
-                    break
-            key = hash((parent, chunk))
+        # A table shorter than the chain indexes what it holds.
+        for (key, parent, chunk), bid in zip(
+                self._chain(seq, chain).blocks(seq), table):
             if key not in index:
-                index[key] = (parent, chunk, table[i], len(chunk))
-                self._keys_of.setdefault(table[i], []).append(key)
+                index[key] = (parent, chunk, bid, len(chunk))
+                self._keys_of.setdefault(bid, []).append(key)
                 newly += 1
-            parent = key
         if newly:
             self.registrations += newly
-            self._match_cache.clear()  # longer chains may now match
-            self._event("register", blocks=newly, tokens=len(seqt))
-        if complete:
-            if len(self._reg_cache) > 256:
-                self._reg_cache.clear()
-            self._reg_cache[sh] = seqt
+            self._matched = None       # a longer chain may now match
+            self._event("register", blocks=newly, tokens=len(seq))
 
     def release(self, blocks: List[int],
-                seq: Optional[List[int]] = None) -> None:
+                seq: Optional[List[int]] = None,
+                chain: Optional[BlockChain] = None) -> None:
         """Drop one reference per block. ``seq`` (the tokens actually
         resident — prompt + generated, truncated to context_len)
         registers the now-computed chunks first, so multi-turn
         continuations and re-admissions hit them."""
         if seq:
-            self.register(seq, blocks)
+            self.register(seq, blocks, chain)
         self._unref(blocks)
 
     def _unref(self, blocks: List[int]) -> None:
@@ -502,7 +567,9 @@ class PrefixPool(PagedKVCache):
             if r <= 0:
                 raise ValueError(f"double free of KV block {b}")
             ref[b] = r - 1
-            if r == 1:
+            if r == 2:
+                self._shared -= 1
+            elif r == 1:
                 if keys_of.get(b):
                     lru[b] = None           # parked, matchable, evictable
                 else:
@@ -545,7 +612,10 @@ class PrefixPool(PagedKVCache):
         return self.hit_tokens / max(1, self.lookup_tokens)
 
     def shared_blocks(self) -> int:
-        return sum(1 for r in self._ref.values() if r > 1)
+        """Blocks more than one sequence holds: a count kept where a
+        refcount crosses 1 <-> 2 (``admit``, ``_unref``,
+        ``WindowPool.acquire``), not a walk of every live block."""
+        return self._shared
 
     def prefix_stats(self) -> dict:
         return {
@@ -625,20 +695,8 @@ class WindowPool(PrefixPool):
 
     # -- prefix index ------------------------------------------------------
 
-    def _chain(self, seq) -> List[Tuple]:
-        """(key, parent, chunk) a block of ``seq``, the ragged tail
-        last: the PrefixPool's chain keys."""
-        bs = self.block_size
-        seqt = tuple(seq)
-        out, parent = [], 0
-        for i in range(0, len(seqt), bs):
-            chunk = seqt[i:i + bs]
-            key = hash((parent, chunk))
-            out.append((key, parent, chunk))
-            parent = key
-        return out
-
-    def match_tail(self, seq: List[int], cached: int
+    def match_tail(self, seq: List[int], cached: int,
+                   chain: Optional[BlockChain] = None
                    ) -> Tuple[int, int, List[int]]:
         """The longest prefix of ``seq``, at most ``cached`` tokens (the
         full kind's match: whole blocks, or all of ``seq``), whose
@@ -647,14 +705,14 @@ class WindowPool(PrefixPool):
         ``acquire``), or ``(0, 0, [])``. Below ``cached`` only block
         boundaries are tried."""
         bs, index = self.block_size, self._index
-        chain = self._chain(seq[:cached])
+        chain = self._chain(seq, chain, cached)
         n = cached
         while n > 0:
             last = -(-n // bs) - 1
             first = max(0, n - self.window) // bs
             bids = []
             for i in range(last, first - 1, -1):
-                key, parent, chunk = chain[i]
+                key, parent, chunk = chain.block(i, cached)
                 e = index.get(key)
                 if e is None or e[0] != parent or e[1] != chunk \
                         or e[3] != len(chunk):
@@ -663,7 +721,6 @@ class WindowPool(PrefixPool):
             else:
                 return n, first, bids[::-1]
             n = (n - 1) // bs * bs
-            chain = chain[:n // bs]
         return 0, 0, []
 
     def _unref(self, blocks: List[int]) -> None:
@@ -693,6 +750,8 @@ class WindowPool(PrefixPool):
             if r == 0:
                 self._lru.pop(b, None)
                 self._cold.pop(b, None)
+            elif r == 1:
+                self._shared += 1
             self._taken.add(b)
             self._ref[b] = r + 1
         if blocks:
@@ -700,13 +759,16 @@ class WindowPool(PrefixPool):
                         tokens=len(blocks) * self.block_size)
 
     def register_tail(self, seq: List[int], table: List[int],
-                      first: int) -> None:
+                      first: int,
+                      chain: Optional[BlockChain] = None) -> None:
         """Index the blocks of ``table`` (the sequence's blocks from
         ``first`` on) that ``seq`` fills. First writer wins a key."""
-        chain = self._chain(seq)
+        chain = self._chain(seq, chain)
         newly = 0
-        for i in range(first, min(len(chain), first + len(table))):
-            key, parent, chunk = chain[i]
+        n = len(seq)
+        for i in range(first, min(-(-n // self.block_size),
+                                  first + len(table))):
+            key, parent, chunk = chain.block(i, n)
             if key not in self._index:
                 bid = table[i - first]
                 self._index[key] = (parent, chunk, bid, len(chunk))
@@ -717,11 +779,12 @@ class WindowPool(PrefixPool):
             self._event("register", blocks=newly, tokens=len(seq))
 
     def release(self, blocks: List[int], seq: Optional[List[int]] = None,
-                first: int = 0) -> None:
+                first: int = 0,
+                chain: Optional[BlockChain] = None) -> None:
         """Drop one reference a block; ``seq`` (the resident tokens)
         indexes the tail first."""
         if seq:
-            self.register_tail(seq, blocks, first)
+            self.register_tail(seq, blocks, first, chain)
         self._unref(blocks)
 
     def truncate(self, table: List[int], keep_tokens: int,

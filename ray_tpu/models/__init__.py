@@ -15,10 +15,14 @@ Serve deployment (serve/llm.py) know no model: they ask ``serving(cfg)``
 for what the configuration's own module says of it, a ``Serving``:
 
   init      ``init(key, cfg)``: the parameters as served
-  step      the decode step, ``(params, tokens, positions, *pools,
-            block_tables, context_lens, q_lens, slot_blocks,
-            slot_offsets, *window, cfg=)`` ->
-            ``(logits, ids, *pools, *window pools)``
+  step      the decode step, ``(params, packed, *pools, *window pools,
+            q=, cfg=)`` -> ``(logits, ids, *pools, *window pools)``.
+            ``packed`` is ONE int32 array ``[max_batch, W]``, a lane a
+            row: the step's whole bookkeeping side by side
+            (``step_columns``), which the engine keeps current from
+            step to step and the program takes apart by static slices
+            (``unpack_step``). ``q`` is the rows a lane, a Python int:
+            a shape of the program, not a value in it
   chunk     one span of a prompt as ONE program, ``(params, tokens,
             *pools, table, *window, cfg=)`` ->
             ``(row, id, *pools, *window pools)``. It writes the span's
@@ -37,11 +41,11 @@ for what the configuration's own module says of it, a ``Serving``:
             every token of a sequence (``*pools`` above are its pools,
             in ``rows``' order); a second kind, if there is one, has a
             ``window`` and keeps only the blocks that cover a
-            sequence's last ``window`` tokens. Its pools and its int32
-            array ride after the full kind's arguments (``*window``:
-            its pools, then ``win``; see models/laguna.py for the
-            array, which in a chunk also names the blocks the span is
-            written to).
+            sequence's last ``window`` tokens. Its pools ride after
+            the full kind's; a step's packed array has its columns
+            too, and a chunk takes its int32 array ``win`` after them
+            (models/laguna.py: the table, its first block, the blocks
+            the span is written to).
   cost      the cost description util/perfmodel.py prices steps from
   counters  names of the int32 counters the step program appends to its
             ``ids`` as rows ``[max_batch + i]``: they ride in the one
@@ -56,7 +60,7 @@ from __future__ import annotations
 import functools
 import importlib
 from dataclasses import dataclass
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -118,6 +122,83 @@ def unpack_span(table, n: int, block_size: int):
     nd = n // block_size
     nb = table.shape[0] - nd - 2
     return table[:nb], table[nb:nb + nd], table[-2], table[-1]
+
+
+class StepColumns(NamedTuple):
+    """Where each part of a lane's row of the packed step array starts
+    (``step_columns``). Columns ``[0, head)`` change every step; the
+    tables behind them only where a block is granted or given back."""
+    tokens: int         # each per-row part is q columns wide
+    positions: int
+    slot_blocks: int
+    slot_offsets: int
+    win_slots: int      # the window kind's slot block a row (q wide, or 0)
+    context_len: int
+    q_len: int
+    head: int           # the end of what a step writes
+    win_first: int      # the window table's first block in the sequence
+    win_table: int
+    table: int          # the full kind's block table, to the row's end
+
+
+@functools.lru_cache(maxsize=None)
+def step_columns(q: int, win_len: int = 0) -> StepColumns:
+    """The packed step array's layout, from shapes alone: ``[tokens |
+    positions | slot blocks | slot offsets (q each) | window slot
+    blocks (q) | context_len | q_len | window first | window table
+    (win_len) | block table]``, the window kind's three parts only
+    where the model has that kind. A padded lane, and a row past a
+    lane's ``q_len``, is all zeros (scratch block 0, offset 0,
+    position 0) but for ``context_len`` 1 and ``q_len`` 1."""
+    ctx = (5 if win_len else 4) * q
+    win = win_len + 1 if win_len else 0
+    return StepColumns(tokens=0, positions=q,
+                       slot_blocks=2 * q, slot_offsets=3 * q,
+                       win_slots=4 * q, context_len=ctx, q_len=ctx + 1,
+                       head=ctx + 2, win_first=ctx + 2,
+                       win_table=ctx + 2 + bool(win_len),
+                       table=ctx + 2 + win)
+
+
+def pack_step(tokens, positions, block_tables, context_lens, q_lens,
+              slot_blocks, slot_offsets, win=None):
+    """A decode step's bookkeeping, built from its parts, as the ONE
+    int32 array ``Serving.step`` takes (``step_columns``): ``tokens`` /
+    ``positions`` / ``slot_blocks`` / ``slot_offsets`` ``[b, q]``,
+    ``block_tables`` ``[b, max_nb]``, ``context_lens`` / ``q_lens``
+    ``[b]``, and the window kind's ``win`` ``[b, win_len + 1 + q]``
+    (``[table | first block | slot block a row]``). The engine never
+    calls this in a step: it keeps its array and writes what changed
+    (llm/engine.py); tests and tools build one from scratch here."""
+    q = np.shape(tokens)[1]
+    col = lambda x: np.asarray(x, np.int32)[:, None]
+    parts = [tokens, positions, slot_blocks, slot_offsets]
+    tail = []
+    if win is not None:
+        win = np.asarray(win, np.int32)
+        n = win.shape[1] - 1 - q
+        parts.append(win[:, n + 1:])
+        tail = [win[:, n:n + 1], win[:, :n]]
+    return np.concatenate(
+        [*parts, col(context_lens), col(q_lens), *tail, block_tables],
+        axis=1, dtype=np.int32)
+
+
+def unpack_step(packed, q: int, win_len: int = 0):
+    """``step_columns``' array, inside the program, back into its
+    parts by static slices: ``(tokens, positions, block_tables,
+    context_lens, q_lens, slot_blocks, slot_offsets, window)``, where
+    ``window`` is ``(table, first, slot_blocks)`` of the kind of layer
+    with a window and None without one."""
+    c = step_columns(q, win_len)
+    part = lambda start: packed[:, start:start + q]
+    window = None
+    if win_len:
+        window = (packed[:, c.win_table:c.table], packed[:, c.win_first],
+                  part(c.win_slots))
+    return (part(c.tokens), part(c.positions), packed[:, c.table:],
+            packed[:, c.context_len], packed[:, c.q_len],
+            part(c.slot_blocks), part(c.slot_offsets), window)
 
 
 @functools.lru_cache(maxsize=64)

@@ -374,8 +374,7 @@ def _paged_sublayer(h, p, layer, k_pool, v_pool, block_tables, context_lens,
     return o, (k_pool, v_pool)
 
 
-def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
-                 context_lens, q_lens, slot_blocks, slot_offsets,
+def forward_step(params, packed, k_pool, v_pool, *, q: int,
                  cfg: GPTConfig):
     """One decode step for a batch of in-flight sequences: ``q`` rows a
     lane against the paged pool in ONE batched paged-attention forward.
@@ -388,27 +387,35 @@ def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
     context_lens is never attended.
 
     Args:
+      packed: [b, W] int32, the step's whole bookkeeping in ONE array
+        that the engine keeps current (models/__init__.py
+        ``step_columns``), taken apart here by static slices into:
       tokens / positions: [b, q] int32 — each row's token and absolute
         position. Rows past q_lens[lane], and every row of a padded
         lane, are padding: their slots point at the pool's reserved
         scratch block 0 and their logits are garbage the engine never
         reads.
-      k_pool / v_pool: [L, num_blocks, block_size, kv_heads * head_dim]
-        (donate these in the caller's jit: they ride in the layer
-        scan's carry, so steady-state decode writes the step's rows
-        into the donated buffers and copies nothing of the stack).
       block_tables: [b, max_nb] int32, 0-padded.
       context_lens: [b] int32 — resident tokens per lane INCLUDING its
         q_lens real rows (1 for a padded lane).
       q_lens: [b] int32 — real rows per lane (1 = plain decode lane).
       slot_blocks / slot_offsets: [b, q] int32 — the pool block and
         in-block offset each row is written at.
+      k_pool / v_pool: [L, num_blocks, block_size, kv_heads * head_dim]
+        (donate these in the caller's jit: they ride in the layer
+        scan's carry, so steady-state decode writes the step's rows
+        into the donated buffers and copies nothing of the stack).
+      q: rows a lane, a Python int (a shape, not a value).
 
     Returns (logits [b, q, vocab], ids [b, q] int32, k_pool, v_pool):
     ``ids`` is the argmax of each logits row (the first index of the
     maximum, as ``numpy.argmax`` on the same row), so a greedy lane's
     tokens are decided here and the host fetches ids, not logits.
     """
+    from . import unpack_step
+
+    (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
+     slot_offsets, _) = unpack_step(packed, q)
     x = _embed(params, tokens, positions, cfg)
 
     def layer(carry, xs):

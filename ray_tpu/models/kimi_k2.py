@@ -365,9 +365,7 @@ def _counters(sizes, rows: int, cfg: KimiK2Config, q: int, in_runs):
         (len(COUNTERS), q)).astype(jnp.int32)
 
 
-def forward_step(params, tokens, positions, pool, block_tables,
-                 context_lens, q_lens, slot_blocks, slot_offsets,
-                 cfg: KimiK2Config):
+def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config):
     """One decode step (models/gpt.py ``forward_step``'s contract) over
     ONE pool of latent rows ``[layers, num_blocks, block_size,
     row_width]``, in the absorbed form: each row's latent row is
@@ -378,7 +376,10 @@ def forward_step(params, tokens, positions, pool, block_tables,
     on of ``ids`` are ``COUNTERS``."""
     from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
                                           paged_attention_latent)
+    from . import unpack_step
 
+    (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
+     slot_offsets, _) = unpack_step(packed, q)
     B, Q = tokens.shape
     nope, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     x = params["embed"][tokens]

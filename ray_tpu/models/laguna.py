@@ -42,11 +42,13 @@ is made. A prefill chunk gathers its
 table's slots and attends over [them ++ the chunk] in the
 ``chunk_attn`` kernel (ops/pallas/chunk_attention.py), both kinds.
 
-The window kind's int32 array (``win``), one row a lane in a decode
-step: ``[table (window_table_len entries), first block, slot block a
-row]``: the blocks from the window's oldest on, that block's index in
-the sequence, and where each row's K/V is written. A chunk's is
-``[table, first block, destination block a block of the chunk]``.
+The window kind's part of a decode step's bookkeeping, in a lane's row
+of the step's one packed array (models/__init__.py ``step_columns``):
+its table (``window_table_len`` entries: the blocks from the window's
+oldest on), that first block's index in the sequence, and a slot block
+a row, where each row's K/V is written. A chunk takes an int32 array of
+its own (``win``): ``[table, first block, destination block a block of
+the chunk]``.
 """
 
 from __future__ import annotations
@@ -347,27 +349,27 @@ COUNTERS = ("moe_experts_hit", "moe_load_max_x1000",
             "kv_pages_in_runs_x1000")
 
 
-def forward_step(params, tokens, positions, k_pool, v_pool, block_tables,
-                 context_lens, q_lens, slot_blocks, slot_offsets,
-                 k_win, v_win, win, cfg: LagunaConfig):
+def forward_step(params, packed, k_pool, v_pool, k_win, v_win, *, q: int,
+                 cfg: LagunaConfig):
     """One decode step (models/gpt.py ``forward_step``'s contract) over
-    two kinds of pool. ``win`` [b, nbw + 1 + q] is the window kind's
-    array (module docstring); a window layer writes each row at
-    (its index, win slot block, slot_offsets) and attends over the
-    lane's window table, whose context counts from the table's first
-    block.
+    two kinds of pool. ``packed`` carries the window kind's columns too
+    (module docstring); a window layer writes each row at (its index,
+    window slot block, slot_offsets) and attends over the lane's window
+    table, whose context counts from the table's first block.
 
     Returns (logits [b, q, vocab], ids [b + 3, q] int32, k_pool, v_pool,
     k_win, v_win): rows b on of ``ids`` are ``COUNTERS``."""
+    from ..llm.kv_cache import window_table_len
     from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
                                           paged_attention_stored)
+    from . import unpack_step
 
-    B, Q = tokens.shape
+    B, Q = packed.shape[0], q
     kv, d = cfg.num_key_value_heads, cfg.head_dim
     bs = k_pool.shape[2]
-    nbw = win.shape[1] - 1 - Q
-    win_tables, win_first = win[:, :nbw], win[:, nbw]
-    win_slots = win[:, nbw + 1:]
+    (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
+     slot_offsets, (win_tables, win_first, win_slots)) = unpack_step(
+        packed, Q, window_table_len(cfg.sliding_window, bs, Q))
     win_lens = context_lens - win_first * bs
     # Row i of a lane sits at ctx - q_len + i and sees keys from that
     # less (window - 1) on, in the window table's own coordinates.

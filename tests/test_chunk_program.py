@@ -250,7 +250,11 @@ def test_a_chunk_length_never_seen_compiles_exactly_one_program(params,
     computed."""
     from jax import monitoring
 
-    eng = LLMEngine(params(name), CONFIGS[name], num_blocks=64,
+    # A pool of a size no other test in the process uses: the programs
+    # are shared a configuration (llm/engine.py ``_jit_programs``), and
+    # a file that ran before this one in the same worker may have
+    # compiled a five-block chunk at the usual 64 blocks already.
+    eng = LLMEngine(params(name), CONFIGS[name], num_blocks=61,
                     block_size=BS, max_batch=2, prefill_chunk_tokens=None)
     # Warm-up: the decode program, a cold prompt of 2 blocks, a prompt
     # behind a cached block (the full-length table), greedy and sampled.

@@ -452,3 +452,14 @@ def test_planted_faults_move_the_logits(params, fault, monkeypatch):
         monkeypatch.setattr(kimi_k2_ref, "route", raw)
     got = np.asarray(kimi_k2_ref.forward(params, prompt, TINY))
     assert np.abs(got - want).max() > 100 * LOGIT_TOL
+
+
+@pytest.mark.parametrize("case", ["kimi-q1", "kimi-spec"])
+def test_kept_step_array_equals_one_built_from_scratch_every_step(case):
+    """tests/kept_array.py's scripted run over the one latent pool: the
+    engine's kept packed array equals one built from scratch at every
+    decode dispatch, and the token streams and the pool's bookkeeping
+    are the ones recorded on the commit before PR 46."""
+    import kept_array
+
+    kept_array.check(kept_array.engines()[case](), case)

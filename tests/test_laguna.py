@@ -76,9 +76,9 @@ def _logits_of(eng):
 
     def on_fetch(logits, ids, all_greedy):
         got = np.asarray(jax.device_get(logits), np.float32)
-        for i, r in enumerate(x for x in eng._active
-                              if x.state == "RUNNING"):
-            rows.setdefault(r.rid, []).append(got[i, 0])
+        for r in eng._active:
+            if r.state == "RUNNING":    # holds a lane of the kept array
+                rows.setdefault(r.rid, []).append(got[r.lane, 0])
         return fetch(logits, ids, all_greedy)
 
     eng._activate, eng._fetch_decisions = on_activate, on_fetch
@@ -387,3 +387,16 @@ def test_config_counts_the_published_parameters():
     shapes = jax.eval_shape(lambda: laguna.init(jax.random.key(0), cfg))
     n = sum(math.prod(l.shape) for l in jax.tree_util.tree_leaves(shapes))
     assert n == cfg.num_params()
+
+
+@pytest.mark.parametrize("case", ["laguna-q1-window", "laguna-spec-window"])
+def test_kept_step_array_equals_one_built_from_scratch_every_step(case):
+    """tests/kept_array.py's scripted run over BOTH kinds of pool: the
+    engine's kept packed array (the full kind's table and the window
+    kind's table, first block and slot blocks) equals one built from
+    scratch at every decode dispatch, through slides, grants, rollbacks,
+    a preemption and finishes; the token streams and both pools'
+    bookkeeping are the ones recorded on the commit before PR 46."""
+    import kept_array
+
+    kept_array.check(kept_array.engines()[case](), case)

@@ -22,6 +22,7 @@ from ray_tpu.llm import (  # noqa: E402
     LLMEngine,
 )
 from ray_tpu.models.gpt import GPTConfig, init  # noqa: E402
+from ray_tpu.models import pack_step  # noqa: E402
 
 # f32 on CPU so decode logits are bit-reproducible across runs of the
 # same process (the determinism assertions compare token ids, which
@@ -481,10 +482,11 @@ def test_program_ids_equal_host_greedy_sample_of_its_logits(Q, kind):
     slot = (1 + np.arange(B, dtype=np.int32))[:, None] + np.arange(
         Q, dtype=np.int32)
     logits, ids, _, _ = decode(
-        params, rng.integers(0, 60, (B, Q), dtype=np.int32), slot,
-        pool, pool + 0, tables, slot[:, -1] + 1,
-        np.full((B,), Q, np.int32),
-        np.broadcast_to(tables[:, :1], (B, Q)), slot)
+        params, pack_step(
+            rng.integers(0, 60, (B, Q), dtype=np.int32), slot, tables,
+            slot[:, -1] + 1, np.full((B,), Q, np.int32),
+            np.broadcast_to(tables[:, :1], (B, Q)), slot),
+        pool, pool + 0, q=Q)
     assert ids.dtype == jnp.int32 and ids.shape == logits.shape[:-1]
     rows = np.asarray(logits, np.float32).reshape(-1, BF16.vocab_size)
     got = np.asarray(ids).reshape(-1)
@@ -643,11 +645,12 @@ def test_step_at_one_row_is_row_zero_of_a_wider_step_with_padding_rows():
 
     def run(q):
         pad = np.zeros((B, q - 1), np.int32)
-        return step(PARAMS, np.hstack([tok, pad]),
-                    np.hstack([slot, slot + 1 + np.arange(q - 1)]),
-                    pool + 0, pool + 0, tables, slot[:, 0] + 1, ones,
-                    np.hstack([tables[:, :1], pad]),    # padding: block 0
-                    np.hstack([slot, pad]))
+        return step(PARAMS, pack_step(
+            np.hstack([tok, pad]),
+            np.hstack([slot, slot + 1 + np.arange(q - 1)]),
+            tables, slot[:, 0] + 1, ones,
+            np.hstack([tables[:, :1], pad]),    # padding: block 0
+            np.hstack([slot, pad])), pool + 0, pool + 0, q=q)
 
     l1, i1, k1, v1 = run(1)
     l3, i3, k3, v3 = run(3)
@@ -830,8 +833,8 @@ def test_token_major_pool_gives_the_head_major_pools_bits(case, cfg):
     args = (rng.integers(0, cfg.vocab_size, (B, Q), dtype=np.int32), pos)
     rest = (tables, pos[:, -1] + 1, np.full((B,), Q, np.int32),
             np.take_along_axis(tables, pos // bs, axis=1), pos % bs)
-    logits, ids, k_new, v_new = step(params, *args, k_pool + 0, v_pool + 0,
-                                     *rest)
+    logits, ids, k_new, v_new = step(params, pack_step(*args, *rest),
+                                     k_pool + 0, v_pool + 0, q=Q)
     l_old, i_old, k_want, v_want = jax.jit(
         functools.partial(_head_major_step, cfg=cfg))(
             params, *args, k_old, v_old, *rest)
@@ -886,15 +889,36 @@ def test_cold_whole_prompt_prefills_through_the_chunk_program_tableless():
 
 
 def test_a_decode_step_returns_the_pools_as_it_got_them_uncommitted():
-    """The q_lens the engine keeps on the device is made like the pools,
-    uncommitted. A committed input would commit the pools the step
-    returns, and every program that takes the pools (each chunk length,
-    the pool writes) would then compile a second time: 15-22 more
-    compilations in the chat cell's set-up when it was (PERF.md section
-    6, PR 30)."""
+    """The decode program's one array is the host's (the engine keeps
+    it, a numpy array), so nothing it is handed is committed. A
+    committed input would commit the pools the step returns, and every
+    program that takes the pools (each chunk length, the pool writes)
+    would then compile a second time: 15-22 more compilations in the
+    chat cell's set-up when it was (PERF.md section 6, PR 30)."""
     eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8, max_batch=4)
-    assert not eng.kv.k.committed and not eng._one_row_each.committed
+    assert not eng.kv.k.committed and isinstance(eng._inputs, np.ndarray)
     eng.add_request([1, 2, 3], max_tokens=4)
     eng.step()
     eng.step()
     assert not eng.kv.k.committed and not eng.kv.v.committed
+
+
+# ---------------------------------------------------------------------------
+# The decode program's ONE packed array, kept from step to step (PR 46)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("case", ["gpt-q1", "gpt-spec",
+                                  "gpt-q1-no-prefix-cache"])
+def test_kept_step_array_equals_one_built_from_scratch_every_step(case):
+    """A scripted run (tests/kept_array.py: admissions, a partial and a
+    full prefix hit, a request of one token, greedy lanes and lanes with
+    a temperature, proposals and rollbacks under speculation, a
+    preemption, finishes). At every decode dispatch the engine's kept
+    array equals one built from scratch from the requests' own state;
+    between steps a lane's tables are its request's and a free lane is
+    the scratch lane; and the token streams, the prefix hits and the
+    order the blocks came back in are the ones recorded on the commit
+    before PR 46. Without a prefix cache the pool indexes nothing, and
+    no request is given a chain of block keys."""
+    import kept_array
+
+    kept_array.check(kept_array.engines()[case](), case)

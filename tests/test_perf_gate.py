@@ -324,8 +324,9 @@ def test_locality_and_spill_bookkeeping_gate():
 
 def test_prefix_pool_bookkeeping_gate():
     """The prefix-cache bookkeeping runs at EVERY admission, under the
-    engine lock: a full-hit admit (per-chunk chain hashing + index
-    verify + ref bumps + LRU pops) plus the matching release
+    engine lock: a full-hit admit (a walk of the request's chain of
+    block keys, which ``add_request`` made on the caller's thread:
+    index verify + ref bumps + LRU pops) plus the matching release
     (re-register walk + unref parks) must stay under 10us per admitted
     request at calibration 1.0 (~2-4us observed solo for a 64-token
     prompt). A regression — the index growing a per-lookup content
@@ -334,7 +335,7 @@ def test_prefix_pool_bookkeeping_gate():
     pytest.importorskip("jax")
     import jax.numpy as jnp
 
-    from ray_tpu.llm.kv_cache import PrefixPool
+    from ray_tpu.llm.kv_cache import BlockChain, PrefixPool
     from ray_tpu.models.gpt import GPTConfig
 
     cal = _calibrate()
@@ -342,16 +343,17 @@ def test_prefix_pool_bookkeeping_gate():
                     n_head=4, dtype=jnp.float32)
     pool = PrefixPool(cfg, num_blocks=32, block_size=16)
     seq = list(range(64))                  # 4 full chunks
-    warm, _ = pool.admit(seq, len(seq) + 1)
-    pool.release(warm, seq=seq)            # chain registered + parked
+    chain = BlockChain(pool.block_size, seq)    # once a request
+    warm, _ = pool.admit(seq, len(seq) + 1, chain=chain)
+    pool.release(warm, seq=seq, chain=chain)    # registered + parked
     n = 2000
     cached = 0
     per_pass = []
     for _ in range(3):                     # min-of-3: GC/scheduler
         t0 = time.perf_counter()           # spikes don't fail the gate
         for _ in range(n):
-            table, cached = pool.admit(seq, len(seq) + 1)
-            pool.release(table, seq=seq)
+            table, cached = pool.admit(seq, len(seq) + 1, chain=chain)
+            pool.release(table, seq=seq, chain=chain)
         per_pass.append((time.perf_counter() - t0) / n)
     per_req = min(per_pass)
     assert cached == len(seq), "gate must exercise the full-hit path"

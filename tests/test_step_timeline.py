@@ -634,9 +634,11 @@ def test_program_names_in_the_lowered_text():
                      CFG.dtype)
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
     def step(q):
-        return _lowered(decode, PARAMS, i32(B, q), i32(B, q), pool, pool,
-                        i32(B, max_nb), i32(B) + q, i32(B) + q, i32(B, q),
-                        i32(B, q))
+        from ray_tpu.models import step_columns
+
+        return decode.lower(
+            PARAMS, i32(B, step_columns(q).table + max_nb), pool, pool,
+            q=q).as_text(debug_info=True)
 
     texts = {
         "llm_decode": step(1),

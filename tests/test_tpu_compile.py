@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import gpt
+from ray_tpu.models import gpt, step_columns
 from ray_tpu.ops.pallas.flash import flash_attention_pallas
 from ray_tpu.ops.pallas.paged_decode import (paged_decode_attention,
                                              paged_verify_attention)
@@ -136,11 +136,10 @@ def test_whole_decode_step_compiles_with_the_kernel(one_chip, as_tpu):
         jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
     pool = S((cfg.n_layer, NB, BS, HKV * HD), jnp.bfloat16)
     B, i32 = 32, jnp.int32
-    step = jax.jit(functools.partial(gpt.forward_step, cfg=cfg),
-                   donate_argnums=(3, 4))
-    c = step.lower(params, S((B, 1), i32), S((B, 1), i32), pool, pool,
-                   S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
-                   S((B, 1), i32), S((B, 1), i32)).compile()
+    step = jax.jit(functools.partial(gpt.forward_step, q=1, cfg=cfg),
+                   donate_argnums=(2, 3))
+    c = step.lower(params, S((B, step_columns(1).table + MAX_NB), i32),
+                   pool, pool).compile()
     assert "tpu_custom_call" in c.as_text()
 
 
@@ -178,9 +177,8 @@ def _chat_cell_decode_lowered(one_chip):
     B, i32 = CELL_B, jnp.int32
     pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
     decode = _jit_programs(cfg)[0]
-    return decode.lower(params, S((B, 1), i32), S((B, 1), i32), pool, pool,
-                        S((B, MAX_NB), i32), S((B,), i32), S((B,), i32),
-                        S((B, 1), i32), S((B, 1), i32))
+    return decode.lower(params, S((B, step_columns(1).table + MAX_NB), i32),
+                        pool, pool, q=1)
 
 
 def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
@@ -258,6 +256,11 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     pools = f"bf16[{cfg.n_layer},{CELL_NB},{BS},{HKV * HD}]"
     assert outputs == [f"bf16[{CELL_B},1,{cfg.vocab_size}]",
                        f"s32[{CELL_B},1]", pools, pools], root[:300]
+    # Beside the parameters and the two pools the program takes ONE
+    # array: the step's packed bookkeeping (one hand-over a step).
+    leaves = len(jax.tree_util.tree_leaves(
+        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))))
+    assert _entry_parameters(text) == leaves + 3
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1 and "%paged_decode" in calls[0].split("=")[0]
@@ -386,10 +389,8 @@ def laguna_programs(one_chip):
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode": decode.lower(
-                params, S((B, 1), i32), S((B, 1), i32), full, full,
-                S((B, max_nb), i32), S((B,), i32), S((B,), i32),
-                S((B, 1), i32), S((B, 1), i32), window, window,
-                S((B, nbw + 2), i32)).compile(),
+                params, S((B, step_columns(1, nbw).table + max_nb), i32),
+                full, full, window, window, q=1).compile(),
             "chunk": chunk.lower(
                 params, S((1, 512), i32), full, full,
                 S((max_nb + 512 // BS + 2,), i32), window, window,
@@ -464,12 +465,13 @@ def test_laguna_decode_program_reads_the_pools_as_stored(laguna_programs):
     # table, 8 of the window kind's (two steps of 32 pages).
     assert _fusions_of(text, f"s32[{CELL_B},72]") == 1
     assert _fusions_of(text, f"s32[{CELL_B},8]") == 1
-    # params' leaves, tokens, positions, then the full kind's K and V
-    # (outputs 2, 3 behind the logits and the ids); the window kind's
-    # follow the five int32 arrays between.
+    # params' leaves, the step's ONE packed array, then the full kind's
+    # K and V (outputs 2, 3 behind the logits and the ids) and the
+    # window kind's behind them: nothing else is handed over.
     leaves = laguna_programs["param_leaves"]
-    assert _aliased(text) == {leaves + 2: 2, leaves + 3: 3,
-                              leaves + 9: 4, leaves + 10: 5}
+    assert _aliased(text) == {leaves + 1: 2, leaves + 2: 3,
+                              leaves + 3: 4, leaves + 4: 5}
+    assert _entry_parameters(text) == leaves + 5
     assert _pool_sized(text, "copy", "transpose", "copy-start",
                        "dynamic-update-slice", "concatenate", "pad") == []
     full = f"bf16[2,{LAGUNA_FULL_BLOCKS},{BS},1024]"
@@ -482,6 +484,15 @@ def test_laguna_decode_program_reads_the_pools_as_stored(laguna_programs):
     assert f"s32[{CELL_B + 3},1]" in root and f"bf16[{CELL_B},1,100352]" \
         in root
     assert c.memory_analysis().temp_size_in_bytes < 100e6
+
+
+def _entry_parameters(text):
+    """How many arguments the compiled program's entry takes."""
+    import re
+
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    return len(set(re.findall(r" parameter\((\d+)\)", entry)))
 
 
 def _aliased(text):
@@ -617,9 +628,8 @@ def kimi_programs(one_chip):
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode": decode.lower(
-                params, S((B, 1), i32), S((B, 1), i32), pool,
-                S((B, max_nb), i32), S((B,), i32), S((B,), i32),
-                S((B, 1), i32), S((B, 1), i32)).compile(),
+                params, S((B, step_columns(1).table + max_nb), i32), pool,
+                q=1).compile(),
             "chunk": chunk.lower(
                 params, S((1, 512), i32), pool,
                 S((max_nb + 512 // BS + 2,), i32)).compile(),
@@ -662,9 +672,10 @@ def test_kimi_decode_program_attends_the_latent_pool_as_stored(
                             "pad") == []
     pool = f"bf16[5,{KIMI_BLOCKS},{BS},{KIMI_ROW}]"
     assert _kimi_pool_sized(text, "scatter") == [("scatter", pool)] * 5
-    # params' leaves, tokens, positions, then the pool: output 2 behind
-    # the logits and the ids.
-    assert _aliased(text) == {kimi_programs["param_leaves"] + 2: 2}
+    # params' leaves, the step's ONE packed array, then the pool: output
+    # 2 behind the logits and the ids.
+    assert _aliased(text) == {kimi_programs["param_leaves"] + 1: 2}
+    assert _entry_parameters(text) == kimi_programs["param_leaves"] + 2
     entry = text[text.index("\nENTRY "):]
     root = next(line for line in entry.splitlines()
                 if line.lstrip().startswith("ROOT "))
