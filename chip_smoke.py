@@ -667,28 +667,31 @@ def _record_logits(eng) -> dict:
     import numpy as np
 
     rows, last = {}, []
-    activate, fetch, chunk = (eng._activate, eng._fetch_decisions,
-                              eng._prefill_chunk)
+    settle, fetch, chunk = (eng._settle, eng._fetch_decisions,
+                            eng._prefill_chunk)
 
     def on_chunk(*args):
         out = chunk(*args)
-        last[:] = [out[0]]      # the row the chunk program hands back
+        last.append(out[0])     # the row the chunk program hands back
         return out
 
-    def on_activate(req, first):
-        if first is not None:
-            rows.setdefault(req.rid, []).append(
-                np.asarray(jax.device_get(last[0]), np.float32))
-        return activate(req, first)
+    def on_settle():
+        # The chunks not yet settled are the last ones dispatched.
+        for ch, row in zip(eng._pending, last[-len(eng._pending):]):
+            if ch.done and ch.req is not None:
+                rows.setdefault(ch.req.rid, []).append(
+                    np.asarray(jax.device_get(row), np.float32))
+        last.clear()
+        return settle()
 
     def on_fetch(logits, ids, all_greedy):
         got = np.asarray(jax.device_get(logits), np.float32)
-        for i, r in enumerate(x for x in eng._active
-                              if x.state == "RUNNING"):
-            rows.setdefault(r.rid, []).append(got[i, 0])
+        for r in eng._active:
+            if r.state == "RUNNING":    # holds a lane of the kept array
+                rows.setdefault(r.rid, []).append(got[r.lane, 0])
         return fetch(logits, ids, all_greedy)
 
-    eng._activate, eng._fetch_decisions = on_activate, on_fetch
+    eng._settle, eng._fetch_decisions = on_settle, on_fetch
     eng._prefill_chunk = on_chunk
     return rows
 

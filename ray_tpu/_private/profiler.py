@@ -381,7 +381,8 @@ def format_device_steps(steps: list) -> str:
     ring's entries) as `rtpu profile --device` prints them, one block a
     step name and owner: the mean step split into its device spans by
     kind (and the dispatch inside each) and its host phases by name,
-    an engine's own counts, what lies between steps, and the window's
+    an engine's own counts (``programs`` / ``programs_queued`` among
+    them), what lies between steps, and the window's
     five longest step intervals, each by its parts
     (``_interval_line``)."""
     from ray_tpu.util import perfmodel
@@ -431,6 +432,11 @@ def format_device_steps(steps: list) -> str:
                 f"{sum(len(e['prefill_chunks']) for e in evs)} chunk(s); "
                 f"waiting {max(e['waiting'] for e in evs)} at most; "
                 f"preempted {sum(e['preempted'] for e in evs)}")
+            if any("programs" in e for e in evs):
+                lines[-1] += (
+                    f"; programs {sum(e.get('programs', 0) for e in evs)}"
+                    f", {sum(e.get('programs_queued', 0) for e in evs)} "
+                    f"queued before their step's first wait")
         timed = [e for e in evs if "interval_ms" in e]
         if timed:
             lines.append(

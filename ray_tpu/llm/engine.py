@@ -139,9 +139,33 @@ class Request:
             yield tok
 
 
+@dataclass
+class _Chunk:
+    """A prefill chunk the step has dispatched and not yet seen done:
+    what it owes the host, settled in ``LLMEngine._settle``."""
+    req: Optional[Request]      # None: preempted with the chunk in flight
+    span: "perfmodel._Program"  # its device span, open
+    result: jax.Array           # the prompt's last id (greedy) or row
+    done: bool                  # the prompt ends with this chunk
+    log: list                   # its row of the step's ``prefill_chunks``
+    t0: float                   # wall clock at its start, and the span's
+    upto: int                   # prompt tokens resident after it, of
+    total: int                  # so many: the request's ``llm.prefill``
+    handed: bool = False        # its lane is taken; its id goes to it
+
+
 # (program, pool specs, its shapes) -> how its kernel runs; see
 # LLMEngine._paged_kernel_mode and _chunk_attention_mode.
 _KERNEL_MODES: dict = {}
+
+
+@jax.jit
+def _place_first(firsts, lane, tok):
+    """``firsts`` (``Serving.step``) with a chunk program's argmax id
+    at a lane: three device arrays, the lane a traced scalar, so ONE
+    tiny program whatever the lane, and no value crosses to the host
+    between the chunk and the decode step queued behind it."""
+    return firsts.at[lane].set(tok)
 
 
 def _i32(*shape):
@@ -180,7 +204,8 @@ def _jit_programs(cfg):
 
     # The step program is ``jit_llm_decode`` at every q (one row a lane,
     # or 1 + k under speculation): q is a shape of the program, which
-    # takes its one packed array apart by it.
+    # takes its one packed array apart by it; ``firsts`` rides as a
+    # keyword too, a device array behind the donated pools.
     return (program("llm_decode", model.step, donate_argnums=step_pools,
                     static_argnames=("q",)),
             program("llm_prefill_chunk", model.chunk,
@@ -284,6 +309,16 @@ class LLMEngine:
         self._inputs[:, self._cols.context_len:self._cols.head] = 1
         self._free_lanes = list(range(self.max_batch - 1, -1, -1))
         self._inputs_written = 0      # elements written, this step
+        # The decode program's other operand, on the device (``firsts``
+        # of ``Serving.step``): -1 a lane, and in a step that queues
+        # the decode program behind its chunks the id of each greedy
+        # prompt that ends in one of them, put at its lane by
+        # ``_place_first``. The lanes' indices wait on the device too,
+        # so placing one hands over no host value.
+        self._no_firsts = jax.numpy.full((self.max_batch,), -1, np.int32)
+        self._lane_ids = list(jax.numpy.arange(self.max_batch,
+                                               dtype=np.int32))
+        self._pending: List[_Chunk] = []    # dispatched, not seen done
 
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
@@ -462,25 +497,11 @@ class LLMEngine:
                              {"rid": req.rid,
                               "preemptions": req.preemptions})
 
-    def _activate(self, req: Request, first):
-        """Prefill done: the first (or first-since-resume) token, and
-        the request enters the decode batch. ``first`` is what was
-        fetched of the chunk program's results for the prompt's last
-        token: its argmax id for a greedy request, which takes it as it
-        is; the logits row for a request that samples. ``None`` marks a
-        FULL prefix-cache hit — nothing was computed, so there is
-        nothing to decide yet; the same step's decode recomputes the
-        last position's logits and decides there."""
+    def _activate(self, req: Request):
+        """Prefill done, as far as the host's side goes: the request
+        enters the decode batch and takes its lane."""
         self._event(req, RUNNING)
-        if first is None:
-            pass
-        elif req.greedy:
-            self._decided["device"] += 1
-            self._emit_token(req, first)
-        else:
-            self._sample_into(req, first)
-        if req.state == RUNNING:        # its first token did not end it
-            self._take_lane(req)
+        self._take_lane(req)
 
     def _release_blocks(self, req: Request):
         """Return req's blocks to the pool. With the prefix pool the
@@ -565,6 +586,12 @@ class LLMEngine:
         admissions — bounds each request's preemption count)."""
         self._active.remove(req)
         self._release_blocks(req)
+        for chunk in self._pending:
+            if chunk.req is req:
+                # Its last chunk is still in flight: the first token
+                # is never fetched; the resume decides it again, the
+                # same one (the prompt's blocks are indexed above).
+                chunk.req = None
         req.block_table = []
         req.context_len = 0
         req.prefilled_upto = 0
@@ -594,17 +621,6 @@ class LLMEngine:
         self._finished_count += 1
         self._event(req, FINISHED)
         req.out_q.put(None)
-
-    def _sample_into(self, req: Request, logits_row) -> bool:
-        """Sample the next token on the host at the request's current
-        absolute position (keyed by (seed, position) alone, so lanes
-        sample in any order); emit it; apply stop conditions. Returns
-        True if the request finished."""
-        self._decided["host"] += 1
-        tok = sample(logits_row, temperature=req.temperature,
-                     top_k=req.top_k, seed=req.seed,
-                     position=len(req.prompt) + len(req.output))
-        return self._emit_token(req, tok)
 
     def _emit_token(self, req: Request, tok: int) -> bool:
         """Append an already-decided token (sampled, or an accepted/
@@ -646,7 +662,19 @@ class LLMEngine:
         A chunk is ONE dispatch of the chunk program (``Serving.chunk``),
         which is donated the pools, writes the span's K/V into them and
         returns the last row's logits and argmax; the host builds two
-        arrays before it and fetches at most one result after.
+        arrays before it. The chunk is DISPATCHED here, not awaited:
+        the pools come back as futures and go into the next program,
+        so the device runs the step's programs in dispatch order with
+        no host between them, and what a chunk owes the host waits in
+        ``_pending`` until the decode program is queued too
+        (``_settle``; a chunk that is alone in flight is settled before
+        the decode step is built, ``_run_decode``). A greedy prompt
+        that ends here takes its lane at once, and its first token can
+        reach the decode program on the device (``firsts``): the host
+        knows everything else of the new lane. Only a result the host
+        must have before it can build the decode step is fetched here:
+        the logits row of a request that samples, and a first token
+        the proposer is to continue from.
         """
         prefills = [r for r in self._active if r.state == PREFILL]
         self._last_prefill_count = len(prefills)
@@ -659,8 +687,10 @@ class LLMEngine:
                 seq = self._seq(req)
                 T = len(seq)
                 if req.prefilled_upto >= T:
-                    # Full prefix-cache hit: zero prefill compute.
-                    self._activate(req, None)
+                    # Full prefix-cache hit: zero prefill compute, and
+                    # nothing decided yet; the same step's decode
+                    # recomputes the last position's logits.
+                    self._activate(req)
                     if req.trace_ctx is not None:
                         tracing.emit("llm.prefill", req.trace_ctx, t0, 0.0,
                                      {"rid": req.rid, "tokens": T,
@@ -704,23 +734,15 @@ class LLMEngine:
                 if self.kv_window is not None:
                     window = (*self.kv_window.pools,
                               self._slide_window(req, upto, c, pad))
-            # Dispatch to results ready is the device span: ONE program,
-            # which writes the chunk's K/V into the pools it is donated,
-            # and at most one fetch: the program's argmax id for a
-            # greedy request whose prompt ends here, that row of logits
-            # for one that samples, nothing for a mid-prompt chunk.
-            with perf.device("llm.prefill.device") as dev:
+            # ONE program, which writes the chunk's K/V into the pools
+            # it is donated. Its span stays open: the wait for it comes
+            # when the step's programs are all queued.
+            with perf.dispatch("llm.prefill.device") as span:
                 row, tok, *pools = self._prefill_chunk(
                     self.params, toks, *self.kv.pools, table, *window)
-                dev.dispatched()
-                self._take_back(pools)
-                if done:
-                    first = jax.device_get(tok if req.greedy else row)
-                else:
-                    first = None
-                    jax.block_until_ready(self.kv.pools[0])
-            device_s = dev.seconds
+            fetch_now = False
             with perf.phase("llm.prefill.host"):
+                self._take_back(pools)
                 req.prefilled_upto = upto + c
                 req.context_len = req.prefilled_upto
                 self._prefill_chunks += 1
@@ -731,30 +753,104 @@ class LLMEngine:
                     self.cfg, c + pad, ctx_tokens=upto))
                 # [positions computed (padded to whole blocks, as
                 # priced), context tokens resident before them, ms, of
-                # them the host's dispatch].
-                self._chunk_log.append([c + pad, upto, device_s * 1e3,
-                                        dev.dispatch_seconds * 1e3])
+                # them the host's dispatch]: the last two once the
+                # chunk is seen done.
+                log = [c + pad, upto, 0.0, 0.0]
+                self._chunk_log.append(log)
+                # What the host will fetch of it: the program's argmax
+                # id for a greedy request whose prompt ends here, that
+                # row of logits for one that samples; of a mid-prompt
+                # chunk nothing, its id only says the program is done.
+                chunk = _Chunk(
+                    req, span, tok if req.greedy or not done else row,
+                    done, log, t0, upto + c, T)
+                self._pending.append(chunk)
                 if done:
                     if self._prefix:
                         # Index the prompt's chunks for later arrivals
-                        # (shared system prompts hit from here on).
+                        # (shared system prompts hit from here on): a
+                        # program that reads these blocks is queued
+                        # behind the one that writes them.
                         self.kv.register(seq, req.block_table, req.chain)
                         if self.kv_window is not None:
                             self.kv_window.register_tail(
                                 seq, req.window_table, req.window_first,
                                 req.chain)
-                    self._activate(req, first)
+                    if not req.greedy or self._spec is not None:
+                        # The sampler needs the row, the proposer the
+                        # token, before the decode step can be built.
+                        fetch_now = True
+                    elif len(req.output) + 1 < req.max_tokens:
+                        # The lane's position, slot and tables are
+                        # host facts: it is taken now, and the decode
+                        # step can be built before the token is seen.
+                        self._activate(req)
+                        chunk.handed = True
+                    # else its first token ends it by length: it never
+                    # takes a lane, and finishes where it is settled.
+            if fetch_now:
+                self._settle()
+
+    def _settle(self):
+        """Collect what the dispatched chunks owe the host, in the
+        device's order. For each: the wait for its result, which closes
+        its device span (a ``device_get`` of a prompt's last id or row
+        returns when THAT chunk is done, whatever is queued behind it);
+        its row of the step's chunk log; a prompt's first (or
+        first-since-resume) token, decided and emitted then and there,
+        so its TTFT is stamped when its chunk is done and not at the
+        step's end; the request's ``llm.prefill`` span. A request that
+        was preempted with its chunk in flight is owed nothing."""
+        perf = self._step_perf
+        for chunk in self._pending:
+            req = chunk.req
+            fetch = chunk.done and req is not None
+            with chunk.span.waiting():
+                if fetch:
+                    first = jax.device_get(chunk.result)
+                else:
+                    jax.block_until_ready(chunk.result)
+            device_s = chunk.span.seconds
+            chunk.log[2:] = [device_s * 1e3,
+                             chunk.span.dispatch_seconds * 1e3]
+            if req is None:
+                continue
+            if fetch and req.greedy:
+                self._decided["device"] += 1
+            elif fetch:
+                # Sampled on the host at the request's absolute
+                # position (keyed by (seed, position) alone).
+                with perf.phase("llm.sample"):
+                    self._decided["host"] += 1
+                    first = sample(
+                        first, temperature=req.temperature,
+                        top_k=req.top_k, seed=req.seed,
+                        position=len(req.prompt) + len(req.output))
+            with perf.phase("llm.emit"):
+                if fetch:
+                    if req.lane is None:
+                        self._event(req, RUNNING)
+                    # A first token that ends the request (a stop
+                    # token, its length) gives back the lane it was
+                    # handed ahead of time, if any: the row that lane
+                    # ran in this step is dropped with it.
+                    if not self._emit_token(req, first) \
+                            and req.lane is None:
+                        self._take_lane(req)
                 if req.trace_ctx is not None:
-                    dur = time.time() - t0
-                    tracing.emit("llm.prefill", req.trace_ctx, t0, dur,
-                                 {"rid": req.rid, "tokens": c,
-                                  "upto": req.prefilled_upto, "total": T,
+                    dur = time.time() - chunk.t0
+                    tracing.emit("llm.prefill", req.trace_ctx, chunk.t0,
+                                 dur,
+                                 {"rid": req.rid,
+                                  "tokens": chunk.upto - chunk.log[1],
+                                  "upto": chunk.upto, "total": chunk.total,
                                   "cached": req.cached_tokens,
-                                  "done": done,
+                                  "done": chunk.done,
                                   "resumed": bool(req.preemptions),
                                   "device_ms": round(device_s * 1e3, 3),
                                   "host_ms": round(
                                       max(dur - device_s, 0.0) * 1e3, 3)})
+        self._pending.clear()
 
     def _take_back(self, pools):
         """The pools a program was donated, as it returned them written:
@@ -903,6 +999,16 @@ class LLMEngine:
         are not live padding onto scratch block 0."""
         spec = self._spec
         perf = self._step_perf
+        if len(self._pending) == 1:
+            # ONE chunk in flight is awaited before the decode step is
+            # built, as every chunk was before PR 47: the host blocks
+            # once for it either way, and while it does the serving
+            # threads have the interpreter. Queued behind a lone short
+            # chunk the decode step left them too little of it: the
+            # chat cell's ``ttft_p50_ms`` rose 37% (PERF.md section 6,
+            # PR 47). With two or more in flight the decode program is
+            # queued behind them and the host waits after that.
+            self._settle()
         with perf.phase("llm.slots"):
             batch = [r for r in self._active if r.state == RUNNING]
         props = dict.fromkeys([r.rid for r in batch], ())
@@ -928,6 +1034,7 @@ class LLMEngine:
             # batch (LIFO victims) — only still-RUNNING sequences decode.
             batch = [r for r in batch if r.state == RUNNING]
         if not batch:
+            self._settle()
             return
         with perf.phase("llm.decode.build"):
             t0 = time.time()
@@ -958,10 +1065,16 @@ class LLMEngine:
                 # the last sequence position back (nothing was computed
                 # at admission), so its first step re-feeds that token —
                 # write-then-attend then recomputes its logits for the
-                # first sample. Rows 1..n-1 feed the lane's proposals
-                # (the proposal budget keeps them inside max_seq).
-                head = [req.prompt[slot] if slot < len(req.prompt)
-                        else req.output[slot - len(req.prompt)], *p, *pad,
+                # first sample. A lane whose prompt ended in a chunk
+                # of this step has its token on the device (``firsts``
+                # puts it in the row's place; the host has not seen
+                # it, so the row says 0). Rows 1..n-1 feed the lane's
+                # proposals (the proposal budget keeps them inside
+                # max_seq).
+                fed = slot - len(req.prompt)
+                head = [req.prompt[slot] if fed < 0 else
+                        req.output[fed] if fed < len(req.output) else 0,
+                        *p, *pad,
                         *rows, *pad,
                         *[table[s // bs] for s in rows], *pad,
                         *[s % bs for s in rows], *pad]
@@ -985,21 +1098,35 @@ class LLMEngine:
             greedy = [r.greedy for r in batch]
             on_device = sum(greedy)
             self._counts = (n_live, sum(ctx), sum(rows_per_lane), on_device)
-        # block_until_ready on the ids bounds the DEVICE span (they are
+            # The ids of the prompts that end in a chunk still in
+            # flight go to their lanes on the device.
+            firsts = self._no_firsts
+            for chunk in self._pending:
+                if chunk.handed and chunk.req is not None:
+                    firsts = _place_first(
+                        firsts, self._lane_ids[chunk.req.lane],
+                        chunk.result)
+        # ONE host array goes in beside the parameters and the pools:
+        # the kept one, copied in the call (a write after it, a lane
+        # given back while the chunks are settled, is not seen), and
+        # ``firsts`` from the device. With the call back, every program
+        # of the step is in the device's queue, and only now does the
+        # host wait: for each chunk in turn, then for the ids.
+        # block_until_ready on them bounds the DEVICE span (they are
         # the program's last output: the argmax of its logits); the
         # fetch that follows is then a copy of max_batch x Q ints,
         # charged to the host, and the logits stay where they are unless
-        # a lane samples with a temperature. ONE host array goes in
-        # beside the parameters and the pools: the kept one, which
-        # nothing writes again before the ids are ready.
-        with perf.device("llm.decode.device") as dev:
+        # a lane samples with a temperature.
+        with perf.dispatch("llm.decode.device") as span:
             window = () if kvw is None else kvw.pools
             logits, ids, *pools = self._decode(
-                self.params, self._inputs, *self.kv.pools, *window, q=Q)
-            dev.dispatched()
-            self._take_back(pools)
+                self.params, self._inputs, *self.kv.pools, *window, q=Q,
+                firsts=firsts)
+        self._take_back(pools)
+        self._settle()
+        with span.waiting():
             jax.block_until_ready(ids)
-        device_s = dev.seconds
+        device_s = span.seconds
         perf.add_cost(cost)
         sampling, emitting = perf.phase("llm.sample"), perf.phase("llm.emit")
         with sampling:
@@ -1021,6 +1148,8 @@ class LLMEngine:
         emitted_total = 0
         decided = [0, 0]                # by the host, by the device
         for i, req in enumerate(batch):
+            if req.state != RUNNING:
+                continue    # its first token, settled above, ended it
             p = props[req.rid]
             slot = req.context_len
             lane = req.lane             # a finish below gives it back
@@ -1103,8 +1232,11 @@ class LLMEngine:
     def step(self) -> int:
         """One scheduler iteration: admit -> prefill -> one decode step
         for every running sequence (one token each; with speculation on
-        it may emit several). Returns the number of in-flight sequences
-        after the step."""
+        it may emit several). The step's programs, its chunks and (behind
+        two chunks or more) the decode program, are dispatched back to
+        back and only then awaited, in that order (``_run_prefills``,
+        ``_settle``).
+        Returns the number of in-flight sequences after the step."""
         perf = self._step_perf
         t_lock = time.perf_counter()
         with self._lock:
@@ -1220,7 +1352,7 @@ class LLMEngine:
             params, window = self._program_specs()
             text = self._decode.lower(
                 params, _i32(*self._inputs.shape), *self._pool_specs,
-                *window, q=Q).as_text()
+                *window, q=Q, firsts=_i32(B)).as_text()
             mode = _KERNEL_MODES[key] = (
                 "compiled" if "tpu_custom_call" in text else "interpret")
         return mode

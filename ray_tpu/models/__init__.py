@@ -16,13 +16,17 @@ for what the configuration's own module says of it, a ``Serving``:
 
   init      ``init(key, cfg)``: the parameters as served
   step      the decode step, ``(params, packed, *pools, *window pools,
-            q=, cfg=)`` -> ``(logits, ids, *pools, *window pools)``.
-            ``packed`` is ONE int32 array ``[max_batch, W]``, a lane a
-            row: the step's whole bookkeeping side by side
+            q=, firsts=, cfg=)`` -> ``(logits, ids, *pools, *window
+            pools)``. ``packed`` is ONE int32 array ``[max_batch, W]``,
+            a lane a row: the step's whole bookkeeping side by side
             (``step_columns``), which the engine keeps current from
             step to step and the program takes apart by static slices
             (``unpack_step``). ``q`` is the rows a lane, a Python int:
-            a shape of the program, not a value in it
+            a shape of the program, not a value in it. ``firsts`` is
+            an int32 ``[max_batch]`` DEVICE array: where it is not
+            negative it is the lane's row-0 token, which a chunk
+            program queued before this step decided and the host has
+            not seen (``unpack_step``); -1 takes the packed array's
   chunk     one span of a prompt as ONE program, ``(params, tokens,
             *pools, table, *window, cfg=)`` ->
             ``(row, id, *pools, *window pools)``. It writes the span's
@@ -184,13 +188,21 @@ def pack_step(tokens, positions, block_tables, context_lens, q_lens,
         axis=1, dtype=np.int32)
 
 
-def unpack_step(packed, q: int, win_len: int = 0):
+def unpack_step(packed, q: int, win_len: int = 0, firsts=None):
     """``step_columns``' array, inside the program, back into its
     parts by static slices: ``(tokens, positions, block_tables,
     context_lens, q_lens, slot_blocks, slot_offsets, window)``, where
     ``window`` is ``(table, first, slot_blocks)`` of the kind of layer
-    with a window and None without one."""
+    with a window and None without one. ``firsts`` (``Serving.step``)
+    takes the place of a lane's row-0 token where it is not negative:
+    the one value of a new lane that the host does not hold when it
+    queues the step behind the lane's last prefill chunk."""
     c = step_columns(q, win_len)
+    if firsts is not None:
+        import jax.numpy as jnp
+
+        packed = packed.at[:, c.tokens].set(
+            jnp.where(firsts >= 0, firsts, packed[:, c.tokens]))
     part = lambda start: packed[:, start:start + q]
     window = None
     if win_len:

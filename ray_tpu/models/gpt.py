@@ -375,7 +375,7 @@ def _paged_sublayer(h, p, layer, k_pool, v_pool, block_tables, context_lens,
 
 
 def forward_step(params, packed, k_pool, v_pool, *, q: int,
-                 cfg: GPTConfig):
+                 cfg: GPTConfig, firsts=None):
     """One decode step for a batch of in-flight sequences: ``q`` rows a
     lane against the paged pool in ONE batched paged-attention forward.
 
@@ -406,6 +406,9 @@ def forward_step(params, packed, k_pool, v_pool, *, q: int,
         scan's carry, so steady-state decode writes the step's rows
         into the donated buffers and copies nothing of the stack).
       q: rows a lane, a Python int (a shape, not a value).
+      firsts: [b] int32 or None — a lane's row-0 token where not
+        negative, decided on the device by the chunk program queued
+        before this step (models/__init__.py ``unpack_step``).
 
     Returns (logits [b, q, vocab], ids [b, q] int32, k_pool, v_pool):
     ``ids`` is the argmax of each logits row (the first index of the
@@ -415,7 +418,7 @@ def forward_step(params, packed, k_pool, v_pool, *, q: int,
     from . import unpack_step
 
     (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
-     slot_offsets, _) = unpack_step(packed, q)
+     slot_offsets, _) = unpack_step(packed, q, firsts=firsts)
     x = _embed(params, tokens, positions, cfg)
 
     def layer(carry, xs):

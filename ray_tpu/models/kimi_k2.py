@@ -365,7 +365,8 @@ def _counters(sizes, rows: int, cfg: KimiK2Config, q: int, in_runs):
         (len(COUNTERS), q)).astype(jnp.int32)
 
 
-def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config):
+def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
+                 firsts=None):
     """One decode step (models/gpt.py ``forward_step``'s contract) over
     ONE pool of latent rows ``[layers, num_blocks, block_size,
     row_width]``, in the absorbed form: each row's latent row is
@@ -379,7 +380,7 @@ def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config):
     from . import unpack_step
 
     (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
-     slot_offsets, _) = unpack_step(packed, q)
+     slot_offsets, _) = unpack_step(packed, q, firsts=firsts)
     B, Q = tokens.shape
     nope, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     x = params["embed"][tokens]

@@ -350,7 +350,7 @@ COUNTERS = ("moe_experts_hit", "moe_load_max_x1000",
 
 
 def forward_step(params, packed, k_pool, v_pool, k_win, v_win, *, q: int,
-                 cfg: LagunaConfig):
+                 cfg: LagunaConfig, firsts=None):
     """One decode step (models/gpt.py ``forward_step``'s contract) over
     two kinds of pool. ``packed`` carries the window kind's columns too
     (module docstring); a window layer writes each row at (its index,
@@ -369,7 +369,7 @@ def forward_step(params, packed, k_pool, v_pool, k_win, v_win, *, q: int,
     bs = k_pool.shape[2]
     (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
      slot_offsets, (win_tables, win_first, win_slots)) = unpack_step(
-        packed, Q, window_table_len(cfg.sliding_window, bs, Q))
+        packed, Q, window_table_len(cfg.sliding_window, bs, Q), firsts)
     win_lens = context_lens - win_first * bs
     # Row i of a lane sits at ctx - q_len + i and sees keys from that
     # less (window - 1) on, in the window table's own coordinates.

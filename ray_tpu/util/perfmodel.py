@@ -285,13 +285,15 @@ def roofline(cost: StepCost, device_s: float, host_gap_s: float = 0.0,
 #: The registry of span names on the two step paths: name -> (kind,
 #: what it covers). ``kind`` None is a HOST phase (``phase()``: its
 #: milliseconds land in ``phases_ms``); any other kind is a DEVICE span
-#: (``device()``: dispatch to ready, its milliseconds land in
-#: ``device_ms`` and ``device_ms_by[kind]``). Host phases of one step
-#: never nest in one another or in a device span, so they partition the
-#: step's host time. While a ``jax.profiler`` session is open every
-#: name is also a ``TraceAnnotation`` for the same interval, so the
-#: span lies on the trace's host plane beside the device ops. A name
-#: that is not here is an error. The names in ``ANNOTATIONS`` are the
+#: (``device()``: dispatch to ready; ``dispatch()``: a program's
+#: dispatch and, later, the host's wait for it, two halves with host
+#: work and other programs' halves between them; its milliseconds land
+#: in ``device_ms`` and ``device_ms_by[kind]``). Host phases of one step
+#: never nest in one another or in a device span's half, so they
+#: partition the step's host time. While a ``jax.profiler`` session is
+#: open every name is also a ``TraceAnnotation`` for the same interval,
+#: so the span lies on the trace's host plane beside the device ops. A
+#: name that is not here is an error. The names in ``ANNOTATIONS`` are the
 #: accounting's own: an interval it times itself (the gap between two
 #: steps, the two halves of a device span, a collection) and annotates
 #: while a session is open; they lie over or inside the phases and
@@ -300,24 +302,29 @@ PHASES: Dict[str, tuple] = {
     "llm.admit": (None, "admission of waiting requests: prefix lookup, "
                         "block grants"),
     "llm.prefill.host": (None, "a prefill chunk's host side: input "
-                               "arrays, prefix register, first-token "
-                               "sample and emit, its request span"),
-    "llm.prefill.device": ("prefill", "one prefill chunk, dispatch to "
-                                      "logits ready (the pool write is "
-                                      "dispatched inside it)"),
+                               "arrays, prefix register, the lane of a "
+                               "prompt that ends here"),
+    "llm.prefill.device": ("prefill", "one prefill chunk (the pool write "
+                                      "is inside its program): its "
+                                      "dispatch, and the host's wait for "
+                                      "its result once the step's "
+                                      "programs are queued"),
     "llm.slots": (None, "writable KV slots for every decode lane: block "
                         "grants, copy-on-write, preemption"),
     "llm.decode.build": (None, "the decode (or verify) program's input "
                                "arrays for max_batch lanes; proposals "
                                "under speculation"),
-    "llm.decode.device": ("decode", "the decode or verify program, "
-                                    "dispatch to its argmax ids ready"),
+    "llm.decode.device": ("decode", "the decode or verify program: its "
+                                    "dispatch, and the wait for its "
+                                    "argmax ids behind the chunks'"),
     "llm.sample": (None, "device_get of the program's ids, which greedy "
                          "lanes take; for lanes with a temperature also "
                          "of the logits, and the sampler (or "
-                         "verify_tokens) on their rows"),
-    "llm.emit": (None, "tokens onto the request queues, finishes, block "
-                       "release, speculative rollback"),
+                         "verify_tokens) on their rows, a prompt's "
+                         "first token among them"),
+    "llm.emit": (None, "tokens onto the request queues (a prompt's "
+                       "first when its chunk is seen done), finishes, "
+                       "block release, speculative rollback"),
     "llm.trace": (None, "the per-request llm.decode_step span copies, "
                         "one a traced lane (util/tracing ring)"),
     "llm.publish": (None, "step_log and the rtpu_llm_* gauge writes"),
@@ -337,12 +344,13 @@ _OWN_INTERVALS = {
     "llm.decode.dispatch": "llm.decode.device until the jitted call has "
                            "returned its futures: argument hand-over and "
                            "enqueue",
-    "llm.decode.wait": "the rest of llm.decode.device: the wait for the "
-                       "ids and the wake-up after it",
+    "llm.decode.wait": "the other half of llm.decode.device: the host "
+                       "blocked on the ids, and the wake-up after it",
     "llm.prefill.dispatch": "llm.prefill.device until the chunk program's "
                             "call has returned",
-    "llm.prefill.wait": "the rest of llm.prefill.device: the wait for its "
-                        "result and the wake-up",
+    "llm.prefill.wait": "the other half of llm.prefill.device, after the "
+                        "step's last dispatch: the host blocked on the "
+                        "chunk's result, and the wake-up",
     "py.gc": "one pass of the interpreter's collector, on the thread that "
              "ran it (gc.callbacks)",
 }
@@ -439,62 +447,106 @@ class _Span:
     """One registry name's reusable context manager: host clock and,
     in a step that began with a profiler session open, a
     TraceAnnotation over the same interval (the annotation starts when
-    it is built, so each interval builds its own). A device span named
-    ``<stem>.device`` can be cut in two by ``dispatched()``. Not
-    re-entrant; one thread drives a StepAccounting (the engine under
-    its lock, a training loop's thread)."""
+    it is built, so each interval builds its own). Not re-entrant; one
+    thread drives a StepAccounting (the engine under its lock, a
+    training loop's thread)."""
 
-    __slots__ = ("_acc", "name", "_kind", "_t0", "_t_mid", "_ann", "_half",
-                 "_halves", "seconds", "dispatch_seconds")
+    __slots__ = ("_acc", "name", "_kind", "_t0", "_ann", "seconds")
 
     def __init__(self, acc: "StepAccounting", name: str):
         self._acc = acc
         self.name = name
         self._kind = PHASES[name][0]
-        self._t0 = self._t_mid = 0.0
-        self._ann = self._half = None
-        stem = name[:-len(".device")] if name.endswith(".device") else None
-        self._halves = stem and (stem + ".dispatch", stem + ".wait")
-        self.seconds = self.dispatch_seconds = 0.0
+        self._t0 = 0.0
+        self._ann = None
+        self.seconds = 0.0
 
     def __enter__(self):
         if self._acc._traced:
             self._ann = _annotation(self.name)
-            if self._halves:
-                self._half = _annotation(self._halves[0])
         self._t0 = time.perf_counter()
         return self
 
-    def dispatched(self):
-        """Inside a ``<stem>.device`` span: the jitted call has
-        returned its futures. What came before is the host's dispatch
-        (argument hand-over and enqueue), what follows the wait."""
-        self._t_mid = time.perf_counter()
-        if self._half is not None:
-            self._half.__exit__(None, None, None)
-            self._half = _annotation(self._halves[1])
-
     def __exit__(self, *exc):
         self.seconds = s = time.perf_counter() - self._t0
-        if self._half is not None:
-            self._half.__exit__(*exc)
-            self._half = None
         if self._ann is not None:
             self._ann.__exit__(*exc)
             self._ann = None
         acc = self._acc
-        kind = self._kind
-        if kind is None:
+        if self._kind is None:
             name = self.name
             acc._phase_s[name] = acc._phase_s.get(name, 0.0) + s
-            return False
+        else:
+            acc.add_device(s, kind=self._kind)
+        return False
+
+
+class _Program:
+    """One dispatched program's device span ``<stem>.device``, open
+    from its dispatch until the host has seen its result, so several
+    can be open at once: a step queues all its programs and then
+    collects them in the device's order. The span is its two halves,
+    each a ``with`` block on the host's clock (and, under a profiler
+    session, the span's name with ``<stem>.dispatch`` or ``<stem>.wait``
+    inside it over the same interval):
+
+      with acc.dispatch(name) as prog:   # the jitted call, until it
+          out = program(...)             # has returned its futures
+      ...                                # host phases, more dispatches
+      with prog.waiting():               # the host blocked on ``out``,
+          jax.block_until_ready(out)     # and the wake-up after it
+
+    What the host does between the two is not in the span: the halves
+    of a step's spans are disjoint from one another and from its host
+    phases, whatever the device ran meanwhile. ``dispatch_seconds`` and
+    ``seconds`` (both halves) can be read once the wait has closed."""
+
+    __slots__ = ("_acc", "_kind", "_names", "_anns", "_t0", "_waiting",
+                 "seconds", "dispatch_seconds")
+
+    def __init__(self, acc: "StepAccounting", name: str, kind: str):
+        self._acc = acc
+        self._kind = kind
+        stem = name[:-len("device")]
+        self._names = (name, stem + "dispatch", stem + "wait")
+        self._anns = ()
+        self._t0 = 0.0
+        self._waiting = False
+        self.seconds = self.dispatch_seconds = 0.0
+
+    def waiting(self) -> "_Program":
+        """The span's second ``with`` block (itself, for the name at
+        the call site)."""
+        return self
+
+    def __enter__(self):
+        acc = self._acc
+        if self._waiting:
+            # From the step's first blocking fetch on, a program that
+            # is dispatched was not queued before the host waited.
+            acc._waited = True
+        if acc._traced:
+            self._anns = (_annotation(self._names[0]),
+                          _annotation(self._names[1 + self._waiting]))
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        s = time.perf_counter() - self._t0
+        for ann in reversed(self._anns):
+            ann.__exit__(*exc)
+        self._anns = ()
+        acc, kind = self._acc, self._kind
         acc.add_device(s, kind=kind)
-        if self._t_mid:
+        self.seconds += s
+        if not self._waiting:
             # The dispatch half is the host's work; the wait is not.
-            self.dispatch_seconds = d = self._t_mid - self._t0
-            acc._dispatch_by[kind] = acc._dispatch_by.get(kind, 0.0) + d
-            acc._dispatch_s += d
-            self._t_mid = 0.0
+            self.dispatch_seconds = s
+            acc._dispatch_by[kind] = acc._dispatch_by.get(kind, 0.0) + s
+            acc._dispatch_s += s
+            acc._programs += 1
+            acc._programs_queued += not acc._waited
+            self._waiting = True
         return False
 
 
@@ -509,9 +561,14 @@ class StepAccounting:
       step_ms / device_ms / host_gap_ms   begin() to finish(), the sum
                     of the device spans, and the rest
       device_ms_by  {kind: ms}; sums to device_ms
-      dispatch_ms_by  {kind: ms} of the device spans that were cut by
-                    dispatched(): the part before the jitted call
+      dispatch_ms_by  {kind: ms} of the device spans opened by
+                    dispatch(): the half before the jitted call
                     returned; each at most its device_ms_by value
+      programs / programs_queued   (a step that used dispatch()) the
+                    programs it dispatched, and those of them that
+                    were dispatched before its first waiting(): equal
+                    where the host blocked on nothing until the
+                    step's whole work was in the device's queue
       phases_ms     {host phase: ms}; with other_ms, what no phase
                     names, they sum to host_gap_ms
       between_ms    the previous finish() to this begin() (absent on
@@ -546,7 +603,8 @@ class StepAccounting:
                  "_phase_s", "_spans", "_flops", "_hbm_bytes",
                  "_tokens", "_finish_t", "_finish_cpu", "_between_s",
                  "_between", "_gap_ann", "_idle_s", "_lock_wait_s",
-                 "_gc_seen", "_gc_s", "idle_total_s", "idle_waits", "last")
+                 "_gc_seen", "_gc_s", "_programs", "_programs_queued",
+                 "_waited", "idle_total_s", "idle_waits", "last")
 
     def __init__(self, hw: Optional[HardwarePeak] = None,
                  n_chips: int = 1, between: Optional[str] = None):
@@ -570,6 +628,9 @@ class StepAccounting:
         self._flops = 0.0
         self._hbm_bytes = 0.0
         self._tokens = 0
+        # dispatch() spans this step, those before its first waiting().
+        self._programs = self._programs_queued = 0
+        self._waited = False
         self._finish_t: Optional[float] = None
         # The thread's CPU clock at the last finish() (at the first
         # begin(), before there was one).
@@ -635,6 +696,8 @@ class StepAccounting:
         self._flops = 0.0
         self._hbm_bytes = 0.0
         self._tokens = 0
+        self._programs = self._programs_queued = 0
+        self._waited = False
 
     def _span(self, name: str) -> _Span:
         span = self._spans.get(name)
@@ -655,13 +718,22 @@ class StepAccounting:
 
     def device(self, name: str) -> _Span:
         """Context manager over a DEVICE span, dispatch to ready; its
-        ``seconds`` (and, where ``dispatched()`` cut it, its
-        ``dispatch_seconds``) can be read after it closes. Price the
-        work with add_cost()."""
+        ``seconds`` can be read after it closes. Price the work with
+        add_cost()."""
         span = self._span(name)
         if span._kind is None:
             raise KeyError(f"{name!r} is a host phase: use phase()")
         return span
+
+    def dispatch(self, name: str) -> _Program:
+        """A new DEVICE span ``<stem>.device`` that stays open past
+        its ``with`` block, which is its dispatch half; its
+        ``waiting()`` block closes it (``_Program``)."""
+        kind = PHASES.get(name, (None,))[0]
+        if kind is None or not name.endswith(".device"):
+            raise KeyError(f"{name!r} is not a device span that "
+                           f"perfmodel.PHASES cuts in two")
+        return _Program(self, name, kind)
 
     def step(self, name: str, step_num: int):
         """``jax.profiler.StepTraceAnnotation`` around the whole step
@@ -752,6 +824,9 @@ class StepAccounting:
         }
         if self._between_s is not None:
             out["between_ms"] = self._between_s * 1e3
+        if self._programs:
+            out["programs"] = self._programs
+            out["programs_queued"] = self._programs_queued
         self.last = out
         if record_as is not None:
             record_device_step(record_as, time.time() - wall_s, out,

@@ -87,7 +87,7 @@ def drive(eng, after_step=lambda: None):
     return out
 
 
-def expected_inputs(eng, packed):
+def expected_inputs(eng, packed, firsts):
     """The decode program's array built FROM SCRATCH from the requests'
     own state, as the engine built it every step before it kept one
     (zeros, then a loop over the lanes). Only a lane's row count and
@@ -116,8 +116,13 @@ def expected_inputs(eng, packed):
         lanes.add(i)
         n = int(packed[i, c.q_len])
         assert 1 <= n <= Q
-        tokens[i, 0] = (req.prompt[slot] if slot < len(req.prompt)
-                        else req.output[slot - len(req.prompt)])
+        fed = slot - len(req.prompt)
+        if fed < len(req.output):
+            tokens[i, 0] = req.prompt[slot] if fed < 0 else req.output[fed]
+        else:
+            # Its prompt ended in a chunk of this step: the token is on
+            # the device, in ``firsts`` at its lane, and the row says 0.
+            assert fed == len(req.output) and firsts[i] >= 0
         tokens[i, 1:n] = packed[i, c.tokens + 1:c.tokens + n]
         for j in range(n):
             positions[i, j] = slot + j
@@ -167,11 +172,12 @@ def check(eng, case):
     equals the one recorded on the parent commit under ``case``."""
     real, seen = eng._decode, []
 
-    def decode(params, packed, *pools, q):
+    def decode(params, packed, *pools, q, firsts):
         assert packed is eng._inputs and q == eng._q_rows
-        np.testing.assert_array_equal(packed, expected_inputs(eng, packed))
+        np.testing.assert_array_equal(
+            packed, expected_inputs(eng, packed, np.asarray(firsts)))
         seen.append(int((packed[:, eng._cols.q_len] > 1).sum()))
-        return real(params, packed, *pools, q=q)
+        return real(params, packed, *pools, q=q, firsts=firsts)
 
     eng._decode = decode
     got = drive(eng, after_step=lambda: check_tables(eng))

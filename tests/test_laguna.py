@@ -60,19 +60,22 @@ def _logits_of(eng):
     back (fetched here whether or not the request is greedy), then one
     a decode step."""
     rows, last = {}, []
-    activate, fetch, chunk = (eng._activate, eng._fetch_decisions,
-                              eng._prefill_chunk)
+    settle, fetch, chunk = (eng._settle, eng._fetch_decisions,
+                            eng._prefill_chunk)
 
     def on_chunk(*args):
         out = chunk(*args)
-        last[:] = [out[0]]
+        last.append(out[0])
         return out
 
-    def on_activate(req, first):
-        if first is not None:
-            rows.setdefault(req.rid, []).append(
-                np.asarray(jax.device_get(last[0]), np.float32))
-        return activate(req, first)
+    def on_settle():
+        # The chunks not yet settled are the last ones dispatched.
+        for ch, row in zip(eng._pending, last[-len(eng._pending):]):
+            if ch.done and ch.req is not None:
+                rows.setdefault(ch.req.rid, []).append(
+                    np.asarray(jax.device_get(row), np.float32))
+        last.clear()
+        return settle()
 
     def on_fetch(logits, ids, all_greedy):
         got = np.asarray(jax.device_get(logits), np.float32)
@@ -81,7 +84,7 @@ def _logits_of(eng):
                 rows.setdefault(r.rid, []).append(got[r.lane, 0])
         return fetch(logits, ids, all_greedy)
 
-    eng._activate, eng._fetch_decisions = on_activate, on_fetch
+    eng._settle, eng._fetch_decisions = on_settle, on_fetch
     eng._prefill_chunk = on_chunk
     return rows
 

@@ -922,3 +922,241 @@ def test_kept_step_array_equals_one_built_from_scratch_every_step(case):
     import kept_array
 
     kept_array.check(kept_array.engines()[case](), case)
+
+
+# ---------------------------------------------------------------------------
+# A step's programs are all queued before the host blocks on any (PR 47)
+# ---------------------------------------------------------------------------
+def _alone(req, cfg=CFG, params=PARAMS, **engine):
+    """The request's tokens when it runs alone in a fresh engine."""
+    engine = {"num_blocks": 64, "block_size": 8, **engine}
+    eng = LLMEngine(params, cfg, **engine)
+    h = eng.add_request(**req)
+    _drain(eng)
+    return list(h.output)
+
+
+def _queued(eng):
+    """(programs, programs_queued) of the engine's last step."""
+    last = eng._step_perf.last
+    return last["programs"], last["programs_queued"]
+
+
+_LIVE = [dict(prompt=[1, 2, 3, 4, 5], max_tokens=24),
+         dict(prompt=[9, 8, 7], max_tokens=24, seed=5, temperature=0.9)]
+_NEW = [dict(prompt=[20, 21, 22, 23, 24, 25], max_tokens=9),
+        dict(prompt=[30, 31, 32], max_tokens=7)]
+
+
+def _mixed_step(new, **engine):
+    """Two lanes decoding, then ``new`` arriving together: the step
+    that runs their chunks beside the live lanes. Returns the engine
+    after that step and every handle (the live ones first)."""
+    eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8, max_batch=6,
+                    **engine)
+    live = [eng.add_request(**r) for r in _LIVE]
+    eng.step()
+    eng.step()
+    assert all(h.state == RUNNING and len(h.output) >= 2 for h in live)
+    late = [eng.add_request(**r) for r in new]
+    eng.step()
+    return eng, live + late
+
+
+def _case_two_finishing_chunks_beside_live_lanes():
+    eng, hs = _mixed_step(_NEW)
+    # Two chunks and the decode program, the host blocking on none of
+    # them before all three were dispatched; both new lanes decoded in
+    # that very step, their first tokens handed over on the device.
+    assert _queued(eng) == (3, 3)
+    assert [len(h.output) for h in hs[2:]] == [2, 2]
+    assert eng.stats()["last_step"]["device_ms_by"].keys() \
+        == {"prefill", "decode"}
+    _drain(eng)
+    assert [h.output for h in hs] == [_alone(r) for r in _LIVE + _NEW]
+
+
+def _case_a_sampling_request_beside_greedy_ones():
+    sampled = dict(prompt=[40, 41, 42, 43], max_tokens=8, seed=3,
+                   temperature=0.8, top_k=20)
+    eng, hs = _mixed_step([_NEW[0], sampled, _NEW[1]])
+    # The sampler needs the second chunk's logits row on the host
+    # before the decode step can be built: the step blocks there, with
+    # two programs queued, and the two after it see a host that waited.
+    assert _queued(eng) == (4, 2)
+    assert [len(h.output) for h in hs[2:]] == [2, 2, 2]
+    _drain(eng)
+    assert [h.output for h in hs] == [
+        _alone(r) for r in _LIVE + [_NEW[0], sampled, _NEW[1]]]
+    assert eng.stats()["tokens_decided_on_host"] == 24 + 8
+
+
+def _case_a_first_token_that_is_a_stop_token():
+    first = _alone(_NEW[0])[0]
+    stopped = dict(_NEW[0], stop_tokens=[first])
+    eng, hs = _mixed_step([stopped, _NEW[1]])
+    h = hs[2]
+    # It was handed a lane before its token was known; the token ended
+    # it when its chunk was seen done: the lane and the blocks are
+    # back, and the id its lane's row decoded in that step is nowhere.
+    assert _queued(eng) == (3, 3)
+    assert h.finish_reason == "stop" and h.output == [first]
+    assert h.emitted == 1 and list(h.tokens()) == [first]
+    assert h.lane is None and h.block_table == []
+    assert len(eng._free_lanes) == eng.max_batch - 3
+    assert len(hs[3].output) == 2
+    _drain(eng)
+    assert eng.kv.num_free == eng.kv.capacity
+    assert [x.output for x in hs[:2] + hs[3:]] \
+        == [_alone(r) for r in _LIVE + [_NEW[1]]]
+
+
+def _case_a_full_prefix_hit():
+    prompt = list(range(50, 66))            # two whole blocks
+    eng, hs = _mixed_step([dict(prompt=prompt, max_tokens=5)])
+    again = eng.add_request(prompt, max_tokens=5)
+    eng.step()
+    # Nothing was computed at admission, so nothing is handed over: the
+    # decode step alone decides its first token.
+    assert again.cached_tokens == len(prompt)
+    assert _queued(eng) == (1, 1) and len(again.output) == 1
+    _drain(eng)
+    assert again.output == hs[2].output \
+        == _alone(dict(prompt=prompt, max_tokens=5))
+
+
+def _case_a_proposer_configured():
+    ngram = {"mode": "ngram", "k": 3}
+    eng, hs = _mixed_step(_NEW, speculative=ngram)
+    # The proposer continues from a new lane's first token: the step
+    # fetches it as soon as its chunk is dispatched, as it always did.
+    assert _queued(eng) == (3, 1)
+    _drain(eng)
+    assert [h.output for h in hs] == [_alone(r) for r in _LIVE + _NEW]
+
+
+def _case_preempted_with_its_chunk_in_flight():
+    # Capacity 5 blocks of 8. ``old`` holds one and needs a second for
+    # slot 8 in the very step that admits ``mid`` and ``new`` (two
+    # blocks each: the pool is dry) and dispatches their only chunks.
+    # The newest lane is the victim: ``new``, whose first token is
+    # still on the device.
+    old = dict(prompt=[1, 2, 3, 4, 5, 6, 7], max_tokens=12)
+    mid = dict(prompt=[10, 11, 12, 13, 14, 15, 16, 17], max_tokens=6)
+    new = dict(prompt=[20, 21, 22, 23, 24, 25, 26, 27], max_tokens=6)
+    eng = LLMEngine(PARAMS, CFG, num_blocks=6, block_size=8)
+    a = eng.add_request(**old)
+    eng.step()
+    assert a.context_len == 8 and len(a.block_table) == 1
+    m, b = eng.add_request(**mid), eng.add_request(**new)
+    dropped, settle = [], eng._settle
+
+    def on_settle():
+        dropped.extend(ch for ch in eng._pending if ch.req is None)
+        return settle()
+
+    eng._settle = on_settle
+    eng.step()
+    assert b.preemptions == 1 and b.state == PREEMPTED
+    assert len(dropped) == 1 and dropped[0].done and dropped[0].handed
+    # Its pending record was dropped: no token was emitted for it, its
+    # lane is free again, and the step still closed the chunk's span.
+    assert b.output == [] and b.first_token_t is None and b.lane is None
+    assert len(m.output) == 2 and m.preemptions == 0
+    assert eng._pending == [] and _queued(eng) == (3, 3)
+    for row in eng._chunk_log:          # the ring entry's prefill_chunks
+        assert row[:2] == [8, 0] and row[2] >= row[3] > 0.0
+    _drain(eng)
+    assert [h.output for h in (a, m, b)] \
+        == [_alone(r) for r in (old, mid, new)]
+    assert eng.kv.num_free == eng.kv.capacity
+
+
+def _case_one_chunk_in_flight_is_awaited_before_the_decode_is_built():
+    eng, hs = _mixed_step(_NEW[:1])
+    # The host blocks once for it either way; its token is on the host
+    # when the decode step is built, and nothing is handed over.
+    assert _queued(eng) == (2, 1)
+    assert len(hs[2].output) == 2
+    _drain(eng)
+    assert [h.output for h in hs] == [_alone(r) for r in _LIVE + _NEW[:1]]
+
+
+@pytest.mark.parametrize("case", [
+    _case_two_finishing_chunks_beside_live_lanes,
+    _case_a_sampling_request_beside_greedy_ones,
+    _case_a_first_token_that_is_a_stop_token,
+    _case_a_full_prefix_hit,
+    _case_a_proposer_configured,
+    _case_preempted_with_its_chunk_in_flight,
+    _case_one_chunk_in_flight_is_awaited_before_the_decode_is_built,
+], ids=lambda f: f.__name__[len("_case_"):])
+def test_a_step_queues_its_programs_and_blocks_only_where_it_must(case):
+    """What decides a finishing prompt's path is what the engine can
+    see in the request: every branch gives the tokens the requests get
+    alone, and only a sampler, a proposer or a chunk that is alone in
+    flight makes the host wait before the step's last dispatch."""
+    case()
+
+
+def test_the_chat_drivers_set_up_replayed():
+    """``benchmark/drivers/serve_closed_loop.py``'s set-up, sizes cut
+    for a CPU: the unshared reference request streams its 16 tokens
+    from the background loop while, one after another as the driver
+    sends them, every shared prefix is registered by a ``max_tokens:
+    1`` request and one ``max_tokens: 1`` request of every tail length
+    follows behind the first prefix. Each of these ends with its first
+    token, arriving while another lane decodes; a tail request is a
+    prefix hit whose chunk starts behind cached blocks."""
+    import threading
+
+    cfg = GPTConfig(vocab_size=128, max_seq=128, d_model=64, n_layer=2,
+                    n_head=4, dtype=jnp.float32)
+    params = init(jax.random.PRNGKey(1), cfg)
+    bs, chunk, every = 8, 32, 8
+    rng = np.random.default_rng(47)
+    toks = lambda n: [int(t) for t in rng.integers(1, 128, n)]
+    reference = dict(prompt=toks(40), max_tokens=16)
+    prefixes = [toks(32) for _ in range(4)]
+    firsts = [dict(prompt=p + toks(max(every, bs)), max_tokens=1)
+              for p in prefixes]
+    tails = [dict(prompt=prefixes[0] + toks(r), max_tokens=1)
+             for r in range(every, chunk + 1, every)]
+    engine = dict(num_blocks=96, block_size=bs, max_batch=8,
+                  prefill_chunk_tokens=chunk)
+
+    eng = LLMEngine(params, cfg, **engine)
+    took_lane, take = [], eng._take_lane
+    eng._take_lane = lambda req: (took_lane.append(req.rid), take(req))[1]
+    eng.start()
+    try:
+        ref = eng.add_request(**reference)
+        ref_tokens = []
+        ref_thread = threading.Thread(
+            target=lambda: ref_tokens.extend(ref.tokens()))
+        ref_thread.start()
+        sent = []
+        for req in firsts + tails:
+            h = eng.add_request(**req)
+            assert len(list(h.tokens())) == 1       # until it has ended
+            sent.append(h)
+        ref_thread.join(timeout=120)
+        assert not ref_thread.is_alive()
+    finally:
+        eng.stop()
+    assert eng._fatal is None
+    for h in [ref] + sent:
+        assert h.finish_reason == "length", (h.rid, h.finish_reason)
+    assert ref_tokens == ref.output and len(ref.output) == 16
+    # Every tail request found the whole prefix, and nothing more.
+    assert [h.cached_tokens for h in sent[len(firsts):]] \
+        == [len(prefixes[0])] * len(tails)
+    assert [h.cached_tokens for h in sent[:len(firsts)]] == [0] * 4
+    # A prompt whose first token ends it never holds a lane.
+    assert took_lane == [ref.rid]
+    assert len(eng._free_lanes) == eng.max_batch
+    # The warm-ups ran beside the reference request, not after it.
+    steps = [set(rids) for _, rids in eng.step_log]
+    assert any(ref.rid in s for s in steps)
+    for h, req in zip([ref] + sent, [reference] + firsts + tails):
+        assert h.output == _alone(req, cfg, params, **engine), h.rid

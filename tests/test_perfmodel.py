@@ -186,6 +186,84 @@ def test_step_accounting_lifecycle():
     assert out["tokens"] == 5
 
 
+def test_spans_open_at_once_keep_the_partition():
+    """A step that queues three programs and then collects them: the
+    spans are open together, their halves are disjoint ``with`` blocks.
+    ``device_ms_by`` still sums to ``device_ms``; a span is its two
+    halves and none of the host work between them; no wait half begins
+    before the one before it ended; ``stall_ms`` (the interval less the
+    CPU time and the WAIT halves) is not negative beyond a tick of the
+    thread's CPU clock, as it would be if a wait half held host work
+    too; and the step counts its programs and those queued before its
+    first wait."""
+    tick_ms = 2e3 * time.get_clock_info("thread_time").resolution + 1.0
+
+    def spin(s):
+        end = time.perf_counter() + s
+        while time.perf_counter() < end:
+            pass
+
+    acc = StepAccounting()
+    for _ in range(2):
+        acc.begin()
+        programs, marks = [], []
+        for name in ("llm.prefill.device", "llm.prefill.device",
+                     "llm.decode.device"):
+            with acc.dispatch(name) as prog:
+                spin(0.002)
+            programs.append(prog)
+            with acc.phase("llm.prefill.host"):
+                spin(0.003)             # the device works; the host too
+        for prog in programs:
+            t0 = time.perf_counter()
+            with prog.waiting():
+                time.sleep(0.004)       # blocked on its result
+            marks.append((t0, time.perf_counter()))
+            with acc.phase("llm.emit"):
+                spin(0.001)
+        out = acc.finish()
+        assert sum(out["device_ms_by"].values()) == pytest.approx(
+            out["device_ms"], abs=1e-6)
+        assert set(out["device_ms_by"]) == {"prefill", "decode"}
+        assert out["step_ms"] == pytest.approx(
+            out["device_ms"] + out["host_gap_ms"], abs=1e-6)
+        assert out["host_gap_ms"] == pytest.approx(
+            sum(out["phases_ms"].values()) + out["other_ms"], abs=1e-6)
+        # Nine host blocks of 3 ms and 1 ms lie between the halves,
+        # in no span: each program is 2 ms of dispatch, 4 ms of wait.
+        assert out["phases_ms"]["llm.prefill.host"] >= 9.0
+        for prog in programs:
+            assert 0.002 <= prog.dispatch_seconds < prog.seconds < 0.012
+        assert out["device_ms"] == pytest.approx(
+            1e3 * sum(p.seconds for p in programs), abs=1e-6)
+        assert sum(out["dispatch_ms_by"].values()) == pytest.approx(
+            1e3 * sum(p.dispatch_seconds for p in programs), abs=1e-6)
+        assert all(a[1] <= b[0] for a, b in zip(marks, marks[1:]))
+        assert out["stall_ms"] >= -tick_ms
+        assert (out["programs"], out["programs_queued"]) == (3, 3)
+
+    # A fetch between two dispatches: what follows it was not queued.
+    acc.begin()
+    with acc.dispatch("llm.prefill.device") as first:
+        pass
+    with first.waiting():
+        pass
+    with acc.dispatch("llm.decode.device") as second:
+        pass
+    with second.waiting():
+        pass
+    out = acc.finish()
+    assert (out["programs"], out["programs_queued"]) == (2, 1)
+    # A step that opened no such span carries neither key.
+    acc.begin()
+    acc.add_device(0.001)
+    assert "programs" not in acc.finish()
+    with pytest.raises(KeyError):
+        acc.dispatch("llm.emit")
+    with pytest.raises(KeyError):
+        acc.dispatch("train.wait")      # a span that is not cut in two
+
+
 def test_device_step_ring_records_and_filters():
     perfmodel.clear_device_steps()
     t0 = time.time()

@@ -171,8 +171,10 @@ def test_the_interval_is_a_sum_of_named_parts():
         acc.begin()
         with acc.phase("llm.admit"):
             _spin(0.002)
-        with acc.device("llm.decode.device") as dev:
-            dev.dispatched()
+        with acc.dispatch("llm.decode.device") as dev:
+            pass
+        with dev.waiting():
+            pass
         assert 0.0 <= dev.dispatch_seconds <= dev.seconds
         out = acc.finish()
         _check_partition(out)
@@ -822,7 +824,14 @@ def test_device_steps_table_splits_the_step_and_sums_the_counts():
     assert f"prefill {sum(e['prefill_tokens'] for e in ring)} in " in counts
     assert f"waiting {max(e['waiting'] for e in ring)} at most" in counts
     n_preempted = sum(r.preemptions for r in reqs)
-    assert n_preempted > 0 and counts.endswith(f"preempted {n_preempted}")
+    programs = sum(e["programs"] for e in ring)
+    assert n_preempted > 0 and counts.endswith(
+        f"preempted {n_preempted}; programs {programs}, "
+        f"{sum(e['programs_queued'] for e in ring)} queued before their "
+        f"step's first wait")
+    assert programs == len(ring) + sum(len(e["prefill_chunks"])
+                                       for e in ring) - sum(
+        e["decode_tokens"] == 0 for e in ring)
     assert train == ("  train.step x 1 (t1): 10.00 ms a step = device 8.00 "
                      "(wait 7.00, dispatch 1.00) + host 2.00")
     assert train_phases == \

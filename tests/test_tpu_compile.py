@@ -178,7 +178,7 @@ def _chat_cell_decode_lowered(one_chip):
     pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
     decode = _jit_programs(cfg)[0]
     return decode.lower(params, S((B, step_columns(1).table + MAX_NB), i32),
-                        pool, pool, q=1)
+                        pool, pool, q=1, firsts=S((B,), i32))
 
 
 def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
@@ -257,10 +257,16 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     assert outputs == [f"bf16[{CELL_B},1,{cfg.vocab_size}]",
                        f"s32[{CELL_B},1]", pools, pools], root[:300]
     # Beside the parameters and the two pools the program takes ONE
-    # array: the step's packed bookkeeping (one hand-over a step).
+    # host array, the step's packed bookkeeping (one hand-over a step),
+    # and ``firsts``, ``s32[64]`` from the device: the first tokens of
+    # the prompts whose last chunks are queued before it (PR 47).
     leaves = len(jax.tree_util.tree_leaves(
         jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))))
-    assert _entry_parameters(text) == leaves + 3
+    assert _entry_parameters(text) == leaves + 4
+    entry_params = [line for line in entry.splitlines()
+                    if " parameter(" in line]
+    assert sum(f" s32[{CELL_B}]{{" in line or f" s32[{CELL_B}] " in line
+               for line in entry_params) == 1, entry_params[-4:]
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1 and "%paged_decode" in calls[0].split("=")[0]
@@ -390,7 +396,8 @@ def laguna_programs(one_chip):
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode": decode.lower(
                 params, S((B, step_columns(1, nbw).table + max_nb), i32),
-                full, full, window, window, q=1).compile(),
+                full, full, window, window, q=1,
+                firsts=S((B,), i32)).compile(),
             "chunk": chunk.lower(
                 params, S((1, 512), i32), full, full,
                 S((max_nb + 512 // BS + 2,), i32), window, window,
@@ -467,11 +474,12 @@ def test_laguna_decode_program_reads_the_pools_as_stored(laguna_programs):
     assert _fusions_of(text, f"s32[{CELL_B},8]") == 1
     # params' leaves, the step's ONE packed array, then the full kind's
     # K and V (outputs 2, 3 behind the logits and the ids) and the
-    # window kind's behind them: nothing else is handed over.
+    # window kind's behind them, and ``firsts`` from the device (PR 47):
+    # nothing else is handed over.
     leaves = laguna_programs["param_leaves"]
     assert _aliased(text) == {leaves + 1: 2, leaves + 2: 3,
                               leaves + 3: 4, leaves + 4: 5}
-    assert _entry_parameters(text) == leaves + 5
+    assert _entry_parameters(text) == leaves + 6
     assert _pool_sized(text, "copy", "transpose", "copy-start",
                        "dynamic-update-slice", "concatenate", "pad") == []
     full = f"bf16[2,{LAGUNA_FULL_BLOCKS},{BS},1024]"
@@ -629,7 +637,7 @@ def kimi_programs(one_chip):
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode": decode.lower(
                 params, S((B, step_columns(1).table + max_nb), i32), pool,
-                q=1).compile(),
+                q=1, firsts=S((B,), i32)).compile(),
             "chunk": chunk.lower(
                 params, S((1, 512), i32), pool,
                 S((max_nb + 512 // BS + 2,), i32)).compile(),
@@ -673,9 +681,9 @@ def test_kimi_decode_program_attends_the_latent_pool_as_stored(
     pool = f"bf16[5,{KIMI_BLOCKS},{BS},{KIMI_ROW}]"
     assert _kimi_pool_sized(text, "scatter") == [("scatter", pool)] * 5
     # params' leaves, the step's ONE packed array, then the pool: output
-    # 2 behind the logits and the ids.
+    # 2 behind the logits and the ids; and ``firsts`` from the device.
     assert _aliased(text) == {kimi_programs["param_leaves"] + 1: 2}
-    assert _entry_parameters(text) == kimi_programs["param_leaves"] + 2
+    assert _entry_parameters(text) == kimi_programs["param_leaves"] + 3
     entry = text[text.index("\nENTRY "):]
     root = next(line for line in entry.splitlines()
                 if line.lstrip().startswith("ROOT "))
@@ -821,3 +829,123 @@ def test_gpt2_chunk_program_has_no_chunk_kernel(one_chip, as_tpu):
         S((MAX_NB + 512 // BS + 2,), jnp.int32)).as_text()
     assert "module @jit_llm_prefill_chunk " in text
     assert "tpu_custom_call" not in text and "chunk_attn" not in text
+
+
+# -- the programs of a step, queued back to back (PR 47) ----------------------
+
+def _chunk_program_texts(one_chip):
+    """The three models' chunk programs lowered for the TPU (the chat
+    cell's 512-token chunk behind context; Laguna's and Kimi's at their
+    tiny test shapes, a 64-token chunk behind context), each Mosaic
+    call's serialized body cut out: it carries the path of the checkout
+    it was traced in."""
+    import re
+
+    import test_kimi_k2
+    import test_laguna
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.llm.kv_cache import window_table_len
+    from ray_tpu.models import kimi_k2, laguna, serving
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    i32, bf16 = jnp.int32, jnp.bfloat16
+
+    def shapes(init, cfg):
+        return jax.tree_util.tree_map(
+            lambda leaf: S(leaf.shape, leaf.dtype),
+            jax.eval_shape(lambda: init(jax.random.key(0), cfg)))
+
+    cfg = gpt.GPT2_SMALL
+    pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), bf16)
+    lowered = {"gpt": _jit_programs(cfg)[1].lower(
+        shapes(gpt.init, cfg), S((1, 512), i32), pool, pool,
+        S((MAX_NB + 512 // BS + 2,), i32))}
+    for name, mod, cfg in (("laguna", laguna, test_laguna.TINY),
+                           ("kimi", kimi_k2, test_kimi_k2.TINY)):
+        model, n = serving(cfg), 64
+        pools = [[S((len(kind.layers), 32, BS, width), bf16)
+                  for width in kind.rows] for kind in model.kinds]
+        args = [*pools[0], S((model.max_seq // BS + n // BS + 2,), i32)]
+        if len(pools) > 1:
+            nbw = window_table_len(model.kinds[1].window, BS, 1)
+            args += [*pools[1], S((nbw + 1 + n // BS,), i32)]
+        lowered[name] = _jit_programs(cfg)[1].lower(
+            shapes(mod.init, cfg), S((1, n), i32), *args)
+    return {name: re.sub(r'backend_config = "[^\n]*?"(?=[,}\s])',
+                         'backend_config = "..."', low.as_text())
+            for name, low in lowered.items()}
+
+
+def test_the_chunk_programs_are_what_they_were_before_the_step_queued_them(
+        one_chip, as_tpu):
+    """PR 47 hands a finishing prompt's first token to the decode
+    program on the device and touches no chunk program: ``Serving.chunk``,
+    ``pack_span`` and the three models' chunk functions lower, for the
+    TPU, to the text they lowered to at the parent commit (sha256,
+    recorded there with this function; Laguna's and Kimi's hold the
+    ``chunk_attn`` kernel, GPT-2's none). So the chat cell's warmed
+    chunk lengths keep their compile-cache keys (ROADMAP A7: an
+    XLA-only program's key survives any move of its source); whoever
+    changes a chunk program records the new text knowingly."""
+    import hashlib
+
+    texts = _chunk_program_texts(one_chip)
+    assert "tpu_custom_call" not in texts["gpt"]
+    assert 'kernel_name = "chunk_attn"' in texts["laguna"]
+    assert 'kernel_name = "chunk_attn"' in texts["kimi"]
+    assert {name: hashlib.sha256(text.encode()).hexdigest()[:16]
+            for name, text in texts.items()} == CHUNK_TEXT_AT_PR46
+
+
+CHUNK_TEXT_AT_PR46 = {"gpt": "99837368b616cd4b",
+                      "laguna": "15e00d4b01149d99",
+                      "kimi": "568d0ef5d4d34d94"}
+
+
+def test_the_placing_program_compiles_once_whatever_the_lane():
+    """A finishing prompt's id goes into ``firsts`` at its lane by ONE
+    tiny jitted program whose lane is a traced scalar from the device:
+    eight prompts ending in one step in eight different lanes cost one
+    backend compilation of it in all (on a CPU here; the shapes are the
+    same on a chip)."""
+    from jax import monitoring
+
+    from ray_tpu.llm import engine as llm_engine
+    from ray_tpu.llm.engine import LLMEngine
+
+    cfg = gpt.GPTConfig(vocab_size=128, max_seq=64, d_model=64, n_layer=2,
+                        n_head=4, dtype=jnp.float32)
+    eng = LLMEngine(gpt.init(jax.random.key(0), cfg), cfg, num_blocks=67,
+                    block_size=8, max_batch=9)
+    eng.add_request([1, 2, 3], max_tokens=3)    # warm-up: lane 0
+    while eng.step():
+        pass
+    placed, compiled = [], []
+    place = llm_engine._place_first
+
+    def on_place(firsts, lane, tok):
+        placed.append(int(lane))
+        return place(firsts, lane, tok)
+
+    def listener(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(event)
+
+    monitoring.register_event_duration_secs_listener(listener)
+    llm_engine._place_first = on_place
+    try:
+        for i in range(8):
+            eng.add_request([5, 6, 7 + i], max_tokens=4 + i)
+        while eng.step():
+            pass
+        eng.add_request([9, 9, 9], max_tokens=3)
+        while eng.step():
+            pass
+    finally:
+        llm_engine._place_first = place
+        monitoring.unregister_event_duration_listener(listener)
+    # (the last prompt's chunk was alone in flight: awaited, not placed)
+    assert sorted(placed) == list(range(8))
+    # One compilation at most (none if a test before this one placed a
+    # token at this batch size in this process).
+    assert len(compiled) <= 1, compiled
