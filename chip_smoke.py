@@ -157,7 +157,7 @@ def _attention_grads(params, tokens, cfg, mesh):
 
 
 def _train_loop(config):
-    """The bench.py train loop (Data ingest -> device_put per step ->
+    """The train loop of the benchmark's train cell (Data ingest -> device_put per step ->
     the tuned GPT-2 step), instrumented: AOT-compiles the step so the
     compiled text can be inspected, and reads the loss on the host after
     every step."""

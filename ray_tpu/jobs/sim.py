@@ -11,8 +11,7 @@ Chaos kills (`shrink`) take slices out from under running gangs, which
 must requeue — never silently die — and queued gang shapes flow back
 into the snapshot as `job_demand`, which is what regrows the fleet.
 
-Used by tests/test_job_plane.py (the end-to-end churn acceptance) and
-``bench.py --jobs`` (makespan + Jain fairness + requeue counts).
+Used by tests/test_job_plane.py (the end-to-end churn acceptance).
 """
 
 from __future__ import annotations

@@ -1253,8 +1253,8 @@ class LLMEngine:
                 # High-water utilization INSIDE the step: post-admission
                 # and post-decode, before finishes drain it — the
                 # end-of-run stats() reading alone always relaxes back
-                # to ~0 (every block freed), which is why SERVE_BENCH
-                # read 0.0 for years.
+                # to ~0 (every block freed), which is why an end-of-run
+                # reader saw 0.0 for years.
                 util_hw = self.kv.utilization()
                 self._run_prefills()
                 self._run_decode()

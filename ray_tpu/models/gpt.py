@@ -81,7 +81,7 @@ class GPTConfig:
 
     def flops_per_token(self) -> float:
         """Training FLOPs/token ≈ 6*N + attention term (delegates to
-        util/perfmodel.py — the shared cost model bench.py and the live
+        util/perfmodel.py — the shared cost model the live
         llm_mfu/train_mfu telemetry series also price against)."""
         from ..util import perfmodel
 

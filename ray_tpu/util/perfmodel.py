@@ -1,14 +1,13 @@
 """Analytic device-step cost model: FLOPs, HBM bytes, MFU, roofline.
 
-The runtime's single source of FLOP/byte truth. Three consumers share
+The runtime's single source of FLOP/byte truth. Two consumers share
 it so they can never disagree:
 
   * the LLM engine (llm/engine.py) prices every prefill/decode step it
     dispatches and publishes continuous ``llm_mfu`` / ``llm_hbm_util``
     telemetry series,
   * the train session (train/session.py) prices wrapped train steps
-    into ``train_*`` equivalents,
-  * bench.py's offline MFU report routes through the same formulas.
+    into ``train_*`` equivalents.
 
 Cost formulas (decoder-only transformer, GPTConfig shapes):
 

@@ -18,6 +18,7 @@ by ``tests/test_lint.py`` and exercised against known-bad fixtures in
 
 import gc
 import os
+import queue
 import threading
 import time
 
@@ -40,14 +41,25 @@ def async_debug(monkeypatch):
 # ---------------------------------------------------------------------------
 def test_reply_path_survives_gc_storm(rt):
     """Bursts of tasks on both lanes while another thread forces full
-    collections as fast as it can: every reply must arrive (r4's bug:
-    GC'd pending handler tasks silently dropped replies, hanging
-    get())."""
-    stop = threading.Event()
+    collections: every reply must arrive (r4's bug: GC'd pending handler
+    tasks silently dropped replies, hanging get()).
+
+    The storm is COUNTED, not free-running: each burst releases
+    ``PER_BURST`` full collections on the storm's thread, the first
+    begun before the burst is submitted, so they fall on pending
+    replies. A thread that collects as fast as it can holds the
+    interpreter lock for as long as the process's heap is large, and
+    late in an xdist worker's life that starved the event loop past
+    any timeout: what was under test was the box."""
+    PER_BURST = 4
+    bursts = queue.Queue()      # a burst's collections; None ends
+    collections = []
 
     def gc_storm():
-        while not stop.is_set():
-            gc.collect()
+        for n in iter(bursts.get, None):
+            for _ in range(n):
+                gc.collect()
+                collections.append(1)
 
     t = threading.Thread(target=gc_storm, daemon=True)
     t.start()
@@ -62,14 +74,18 @@ def test_reply_path_survives_gc_storm(rt):
 
         for round_ in range(3):
             n = 60
+            bursts.put(PER_BURST)
             refs = [dev.remote(i) for i in range(n)]
             assert ray_tpu.get(refs, timeout=60) == list(range(n))
+            bursts.put(PER_BURST)
             refs = [cpu.remote(i) for i in range(20)]
             assert ray_tpu.get(refs, timeout=120) == [
                 i * 2 for i in range(20)]
     finally:
-        stop.set()
-        t.join()
+        bursts.put(None)
+        t.join(timeout=120)
+    assert not t.is_alive()
+    assert len(collections) == 6 * PER_BURST
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,7 @@ def test_many_queued_tasks_drain(rt):
     out = ray_tpu.get(refs, timeout=300)
     dt = time.monotonic() - t0
     assert out == list(range(n))
-    # Recorded drain rate is ~6k tasks/s (MICROBENCH queued_50k_tasks);
+    # Recorded drain rate is ~6k tasks/s (microbench queued_50k_tasks);
     # 10s gives 6x headroom on a loaded box.
     assert dt < 10, f"{n} tasks took {dt:.1f}s"
 
@@ -99,8 +99,8 @@ def test_actor_call_throughput(rt):
 
 @pytest.mark.skipif(not __import__("os").environ.get("RT_ENVELOPE"),
                     reason="full-scale envelope: set RT_ENVELOPE=1 "
-                           "(the MICROBENCH artifact run exercises it "
-                           "every round at 500k/1000-node scale)")
+                           "(500k queued tasks, 1000 nodes; nothing "
+                           "else runs it)")
 def test_full_scale_envelope_floors(rt):
     """VERDICT r4 item 5 floors at artifact scale: 500k queued tasks
     drain >= 3k/s; 1000 REAL NodeService objects churn >= 100k

@@ -120,7 +120,7 @@ def test_membership_reconcile_20_nodes_through_restart(tmp_path):
         loop.close()
 
 
-def test_head_killed_mid_workload_tasks_survive(tmp_path):
+def test_head_killed_mid_workload_tasks_survive(tmp_path, fixed_port):
     """Detached head + 2 worker nodes; 6 tasks sleeping on the workers;
     kill -9 the head mid-flight; restart it on the same port. The driver
     and nodes reconnect and every task result arrives."""
@@ -129,7 +129,7 @@ def test_head_killed_mid_workload_tasks_survive(tmp_path):
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("RT_SESSION_TOKEN", None)
-    port = 41000 + (os.getpid() % 20000)
+    port = fixed_port
     cli = [sys.executable, "-m", "ray_tpu.scripts.cli", "--temp-dir", temp]
 
     def start_head():

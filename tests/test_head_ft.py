@@ -153,7 +153,7 @@ def test_named_actor_dropped_when_node_dies(tmp_path):
 # ---------------------------------------------------------------------------
 # Live: CLI head restart with a surviving worker node
 # ---------------------------------------------------------------------------
-def test_head_restart_cluster_survives(tmp_path):
+def test_head_restart_cluster_survives(tmp_path, fixed_port):
     """rtpu start --head; add a worker node; kill the head daemon; start
     a new head on the same port + persist file -> the node re-registers
     and KV written before the restart is still there."""
@@ -161,7 +161,7 @@ def test_head_restart_cluster_survives(tmp_path):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    port = 40000 + (os.getpid() % 20000)
+    port = fixed_port
     cli = [sys.executable, "-m", "ray_tpu.scripts.cli", "--temp-dir", temp]
 
     def start_head():

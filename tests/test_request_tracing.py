@@ -540,8 +540,12 @@ def test_preemption_links_victim_trace(rt_trace):
     """Over-admission on a tiny KV pool: the evicted request's OWN
     waterfall records the preempt and the later resume, so a stalled
     token cadence is explainable from the trace alone."""
+    import engine_by_hand
+
     _, serve = rt_trace
-    url = _deploy_llm(serve, num_blocks=6, block_size=8, max_batch=4)
+    with engine_by_hand.held() as engines:
+        url = _deploy_llm(serve, num_blocks=6, block_size=8, max_batch=4)
+    eng, = engines
     tids: dict = {}
 
     def worker(i):
@@ -552,15 +556,24 @@ def test_preemption_links_victim_trace(rt_trace):
                   "seed": i, "temperature": 0.9})
         assert frames[-1]["done"]
 
-    threads = []
-    for i in range(3):
-        t = threading.Thread(target=worker, args=(i,))
+    # One stream decodes alone for four steps, then two join it: the
+    # three are in flight together whatever the box is doing, because
+    # the test is what steps the engine.
+    threads = [threading.Thread(target=worker, args=(i,)) for i in range(3)]
+    threads[0].start()
+    engine_by_hand.arrived(eng, 1)
+    engine_by_hand.drive(eng, steps=4)
+    for t in threads[1:]:
         t.start()
-        threads.append(t)
-        time.sleep(0.15)     # stagger: later requests join mid-decode
+    engine_by_hand.arrived(eng, 3)
+    engine_by_hand.drive(eng)
     for t in threads:
         t.join(timeout=180)
     assert len(tids) == 3 and all(tids.values())
+    from ray_tpu.llm.engine import PREEMPTED
+
+    victims = {rid for _, rid, s in eng.events() if s == PREEMPTED}
+    assert victims, "three 35-token streams fitted a 40-token pool"
 
     # Preempt/resume land on the worker flusher after the streams
     # finish: poll until every preempted trace also shows its resume.
@@ -576,11 +589,13 @@ def test_preemption_links_victim_trace(rt_trace):
                     preempts.append(s)
                 elif s["name"] == "llm.resume":
                     resumes.append(s)
-        if preempts and {s["trace_id"] for s in preempts} == \
+        if len({s["trace_id"] for s in preempts}) == len(victims) and \
+                {s["trace_id"] for s in preempts} == \
                 {s["trace_id"] for s in resumes}:
             break
         time.sleep(0.5)
-    assert preempts, "tight pool produced no llm.preempt spans"
+    # One victim's trace for each request the engine preempted.
+    assert len({s["trace_id"] for s in preempts}) == len(victims)
     for s in preempts:
         assert s["attributes"]["preemptions"] >= 1
         assert "kv_util" in s["attributes"]

@@ -267,6 +267,76 @@ def test_http_ingress(serve_rt):
     assert out == {"echo": {"a": 1}}
 
 
+def test_every_request_by_handle_and_by_proxy_gets_its_own_answer(serve_rt):
+    """N requests through a handle and N through the local HTTP proxy
+    (keep-alive clients, two at a time) against one jitted deployment:
+    every one is answered with its own payload, and the deployment's
+    own count of requests reads 2N."""
+    import http.client
+
+    @serve.deployment(ray_actor_options=DEVICE)
+    class Model:
+        def __init__(self):
+            import jax
+            import jax.numpy as jnp
+
+            w = jax.random.normal(jax.random.key(0), (64, 64))
+            self._fwd = jax.jit(lambda x: (x @ w).sum())
+            self.unit = float(self._fwd(jnp.ones((8, 64))))  # compiles
+            self.seen = 0
+            self._lock = threading.Lock()
+
+        def __call__(self, req):
+            import jax.numpy as jnp
+
+            with self._lock:
+                self.seen += 1
+            x = jnp.ones((8, 64)) * float(req["scale"])
+            return {"y": float(self._fwd(x)), "id": req["id"]}
+
+        def counts(self):
+            return {"seen": self.seen, "unit": self.unit}
+
+    proxy = serve.start(http_port=0)
+    handle = serve.run(Model.bind(), route_prefix="/")
+    unit = handle.counts.remote().result(timeout=120)["unit"]
+    n = 40
+
+    def own(reply, i):
+        return reply["id"] == i and \
+            reply["y"] == pytest.approx(unit * (i + 1), rel=1e-4)
+
+    replies = [handle.remote({"scale": i + 1, "id": i}) for i in range(n)]
+    assert all(own(r.result(timeout=60), i) for i, r in enumerate(replies))
+
+    answered = {}
+
+    def client(ids):
+        conn = http.client.HTTPConnection("127.0.0.1", proxy.port,
+                                          timeout=60)
+        try:
+            for i in ids:
+                conn.request("POST", "/",
+                             body=json.dumps({"scale": i + 1, "id": i}),
+                             headers={"Content-Type": "application/json"})
+                resp = conn.getresponse()
+                body = resp.read()
+                answered[i] = (resp.status, json.loads(body))
+        finally:
+            conn.close()
+
+    clients = [threading.Thread(target=client, args=(range(k, n, 2),))
+               for k in range(2)]
+    for t in clients:
+        t.start()
+    for t in clients:
+        t.join(timeout=120)
+    assert sorted(answered) == list(range(n))
+    assert all(status == 200 and own(reply, i)
+               for i, (status, reply) in answered.items())
+    assert handle.counts.remote().result(timeout=60)["seen"] == 2 * n
+
+
 def test_subprocess_replicas(serve_rt):
     @serve.deployment(num_replicas=2)  # cpu lane → subprocess workers
     class PidReporter:

@@ -156,44 +156,56 @@ def test_late_join_and_preemption_through_serve(rt_llm):
     """The engine behind the deployment recomposes its batch mid-stream
     and survives over-admission: a tiny pool forces preempt+resume and
     the streamed tokens still match a run with a roomy pool."""
-    _, serve = rt_llm
+    import engine_by_hand
+    from ray_tpu.llm.engine import PREEMPTED
+    from ray_tpu.serve.llm import LLMServer
 
-    def collect(url, seeds):
-        out, threads = {}, []
+    _, serve = rt_llm
+    seeds = range(3)
+
+    def collect(url, eng):
+        """Stream 0 decodes alone for four steps, then 1 and 2 join it
+        mid-decode; the test steps the engine, so the three are in
+        flight together whatever the box is doing."""
+        out = {}
 
         def worker(i):
             # 5 + 30 tokens are all 5 blocks of the tight pool, so ANY
-            # two streams in flight together force a preemption. (At 10
-            # tokens only three in lock-step did, which hung on which
-            # thread won the engine's lock after the first step's
-            # compilations: one run in two here, before PR 33 and
-            # after.)
+            # two streams in flight together force a preemption.
             out[i] = _stream_http(
                 url, {"prompt": [3, 1, 4, 1, 5], "max_tokens": 30,
                       "seed": i, "temperature": 0.9})
 
-        for i in seeds:
-            t = threading.Thread(target=worker, args=(i,))
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in seeds]
+        threads[0].start()
+        engine_by_hand.arrived(eng, 1)
+        engine_by_hand.drive(eng, steps=4)
+        for t in threads[1:]:
             t.start()
-            threads.append(t)
-            time.sleep(0.15)    # stagger: later requests join mid-decode
+        engine_by_hand.arrived(eng, len(seeds))
+        engine_by_hand.drive(eng)
         for t in threads:
             t.join(timeout=180)
         return {i: [f["token"] for f in fr if "token" in f]
                 for i, fr in out.items()}, out
 
-    from ray_tpu.serve.llm import LLMServer
+    with engine_by_hand.held(expect=2) as engines:
+        url = _deploy(serve, num_blocks=64, block_size=8, max_batch=4)
+        # Second app, tiny pool, side by side at its own route prefix:
+        # capacity 5 blocks = 40 tokens < 2 sequences x (5 prompt + 30
+        # out).
+        serve.run(LLMServer.options(name="LLMTight").bind(
+            CFG, num_blocks=6, block_size=8, max_batch=4),
+            name="llm-tight", route_prefix="/tight")
+    tight_eng, roomy_eng = sorted(engines, key=lambda e: e.kv.capacity)
+    assert tight_eng.kv.capacity == 5
 
-    url = _deploy(serve, num_blocks=64, block_size=8, max_batch=4)
-    # Second app, tiny pool, side by side at its own route prefix:
-    # capacity 5 blocks = 40 tokens < 2 sequences x (5 prompt + 30 out).
-    serve.run(LLMServer.options(name="LLMTight").bind(
-        CFG, num_blocks=6, block_size=8, max_batch=4),
-        name="llm-tight", route_prefix="/tight")
-
-    roomy, _ = collect(url, range(3))
-    tight, frames = collect(url + "tight", range(3))
+    roomy, _ = collect(url, roomy_eng)
+    tight, frames = collect(url + "tight", tight_eng)
+    assert all(len(toks) == 30 for toks in roomy.values()), roomy
     assert tight == roomy
+    assert not [s for _, _, s in roomy_eng.events() if s == PREEMPTED]
     h = serve.get_app_handle("llm-tight")
     st = h.options(method_name="engine_stats").remote().result(
         timeout=60)
@@ -202,6 +214,8 @@ def test_late_join_and_preemption_through_serve(rt_llm):
     # have preempted at least once, and output still matched exactly.
     total_preempt = sum(fr[-1]["preemptions"] for fr in frames.values())
     assert total_preempt > 0, frames
+    assert total_preempt == len(
+        [s for _, _, s in tight_eng.events() if s == PREEMPTED])
 
 
 _DETACHED_DRIVER = """

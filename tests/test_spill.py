@@ -21,6 +21,15 @@ def _oid() -> ObjectID:
     return ObjectID(secrets.token_bytes(28))
 
 
+def _current_rss() -> int:
+    """Current (not high-water) resident bytes: ru_maxrss only grows."""
+    try:
+        with open("/proc/self/statm") as f:
+            return int(f.read().split()[1]) * os.sysconf("SC_PAGESIZE")
+    except (OSError, ValueError, IndexError):
+        return 0
+
+
 @pytest.fixture
 def store(tmp_path):
     s = SharedMemoryStore(secrets.token_hex(6),
@@ -174,7 +183,6 @@ def test_sort_several_times_capacity_bounded_rss(monkeypatch):
 
     import ray_tpu
     import ray_tpu.data as rd
-    from ray_tpu.scripts.data_bench import _current_rss
 
     cap = 32 * 1024 * 1024
     monkeypatch.setenv("RT_NATIVE_STORE", "0")

@@ -470,12 +470,31 @@ def test_telemetry_disabled_by_config():
         ray_tpu.shutdown()
 
 
-def test_spill_series_sampled_with_idle_decay(rt):
+@pytest.fixture
+def rt_python_store(monkeypatch):
+    """A runtime whose node holds the pure-Python store: its stats()
+    folds in the session's ``.spill_log``, which ``_spill_event``
+    appends to. (The native store, the default, counts its own spills
+    in C and never reads that log: under it the events this test plants
+    are invisible and the series reads 0.0, which is how this test
+    failed in every run of the whole suite.)"""
+    from ray_tpu._private.object_store import SharedMemoryStore
+
+    monkeypatch.setenv("RT_NATIVE_STORE", "0")
+    ray_tpu.shutdown()
+    rt = ray_tpu.init(num_cpus=1)
+    assert type(rt.node.shm) is SharedMemoryStore
+    yield rt
+    ray_tpu.shutdown()
+
+
+def test_spill_series_sampled_with_idle_decay(rt_python_store):
     """The sampler surfaces the store's session-wide spill/restore
     ledger as store_spill_events / store_spilled_bytes /
     store_restored_bytes, and an idle store decays the series to 0
     (the PR-10 gauge contract) instead of freezing it at the last
     cumulative value."""
+    rt = rt_python_store
     sampler = TelemetrySampler(rt.node)
     m = sampler.sample()["metrics"]
     assert m["store_spill_events"] == 0.0  # quiet store reads 0

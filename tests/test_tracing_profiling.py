@@ -261,8 +261,10 @@ def test_cluster_profile_covers_workers(rt):
 # process returns one window of accounted device steps + host timeline;
 # the driver aligns clocks and merges into one Chrome trace.
 # ---------------------------------------------------------------------------
-def test_cluster_device_profile_merges_processes(rt):
+def test_cluster_device_profile_merges_processes(rt, tmp_path):
     import json
+    import os
+    import threading
     import time
 
     import ray_tpu
@@ -270,33 +272,59 @@ def test_cluster_device_profile_merges_processes(rt):
     from ray_tpu.util import perfmodel
 
     @ray_tpu.remote
-    def stepper_xyz(sec):
+    def stepper_xyz(started, stop):
         # A worker acting like an engine: accounted device steps land
-        # in its process-local ring while the capture window runs.
+        # in its process-local ring from the moment it says it has
+        # started until it is told to stop.
+        import os as _os
         import time as _t
 
         from ray_tpu.util import perfmodel as pm
 
         t0 = _t.monotonic()
         n = 0
-        while _t.monotonic() - t0 < sec:
+        while not _os.path.exists(stop) and _t.monotonic() - t0 < 120:
             pm.record_device_step(
                 "llm.step", _t.time(),
                 {"step_ms": 2.0, "device_ms": 1.5, "host_gap_ms": 0.5,
                  "mfu": 0.3, "hbm_util": 0.2, "verdict": "compute"},
                 {"deployment": "capture_test"})
             n += 1
+            if n == 1:
+                open(started, "w").close()
             _t.sleep(0.05)
         return n
 
+    # The capture fans out to the workers that are ALIVE: wait for the
+    # worker's word that it is stepping (it takes seconds to start
+    # beside five busy xdist workers; a half-second sleep here was why
+    # this test failed under load), and hold it there until the window
+    # has been taken.
+    started, stop = str(tmp_path / "started"), str(tmp_path / "stop")
     perfmodel.clear_device_steps()
-    ref = stepper_xyz.remote(8.0)
-    time.sleep(0.5)
-    # The driver/node process steps too (train-session shape).
-    perfmodel.record_device_step(
-        "train.step", time.time(),
-        {"step_ms": 10.0, "device_ms": 8.0}, {"trial": "t0"})
-    profs = rt.cluster_device_profile(duration_s=1.0, hz=50.0)
+    ref = stepper_xyz.remote(started, stop)
+    deadline = time.monotonic() + 120
+    while not os.path.exists(started):
+        assert time.monotonic() < deadline, "the worker never stepped"
+        time.sleep(0.05)
+    # The driver/node process steps too (train-session shape), through
+    # the window.
+    driver_done = threading.Event()
+
+    def driver_steps():
+        while not driver_done.wait(0.05):
+            perfmodel.record_device_step(
+                "train.step", time.time(),
+                {"step_ms": 10.0, "device_ms": 8.0}, {"trial": "t0"})
+
+    stepping = threading.Thread(target=driver_steps, daemon=True)
+    stepping.start()
+    try:
+        profs = rt.cluster_device_profile(duration_s=1.0, hz=50.0)
+    finally:
+        driver_done.set()
+        open(stop, "w").close()
+        stepping.join(timeout=30)
     offsets = rt.clock_offsets()
     assert ray_tpu.get(ref, timeout=60) > 0
 

@@ -70,6 +70,86 @@ def test_jax_trainer_end_to_end(rt, tmp_path):
     assert int(restored["step"]) == 4
 
 
+def _ingest_loop(config):
+    """The benchmark's train cell at the TINY preset
+    (benchmark/drivers/train_loop.py): Data ingest -> device_put a step
+    -> the wrapped GPT step -> a report a step."""
+    import jax
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models import gpt
+    from ray_tpu.parallel import MeshSpec
+
+    cfg = gpt.TINY
+    mesh = MeshSpec.auto(len(jax.devices())).build()
+    opt = optax.adamw(3e-3)
+    params = gpt.init(jax.random.key(0), cfg)
+    state = {"params": params, "opt_state": opt.init(params), "step": 0}
+    state = gpt.shard_state(state, mesh, cfg)
+    step = rt_train.wrap_step(gpt.make_train_step(cfg, opt, mesh), cfg)
+    sharding = NamedSharding(mesh, P(("dp", "fsdp")))
+    shard = rt_train.get_dataset_shard("train")
+    n = 0
+    while n < config["steps"]:
+        for b in shard.iter_batches(batch_size=config["batch"],
+                                    batch_format="jax", sharding=sharding,
+                                    drop_last=True):
+            state, m = step(state, b["tokens"])
+            n += 1
+            rt_train.report({"step": n, "loss": float(m["loss"])})
+            if n == config["steps"]:
+                break
+
+
+def test_fit_over_data_ingest_is_what_the_benchmarks_train_cell_reads(
+        rt, tmp_path):
+    """JaxTrainer.fit over a ``ray_tpu.data`` shard with a wrapped step:
+    the losses are finite and fall, there is one report a step, and the
+    ``train.step`` ring holds one entry for each step that a next one
+    closed, each with the ``data.next_batch`` phase the benchmark's
+    train cell attributes its host time with."""
+    import math
+    import time
+
+    from ray_tpu import data as rt_data
+    from ray_tpu.models import gpt
+    from ray_tpu.util import perfmodel
+
+    steps, batch = 6, 8
+    rng = np.random.default_rng(0)
+    rows = [{"tokens": rng.integers(0, gpt.TINY.vocab_size,
+                                    gpt.TINY.max_seq, dtype=np.int32)}
+            for _ in range(batch * 2)]          # two batches, cycled
+    perfmodel.clear_device_steps()
+    t0 = time.time()
+    result = JaxTrainer(
+        _ingest_loop,
+        train_loop_config={"steps": steps, "batch": batch},
+        scaling_config=ScalingConfig(num_workers=1, use_tpu=True),
+        run_config=RunConfig(name="ingest_e2e", storage_path=str(tmp_path)),
+        datasets={"train": rt_data.from_items(rows)},
+    ).fit()
+    assert result.error is None
+    history = result.metrics_history
+    assert [m["step"] for m in history] == list(range(1, steps + 1))
+    losses = [m["loss"] for m in history]
+    assert all(math.isfinite(x) for x in losses)
+    assert losses[-1] < losses[0], losses
+    ring = [e for e in perfmodel.device_step_events(since=t0)
+            if e["name"] == "train.step"]
+    perfmodel.clear_device_steps()
+    # A step's entry closes when the next step begins.
+    assert len(ring) == steps - 1
+    for e in ring:
+        assert e["phases_ms"]["data.next_batch"] > 0.0
+        assert "train.report" in e["phases_ms"]
+    # What the driver reads off each report from the second step on.
+    for m in history[1:]:
+        assert m["train_data_wait_ms"] > 0.0
+        assert m["train_step_ms"] >= m["train_device_ms"] > 0.0
+
+
 def test_trainer_checkpoint_retention(rt, tmp_path):
     trainer = JaxTrainer(
         _gpt_loop,
