@@ -3,9 +3,11 @@
 Reference layer map: the Orca-style scheduler (Yu et al., OSDI '22) the
 reference runtime fronts with external inference servers — here it is
 native. One engine owns the model params, the paged KV pool
-(llm/kv_cache.py) and a step loop; requests stream tokens out through
-per-request queues, so N serve threads (one per in-flight HTTP request)
-share ONE device-resident batch.
+(llm/kv_cache.py) and a step loop; N in-flight requests share ONE
+device-resident batch, and what a step decides for them leaves the
+engine in one hand-over to its sink (Serve's replica: serve/llm.py),
+or, for a request added without a consumer behind that sink, through
+its own queue (``Request.tokens()``).
 
 Scheduling is per STEP, not per request: every step first admits waiting
 requests into the in-flight batch (prefill), then runs ONE decode token
@@ -60,7 +62,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Callable, Deque, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -121,6 +123,10 @@ class Request:
     # Serving-lane trace context ({"trace_id", "span_id"} of the request
     # span this generation belongs to); None outside traced requests.
     trace_ctx: Optional[dict] = None
+    # ``add_request``'s: whatever the engine's sink finds this request's
+    # reader by, which the engine never looks at (None: its tokens and
+    # its finish leave on ``out_q``).
+    consumer: object = None
     out_q: "queue.Queue" = field(default_factory=queue.Queue)
 
     @property
@@ -130,8 +136,8 @@ class Request:
         return is_greedy(self.temperature, self.top_k)
 
     def tokens(self):
-        """Blocking generator over this request's output tokens (the
-        serve streaming path iterates this on a replica thread)."""
+        """Blocking generator over this request's output tokens, for a
+        request added without a consumer (data/llm.py, tests, tools)."""
         while True:
             tok = self.out_q.get()
             if tok is None:
@@ -224,7 +230,8 @@ class LLMEngine:
                  block_size: int = 16, max_batch: int = 8,
                  prefill_chunk_tokens: Optional[int] = None,
                  prefix_cache: bool = True,
-                 speculative=None, name: str = "llm"):
+                 speculative=None, name: str = "llm",
+                 sink: Optional[Callable[[list], None]] = None):
         self.cfg = cfg
         # What the model's module says of serving it: the two programs,
         # the kinds of layer its cache has, its costs (the seam,
@@ -324,7 +331,9 @@ class LLMEngine:
         self._cond = threading.Condition(self._lock)
         self._waiting: Deque[Request] = collections.deque()
         self._active: List[Request] = []      # PREFILL/RUNNING, batch order
-        self._requests: Dict[int, Request] = {}
+        # A request is held while it waits or runs, and no longer: a
+        # finished one is its caller's to keep (kept here, prompt and
+        # all, it was the collector's to walk in every oldest pass).
         self._ids = itertools.count(1)
         self._events: Deque[tuple] = collections.deque(maxlen=4096)
         # (step_idx, (rid, ...)) per step — the in-flight composition
@@ -369,6 +378,18 @@ class LLMEngine:
         # from its last prefill chunk) or a logits row sampled on the
         # host (every token of a request with a temperature).
         self._decided = {"device": 0, "host": 0}
+        # What the step has decided for the requests that have a
+        # consumer, as ``[request, tokens, finish reason or None]`` in
+        # the order decided, until ``_hand_over`` gives it to the sink
+        # in ONE call: where the settled chunks' first tokens are all
+        # decided (before the wait for the decode program, which they do
+        # not sit out) and where the decode step's emission ends. The
+        # sink runs on the engine's thread under its lock and calls
+        # nothing of the engine.
+        self._sink = sink
+        self._outbox: List[list] = []
+        self._handovers = 0           # calls of the sink, this step
+        self._tokens_handed = 0       # the tokens they carried
 
     # -- events ------------------------------------------------------------
 
@@ -384,11 +405,19 @@ class LLMEngine:
     def add_request(self, prompt: List[int], max_tokens: int = 16, *,
                     temperature: float = 0.0, top_k: int = 0,
                     seed: int = 0, stop_tokens=(),
-                    trace_ctx: Optional[dict] = None) -> Request:
+                    trace_ctx: Optional[dict] = None,
+                    consumer=None) -> Request:
         """Validate + enqueue; returns the Request whose .tokens()
-        generator streams the output. Raises if the request could never
-        run (so the pool-exhaustion path is always recoverable by
-        preemption, never a livelock)."""
+        generator streams the output. With a ``consumer`` the output
+        goes to the engine's sink instead: a step calls it with a list
+        of ``(request, tokens, finish_reason)`` (the reason None while
+        the request runs), one call for all such requests, and the
+        consumer rides on the request for the sink to find its reader
+        by. Raises if the request could never run (so the
+        pool-exhaustion path is always recoverable by preemption, never
+        a livelock)."""
+        if consumer is not None and self._sink is None:
+            raise ValueError("a consumer needs an engine built with a sink")
         if self._fatal is not None:
             raise RuntimeError(
                 f"the engine's step loop died: {self._fatal!r}"
@@ -422,9 +451,8 @@ class LLMEngine:
                       seed=int(seed),
                       stop_tokens=tuple(int(t) for t in stop_tokens),
                       submit_t=time.time(), trace_ctx=trace_ctx,
-                      chain=chain)
+                      chain=chain, consumer=consumer)
         with self._cond:
-            self._requests[req.rid] = req
             self._waiting.append(req)
             self._arrived += 1
             self._event(req, WAITING)
@@ -620,13 +648,36 @@ class LLMEngine:
         req.finish_t = time.time()
         self._finished_count += 1
         self._event(req, FINISHED)
-        req.out_q.put(None)
+        if req.consumer is None:
+            req.out_q.put(None)
+        else:
+            self._outgoing(req)[2] = reason
+
+    def _outgoing(self, req: Request) -> list:
+        """The request's entry of the coming hand-over: a request's
+        tokens of one step are decided in a row, so it is the last one
+        or a new one."""
+        out = self._outbox
+        if not out or out[-1][0] is not req:
+            out.append([req, [], None])
+        return out[-1]
+
+    def _hand_over(self):
+        """Everything decided since the last hand-over leaves in ONE
+        call of the sink: one lock and one wake-up on the serving side,
+        however many lanes emitted."""
+        handed, self._outbox = self._outbox, []
+        if handed:
+            self._handovers += 1
+            self._tokens_handed += sum(len(e[1]) for e in handed)
+            self._sink(handed)
 
     def _emit_token(self, req: Request, tok: int) -> bool:
         """Append an already-decided token (sampled, or an accepted/
         corrected speculative draw — identical by construction), push it
-        to the consumer, apply stop conditions. Returns True if the
-        request finished."""
+        to the consumer (its queue, or the coming hand-over to the
+        sink), apply stop conditions. Returns True if the request
+        finished."""
         tok = int(tok)
         req.output.append(tok)
         now = time.time()
@@ -634,9 +685,13 @@ class LLMEngine:
             req.first_token_t = now
         self._token_times.append((now, 1))
         self._tokens_in_window += 1
-        while req.emitted < len(req.output):
-            req.out_q.put(req.output[req.emitted])
-            req.emitted += 1
+        if req.consumer is None:
+            while req.emitted < len(req.output):
+                req.out_q.put(req.output[req.emitted])
+                req.emitted += 1
+        else:
+            self._outgoing(req)[1].extend(req.output[req.emitted:])
+            req.emitted = len(req.output)
         if tok in req.stop_tokens:
             self._finish(req, "stop")
             return True
@@ -851,6 +906,11 @@ class LLMEngine:
                                   "host_ms": round(
                                       max(dur - device_s, 0.0) * 1e3, 3)})
         self._pending.clear()
+        # Then and there: a first token does not sit out the wait for
+        # the decode program.
+        if self._outbox:
+            with perf.phase("llm.emit"):
+                self._hand_over()
 
     def _take_back(self, pools):
         """The pools a program was donated, as it returned them written:
@@ -1199,6 +1259,8 @@ class LLMEngine:
                                 req.window_table, req.context_len,
                                 req.window_first)))
                     spec.rollback(req.rid, len(p) - n_acc, len(freed))
+        with emitting:
+            self._hand_over()
         self._decided["host"] += decided[0]
         self._decided["device"] += decided[1]
         dur = time.time() - t0
@@ -1246,6 +1308,7 @@ class LLMEngine:
             self._counts = (0, 0, 0, 0)
             self._counters = {}
             self._inputs_written = 0
+            self._handovers = self._tokens_handed = 0
             preempted0 = self._preempt_count
             with perf.step("llm.step", self._steps + 1):
                 with perf.phase("llm.admit"):
@@ -1298,6 +1361,12 @@ class LLMEngine:
                        # changed, not what is live.
                        "inputs_written": self._inputs_written,
                        "inputs_size": self._inputs.size,
+                       # Calls of the sink in this step and the tokens
+                       # they carried: how many tokens leave the engine
+                       # for one lock and one wake-up behind it (both 0
+                       # where no request has a consumer).
+                       "handovers": self._handovers,
+                       "tokens_handed": self._tokens_handed,
                        **window, **self._step_counters()})
             if entry is not None:
                 self._arrived = 0       # callers wait for this lock
@@ -1592,8 +1661,10 @@ class LLMEngine:
 
     def _release_consumers(self, reason: str):
         """End every in-flight and waiting request so no consumer stays
-        parked on its queue (shutdown, or a step loop that died)."""
+        parked on its queue or its stream (shutdown, or a step loop
+        that died)."""
         with self._lock:
             for req in list(self._active) + list(self._waiting):
                 self._finish(req, reason)
             self._waiting.clear()
+            self._hand_over()

@@ -6,10 +6,14 @@ provides by fronting external engines — here the engine is native
 (ray_tpu.llm). One replica hosts ONE LLMEngine; Serve's replica thread
 pool delivers concurrent ``__call__``s, each of which registers a
 request with the shared engine EAGERLY (so TTFT starts at arrival, not
-at first stream pull) and returns a generator. The generator rides the
-existing STREAM_MARKER protocol: the replica parks it, the proxy drains
-it chunk-at-a-time, and HTTP clients see ndjson chunked transfer — one
-frame per token.
+at first stream pull) and returns a ``PushedStream``. During a step the
+interpreter is the engine's: its thread and the serving threads meet
+ONCE on the way out, where the engine hands its sink (``_hand_over``)
+what the step decided for every lane and the sink puts all of it into
+the replica's streams under one lock, with one wake-up of the poll that
+waits for them (no thread a stream). The streams ride the STREAM_MARKER
+protocol: the proxy reads them as it reads a generator's, and HTTP
+clients see ndjson chunked transfer — one frame per token.
 
 SLO + telemetry: per-request TTFT and TPOT are recorded as serve phases
 (slo.record_phase), so ``serve.status()`` reports their p50/p95/p99 next
@@ -25,11 +29,14 @@ and the final frame carries the decoded text when every token is a byte.
 
 from __future__ import annotations
 
+import gc
+import threading
 import time
 from typing import Any, Optional
 
 from . import slo
 from .deployment import deployment
+from .replica import PushedStream, push
 
 
 def encode(text: str):
@@ -42,6 +49,49 @@ def decode(tokens) -> Optional[str]:
     if any(t < 0 or t > 255 for t in tokens):
         return None
     return bytes(tokens).decode("utf-8", errors="replace")
+
+
+_freeze_lock = threading.Lock()
+_frozen = False
+# Programs this process has built so far (jax's own event, which a hit
+# in its persistent cache raises too), counted from the first replica
+# on: a replica whose requests finish while this stands still is warm.
+_programs_built = 0
+_counting = False
+
+
+def _count_programs():
+    global _counting
+    with _freeze_lock:
+        if _counting:
+            return
+        _counting = True
+    import jax
+
+    def built(event, duration, **_):
+        global _programs_built
+        if event == "/jax/core/compile/backend_compile_duration":
+            _programs_built += 1
+
+    jax.monitoring.register_event_duration_secs_listener(built)
+
+
+def _freeze_setup_heap():
+    """Once a process, when a serving replica's engine is built and
+    warm: collect, then move everything that is left (what jax, Serve,
+    the model and set-up built, which lives as long as the process)
+    out of the collector's sight. Its passes still run at their
+    thresholds, over what is allocated from here on; a pass of the
+    oldest generation no longer walks the set-up's heap with the
+    interpreter held, which was the longest pause of a serving window
+    (PERF.md section 6, PR 52)."""
+    global _frozen
+    with _freeze_lock:
+        if _frozen:
+            return
+        _frozen = True
+    gc.collect()
+    gc.freeze()
 
 
 class _LLMServer:
@@ -80,6 +130,13 @@ class _LLMServer:
         if isinstance(system_prompt, str):
             system_prompt = encode(system_prompt)
         self.system_prompt = [int(t) for t in (system_prompt or ())]
+        # Warm is when as many requests as the engine has lanes have
+        # finished since the process last built a program: whatever
+        # warms a deployment up (a request a chunk length, a request a
+        # shared prefix) builds as it goes or is fewer than that.
+        _count_programs()
+        self._built_seen = _programs_built
+        self._finished_since = 0
         # Serving defaults to chunked prefill (bounded per-step prefill
         # keeps decode streams emitting every step) and prefix caching.
         # ``speculative`` (None | dict | SpecConfig — llm/spec.py) turns
@@ -94,7 +151,8 @@ class _LLMServer:
                                 max_batch=max_batch,
                                 prefill_chunk_tokens=prefill_chunk_tokens,
                                 prefix_cache=prefix_cache,
-                                speculative=speculative, name=name)
+                                speculative=speculative, name=name,
+                                sink=self._hand_over)
         self.engine.start()
 
     def __call__(self, request: Any):
@@ -112,15 +170,16 @@ class _LLMServer:
         if self.system_prompt:
             prompt = self.system_prompt + list(prompt)
         # Register with the engine NOW: the request joins the in-flight
-        # batch at the next step even though the generator body below
-        # only runs when the stream is first pulled. The replica span's
-        # trace context is captured HERE (this thread) because gen()
-        # executes later on the stream's feeder thread with no context set.
+        # batch at the next step, and what the engine decides for it
+        # before the replica has the stream on its books waits in the
+        # stream. The replica span's trace context is captured HERE
+        # (this thread): the frames are made on the engine's thread,
+        # which has none set.
         from ray_tpu.util import tracing
 
         trace_ctx = tracing.current_context.get()
-        trace_id = (trace_ctx or {}).get("trace_id")
-        req = self.engine.add_request(
+        stream = PushedStream()
+        self.engine.add_request(
             prompt,
             max_tokens=int(request.get("max_tokens",
                                        self.default_max_tokens)),
@@ -128,36 +187,58 @@ class _LLMServer:
             top_k=int(request.get("top_k", 0)),
             seed=int(request.get("seed", 0)),
             stop_tokens=request.get("stop_tokens", ()),
-            trace_ctx=trace_ctx)
+            trace_ctx=trace_ctx, consumer=stream)
+        return stream
+
+    def _hand_over(self, handed):
+        """The engine's sink: a hand-over's ``(request, tokens,
+        finish_reason)`` as frames, all into their streams in one
+        ``push``. Runs on the engine's thread under the engine's lock
+        and takes the replica's stream condition inside it, never the
+        reverse: it calls nothing of the engine, and the replica's
+        stream calls take no engine lock. The SLO phases are recorded
+        where a first token and a finish are handed over: ``ttft`` ends
+        HERE, before the reader's poll has woken."""
         dep = self.engine.name
-
-        def gen():
-            first = True
-            for tok in req.tokens():
-                if first:
-                    first = False
-                    slo.record_phase("ttft", time.time() - req.submit_t,
-                                     dep, trace_id=trace_id)
-                    # The part of it spent waiting for a lane:
-                    # add_request -> first admission into the batch.
-                    slo.record_phase("engine_queue",
-                                     req.admit_t - req.submit_t, dep,
-                                     trace_id=trace_id)
-                yield {"token": tok}
-            if req.first_token_t and req.finish_t \
-                    and len(req.output) > 1:
-                slo.record_phase(
-                    "tpot",
-                    (req.finish_t - req.first_token_t)
-                    / (len(req.output) - 1), dep, trace_id=trace_id)
-            yield {"done": True,
-                   "finish_reason": req.finish_reason,
-                   "num_tokens": len(req.output),
-                   "preemptions": req.preemptions,
-                   "cached_tokens": req.cached_tokens,
-                   "text": decode(req.output)}
-
-        return gen()
+        now = time.time()
+        pushes = []
+        for req, tokens, reason in handed:
+            frames = [{"token": tok} for tok in tokens]
+            first = tokens and req.emitted == len(tokens)
+            if first or reason is not None:
+                trace_id = (req.trace_ctx or {}).get("trace_id")
+            if first:
+                slo.record_phase("ttft", now - req.submit_t, dep,
+                                 trace_id=trace_id)
+                # The part of it spent waiting for a lane:
+                # add_request -> first admission into the batch.
+                slo.record_phase("engine_queue",
+                                 req.admit_t - req.submit_t, dep,
+                                 trace_id=trace_id)
+            if reason is not None:
+                if req.first_token_t and len(req.output) > 1:
+                    slo.record_phase(
+                        "tpot",
+                        (req.finish_t - req.first_token_t)
+                        / (len(req.output) - 1), dep, trace_id=trace_id)
+                frames.append({"done": True,
+                               "finish_reason": reason,
+                               "num_tokens": len(req.output),
+                               "preemptions": req.preemptions,
+                               "cached_tokens": req.cached_tokens,
+                               "text": decode(req.output)})
+            pushes.append((req.consumer, frames, reason is not None, None))
+        push(pushes)
+        if not _frozen:
+            # In a deployment's set-up, not in an engine's
+            # (``LLMEngine.start``): an engine made by a test or by
+            # data/llm does not freeze its process.
+            if self._built_seen != _programs_built:
+                self._built_seen, self._finished_since = _programs_built, 0
+            else:
+                self._finished_since += sum(p[2] for p in pushes)
+                if self._finished_since >= self.engine.max_batch:
+                    _freeze_setup_heap()
 
     def engine_stats(self) -> dict:
         """Engine introspection over the handle

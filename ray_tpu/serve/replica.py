@@ -19,10 +19,17 @@ from typing import Any, Optional
 from . import slo
 from .multiplex import _set_request_model_id
 
-# A request whose user code returned a generator answers with this marker;
-# the caller reads its chunks from the SAME replica via stream_poll
-# (reference: streaming responses through the handle,
-# python/ray/serve/handle.py DeploymentResponseGenerator).
+# A request whose user code returned a generator, or a PushedStream,
+# answers with this marker; the caller reads its chunks from the SAME
+# replica via stream_poll (reference: streaming responses through the
+# handle, python/ray/serve/handle.py DeploymentResponseGenerator). Which
+# of the two it was is decided by the type of what user code returned
+# and shows in nothing the caller sees: both are a ``_Stream`` on the
+# replica's books, read by the same polls. A generator is run by a
+# feeder thread of its own (``_feed``); a pushed stream has no thread:
+# the deployment's own thread (an engine's step loop) hands it chunks,
+# those of many streams under one acquisition of the stream condition
+# (``push``).
 STREAM_MARKER = "__rtpu_stream__"
 
 # How far a parked generator runs ahead of its reader: a feeder stops
@@ -52,9 +59,11 @@ def _with_model_id(gen, model_id: str):
 
 
 class _Stream:
-    """One parked generator and the chunks it has yielded that no caller
-    has taken yet. Every field is read and written under the replica's
-    stream condition."""
+    """One stream on the replica's books: the chunks no caller has taken
+    yet, yielded by a parked generator that a feeder thread runs or
+    handed to a ``PushedStream`` (``gen`` is None: no thread, and no
+    run-ahead bound). Every field is read and written under the
+    replica's stream condition."""
 
     __slots__ = ("sid", "gen", "caller", "ready", "yielded", "limit",
                  "ended", "error", "cancelled")
@@ -65,7 +74,8 @@ class _Stream:
         # Whose polls carry its chunks: nobody's until its reader has
         # said (stream_grant) that it is there to be dealt them.
         self.caller: Optional[str] = None
-        self.ready: list = []  # (chunk, t_yield), in the order yielded
+        # (chunk, t_yield), in the order yielded or handed over
+        self.ready: list = []
         self.yielded = 0
         self.limit = STREAM_RUN_AHEAD  # yield no chunk past this count
         self.ended = False  # the generator returned, raised or was closed
@@ -111,8 +121,67 @@ def _feed(stream: _Stream, cond: threading.Condition, deployment: str):
             cond.notify_all()
 
 
+# The (stream condition, name for a stream's error) of the replica
+# whose request the thread is running: what a PushedStream made by that
+# request's user code is filled under.
+_serving = threading.local()
+
+
+class PushedStream(_Stream):
+    """A streaming response that the deployment fills itself: user code
+    makes one inside the request it answers and returns it in place of
+    a generator, and hands it chunks as it has them, from a thread of
+    its own, through ``push``. The replica starts no thread for it, and
+    handle, proxy and client read it as they read a generator's stream.
+    It has no run-ahead bound: whoever pushes is never made to wait for
+    a reader. It is filled under its replica's stream condition from
+    its first chunk on, so what is handed over before the replica has
+    put it on its books waits in it; what is handed to a stream that
+    was cancelled or abandoned, or past its end, is dropped."""
+
+    __slots__ = ("cond", "label")
+
+    def __init__(self):
+        super().__init__(0, None)
+        replica = getattr(_serving, "replica", None)
+        if replica is None:
+            raise RuntimeError(
+                "a PushedStream is made by the request it answers")
+        self.cond, self.label = replica
+
+
+def push(handed):
+    """Hand chunks to many pushed streams at once. ``handed`` is a list
+    of ``(stream, chunks, ended, error)``: the chunks in order, then, if
+    ``ended``, the stream's end (where its reader raises ``error``, if
+    that is not None, behind the chunks).
+    The streams of one replica are filled under ONE acquisition of its
+    stream condition, with one time of arrival, and the polls that wait
+    on it are woken ONCE."""
+    by_cond: dict = {}
+    for entry in handed:
+        by_cond.setdefault(entry[0].cond, []).append(entry)
+    t_yield = time.perf_counter()
+    for cond, entries in by_cond.items():
+        with cond:
+            for stream, chunks, ended, error in entries:
+                if stream.cancelled or stream.ended:
+                    continue
+                stream.ready += [(chunk, t_yield) for chunk in chunks]
+                stream.yielded += len(chunks)
+                if ended:
+                    stream.ended = True
+                    if error is not None:
+                        from ray_tpu._private.exceptions import TaskError
+
+                        stream.error = TaskError.from_exception(
+                            error, stream.label)
+            cond.notify_all()
+
+
 def _abandon(streams: dict, cond: threading.Condition):
-    """The replica is gone: end the feeders that wait for a reader."""
+    """The replica is gone: end the feeders that wait for a reader, and
+    drop what is handed to a pushed stream from now on."""
     with cond:
         for stream in streams.values():
             stream.cancelled = True
@@ -199,6 +268,8 @@ class Replica:
         t_exec0 = time.perf_counter()
         try:
             _set_request_model_id(multiplexed_model_id)
+            _serving.replica = (self._stream_cond,
+                                f"{self._deployment}.stream")
             if callable(self.instance) and method == "__call__":
                 target = self.instance
             else:
@@ -212,15 +283,21 @@ class Replica:
                 # request's multiplex id must travel with it.
                 if multiplexed_model_id:
                     result = _with_model_id(result, multiplexed_model_id)
-                with self._stream_cond:
-                    self._stream_counter += 1
-                    sid = self._stream_counter
-                    stream = self._streams[sid] = _Stream(sid, result)
+                stream = _Stream(0, result)
+                sid = self._book(stream)
                 threading.Thread(
                     target=_feed, daemon=True, name=f"serve-feed-{sid}",
                     args=(stream, self._stream_cond, self._deployment),
                 ).start()
                 return {STREAM_MARKER: sid}
+            if isinstance(result, PushedStream):
+                # The deployment fills it: on the books with no thread
+                # behind it, whatever it was handed already in it.
+                if result.sid or result.cond is not self._stream_cond:
+                    raise TypeError(
+                        "a PushedStream answers the one request whose "
+                        "user code made it")
+                return {STREAM_MARKER: self._book(result)}
             return result
         except BaseException as e:
             if rspan is not None:
@@ -235,10 +312,19 @@ class Replica:
             if rspan is not None:
                 rspan.__exit__(None, None, None)
             _set_request_model_id(None)
+            _serving.replica = None
             with self._lock:
                 self._ongoing -= 1
             slo.set_queue_depth(self._ongoing + len(self._streams),
                                 self._deployment)
+
+    def _book(self, stream: _Stream) -> int:
+        """Put a new stream on the books under the next sid."""
+        with self._stream_cond:
+            self._stream_counter += 1
+            sid = stream.sid = self._stream_counter
+            self._streams[sid] = stream
+        return sid
 
     def _take(self, stream: _Stream, n: int):
         """(chunks, yield times, done, error) — the stream's first ``n``
@@ -314,9 +400,11 @@ class Replica:
 
     def _grant(self, sid: int, upto: int):
         """Let stream ``sid`` yield up to ``upto`` chunks in all (the
-        stream condition is held by the caller)."""
+        stream condition is held by the caller). A pushed stream has no
+        feeder to tell."""
         stream = self._streams.get(sid)
-        if stream is not None and upto > stream.limit:
+        if stream is not None and stream.gen is not None \
+                and upto > stream.limit:
             stream.limit = upto
             self._stream_cond.notify_all()
 
@@ -336,7 +424,8 @@ class Replica:
 
     def stream_cancel(self, sid: int):
         """Free stream ``sid``: its feeder closes the generator, now if
-        it waits for a reader, else when the running next() returns."""
+        it waits for a reader, else when the running next() returns; a
+        pushed stream drops what it is handed from now on."""
         with self._stream_cond:
             stream = self._streams.pop(sid, None)
             if stream is not None:

@@ -15,7 +15,9 @@ continuous-batching autoscaler consumes (ROADMAP item 1):
   * ``execute``         — user code (includes batch residency for
                           batched methods; ``execute - batch_wait``
                           isolates pure compute)
-  * ``ttft`` / ``tpot`` — generation deployments only (serve/llm.py):
+  * ``ttft`` / ``tpot`` — generation deployments only (serve/llm.py,
+                          recorded on the engine's thread where a first
+                          token and a finish are handed to the replica):
                           time-to-first-token and time-per-output-token
   * ``engine_queue``    — generation deployments only (serve/llm.py,
                           beside ``ttft``, from the request's own
@@ -25,9 +27,11 @@ continuous-batching autoscaler consumes (ROADMAP item 1):
                           (Replica.stream_poll, stream_next): one
                           reply's duration from its call (its count is
                           the number of replies), and, once a chunk, how
-                          long the chunk sat in the replica between the
-                          generator yielding it and the reply that
-                          carries it leaving
+                          long the chunk sat in the replica between its
+                          generator yielding it (or, for a pushed
+                          stream, the deployment handing it over: an
+                          LLM step's tokens, all at one instant) and
+                          the reply that carries it leaving
 
 Two sinks per observation, both cheap (a bucket increment under one
 lock):

@@ -29,9 +29,11 @@ def _tokens(seed, n, vocab=120):
             np.random.default_rng(seed).integers(1, vocab, n)]
 
 
-def drive(eng, after_step=lambda: None):
-    """The script. Returns what the run decided, as plain data."""
-    reqs = {}
+def drive(eng, after_step=lambda: None, reqs=None):
+    """The script. Returns what the run decided, as plain data; the
+    requests it added are left in ``reqs`` (name -> Request) for a
+    caller that gives one: the engine keeps no finished request."""
+    reqs = {} if reqs is None else reqs
 
     def add(name, prompt, **kw):
         reqs[name] = eng.add_request(prompt, **kw)
@@ -180,7 +182,8 @@ def check(eng, case):
         return real(params, packed, *pools, q=q, firsts=firsts)
 
     eng._decode = decode
-    got = drive(eng, after_step=lambda: check_tables(eng))
+    reqs = {}
+    got = drive(eng, after_step=lambda: check_tables(eng), reqs=reqs)
     assert len(seen) > 20
     assert not eng._active and len(eng._free_lanes) == eng.max_batch
     with open(RECORDED) as f:
@@ -190,7 +193,8 @@ def check(eng, case):
     if eng._prefix:
         assert got["cached"]["partial"] == 16 and got["cached"]["full"] == 19
     else:       # a pool that indexes nothing: no request has a chain
-        assert all(r.chain is None for r in eng._requests.values())
+        assert len(reqs) == 7
+        assert all(r.chain is None for r in reqs.values())
     assert got["outputs"]["one"] and len(got["outputs"]["one"]) == 1
     assert sum(got["preemptions"].values()) >= 1
     if eng._q_rows > 1:

@@ -293,3 +293,267 @@ def test_readme_recipe_on_detached_cluster_lands_on_the_node_daemon(
     replica = next(a for a in got["actors"]
                    if a["name"].startswith("SERVE:LLMServer"))
     assert replica["is_device"] and replica["node_id"] == daemon["node_id"]
+
+
+# ---------------------------------------------------------------------------
+# A step's tokens leave in one hand-over to the replica (PR 52): one
+# replica by hand, no cluster, its engine stepped by the test.
+# ---------------------------------------------------------------------------
+import gc  # noqa: E402
+
+import engine_by_hand  # noqa: E402
+
+_POOL = dict(num_blocks=64, block_size=8, max_batch=4)
+
+
+def _params():
+    from ray_tpu.models.gpt import init
+
+    return init(jax.random.PRNGKey(0), CFG)
+
+
+@pytest.fixture
+def by_hand():
+    """make(**kw) -> (replica, engine): an ``_LLMServer`` replica whose
+    engine's loop was never started."""
+    from ray_tpu.serve import slo
+    from ray_tpu.serve.llm import _LLMServer
+    from ray_tpu.serve.replica import Replica
+
+    slo._reset_for_tests()
+    made = []
+
+    def make(name="by_hand", **kw):
+        with engine_by_hand.held() as engines:
+            rep = Replica(_LLMServer, (CFG,),
+                          dict(params=_params(), **_POOL, **kw),
+                          deployment_name=name)
+        made.append(rep)
+        return rep, engines[0]
+
+    yield make
+    for rep in made:
+        rep.instance.engine.stop()
+    slo._reset_for_tests()
+
+
+def _ask(rep, prompt, **kw):
+    from ray_tpu.serve.replica import STREAM_MARKER
+
+    return rep.handle_request(
+        "__call__", ({"prompt": prompt, **kw},), {})[STREAM_MARKER]
+
+
+def _read_all(rep, sids):
+    """{sid: frames}: every stream drained through stream_poll."""
+    for sid in sids:
+        rep.stream_grant(sid, 16, "me")
+    frames, open_sids = {sid: [] for sid in sids}, set(sids)
+    while open_sids:
+        reply = rep.stream_poll("me")
+        assert reply, "streams that ended have their ends ready"
+        for sid, (chunks, done, error) in reply.items():
+            assert error is None
+            frames[sid] += chunks
+            if done:
+                open_sids.discard(sid)
+    return frames
+
+
+def test_a_steps_tokens_take_the_stream_condition_once_and_no_thread(
+        by_hand):
+    """(a, b) Four live streams: no ``serve-feed-*`` thread is started,
+    one decode step takes ``_stream_cond`` once and notifies once, its
+    ring entry counts one hand-over of four tokens, and the next
+    stream_poll reply carries all four streams' frames."""
+    from ray_tpu.util import perfmodel
+
+    class Counted(threading.Condition):
+        entered = notified = 0
+
+        def __enter__(self):
+            Counted.entered += 1
+            return super().__enter__()
+
+        def notify_all(self):
+            Counted.notified += 1
+            super().notify_all()
+
+    def feeders():
+        return {t.name for t in threading.enumerate()
+                if t.name.startswith("serve-feed-")}
+
+    rep, eng = by_hand()
+    rep._stream_cond = Counted()
+    before = feeders()
+    sids = [_ask(rep, [3, 1, 4, 1, 5, i], max_tokens=12) for i in range(4)]
+    assert feeders() == before
+    for sid in sids:
+        rep.stream_grant(sid, 16, "me")
+    engine_by_hand.drive(eng, steps=2)       # prompts in, lanes taken
+    assert len([r for r in eng._active if r.lane is not None]) == 4
+    rep.stream_poll("me")                    # take what the prefill left
+    perfmodel.clear_device_steps()
+    Counted.entered = Counted.notified = 0
+    eng.step()
+    assert (Counted.entered, Counted.notified) == (1, 1)
+    (entry,) = [e for e in perfmodel.device_step_events()
+                if e.get("deployment") == eng.name]
+    assert (entry["handovers"], entry["tokens_handed"]) == (1, 4)
+    reply = rep.stream_poll("me")
+    assert sorted(reply) == sids
+    assert all(len(chunks) == 1 and "token" in chunks[0] and not done
+               for chunks, done, _ in reply.values())
+    engine_by_hand.drive(eng)
+    assert feeders() == before
+    perfmodel.clear_device_steps()
+
+
+@pytest.mark.parametrize("speculative", [None, {"mode": "ngram", "k": 3}],
+                         ids=["plain", "proposer"])
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+def test_a_pushed_stream_delivers_what_tokens_delivers(
+        by_hand, temperature, speculative):
+    """(c) Frame for frame: the same prompts and seeds through a bare
+    engine's ``Request.tokens()`` and through the replica's streams give
+    the same tokens in the same order, the final frame last."""
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.serve.llm import decode
+
+    asks = [dict(prompt=[5, 6, 7, 5, 6, 7, 5, 6], max_tokens=14, seed=1),
+            dict(prompt=[9, 8, 7, 6], max_tokens=9, seed=2),
+            dict(prompt=[5, 6, 7, 5, 6, 7, 5, 6], max_tokens=3, seed=3)]
+    bare = LLMEngine(_params(), CFG, **_POOL, prefill_chunk_tokens=32,
+                     speculative=speculative)
+    reqs = [bare.add_request(a["prompt"], a["max_tokens"], seed=a["seed"],
+                             temperature=temperature) for a in asks]
+    engine_by_hand.drive(bare)
+    want = [list(r.tokens()) for r in reqs]
+    assert [len(w) for w in want] == [14, 9, 3]
+
+    rep, eng = by_hand(speculative=speculative)
+    sids = [_ask(rep, temperature=temperature, **a) for a in asks]
+    engine_by_hand.drive(eng)
+    frames = _read_all(rep, sids)
+    for sid, toks, req in zip(sids, want, reqs):
+        assert frames[sid][:-1] == [{"token": t} for t in toks]
+        assert frames[sid][-1] == {
+            "done": True, "finish_reason": "length",
+            "num_tokens": len(toks), "preemptions": 0,
+            "cached_tokens": req.cached_tokens, "text": decode(toks)}
+    if speculative is not None and not temperature:
+        # A greedy loop repeats itself: several tokens left in a step.
+        assert eng.stats()["spec"]["accepted"] > 0
+
+
+def _alive(refs):
+    gc.collect()
+    return [r().rid for r in refs if r() is not None]
+
+
+def test_phases_are_recorded_once_a_request_and_finished_requests_go(
+        by_hand):
+    """(e, f) ``ttft``, ``engine_queue`` and ``tpot`` once a request;
+    when a request's last frame has been handed over nothing of the
+    engine or the replica holds the request (it has no ``_requests``),
+    and ``stats()`` still answers."""
+    import weakref
+
+    rep, eng = by_hand()
+    assert not hasattr(eng, "_requests")
+    answers = (9, 2, 12)
+    sids = [_ask(rep, [1, 2, 3, i + 4], max_tokens=n)
+            for i, n in enumerate(answers)]
+    refs = [weakref.ref(r) for r in eng._waiting]
+    assert _alive(refs) == [1, 2, 3]
+    engine_by_hand.drive(eng, steps=2)       # the two-token answer is out
+    assert _alive(refs) == [1, 3]
+    engine_by_hand.drive(eng)
+    assert _alive(refs) == []
+    stats = rep.instance.engine_stats()
+    assert stats["finished"] == 3 and stats["in_flight"] == 0
+    frames = _read_all(rep, sids)
+    assert [len(frames[sid]) for sid in sids] == [n + 1 for n in answers]
+    for phase in ("ttft", "engine_queue", "tpot"):
+        assert stats["phase_hist"][phase]["count"] == 3, phase
+
+
+def test_a_request_without_a_sink_leaves_by_its_queue_and_is_forgotten():
+    """(f) The engine outside Serve: tokens on ``out_q``, no hand-over
+    counted, a finished request held by its caller alone, and no
+    consumer without a sink."""
+    import weakref
+
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.util import perfmodel
+
+    perfmodel.clear_device_steps()
+    eng = LLMEngine(_params(), CFG, **_POOL, name="no_sink")
+    with pytest.raises(ValueError, match="sink"):
+        eng.add_request([1, 2, 3], max_tokens=2, consumer=object())
+    short = eng.add_request([1, 2, 3], max_tokens=2)
+    long = eng.add_request([4, 5, 6], max_tokens=9)
+    refs = [weakref.ref(short), weakref.ref(long)]
+    engine_by_hand.drive(eng, steps=3)
+    assert len(list(short.tokens())) == 2
+    del short
+    assert _alive(refs) == [long.rid]
+    engine_by_hand.drive(eng)
+    assert eng.stats()["finished"] == 2
+    assert len(list(long.tokens())) == 9
+    del long
+    assert _alive(refs) == []
+    ring = [e for e in perfmodel.device_step_events()
+            if e.get("deployment") == "no_sink"]
+    assert ring and all(e["handovers"] == 0 and e["tokens_handed"] == 0
+                        for e in ring)
+    perfmodel.clear_device_steps()
+
+
+def test_the_replica_freezes_once_a_process_and_an_engine_never(
+        by_hand, monkeypatch):
+    """(g) The set-up's heap leaves the collector's sight once, when the
+    process's first replica is warm: as many of its requests as its
+    engine has lanes (four here) have been handed over whole since the
+    process last built a program. A bare engine's process is left
+    alone."""
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.serve import llm as serve_llm
+
+    calls = []
+    monkeypatch.setattr(gc, "collect", lambda *a: calls.append("collect"))
+    monkeypatch.setattr(gc, "freeze", lambda: calls.append("freeze"))
+    monkeypatch.setattr(serve_llm, "_frozen", False)
+
+    def answer(rep, eng, n):
+        """``n`` requests one after another, each run to its end."""
+        for _ in range(n):
+            sid = _ask(rep, [1, 2, 3], max_tokens=6)
+            engine_by_hand.drive(eng)
+        return sid
+
+    bare = LLMEngine(_params(), CFG, **_POOL)
+    for _ in range(6):
+        req = bare.add_request([1, 2, 3], max_tokens=3)
+        engine_by_hand.drive(bare)
+    assert len(list(req.tokens())) == 3 and calls == []
+
+    first, eng1 = by_hand("freeze_a")
+    second, eng2 = by_hand("freeze_b")
+    # Three, whether or not the first of them built the step programs.
+    answer(first, eng1, 3)
+    assert calls == []
+    # A program built now (a warm-up of another shape) starts the count
+    # again: the fourth request in a row is not the fourth since.
+    built = serve_llm._programs_built
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+    assert serve_llm._programs_built > built
+    answer(first, eng1, 3)
+    assert calls == []
+    sid = answer(first, eng1, 1)
+    assert calls == ["collect", "freeze"]
+    # Its last frame was handed over before the process stood still.
+    assert _read_all(first, [sid])[sid][-1]["done"]
+    answer(first, eng1, 5)
+    answer(second, eng2, 5)
+    assert calls == ["collect", "freeze"]

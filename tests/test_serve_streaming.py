@@ -416,3 +416,213 @@ def test_grpc_drains_a_stream_through_the_same_path(rt):
         [("g", i) for i in range(30)]
     _wait(lambda: not _pollers() and not _state(h)["feeders"],
           "the poller and the feeder to end")
+
+
+# ---------------------------------------------------------------------------
+# A stream the deployment fills itself (PR 52): user code returns a
+# PushedStream in place of a generator and hands chunks to many of them
+# in one ``push``; the replica keeps it on the same books with no feeder
+# thread, and polls, handle and proxy read it as they read a generator's.
+# ---------------------------------------------------------------------------
+from ray_tpu.serve.replica import (  # noqa: E402
+    STREAM_MARKER,
+    PushedStream,
+    Replica,
+)
+
+
+def _feeders():
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("serve-feed-")]
+
+
+@serve.deployment
+class Pusher:
+    """A PushedStream a request, filled by ``feed``: what an engine's
+    step loop does with a step's tokens for every lane. ``gen`` is the
+    other kind of streaming method, on the same replica."""
+
+    def __init__(self):
+        self.streams = {}
+
+    def __call__(self, req):
+        # Imported here: the class travels to its replica by value, and
+        # this module is not importable there.
+        from ray_tpu.serve.replica import PushedStream, push
+
+        stream = self.streams[req["tag"]] = PushedStream()
+        # Handed over before the replica has the stream on its books.
+        push([(stream, [{"tag": req["tag"], "i": i}
+                        for i in range(req.get("early", 0))], False, None)])
+        return stream
+
+    def gen(self, req):
+        return ({"tag": req["tag"], "i": i} for i in range(req["n"]))
+
+    def feed(self, req):
+        from ray_tpu.serve.replica import push
+
+        push([(self.streams[tag], [{"tag": tag, "i": i} for i in chunks],
+               ended, ValueError(error) if error else None)
+              for tag, chunks, ended, error in req["handed"]])
+        return {"feeders": sum(t.name.startswith("serve-feed-")
+                               for t in threading.enumerate())}
+
+
+# The class for a Replica built by hand; the name above is the
+# deployment (so the class travels to a replica's worker by value).
+_Pusher = Pusher.func_or_class
+
+
+class _Counted(threading.Condition):
+    """A stream condition that counts its acquisitions and wake-ups."""
+
+    def __init__(self):
+        super().__init__()
+        self.entered = self.notified = 0
+
+    def __enter__(self):
+        self.entered += 1
+        return super().__enter__()
+
+    def notify_all(self):
+        self.notified += 1
+        super().notify_all()
+
+
+def _open(rep, tag, early=0):
+    return rep.handle_request(
+        "__call__", ({"tag": tag, "early": early},), {})[STREAM_MARKER]
+
+
+def test_a_pushed_stream_has_no_feeder_and_a_generator_still_has_one():
+    """Which path a streaming response takes is the type of what user
+    code returned: a generator is parked behind a feeder thread of its
+    own, a PushedStream goes on the same books with no thread."""
+    rep = Replica(_Pusher, (), {}, deployment_name="push_kinds")
+    before = set(_feeders())
+    pushed = _open(rep, "p")
+    assert set(_feeders()) == before
+    assert rep._streams[pushed].gen is None
+    # Longer than its run-ahead: its feeder waits for a reader.
+    parked = rep.handle_request(
+        "gen", ({"tag": "g", "n": 40},), {})[STREAM_MARKER]
+    assert set(_feeders()) - before == {f"serve-feed-{parked}"}
+    assert rep._streams[parked].gen is not None
+    assert rep.stream_next(parked, 3) == (
+        [{"tag": "g", "i": i} for i in range(3)], False)
+    rep.stream_cancel(parked)
+    _wait(lambda: set(_feeders()) == before, "the generator's feeder to end")
+    rep.instance.feed({"handed": [["p", [0, 1], True, None]]})
+    assert rep.stream_next(pushed, 16) == (
+        [{"tag": "p", "i": 0}, {"tag": "p", "i": 1}], True)
+    assert not rep._streams
+
+
+def test_one_push_fills_many_streams_under_one_lock_with_one_wake_up():
+    """(b) A push into twelve streams takes the stream condition once,
+    stamps one time of arrival and wakes the polls once, and the next
+    stream_poll reply carries all of it."""
+    rep = Replica(_Pusher, (), {}, deployment_name="push_books")
+    cond = rep._stream_cond = _Counted()
+    sids = [_open(rep, k) for k in range(12)]
+    for sid in sids:
+        rep.stream_grant(sid, 16, "me")      # the reader's first word
+    cond.entered = cond.notified = 0
+    rep.instance.feed({"handed": [[k, [0, 1, 2], k % 2 == 0, None]
+                                  for k in range(12)]})
+    assert (cond.entered, cond.notified) == (1, 1)
+    assert len({t for s in rep._streams.values() for _, t in s.ready}) == 1
+    reply = rep.stream_poll("me")
+    assert sorted(reply) == sids
+    for k, sid in enumerate(sids):
+        assert reply[sid] == ([{"tag": k, "i": i} for i in range(3)],
+                              k % 2 == 0, None)
+    # The ended ones are off the books; the rest are ongoing work.
+    assert sorted(rep._streams) == sids[1::2]
+    assert rep.stats()["ongoing"] == 6
+    hist = rep.stats()["phase_hist"]
+    assert hist["stream_hold"]["count"] == 36
+
+
+def test_what_is_handed_over_before_the_books_and_the_reader_is_kept():
+    """Chunks handed over before the replica saw the stream, and before
+    its reader said whose polls carry it, wait in it, in order; a grant
+    moves nothing of a stream that has no feeder."""
+    rep = Replica(_Pusher, (), {}, deployment_name="push_early")
+    sid = _open(rep, "a", early=2)
+    rep.instance.feed({"handed": [["a", [2, 3], False, None]]})
+    stream = rep._streams[sid]
+    assert stream.caller is None
+    assert [c["i"] for c, _ in stream.ready] == [0, 1, 2, 3]
+    assert rep.stream_poll("nobody") == {}   # its limit: nothing is theirs
+    limit = stream.limit
+    rep.stream_grant(sid, 10 ** 6, "me")
+    assert stream.limit == limit
+    assert rep.stream_poll("me") == {
+        sid: ([{"tag": "a", "i": i} for i in range(4)], False, None)}
+
+
+def test_a_pushed_stream_answers_the_one_request_that_made_it():
+    with pytest.raises(RuntimeError, match="made by the request"):
+        PushedStream()                       # outside any request
+
+    class Again:
+        made = None
+
+        def __call__(self, req):
+            self.made = self.made or PushedStream()
+            return self.made
+
+    rep = Replica(Again, (), {}, deployment_name="push_again")
+    sid = rep.handle_request("__call__", ({},), {})[STREAM_MARKER]
+    with pytest.raises(TypeError, match="answers the one request"):
+        rep.handle_request("__call__", ({},), {})
+    assert list(rep._streams) == [sid]
+    other = Replica(Again, (), {}, deployment_name="push_other")
+    other.instance.made = rep.instance.made
+    with pytest.raises(TypeError, match="answers the one request"):
+        other.handle_request("__call__", ({},), {})
+    assert not other._streams
+
+
+def test_a_cancelled_or_abandoned_pushed_stream_drops_what_it_is_handed():
+    """(d) stream_cancel of a pushed stream leaves no entry in
+    ``_streams``, and what is handed to it afterwards, or past its end,
+    or after its replica is gone, goes nowhere."""
+    rep = Replica(_Pusher, (), {}, deployment_name="push_cancel")
+    a, b, c = (_open(rep, tag) for tag in "abc")
+    held = {tag: rep.instance.streams[tag] for tag in "abc"}
+    rep.stream_cancel(a)
+    assert sorted(rep._streams) == [b, c]
+    rep.instance.feed({"handed": [["a", [0, 1], True, None],
+                                  ["b", [0], True, None]]})
+    assert held["a"].ready == [] and not held["a"].ended
+    assert rep.stream_next(a, 16) == ([], True)
+    rep.instance.feed({"handed": [["b", [1], False, None]]})    # past its end
+    assert rep.stream_next(b, 16) == ([{"tag": "b", "i": 0}], True)
+    assert sorted(rep._streams) == [c]
+    feed = rep.instance.feed
+    del rep                                  # the finalizer abandons c
+    feed({"handed": [["c", [0], False, None]]})
+    assert held["c"].cancelled and held["c"].ready == []
+
+
+@pytest.mark.parametrize("k", [0, 5])
+def test_an_error_handed_to_a_pushed_stream_arrives_behind_its_chunks(
+        rt, k):
+    """(d) Through the handle, as a generator's: the k chunks handed
+    over before the error, then the error, and the stream is freed."""
+    serve.run(Pusher.bind(), name="default")
+    h = serve.get_app_handle("default")
+    response = h.remote({"tag": "e", "early": k})
+    assert STREAM_MARKER in response.result(timeout=30)    # it is there
+    it = response.iter_stream(timeout=60)
+    fed = h.options(method_name="feed").remote(
+        {"handed": [["e", [], True, f"boom at {k}"]]}).result(timeout=30)
+    assert fed["feeders"] == 0
+    assert [next(it)["i"] for _ in range(k)] == list(range(k))
+    with pytest.raises(ray_tpu.TaskError, match=f"boom at {k}") as ei:
+        next(it)
+    assert isinstance(ei.value.cause, ValueError)
+    _wait(lambda: not _pollers(), "the failed stream to be freed")
