@@ -7,8 +7,9 @@ builder and its keyword arguments: pool, block size, ``max_batch``,
 chunk size) and from the cell's file ``traffic`` (callers, prefixes,
 body and answer sizes, stagger and ramp) and ``reference_request``.
 
-Set-up, in this order: deploy; one unshared reference request, which
-decodes beside the warm-ups and the ramp; one request a prefix, which registers it;
+Set-up, in this order: deploy, and wait for the replica's first answer
+(``engine_stats``); one unshared reference request, which decodes
+beside the warm-ups and the ramp; one request a prefix, which registers it;
 one request for every chunk length the cell's prompts can give (the
 engine compiles a chunk's forward pass, its cache slice and its pool
 write once a length; bodies come in multiples of ``multiple_of``, so
@@ -149,6 +150,10 @@ def run(ctx) -> dict:
     proxy = serve.start(http_port=0)
     host, port = "127.0.0.1", proxy.port
     stats = handle.options(method_name="engine_stats")
+    # ``serve.run`` returns before the replica is built: the first
+    # answer to anything says it is up, so that no request's timeout
+    # (the proxy's 504 after 60 s) covers the start.
+    stats.remote().result(timeout=900)
     log(f"deployed in {time.perf_counter() - t0:.1f} s on port {port}")
 
     # -- the reference request: unshared, decoding beside the warm-ups

@@ -1,8 +1,11 @@
 """Gap between consecutive token frames of one stream at the client,
-all streams pooled: the nearest-rank 99th percentile. The proxy hands
-a stream's tokens on in pulls of up to 16, so one gap in sixteen is a
-pull's and the rest are a step's or none: the 99th percentile lies well
-inside the pulls' gaps, the 95th on the edge between the two kinds."""
+all streams pooled: the nearest-rank 99th percentile. Since PR 35 a
+token leaves the replica when it is made, so a gap is an engine step's
+length and the 99th percentile of a window is among its longest steps.
+End to end in the chat cell alone, whose ~1,600 steps a window put
+sixteen of them beyond it; the long-context cells, with ~1,000 and ~650
+steps, report the same reading per layer as ``itl_p99_long_ms``
+(PERF.md section 6, PR 54, refusal round)."""
 
 from benchmark import clientstats, traffic
 from benchmark.harness import log

@@ -1,14 +1,16 @@
 """Device time of the flash attention kernels (forward and backward,
-all layers) in one training step: the self time of the trace's Mosaic
-custom-call events that take the step's queries, over the executions of
-the step's program."""
+all layers) in one training step: the self time of the Mosaic kernels
+whose name begins ``flash_`` (ops/pallas/flash.py's names on its
+``pallas_call``s: ``flash_fwd``, ``flash_bwd_fused``, ``flash_bwd_dq``,
+``flash_bwd_dkv``), over the executions of ``jit_train_step``, by
+``named_kernels.per_execution_s``. Found by the kernels' names on the
+trace's op events, never by an operand."""
 
-from benchmark import kernels
+from benchmark import named_kernels
+
+NEEDLE = "%flash_"
 
 
 def read(c):
-    t = c.get("trace")
-    if not t:
-        return None
-    per_step = kernels.mosaic_s_per_step(t, kernels.flash_operand(c))
-    return None if per_step is None else per_step * 1e3
+    s = named_kernels.per_execution_s(c, NEEDLE, named_kernels.TRAIN_PROGRAM)
+    return None if s is None else s * 1e3

@@ -1,16 +1,16 @@
-"""Device time of the paged decode kernel in one decode step: the mean
-self time of the trace's Mosaic custom-call events that take the
-configuration's KV pool, times the model's layers (the kernel runs once
-a layer in a decode step)."""
+"""Device time of the paged decode kernel in one decode step: the self
+time of the Mosaic kernels named ``paged_decode`` (the name on
+ops/pallas/paged_decode.py's ``pallas_call``, and on whatever kernel
+takes its place under that name), all layers, over the executions of
+``jit_llm_decode``, by ``named_kernels.per_execution_s``. Found by the
+kernel's name on the trace's op events, never by an operand: the pool
+it reads may be head-major, as stored, or not an operand at all."""
 
-from benchmark import kernels
+from benchmark import named_kernels
+
+NEEDLE = "%paged_decode"
 
 
 def read(c):
-    t = c.get("trace")
-    if not t:
-        return None
-    secs, calls = kernels.mosaic_s(t, kernels.paged_operand(c))
-    if not calls:
-        return None
-    return secs / calls * c["model_fields"]["n_layer"] * 1e3
+    s = named_kernels.per_decode_step_s(c, NEEDLE)
+    return None if s is None else s * 1e3

@@ -80,7 +80,7 @@ def main() -> int:
                     help="tiny sizes on any backend; exits 3, no result")
     args = ap.parse_args()
 
-    from benchmark import harness, xplane
+    from benchmark import harness, named_kernels, xplane
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
@@ -157,6 +157,9 @@ def main() -> int:
             raise SystemExit("benchmark: the traced window holds no "
                              "device operation; no result")
         line["metrics"] = read_metrics("layer_metrics", layer, collected)
+        for name, secs, ops in named_kernels.unread(trace):
+            harness.log(f"Mosaic kernels that no reader of this cell "
+                        f"counts: {name} ({ops} ops, {secs * 1e3:.3f} ms)")
         line["device"].update(busy_s=trace["busy_s"],
                               window_s=trace["window_s"])
         # Idle gaps by host activity need host spans on the device's

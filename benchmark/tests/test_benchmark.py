@@ -13,7 +13,7 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, ROOT)
 
-from benchmark import flops, harness, kernels, traffic, xplane  # noqa: E402
+from benchmark import flops, harness, named_kernels, traffic, xplane  # noqa: E402
 
 
 def _manifest():
@@ -121,20 +121,18 @@ def test_trace_reduction_on_a_small_trace():
     assert r["op_calls"]["fusion.1"] == pytest.approx(3 / 2)
     assert sum(r["op_self_s"].values()) == pytest.approx(r["busy_s"])
     assert xplane.top_ops(r, 2)[0][0] == "fusion.1"
-    # A Mosaic kernel is counted only for the reader whose operand it
-    # takes: another kernel's reader sees nothing of it.
-    secs, calls = kernels.mosaic_s(r, "[8,64]")
-    assert secs == pytest.approx(150e-9) and calls == pytest.approx(0.5)
-    assert kernels.mosaic_s(r, "[12,2560,16,64]") == (0.0, 0.0)
+    # A Mosaic kernel is found by its instruction's name, and one that
+    # no reader has asked for is named: 150 ns in half an execution of
+    # ``jit_step`` on the average chip.
+    c = {"trace": r}
+    assert named_kernels.per_execution_s(c, "%paged_decode",
+                                         "jit_step") is None
+    assert named_kernels.unread(r) == [["%custom-call",
+                                        pytest.approx(150e-9), 1]]
+    assert named_kernels.per_execution_s(
+        c, "%custom-call", "jit_step") == pytest.approx(300e-9)
+    assert named_kernels.unread(r) == []
     assert xplane.idle_pct(r) == pytest.approx(100 * (1 - 900 / 1500))
-
-
-def test_kernel_operands_come_from_the_configurations_own_sizes():
-    serve = {"model_fields": GPT2S, "rehearse": False, "config": {
-        "serve": {"kwargs": {"num_blocks": 2560, "block_size": 16}}}}
-    assert kernels.paged_operand(serve) == "[12,2560,16,64]"
-    train = {"model_fields": GPT2S, "batch": 24, "chips": 1, "seq": 1024}
-    assert kernels.flash_operand(train) == "[24,12,1024,64]"
 
 
 @pytest.mark.parametrize("intervals,want_ns", [

@@ -26,8 +26,10 @@ WINDOW = ('%chunk_attn.9 = bf16[8,8,512,128]{3,2,1,0} custom-call(s32[2]{0} '
 # Takes the kernel's result: holds its name, is not the kernel.
 CONSUMER = ('%fusion.12 = bf16[512,48,128]{2,1,0} fusion(bf16[8,6,512,128]'
             '{3,2,1,0} %chunk_attn.3), kind=kLoop')
-DECODE = "%attn_full.2 = bf16[64,8,6,128]{3,2,1,0} custom-call(...)"
-EXPERTS = "%moe_experts_chunk.3 = bf16[7936,1024]{1,0} custom-call(...)"
+DECODE = ('%attn_full.2 = bf16[64,8,6,128]{3,2,1,0} custom-call(...), '
+          'custom_call_target="tpu_custom_call"')
+EXPERTS = ('%moe_experts_chunk.3 = bf16[7936,1024]{1,0} custom-call(...), '
+           'custom_call_target="tpu_custom_call"')
 
 
 def _read(name, c):
@@ -64,9 +66,10 @@ def test_nothing_to_read_is_none_and_not_an_error(c):
 
 
 def test_the_name_shares_nothing_with_the_decode_steps_needles():
-    """``named_kernels`` sums every op whose name HOLDS a reader's
-    needle, chunk programs included: a chunk kernel named
-    ``attn_full_chunk`` would be added into ``attn_full_ms``."""
+    """``named_kernels`` sums every kernel whose name HOLDS a reader's
+    needle, whatever program ran it: a chunk kernel named
+    ``attn_full_chunk`` would be added into ``attn_full_ms``. (That no
+    needle of ANY reader holds another's: ``test_named_kernels.py``.)"""
     mine = harness.load_module("layer_metrics", "chunk_attn_ms").NEEDLE
     assert mine == "%chunk_attn"
     # Every needle a reader's source holds, so that one a later PR adds
@@ -78,7 +81,7 @@ def test_the_name_shares_nothing_with_the_decode_steps_needles():
             theirs |= set(re.findall(r'"(%[a-z_]+)"', f.read()))
     theirs.discard(mine)
     assert theirs >= {"%attn_full", "%attn_window", "%attn_latent",
-                      "%moe_experts_decode"}
+                      "%moe_experts_decode", "%paged_decode", "%flash_"}
     for needle in theirs:
         assert needle not in mine and mine not in needle
         for op in (FULL, WINDOW):

@@ -20,8 +20,9 @@ What the reduction takes from a trace:
               and seconds
 On the TPU an op event's name is the instruction's whole HLO text, and
 the events carry nothing else worth keeping: a kernel is found by
-what that text holds (``custom_call_target="tpu_custom_call"``, its
-operand shapes). ``short_name`` cuts a name down for the breakdown.
+what that text holds (``custom_call_target="tpu_custom_call"`` and, at
+its head, the instruction's name: ``named_kernels.py``). ``short_name``
+cuts a name down for the breakdown.
 A device plane is one whose name starts with ``/device:`` and is not a
 ``/device:CUSTOM`` or host plane; an op line is one named ``XLA Ops``
 (TPU) or, failing that, any line of a device plane that is not a
@@ -182,17 +183,6 @@ def reduce_rows(rows: list) -> dict:
 def top_ops(reduced: dict, n: int = 10) -> list:
     ops = sorted(reduced["op_self_s"].items(), key=lambda kv: -kv[1])
     return [[short_name(name), secs] for name, secs in ops[:n]]
-
-
-def matching_s(reduced: dict, needles: list) -> tuple:
-    """(seconds, calls) of the ops whose name holds any of ``needles``:
-    self time, on the average chip."""
-    secs = calls = 0.0
-    for name, s in reduced["op_self_s"].items():
-        if any(x in name for x in needles):
-            secs += s
-            calls += reduced["op_calls"][name]
-    return secs, calls
 
 
 def idle_pct(reduced: dict) -> float:
