@@ -1,6 +1,10 @@
 """Driver ``serve_closed_loop``: N callers over HTTP streaming through
 the Serve proxy, each sending its next request when the last one's done
-frame arrives, no think time.
+frame arrives, no think time. The callers are threads of generator
+subprocesses (``drivers/callers.py``; four processes for 64 callers),
+not of this process, which is the server's: they share with it the
+loopback socket and the monotonic clock. The set-up requests below are
+sent from this process, before the window.
 
 Reads from the configuration file ``model`` and ``serve`` (the app's
 builder and its keyword arguments: pool, block size, ``max_batch``,
@@ -15,8 +19,10 @@ engine compiles a chunk's forward pass, its cache slice and its pool
 write once a length; bodies come in multiples of ``multiple_of``, so
 the lengths are its multiples up to the chunk budget: a deployment
 that has been up for a while has them all, so the window must too);
-the callers, staggered; the ramp. Then the window. Requests still in
-flight when it closes are cut off by closing their sockets.
+the callers, staggered (each generator starts its callers at the
+instants it is handed); the ramp. Then the window. Requests still in
+flight when it closes are cut off by closing their sockets: the
+generators are told so at the close, and hand their records over then.
 
 After the window, outside every timed span, ``correct`` is decided:
 the reference request and, for every prefix, the longest answer a
@@ -25,98 +31,25 @@ decoded in a full batch) are rebuilt from the plan and compared token
 by token with plain greedy decoding over ``gpt.forward``.
 
 Collects: one record a request (send time, every token frame's time,
-the done frame), ``engine_stats`` at both edges of the window, the
-engine's ``llm.step`` ring entries in between, and the device's memory
-peaks as the window closes.
+the done frame, stamped by the generator that sent it), the
+generators' reports (``callers``: their CPU seconds inside the window),
+``engine_stats`` at both edges of the window, the engine's ``llm.step``
+ring entries in between, and the device's memory peaks as the window
+closes.
 """
 
 from __future__ import annotations
 
-import http.client
 import importlib
-import json
-import socket
 import threading
 import time
+
+from benchmark.drivers.callers import Fleet, server_threads, stream
 
 
 # Token streams of the set-up requests: "callers" no real caller has.
 _REFERENCE, _WARM_PREFIX, _FIRST_OF_PREFIX, _TAIL = (
     1_000_001, 1_000_002, 1_000_003, 1_000_004)
-
-
-class _Caller:
-    """One closed-loop caller on its own thread and connection."""
-
-    def __init__(self, host, port, index, requests, records, stop):
-        self.host, self.port, self.index = host, port, index
-        self.requests = requests        # iterator of (meta, payload)
-        self.records = records
-        self.stop = stop
-        self.conn = None
-        self.thread = threading.Thread(target=self._run, daemon=True,
-                                       name=f"caller-{index}")
-
-    def _run(self):
-        for meta, payload in self.requests:
-            if self.stop.is_set():
-                return
-            self.records.append(stream(self.host, self.port, payload, meta,
-                                       holder=self))
-
-    def cut(self):
-        conn = self.conn
-        if conn is not None and conn.sock is not None:
-            try:
-                conn.sock.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
-
-
-def stream(host, port, payload, meta=None, holder=None) -> dict:
-    """One streaming request. Every token frame is stamped as it is
-    read; a request that errors, is refused or ends with
-    ``finish_reason: "error"`` is ``failed``."""
-    rec = dict(meta or {}, t_send=None, t_tokens=[], tokens=[], done=None,
-               t_done=None, error=None)
-    body = json.dumps(payload).encode()
-    conn = http.client.HTTPConnection(host, port, timeout=300)
-    if holder is not None:
-        holder.conn = conn
-    try:
-        rec["t_send"] = time.perf_counter()
-        conn.request("POST", "/", body,
-                     {"Content-Type": "application/json"})
-        resp = conn.getresponse()
-        if resp.status != 200:
-            rec["error"] = f"HTTP {resp.status}: {resp.read()[:200]!r}"
-        else:
-            for line in resp:
-                now = time.perf_counter()
-                if not line.strip():
-                    continue
-                frame = json.loads(line)
-                if "token" in frame:
-                    rec["t_tokens"].append(now)
-                    rec["tokens"].append(frame["token"])
-                elif frame.get("done"):
-                    rec["done"], rec["t_done"] = frame, now
-                elif "error" in frame:
-                    rec["error"] = str(frame["error"])[:200]
-    except (OSError, http.client.HTTPException, ValueError) as e:
-        rec["error"] = f"{type(e).__name__}: {e}"
-    finally:
-        if holder is not None:
-            holder.conn = None
-        conn.close()
-    rec["t_end"] = time.perf_counter()
-    if rec["error"] is None and rec["done"] is None:
-        rec["error"] = "stream ended without a done frame"
-    if rec["done"] is not None and \
-            rec["done"].get("finish_reason") == "error":
-        rec["error"] = "finish_reason: error"
-    rec["failed"] = rec["error"] is not None
-    return rec
 
 
 def _must(rec: dict, what: str) -> dict:
@@ -155,6 +88,10 @@ def run(ctx) -> dict:
     # (the proxy's 504 after 60 s) covers the start.
     stats.remote().result(timeout=900)
     log(f"deployed in {time.perf_counter() - t0:.1f} s on port {port}")
+    # The generators start their interpreters beside the warm-ups; the
+    # harness ends them whatever becomes of this run.
+    fleet = Fleet(host, port, spec, ctx.seed, vocab, log)
+    ctx.cleanup.append(fleet.kill)
 
     # -- the reference request: unshared, decoding beside the warm-ups
     ref_prompt = plan["tokens"](_REFERENCE, 0, ref["prompt_tokens"])
@@ -187,32 +124,10 @@ def run(ctx) -> dict:
     log(f"{len(first)} prefix request(s) and {len(tails)} chunk lengths "
         f"warmed {time.perf_counter() - t0:.1f} s after deploy began")
 
-    # -- the callers
-    records, stop = [], threading.Event()
-
-    def requests_of(c: int, who: dict):
-        index = 0
-        while True:
-            for body, answer in who["sizes"]:
-                if index == 0:
-                    # Callers start at mixed phases of their answers.
-                    answer = max(1, round(answer * who["first_share"]))
-                pre = prefixes[who["prefix"]] if who["prefix"] is not None \
-                    else []
-                prompt = pre + plan["tokens"](c, index, body)
-                yield ({"caller": c, "index": index, "prefix": who["prefix"],
-                        "body": body, "prompt_len": len(prompt),
-                        "max_tokens": answer},
-                       {"prompt": prompt, "max_tokens": answer})
-                index += 1
-
-    callers = [_Caller(host, port, c, requests_of(c, who), records, stop)
-               for c, who in enumerate(plan["callers"])]
-    t_first_caller = time.perf_counter()
-    for c, caller in enumerate(callers):
-        due = t_first_caller + spec["stagger_s"] * c / max(len(callers), 1)
-        time.sleep(max(0.0, due - time.perf_counter()))
-        caller.thread.start()
+    # -- the callers, in their generators
+    checks += fleet.ready()
+    t_first_caller = time.perf_counter() + 0.05
+    fleet.start(t_first_caller, spec["stagger_s"])
     time.sleep(max(0.0, t_first_caller + spec["ramp_s"]
                    - time.perf_counter()))
     ref_thread.join(timeout=600)
@@ -233,15 +148,12 @@ def run(ctx) -> dict:
     t_close, t_close_wall = time.perf_counter(), time.time()
     stats_close = stats.remote().result(timeout=60)
     memory = memory_peaks()
-    stop.set()
+    checks.append(server_threads(log))
     steps = [e for e in perfmodel.device_step_events(since=t_open_wall)
              if e["name"] == "llm.step" and e["t_wall"] <= t_close_wall]
-    in_flight = 0
-    for caller in callers:
-        caller.cut()
-    for caller in callers:
-        caller.thread.join(timeout=30)
-        in_flight += caller.thread.is_alive()
+    closed = fleet.close(t_open, t_close)
+    records, in_flight = closed["records"], closed["in_flight"]
+    checks += fleet.checks(closed["reports"])
     serve.shutdown()
 
     ended = [r for r in records if t_open <= r["t_end"] <= t_close]
@@ -324,6 +236,7 @@ def run(ctx) -> dict:
         "records": [r for r in records
                     if not (r["failed"] and r["t_end"] <= t_close)],
         "failed_records": [r for r in ended if r["failed"]],
+        "callers": closed["reports"],
         "engine_stats": (stats_open, stats_close),
         "engine_steps": steps,
         "max_batch": engine["max_batch"],

@@ -4,9 +4,10 @@ same set-up order, window, records and collected keys), with the plain
 reference that decides ``correct`` NAMED BY THE CONFIGURATION's file
 (``reference.module``) instead of ``benchmark/reference.py``, which is
 wired to ``gpt.forward``. A file of its own because no existing file of
-the benchmark may change; ``stream``, ``_Caller``, ``_must`` and the
-set-up's token-stream ids are ``serve_closed_loop``'s, by import, and
-the plan is ``traffic.closed_loop_plan``'s.
+the benchmark may change; ``stream``, ``_must`` and the set-up's
+token-stream ids are ``serve_closed_loop``'s, by import, the callers
+are ``drivers/callers.py``'s generator subprocesses as there, and the
+plan is ``traffic.closed_loop_plan``'s.
 
 What differs, and why:
 
@@ -82,6 +83,10 @@ def run(ctx) -> dict:
     # says it is up, so that no request's timeout covers the start.
     stats.remote().result(timeout=900)
     log(f"deployed in {time.perf_counter() - t0:.1f} s on port {port}")
+    # The generators start their interpreters beside the warm-ups; the
+    # harness ends them whatever becomes of this run.
+    fleet = base.Fleet(host, port, spec, ctx.seed, vocab, log)
+    ctx.cleanup.append(fleet.kill)
 
     # -- the programs' first compilations, one short request each, so
     # that no later request waits out more than one inside the proxy's
@@ -123,32 +128,10 @@ def run(ctx) -> dict:
         f"lengths warmed {time.perf_counter() - t0:.1f} s after deploy "
         f"began")
 
-    # -- the callers
-    records, stop = [], threading.Event()
-
-    def requests_of(c: int, who: dict):
-        index = 0
-        while True:
-            for body, answer in who["sizes"]:
-                if index == 0:
-                    # Callers start at mixed phases of their answers.
-                    answer = max(1, round(answer * who["first_share"]))
-                prompt = prefixes[who["prefix"]] \
-                    + plan["tokens"](c, index, body)
-                yield ({"caller": c, "index": index, "prefix": who["prefix"],
-                        "body": body, "prompt_len": len(prompt),
-                        "max_tokens": answer},
-                       {"prompt": prompt, "max_tokens": answer})
-                index += 1
-
-    callers = [base._Caller(host, port, c, requests_of(c, who), records,
-                            stop)
-               for c, who in enumerate(plan["callers"])]
-    t_first_caller = time.perf_counter()
-    for c, caller in enumerate(callers):
-        due = t_first_caller + spec["stagger_s"] * c / max(len(callers), 1)
-        time.sleep(max(0.0, due - time.perf_counter()))
-        caller.thread.start()
+    # -- the callers, in their generators
+    checks += fleet.ready()
+    t_first_caller = time.perf_counter() + 0.05
+    fleet.start(t_first_caller, spec["stagger_s"])
     time.sleep(max(0.0, t_first_caller + spec["ramp_s"]
                    - time.perf_counter()))
     ref_thread.join(timeout=600)
@@ -169,15 +152,12 @@ def run(ctx) -> dict:
     t_close, t_close_wall = time.perf_counter(), time.time()
     stats_close = stats.remote().result(timeout=60)
     memory = memory_peaks()
-    stop.set()
+    checks.append(base.server_threads(log))
     steps = [e for e in perfmodel.device_step_events(since=t_open_wall)
              if e["name"] == "llm.step" and e["t_wall"] <= t_close_wall]
-    in_flight = 0
-    for caller in callers:
-        caller.cut()
-    for caller in callers:
-        caller.thread.join(timeout=30)
-        in_flight += caller.thread.is_alive()
+    closed = fleet.close(t_open, t_close)
+    records, in_flight = closed["records"], closed["in_flight"]
+    checks += fleet.checks(closed["reports"])
     # Requests cut off at the close still decode in the engine: let it
     # drain, so that it is idle when its arrays go (below).
     for _ in range(120):
@@ -294,6 +274,7 @@ def run(ctx) -> dict:
         "records": [r for r in records
                     if not (r["failed"] and r["t_end"] <= t_close)],
         "failed_records": [r for r in ended if r["failed"]],
+        "callers": closed["reports"],
         "engine_stats": (stats_open, stats_close),
         "engine_steps": steps,
         "max_batch": engine["max_batch"],

@@ -1,6 +1,6 @@
 """Driver ``train_loop``: one ``JaxTrainer.fit`` on the device lane.
 
-The recipe of ``examples/train_gpt.py`` with ``bench.py``'s ingest:
+The recipe of ``examples/train_gpt.py`` with the Data ingest below:
 ``ray_tpu.data.from_items`` of seeded token rows, cycled ->
 ``iter_batches(batch_format="jax", sharding=...)`` ->
 ``train.wrap_step(make_train_step(...), cfg)`` -> ``train.report`` every

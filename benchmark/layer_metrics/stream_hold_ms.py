@@ -1,7 +1,9 @@
-"""Mean time a stream chunk sat in the replica between its generator
-yielding it and the pull that carries it returning
-(``Replica.stream_next``), over the chunks of the window:
-``engine_stats()["phase_hist"]["stream_hold"]``."""
+"""Mean time a stream chunk sat in the replica between the hand-over
+that filled its stream (``serve/replica.py`` ``push``, once a step
+since PR 52; a generator deployment's yield) and the reply that
+carries it leaving (``Replica.stream_poll``: one long-poll a handle
+for every ready chunk, no pull of sixteen since PR 35), over the
+chunks of the window: ``engine_stats()["phase_hist"]["stream_hold"]``."""
 
 from benchmark import timeline
 

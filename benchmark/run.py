@@ -1,4 +1,6 @@
-"""One run of one cell of the benchmark, in one process that owns the chip.
+"""One run of one cell of the benchmark, in one process that owns the chip
+(a serving cell's callers are generator subprocesses of their own,
+``drivers/callers.py``: clients, which take no chip).
 
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
@@ -104,7 +106,11 @@ def main() -> int:
         workload_name=args.workload, workload=workload, config=config,
         model_cfg=model_cfg, model_fields=model_fields,
         chips=int(config["chips"]), seed=args.seed, seconds=float(seconds),
-        trace=bool(args.trace), rehearse=args.rehearse, device=device)
+        trace=bool(args.trace), rehearse=args.rehearse, device=device,
+        # What a driver starts outside this process (the serving cells'
+        # generators) it lists here, and it is stopped and waited for
+        # whatever becomes of the run.
+        cleanup=[])
     ray_tpu.init(num_cpus=2)
     try:
         collected = driver.run(ctx)
@@ -112,6 +118,8 @@ def main() -> int:
         # with a reference) reads the peaks itself, as the window closes.
         memory = collected.pop("memory", None) or harness.memory_peaks()
     finally:
+        for stop in ctx.cleanup:
+            stop()
         ray_tpu.shutdown()
 
     collected.update(config=config, workload=workload, device=device,
