@@ -1,0 +1,29 @@
+"""Share of its roofline the routed experts' grouped product at the
+latent width reaches in a decode step: the least time the chip could
+take for the assignments that fell on the experts held here
+(``latent_moe_cost.operations`` at the published peak) or for reading
+the held experts that got a token (``latent_moe_cost.bytes_read`` at the
+published HBM bandwidth), whichever is longer, over the kernels' device
+time a step (the Mosaic kernels named ``moe_experts_decode``,
+``moe_expert_ms``'s seconds). Assignments and experts hit are the means
+of the ring's ``moe_held_rows`` and ``moe_experts_hit`` over the steps
+that decoded (a layer's mean each, counted by the step program
+itself)."""
+
+from benchmark import flops, latent_moe_cost, named_kernels, timeline
+
+
+def read(c):
+    per_step = named_kernels.per_decode_step_s(c, "%moe_experts_decode")
+    steps = [e for e in timeline.entries(c, "moe_held_rows")
+             if e.get("decode_tokens", 0) > 0 and "moe_experts_hit" in e]
+    fields = c["model_fields"]
+    if per_step is None or not steps or "moe_latent_size" not in fields:
+        return None
+    rows = sum(e["moe_held_rows"] for e in steps) / len(steps)
+    hit = sum(e["moe_experts_hit"] for e in steps) / len(steps)
+    peaks = flops.peaks(c["device"]["kind"])
+    need = max(
+        latent_moe_cost.operations(rows, fields) / peaks["bf16_flops_per_s"],
+        latent_moe_cost.bytes_read(hit, fields) / peaks["hbm_bytes_per_s"])
+    return 100.0 * need / per_step

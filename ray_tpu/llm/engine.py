@@ -69,8 +69,8 @@ import numpy as np
 
 from ..models import pack_span, serving, step_columns
 from ..util import perfmodel, tracing
-from .kv_cache import (BlockChain, PagedKVCache, PrefixPool, WindowPool,
-                       window_table_len)
+from .kv_cache import (BlockChain, PagedKVCache, PrefixPool, StatePool,
+                       WindowPool, window_table_len)
 from .sampling import accept_draws, is_greedy, sample, verify_tokens
 from .spec import make_spec
 
@@ -102,6 +102,11 @@ class Request:
     # one: the sequence's blocks from ``window_first`` on.
     window_table: List[int] = field(default_factory=list)
     window_first: int = 0
+    # Where the model's sequences keep a state: the lane's slot of the
+    # state pools, held from admission to release, and the parked
+    # snapshot its first span is to start from (None: its own slot).
+    state_slot: Optional[int] = None
+    state_from: Optional[int] = None
     # The prompt's chain of prefix-index keys, made once in
     # add_request (None where the pool indexes nothing); blocks of
     # generated tokens join it as they fill.
@@ -193,11 +198,16 @@ def _jit_programs(cfg):
     # every kind's follow the two leading arguments (the parameters and
     # the program's array), as many as the kinds say they have
     # (``LayerKind.rows``); in the chunk a kind with a window has its
-    # own after the full kind's table.
+    # own after the full kind's table. The pools of what a sequence
+    # keeps (``Serving.state``) ride last in both, behind the window
+    # kind's pools and its array.
     n = [len(kind.rows) for kind in model.kinds] + [0]
-    step_pools = tuple(range(2, 2 + n[0] + n[1]))
+    ns = len(model.state.parts) if model.state is not None else 0
+    step_pools = tuple(range(2, 2 + n[0] + n[1] + ns))
+    after = 3 + n[0] + n[1] + bool(n[1])
     chunk_pools = tuple(range(2, 2 + n[0])) \
-        + tuple(range(3 + n[0], 3 + n[0] + n[1]))
+        + tuple(range(3 + n[0], 3 + n[0] + n[1])) \
+        + tuple(range(after, after + ns))
 
     def program(name, fn, **jit_kwargs):
         # The name is what a device trace's ``XLA Modules`` line shows
@@ -227,6 +237,7 @@ class LLMEngine:
 
     def __init__(self, params, cfg, *, num_blocks: int = 64,
                  window_blocks: Optional[int] = None,
+                 state_slots: Optional[int] = None,
                  block_size: int = 16, max_batch: int = 8,
                  prefill_chunk_tokens: Optional[int] = None,
                  prefix_cache: bool = True,
@@ -268,6 +279,13 @@ class LLMEngine:
         # above.
         self.max_nb = self.kv.blocks_for_tokens(self.model.max_seq)
         self._decode, self._prefill_chunk = _jit_programs(cfg)
+        if self.model.state is not None and speculative is not None:
+            raise ValueError(
+                "speculative decoding is refused for a model whose "
+                "sequences keep a state: a verify step's rejected rows are "
+                "rolled back by truncating the block table, and a "
+                "state-space layer's state has already moved past them "
+                "and cannot be cut back")
         # Speculative decoding (llm/spec.py): when enabled, a decode
         # step scores k+1 rows per lane (fixed q shape, one compile)
         # and the accepted prefix + one corrected/bonus token all land
@@ -294,6 +312,25 @@ class LLMEngine:
             self.kv_window = WindowPool(cfg, num_blocks=window_blocks,
                                         block_size=block_size)
             self._win_len = nbw
+        # What a SEQUENCE keeps (a state-space layer's state) lives in
+        # slots: one a lane, held from admission to release, and parked
+        # snapshots the prefix index hands to later sequences.
+        # ``state_slots`` must give every lane its slot, so a grant
+        # waits for nothing but a snapshot another admission has yet to
+        # read (parked snapshots are evicted for it).
+        self.states: Optional[StatePool] = None
+        if self.model.state is not None:
+            if self.kv_window is not None:
+                raise ValueError("a model with a window kind AND a state "
+                                 "is not built: their two answers to a "
+                                 "prefix match are not combined")
+            if state_slots is None:
+                state_slots = 2 * self.max_batch + 1
+            if state_slots - 1 < self.max_batch:
+                raise ValueError(
+                    f"state_slots {state_slots} cannot give {self.max_batch} "
+                    f"lanes a slot each (slot 0 is scratch)")
+            self.states = StatePool(cfg, state_slots)
         self._kv_window_util_peak = 0.0
         self._window_live = 0         # window blocks lanes hold, last step
         self._counters = {}           # the step program's own, last step
@@ -310,7 +347,8 @@ class LLMEngine:
         # hand-over of the interpreter lock, 0.85 ms apiece in the chat
         # cell (PERF.md section 6, PRs 30 and 46). A host array, so the
         # pools the step returns stay uncommitted.
-        self._cols = step_columns(self._q_rows, self._win_len)
+        self._cols = step_columns(self._q_rows, self._win_len,
+                                  self.states is not None)
         self._inputs = np.zeros(
             (self.max_batch, self._cols.table + self.max_nb), np.int32)
         self._inputs[:, self._cols.context_len:self._cols.head] = 1
@@ -473,15 +511,27 @@ class LLMEngine:
                 # A prefix can be taken up only where every kind of
                 # layer still holds what a query there reads: all of it
                 # in the full kind, the window behind it in the other.
-                upto, tail = None, (0, [])
+                upto, tail, snap = None, (0, []), None
                 if self.kv_window is not None:
                     upto, *tail = self.kv_window.match_tail(
                         seq, self.kv.match(seq, req.chain), req.chain)
+                elif self.states is not None:
+                    # ... and where a state at its end is parked. At
+                    # least one token is left to compute: a state cannot
+                    # be held a position back as a block table can.
+                    matched = self.kv.match(seq, req.chain)
+                    upto, snap = self.states.match(req.chain, matched,
+                                                   len(seq) - 1)
+                    if not self._grant_state(req, snap):
+                        break
                 got = self.kv.admit(seq, len(seq) + 1, upto=upto,
                                     chain=req.chain)
                 if got is None:
+                    self._return_state(req)
                     break
                 grant, cached = got
+                if self.states is not None:
+                    self.states.take_up(snap, cached, matched)
                 req.block_table = grant
                 req.cached_tokens = cached
                 if self.kv_window is not None:
@@ -502,9 +552,13 @@ class LLMEngine:
                     req.context_len = cached
                     req.prefilled_upto = cached
             else:
+                if self.states is not None \
+                        and not self._grant_state(req, None):
+                    break
                 grant = self.kv.alloc(
                     self.kv.blocks_for_tokens(len(seq) + 1))
                 if grant is None:
+                    self._return_state(req)
                     break
                 req.block_table = grant
                 req.cached_tokens = 0
@@ -524,6 +578,33 @@ class LLMEngine:
                              time.time(), 0.0,
                              {"rid": req.rid,
                               "preemptions": req.preemptions})
+
+    def _grant_state(self, req: Request, snap: Optional[int]) -> bool:
+        """The lane's slot of the state pools, from admission on (a
+        prefill span leaves its state there), and a hold on the parked
+        snapshot ``snap`` the request will start from, taken first so
+        that the grant does not evict it. False if no slot can be had
+        yet (every other one is a lane's or held)."""
+        if snap is not None:
+            self.states.hold(snap)
+        slot = self.states.grant()
+        if slot is None:
+            if snap is not None:
+                self.states.read(snap)
+            return False
+        req.state_slot, req.state_from = slot, snap
+        return True
+
+    def _return_state(self, req: Request):
+        """The request's slot goes back (a new tenant starts from zeros
+        or from a snapshot, never from what is left there), and its
+        hold on a snapshot it never came to read."""
+        if req.state_slot is None:
+            return
+        if req.state_from is not None:
+            self.states.read(req.state_from)
+        self.states.give_back(req.state_slot)
+        req.state_slot = req.state_from = None
 
     def _activate(self, req: Request):
         """Prefill done, as far as the host's side goes: the request
@@ -547,6 +628,7 @@ class LLMEngine:
             self.kv_window.release(req.window_table, seq=seq,
                                    first=req.window_first, chain=req.chain)
             req.window_table, req.window_first = [], 0
+        self._return_state(req)
         self._drop_lane(req)
 
     @staticmethod
@@ -573,6 +655,9 @@ class LLMEngine:
         self._inputs_written += len(table)
         if self.kv_window is not None:
             self._write_window(req, 0)
+        if self.states is not None:
+            self._inputs[req.lane, self._cols.state_slot] = req.state_slot
+            self._inputs_written += 1
 
     def _write_window(self, req: Request, held: int):
         """The lane's window table and its first block, whole: the
@@ -763,6 +848,12 @@ class LLMEngine:
                     # program writes whole blocks); a budget below one
                     # block still makes one block of progress.
                     c = (c // bs) * bs or min(bs, rem)
+                # A sequence with a state ends a span at its last block
+                # boundary, where a snapshot is taken: a ragged prompt
+                # runs one short span more behind it.
+                snap_at = self._snapshot_at(req, T, upto)
+                if upto < snap_at < upto + c:
+                    c = snap_at - upto
                 if budget is not None:
                     budget -= c
                 pad = -c % bs
@@ -781,14 +872,22 @@ class LLMEngine:
                 if upto:
                     read[:len(req.block_table)] = req.block_table
                 b0 = upto // bs
+                # A sequence with a state: the slot its span starts from
+                # (a parked snapshot's behind a prefix hit, else its
+                # own) and its own, which the span's end state goes to.
+                state = () if self.states is None else (
+                    req.state_slot if req.state_from is None
+                    else req.state_from, req.state_slot)
                 table = pack_span(
                     read, req.block_table[b0:b0 + (c + pad) // bs],
-                    upto, c - 1)
+                    upto, c - 1, *state)
                 done = upto + c >= T
                 window = ()
                 if self.kv_window is not None:
                     window = (*self.kv_window.pools,
                               self._slide_window(req, upto, c, pad))
+                elif self.states is not None:
+                    window = self.states.pools
             # ONE program, which writes the chunk's K/V into the pools
             # it is donated. Its span stays open: the wait for it comes
             # when the step's programs are all queued.
@@ -843,6 +942,21 @@ class LLMEngine:
                         chunk.handed = True
                     # else its first token ends it by length: it never
                     # takes a lane, and finishes where it is settled.
+            if self.states is not None:
+                if req.state_from is not None:
+                    # The program that reads the parked snapshot is
+                    # queued: it is the lane's own state from here on.
+                    with perf.phase("llm.state_restore"):
+                        self.states.read(req.state_from)
+                        req.state_from = None
+                if upto + c == snap_at:
+                    # Behind the span that left the state there, before
+                    # the program that moves it on.
+                    with perf.phase("llm.state_snapshot"):
+                        self.states.snapshot(
+                            req.chain.reach(seq, snap_at)
+                            .keys[snap_at // bs - 1], snap_at,
+                            req.state_slot)
             if fetch_now:
                 self._settle()
 
@@ -914,11 +1028,23 @@ class LLMEngine:
 
     def _take_back(self, pools):
         """The pools a program was donated, as it returned them written:
-        the full kind's, then the window kind's."""
+        the full kind's, then the window kind's or the state's."""
         n = len(self.kv.pools)
         self.kv.pools = tuple(pools[:n])
         if self.kv_window is not None:
             self.kv_window.pools = tuple(pools[n:])
+        elif self.states is not None:
+            self.states.pools = tuple(pools[n:])
+
+    def _snapshot_at(self, req: Request, T: int, upto: int) -> int:
+        """Where in a sequence of ``T`` tokens, prefilled as far as
+        ``upto``, a span has to end for a snapshot of the state: its
+        last block boundary, if that is still ahead and the prefix
+        index could hand the snapshot on; else 0."""
+        if self.states is None or req.chain is None:
+            return 0
+        at = T // self.kv.block_size * self.kv.block_size
+        return at if at > upto else 0
 
     def _slide_window(self, req: Request, upto: int, c: int, pad: int):
         """The window kind's side of a chunk, on the host and BEFORE
@@ -1178,7 +1304,8 @@ class LLMEngine:
         # charged to the host, and the logits stay where they are unless
         # a lane samples with a temperature.
         with perf.dispatch("llm.decode.device") as span:
-            window = () if kvw is None else kvw.pools
+            window = kvw.pools if kvw is not None else \
+                self.states.pools if self.states is not None else ()
             logits, ids, *pools = self._decode(
                 self.params, self._inputs, *self.kv.pools, *window, q=Q,
                 firsts=firsts)
@@ -1330,6 +1457,10 @@ class LLMEngine:
                         self._kv_window_util_peak, self._window_live
                         / max(1, self.kv_window.capacity))
                     window["window_blocks_live"] = self._window_live
+                if self.states is not None:
+                    # The state pools' counters, as they stand at the
+                    # step's end (``StatePool.stats``).
+                    window.update(self.states.stats())
                 self._steps += 1
                 with perf.phase("llm.publish"):
                     self.step_log.append(
@@ -1396,16 +1527,17 @@ class LLMEngine:
 
     def _program_specs(self):
         """What both programs take, as shapes: (the parameters, the
-        window kind's pools, or none where the model has no such
-        kind)."""
+        window kind's pools or the state's, or none where the model has
+        neither)."""
         params = jax.tree_util.tree_map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=x.sharding),
             self.params)
-        if self.kv_window is None:
+        more = self.kv_window or self.states
+        if more is None:
             return params, ()
         return params, tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
-                             for p in self.kv_window.pools)
+                             for p in more.pools)
 
     def _paged_kernel_mode(self) -> str:
         """"compiled" if the decode program this engine steps with
@@ -1440,11 +1572,15 @@ class LLMEngine:
         mode = _KERNEL_MODES.get(key)
         if mode is None:
             params, window = self._program_specs()
-            if window:      # the kind's array: its table, first, one block
+            if self.kv_window is not None:
+                # the kind's array: its table, first, one block
                 window += (_i32(self._win_len + 2),)
+            # the table, one block written, ctx_len, last (and a state's
+            # two slots)
+            table = self.max_nb + 3 + 2 * (self.states is not None)
             traced = chunk.trace(
                 params, _i32(1, self.kv.block_size), *self._pool_specs,
-                _i32(self.max_nb + 3), *window)
+                _i32(table), *window)
             if "name=chunk_attn" not in str(traced.jaxpr):
                 mode = "xla"
             elif 'kernel_name = "chunk_attn"' in traced.lower().as_text():
@@ -1479,6 +1615,8 @@ class LLMEngine:
                 "kv_window_utilization": self.kv_window.utilization(),
                 "kv_window_util_peak": self._kv_window_util_peak,
                 "kv_window_blocks_slid": self.kv_window.slid_blocks}),
+            # What the model's sequences keep: the slots' counters.
+            **({} if self.states is None else self.states.stats()),
             "tokens_per_s": self.tokens_per_s(),
             "prefill_chunks": self._prefill_chunks,
             # Output tokens by where they were decided (see __init__).
@@ -1558,11 +1696,17 @@ class LLMEngine:
                           "Coded roofline verdict of the last step "
                           "(1=compute, 2=hbm, 3=host; 0=idle)",
                           tag_keys=keys),
+                    Gauge("rtpu_llm_state_slots_live",
+                          "State slots that lanes hold (0: the model's "
+                          "sequences keep no state)", tag_keys=keys),
+                    Gauge("rtpu_llm_state_snapshots_parked",
+                          "Parked state snapshots the prefix index can "
+                          "hand on", tag_keys=keys),
                 )
             tags = {"deployment": self.name}
             (tps, util, bsz, step_ms, dev_ms, gap_ms, mfu,
              hbm, hitr, shared, chunks, s_acc, s_tps,
-             verd) = self._gauges
+             verd, st_live, st_parked) = self._gauges
             # Shared idle-decay clock: a busy publish touches it; idle
             # ticks keep the last busy values until the window lapses,
             # then every step-derived series reads zero.
@@ -1573,6 +1717,11 @@ class LLMEngine:
             tps.set(self.tokens_per_s(), tags=tags)
             util.set(self.kv.utilization(), tags=tags)
             bsz.set(float(len(self._active)), tags=tags)
+            if self.states is not None:
+                st = self.states.stats()
+                st_live.set(float(st["state_slots_live"]), tags=tags)
+                st_parked.set(float(st["state_snapshots_parked"]),
+                              tags=tags)
             if live:
                 hitr.set(self.kv.hit_rate() if self._prefix else 0.0,
                          tags=tags)

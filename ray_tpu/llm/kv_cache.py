@@ -45,7 +45,11 @@ in the engine names a key or a value. A model whose
 layers all keep every token has one kind (``PagedKVCache`` /
 ``PrefixPool``); a kind with a window keeps only
 the blocks that cover a sequence's last ``window`` tokens in pools of
-its own (``WindowPool``), so a lane holds two block tables. (Head-major, ``[..., kv_heads, num_blocks, block_size, head_dim]``
+its own (``WindowPool``), so a lane holds two block tables. What a
+SEQUENCE keeps whatever its length (a state-space layer's state, the
+seam's ``Serving.state``) lives in pools of SLOTS beside them
+(``StatePool``): a slot a live lane, parked snapshots that the prefix
+index hands to later sequences. (Head-major, ``[..., kv_heads, num_blocks, block_size, head_dim]``
 with a 64-wide minor dimension, the runtime kept it in a compact layout
 that no reader or writer wanted, and every program converted the whole
 pool there and back: PERF.md section 6, PR 31.) Only models/gpt.py's
@@ -798,3 +802,191 @@ class WindowPool(PrefixPool):
         del table[nb:]
         self.free(surplus)
         return surplus
+
+
+@functools.lru_cache(maxsize=None)
+def _slot_copy_program(n: int):
+    def state_copy_slot(*args):
+        """A snapshot: one slot's state, every layer and part, copied to
+        another, ``(*pools, src, dst)`` (traced scalars: one compile).
+        ``jit_state_copy_slot`` on a device trace."""
+        src, dst = args[n:]
+        return tuple(pool.at[:, dst].set(pool[:, src]) for pool in args[:n])
+
+    return jax.jit(state_copy_slot, donate_argnums=tuple(range(n)))
+
+
+class StatePool:
+    """Slots of what a sequence keeps in the layers that carry a state
+    (``Serving.state``): one device pool a part, ``[layers, slots,
+    *part]``, and who holds which slot. Slot 0 is scratch, as block 0
+    is: padded decode lanes point at it and it is never handed out.
+
+    A slot is FREE, LIVE (a lane's: the decode program moves it in
+    place every step, a prefill span leaves its final state there) or
+    PARKED: a snapshot of a sequence's state AT a block boundary,
+    indexed under that block's chain key (``BlockChain``, the prefix
+    index's keys), which a later sequence with the same prefix can
+    start from. A snapshot is never written again; the sequence that
+    takes it up reads it in its first span and writes its own slot, so
+    any number can share one. ``match`` gives the prefix index its
+    second answer: of the tokens the paged pools still hold, the
+    longest prefix that ALSO has a snapshot; what was matched beyond it
+    is computed again.
+
+    Parked snapshots are evicted for a grant, those that no sequence
+    ever took up first, oldest first (``WindowPool._cold``'s order and
+    its reason: a finished prompt's snapshot is mostly never asked for
+    again, a shared prefix's is asked for by every request), then the
+    taken-up ones, least recently taken first. A snapshot that a
+    request has matched and not yet read is held (``hold``) and not
+    evicted. Eviction costs recomputation, never correctness."""
+
+    def __init__(self, cfg, state_slots: int):
+        kind = serving(cfg).state
+        if kind is None:
+            raise ValueError("the model's sequences keep no state")
+        if state_slots < 2:
+            raise ValueError("need >= 2 state slots (slot 0 is reserved)")
+        self.num_slots = int(state_slots)
+        self.pools = tuple(
+            jnp.zeros((len(kind.layers), state_slots, *shape), dtype)
+            for shape, dtype in kind.parts)
+        self._free: List[int] = list(range(state_slots - 1, 0, -1))
+        self._live: set = set()
+        self._index: Dict[int, int] = {}            # chain key -> slot
+        self._parked: Dict[int, Tuple[int, int]] = {}   # slot -> (key, n)
+        self._cold: "collections.OrderedDict[int, None]" = \
+            collections.OrderedDict()               # parked, never taken
+        self._warm: "collections.OrderedDict[int, None]" = \
+            collections.OrderedDict()               # parked, taken up
+        self._held: Dict[int, int] = {}             # slot -> readers to come
+        # A slot's index as a device scalar, made once: a snapshot's
+        # copy then hands no host value over (as the engine's lanes').
+        self._ids = list(jnp.arange(state_slots, dtype=jnp.int32))
+        self.snapshots = 0          # taken, all time
+        self.taken = 0              # times a snapshot was taken up
+        self.evicted = 0
+        self.resumed_tokens = 0     # matched tokens a snapshot let skip
+        self.recomputed_tokens = 0  # matched tokens computed again
+        self.live_peak = 0.0
+
+    @property
+    def capacity(self) -> int:
+        return self.num_slots - 1
+
+    def utilization(self) -> float:
+        """Share of the slots that lanes hold or that were taken up."""
+        return (len(self._live) + len(self._warm)) / max(1, self.capacity)
+
+    # -- slots -------------------------------------------------------------
+
+    def _evict_one(self) -> bool:
+        for parked in (self._cold, self._warm):
+            for slot in parked:
+                if slot not in self._held:
+                    del parked[slot]
+                    del self._index[self._parked.pop(slot)[0]]
+                    self._free.append(slot)
+                    self.evicted += 1
+                    return True
+        return False
+
+    def grant(self) -> Optional[int]:
+        """A slot for a lane, a parked snapshot evicted for it if need
+        be; None if every slot is live or held."""
+        if not self._free and not self._evict_one():
+            return None
+        slot = self._free.pop()
+        self._live.add(slot)
+        self.live_peak = max(self.live_peak, self.utilization())
+        return slot
+
+    def give_back(self, slot: int) -> None:
+        if slot not in self._live:
+            raise ValueError(f"state slot {slot} is not a lane's")
+        self._live.remove(slot)
+        self._free.append(slot)
+
+    # -- the prefix index's second answer ------------------------------------
+
+    def match(self, chain: Optional[BlockChain], cached: int,
+              limit: int) -> Tuple[int, Optional[int]]:
+        """The longest prefix, at most ``min(cached, limit)`` tokens and
+        whole blocks, at whose end a snapshot is parked: ``(tokens, its
+        slot)`` or ``(0, None)``. ``cached`` is what the paged pools
+        match of the sequence (its blocks were verified against the
+        chain there, and a snapshot's key is its last block's);
+        ``limit`` keeps at least one token for the sequence to
+        compute."""
+        if chain is None:
+            return 0, None
+        keys, index = chain.keys, self._index
+        for i in range(min(min(cached, limit) // chain.block_size,
+                           len(keys)), 0, -1):
+            slot = index.get(keys[i - 1])
+            if slot is not None and self._parked[slot][1] \
+                    == i * chain.block_size:
+                return i * chain.block_size, slot
+        return 0, None
+
+    def hold(self, slot: int) -> None:
+        """A sequence will start from this snapshot: it stays until the
+        sequence's first span has read it (``read``)."""
+        self._held[slot] = self._held.get(slot, 0) + 1
+
+    def read(self, slot: int) -> None:
+        """The program that reads the snapshot is dispatched (or its
+        sequence gave up): one hold less."""
+        left = self._held[slot] - 1
+        if left:
+            self._held[slot] = left
+        else:
+            del self._held[slot]
+
+    def take_up(self, slot: Optional[int], tokens: int, cached: int) -> None:
+        """Count an admission: ``tokens`` resumed from the snapshot in
+        ``slot`` (none: 0) of ``cached`` that the paged pools matched.
+        A snapshot taken up outlives those that never were."""
+        self.resumed_tokens += tokens
+        self.recomputed_tokens += max(cached - tokens, 0)
+        if slot is None:
+            return
+        self._cold.pop(slot, None)
+        self._warm[slot] = None
+        self._warm.move_to_end(slot)
+        self.taken += 1
+        self.live_peak = max(self.live_peak, self.utilization())
+
+    def snapshot(self, key: int, tokens: int, src: int) -> Optional[int]:
+        """Park a copy of the live slot ``src``, the state of a sequence
+        at ``tokens`` tokens whose last block has chain key ``key``:
+        one device copy, dispatched here behind the program that left
+        the state there. Returns the snapshot's slot, or None where one
+        is indexed already or no slot can be had."""
+        if key in self._index or (not self._free and not self._evict_one()):
+            return None
+        dst = self._free.pop()
+        self.pools = _slot_copy_program(len(self.pools))(
+            *self.pools, self._ids[src], self._ids[dst])
+        self._index[key] = dst
+        self._parked[dst] = (key, tokens)
+        self._cold[dst] = None
+        self.snapshots += 1
+        return dst
+
+    # -- introspection -------------------------------------------------------
+
+    def stats(self) -> dict:
+        return {
+            "state_slots": self.capacity,
+            "state_slots_live": len(self._live),
+            "state_snapshots_parked": len(self._parked),
+            "state_snapshots_taken_up": len(self._warm),
+            "state_snapshots": self.snapshots,
+            "state_taken": self.taken,
+            "state_evicted": self.evicted,
+            "state_resumed_tokens": self.resumed_tokens,
+            "state_recomputed_tokens": self.recomputed_tokens,
+            "state_live_peak": self.live_peak,
+        }

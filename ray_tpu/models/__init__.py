@@ -1,8 +1,10 @@
 """Model families: the GPT decoder (models/gpt.py, trained and served),
 Laguna (models/laguna.py, served: window and full attention layers,
-routed experts) and Kimi-K2 (models/kimi_k2.py, served: latent
+routed experts), Kimi-K2 (models/kimi_k2.py, served: latent
 attention over one pool of latent rows, a share of sigmoid-routed
-experts).
+experts) and Nemotron-H (models/nemotron_h.py, served: state-space
+layers that keep a fixed state a SEQUENCE beside one attention layer's
+keys and values, a share of not-gated experts in a latent space).
 
 Models are pure-JAX functional: ``init(key, cfg)`` returns the param pytree;
 ``param_axes(cfg)`` returns the matching pytree of logical-axis annotations
@@ -50,6 +52,25 @@ for what the configuration's own module says of it, a ``Serving``:
             too, and a chunk takes its int32 array ``win`` after them
             (models/laguna.py: the table, its first block, the blocks
             the span is written to).
+  state     what a SEQUENCE keeps, whatever its length, or None (the
+            three attention families): a ``StateKind``, which names the
+            layers that carry a state and the parts of one layer's
+            (shape and dtype: a state-space layer's recurrent state and
+            the last rows of its convolution's input). The cache
+            manager holds them in pools of SLOTS, ``[layers, slots,
+            *part]`` (llm/kv_cache.py ``StatePool``): a live lane has a
+            slot, the prefix index parks snapshots in others. The
+            pools ride behind every kind's in both programs (donated,
+            handed back written); the step's packed array has a column
+            for each lane's slot (``step_columns(..., state=True)``,
+            ``step_state_slots``), slot 0 being scratch as block 0 is;
+            a chunk's table ends in two slots (``pack_span``'s
+            ``extra``): the one the span's initial state is read from
+            (a parked snapshot's, for the first span behind a prefix
+            hit) and the lane's own, which its final state is written
+            to. A span from position 0 starts from zeros whatever the
+            slot holds, so a slot's next tenant never sees the last
+            one's state
   cost      the cost description util/perfmodel.py prices steps from
   counters  names of the int32 counters the step program appends to its
             ``ids`` as rows ``[max_batch + i]``: they ride in the one
@@ -97,6 +118,21 @@ def keys_and_values(name: str, layers, kv_heads: int, head_dim: int,
 
 
 @dataclass(frozen=True)
+class StateKind:
+    """What a sequence keeps in the layers that carry a state, as the
+    cache manager sees it: one pool a part, ``[layers, slots, *shape]``."""
+    layers: Tuple[int, ...]     # the model's layers with a state, in order
+    parts: Tuple[Tuple[Tuple[int, ...], Any], ...]  # (shape, dtype) a part
+
+    @property
+    def slot_bytes(self) -> int:
+        """Bytes of one slot: every part of every layer."""
+        return len(self.layers) * sum(
+            int(np.prod(shape)) * np.dtype(dtype).itemsize
+            for shape, dtype in self.parts)
+
+
+@dataclass(frozen=True)
 class Serving:
     init: Callable
     step: Callable
@@ -106,26 +142,32 @@ class Serving:
     max_seq: int
     vocab_size: int
     counters: Tuple[str, ...] = ()
+    state: Optional[StateKind] = None
 
 
-def pack_span(block_table, dest, ctx_len: int, last: int):
+def pack_span(block_table, dest, ctx_len: int, last: int, *extra: int):
     """A chunk's bookkeeping as the ONE int32 array ``Serving.chunk``
     takes: ``[block table (nb, 0-padded; nb = 0 for a span from the
     prompt's start) | destination blocks (one a block of the span) |
-    ctx_len | last]``. Each host array handed to a program is a
+    ctx_len | last]``, and behind them what else the model's chunk
+    takes by value (``extra``: a model with a state, the slot it reads
+    and the slot it writes). Each host array handed to a program is a
     hand-over of the interpreter lock beside the serving threads
-    (PERF.md section 6, PR 30), so the four ride in one."""
-    return np.concatenate([block_table, dest, (ctx_len, last)],
+    (PERF.md section 6, PR 30), so they all ride in one."""
+    return np.concatenate([block_table, dest, (ctx_len, last, *extra)],
                           dtype=np.int32)
 
 
-def unpack_span(table, n: int, block_size: int):
+def unpack_span(table, n: int, block_size: int, extra: int = 0):
     """``pack_span``'s array, inside the program, back into its four
-    parts. ``n`` is the span's padded length, so the block table's
+    parts (and its ``extra`` trailing values, where the model's chunk
+    has any). ``n`` is the span's padded length, so the block table's
     length follows from the array's own: a shape, not a value."""
     nd = n // block_size
-    nb = table.shape[0] - nd - 2
-    return table[:nb], table[nb:nb + nd], table[-2], table[-1]
+    nb = table.shape[0] - nd - 2 - extra
+    four = (table[:nb], table[nb:nb + nd], table[-2 - extra],
+            table[-1 - extra])
+    return four + tuple(table[-extra:]) if extra else four
 
 
 class StepColumns(NamedTuple):
@@ -140,38 +182,45 @@ class StepColumns(NamedTuple):
     context_len: int
     q_len: int
     head: int           # the end of what a step writes
+    state_slot: int     # the lane's state slot (one column, or none)
     win_first: int      # the window table's first block in the sequence
     win_table: int
     table: int          # the full kind's block table, to the row's end
 
 
 @functools.lru_cache(maxsize=None)
-def step_columns(q: int, win_len: int = 0) -> StepColumns:
+def step_columns(q: int, win_len: int = 0, state: bool = False
+                 ) -> StepColumns:
     """The packed step array's layout, from shapes alone: ``[tokens |
     positions | slot blocks | slot offsets (q each) | window slot
-    blocks (q) | context_len | q_len | window first | window table
-    (win_len) | block table]``, the window kind's three parts only
-    where the model has that kind. A padded lane, and a row past a
+    blocks (q) | context_len | q_len | state slot | window first |
+    window table (win_len) | block table]``, the window kind's three
+    parts only where the model has that kind and the state slot only
+    where its sequences keep a state. A padded lane, and a row past a
     lane's ``q_len``, is all zeros (scratch block 0, offset 0,
-    position 0) but for ``context_len`` 1 and ``q_len`` 1."""
+    position 0, scratch slot 0) but for ``context_len`` 1 and ``q_len``
+    1."""
     ctx = (5 if win_len else 4) * q
     win = win_len + 1 if win_len else 0
+    head = ctx + 2
+    tail = head + bool(state)
     return StepColumns(tokens=0, positions=q,
                        slot_blocks=2 * q, slot_offsets=3 * q,
                        win_slots=4 * q, context_len=ctx, q_len=ctx + 1,
-                       head=ctx + 2, win_first=ctx + 2,
-                       win_table=ctx + 2 + bool(win_len),
-                       table=ctx + 2 + win)
+                       head=head, state_slot=head, win_first=tail,
+                       win_table=tail + bool(win_len),
+                       table=tail + win)
 
 
 def pack_step(tokens, positions, block_tables, context_lens, q_lens,
-              slot_blocks, slot_offsets, win=None):
+              slot_blocks, slot_offsets, win=None, state_slots=None):
     """A decode step's bookkeeping, built from its parts, as the ONE
     int32 array ``Serving.step`` takes (``step_columns``): ``tokens`` /
     ``positions`` / ``slot_blocks`` / ``slot_offsets`` ``[b, q]``,
     ``block_tables`` ``[b, max_nb]``, ``context_lens`` / ``q_lens``
     ``[b]``, and the window kind's ``win`` ``[b, win_len + 1 + q]``
-    (``[table | first block | slot block a row]``). The engine never
+    (``[table | first block | slot block a row]``); ``state_slots``
+    ``[b]`` where the model's sequences keep a state. The engine never
     calls this in a step: it keeps its array and writes what changed
     (llm/engine.py); tests and tools build one from scratch here."""
     q = np.shape(tokens)[1]
@@ -183,12 +232,21 @@ def pack_step(tokens, positions, block_tables, context_lens, q_lens,
         n = win.shape[1] - 1 - q
         parts.append(win[:, n + 1:])
         tail = [win[:, n:n + 1], win[:, :n]]
+    if state_slots is not None:
+        tail.insert(0, col(state_slots))
     return np.concatenate(
         [*parts, col(context_lens), col(q_lens), *tail, block_tables],
         axis=1, dtype=np.int32)
 
 
-def unpack_step(packed, q: int, win_len: int = 0, firsts=None):
+def step_state_slots(packed, q: int, win_len: int = 0):
+    """Each lane's state slot ``[b]``, from a packed array laid out
+    with ``step_columns(q, win_len, state=True)``."""
+    return packed[:, step_columns(q, win_len, True).state_slot]
+
+
+def unpack_step(packed, q: int, win_len: int = 0, firsts=None,
+                state: bool = False):
     """``step_columns``' array, inside the program, back into its
     parts by static slices: ``(tokens, positions, block_tables,
     context_lens, q_lens, slot_blocks, slot_offsets, window)``, where
@@ -196,8 +254,9 @@ def unpack_step(packed, q: int, win_len: int = 0, firsts=None):
     with a window and None without one. ``firsts`` (``Serving.step``)
     takes the place of a lane's row-0 token where it is not negative:
     the one value of a new lane that the host does not hold when it
-    queues the step behind the lane's last prefill chunk."""
-    c = step_columns(q, win_len)
+    queues the step behind the lane's last prefill chunk. ``state``
+    says the array has a state-slot column (``step_state_slots``)."""
+    c = step_columns(q, win_len, state)
     if firsts is not None:
         import jax.numpy as jnp
 
@@ -219,4 +278,4 @@ def serving(cfg) -> Serving:
     return importlib.import_module(type(cfg).__module__).serving(cfg)
 
 
-from . import gpt, kimi_k2, laguna, resnet  # noqa: E402,F401
+from . import gpt, kimi_k2, laguna, nemotron_h, resnet  # noqa: E402,F401

@@ -29,7 +29,9 @@ dropped):
 
 Experts are SwiGLU: ``w1`` holds gate and up side by side,
 ``[E, d_model, 2 * d_ff]``, and ``w2`` is ``[E, d_ff, d_model]``; the
-router's weight multiplies an expert's OUTPUT.
+router's weight multiplies an expert's OUTPUT. The other expert served
+is NOT gated (``routed_experts(..., activation="relu2")``):
+``relu(x w1)^2 w2`` with ``w1`` ``[E, d_model, d_ff]``.
 
 Expert parallelism (``ep_axis``, inside shard_map): a shard holds
 ``E / ep`` of the experts, routes over all E, and computes the part of
@@ -38,7 +40,9 @@ the result its own experts give for every token of its ep group
 back): the layer is told which experts it holds, and what the others
 add is the other shards'. models/laguna.py serves this layer on one
 chip with every expert local; models/kimi_k2.py serves one chip's
-share of it (``routed_experts(..., first=)`` with 12 of 384 experts).
+share of it (``routed_experts(..., first=)`` with 12 of 384 experts),
+as models/nemotron_h.py does with not-gated experts in a latent space
+(64 of 512, 22 a token).
 """
 
 from __future__ import annotations
@@ -251,21 +255,27 @@ grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def routed_experts(x, experts, weights, w1, w2, *, first=0,
-                   name: str = "moe_experts"):
+                   name: str = "moe_experts", activation: str = "swiglu"):
     """The grouped product and the combine: ``sum_j weights[t, j] *
     Expert_{experts[t, j]}(x[t])`` over the experts held here (``w1``
     [E, d, 2f], ``w2`` [E, f, d], global ids from ``first``), every
     assignment computed; ``name`` is the grouped product's kernel on a
-    device trace. Returns (y [T, d] in x's dtype, sizes [E]: the tokens
-    each held expert got)."""
+    device trace. ``activation`` "relu2" is the expert that is not
+    gated, ``relu(x w1)^2 w2`` with ``w1`` [E, d, f]. Returns (y [T, d]
+    in x's dtype, sizes [E]: the tokens each held expert got)."""
     T, k = experts.shape
     E, _, f2 = w1.shape
     sizes, dest, src, tile_expert, n_used = dispatch_plan(
         experts, E, first)
     rows = x[src]                                    # [M, d], by expert
     gu = grouped_matmul(rows, w1, tile_expert, n_used, name)
-    act = (jax.nn.silu(gu[:, :f2 // 2].astype(jnp.float32))
-           * gu[:, f2 // 2:].astype(jnp.float32)).astype(x.dtype)
+    if activation == "relu2":
+        act = jnp.square(jax.nn.relu(gu.astype(jnp.float32))).astype(x.dtype)
+    elif activation == "swiglu":
+        act = (jax.nn.silu(gu[:, :f2 // 2].astype(jnp.float32))
+               * gu[:, f2 // 2:].astype(jnp.float32)).astype(x.dtype)
+    else:
+        raise ValueError(f"unknown expert activation {activation!r}")
     out = grouped_matmul(act, w2, tile_expert, n_used, name)
     M = rows.shape[0]
     held = (dest < M).reshape(T, k)

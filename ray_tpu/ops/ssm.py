@@ -1,0 +1,257 @@
+"""The Mamba-2 state-space mixer's recurrence in its two served forms.
+
+A head ``h`` of ``H`` keeps a state ``S`` [P, N] (``P`` the head's
+width, ``N`` the state size) and a token moves it by
+
+  S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T,    y_t = S_t C_t
+
+with ``A`` a negative scalar a head, ``dt_t`` a positive scalar a head a
+token, ``x_t`` [P], and ``B_t``, ``C_t`` [N] shared by the ``H / G``
+heads of a group. (The ``D x`` skip, the gate and the convolution in
+front are the model's, models/nemotron_h.py.) Everything here is
+float32: the state is what a sequence carries for thousands of tokens.
+
+  ssd_scan     a span of a prompt: the chunked form (state-space
+               duality, Dao & Gu 2024): blocks of ``chunk`` tokens, a
+               block's own tokens against each other as one masked
+               product, the state handed from block to block; an
+               initial state comes in and the final one goes out. Rows
+               with ``dt`` = 0 leave the state as it is, which is how a
+               span is padded. A Pallas kernel (``ssm_scan`` on a
+               device trace): a grid step is one group of heads in one
+               block, the blocks of a group in order with the group's
+               state resident in VMEM between them, so a span's state
+               is read once and written once.
+  ssm_update   one token a lane of a decode batch, IN PLACE in the pool
+               of state slots ``[layers, slots, H, P, N]``: a Pallas
+               kernel (``ssm_update`` on a device trace) that takes each
+               lane's slot from a scalar-prefetched table, reads the
+               slot's state block by block, writes it back where it was
+               (``input_output_aliases``) and hands back ``y``. A step
+               moves each live state once in and once out and nothing
+               else of the pool. On the CPU the Pallas interpreter runs
+               the same kernel (tests).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+HIGHEST = jax.lax.Precision.HIGHEST
+F32 = jnp.float32
+# Lanes of the kernel's column block: a head's ``dt x`` column sits at
+# lane j, its decay at lane HALF + j.
+LANES, HALF = 128, 64
+# The two kernels' names on a device trace (the benchmark's readers find
+# them by these).
+SCAN_KERNEL, UPDATE_KERNEL = "ssm_scan", "ssm_update"
+
+
+def _blocks(x, dt, A, B, C, chunk: int):
+    """The scan's inputs in blocks of ``chunk`` rows, float32: (x dt
+    [c, l, G, J, P], the running sum of dt A inside each block [c, l,
+    G, J], B and C [c, l, G, N]); the tail padded with ``dt`` = 0."""
+    n, H, P = x.shape
+    G, N = B.shape[1:]
+    pad = -n % chunk
+    if pad:
+        x, dt, B, C = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                       for a in (x, dt, B, C))
+    c, l, J = (n + pad) // chunk, chunk, H // G
+    x, dt, B, C = (a.astype(F32) for a in (x, dt, B, C))
+    cum = jnp.cumsum((dt * A.astype(F32)).reshape(c, l, G, J), axis=1)
+    return ((x * dt[..., None]).reshape(c, l, G, J, P), cum,
+            B.reshape(c, l, G, N), C.reshape(c, l, G, N))
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=HIGHEST,
+                               preferred_element_type=F32)
+
+
+def _scan_kernel(xdt_ref, cum_ref, b_ref, c_ref, s0_ref, y_ref, s_ref, *,
+                 heads: int):
+    """One group's ``heads`` heads in one block of l rows. ``s_ref`` is
+    the group's state, resident across the group's blocks: the initial
+    state at the first, the final one when the last has run."""
+    @pl.when(pl.program_id(1) == 0)
+    def _first():
+        s_ref[...] = s0_ref[...]
+
+    Bm, Cm = b_ref[...], c_ref[...]                     # [l, N]
+    l, P = Bm.shape[0], xdt_ref.shape[-1]
+    scores = _dot(Cm, Bm, ((1,), (1,)))                 # [t, s]
+    t = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
+    for j in range(heads):
+        at_s = jnp.broadcast_to(cum_ref[j:j + 1, :], (l, l))   # cum[s]
+        at_t = at_s.T                                          # cum[t]
+        # Row t takes from row s <= t what s put in, decayed over (s, t].
+        decay = jnp.exp(jnp.where(t >= s, at_t - at_s, -jnp.inf))
+        xd, S = xdt_ref[j], s_ref[j]                    # [l, P], [P, N]
+        rows = at_t[:, :P]                              # cum[t], P wide
+        y_ref[j] = (_dot(scores * decay, xd, ((1,), (0,)))
+                    + jnp.exp(rows) * _dot(Cm, S, ((1,), (1,))))
+        # The block's whole sum, as a row beside ``rows`` and as a
+        # column beside the state (a 1 x 1 value broadcasts neither way).
+        end = at_t[l - 1:l, :P]
+        s_ref[j] = (jnp.exp(at_s[:P, l - 1:l]) * S
+                    + _dot(xd * jnp.exp(end - rows), Bm, ((0,), (0,))))
+
+
+@functools.lru_cache(maxsize=None)
+def _make_scan(c: int, l: int, G: int, J: int, P: int, N: int,
+               interpret: bool):
+    by_block = lambda *shape: pl.BlockSpec(
+        (None, None, *shape), lambda g, i: (g, i) + (0,) * len(shape))
+    state = pl.BlockSpec((None, J, P, N), lambda g, i: (g, 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_scan_kernel, heads=J),
+        grid=(G, c),
+        in_specs=[by_block(J, l, P), by_block(J, l), by_block(l, N),
+                  by_block(l, N), state],
+        out_specs=[by_block(J, l, P), state],
+        out_shape=[jax.ShapeDtypeStruct((G, c, J, l, P), F32),
+                   jax.ShapeDtypeStruct((G, J, P, N), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name=SCAN_KERNEL)
+
+
+def ssd_scan(x, dt, A, B, C, S0, chunk: int):
+    """x [n, H, P], dt [n, H] (after softplus; 0 on padding rows), A
+    [H], B and C [n, G, N], S0 [H, P, N] -> (y [n, H, P], S [H, P, N]
+    after the last row), all float32. ``n`` need not be a multiple of
+    ``chunk``: the tail is padded with ``dt`` = 0. The kernel wants a
+    head no wider than a block is long (``P <= chunk``)."""
+    n, H, P = x.shape
+    G, N = B.shape[1:]
+    if P > chunk:
+        raise ValueError(f"head width {P} over the block length {chunk}")
+    xdt, cum, B, C = _blocks(x, dt, A, B, C, chunk)
+    c, l, J = xdt.shape[0], chunk, H // G
+    call = _make_scan(c, l, G, J, P, N, jax.default_backend() == "cpu")
+    y, S = call(xdt.transpose(2, 0, 3, 1, 4),           # [G, c, J, l, P]
+                cum.transpose(2, 0, 3, 1),              # [G, c, J, l]
+                B.transpose(2, 0, 1, 3), C.transpose(2, 0, 1, 3),
+                S0.astype(F32).reshape(G, J, P, N))
+    return (y.transpose(1, 3, 0, 2, 4).reshape(c * l, H, P)[:n],
+            S.reshape(H, P, N))
+
+
+def ssm_recurrence(x, dt, A, B, C, S0):
+    """``ssd_scan``'s contract as the token recurrence itself, a
+    ``lax.scan`` over rows: the kernel's ground truth (tests)."""
+    H, G = x.shape[1], B.shape[1]
+    heads = lambda a: jnp.repeat(a.astype(F32), H // G, axis=0)  # [G,N]->[H,N]
+
+    def token(S, row):
+        x_t, dt_t, B_t, C_t = row
+        S = (jnp.exp(dt_t * A)[:, None, None] * S
+             + (dt_t[:, None] * x_t)[..., None] * heads(B_t)[:, None, :])
+        return S, (S * heads(C_t)[:, None, :]).sum(-1)
+
+    S, y = jax.lax.scan(token, S0.astype(F32),
+                        (x.astype(F32), dt.astype(F32), B, C))
+    return y, S
+
+
+# ---------------------------------------------------------------------------
+# The decode step's update
+# ---------------------------------------------------------------------------
+
+
+def _head_block(H: int, G: int) -> int:
+    """Heads a grid step updates: as many as the column block has lanes
+    for (``HALF``), in whole groups."""
+    hb = min(H, HALF)
+    if H % hb or hb % (H // G):
+        raise ValueError(f"{H} heads in {G} groups do not cut into blocks "
+                         f"of {hb}")
+    return hb
+
+
+def _update_kernel(slots_ref, cols_ref, b_ref, c_ref, s_ref, y_ref, o_ref,
+                   *, hb: int, hg: int):
+    """One lane's ``hb`` heads: ``S <- decay S + (dt x) B^T``, ``y = S
+    C``. The columns block [P, 128] has head j's ``dt x`` [P] at lane j
+    and its decay (the same value down the column) at lane HALF + j, so
+    a head's two columns broadcast along the state's lanes as they
+    are."""
+    del slots_ref
+    cols = cols_ref[...]
+    for j in range(hb):
+        g = j // hg
+        new = (cols[:, HALF + j:HALF + j + 1] * s_ref[j]
+               + cols[:, j:j + 1] * b_ref[g:g + 1, :])
+        o_ref[j] = new
+        y_ref[:, j:j + 1] = jnp.sum(new * c_ref[g:g + 1, :], axis=1,
+                                    keepdims=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_update(b: int, L: int, slots: int, H: int, P: int, N: int,
+                 G: int, layer: int, interpret: bool):
+    hb = _head_block(H, G)
+    hg, nk = H // G, H // hb
+    small = lambda rows, width: pl.BlockSpec(
+        (None, None, rows, width), lambda i, k, slots_ref: (i, k, 0, 0))
+    cols, group_rows = small(P, LANES), small(hb // hg, N)
+    state = pl.BlockSpec((None, None, hb, P, N),
+                         lambda i, k, slots_ref: (layer, slots_ref[i], k,
+                                                  0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b, nk),
+        in_specs=[cols, group_rows, group_rows, state],
+        out_specs=[cols, state])
+    return pl.pallas_call(
+        functools.partial(_update_kernel, hb=hb, hg=hg),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, nk, P, LANES), F32),
+                   jax.ShapeDtypeStruct((L, slots, H, P, N), F32)],
+        # (slots, cols, B, C, pool) -> (y, pool): the pool in place.
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret, name=UPDATE_KERNEL)
+
+
+def ssm_update(pool, layer: int, slots, decay, dtx, B, C):
+    """One token a lane. ``pool`` [L, slots, H, P, N] float32, donated
+    by the caller's program; ``slots`` [b] int32, a lane's slot (0 for
+    a padded lane: scratch); ``decay`` [b, H] = exp(dt A); ``dtx`` [b,
+    H, P] = dt x; ``B``, ``C`` [b, G, N]. Returns (y [b, H, P] float32,
+    the pool with the lanes' slots of ``layer`` updated)."""
+    L, n_slots, H, P, N = pool.shape
+    b, G = B.shape[:2]
+    hb = _head_block(H, G)
+    nk = H // hb
+    # Head j of block k: its dt x down lane j, its decay down lane
+    # HALF + j.
+    cols = jnp.zeros((b, nk, P, LANES), F32)
+    cols = cols.at[..., :hb].set(
+        dtx.astype(F32).reshape(b, nk, hb, P).transpose(0, 1, 3, 2))
+    cols = cols.at[..., HALF:HALF + hb].set(jnp.broadcast_to(
+        decay.astype(F32).reshape(b, nk, 1, hb), (b, nk, P, hb)))
+    by_block = lambda a: a.astype(F32).reshape(b, nk, G // nk, N)
+    call = _make_update(b, L, n_slots, H, P, N, G, int(layer),
+                        jax.default_backend() == "cpu")
+    y, pool = call(slots.astype(jnp.int32), cols, by_block(B), by_block(C),
+                   pool)
+    return y[..., :hb].transpose(0, 1, 3, 2).reshape(b, H, P), pool
+
+
+def ssm_update_reference(pool, layer: int, slots, decay, dtx, B, C):
+    """``ssm_update`` as a gather, the recurrence and a scatter in
+    plain jnp (tests)."""
+    H, G = pool.shape[2], B.shape[1]
+    heads = lambda a: jnp.repeat(a.astype(F32), H // G, axis=1)
+    S = (decay[..., None, None] * pool[layer, slots]
+         + dtx[..., None] * heads(B)[:, :, None, :])
+    return ((S * heads(C)[:, :, None, :]).sum(-1),
+            pool.at[layer, slots].set(S))

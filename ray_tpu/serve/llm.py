@@ -102,6 +102,7 @@ class _LLMServer:
     def __init__(self, cfg=None, params=None, *, seed: int = 0,
                  num_blocks: int = 64,
                  window_blocks: Optional[int] = None,
+                 state_slots: Optional[int] = None,
                  block_size: int = 16,
                  max_batch: int = 8, default_max_tokens: int = 32,
                  prefill_chunk_tokens: Optional[int] = 32,
@@ -144,9 +145,12 @@ class _LLMServer:
         # bit-identical either way, so it is purely a throughput knob.
         # ``window_blocks`` sizes the second pool of a model that has a
         # kind of layer with a window (None: twice what max_batch lanes
-        # hold); a model without one has no such pool.
+        # hold); a model without one has no such pool. ``state_slots``
+        # sizes the slots of a model whose sequences keep a state (a
+        # lane's each and parked snapshots; None: twice max_batch).
         self.engine = LLMEngine(params, cfg, num_blocks=num_blocks,
                                 window_blocks=window_blocks,
+                                state_slots=state_slots,
                                 block_size=block_size,
                                 max_batch=max_batch,
                                 prefill_chunk_tokens=prefill_chunk_tokens,

@@ -114,7 +114,10 @@ def _shape(cfg) -> dict:
     keys and values; 0 where the cache holds keys), for layers with a
     window ``attn_windows`` ((coefficient, window) pairs), parameters in
     all, parameters a step of n rows streams, bytes a parameter, cache
-    elements a token."""
+    elements a token; and, only where the model's sequences keep a
+    state, ``state_bytes_per_seq`` (a slot's bytes, moved in and out a
+    lane a step), ``state_ops_per_row`` (a decode row's state update)
+    and ``scan_ops_per_row`` (a chunk's row in the chunked scan)."""
     from ..models import serving
 
     return serving(cfg).cost
@@ -186,7 +189,10 @@ def decode_step_cost(cfg, context_lens: Sequence[int],
     for ctx, q in zip(context_lens, q_lens):
         attn += _attn_flops(s, q, ctx)
         total_ctx += ctx
-    flops = 2.0 * s["matmul_weights"] * n_rows + attn
+    # A layer that keeps a state a sequence costs a row the same at any
+    # context and moves the lane's whole state in and out.
+    flops = (2.0 * s["matmul_weights"] * n_rows + attn
+             + s.get("state_ops_per_row", 0.0) * n_rows)
     kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
     if param_bytes is None:
         param_bytes = s["param_bytes"]
@@ -194,7 +200,8 @@ def decode_step_cost(cfg, context_lens: Sequence[int],
     # routed layer the experts the step's rows are expected to hit.
     hbm = (s["streamed_params"](n_rows) * param_bytes
            + total_ctx * kvb                 # context KV read per lane
-           + n_rows * kvb)                   # KV write per scored row
+           + n_rows * kvb                    # KV write per scored row
+           + 2.0 * len(context_lens) * s.get("state_bytes_per_seq", 0))
     return StepCost(flops, hbm, int(n_rows))
 
 
@@ -218,11 +225,14 @@ def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
     flops = (2.0 * (s["matmul_weights"] - s["head_weights"]) * T
              + 2.0 * s["head_weights"] + s["chunk_attn_per_ctx"] * seen
              + s["chunk_ctx_ops"] * ctx
-             + sum(coef * min(seen, T * w) for coef, w in s["attn_windows"]))
+             + sum(coef * min(seen, T * w) for coef, w in s["attn_windows"])
+             + s.get("scan_ops_per_row", 0.0) * T)
     kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
     if param_bytes is None:
         param_bytes = s["param_bytes"]
-    hbm = s["streamed_params"](T) * param_bytes + (2.0 * T + ctx) * kvb
+    # A span reads the sequence's state once and writes it once.
+    hbm = (s["streamed_params"](T) * param_bytes + (2.0 * T + ctx) * kvb
+           + 2.0 * s.get("state_bytes_per_seq", 0))
     return StepCost(flops, hbm, T)
 
 
@@ -308,6 +318,13 @@ PHASES: Dict[str, tuple] = {
                                       "dispatch, and the host's wait for "
                                       "its result once the step's "
                                       "programs are queued"),
+    "llm.state_restore": (None, "a parked state snapshot handed to the "
+                                "first span of the sequence that took "
+                                "it up"),
+    "llm.state_snapshot": (None, "a sequence's state parked at its last "
+                                 "block boundary: the prefix index's "
+                                 "entry and the dispatch of the slot's "
+                                 "copy"),
     "llm.slots": (None, "writable KV slots for every decode lane: block "
                         "grants, copy-on-write, preemption"),
     "llm.decode.build": (None, "the decode (or verify) program's input "

@@ -145,15 +145,19 @@ def test_manifest_lists_the_cell_where_the_issue_says():
     assert config["file"] == f"benchmark/configs/{CONFIG}.json"
     e2e = {x["name"] for x in m["end_to_end"]
            if CELL in x.get("workloads", [CELL])}
-    assert e2e == {"serve_tokens_per_s", "itl_p99_ms", "setup_s"}
+    # Not itl_p99_ms since PR 54's check (too unsteady for any bound
+    # here): the two latent readers move serve_tokens_per_s.
+    assert e2e == {"serve_tokens_per_s", "setup_s"}
     by_name = {x["name"]: x for x in m["per_layer"]}
-    mine = {"attn_latent_ms": "itl_p99_ms",
-            "attn_latent_roofline_pct": "itl_p99_ms",
-            "latent_chunk_ms": "serve_tokens_per_s",
-            "moe_held_rows": "serve_tokens_per_s"}
-    for name, moves in mine.items():
+    mine = ("attn_latent_ms", "attn_latent_roofline_pct", "latent_chunk_ms",
+            "moe_held_rows")
+    for name in mine:
+        assert by_name[name]["moves"] == "serve_tokens_per_s", name
+        assert by_name[name]["workloads"][0] == CELL, name
+    # A shared reader holds the cell; what counts this configuration's
+    # own fields is read in its cell alone.
+    for name in mine[:3]:
         assert by_name[name]["workloads"] == [CELL], name
-        assert by_name[name]["moves"] == moves, name
     for name in ("batch_occupancy_pct", "itl_p95_ms", "engine_host_gap_ms",
                  "kv_live_peak_pct", "decode_step_ms", "decode_device_ms",
                  "device_idle_pct.serve", "engine_schedule_ms",

@@ -330,7 +330,9 @@ def test_manifest_lists_the_cell_where_the_issue_says():
     # Not ttft_p50_ms: its median spread 8.6-14.0% over six seeds here,
     # where a new cell may show 5% (PERF.md section 7), so the cell is
     # left off it and off the per-layer metrics that move it.
-    assert e2e == {"serve_tokens_per_s", "itl_p99_ms", "setup_s"}
+    # Nor itl_p99_ms: PR 54's check found it too unsteady for any bound
+    # here; it is read per layer as itl_p99_long_ms.
+    assert e2e == {"serve_tokens_per_s", "setup_s"}
     for x in m["per_layer"]:
         if x["moves"] == "ttft_p50_ms":
             assert CELL not in x["workloads"], x["name"]
@@ -341,9 +343,12 @@ def test_manifest_lists_the_cell_where_the_issue_says():
         assert CELL in by_name[name]["workloads"], name
     # What only this configuration's programs write is read in its cell
     # alone: the readers count 256 experts all held, layers by kind.
-    for name in ("moe_roofline_pct", "attn_full_ms", "attn_window_ms",
+    for name in ("moe_roofline_pct", "attn_window_ms",
                  "kv_window_live_pct", "attn_full_roofline_pct"):
         assert by_name[name]["workloads"] == [CELL], name
+    # Found by the kernel's name alone, so another configuration whose
+    # paged call carries it may list its cell too.
+    assert CELL in by_name["attn_full_ms"]["workloads"]
     for x in m["per_layer"]:
         if x["name"] in ("paged_kernel_ms", "paged_roofline_pct"):
             assert CELL not in x["workloads"]

@@ -149,8 +149,8 @@ def test_whole_decode_step_compiles_with_the_kernel(one_chip, as_tpu):
 ], ids=["fused_backward", "split_backward"])
 def test_flash_kernels_carry_their_names(one_chip, seq, names):
     """A device trace finds a kernel by the name on its pallas_call
-    (benchmark/kernels.py still goes by target and operand): lowered
-    for the TPU, forward and both forms of the backward."""
+    (benchmark/named_kernels.py goes by it since PR 54): lowered for
+    the TPU, forward and both forms of the backward."""
     q = jax.ShapeDtypeStruct((2, 12, seq, 64), jnp.bfloat16,
                              sharding=one_chip)
     attn = functools.partial(flash_attention_pallas, causal=True,
@@ -187,10 +187,9 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
     cell's shapes: the module is ``jit_llm_decode`` and it holds exactly
     one Mosaic call, ``paged_decode``, which takes the layer's pool as
     an operand, head-major (models/gpt.py makes that view of the stored
-    pool). benchmark/kernels.py finds the
-    kernel by that operand and divides its seconds by calls x layers: a
-    second Mosaic call on the pool, or a reshaped or stacked pool, would
-    move ``paged_kernel_ms`` without moving the kernel."""
+    pool). benchmark/named_kernels.py finds the kernel by that name and
+    divides its seconds by the executions of ``jit_llm_decode``: a
+    renamed kernel or program would silence ``paged_kernel_ms``."""
     cfg, nb = gpt.GPT2_SMALL, CELL_NB
     text = _chat_cell_decode_lowered(one_chip).as_text()
     assert "module @jit_llm_decode " in text
@@ -240,10 +239,10 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     decode program returns the lanes' argmax ids, ``s32[64,1]``, beside
     its logits (the engine fetches those 64 ints and leaves the logits
     on the device), and its one Mosaic call is still ``paged_decode``
-    on operands of the layer pool's shape ``[12,2560,16,64]``: what
-    benchmark/kernels.py's ``paged_operand`` looks for in a device
-    trace's op names. A refactor that moves either fails here, not in
-    the benchmark's traced run."""
+    on operands of the layer pool's shape ``[12,2560,16,64]`` (the
+    benchmark's readers go by the kernel's NAME since PR 54, never by
+    an operand). A refactor that moves either fails here, not in the
+    benchmark's traced run."""
     import re
 
     cfg = gpt.GPT2_SMALL
@@ -949,3 +948,114 @@ def test_the_placing_program_compiles_once_whatever_the_lane():
     # One compilation at most (none if a test before this one placed a
     # token at this batch size in this process).
     assert len(compiled) <= 1, compiled
+
+
+# -- Nemotron-3-Super at the cell's shapes (configs/nemotron3-super-serve) ----
+
+NEMO_BLOCKS, NEMO_SLOTS, NEMO_MAX_SEQ = 9216, 129, 4864
+NEMO_STATE = f"f32[5,{NEMO_SLOTS},128,64,128]"
+NEMO_CONV = f"bf16[5,{NEMO_SLOTS},3,10240]"
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(one_chip):
+    """The engine's own decode and chunk programs for the served share
+    of Nemotron-3-Super (one period of 11 layers, 64 of 512 experts, a
+    16,384-token slice of the vocabulary), compiled for the described
+    v5e at the cell's shapes: 64 lanes, keys and values of ONE attention
+    layer in 9,216 blocks of 16 rows of 256, and the five state-space
+    layers' state in 129 slots (2.7 GB in float32 beside 40 MB of
+    convolution rows). ~35 s for the two."""
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.models import nemotron_h
+
+    cfg = nemotron_h.NemotronHConfig(
+        num_hidden_layers=11, hybrid_override_pattern="EMEMEMEMEM*",
+        vocab_size=16384, experts_held=64, max_seq=NEMO_MAX_SEQ)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: nemotron_h.init(jax.random.key(0), cfg)))
+    B, i32 = CELL_B, jnp.int32
+    kv = S((1, NEMO_BLOCKS, BS, 256), jnp.bfloat16)
+    state = (S((5, NEMO_SLOTS, 128, 64, 128), jnp.float32),
+             S((5, NEMO_SLOTS, 3, 10240), jnp.bfloat16))
+    max_nb = NEMO_MAX_SEQ // BS
+    decode, chunk = _jit_programs(cfg)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "param_leaves": len(jax.tree_util.tree_leaves(params)),
+            "decode": decode.lower(
+                params, S((B, step_columns(1, 0, True).table + max_nb), i32),
+                kv, kv, *state, q=1, firsts=S((B,), i32)).compile(),
+            # block table, 32 blocks written, ctx_len, last, two slots
+            "chunk": chunk.lower(
+                params, S((1, 512), i32), kv, kv,
+                S((max_nb + 512 // BS + 4,), i32), *state).compile(),
+        }
+
+
+def _nemo_state_sized(text, *opcodes):
+    """``_results`` at the size of ONE layer of the state pool."""
+    return _results(text, *opcodes, at_least=NEMO_SLOTS * 128 * 64 * 128)
+
+
+def test_nemotron_decode_program_moves_its_states_in_place(
+        nemotron_programs):
+    """The decode program at the cell's shapes: the state update once a
+    state-space layer under its name (``ssm_update`` x 5), the stored
+    paged call once (``attn_full``), the grouped product twice an
+    expert layer (``moe_experts_decode`` x 10): how the benchmark's
+    readers find them. All four pools (keys, values, states,
+    convolution rows) are donated and aliased to their outputs; the
+    2.7 GB state pool is an operand of the five kernels and of NOTHING
+    else that makes a pool-sized result: no gather of the lanes' slots,
+    no scatter back, no copy, no change of layout (what that costs when
+    it goes wrong is the Kimi pool's ``why_640``: 2.56 GB of
+    temporaries a step). Temporaries are tens of MB."""
+    c = nemotron_programs["decode"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert _mosaic_calls(text) == {"ssm_update": 5, "attn_full": 1,
+                                   "moe_experts_decode": 10}
+    assert _nemo_state_sized(text, "copy", "transpose", "copy-start",
+                             "gather", "scatter", "dynamic-slice",
+                             "dynamic-update-slice", "concatenate", "pad",
+                             "fusion") == []
+    for line in [l for n, l in _mosaic_lines(text) if n == "ssm_update"]:
+        assert NEMO_STATE in line.split("custom-call(")[0]     # comes back
+    # params' leaves, the packed array, then the four pools: outputs 2-5
+    # behind the logits and the ids; and ``firsts`` from the device.
+    n = nemotron_programs["param_leaves"]
+    assert _aliased(text) == {n + 1: 2, n + 2: 3, n + 3: 4, n + 4: 5}
+    assert _entry_parameters(text) == n + 6
+    entry = text[text.index("\nENTRY "):]
+    root = next(line for line in entry.splitlines()
+                if line.lstrip().startswith("ROOT "))
+    assert f"s32[{CELL_B + 4},1]" in root and f"bf16[{CELL_B},1,16384]" \
+        in root and NEMO_STATE in root and NEMO_CONV in root
+    assert c.memory_analysis().temp_size_in_bytes < 150e6
+
+
+def test_nemotron_chunk_program_scans_from_a_slot_into_a_slot(
+        nemotron_programs):
+    """A 512-token span behind a 4,864-token table, ONE program:
+    ``chunk_attn`` for the one attention layer, ``moe_experts_chunk``
+    twice an expert layer, the chunked scan once a state-space layer
+    (``ssm_scan``); each state-space layer reads ONE slot of the
+    state pool and writes one (a slice and an in-place update of 4 MB,
+    never the pool); all four pools aliased; the head runs on the one
+    row that comes back; temporaries stay under a quarter of a GB."""
+    c = nemotron_programs["chunk"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_prefill_chunk")
+    assert _mosaic_calls(text) == {"moe_experts_chunk": 10, "chunk_attn": 1,
+                                   "ssm_scan": 5}
+    assert _nemo_state_sized(text, "copy", "transpose", "copy-start",
+                             "gather", "scatter", "concatenate",
+                             "pad") == []
+    n = nemotron_programs["param_leaves"]
+    assert _aliased(text) == {n + 1: 2, n + 2: 3, n + 4: 4, n + 5: 5}
+    assert "[512,16384]" not in text and "[1,512,16384]" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 250e6
