@@ -9,8 +9,9 @@ itself, built from the repo's own layers:
                             ref-counted prefix cache with COW) +
                             WindowPool (layers with a window: a lane
                             keeps the blocks that cover its window)
-  * ops/pallas/paged_decode.py — decode-attention kernel gathering K/V
-                            through block tables (interpret mode on CPU)
+  * ops/pallas/paged_fetch.py — decode-attention kernels that copy
+                            their pages out of the pools as stored,
+                            by block table (interpret mode on CPU)
   * models/__init__.py   — the serving seam: a model's step and chunk
                             functions, cache and cost descriptions
   * models/gpt.py, models/laguna.py — forward_step /

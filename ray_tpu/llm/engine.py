@@ -1619,6 +1619,9 @@ class LLMEngine:
             **({} if self.states is None else self.states.stats()),
             "tokens_per_s": self.tokens_per_s(),
             "prefill_chunks": self._prefill_chunks,
+            # The step program's own counters (``kv_pages_in_runs``, a
+            # model's expert counts) where the newest step decoded.
+            **self._step_counters(),
             # Output tokens by where they were decided (see __init__).
             "tokens_decided_on_device": self._decided["device"],
             "tokens_decided_on_host": self._decided["host"],

@@ -52,8 +52,9 @@ seam's ``Serving.state``) lives in pools of SLOTS beside them
 index hands to later sequences. (Head-major, ``[..., kv_heads, num_blocks, block_size, head_dim]``
 with a 64-wide minor dimension, the runtime kept it in a compact layout
 that no reader or writer wanted, and every program converted the whole
-pool there and back: PERF.md section 6, PR 31.) Only models/gpt.py's
-paged call still reads a layer head-major (it makes that view).
+pool there and back: PERF.md section 6, PR 31.) No program reads a
+pool any other way than stored: every model's paged call copies its
+pages out of the stacked pools itself (ops/pallas/paged_fetch.py).
 """
 
 from __future__ import annotations

@@ -314,7 +314,7 @@ def forward(params, tokens, cfg: GPTConfig, mesh: Optional[Mesh] = None,
 # span of a prompt against whatever of it already sits in the paged pool
 # (llm/kv_cache.py) and WRITES the span's K/V into it; forward_step runs
 # the next rows of every in-flight sequence against that pool through
-# the paged-attention kernel (ops/pallas/paged_decode). Both are the
+# the paged-attention kernel (ops/pallas/paged_fetch). Both are the
 # training layer (_block) around an attention sublayer of their own, on
 # the training params — there is no separate "inference model".
 # ---------------------------------------------------------------------------
@@ -326,32 +326,18 @@ def _greedy_ids(logits):
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
-def _head_major(pool, layer, cfg: GPTConfig):
-    """Layer ``layer`` of a stored pool [L, num_blocks, block_size,
-    kv_heads * head_dim] as the paged kernel takes it,
-    [kv_heads, num_blocks, block_size, head_dim]: each head's lanes of
-    every row, sliced from the stacked pool and joined head-major. The
-    one pass over a layer's pool that a decode step makes: for the TPU,
-    XLA writes each slice in place into the one operand buffer (one
-    fusion a head), where a reshape and a transpose of ``pool[layer]``
-    cost it three passes (PERF.md section 6, PR 31)."""
-    num_blocks, block_size = pool.shape[1:3]
-    d = cfg.head_dim
-    return jnp.concatenate([
-        jax.lax.dynamic_slice(pool, (layer, 0, 0, h * d),
-                              (1, num_blocks, block_size, d))
-        for h in range(cfg.kv_heads)])
-
-
 def _paged_sublayer(h, p, layer, k_pool, v_pool, block_tables, context_lens,
                     q_lens, slot_blocks, slot_offsets, cfg: GPTConfig):
-    """A decode step's attention at layer ``layer`` of the stored pools
-    [L, num_blocks, block_size, kv_heads * head_dim]: project every row,
-    write its K/V in place at (layer, slot_blocks, slot_offsets), THEN
-    attend over the lane's block table in the written pool — the
-    write-then-attend convention of ops/pallas/paged_decode, so a row
-    sees itself. Carries the updated pools out of the layer."""
-    from ..ops.pallas.paged_decode import paged_verify_attention
+    """A decode step's attention at layer ``layer`` (traced: the layers
+    run under one scan) of the stored pools [L, num_blocks, block_size,
+    kv_heads * head_dim]: project every row, write its K/V in place at
+    (layer, slot_blocks, slot_offsets), THEN attend over the lane's
+    block table in the written pools as they are stored: write-then-
+    attend, so a row sees itself; the kernel
+    (ops/pallas/paged_fetch.py, under the name ``paged_decode``) copies
+    its pages out of the stacked pools itself, so the step makes no
+    other pass over them. Carries the updated pools out of the layer."""
+    from ..ops.pallas.paged_fetch import paged_attention_stored
 
     dt = cfg.dtype
     B, Q = h.shape[:2]
@@ -364,14 +350,22 @@ def _paged_sublayer(h, p, layer, k_pool, v_pool, block_tables, context_lens,
         k_tok.astype(k_pool.dtype).reshape(B, Q, -1))
     v_pool = v_pool.at[layer, slot_blocks, slot_offsets].set(
         v_tok.astype(v_pool.dtype).reshape(B, Q, -1))
-    o = paged_verify_attention(
-        q.reshape(B, Q, hkv, group, cfg.head_dim),
-        _head_major(k_pool, layer, cfg), _head_major(v_pool, layer, cfg),
-        block_tables, context_lens, q_lens)
+    # No window: every row sees from the table's first key on (row i
+    # sees from ``starts + i``, so zeros would hide a verify step's
+    # first i keys from its row i).
+    o = paged_attention_stored(
+        q.reshape(B, Q, hkv, group, cfg.head_dim), k_pool, v_pool, layer,
+        block_tables, context_lens, q_lens,
+        jnp.full_like(context_lens, -Q), name="paged_decode")
     o = jnp.einsum("bqhd,hdm->bqm",
                    o.reshape(B, Q, cfg.n_head, cfg.head_dim),
                    p["wo"].astype(dt))
     return o, (k_pool, v_pool)
+
+
+# What forward_step appends to its ids: 1000 x the share of the batch's
+# live cache pages that the paged kernel fetches in whole runs.
+COUNTERS = ("kv_pages_in_runs_x1000",)
 
 
 def forward_step(params, packed, k_pool, v_pool, *, q: int,
@@ -410,11 +404,13 @@ def forward_step(params, packed, k_pool, v_pool, *, q: int,
         negative, decided on the device by the chunk program queued
         before this step (models/__init__.py ``unpack_step``).
 
-    Returns (logits [b, q, vocab], ids [b, q] int32, k_pool, v_pool):
-    ``ids`` is the argmax of each logits row (the first index of the
-    maximum, as ``numpy.argmax`` on the same row), so a greedy lane's
-    tokens are decided here and the host fetches ids, not logits.
+    Returns (logits [b, q, vocab], ids [b + 1, q] int32, k_pool,
+    v_pool): rows 0..b-1 of ``ids`` are the argmax of each logits row
+    (the first index of the maximum, as ``numpy.argmax`` on the same
+    row), so a greedy lane's tokens are decided here and the host
+    fetches ids, not logits; row b is ``COUNTERS``.
     """
+    from ..ops.pallas.paged_fetch import kv_pages_in_runs_x1000
     from . import unpack_step
 
     (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
@@ -436,7 +432,12 @@ def forward_step(params, packed, k_pool, v_pool, *, q: int,
         layer, (x, k_pool, v_pool),
         (params["blocks"], jnp.arange(cfg.n_layer)))
     logits = _head(params, x, cfg)
-    return logits, _greedy_ids(logits), k_pool, v_pool
+    in_runs = kv_pages_in_runs_x1000(block_tables, context_lens, k_pool,
+                                     v_pool, score_rows=q * cfg.n_head)
+    ids = jnp.concatenate([
+        _greedy_ids(logits),
+        jnp.broadcast_to(in_runs, (len(COUNTERS), q)).astype(jnp.int32)])
+    return logits, ids, k_pool, v_pool
 
 
 def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len):
@@ -598,7 +599,7 @@ def serving(cfg: GPTConfig):
     return Serving(init=init, step=forward_step,
                    chunk=forward_prefill_chunk, kinds=(full,),
                    cost=cost_shape(cfg), max_seq=cfg.max_seq,
-                   vocab_size=cfg.vocab_size)
+                   vocab_size=cfg.vocab_size, counters=COUNTERS)
 
 
 @jax.custom_vjp

@@ -55,7 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..attention import NEG_INF
-from .paged_decode import interpret_default
+from . import interpret_default
 
 # Keys a grid step folds, and the query rows (heads of a group x
 # queries) it folds them into: the step's float32 scores are at most

@@ -1,20 +1,26 @@
 """Paged decode attention over a pool AS STORED, by kernels that fetch
-their own pages: ``attn_full`` / ``attn_window`` (keys and values, rows
-of ``kv_heads * 128``; models/laguna.py) and ``attn_latent`` (latent
-attention in the absorbed form, rows of 640; models/kimi_k2.py).
+their own pages: keys and values in rows of ``kv_heads * head_dim``
+(``attn_full`` / ``attn_window``, heads of 128: models/laguna.py,
+models/nemotron_h.py; ``paged_decode``, 12 heads of 64: models/gpt.py)
+and ``attn_latent`` (latent attention in the absorbed form, rows of
+640; models/kimi_k2.py). Every decode and verify step of every served
+model attends here; there is no other paged kernel.
 
-Why not page windows. ``paged_decode.py`` (GPT-2's kernel) names a
-lane's scattered pages through ``BlockSpec`` windows, one a page, and
-has to: its head-major operand has a minor dimension of 64, Mosaic sees
-it padded to 128 lanes and refuses every manual slice of it. Until PR
-42 these kernels did the same, and a window costs ~82 ns whatever it
-carries (its index map's scalar arithmetic, the pipeline's
-changed-index test, a descriptor, a semaphore wait, its part of the
-``concatenate`` that joins a step's windows): 32 windows were 2.6 us of
-a grid step whose bytes take 0.8 us (latent) or 1.3 us (stored). The
-transfers' count set the time, not their bytes. A row here is whole
-128-lane tiles and a page whole sublane tiles, so Mosaic takes manual
-slices of these pools, and the kernels move their own keys.
+Why not page windows. Until PR 42 these kernels named a lane's
+scattered pages through ``BlockSpec`` windows, one a page (GPT-2's did
+until PR 59, over a head-major copy of a layer's pool that the program
+made for it once a layer: ``[kv_heads, num_blocks, block_size, 64]``,
+whose 64-wide minor dimension Mosaic pads to 128 lanes and refuses to
+slice). A window costs ~82 ns whatever it carries (its index map's
+scalar arithmetic, the pipeline's changed-index test, a descriptor, a
+semaphore wait, its part of the ``concatenate`` that joins a step's
+windows): 32 windows were 2.6 us of a grid step whose bytes take 0.8 us
+(latent) or 1.3 us (stored). The transfers' count set the time, not
+their bytes. A stored row is whole 128-lane tiles and a page whole
+sublane tiles, so Mosaic takes manual slices of these pools, and the
+kernels move their own keys. A head of 64 is half a lane tile OF SUCH A
+ROW: the copy still moves whole rows, never a slice of HBM, and the
+head is a static lane slice of the VMEM tile (``_stored_kernel``).
 
 The mechanism (``_fetched``, ``_page_copies``). The stacked pool stays
 in HBM as stored (``memory_space=pl.ANY``) and is an operand ONCE (once
@@ -84,11 +90,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..attention import NEG_INF
-from .paged_decode import interpret_default
+from . import interpret_default
 
 # VMEM a grid step's tiles (two compute blocks deep) and its float32
 # scores may take; v5e's scoped default is 16 MiB.
@@ -298,20 +305,42 @@ def _fetching_call(kernel, *, name: str, interpret: bool, b: int,
         interpret=interpret, name=name)
 
 
-def _stored_kernel(tables_ref, lens_ref, qlens_ref, starts_ref, runs_ref,
-                   q_ref, k_pool, v_pool, o_ref, k_tile, v_tile, sem,
-                   slot_ref, m_ref, l_ref, acc_ref, *, layer: int,
-                   block_size: int, pages: int, run: int, n_blocks: int,
-                   scale: float, group: int, hkv: int, d: int):
-    """``paged_decode._decode_kernel`` on pages of the pool AS STORED,
-    which the kernel copies itself (``_fetched``): a page is
-    ``(block_size, kv_heads * d)``, a token's K (or V) of every head in
-    one row, so each head is a static slice of whole 128-lane tiles
-    (d = 128) and the heads fold one after another, two plain matmuls
-    each. Adds a lower bound on the keys a row sees, for layers with a
-    window: row i of lane b sees key positions ``>= starts[b] + i``
-    (its table holds only the blocks that cover its window, so the
-    window's start lies inside the oldest of them)."""
+def _stored_kernel(*refs, layer: int | None, block_size: int, pages: int,
+                   run: int, n_blocks: int, scale: float, group: int,
+                   hkv: int, d: int):
+    """One grid step of attention over keys and values on pages of the
+    pool AS STORED, which the kernel copies itself (``_fetched``): a
+    page is ``(block_size, kv_heads * d)``, a token's K (or V) of every
+    head in one row.
+
+    A head is a static lane slice of the tile, whole 128-lane tiles at
+    d = 128 and half of one at d = 64, and the heads fold one after
+    another, two plain matmuls each. (At d = 64 and ``group`` 1 the
+    twelve pairs of one-row products a block are bound by the MXU's
+    latency, not by bytes: 29% of the roofline where d = 128 stands at
+    87%; PERF.md section 6, PRs 58 and 59.)
+
+    The q block is a KV head's ``q_len * group`` rows, row ``r`` query
+    token ``r // group`` at position ``ctx - q_lens[b] + r // group``:
+    causal within the span, so a row sees the resident context and the
+    step's rows at or before itself; a lane with fewer real rows than
+    ``q_len`` (short proposals, padding) clamps to the plain context
+    mask and its spare rows are defined garbage the engine never reads.
+    A layer with a window adds a lower bound: row i of lane b sees key
+    positions ``>= starts[b] + i`` (its table holds only the blocks
+    that cover its window, so the window's start lies inside the oldest
+    of them).
+
+    ``layer`` is the caller's Python int, or ``None``: then the layer
+    rides in as one more scalar-prefetch operand, the first (a model
+    whose layers run under one ``lax.scan`` has one kernel a program
+    and a traced index)."""
+    if layer is None:
+        layer_ref, *refs = refs
+        layer = layer_ref[0]
+    (tables_ref, lens_ref, qlens_ref, starts_ref, runs_ref, q_ref, k_pool,
+     v_pool, o_ref, k_tile, v_tile, sem, slot_ref, m_ref, l_ref,
+     acc_ref) = refs
     b = pl.program_id(0)
     blk = pl.program_id(1)
     span = pages * block_size
@@ -355,24 +384,26 @@ def _stored_kernel(tables_ref, lens_ref, qlens_ref, starts_ref, runs_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _make_stored_call(b: int, hkv: int, group: int, d: int, layer: int,
-                      num_blocks: int, block_size: int, max_nb: int,
-                      q_dtype, p_dtype, interpret: bool, q_len: int,
-                      name: str):
+def _make_stored_call(b: int, hkv: int, group: int, d: int,
+                      layer: int | None, num_blocks: int, block_size: int,
+                      max_nb: int, q_dtype, p_dtype, interpret: bool,
+                      q_len: int, name: str):
+    """``layer`` ``None``: the call takes the layer, ``[1]`` int32,
+    before the tables."""
     rows = q_len * group
     pages, run = _geometry(2 * hkv * d, hkv * rows, block_size, max_nb,
                            num_blocks, jnp.dtype(p_dtype).itemsize)
     n_blocks = pl.cdiv(max_nb, pages)
     lane = pl.BlockSpec(
-        (1, hkv, rows, d),
-        lambda bi, blk, tables, lens, qlens, starts, runs: (bi, 0, 0, 0))
+        (1, hkv, rows, d), lambda bi, blk, *prefetched: (bi, 0, 0, 0))
     call = _fetching_call(
         functools.partial(_stored_kernel, layer=layer,
                           block_size=block_size, pages=pages, run=run,
                           n_blocks=n_blocks, scale=d ** -0.5, group=group,
                           hkv=hkv, d=d),
         name=name, interpret=interpret, b=b, n_blocks=n_blocks,
-        prefetch=5,      # tables, context lens, q lens, starts, run flags
+        # (layer,) tables, context lens, q lens, starts, run flags
+        prefetch=5 + (layer is None),
         q_block=lane, out_block=lane,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q_dtype),
         pools=2, tile=pltpu.VMEM((2, pages, block_size, hkv * d), p_dtype),
@@ -380,33 +411,46 @@ def _make_stored_call(b: int, hkv: int, group: int, d: int, layer: int,
                          pltpu.VMEM((hkv, rows, 1), jnp.float32),
                          pltpu.VMEM((hkv, rows, d), jnp.float32)])
 
-    def attend(tables, lens, qlens, starts, q, k_pool, v_pool):
+    def attend(tables, lens, qlens, starts, q, k_pool, v_pool, *layer):
         runs, _ = _page_runs(tables, lens, block_size, run,
                              n_blocks * pages // run)
-        return call(tables, lens, qlens, starts, runs, q, k_pool, v_pool)
+        return call(*layer, tables, lens, qlens, starts, runs, q, k_pool,
+                    v_pool)
     return attend
 
 
-def paged_attention_stored(q, k_pool, v_pool, layer: int, block_tables,
+def paged_attention_stored(q, k_pool, v_pool, layer, block_tables,
                            context_lens, q_lens, starts, *, name: str,
                            interpret: bool | None = None):
-    """``paged_decode.paged_verify_attention`` over the pool as the
-    cache stores it, with an optional window.
+    """Attention of a decode step (``q_len`` 1) or a speculative verify
+    step (``q_len`` rows a lane in one pass) over block-paged keys and
+    values, the pool as the cache stores it, with an optional window.
 
     Args:
-      q: ``[batch, q_len, kv_heads, group, head_dim]``.
+      q: ``[batch, q_len, kv_heads, group, head_dim]``: query heads
+        grouped by the KV head they read; row j of lane b sits at
+        position ``context_lens[b] - q_lens[b] + j`` (write-then-attend:
+        the lane's real rows' K and V are already in their slots, so a
+        row sees itself).
       k_pool / v_pool: ``[layers, num_blocks, block_size, kv_heads *
         head_dim]``, the stacked pool of one kind of layer, untouched:
-        the kernel copies its pages out of it at the static ``layer``.
-        No head-major view is made (at head_dim 128 a row is whole lane
-        tiles).
-      block_tables / context_lens / q_lens: as ``paged_verify_attention``;
-        for a layer with a window the table holds the blocks from the
-        window's oldest on, and ``context_lens`` counts from that
-        block's first slot.
+        the kernel copies its pages out of it at ``layer``. No
+        head-major view is made.
+      layer: the layer of the pools, a Python int, or a traced int32
+        scalar (a layer body under ``lax.scan``).
+      block_tables: ``[batch, max_blocks]`` int32, a lane's pool blocks,
+        padded with 0 (the reserved scratch block); for a layer with a
+        window the table holds the blocks from the window's oldest on.
+      context_lens: ``[batch]`` int32, resident tokens a lane INCLUDING
+        this step's ``q_lens[b]`` real rows (counted from the table's
+        first slot).
+      q_lens: ``[batch]`` int32, real rows a lane (1..q_len); rows past
+        it attend the whole context and are garbage nobody reads.
       starts: ``[batch]`` int32, the first key position (in the table's
         own coordinates) that row 0 of a lane sees; row i sees from
-        ``starts + i``. Zeros (or below) for a layer without a window.
+        ``starts + i``. For a layer without a window ``-q_len`` or
+        below (zeros will do at ``q_len`` 1 only: row i of a verify
+        step would miss the table's first i keys).
       name: the kernel's name on a device trace.
 
     Returns ``[batch, q_len, kv_heads, group, head_dim]`` in q's dtype.
@@ -417,13 +461,16 @@ def paged_attention_stored(q, k_pool, v_pool, layer: int, block_tables,
     _, num_blocks, block_size, width = k_pool.shape
     if width != hkv * d:
         raise ValueError(f"pool row {width} != {hkv} kv heads x {d}")
-    call = _make_stored_call(b, hkv, group, d, int(layer), num_blocks,
+    static = isinstance(layer, (int, np.integer))
+    call = _make_stored_call(b, hkv, group, d,
+                             int(layer) if static else None, num_blocks,
                              block_size, block_tables.shape[1], q.dtype,
                              k_pool.dtype, interpret, q_len, name)
+    traced = () if static else (jnp.asarray(layer, jnp.int32).reshape(1),)
     qf = q.transpose(0, 2, 1, 3, 4).reshape(b, hkv, q_len * group, d)
     out = call(block_tables.astype(jnp.int32),
                context_lens.astype(jnp.int32), q_lens.astype(jnp.int32),
-               starts.astype(jnp.int32), qf, k_pool, v_pool)
+               starts.astype(jnp.int32), qf, k_pool, v_pool, *traced)
     return out.reshape(b, hkv, q_len, group, d).transpose(0, 2, 1, 3, 4)
 
 
@@ -466,7 +513,7 @@ def _latent_kernel(tables_ref, lens_ref, qlens_ref, runs_ref, q_ref, pool,
     against the whole row, and the values are the first ``rank``
     columns of the same tile: a page is read once for both. Row ``r``
     is query token ``r // heads``; the causal bound is
-    ``paged_decode._decode_kernel``'s."""
+    ``_stored_kernel``'s."""
     b = pl.program_id(0)
     blk = pl.program_id(1)
     span = pages * block_size
@@ -557,7 +604,7 @@ def paged_attention_latent(q, pool, layer: int, block_tables, context_lens,
         the scores are ``q . row`` over the whole width and the values
         the row's first ``rank`` columns.
       block_tables / context_lens / q_lens: as
-        ``paged_decode.paged_verify_attention``.
+        ``paged_attention_stored``.
       scale: the softmax scale (the model's, with its YaRN ``mscale``).
 
     Returns ``[batch, q_len, heads, rank]`` in q's dtype: a head's

@@ -156,11 +156,16 @@ class DeploymentResponse:
         finally:
             self._settle()
 
-    def _open_stream(self, result, chunk_batch: int):
-        """The handle's end of the stream that ``result`` names, or None
-        where the deployment did not return a generator."""
+    def open_stream(self, result, chunk_batch: int = 16):
+        """The handle's end of the stream that ``result`` (this
+        response's value) names, or None where the deployment did not
+        return a generator. ``iter_stream`` reads it from a thread
+        (``take``); a reader on an event loop attaches a sink
+        (``attach``), and then waiting for a chunk holds neither a
+        thread nor a task."""
         from .replica import STREAM_MARKER
 
+        self._settle()
         if not (isinstance(result, dict) and STREAM_MARKER in result):
             return None
         return self._router.open_stream(
@@ -177,7 +182,7 @@ class DeploymentResponse:
         is yielded as the single item (reference:
         handle.options(stream=True) -> DeploymentResponseGenerator)."""
         result = self.result(timeout=timeout)
-        stream = self._open_stream(result, chunk_batch)
+        stream = self.open_stream(result, chunk_batch)
         if stream is None:
             yield result
             return
@@ -186,25 +191,6 @@ class DeploymentResponse:
                 yield chunk
         finally:
             # Early consumer exit: free the parked generator.
-            stream.close()
-
-    async def aiter_stream(self, timeout: Optional[float] = None,
-                           chunk_batch: int = 16):
-        """``iter_stream`` for an event loop: waiting for a chunk holds
-        no thread."""
-        import asyncio
-
-        result = await asyncio.get_running_loop().run_in_executor(
-            None, lambda: self.result(timeout=timeout))
-        stream = self._open_stream(result, chunk_batch)
-        if stream is None:
-            yield result
-            return
-        try:
-            while (chunk := await stream.atake(timeout)) \
-                    is not _STREAM_END:
-                yield chunk
-        finally:
             stream.close()
 
     def _to_object_ref(self):
@@ -231,9 +217,12 @@ _STREAM_END = object()
 
 class _StreamEnd:
     """The handle's end of one stream: the router's poller deals what
-    the replica sent into it, one consumer (a thread in ``take`` or a
-    task in ``atake``) takes it out, and what the consumer has taken is
-    what lets the replica's generator run on."""
+    the replica sent into it, ONE consumer takes it out, and what the
+    consumer has taken is what lets the replica's generator run on.
+    Which kind of consumer is which call the reader made: a thread
+    blocks in ``take``; a loop cannot block, so its reader attaches a
+    sink (``attach``) and the poller has the loop call ``pump``, once a
+    reply for every such stream the reply carries (``Router._flush``)."""
 
     def __init__(self, router: "Router", key, actor, sid: int,
                  run_ahead: int):
@@ -246,20 +235,25 @@ class _StreamEnd:
         self._chunks: deque = deque()  # dealt, not yet taken
         self._ended = False  # the replica has sent this stream's end
         self._error: Optional[BaseException] = None  # ... and it was this
-        self._waker: Optional[Callable] = None
         self._consumed = 0
         self._granted = 0
+        # A loop's consumer: ``_sink(chunks, ended, error)`` is called
+        # on ``_loop`` and nowhere else; it returns False to be given
+        # no more until it calls ``resume`` (its drain waiter does).
+        self._loop = None
+        self._sink: Optional[Callable] = None
+        self._taking = True
 
     def deal(self, chunks, done: bool, error):
-        """Poller side: append one reply's share of this stream."""
+        """Poller side: append one reply's share of this stream. Returns
+        the loop that has to ``pump`` it, or None where a thread takes
+        it (woken here)."""
         with self._cond:
             self._chunks.extend(chunks)
             if done or error is not None:
                 self._ended, self._error = True, error
-            waker, self._waker = self._waker, None
             self._cond.notify_all()
-        if waker is not None:
-            waker()
+            return self._loop
 
     def grant(self, explicit: bool = False) -> Optional[int]:
         """How far the generator may yield, if that is further than the
@@ -291,14 +285,12 @@ class _StreamEnd:
             raise self._error
         return _STREAM_END
 
-    def _after_take(self, item):
-        if item is not _STREAM_END \
-                and (upto := self.grant(explicit=True)) is not None:
+    def _after_take(self):
+        if (upto := self.grant(explicit=True)) is not None:
             try:
                 self._actor.stream_grant.remote(self.sid, upto)
             except Exception:  # lint: allow-swallow(grant to a gone replica; the poller reports it)
                 pass
-        return item
 
     def take(self, timeout: Optional[float]):
         """Next chunk, or _STREAM_END; raises the stream's error, or
@@ -310,45 +302,54 @@ class _StreamEnd:
                 raise GetTimeoutError(
                     f"no stream chunk within {timeout}s")
             item = self._pop()
-        return self._after_take(item)
+        if item is not _STREAM_END:
+            self._after_take()
+        return item
 
-    async def atake(self, timeout: Optional[float]):
-        """``take`` for an event loop: the poller wakes the waiting task
-        through ``call_soon_threadsafe``."""
-        import asyncio
+    def attach(self, loop, sink: Callable):
+        """A loop's reader, once, on ``loop``: from now on everything
+        this stream is dealt goes to ``sink(chunks, ended, error)``, a
+        reply's share in one call (what was dealt before now, first, in
+        this call); ``ended`` comes with or behind the last chunks, and
+        ``error`` with it. A sink that returns False is given no more
+        (what arrives waits here, uncounted, so a generator stops at its
+        run-ahead bound) until it calls ``resume``."""
+        with self._cond:
+            self._loop, self._sink = loop, sink
+        self.pump()
 
-        loop = asyncio.get_running_loop()
-        while True:
-            with self._cond:
-                if self._has_next():
-                    item = self._pop()
-                    break
-                fut = loop.create_future()
-                self._waker = lambda: loop.call_soon_threadsafe(
-                    _resolve, fut)
-            try:
-                await asyncio.wait_for(fut, timeout)
-            finally:
-                with self._cond:
-                    self._waker = None
-        return self._after_take(item)
+    def resume(self):
+        """The sink, on its loop: it takes again."""
+        self._taking = True
+        self.pump()
+
+    def pump(self):
+        """On the sink's loop: give the sink what has been dealt."""
+        with self._cond:
+            sink = self._sink
+            if sink is None or not (self._taking and self._has_next()):
+                return
+            chunks = list(self._chunks)
+            self._chunks.clear()
+            self._consumed += len(chunks)
+            ended, error = self._ended, self._error
+            if ended:
+                self._sink = None
+        self._taking = sink(chunks, ended, error) is not False
+        if chunks and not ended:
+            self._after_take()
 
     def close(self):
         """Consumer side, always: leave the poller's books, and free the
         replica's generator unless its end has already arrived."""
         self._router.close_stream(self._key, self.sid)
         with self._cond:
-            ended = self._ended
+            ended, self._sink = self._ended, None
         if not ended:
             try:
                 self._actor.stream_cancel.remote(self.sid)
             except Exception:  # lint: allow-swallow(cancel on a gone replica)
                 pass
-
-
-def _resolve(fut):
-    if not fut.done():
-        fut.set_result(None)
 
 
 def _replica_key(replica):
@@ -535,18 +536,43 @@ class Router:
             except Exception as e:  # noqa: BLE001 - every waiting consumer raises it
                 with self._lock:
                     ends = self._stream_ends.pop(key, {})
-                for end in ends.values():
-                    end.deal((), True, e)
+                self._deal([(end, ((), True, e)) for end in ends.values()])
                 return
             with self._lock:
-                dealt = [(ends.get(sid), share)
-                         for sid, share in reply.items()]
+                dealt = [(ends[sid], share) for sid, share in reply.items()
+                         if sid in ends]
                 for sid, (_, done, error) in reply.items():
                     if done or error is not None:
                         ends.pop(sid, None)
-            for end, share in dealt:
-                if end is not None:
-                    end.deal(*share)
+            self._deal(dealt)
+
+    def _deal(self, dealt):
+        """One reply's shares to their ends: a thread that waits in
+        ``take`` is woken by its end, and the ends that a loop reads
+        are pumped by ONE callback on it, whatever their number."""
+        by_loop: dict = {}
+        for end, share in dealt:
+            if (loop := end.deal(*share)) is not None:
+                by_loop.setdefault(loop, []).append(end)
+        for loop, ends in by_loop.items():
+            try:
+                loop.call_soon_threadsafe(self._flush, ends)
+            except RuntimeError:  # lint: allow-swallow(the loop is closed: its readers are gone)
+                pass
+
+    def _flush(self, ends):
+        """On a reader's loop, once a reply: every sink takes its
+        stream's share. ``proxy_flush`` counts the wakes (``stream_hold``
+        counts the chunks, so the two give chunks a wake)."""
+        import time as _time
+
+        from . import slo
+
+        t0 = _time.perf_counter()
+        for end in ends:
+            end.pump()
+        slo.record_phase("proxy_flush", _time.perf_counter() - t0,
+                         self._name)
 
     def remove_replica(self, key):
         """Drop a replica observed dead so the retry (and subsequent

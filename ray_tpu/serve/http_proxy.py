@@ -15,7 +15,107 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from typing import Optional
+
+
+class _StreamWriter:
+    """The sink of one HTTP stream (``_StreamEnd.attach``): called on
+    the proxy's loop with a reply's share of the stream, it encodes the
+    frames and writes them to the response then and there, several in
+    one write. ``finished`` resolves at the stream's end: False, True
+    where it stalled past the per-chunk deadline, or the stream's error
+    (behind the chunks before it) or the connection's.
+
+    What a task around ``await write`` gave is kept without one: when
+    the transport's buffer is over its limit the sink takes no more (the
+    frames wait in the stream's end, and a generator at its run-ahead
+    bound) until ONE drain waiter has seen it empty; and the per-chunk
+    deadline is one timer a stream, re-armed when it fires from the
+    time of the last frame."""
+
+    def __init__(self, end, request, writer, root, timeout_s: float):
+        self.loop = asyncio.get_running_loop()
+        self.finished = self.loop.create_future()
+        self._end, self._request, self._writer = end, request, writer
+        self._root, self._timeout_s = root, timeout_s
+        self._first = True
+        self._drain = None  # the drain waiter, while the sink is paused
+        self._last = self.loop.time()
+        self._timer = self.loop.call_later(timeout_s, self._deadline)
+
+    def __call__(self, chunks, ended, error):
+        if self.finished.done():
+            return False
+        self._last = self.loop.time()
+        if chunks:
+            if self._first and self._root is not None:
+                # TTFT on the root span: arrival -> first streamed
+                # chunk reaches the proxy.
+                self._root.add_event(
+                    "ttft", ms=(time.time() - self._root.start) * 1e3)
+            self._first = False
+            try:
+                # Without the drain ``write`` awaits nothing: run to its
+                # end here, it leaves the frames with the transport.
+                self._writer.write(b"".join(
+                    bytes(c) if isinstance(c, (bytes, bytearray))
+                    else (json.dumps(c) + "\n").encode()
+                    for c in chunks), drain=False).send(None)
+            except StopIteration:
+                pass
+            except Exception as e:  # noqa: BLE001 - a chunk JSON cannot encode, a connection that is gone: the handler raises it, the loop's other streams go on
+                return self._finish(e)
+        if ended:
+            return self._finish(error)
+        if self._request.protocol.writing_paused:
+            self._drain = self.loop.create_task(self._drained())
+            return False
+        return True
+
+    async def _drained(self):
+        try:
+            await self._writer.drain()
+        except (ConnectionError, OSError) as e:
+            self._finish(e)
+            return
+        self._drain = None
+        self._last = self.loop.time()
+        self._end.resume()
+
+    def _deadline(self):
+        """The per-chunk deadline: a generator that stalls mid-stream
+        past the request timeout ends the response (a reader that
+        stalls does not: its frames wait for it)."""
+        idle = 0.0 if self._drain is not None \
+            else self.loop.time() - self._last
+        if idle < self._timeout_s:
+            self._timer = self.loop.call_later(
+                self._timeout_s - idle, self._deadline)
+        else:
+            self._finish(timed_out=True)
+
+    def _finish(self, error=None, timed_out: bool = False):
+        if not self.finished.done():
+            if self._root is not None and not self._first:
+                self._root.add_event(
+                    "last_token",
+                    ms=(time.time() - self._root.start) * 1e3,
+                    aborted=timed_out)
+            if error is not None:
+                self.finished.set_exception(error)
+            else:
+                self.finished.set_result(timed_out)
+        return False
+
+    def stop(self):
+        self._timer.cancel()
+        if self._drain is not None:
+            self._drain.cancel()
+        if not self.finished.done():
+            self.finished.cancel()
+        elif not self.finished.cancelled():
+            self.finished.exception()  # seen, where the handler was cancelled
 
 
 class HTTPProxy:
@@ -181,7 +281,7 @@ class HTTPProxy:
         from .replica import STREAM_MARKER
 
         if isinstance(result, dict) and STREAM_MARKER in result:
-            return await self._stream(request, resp, root)
+            return await self._stream(request, resp, result, root)
         if is_asgi and isinstance(result, dict) and "status" in result:
             from multidict import CIMultiDict
 
@@ -196,11 +296,12 @@ class HTTPProxy:
                                 headers=hdrs)
         return web.json_response(result)
 
-    async def _stream(self, request, resp, root=None):
+    async def _stream(self, request, resp, result, root=None):
         """Chunked transfer of a generator response: each chunk is a raw
-        bytes frame or one newline-delimited JSON document."""
-        import time as _time
-
+        bytes frame or one newline-delimited JSON document. The frames
+        are written where the handle's poller hands them to this loop
+        (``_StreamWriter``, the stream's sink): this task waits for the
+        stream's end and for nothing else."""
         from aiohttp import web
 
         headers = {"Content-Type": "application/x-ndjson"}
@@ -208,41 +309,19 @@ class HTTPProxy:
             headers["x-rtpu-trace-id"] = root.trace_id
         sr = web.StreamResponse(headers=headers)
         sr.enable_chunked_encoding()
-        await sr.prepare(request)
-        # A waiting stream holds no thread: the handle's poller wakes
-        # this task when the replica has sent the stream's next chunk.
-        it = resp.aiter_stream(timeout=self.request_timeout_s)
-        timed_out = False
-        first_chunk = True
+        writer = await sr.prepare(request)
+        end = resp.open_stream(result)
+        out = _StreamWriter(end, request, writer, root,
+                            self.request_timeout_s)
         try:
-            try:
-                # Per-chunk deadline: a generator that stalls mid-stream
-                # past the request timeout ends the response, and the
-                # client sees an ABORTED (not cleanly completed) stream.
-                async for chunk in it:
-                    if first_chunk and root is not None:
-                        # TTFT on the root span: arrival -> first
-                        # streamed chunk reaches the proxy.
-                        root.add_event(
-                            "ttft",
-                            ms=(_time.time() - root.start) * 1e3)
-                        first_chunk = False
-                    if isinstance(chunk, (bytes, bytearray)):
-                        await sr.write(bytes(chunk))
-                    else:
-                        await sr.write((json.dumps(chunk) + "\n").encode())
-            except (TimeoutError, asyncio.TimeoutError):
-                timed_out = True
-            if root is not None and not first_chunk:
-                root.add_event(
-                    "last_token",
-                    ms=(_time.time() - root.start) * 1e3,
-                    aborted=timed_out)
+            end.attach(out.loop, out)
+            timed_out = await out.finished
         finally:
             # Free the replica-side generator (a no-op once the stream
-            # has ended): also when the client disconnected, cancelling
-            # this handler mid-await.
-            await it.aclose()
+            # has ended): also when the client disconnected, which fails
+            # the next write or cancels this handler mid-await.
+            out.stop()
+            end.close()
         if timed_out:
             # In-band error frame, then abort the connection WITHOUT the
             # terminating chunk: a truncated stream must not look like a

@@ -32,6 +32,13 @@ continuous-batching autoscaler consumes (ROADMAP item 1):
                           stream, the deployment handing it over: an
                           LLM step's tokens, all at one instant) and
                           the reply that carries it leaving
+  * ``proxy_flush``     — streaming responses read on an event loop
+                          (the HTTP proxy): one callback of the loop,
+                          in which every stream of a ``stream_poll``
+                          reply has its share written to its socket. Its
+                          count is the number of wakes of the loop that
+                          streams cost, so ``stream_hold``'s count over
+                          it is chunks a wake
 
 Two sinks per observation, both cheap (a bucket increment under one
 lock):
@@ -65,7 +72,8 @@ PHASE_BOUNDS: List[float] = [
 # token from request arrival at the engine, and per-output-token latency
 # (decode cadence) — the two numbers an LLM serving SLO is written in.
 PHASES = ("proxy_queue", "replica_queue", "batch_wait", "execute",
-          "ttft", "tpot", "engine_queue", "stream_pull", "stream_hold")
+          "ttft", "tpot", "engine_queue", "stream_pull", "stream_hold",
+          "proxy_flush")
 
 _lock = threading.Lock()
 # Deployment hosted by THIS process (set by Replica.__init__).

@@ -10,6 +10,7 @@ does, and reads the same alone and under load.
 import contextlib
 import gc
 import sys
+import threading
 from unittest import mock
 
 
@@ -47,14 +48,18 @@ _EXIT = python_calls.__exit__.__code__
 
 
 @contextlib.contextmanager
-def calls_of(owner, name):
+def calls_of(owner, name, thread=None):
     """Count the calls of ``owner.name`` inside the block, by whatever
-    thread; the function still runs."""
+    thread, or by the one thread whose ``threading.get_ident()`` is
+    ``thread`` (a block that must not count what a loop left running by
+    an earlier test of the same process does meanwhile); the function
+    still runs."""
     real = getattr(owner, name)
     count = Count()
 
     def counted(*args, **kwargs):
-        count.n += 1
+        if thread is None or threading.get_ident() == thread:
+            count.n += 1
         return real(*args, **kwargs)
 
     with mock.patch.object(owner, name, counted):

@@ -313,6 +313,32 @@ def test_step_breakdown_in_stats_spans_and_ring():
     perfmodel.clear_device_steps()
 
 
+def test_step_counts_the_pages_its_kernel_fetches_in_runs():
+    """The decode program appends ``kv_pages_in_runs_x1000`` to its ids
+    (the seam's ``counters``), so every ``llm.step`` ring entry that
+    decoded, and ``stats()``, say what share of the
+    batch's live cache pages the paged kernel fetched in whole runs of
+    adjacent blocks: all of them for a lane whose eight blocks a fresh
+    pool granted in order, none for a lane one block long. (One lane: a
+    padded lane's scratch page counts as a live page, fetched alone.)"""
+    from ray_tpu.models import serving
+    from ray_tpu.util import perfmodel
+
+    assert serving(CFG).counters == ("kv_pages_in_runs_x1000",)
+    for prompt, share in ((list(range(1, 59)), 1.0), ([5, 6, 7], 0.0)):
+        perfmodel.clear_device_steps()
+        eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8,
+                        max_batch=1)
+        eng.add_request(prompt, max_tokens=4)
+        _drain(eng)
+        decoded = [e for e in perfmodel.device_step_events()
+                   if e["name"] == "llm.step" and e["decode_tokens"] > 0]
+        assert len(decoded) == 3
+        assert [e["kv_pages_in_runs"] for e in decoded] == [share] * 3
+        assert eng.stats()["kv_pages_in_runs"] == share
+    perfmodel.clear_device_steps()
+
+
 def test_idle_engine_decays_perf_gauges_to_zero():
     """Acceptance: a drained engine must publish zeroed gauges from its
     background loop's idle ticks — the MFU/step series decay instead of
@@ -487,9 +513,10 @@ def test_program_ids_equal_host_greedy_sample_of_its_logits(Q, kind):
             slot[:, -1] + 1, np.full((B,), Q, np.int32),
             np.broadcast_to(tables[:, :1], (B, Q)), slot),
         pool, pool + 0, q=Q)
-    assert ids.dtype == jnp.int32 and ids.shape == logits.shape[:-1]
+    # (the row behind the lanes' is the program's counter)
+    assert ids.dtype == jnp.int32 and ids.shape == (B + 1, Q)
     rows = np.asarray(logits, np.float32).reshape(-1, BF16.vocab_size)
-    got = np.asarray(ids).reshape(-1)
+    got = np.asarray(ids)[:B].reshape(-1)
     assert [sample(r, temperature=0.0) for r in rows] == got.tolist()
     if kind == "ties":
         assert all((r == r.max()).sum() >= 2 for r in rows)
@@ -500,15 +527,16 @@ def test_program_ids_equal_host_greedy_sample_of_its_logits(Q, kind):
 
 
 def test_all_greedy_decode_step_fetches_ids_not_logits(watch_device_get):
-    """Every lane greedy: a decode step brings max_batch ints to the
-    host and nothing else; the logits stay on the device."""
+    """Every lane greedy: a decode step brings max_batch ints (and the
+    program's one counter behind them) to the host and nothing else;
+    the logits stay on the device."""
     eng = LLMEngine(PARAMS, CFG, num_blocks=64, block_size=8, max_batch=4)
     hs = [eng.add_request([1 + i, 2, 3], max_tokens=6) for i in range(3)]
     eng.step()                      # prefills fetch their last row each
     fetched = watch_device_get()
     eng.step()
     eng.step()
-    assert fetched == [eng.max_batch] * 2, fetched
+    assert fetched == [eng.max_batch + 1] * 2, fetched
     assert max(fetched) < CFG.vocab_size
     _drain(eng)
     assert all(h.finish_reason == "length" for h in hs)
@@ -654,7 +682,9 @@ def test_step_at_one_row_is_row_zero_of_a_wider_step_with_padding_rows():
 
     l1, i1, k1, v1 = run(1)
     l3, i3, k3, v3 = run(3)
-    assert l1.shape == (B, 1, CFG.vocab_size) and i3.shape == (B, 3)
+    # (the lanes' rows, and the program's counter behind them: the
+    # same share of pages in runs whatever the rows a lane)
+    assert l1.shape == (B, 1, CFG.vocab_size) and i3.shape == (B + 1, 3)
     np.testing.assert_allclose(np.asarray(l3[:, 0]), np.asarray(l1[:, 0]),
                                atol=2e-5, rtol=0)
     assert np.asarray(i3[:, 0]).tolist() == np.asarray(i1[:, 0]).tolist()
@@ -715,13 +745,13 @@ def test_chunk_with_an_empty_table_is_the_plain_causal_forward():
 # The parent's pool layout, kept here as the reference the stored
 # (token-major) pool is held to: [L, kv_heads, num_blocks, block_size,
 # head_dim], written and read as models/gpt.py did before PR 31.
-def _head_major_pool(pool, cfg):
+def _heads_first_pool(pool, cfg):
     L, nb, bs, _ = pool.shape
     return pool.reshape(L, nb, bs, cfg.kv_heads, cfg.head_dim).transpose(
         0, 3, 1, 2, 4)
 
 
-def _head_major_layers(params, x, k_pool, v_pool, attend, cfg):
+def _heads_first_layers(params, x, k_pool, v_pool, attend, cfg):
     """The parent's layer scan: a layer's head-major pools come in as
     the scan's xs and whatever ``attend(h, p, kp, vp)`` carries goes out
     stacked."""
@@ -738,10 +768,16 @@ def _head_major_layers(params, x, k_pool, v_pool, attend, cfg):
     return gpt._head(params, x, cfg), carried
 
 
-def _head_major_step(params, tokens, positions, k_pool, v_pool, tables,
+def _heads_first_step(params, tokens, positions, k_pool, v_pool, tables,
                      context_lens, q_lens, slot_blocks, slot_offsets, cfg):
     from ray_tpu.models import gpt
-    from ray_tpu.ops.pallas.paged_decode import paged_verify_attention
+    from ray_tpu.ops.pallas.paged_fetch import (
+        paged_attention_stored_reference)
+
+    def stored(pool):
+        """One layer's head-major pool as a stack of one stored layer."""
+        hkv, nb, bs, d = pool.shape
+        return pool.transpose(1, 2, 0, 3).reshape(1, nb, bs, hkv * d)
 
     def attend(h, p, kp, vp):
         B, Q = h.shape[:2]
@@ -750,21 +786,23 @@ def _head_major_step(params, tokens, positions, k_pool, v_pool, tables,
             k_tok.astype(kp.dtype).transpose(2, 0, 1, 3))
         vp = vp.at[:, slot_blocks, slot_offsets].set(
             v_tok.astype(vp.dtype).transpose(2, 0, 1, 3))
-        o = paged_verify_attention(
+        # Plain jnp, not the kernel under test: an independent witness.
+        o = paged_attention_stored_reference(
             q.reshape(B, Q, cfg.kv_heads, cfg.n_head // cfg.kv_heads,
-                      cfg.head_dim), kp, vp, tables, context_lens, q_lens)
+                      cfg.head_dim), stored(kp), stored(vp), 0, tables,
+            context_lens, q_lens, jnp.full_like(context_lens, -Q))
         o = jnp.einsum("bqhd,hdm->bqm",
                        o.reshape(B, Q, cfg.n_head, cfg.head_dim),
                        p["wo"].astype(cfg.dtype))
         return o, (kp, vp)
 
-    logits, (k_pool, v_pool) = _head_major_layers(
+    logits, (k_pool, v_pool) = _heads_first_layers(
         params, gpt._embed(params, tokens, positions, cfg), k_pool, v_pool,
         attend, cfg)
     return logits, gpt._greedy_ids(logits), k_pool, v_pool
 
 
-def _head_major_chunk(params, tokens, positions, k_pool, v_pool, table,
+def _heads_first_chunk(params, tokens, positions, k_pool, v_pool, table,
                       ctx_len, cfg):
     from ray_tpu.models import gpt
 
@@ -780,7 +818,7 @@ def _head_major_chunk(params, tokens, positions, k_pool, v_pool, table,
         return (jnp.einsum("bshd,hdm->bsm", o, p["wo"].astype(cfg.dtype)),
                 (k_tok, v_tok))
 
-    logits, (k, v) = _head_major_layers(
+    logits, (k, v) = _heads_first_layers(
         params, gpt._embed(params, tokens, positions, cfg), k_pool, v_pool,
         attend, cfg)
     return logits, k, v
@@ -789,14 +827,16 @@ def _head_major_chunk(params, tokens, positions, k_pool, v_pool, table,
 @pytest.mark.parametrize("cfg", [CFG, BF16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("case", ["decode", "verify_3_rows", "chunk_context",
                                   "chunk_empty_table"])
-def test_token_major_pool_gives_the_head_major_pools_bits(case, cfg):
+def test_token_major_pool_gives_the_heads_first_pools_bits(case, cfg):
     """The stored pool [L, num_blocks, block_size, kv_heads * head_dim]
     against the parent's [L, kv_heads, num_blocks, block_size,
-    head_dim], re-derived from it by reshape and transpose: a decode
-    step, a three-row verify step and a chunk with and without context
-    give the same logits and ids bit for bit, the pools they return are
-    the same pool in the two layouts, and gather_tokens reads back what
-    the head-major pool holds under the same table."""
+    head_dim], re-derived from it by reshape and transpose: a
+    chunk with and without context gives the same logits bit for bit;
+    a decode step and a three-row verify step, whose witness attends
+    with the plain-jnp ``paged_attention_stored_reference`` over the
+    head-major pool re-stored (not with the kernel the program runs),
+    give the same logits, ids and pools to rounding; and gather_tokens
+    reads back what the head-major pool holds under the same table."""
     import functools
 
     from ray_tpu.llm.engine import _jit_programs
@@ -810,8 +850,8 @@ def test_token_major_pool_gives_the_head_major_pools_bits(case, cfg):
     draw = lambda: jnp.asarray(rng.standard_normal(
         (cfg.n_layer, nb, bs, cfg.kv_heads * cfg.head_dim)), cfg.dtype)
     k_pool, v_pool = draw(), draw()
-    k_old, v_old = (_head_major_pool(k_pool, cfg),
-                    _head_major_pool(v_pool, cfg))
+    k_old, v_old = (_heads_first_pool(k_pool, cfg),
+                    _heads_first_pool(v_pool, cfg))
     equal = lambda a, b: np.array_equal(np.asarray(a, np.float32),
                                         np.asarray(b, np.float32))
     if case.startswith("chunk"):
@@ -821,7 +861,7 @@ def test_token_major_pool_gives_the_head_major_pools_bits(case, cfg):
         args = (rng.integers(0, cfg.vocab_size, (1, T), dtype=np.int32),
                 ctx + np.arange(T, dtype=np.int32))
         got = chunk(params, *args, k_pool, v_pool, table, np.int32(ctx))
-        want = jax.jit(functools.partial(_head_major_chunk, cfg=cfg))(
+        want = jax.jit(functools.partial(_heads_first_chunk, cfg=cfg))(
             params, *args, k_old, v_old, table, np.int32(ctx))
         assert all(equal(g, w) for g, w in zip(got, want))
         return
@@ -836,18 +876,28 @@ def test_token_major_pool_gives_the_head_major_pools_bits(case, cfg):
     logits, ids, k_new, v_new = step(params, pack_step(*args, *rest),
                                      k_pool + 0, v_pool + 0, q=Q)
     l_old, i_old, k_want, v_want = jax.jit(
-        functools.partial(_head_major_step, cfg=cfg))(
+        functools.partial(_heads_first_step, cfg=cfg))(
             params, *args, k_old, v_old, *rest)
-    assert equal(logits, l_old)
-    assert np.asarray(ids).tolist() == np.asarray(i_old).tolist()
-    assert equal(_head_major_pool(k_new, cfg), k_want)
-    assert equal(_head_major_pool(v_new, cfg), v_want)
+    # The witness attends in plain jnp (float32 softmax over a dense
+    # gather), the program through the kernel: equal to rounding.
+    atol = 2e-6 if cfg is CFG else 0.02
+    near = lambda a, b: np.allclose(np.asarray(a, np.float32),
+                                    np.asarray(b, np.float32),
+                                    atol=atol, rtol=0)
+    assert near(logits, l_old)
+    chosen = np.take_along_axis(
+        np.asarray(l_old, np.float32),
+        np.asarray(ids)[:B].reshape(B, Q, 1), axis=-1)[..., 0]
+    assert near(chosen, np.asarray(l_old, np.float32).max(-1))
+    assert near(_heads_first_pool(k_new, cfg), k_want)
+    assert near(_heads_first_pool(v_new, cfg), v_want)
     assert not equal(k_new, k_pool)               # the rows were written
     kv = PagedKVCache(cfg, num_blocks=nb, block_size=bs)
     kv.k, kv.v = k_new, v_new
     n = int(pos[1, -1]) + 1
     k_back, v_back = kv.gather_tokens([int(b) for b in tables[1, :2]], n)
-    for back, old in ((k_back, k_want), (v_back, v_want)):
+    for back, old in ((k_back, _heads_first_pool(k_new, cfg)),
+                      (v_back, _heads_first_pool(v_new, cfg))):
         rows = old[:, :, tables[1, :2]].transpose(0, 2, 3, 1, 4).reshape(
             cfg.n_layer, 2 * bs, cfg.kv_heads, cfg.head_dim)[:, :n]
         # Rows come back as the pool holds them, heads side by side.

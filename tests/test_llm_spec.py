@@ -348,7 +348,8 @@ def test_accept_draws_on_ids_is_verify_tokens_on_the_rows(proposed):
 # ---------------------------------------------------------------------------
 def test_all_greedy_verify_step_fetches_ids_not_logits(watch_device_get):
     """Every lane greedy: a verify step brings max_batch x (k + 1) ints
-    to the host and nothing else; the logits stay on the device."""
+    (and the program's one counter's row behind them) to the host and
+    nothing else; the logits stay on the device."""
     eng = LLMEngine(PARAMS, CFG, num_blocks=32, block_size=8, max_batch=4,
                     speculative=NGRAM)
     hs = [eng.add_request(LOOPY, max_tokens=24),
@@ -357,7 +358,7 @@ def test_all_greedy_verify_step_fetches_ids_not_logits(watch_device_get):
     fetched = watch_device_get()
     eng.step()
     eng.step()
-    assert fetched == [eng.max_batch * (NGRAM["k"] + 1)] * 2, fetched
+    assert fetched == [(eng.max_batch + 1) * (NGRAM["k"] + 1)] * 2, fetched
     _drain(eng)
     assert [h.finish_reason for h in hs] == ["length", "length"]
     assert eng.stats()["spec"]["accepted"] > 0

@@ -8,12 +8,22 @@ The tables are 40 slots of 8 rows and ``_VMEM_BUDGET`` is held so that a
 grid step carries 16 pages (two groups of 8): a lane has up to three
 compute blocks, the last with one group the table does not have, and the
 chain of copies crosses lanes of different lengths.
+
+From "GPT-2's shapes" on: the stored kernel as models/gpt.py calls it
+(heads of 64, one query head a KV head, no window, decode and verify
+steps, the layer static or traced), the cases of the page-window kernel
+it replaced (PR 59). Tolerances there: float32 within 2e-5 of the
+reference (one fused online softmax against a dense one: rounding);
+bfloat16 operands with float32 accumulation within 2e-2 (the error is
+the operands' quantization, not the algorithm).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.pallas import paged_fetch
 
 BS, MAX_NB, NUM_BLOCKS, LANES = 8, 40, 160, 3
@@ -277,3 +287,183 @@ def test_one_helper_decides_the_run_size(monkeypatch, budget):
             jnp.asarray(tables), jnp.asarray(lens),
             *map(jnp.asarray, pools), score_rows=4)
     assert len(asked) == 4 and asked[0] == asked[1] and asked[2] == asked[3]
+
+
+# -- GPT-2's shapes: heads of 64, no window, decode and verify (PR 59) --------
+
+ATOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+def _no_window(lens, q_len):
+    """``starts`` of a layer without a window: below every row's own."""
+    return jnp.full((len(lens),), -q_len, jnp.int32)
+
+
+def _attend(q, k_pool, v_pool, layer, tables, lens, q_lens):
+    """(kernel, reference) on one layer of stored pools, float32."""
+    args = (q, k_pool, v_pool, layer, jnp.asarray(tables),
+            jnp.asarray(lens), jnp.asarray(q_lens),
+            _no_window(lens, q.shape[1]))
+    return (np.asarray(paged_fetch.paged_attention_stored(
+                *args, name="paged_decode"), np.float32),
+            np.asarray(paged_fetch.paged_attention_stored_reference(
+                *args), np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("d", [64, 128], ids=["d64", "d128"])
+@pytest.mark.parametrize("group", [1, 4], ids=["g1", "g4"])
+@pytest.mark.parametrize("q_len", [1, 5], ids=["decode", "verify_q5"])
+def test_cutting_into_compute_blocks_matches_reference(budget, q_len, group,
+                                                       d, dtype):
+    """What the cut into grid steps of several pages can get wrong, one
+    lane each, against the plain reference: contexts of 1, one under,
+    at and one over a compute block's edge, and the whole table; a
+    table that is not a whole number of compute blocks (a lane's last
+    block is partial); page ids that fall and repeat, within a lane and
+    across lanes; padded lanes (context 1, table of zeros) between live
+    ones; and, for verify, lanes with fewer real rows than q_len. Rows
+    past a lane's q_lens attend its whole context in both, so the whole
+    tensor compares."""
+    budget(paged_fetch._VMEM_BUDGET)
+    hkv, block_size, max_nb, num_blocks = 2, 8, 6, 40
+    pages, run = paged_fetch._geometry(
+        2 * hkv * d, hkv * q_len * group, block_size, max_nb, num_blocks,
+        jnp.dtype(dtype).itemsize)
+    span = pages * block_size
+    assert 1 < pages < max_nb and max_nb % pages and run == pages, pages
+    full = max_nb * block_size
+    lens = np.array([1, span - 1, 1, span, span + 1, 1, full, full - 3],
+                    np.int32)
+    live = lambda n: -(-int(n) // block_size)
+    tables = np.zeros((len(lens), max_nb), np.int32)
+    tables[0, :1] = [17]
+    tables[1, :live(lens[1])] = np.arange(30, 30 - live(lens[1]), -1)
+    tables[3, :live(lens[3])] = np.arange(9, 9 + live(lens[3]))
+    # Lane 4 shares lane 3's pages and names its last one twice.
+    tables[4, :live(lens[4])] = np.r_[tables[3, :live(lens[3])],
+                                      tables[3, live(lens[3]) - 1]]
+    tables[6] = [5, 4, 3, 5, 4, 3]          # falls, then repeats
+    tables[7] = np.arange(39, 39 - max_nb, -1)
+    # Lanes 2 and 5 are padding: context 1, table of zeros.
+    q_lens = np.minimum(lens, [1, 5, 1, 3, 5, 1, 2, 4][:len(lens)])
+    q_lens = np.minimum(q_lens, q_len).astype(np.int32)
+
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(q_len + group + d), 3)
+    pool = lambda key: jax.random.normal(
+        key, (3, num_blocks, block_size, hkv * d), dtype)
+    q = jax.random.normal(kq, (len(lens), q_len, hkv, group, d), dtype)
+    got, want = _attend(q, pool(kk), pool(kv), 2, tables, lens, q_lens)
+    np.testing.assert_allclose(got, want, atol=ATOL[dtype], rtol=0)
+
+
+def _laid_out(seq, block_size, num_blocks):
+    """``seq`` [ctx, heads, d] laid into blocks 1.. of one stored layer
+    (block 0 is scratch), and the table that names them."""
+    ctx, heads, d = seq.shape
+    nb = -(-ctx // block_size)
+    pool = np.zeros((1, num_blocks, block_size, heads * d), np.float32)
+    rows = np.pad(np.asarray(seq).reshape(ctx, heads * d),
+                  ((0, nb * block_size - ctx), (0, 0)))
+    pool[0, 1:nb + 1] = rows.reshape(nb, block_size, heads * d)
+    return jnp.asarray(pool), np.arange(1, nb + 1, dtype=np.int32)[None]
+
+
+@pytest.mark.parametrize("q_len", [1, 3], ids=["decode", "verify_q3"])
+def test_a_step_is_the_last_rows_of_dense_causal_attention(q_len):
+    """The decode step IS the last row of dense causal attention, and a
+    verify step its last q_len rows (every one of which sees the
+    sequence's FIRST key: without a window ``starts`` lies below zero):
+    lay contiguous K/V into blocks as stored, attend with the kernel,
+    compare with ops/attention.causal_attention's last positions."""
+    d, heads, block_size, ctx = 64, 2, 8, 21
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+    k_seq = jax.random.normal(kk, (1, ctx, heads, d), jnp.float32)
+    v_seq = jax.random.normal(kv, (1, ctx, heads, d), jnp.float32)
+    q_seq = jax.random.normal(kq, (1, ctx, heads, d), jnp.float32)
+    # A first key that would dominate any row that saw it.
+    k_seq = k_seq.at[0, 0].set(q_seq[0, -q_len:].mean(0) * 4.0)
+    dense = causal_attention(q_seq, k_seq, v_seq)[0, -q_len:]
+    k_pool, table = _laid_out(k_seq[0], block_size, 6)
+    v_pool, _ = _laid_out(v_seq[0], block_size, 6)
+    q = q_seq[:, -q_len:].reshape(1, q_len, heads, 1, d)
+    got, _ = _attend(q, k_pool, v_pool, 0, table,
+                     np.asarray([ctx], np.int32),
+                     np.asarray([q_len], np.int32))
+    np.testing.assert_allclose(got[0, :, :, 0], np.asarray(dense),
+                               atol=2e-5, rtol=0)
+
+
+def test_verify_is_causal_within_the_speculative_span():
+    """Write-then-attend: a lane's real rows are already in their
+    slots, and row j must not see rows j+1..: perturbing a LATER
+    speculative slot's K/V cannot change an earlier row's output, and
+    changes its own."""
+    rng = np.random.default_rng(8)
+    hkv, d, bs, q_len = 1, 64, 4, 3
+    pools = [jnp.asarray(rng.standard_normal((2, 8, bs, hkv * d)),
+                         jnp.float32) for _ in range(2)]
+    q = jnp.asarray(rng.standard_normal((1, q_len, hkv, 1, d)), jnp.float32)
+    tables = np.asarray([[3, 6]], np.int32)
+    ctx = 7
+    lens, q_lens = np.asarray([ctx], np.int32), np.asarray([3], np.int32)
+    out1, _ = _attend(q, *pools, 1, tables, lens, q_lens)
+    # Perturb the LAST real slot (position ctx - 1, row 2's write site).
+    blk, off = int(tables[0, (ctx - 1) // bs]), (ctx - 1) % bs
+    out2, _ = _attend(q, pools[0].at[1, blk, off].add(100.0),
+                      pools[1].at[1, blk, off].add(-50.0), 1, tables, lens,
+                      q_lens)
+    # Rows 0 and 1 see positions <= ctx-3 / ctx-2 only: unchanged.
+    np.testing.assert_allclose(out1[0, :2], out2[0, :2], atol=2e-5, rtol=0)
+    assert not np.allclose(out1[0, 2], out2[0, 2], atol=1e-3)
+
+
+def test_scratch_block_garbage_is_masked():
+    """Padded table slots point at block 0, and a padded lane (context
+    1, table of zeros) reads it: whatever lives there must not reach a
+    live lane's output."""
+    rng = np.random.default_rng(4)
+    hkv, d, bs = 2, 64, 4
+    pools = [jnp.asarray(rng.standard_normal((1, 8, bs, hkv * d)),
+                         jnp.float32) for _ in range(2)]
+    q = jnp.asarray(rng.standard_normal((3, 1, hkv, 1, d)), jnp.float32)
+    tables = np.asarray([[5, 2, 0, 0], [0, 0, 0, 0], [7, 0, 0, 0]], np.int32)
+    lens, ones = np.asarray([6, 1, 3], np.int32), np.ones(3, np.int32)
+    out1, ref = _attend(q, *pools, 0, tables, lens, ones)
+    out2, _ = _attend(q, pools[0].at[:, 0].set(1e4),
+                      pools[1].at[:, 0].set(-1e4), 0, tables, lens, ones)
+    np.testing.assert_allclose(out1, ref, atol=2e-5, rtol=0)
+    np.testing.assert_allclose(out1[[0, 2]], out2[[0, 2]], atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("q_len", [1, 5], ids=["decode", "verify_q5"])
+def test_a_traced_layer_under_scan_gives_the_static_layers_bits(q_len):
+    """models/gpt.py runs its layers under one ``lax.scan``: the layer
+    index is traced and rides into the kernel as a scalar-prefetch
+    operand. Every layer of the stack through the scan equals, bit for
+    bit, the call with that layer as a Python int, and the two differ
+    from layer to layer (so the operand, not a constant, picks the
+    pages)."""
+    rng = np.random.default_rng(5)
+    layers, hkv, d, bs, lanes = 3, 4, 64, 8, 3
+    pools = [jnp.asarray(rng.standard_normal((layers, 24, bs, hkv * d)),
+                         jnp.bfloat16) for _ in range(2)]
+    q = jnp.asarray(rng.standard_normal((lanes, q_len, hkv, 1, d)),
+                    jnp.bfloat16)
+    tables = jnp.asarray(rng.permutation(np.arange(1, 24))[:lanes * 6]
+                         .reshape(lanes, 6).astype(np.int32))
+    lens = jnp.asarray([6 * bs, 13, 1], jnp.int32)
+    q_lens = jnp.minimum(jnp.asarray([q_len, 2, 1]), q_len).astype(jnp.int32)
+
+    def attend(layer):
+        return paged_fetch.paged_attention_stored(
+            q, *pools, layer, tables, lens, q_lens, _no_window(lens, q_len),
+            name="paged_decode")
+
+    _, scanned = jax.lax.scan(lambda c, i: (c, attend(i)), 0,
+                              jnp.arange(layers))
+    static = [np.asarray(attend(i), np.float32) for i in range(layers)]
+    for i in range(layers):
+        assert (np.asarray(scanned[i], np.float32) == static[i]).all()
+    assert not (static[0] == static[1]).all()

@@ -18,6 +18,7 @@ moved onto the hot path is the regression.
 
 import dataclasses
 import secrets
+import threading
 import types
 import uuid
 
@@ -190,7 +191,10 @@ def test_flight_recorder_overhead_gate():
         mp.setattr(flightrec, "time", clock)
         pair()                  # lazy gauge creation; publishes at 0 s
         quiet = _calls(pair) - 3 - 1    # less the clock's three; pair
-        with calls_of(Gauge, "set") as published:
+        # This thread's writes alone: an engine that an earlier test of
+        # the same xdist worker left idling publishes gauges of its own.
+        with calls_of(Gauge, "set",
+                      thread=threading.get_ident()) as published:
             for _ in range(n):
                 pair()
     span_s = 2 * n * 10e-6

@@ -136,6 +136,8 @@ class Ticker:
                 for i in range(n):
                     if i == fail_at:
                         raise ValueError(f"boom at {i}")
+                    if i == req.get("stall_at"):
+                        time.sleep(5.0)
                     if together:
                         with self.cond:
                             seen = self.tick
@@ -143,6 +145,9 @@ class Ticker:
                     elif req.get("period", PERIOD):
                         time.sleep(req.get("period", PERIOD))
                     self.yielded += 1
+                    if req.get("pad"):
+                        yield {"tag": tag, "i": i, "pad": "x" * req["pad"]}
+                        continue
                     yield {"tag": tag, "i": i, "t": time.time()}
             finally:
                 self.closed += 1
@@ -626,3 +631,273 @@ def test_an_error_handed_to_a_pushed_stream_arrives_behind_its_chunks(
         next(it)
     assert isinstance(ei.value.cause, ValueError)
     _wait(lambda: not _pollers(), "the failed stream to be freed")
+
+
+# ---------------------------------------------------------------------------
+# A loop's consumer is a sink (PR 59): a poll reply is ONE callback on
+# the proxy's loop, which writes every stream's share to its socket; no
+# task switch, future or timer a frame. What ``await write`` and
+# ``wait_for`` were there for holds without them.
+# ---------------------------------------------------------------------------
+import asyncio  # noqa: E402
+import socket  # noqa: E402
+
+from ray_tpu.serve import slo  # noqa: E402
+from ray_tpu.serve.deployment import Router, _StreamEnd  # noqa: E402
+
+
+def _flushes(name):
+    return slo.phase_hist(name).get("proxy_flush", {"count": 0})["count"]
+
+
+def _post(port, body, rcvbuf=None):
+    """An HTTP connection with the request sent and the response's head
+    read; the body is the caller's to read, or not."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=60)
+    if rcvbuf:
+        sock = socket.socket()
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+        sock.settimeout(60)
+        sock.connect(("127.0.0.1", port))
+        conn.sock = sock
+    conn.request("POST", "/", body=json.dumps(body),
+                 headers={"Content-Type": "application/json"})
+    return conn, conn.getresponse()
+
+
+def test_a_reply_with_64_streams_frames_is_one_wake_of_the_loop(rt):
+    """Every push fills 64 streams, one stream_poll reply carries them,
+    and the proxy's loop wakes ONCE for the reply: ``proxy_flush``
+    counts a wake a round where ``stream_hold`` counts 64 chunks. What
+    was handed over before the writer attached arrives first."""
+    serve.run(Pusher.bind(), name="default")
+    h = serve.get_app_handle("default")
+    proxy = serve.start(http_port=0)
+    n, rounds = 64, 12
+    opened = [_post(proxy.port, {"tag": k, "early": 2}) for k in range(n)]
+    # Every reader has its early frames: its stream is on the books,
+    # its writer attached and its caller known to the replica.
+    early = [[json.loads(resp.readline()) for _ in range(2)]
+             for _, resp in opened]
+    assert early == [[{"tag": k, "i": i} for i in range(2)]
+                     for k in range(n)]
+    before, held = _flushes("Pusher"), _replica_hist("Pusher")
+    feed = h.options(method_name="feed")
+    for r in range(rounds):
+        feed.remote({"handed": [[k, [2 + r], r == rounds - 1, None]
+                                for k in range(n)]}).result(timeout=30)
+    for k, (conn, resp) in enumerate(opened):
+        rest = [json.loads(line) for line in resp.read().splitlines()]
+        assert rest == [{"tag": k, "i": 2 + r} for r in range(rounds)]
+        conn.close()
+    frames = _replica_hist("Pusher")["stream_hold"] - held["stream_hold"]
+    wakes = _flushes("Pusher") - before
+    assert frames == n * rounds
+    # A round is one reply and one wake; two rounds may share one.
+    assert 1 <= wakes <= rounds + 1, (wakes, rounds)
+    _wait(lambda: not _pollers(), "the poller to end")
+
+
+def test_a_reader_that_stops_holds_its_frames_and_the_generator(rt):
+    """Backpressure without ``await write``: a client that stops reading
+    fills the transport's buffer, the sink stops taking, the frames wait
+    in the stream's end with ``_consumed`` standing still, and the
+    generator stops sixteen chunks past it; reading on drains the
+    buffer and a drain waiter resumes the stream, every chunk in order."""
+    from ray_tpu.serve.deployment import _router_for
+
+    h = _ticker()
+    proxy = serve.start(http_port=0)
+    total, pad = 1500, 16384
+    conn, resp = _post(proxy.port, {"n": total, "tag": "slow", "period": 0,
+                                    "pad": pad}, rcvbuf=8192)
+    assert json.loads(resp.readline())["i"] == 0
+    (end,) = [e for ends in _router_for("Ticker")._stream_ends.values()
+              for e in ends.values()]
+    _wait(lambda: not end._taking, "the sink to stop taking", timeout=60)
+    seen = [-1, time.monotonic()]
+
+    def settled():
+        y = _state(h)["yielded"]
+        if y != seen[0]:
+            seen[:] = [y, time.monotonic()]
+        return time.monotonic() - seen[1] > 1.0
+
+    _wait(settled, "the generator to stop", timeout=60)
+    stopped = seen[0]
+    assert stopped < total
+    assert not end._taking and end._consumed < stopped
+    # The generator at its run-ahead bound, and what it yielded past
+    # what the sink took held here (or on its way here).
+    assert stopped == end._consumed + 16
+    _wait(lambda: len(end._chunks) == 16, "the held frames")
+    rest = [json.loads(line)["i"] for line in resp.read().splitlines()]
+    assert rest == list(range(1, total))
+    conn.close()
+    _wait(lambda: _state(h)["closed"] == 1 and not _pollers(),
+          "the stream to be freed")
+
+
+def test_frames_then_a_stall_one_timer_a_stream(rt):
+    """The per-chunk deadline is one timer a stream: frames that keep
+    coming re-arm nothing, the timer re-arms itself from the time of the
+    last frame when it fires, and a stall past the request timeout ends
+    the stream with the frames it had, the in-band error frame and an
+    aborted connection."""
+    h = _ticker()
+    assert len(list(h.remote({"n": 1}).iter_stream(timeout=60))) == 1
+    proxy = serve.start(http_port=0, request_timeout_s=1.0)
+    armed = []
+    call_later = proxy._loop.call_later
+
+    def counting(delay, callback, *args):
+        if getattr(callback, "__name__", "") == "_deadline":
+            armed.append(delay)
+        return call_later(delay, callback, *args)
+
+    proxy._loop.call_later = counting
+    frames = 30
+    conn, resp = _post(proxy.port, {"n": frames + 1, "period": PERIOD,
+                                    "fail_at": None, "stall_at": frames})
+    assert resp.status == 200
+    with pytest.raises(http.client.IncompleteRead) as ei:
+        resp.read()
+    lines = [json.loads(line) for line in ei.value.partial.splitlines()]
+    assert [f["i"] for f in lines[:-1]] == list(range(frames))
+    assert lines[-1] == {"error": "stream chunk timed out"}
+    conn.close()
+    # Armed at the attach and once a firing: 30 frames over 1.5 s (more
+    # on a loaded box) and a stall of 1 s are three or four firings,
+    # not thirty timers.
+    assert 2 <= len(armed) <= 12, armed
+    assert all(0 < d <= 1.0 for d in armed)
+    _wait(lambda: _state(h)["closed"] >= 2 and not _pollers(),
+          "the stalled stream to be freed", timeout=20)
+
+
+def test_a_client_that_disconnects_cancels_the_replicas_stream(rt):
+    h = _ticker()
+    proxy = serve.start(http_port=0)
+    conn, resp = _post(proxy.port, {"n": 10 ** 9, "tag": "gone"})
+    assert [json.loads(resp.readline())["i"] for _ in range(3)] == [0, 1, 2]
+    assert _state(h)["feeders"] == 1 and len(_pollers()) == 1
+    conn.sock.shutdown(socket.SHUT_RDWR)
+    conn.close()
+    _wait(lambda: _state(h)["closed"] == 1 and not _state(h)["feeders"],
+          "the generator to close")
+    _wait(lambda: not _pollers(), "the poller to end")
+
+
+def test_order_and_exactly_once_over_64_sinks(rt):
+    """64 generator streams through the proxy, yielding as fast as
+    their sinks' grants allow, with every thread of this process (the
+    device lane's feeders and polls, the poller, the loop) switched
+    every 10 us: each client gets its own chunks, all of them, once, in
+    order."""
+    import sys
+
+    serve.run(Ticker.options(ray_actor_options={
+        "scheduling_strategy": "device"}).bind(), name="default")
+    h = serve.get_app_handle("default")
+    proxy = serve.start(http_port=0)
+    got = [None] * 64
+    # This process's own histogram: the replica is a thread of it.
+    held = _replica_hist()["stream_hold"]
+
+    def client(k):
+        conn, resp = _post(proxy.port, {"n": 60, "tag": k, "period": 0})
+        got[k] = [json.loads(line) for line in resp.read().splitlines()]
+        conn.close()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(64)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for k, frames in enumerate(got):
+        assert [(f["tag"], f["i"]) for f in frames] == \
+            [(k, i) for i in range(60)]
+    assert _replica_hist()["stream_hold"] - held == 64 * 60
+    _wait(lambda: not _pollers() and not _state(h)["feeders"],
+          "pollers and feeders to end")
+
+
+class _Remote:
+    def __init__(self, log, name):
+        self.log, self.name = log, name
+
+    def remote(self, *args):
+        self.log.append((self.name, *args))
+
+
+class _Actor:
+    """Records the actor calls a stream's end makes."""
+
+    def __init__(self):
+        self.calls = []
+        self.stream_grant = _Remote(self.calls, "grant")
+        self.stream_cancel = _Remote(self.calls, "cancel")
+
+
+def test_a_sink_gets_what_was_dealt_before_it_first_and_can_pause():
+    """The end alone, no cluster: shares dealt before the sink attached
+    wait for it and come first; a reply for many sinks is one callback
+    of their loop; a sink that returns False is given nothing more and
+    counts nothing as consumed until it resumes."""
+    router, actor, loop = Router("sinks"), _Actor(), asyncio.new_event_loop()
+    ends = [_StreamEnd(router, "k", actor, sid, 4) for sid in (1, 2, 3)]
+    got = {e.sid: [] for e in ends}
+    taking = {e.sid: True for e in ends}
+
+    def sink(sid):
+        def take(chunks, ended, error):
+            got[sid].append((list(chunks), ended, error))
+            return taking[sid]
+        return take
+
+    a, b, c = ends
+    assert a.deal(["a0", "a1"], False, None) is None   # nobody's loop yet
+    scheduled = []
+    loop.call_soon_threadsafe = lambda fn, *args: scheduled.append((fn, args))
+    before = _flushes("sinks")
+    for e in ends:
+        e.attach(loop, sink(e.sid))
+    assert got == {1: [(["a0", "a1"], False, None)], 2: [], 3: []}
+    assert a._consumed == 2 and not scheduled
+    boom = ValueError("boom")
+    router._deal([(a, (["a2"], False, None)), (b, (["b0", "b1"], True, None)),
+                  (c, (["c0"], True, boom))])
+    (call,) = scheduled                     # ONE callback for three streams
+    call[0](*call[1])
+    assert _flushes("sinks") == before + 1
+    assert got[1][1:] == [(["a2"], False, None)]
+    assert got[2] == [(["b0", "b1"], True, None)]
+    assert got[3] == [(["c0"], True, boom)]  # the error behind its chunk
+    # Paused: the share waits in the end, uncounted, and asks no grant.
+    taking[1] = False
+    a.deal(["a3"], False, None)
+    a.pump()
+    assert a._consumed == 4 and not a._taking
+    a.deal(["a4", "a5"], False, None)
+    a.pump()
+    assert len(got[1]) == 3 and list(a._chunks) == ["a4", "a5"]
+    assert actor.calls[-1] == ("grant", 1, 8) and a.grant() is None
+    taking[1] = True
+    a.resume()
+    assert got[1][3] == (["a4", "a5"], False, None) and a._consumed == 6
+    # Half a run-ahead behind the replica's word: the consumer says so.
+    grants = [call for call in actor.calls if call[0] == "grant"]
+    assert grants[-1] == ("grant", 1, 10)
+    a.close()
+    assert ("cancel", 1) in actor.calls
+    a.deal(["late"], False, None)
+    a.pump()                                 # closed: goes nowhere
+    assert len(got[1]) == 4
+    loop.close()

@@ -24,8 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models import gpt, step_columns
 from ray_tpu.ops.pallas.flash import flash_attention_pallas
-from ray_tpu.ops.pallas.paged_decode import (paged_decode_attention,
-                                             paged_verify_attention)
+from ray_tpu.ops.pallas.paged_fetch import paged_attention_stored
 from ray_tpu.parallel import MeshSpec
 
 # GPT-2-small serving widths: 12 KV heads x head_dim 64, block 16.
@@ -79,28 +78,36 @@ def test_flash_forward_and_backward_compile(one_chip):
     assert "tpu_custom_call" in bwd.as_text()
 
 
+@pytest.mark.parametrize("layer", ["static_layer", "traced_layer"])
 @pytest.mark.parametrize("q_len", [1, 5], ids=["decode", "verify_q5"])
 @pytest.mark.parametrize("batch, hkv, group, hd, nb, max_nb", [
     (32, HKV, 1, HD, NB, MAX_NB),
     (64, HKV, 1, HD, 2560, MAX_NB),     # gpt2s-serve-chat's own shapes
     (16, 8, 8, 128, 2048, 128),         # head_dim 128, group 8
 ], ids=["b32_4096_blocks", "chat_cell", "hd128_group8"])
-def test_paged_kernel_compiles(one_chip, q_len, batch, hkv, group, hd, nb,
-                               max_nb):
+def test_paged_kernel_compiles(one_chip, layer, q_len, batch, hkv, group, hd,
+                               nb, max_nb):
+    """The kernel alone on a stack of 12 layers as stored, the layer a
+    Python int or an operand (a model whose layers run under one scan):
+    GPT-2's widths, a head half a lane tile, and a grouped head of 128."""
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    pool = S((hkv, nb, BS, hd), jnp.bfloat16)
-    tables, lens = S((batch, max_nb), jnp.int32), S((batch,), jnp.int32)
-    if q_len == 1:
-        c = _compile(
-            functools.partial(paged_decode_attention, interpret=False),
-            S((batch, hkv, group, hd), jnp.bfloat16), pool, pool, tables,
-            lens)
-    else:
-        c = _compile(
-            functools.partial(paged_verify_attention, interpret=False),
-            S((batch, q_len, hkv, group, hd), jnp.bfloat16), pool, pool,
-            tables, lens, lens)
-    assert "tpu_custom_call" in c.as_text()
+    pool = S((12, nb, BS, hkv * hd), jnp.bfloat16)
+    lanes = S((batch,), jnp.int32)
+
+    def attend(q, k, v, tables, lens, qlens, starts, at):
+        return paged_attention_stored(
+            q, k, v, 7 if layer == "static_layer" else at, tables, lens,
+            qlens, starts, name="paged_decode", interpret=False)
+
+    c = _compile(attend, S((batch, q_len, hkv, group, hd), jnp.bfloat16),
+                 pool, pool, S((batch, max_nb), jnp.int32), lanes, lanes,
+                 lanes, S((), jnp.int32))
+    (call,) = [line for line in c.as_text().splitlines()
+               if "tpu_custom_call" in line]
+    assert "%paged_decode" in call.split("=")[0]
+    # (layer,) tables, lens, q lens, starts, run flags; q; K and V once.
+    assert len(call.split("custom-call(")[1].split(")")[0].split(", ")) \
+        == 8 + (layer == "traced_layer")
 
 
 def test_flash_compiles_under_a_four_device_mesh(topo, as_tpu):
@@ -185,9 +192,10 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
                                                            as_tpu):
     """The engine's own decode program, lowered for the TPU at the chat
     cell's shapes: the module is ``jit_llm_decode`` and it holds exactly
-    one Mosaic call, ``paged_decode``, which takes the layer's pool as
-    an operand, head-major (models/gpt.py makes that view of the stored
-    pool). benchmark/named_kernels.py finds the kernel by that name and
+    one Mosaic call (the twelve layers run under one scan, the layer an
+    operand of the kernel), ``paged_decode``, which takes the STACKED
+    pools as the cache stores them and no view of a layer.
+    benchmark/named_kernels.py finds the kernel by that name and
     divides its seconds by the executions of ``jit_llm_decode``: a
     renamed kernel or program would silence ``paged_kernel_ms``."""
     cfg, nb = gpt.GPT2_SMALL, CELL_NB
@@ -197,31 +205,9 @@ def test_decode_program_and_paged_kernel_carry_their_names(one_chip,
              if "@tpu_custom_call" in line]
     assert len(calls) == 1, len(calls)
     assert 'kernel_name = "paged_decode"' in calls[0]
-    assert f"tensor<{HKV}x{nb}x{BS}x{HD}xbf16>" in calls[0]
-    assert f"tensor<{cfg.n_layer}x{HKV}x{nb}x{BS}x{HD}xbf16>" \
-        not in calls[0]
-
-
-def test_gpt2_paged_wrappers_stand_on_the_lines_the_compile_cache_knows():
-    """A Mosaic kernel's serialized body carries the file and line of
-    every frame that led to its ``pallas_call`` and jax's compile cache
-    hashes it: ``paged_verify_attention`` one line up or down and the
-    chat cell's decode program compiles anew on a machine whose cache
-    holds the parent's, which its driver's set-up may not survive
-    (ROADMAP.md A7). PR 42 took 308 lines out of the file above the
-    wrappers and left them where they stood; whoever moves them does
-    it knowingly (A1b deletes the file whole)."""
-    import inspect
-
-    from ray_tpu.ops.pallas import paged_decode
-
-    firsts = {name: inspect.getsourcelines(getattr(paged_decode, name))[1]
-              for name in ("_decode_kernel", "_make_decode_call",
-                           "paged_decode_attention",
-                           "paged_verify_attention")}
-    assert firsts == {"_decode_kernel": 102, "_make_decode_call": 169,
-                      "paged_decode_attention": 530,
-                      "paged_verify_attention": 566}
+    assert calls[0].count(
+        f"tensor<{cfg.n_layer}x{nb}x{BS}x{HKV * HD}xbf16>") == 2
+    assert f"x{nb}x{BS}x{HD}xbf16>" not in calls[0]
 
 
 @pytest.fixture(scope="module")
@@ -236,13 +222,15 @@ def chat_decode(one_chip):
 def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
         chat_decode):
     """Compiled for the described v5e at the chat cell's shapes, the
-    decode program returns the lanes' argmax ids, ``s32[64,1]``, beside
-    its logits (the engine fetches those 64 ints and leaves the logits
-    on the device), and its one Mosaic call is still ``paged_decode``
-    on operands of the layer pool's shape ``[12,2560,16,64]`` (the
-    benchmark's readers go by the kernel's NAME since PR 54, never by
-    an operand). A refactor that moves either fails here, not in the
-    benchmark's traced run."""
+    decode program returns the lanes' argmax ids with its one counter's
+    row behind them, ``s32[65,1]``, beside its logits (the engine
+    fetches those 65 ints and leaves the logits on the device), and its
+    one Mosaic call is still ``paged_decode``, on the two stacked pools
+    as stored, ``[12,2560,16,768]``, each ONCE, and on nothing of a
+    layer pool's head-major shape ``[12,2560,16,64]`` (the benchmark's
+    readers go by the kernel's NAME since PR 54, never by an operand).
+    A refactor that moves either fails here, not in the benchmark's
+    traced run."""
     import re
 
     cfg = gpt.GPT2_SMALL
@@ -254,7 +242,7 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     outputs = re.findall(r"(\w+\[[\d,]*\])", root.split(" tuple(")[0])
     pools = f"bf16[{cfg.n_layer},{CELL_NB},{BS},{HKV * HD}]"
     assert outputs == [f"bf16[{CELL_B},1,{cfg.vocab_size}]",
-                       f"s32[{CELL_B},1]", pools, pools], root[:300]
+                       f"s32[{CELL_B + 1},1]", pools, pools], root[:300]
     # Beside the parameters and the two pools the program takes ONE
     # host array, the step's packed bookkeeping (one hand-over a step),
     # and ``firsts``, ``s32[64]`` from the device: the first tokens of
@@ -271,10 +259,12 @@ def test_compiled_decode_program_returns_ids_and_keeps_the_kernel_in_sight(
     assert len(calls) == 1 and "%paged_decode" in calls[0].split("=")[0]
     operands = re.findall(r"%[\w.\-]+", calls[0].split("custom-call(")[1]
                           .split(")")[0])
-    layer_pool = f"bf16[{HKV},{CELL_NB},{BS},{HD}]"
+    # layer, tables, lens, q lens, starts, run flags; q; K, V: distinct.
+    assert len(operands) == len(set(operands)) == 9, operands
     shapes = [re.search(rf"^\s*(?:ROOT )?{re.escape(o)} = (\S+?)\{{",
-                        text, re.M).group(1) for o in set(operands)]
-    assert shapes.count(layer_pool) == 2, shapes      # K's and V's pool
+                        text, re.M).group(1) for o in operands]
+    assert shapes.count(pools) == 2, shapes           # K's and V's pool
+    assert f"[{HKV},{CELL_NB},{BS},{HD}]" not in text
 
 
 LAYER_POOL = HKV * CELL_NB * BS * HD      # elements of one layer's K
@@ -300,29 +290,28 @@ def _results(text, *opcodes, at_least=LAYER_POOL):
 
 def test_compiled_decode_program_copies_the_pool_no_more_than_it_did(
         chat_decode):
-    """ROADMAP A1's pin, tightened by PR 31. Compiled for the described
-    v5e at the chat cell's shapes, the decode program (one row a lane
-    through forward_step) (a) copies the stacked pool nowhere: the
-    pools ride in the layer scan's carry and the step's rows are
-    scattered into the donated buffers; and (b) holds no ``copy`` or
-    ``transpose`` of a layer's pool or more, N = 0, in the loop body or
-    outside it. The one pass a layer's pool still costs is the paged
-    kernel's head-major operand: ``bf16[12,2560,16,64]``, allocated
-    once a pool and filled in place by one ``dynamic-update-slice``
-    fusion a head from that head's lanes of the stored pool, 2 x 12
-    fusions a layer that each write a twelfth. (The parent held eight
-    whole-pool copies, six in the loop body and two of the stack, and
-    2.93 GB of temporaries: 41.3 of its program's 54.5 ms on the chip,
-    PERF.md section 6.) A compiler's count, not a time."""
+    """ROADMAP A1's pin, tightened by PR 31 and closed by PR 59 (built by the refused PR 58).
+    Compiled for the described v5e at the chat cell's shapes, the decode
+    program (one row a lane through forward_step) (a) copies the
+    stacked pool nowhere: the pools ride in the layer scan's carry and
+    the step's rows are scattered into the donated buffers; (b) holds
+    no ``copy`` or ``transpose`` of a layer's pool or more, N = 0, in
+    the loop body or outside it; and (c) makes NO pass over a layer's
+    pool at all: the paged kernel copies its pages out of the stacked
+    pools itself, so nothing of a layer's size is sliced, joined,
+    padded or written beside the two scatters. (Until PR 59 the kernel
+    took a head-major operand ``bf16[12,2560,16,64]`` a pool, filled by
+    one ``dynamic-update-slice`` fusion a head: 24 fusions a layer,
+    1.25 s of a ~4 s trace; before PR 31 eight whole-pool copies and
+    2.93 GB of temporaries. PERF.md section 6.) A compiler's count, not
+    a time."""
     text = chat_decode.as_text()
     assert _results(text, "copy", "transpose", "copy-start") == []
     stack = f"bf16[{gpt.GPT2_SMALL.n_layer},{CELL_NB},{BS},{HKV * HD}]"
-    operand = f"bf16[{HKV},{CELL_NB},{BS},{HD}]"
     assert _results(text, "scatter") == [("scatter", stack)] * 2
-    assert _results(text, "dynamic-update-slice") == \
-        [("dynamic-update-slice", operand)] * (2 * HKV)
-    assert _results(text, "dynamic-slice", "concatenate", "pad") == []
-    assert chat_decode.memory_analysis().temp_size_in_bytes < 700e6
+    assert _results(text, "dynamic-update-slice", "dynamic-slice",
+                    "concatenate", "pad") == []
+    assert chat_decode.memory_analysis().temp_size_in_bytes < 200e6
 
 
 @pytest.mark.parametrize("program", ["kv_scatter_blocks", "kv_copy_block"])
@@ -391,17 +380,45 @@ def laguna_programs(one_chip):
     decode, chunk = _jit_programs(cfg)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = decode.lower(
+            params, S((B, step_columns(1, nbw).table + max_nb), i32),
+            full, full, window, window, q=1, firsts=S((B,), i32))
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
-            "decode": decode.lower(
-                params, S((B, step_columns(1, nbw).table + max_nb), i32),
-                full, full, window, window, q=1,
-                firsts=S((B,), i32)).compile(),
+            "decode_kernels": _kernel_bodies(lowered.as_text()),
+            "decode": lowered.compile(),
             "chunk": chunk.lower(
                 params, S((1, 512), i32), full, full,
                 S((max_nb + 512 // BS + 2,), i32), window, window,
                 S((nbw + 1 + 512 // BS,), i32)).compile(),
         }
+
+
+def _kernel_bodies(lowered_text):
+    """(kernel name, sha256 of its body) of each Mosaic call of a
+    LOWERED program, in program order: the body is the kernel's own
+    MLIR module, which the call carries serialized, printed without
+    source locations (so a kernel that moved in its file, or whose
+    callers did, reads the same)."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True      # ``stable_mosaic``
+    found = []
+    with ctx:
+        for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                               lowered_text):
+            module = ir.Module.parse(base64.b64decode(body))
+            asm = module.operation.get_asm(enable_debug_info=False)
+            name = re.match(r"module @(\w+)", asm).group(1)
+            found.append((name, hashlib.sha256(asm.encode())
+                          .hexdigest()[:16]))
+    return found
 
 
 def _mosaic_lines(text):
@@ -984,11 +1001,13 @@ def nemotron_programs(one_chip):
     decode, chunk = _jit_programs(cfg)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = decode.lower(
+            params, S((B, step_columns(1, 0, True).table + max_nb), i32),
+            kv, kv, *state, q=1, firsts=S((B,), i32))
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
-            "decode": decode.lower(
-                params, S((B, step_columns(1, 0, True).table + max_nb), i32),
-                kv, kv, *state, q=1, firsts=S((B,), i32)).compile(),
+            "decode_kernels": _kernel_bodies(lowered.as_text()),
+            "decode": lowered.compile(),
             # block table, 32 blocks written, ctx_len, last, two slots
             "chunk": chunk.lower(
                 params, S((1, 512), i32), kv, kv,
@@ -1059,3 +1078,33 @@ def test_nemotron_chunk_program_scans_from_a_slot_into_a_slot(
     assert _aliased(text) == {n + 1: 2, n + 2: 3, n + 4: 4, n + 5: 5}
     assert "[512,16384]" not in text and "[1,512,16384]" not in text
     assert c.memory_analysis().temp_size_in_bytes < 250e6
+
+
+# -- the stored kernels at head_dim 128 are what they were (PR 59) ------------
+
+STORED_KERNELS_AT_PR57 = {
+    "laguna": [("attn_full", "c1f3574976d76470"),
+               ("attn_window", "90ce8d54296f1d30"),
+               ("attn_window", "984c78dcf0dcb15f"),
+               ("attn_window", "ed4595bb099930fa"),
+               ("attn_full", "add6a59ba60f7c17")],
+    "nemotron": [("attn_full", "2fb8c21143f770b5")],
+}
+
+
+@pytest.mark.parametrize("model", ["laguna", "nemotron"])
+def test_the_stored_kernels_at_head_dim_128_lower_to_what_they_did(
+        model, request):
+    """PR 59 gave ``paged_attention_stored`` GPT-2's shape (a head half
+    a lane tile) and a layer index that may be traced; Laguna's and
+    Nemotron's callers still pass a Python int and whole-tile heads,
+    and their decode programs at the cells' shapes lower ``attn_full``
+    and ``attn_window`` to the kernels they lowered to at the parent
+    commit: each Mosaic body printed without source locations, sha256,
+    recorded at PR 57's tree with this function. So the pages, runs and
+    arithmetic PR 42 measured stand; whoever changes the d = 128 path
+    records the new text knowingly."""
+    programs = request.getfixturevalue(f"{model}_programs")
+    stored = [(name, sha) for name, sha in programs["decode_kernels"]
+              if name.startswith("attn_")]
+    assert stored == STORED_KERNELS_AT_PR57[model]
