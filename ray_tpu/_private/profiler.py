@@ -20,6 +20,7 @@ image, and both need ptrace; instead processes SELF-sample:
 
 from __future__ import annotations
 
+import os
 import sys
 import threading
 import time
@@ -159,10 +160,131 @@ def render_flamegraph_svg(folded: str, title: str = "rtpu flamegraph",
 
 
 # ---------------------------------------------------------------------------
+# The process's CPU by thread
+# ---------------------------------------------------------------------------
+# The threads a serving or training process is known to run, by the
+# prefix of their names; a Python thread of any other name is in
+# ``other``, a task of the process that is no Python thread (the XLA
+# and TPU runtimes', a native store's) in ``native``.
+THREAD_GROUPS = ("serve-http", "serve-stream-poll", "llm-engine",
+                 "rt-core-loop", "actor", "device-exec", "asyncio",
+                 "MainThread", "train-loop")
+_threads_lock = threading.Lock()
+_threads_seen: dict = {}     # tid -> (group, cpu ns, wait ns)
+_threads_gone: dict = {}     # group -> [cpu ns, wait ns] of ended threads
+
+
+def _task_times(tid: str):
+    """(on-CPU ns, run-queue wait ns or None) of one task of this
+    process: the scheduler's own record where the kernel keeps it
+    (``schedstat``), else ``utime + stime`` in clock ticks and no wait;
+    None for a task that ended meanwhile."""
+    try:
+        with open(f"/proc/self/task/{tid}/schedstat") as f:
+            run_ns, wait_ns, _ = f.read().split()
+        return int(run_ns), int(wait_ns)
+    except (OSError, ValueError):
+        pass
+    try:
+        with open(f"/proc/self/task/{tid}/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        ticks = int(fields[11]) + int(fields[12])
+        return ticks * 10**9 // os.sysconf("SC_CLK_TCK"), None
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def thread_cpu() -> dict:
+    """The process's CPU time by thread group, cumulative: two readings
+    give a window's, and who had the cores in it.
+
+      by_group       {group: {"threads": live threads in it now,
+                     "cpu_s": on-CPU seconds, "wait_s": seconds
+                     runnable and waiting for a core (None where the
+                     kernel keeps no ``schedstat``)}}; a group is a
+                     name of ``THREAD_GROUPS`` that the thread's name
+                     starts with, else ``other``; ``native`` for tasks
+                     that are no Python thread
+      process_cpu_s  ``time.process_time()`` at the reading, which the
+                     groups' growth should add up to
+
+    A thread that has ended keeps what it had used when it was last
+    seen, so a group only grows. Read where somebody asks
+    (``engine_stats()``, a ``device_profile`` capture), never in a
+    step: a reading opens a file a task."""
+    names = {str(t.native_id): t.name for t in threading.enumerate()
+             if t.native_id is not None}
+    try:
+        tids = os.listdir("/proc/self/task")
+    except OSError:
+        tids = []
+    live: dict = {}
+    for tid in tids:
+        times = _task_times(tid)
+        if times is None:
+            continue
+        name = names.get(tid)
+        group = "native" if name is None else next(
+            (g for g in THREAD_GROUPS if name.startswith(g)), "other")
+        live[tid] = (group, *times)
+    # One kernel, one answer: a wait for every task or for none.
+    waits = all(wait is not None for _, _, wait in live.values())
+    totals: dict = {}           # group -> [live threads, cpu ns, wait ns]
+    with _threads_lock:
+        for tid, (group, cpu, wait) in _threads_seen.items():
+            now = live.get(tid)
+            if now is None or now[0] != group or now[1] < cpu:
+                # Ended (or its id is another thread's now): what it
+                # had used stays in its group.
+                gone = _threads_gone.setdefault(group, [0, 0])
+                gone[0] += cpu
+                gone[1] += wait or 0
+        _threads_seen.clear()
+        _threads_seen.update(live)
+        for group, (cpu, wait) in _threads_gone.items():
+            totals[group] = [0, cpu, wait]
+    for group, cpu, wait in live.values():
+        total = totals.setdefault(group, [0, 0, 0])
+        total[0] += 1
+        total[1] += cpu
+        total[2] += wait or 0
+    return {"by_group": {
+                group: {"threads": n, "cpu_s": cpu / 1e9,
+                        "wait_s": wait / 1e9 if waits else None}
+                for group, (n, cpu, wait) in totals.items()},
+            "process_cpu_s": time.process_time()}
+
+
+def format_thread_cpu(a: dict, b: dict) -> str:
+    """Two ``thread_cpu`` readings of one process as `rtpu profile
+    --device` prints them: CPU seconds a second of the window by group,
+    busiest first, and the same for the wait for a core."""
+    span = b["process_cpu_s"] - a["process_cpu_s"]
+    rows = []
+    for group, now in b["by_group"].items():
+        was = a["by_group"].get(group, {"cpu_s": 0.0, "wait_s": 0.0})
+        wait = (None if now["wait_s"] is None or was["wait_s"] is None
+                else now["wait_s"] - was["wait_s"])
+        rows.append((now["cpu_s"] - was["cpu_s"], group, now["threads"],
+                     wait))
+    rows.sort(key=lambda r: -r[0])
+    total = sum(r[0] for r in rows)
+    lines = [f"  CPU by thread: {total:.3f} s on the cores (the process's "
+             f"CPU clock: {span:.3f} s), by group (threads, CPU s, s "
+             f"runnable and waiting for a core):"]
+    for cpu, group, n, wait in rows:
+        lines.append(f"    {group:<20} {n:4d} {cpu:9.3f} "
+                     + ("        -" if wait is None else f"{wait:9.3f}"))
+    return "\n".join(lines)
+
+
+# ---------------------------------------------------------------------------
 # Gang-coordinated device capture (the `rtpu profile --device` unit)
 # ---------------------------------------------------------------------------
-# Each process answers a ``device_profile`` RPC with four layers for
+# Each process answers a ``device_profile`` RPC with five layers for
 # the window:
+#   * threads — the process's CPU by thread group at the window's two
+#     edges (``thread_cpu``): who had the cores while the device idled.
 #   * device_steps — the deterministic spine: every accounted engine /
 #     train step from the perfmodel ring (name, wall time, device/host
 #     split, phases, counts, MFU, verdict). Always present, backend or
@@ -355,8 +477,9 @@ def _interval_line(e: dict) -> str:
     all ms: the gap before the step, its device spans by kind
     (dispatch / wait where the span was cut), its host phases over
     1 ms, the collector's passes that ended in it, the thread's CPU
-    time and the time it had work and was not running, and the step's
-    load."""
+    time and the time it had work and was not running, the interpreter
+    probe's long samples in it (the process stood still, or a thread
+    kept the interpreter), and the step's load."""
     parts = [f"between {e.get('between_ms', 0.0):.1f} (lock "
              f"{e['lock_wait_ms']:.1f}, idle {e['idle_ms']:.1f})"]
     dispatch = e["dispatch_ms_by"]
@@ -370,6 +493,9 @@ def _interval_line(e: dict) -> str:
     if e.get("gc_gen") is not None:
         line += f"; gc {e['gc_ms']:.1f} (gen {e['gc_gen']})"
     line += f"; cpu {e['cpu_ms']:.1f}, stall {e['stall_ms']:.1f}"
+    if "standstill_ms" in e:
+        line += (f"; standstill {e['standstill_ms']:.1f}, held long "
+                 f"{e['held_long_ms']:.1f}")
     if "lanes" in e:
         line += (f"; lanes {e['lanes']}, chunk tokens "
                  f"{e['prefill_tokens']}, arrived {e.get('arrived', 0)}")
@@ -382,8 +508,9 @@ def format_device_steps(steps: list) -> str:
     step name and owner: the mean step split into its device spans by
     kind (and the dispatch inside each) and its host phases by name,
     an engine's own counts (``programs`` / ``programs_queued`` among
-    them), what lies between steps, and the window's
-    five longest step intervals, each by its parts
+    them), the interpreter probe's totals over the window
+    (``perfmodel._InterpreterProbe``), what lies between steps, and the
+    window's five longest step intervals, each by its parts
     (``_interval_line``)."""
     from ray_tpu.util import perfmodel
 
@@ -438,6 +565,22 @@ def format_device_steps(steps: list) -> str:
                     f", {sum(e.get('programs_queued', 0) for e in evs)} "
                     f"queued before their step's first wait")
         timed = [e for e in evs if "interval_ms" in e]
+        probed = [e for e in timed if e.get("interp_n")]
+        if probed:
+            n = sum(e["interp_n"] for e in probed)
+
+            def total(key):
+                return sum(e[key] for e in probed)
+
+            lines.append(
+                f"    interpreter probe: {n} samples, "
+                f"{total('interp_late_ms') / n:.2f} ms late a sample "
+                f"(longest "
+                f"{max(e['interp_late_max_ms'] for e in probed):.1f}), "
+                f"{100.0 * total('interp_held_n') / n:.1f}% found it "
+                f"held; the process stood still "
+                f"{total('standstill_ms'):.1f} ms, a thread kept the "
+                f"interpreter long {total('held_long_ms'):.1f} ms")
         if timed:
             lines.append(
                 f"    between steps {mean('between_ms'):.2f} (lock "
@@ -512,7 +655,7 @@ def device_profile(duration_s: float = 2.0, hz: float = 99.0,
                    include_jax: bool = True) -> dict:
     """One capture window for THIS process: start an XLA profiler trace
     session, run the host sampling profiler for the window, stop the
-    trace, and return all four layers plus the process's wall clock at
+    trace, and return all five layers plus the process's wall clock at
     the window edges (the driver's clock-alignment anchors)."""
     import shutil
     import tempfile
@@ -520,6 +663,7 @@ def device_profile(duration_s: float = 2.0, hz: float = 99.0,
     from ray_tpu.util import perfmodel
 
     t0_wall = time.time()
+    threads0 = thread_cpu()
     tmpdir = None
     jax_err = None
     if include_jax:
@@ -551,6 +695,7 @@ def device_profile(duration_s: float = 2.0, hz: float = 99.0,
         "t0_wall": t0_wall,
         "t1_wall": time.time(),
         "host": host,
+        "threads": (threads0, thread_cpu()),
         "device_steps": perfmodel.device_step_events(since=t0_wall - 1.0),
         "idle_gaps": gaps,
         "jax_trace": jax_trace,

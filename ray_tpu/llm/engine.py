@@ -894,9 +894,14 @@ class LLMEngine:
             with perf.dispatch("llm.prefill.device") as span:
                 row, tok, *pools = self._prefill_chunk(
                     self.params, toks, *self.kv.pools, table, *window)
+            with perf.phase("llm.pools"):
+                # Here, under its name, and not when this function
+                # returns: the window kind's old arrays live on in
+                # ``window``.
+                self._take_back(pools)
+                del window, pools
             fetch_now = False
             with perf.phase("llm.prefill.host"):
-                self._take_back(pools)
                 req.prefilled_upto = upto + c
                 req.context_len = req.prefilled_upto
                 self._prefill_chunks += 1
@@ -1028,7 +1033,11 @@ class LLMEngine:
 
     def _take_back(self, pools):
         """The pools a program was donated, as it returned them written:
-        the full kind's, then the window kind's or the state's."""
+        the full kind's, then the window kind's or the state's. The
+        arrays that were given away lose their last reference here or
+        soon after, while the program that took them is still in the
+        device's queue: freeing them then is not free (``llm.pools``:
+        a third of Laguna's host gap was this, under no name)."""
         n = len(self.kv.pools)
         self.kv.pools = tuple(pools[:n])
         if self.kv_window is not None:
@@ -1309,7 +1318,8 @@ class LLMEngine:
             logits, ids, *pools = self._decode(
                 self.params, self._inputs, *self.kv.pools, *window, q=Q,
                 firsts=firsts)
-        self._take_back(pools)
+        with perf.phase("llm.pools"):
+            self._take_back(pools)
         self._settle()
         with span.waiting():
             jax.block_until_ready(ids)
@@ -1627,10 +1637,13 @@ class LLMEngine:
             "tokens_decided_on_host": self._decided["host"],
             # Cumulative: how long, and how often, the loop slept on an
             # empty engine; the process's collector, {generation:
-            # [passes, seconds]}. Two readings give a window's.
+            # [passes, seconds]}; the process's interpreter probe
+            # (samples, their lateness, how many found it held, long
+            # ones by standstill and held). Two readings give a window's.
             "idle_s": self._step_perf.idle_total_s,
             "idle_waits": self._step_perf.idle_waits,
             "gc": perfmodel.gc_totals(),
+            "interp": perfmodel.interp_totals(),
         }
         if self._prefix:
             ps = self.kv.prefix_stats()

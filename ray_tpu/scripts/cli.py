@@ -683,7 +683,8 @@ def cmd_profile(args):
 
     from ray_tpu._private.profiler import (build_merged_trace,
                                            format_device_steps,
-                                           format_idle_gaps)
+                                           format_idle_gaps,
+                                           format_thread_cpu)
     from ray_tpu.util import state
 
     t0 = time.time()
@@ -716,13 +717,17 @@ def cmd_profile(args):
     print("open in chrome://tracing or https://ui.perfetto.dev")
     # Where the steps went and why the chip waited: each process that
     # ran steps splits them by span (util/perfmodel.PHASES), and each
-    # that traced a device names the host span over its idle gaps.
+    # that traced a device names the host span over its idle gaps;
+    # every process says which of its threads had the cores.
     for source in captured:
         steps = profs[source].get("device_steps")
         gaps = profs[source].get("idle_gaps")
-        if not steps and not (gaps and gaps["idle_s"] > 0):
+        threads = profs[source].get("threads")
+        if not steps and not threads and not (gaps and gaps["idle_s"] > 0):
             continue
         print(f"{source}:")
+        if threads:
+            print(format_thread_cpu(*threads))
         if steps:
             print(format_device_steps(steps))
         if gaps and gaps["idle_s"] > 0:

@@ -521,6 +521,8 @@ class Router:
         ends when the replica has no live stream of this router."""
         import ray_tpu
 
+        from .replica import REPLY_SENT
+
         while True:
             with self._lock:
                 ends = self._stream_ends.get(key)
@@ -538,41 +540,53 @@ class Router:
                     ends = self._stream_ends.pop(key, {})
                 self._deal([(end, ((), True, e)) for end in ends.values()])
                 return
+            t_reply = reply.pop(REPLY_SENT, None)
             with self._lock:
                 dealt = [(ends[sid], share) for sid, share in reply.items()
                          if sid in ends]
                 for sid, (_, done, error) in reply.items():
                     if done or error is not None:
                         ends.pop(sid, None)
-            self._deal(dealt)
+            self._deal(dealt, t_reply)
 
-    def _deal(self, dealt):
+    def _deal(self, dealt, t_reply: Optional[float] = None):
         """One reply's shares to their ends: a thread that waits in
         ``take`` is woken by its end, and the ends that a loop reads
-        are pumped by ONE callback on it, whatever their number."""
+        are pumped by ONE callback on it, whatever their number.
+        ``t_reply``: when the reply left the replica (wall clock)."""
         by_loop: dict = {}
         for end, share in dealt:
             if (loop := end.deal(*share)) is not None:
                 by_loop.setdefault(loop, []).append(end)
         for loop, ends in by_loop.items():
             try:
-                loop.call_soon_threadsafe(self._flush, ends)
+                loop.call_soon_threadsafe(self._flush, ends, t_reply)
             except RuntimeError:  # lint: allow-swallow(the loop is closed: its readers are gone)
                 pass
 
-    def _flush(self, ends):
+    def _flush(self, ends, t_reply: Optional[float] = None):
         """On a reader's loop, once a reply: every sink takes its
         stream's share. ``proxy_flush`` counts the wakes (``stream_hold``
-        counts the chunks, so the two give chunks a wake)."""
+        counts the chunks, so the two give chunks a wake);
+        ``stream_out`` is the reply's whole way from the replica to the
+        end of this callback."""
         import time as _time
+
+        from ray_tpu.util import perfmodel
 
         from . import slo
 
+        ann = perfmodel.session_annotation("serve.flush")
         t0 = _time.perf_counter()
         for end in ends:
             end.pump()
         slo.record_phase("proxy_flush", _time.perf_counter() - t0,
                          self._name)
+        if t_reply is not None:
+            slo.record_phase("stream_out", _time.time() - t_reply,
+                             self._name)
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def remove_replica(self, key):
         """Drop a replica observed dead so the retry (and subsequent

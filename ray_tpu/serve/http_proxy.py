@@ -34,11 +34,14 @@ class _StreamWriter:
     deadline is one timer a stream, re-armed when it fires from the
     time of the last frame."""
 
-    def __init__(self, end, request, writer, root, timeout_s: float):
+    def __init__(self, end, request, writer, root, timeout_s: float,
+                 arrived: Optional[tuple] = None):
         self.loop = asyncio.get_running_loop()
         self.finished = self.loop.create_future()
         self._end, self._request, self._writer = end, request, writer
         self._root, self._timeout_s = root, timeout_s
+        # (the handler's arrival stamp, time.perf_counter(); deployment)
+        self._arrived = arrived
         self._first = True
         self._drain = None  # the drain waiter, while the sink is paused
         self._last = self.loop.time()
@@ -49,12 +52,12 @@ class _StreamWriter:
             return False
         self._last = self.loop.time()
         if chunks:
-            if self._first and self._root is not None:
+            first, self._first = self._first, False
+            if first and self._root is not None:
                 # TTFT on the root span: arrival -> first streamed
                 # chunk reaches the proxy.
                 self._root.add_event(
                     "ttft", ms=(time.time() - self._root.start) * 1e3)
-            self._first = False
             try:
                 # Without the drain ``write`` awaits nothing: run to its
                 # end here, it leaves the frames with the transport.
@@ -66,6 +69,15 @@ class _StreamWriter:
                 pass
             except Exception as e:  # noqa: BLE001 - a chunk JSON cannot encode, a connection that is gone: the handler raises it, the loop's other streams go on
                 return self._finish(e)
+            if first and self._arrived is not None:
+                # The server's whole first token: the handler's arrival
+                # to the first frame with the transport.
+                from . import slo
+
+                t_arrive, deployment = self._arrived
+                slo.record_phase(
+                    "proxy_ttft", time.perf_counter() - t_arrive, deployment,
+                    trace_id=getattr(self._root, "trace_id", None))
         if ended:
             return self._finish(error)
         if self._request.protocol.writing_paused:
@@ -281,7 +293,8 @@ class HTTPProxy:
         from .replica import STREAM_MARKER
 
         if isinstance(result, dict) and STREAM_MARKER in result:
-            return await self._stream(request, resp, result, root)
+            return await self._stream(request, resp, result, root,
+                                      (t_arrive, handle._name))
         if is_asgi and isinstance(result, dict) and "status" in result:
             from multidict import CIMultiDict
 
@@ -296,7 +309,8 @@ class HTTPProxy:
                                 headers=hdrs)
         return web.json_response(result)
 
-    async def _stream(self, request, resp, result, root=None):
+    async def _stream(self, request, resp, result, root=None,
+                      arrived: Optional[tuple] = None):
         """Chunked transfer of a generator response: each chunk is a raw
         bytes frame or one newline-delimited JSON document. The frames
         are written where the handle's poller hands them to this loop
@@ -312,7 +326,7 @@ class HTTPProxy:
         writer = await sr.prepare(request)
         end = resp.open_stream(result)
         out = _StreamWriter(end, request, writer, root,
-                            self.request_timeout_s)
+                            self.request_timeout_s, arrived)
         try:
             end.attach(out.loop, out)
             timed_out = await out.finished

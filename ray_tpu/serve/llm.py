@@ -251,6 +251,11 @@ class _LLMServer:
         # Cumulative sum/count a phase (ttft, tpot, engine_queue,
         # stream_hold, ...): two readings give a window's mean.
         out["phase_hist"] = slo.phase_hist(self.engine.name)
+        # Cumulative CPU seconds (and wait for a core) by thread group
+        # of this process, the replica's: two readings give a window's.
+        from ray_tpu._private.profiler import thread_cpu
+
+        out["threads"] = thread_cpu()
         return out
 
     def check_health(self) -> bool:

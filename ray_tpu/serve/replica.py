@@ -42,6 +42,12 @@ STREAM_RUN_AHEAD = 16
 # a caller whose last stream went away stops holding a call slot.
 STREAM_POLL_LIMIT_S = 1.0
 
+# Key of a stream_poll reply beside its sids: the wall clock at which
+# the reply left the replica (``_record_reply``), from which its reader
+# times the reply's way out (the ``stream_out`` phase), as ``submit_ts``
+# is carried the other way.
+REPLY_SENT = "t_reply"
+
 
 def _with_model_id(gen, model_id: str):
     """Run each next() of a parked generator under the request's
@@ -234,8 +240,9 @@ class Replica:
                        multiplexed_model_id: str = "",
                        submit_ts: float = 0.0,
                        trace_ctx: Optional[dict] = None) -> Any:
-        from ray_tpu.util import tracing
+        from ray_tpu.util import perfmodel, tracing
 
+        ann = perfmodel.session_annotation("serve.handle_request")
         trace_id = (trace_ctx or {}).get("trace_id")
         if submit_ts:
             # Handle-side submit stamp -> here: actor-lane queueing.
@@ -317,6 +324,8 @@ class Replica:
                 self._ongoing -= 1
             slo.set_queue_depth(self._ongoing + len(self._streams),
                                 self._deployment)
+            if ann is not None:
+                ann.__exit__(None, None, None)
 
     def _book(self, stream: _Stream) -> int:
         """Put a new stream on the books under the next sid."""
@@ -337,20 +346,26 @@ class Replica:
         return ([c for c, _ in taken], [t for _, t in taken], done,
                 stream.error if done else None)
 
-    def _record_reply(self, t_call: float, yielded: list):
+    def _record_reply(self, t_call: float, yielded: list) -> float:
         """One reply left: its duration, and how long each chunk it
-        carries sat here since the generator yielded it."""
-        t_reply = time.perf_counter()
+        carries sat here since the generator yielded it. Returns the
+        instant on the wall clock, for the reply to carry."""
+        t_reply, t_wall = time.perf_counter(), time.time()
         slo.record_phase("stream_pull", t_reply - t_call, self._deployment)
         slo.record_phases("stream_hold", [t_reply - t for t in yielded],
                           self._deployment)
+        return t_wall
 
     def stream_poll(self, caller_id: str, grants: Optional[dict] = None):
         """{sid: (chunks, done, error)} — everything the streams of
         ``caller_id`` have ready, as soon as any of them has anything
-        (empty after STREAM_POLL_LIMIT_S with nothing). ``grants`` is
-        {sid: count}: how far each stream may now yield (what its reader
-        has consumed plus its run-ahead bound)."""
+        (empty after STREAM_POLL_LIMIT_S with nothing), and, where it
+        carries anything, under ``REPLY_SENT`` the wall clock at which
+        the reply left. ``grants``
+        is {sid: count}: how far each stream may now yield (what its
+        reader has consumed plus its run-ahead bound)."""
+        from ray_tpu.util import perfmodel
+
         t_call = time.perf_counter()
         deadline = t_call + STREAM_POLL_LIMIT_S
         reply, yielded = {}, []
@@ -365,12 +380,18 @@ class Replica:
                 if ready or wait <= 0:
                     break
                 self._stream_cond.wait(wait)
+            # From here the poll is code that runs, not a wait.
+            ann = perfmodel.session_annotation("serve.stream_poll")
             for stream in ready:
                 chunks, times, done, error = self._take(
                     stream, len(stream.ready))
                 reply[stream.sid] = (chunks, done, error)
                 yielded += times
-        self._record_reply(t_call, yielded)
+        t_sent = self._record_reply(t_call, yielded)
+        if reply:
+            reply[REPLY_SENT] = t_sent
+        if ann is not None:
+            ann.__exit__(None, None, None)
         return reply
 
     def stream_next(self, sid: int, max_chunks: int = 16):

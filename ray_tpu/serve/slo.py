@@ -39,6 +39,23 @@ continuous-batching autoscaler consumes (ROADMAP item 1):
                           count is the number of wakes of the loop that
                           streams cost, so ``stream_hold``'s count over
                           it is chunks a wake
+  * ``stream_out``      — beside ``proxy_flush``, once a ``stream_poll``
+                          reply that a loop reads: from the instant the
+                          reply left the replica (``_record_reply``'s
+                          wall clock, carried in the reply) to the end
+                          of the callback that wrote its shares: result
+                          serialisation, the runtime's loop, the
+                          poller's wake, ``call_soon_threadsafe``, the
+                          loop's wake, encode and ``send``
+  * ``proxy_ttft``      — once a streamed HTTP request: the handler's
+                          arrival stamp (where ``proxy_queue`` starts)
+                          to its first frame written to the socket. The
+                          server's whole first token on one clock:
+                          ``proxy_queue`` + ``replica_queue`` +
+                          ``execute`` + ``ttft`` + the first chunk's
+                          ``stream_hold`` + ``stream_out``, and what
+                          they leave over is the stream's attach
+                          (``open_stream``, ``stream_grant``)
 
 Two sinks per observation, both cheap (a bucket increment under one
 lock):
@@ -73,7 +90,7 @@ PHASE_BOUNDS: List[float] = [
 # (decode cadence) — the two numbers an LLM serving SLO is written in.
 PHASES = ("proxy_queue", "replica_queue", "batch_wait", "execute",
           "ttft", "tpot", "engine_queue", "stream_pull", "stream_hold",
-          "proxy_flush")
+          "proxy_flush", "stream_out", "proxy_ttft")
 
 _lock = threading.Lock()
 # Deployment hosted by THIS process (set by Replica.__init__).

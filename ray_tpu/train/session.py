@@ -15,7 +15,8 @@ closes the step, report to report, on the session's
 ``train_step_ms``/``train_device_ms``/``train_host_gap_ms``/
 ``train_mfu``/``train_hbm_util`` and the split of the device span
 (``train_dispatch_ms`` + ``train_ready_wait_ms``) and of the host's part
-(``train_data_wait_ms``), the same values ride the worker metrics
+(``train_data_wait_ms``), and the step's share of a standstill of the
+machine (``train_standstill_ms``), the same values ride the worker metrics
 flusher into head telemetry series (``train_mfu:<trial>``, ...), and
 every step lands in the perfmodel device-step ring where
 ``rtpu profile --device`` collects it.
@@ -122,6 +123,10 @@ class _TrainSession:
             "train_ready_wait_ms": step["device_ms_by"].get("wait", 0.0),
             "train_data_wait_ms": step["phases_ms"].get(
                 "data.next_batch", 0.0),
+            # What the interpreter probe put down to the step as the
+            # machine's: it woke over 50 ms late and the process's CPU
+            # clock had stood still (perfmodel._InterpreterProbe).
+            "train_standstill_ms": step["standstill_ms"],
         }
         if "mfu" in step:       # only with a peak: never on the CPU
             out.update(train_mfu=step["mfu"],

@@ -325,6 +325,10 @@ PHASES: Dict[str, tuple] = {
                                  "block boundary: the prefix index's "
                                  "entry and the dispatch of the slot's "
                                  "copy"),
+    "llm.pools": (None, "the pools a program was donated taken back as it "
+                        "returned them, right after its dispatch: the step "
+                        "drops its references to the arrays it gave away, "
+                        "which frees them while that program is in flight"),
     "llm.slots": (None, "writable KV slots for every decode lane: block "
                         "grants, copy-on-write, preemption"),
     "llm.decode.build": (None, "the decode (or verify) program's input "
@@ -369,6 +373,15 @@ _OWN_INTERVALS = {
                         "chunk's result, and the wake-up",
     "py.gc": "one pass of the interpreter's collector, on the thread that "
              "ran it (gc.callbacks)",
+    "serve.handle_request": "a replica's call slot in user code for one "
+                            "request (Replica.handle_request): for a "
+                            "generation deployment, add_request",
+    "serve.stream_poll": "a stream poll's reply being made "
+                         "(Replica.stream_poll), from its wake with "
+                         "something to carry to its return",
+    "serve.flush": "one callback of a reader's loop (Router._flush): "
+                   "every stream of a poll's reply has its share encoded "
+                   "and written to its socket",
 }
 PHASES.update((name, (None, what)) for name, what in _OWN_INTERVALS.items())
 ANNOTATIONS = frozenset(_OWN_INTERVALS)
@@ -386,8 +399,10 @@ def _session_open() -> bool:
     """Is a ``jax.profiler`` session open in this process? False where
     jax is not imported: a CPU-lane worker must not pay the import for
     a span nobody reads."""
-    jax = sys.modules.get("jax")
-    return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
+    # No attribute yet while jax is half imported on some thread (a
+    # collection inside the import runs the collector's hook).
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return profiler is not None and profiler.TraceAnnotation.is_enabled()
 
 
 def _annotation(name: str):
@@ -457,6 +472,108 @@ def gc_totals() -> Dict[int, list]:
     process's first accounted step (cumulative: two readings give a
     window's)."""
     return {gen: list(t) for gen, t in _COLLECTOR.totals.items()}
+
+
+def session_annotation(name: str):
+    """A ``TraceAnnotation`` under a name of ``ANNOTATIONS`` that starts
+    now, for a block of code on a thread no StepAccounting drives (the
+    serving threads' bodies); None, and nothing built, while no
+    ``jax.profiler`` session is open. The caller ends it with
+    ``__exit__``."""
+    if name not in ANNOTATIONS:
+        raise KeyError(f"{name!r} is not in perfmodel.ANNOTATIONS")
+    return _annotation(name) if _session_open() else None
+
+
+#: The interpreter probe's period: it asks to sleep this long, and what
+#: it sleeps longer is what it waited for the interpreter (or a core).
+INTERP_PERIOD_S = 0.02
+#: A sample later than this was HELD: somebody had the interpreter when
+#: the probe woke. Set from an idle process's lateness on the
+#: benchmark's host, whose timer alone wakes a sleeper 0.7 ms late
+#: (PERF.md section 6, PR 60: 3,000 idle samples, mean 0.68-0.71 ms,
+#: 99th percentile 1.05, one in 125 over 1.0, the largest 1.14 but for
+#: one of 90.7).
+INTERP_HELD_FLOOR_S = 0.0015
+#: A sample this late is a pause somebody will look for by name: its
+#: lateness goes to ``standstill_ms`` where the PROCESS's CPU clock
+#: advanced by under ``_STANDSTILL_CPU_SHARE`` of the sample's wall time
+#: (nobody in the process ran: the OS or the machine had it), else to
+#: ``held_long_ms`` (a thread of the process kept the interpreter).
+INTERP_LONG_S = 0.05
+_STANDSTILL_CPU_SHARE = 0.1
+
+
+class _InterpreterProbe:
+    """Whether a thread that wakes with work to do could have the
+    interpreter, process-wide: one daemon thread, started by the first
+    step any StepAccounting begins, sleeps ``INTERP_PERIOD_S`` and reads
+    how much later than asked it runs again. Asleep it holds nothing;
+    to run again it needs the interpreter like a poll with a reply to
+    carry, a request's executor hand-off or the engine after the
+    device, so its lateness is what any of them pays at that instant. A
+    sample costs two clock readings (the second the process's CPU
+    clock, a system call). Counts are cumulative; a reader takes two
+    readings (``StepAccounting.finish`` does, an interval)."""
+
+    def __init__(self):
+        self._start_lock = threading.Lock()
+        self.thread: Optional[threading.Thread] = None
+        # (samples, late s, held samples, standstill s, held-long s):
+        # replaced whole by the probe's thread once a sample, so ONE
+        # read gives a reader a consistent set.
+        self.totals = (0, 0.0, 0, 0.0, 0.0)
+        # The last samples' (number, late s), for an interval's longest.
+        self.recent: collections.deque = collections.deque(maxlen=32)
+
+    def start(self):
+        with self._start_lock:
+            if self.thread is None:
+                self.thread = threading.Thread(
+                    target=self._run, daemon=True, name="interp-probe")
+                self.thread.start()
+
+    def _run(self):
+        period = INTERP_PERIOD_S
+        n = held = 0
+        late_s = standstill_s = held_long_s = 0.0
+        cpu = time.process_time()
+        t = time.perf_counter()
+        while True:
+            time.sleep(period)
+            now = time.perf_counter()
+            cpu_now = time.process_time()
+            late = max(0.0, now - t - period)
+            n += 1
+            late_s += late
+            if late > INTERP_HELD_FLOOR_S:
+                held += 1
+                if late >= INTERP_LONG_S:
+                    if cpu_now - cpu < _STANDSTILL_CPU_SHARE * (now - t):
+                        standstill_s += late
+                    else:
+                        held_long_s += late
+            self.recent.append((n, late))
+            self.totals = (n, late_s, held, standstill_s, held_long_s)
+            # Read again: what the bookkeeping above took (or waited
+            # for) is not the next sample's lateness.
+            cpu, t = time.process_time(), time.perf_counter()
+
+
+_PROBE = _InterpreterProbe()
+
+
+def interp_totals() -> dict:
+    """The interpreter probe's cumulative counts since this process's
+    first accounted step (two readings give a window's): ``n`` samples,
+    their summed lateness ``late_s``, ``held_n`` of them later than
+    ``INTERP_HELD_FLOOR_S``, and of those at least ``INTERP_LONG_S``
+    late ``standstill_s`` (the process's CPU clock stood still) and
+    ``held_long_s`` (it ran)."""
+    n, late_s, held, standstill_s, held_long_s = _PROBE.totals
+    return {"n": n, "late_s": late_s, "held_n": held,
+            "standstill_s": standstill_s, "held_long_s": held_long_s,
+            "period_s": INTERP_PERIOD_S}
 
 
 class _Span:
@@ -610,6 +727,19 @@ class StepAccounting:
                     inside the interval, whichever thread ran them:
                     their sum, the longest, the oldest generation
                     (None: no pass)
+      interp_n / interp_late_ms / interp_late_max_ms / interp_held_n
+                    the interpreter probe's samples that ended inside
+                    the interval (``_InterpreterProbe``): how many,
+                    their summed and their longest lateness, and how
+                    many were later than INTERP_HELD_FLOOR_S: what a
+                    thread of this process that woke with work to do
+                    waited for the interpreter
+      standstill_ms / held_long_ms   the lateness of the samples at
+                    least INTERP_LONG_S late, by whether the PROCESS's
+                    CPU clock stood still meanwhile (the OS or the
+                    machine had the process) or ran (a thread kept the
+                    interpreter: a collection's pass, which gc_max_ms
+                    of the same entry then matches)
       tokens / flops / hbm_bytes and, with a peak, mfu / hbm_util /
       verdict / hardware
     """
@@ -619,7 +749,8 @@ class StepAccounting:
                  "_phase_s", "_spans", "_flops", "_hbm_bytes",
                  "_tokens", "_finish_t", "_finish_cpu", "_between_s",
                  "_between", "_gap_ann", "_idle_s", "_lock_wait_s",
-                 "_gc_seen", "_gc_s", "_programs", "_programs_queued",
+                 "_gc_seen", "_gc_s", "_interp_seen", "_programs",
+                 "_programs_queued",
                  "_waited", "idle_total_s", "idle_waits", "last")
 
     def __init__(self, hw: Optional[HardwarePeak] = None,
@@ -656,6 +787,7 @@ class StepAccounting:
         self._idle_s = self._lock_wait_s = 0.0
         self._gc_seen = _COLLECTOR.count
         self._gc_s = _COLLECTOR.seconds()
+        self._interp_seen = _PROBE.totals
         # Cumulative, so a window's share is a difference of two
         # readings whatever the ring still holds.
         self.idle_total_s = 0.0
@@ -696,6 +828,8 @@ class StepAccounting:
     def begin(self):
         if _COLLECTOR.hook not in gc.callbacks:
             gc.callbacks.append(_COLLECTOR.hook)
+        if _PROBE.thread is None:
+            _PROBE.start()
         if self._finish_cpu is None:
             self._finish_cpu = time.thread_time()
         self._wall0 = now = time.perf_counter()
@@ -798,6 +932,13 @@ class StepAccounting:
         idle_s, lock_s = self._idle_s, self._lock_wait_s
         self._idle_s = self._lock_wait_s = 0.0
         gc_ms, gc_max_ms, gc_gen = self._gc_since_last()
+        # The probe's samples since the previous finish(): one read of
+        # its totals, and of its last samples for the longest.
+        interp = _PROBE.totals
+        seen, self._interp_seen = self._interp_seen, interp
+        late_max = max([late for k, late in tuple(_PROBE.recent)
+                        if seen[0] < k <= interp[0]], default=0.0) \
+            if interp[0] != seen[0] else 0.0
         if self._traced and self._between is not None:
             self._gap_ann = _annotation(self._between)
         if self._device_s <= 0.0 and self._flops <= 0.0:
@@ -829,6 +970,12 @@ class StepAccounting:
             "gc_ms": gc_ms,
             "gc_max_ms": gc_max_ms,
             "gc_gen": gc_gen,
+            "interp_n": interp[0] - seen[0],
+            "interp_late_ms": (interp[1] - seen[1]) * 1e3,
+            "interp_late_max_ms": late_max * 1e3,
+            "interp_held_n": interp[2] - seen[2],
+            "standstill_ms": (interp[3] - seen[3]) * 1e3,
+            "held_long_ms": (interp[4] - seen[4]) * 1e3,
             "tokens": self._tokens,
             "flops": self._flops,
             "hbm_bytes": self._hbm_bytes,

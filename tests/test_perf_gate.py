@@ -159,6 +159,10 @@ def test_step_accounting_overhead_gate():
     assert one - 1 <= STEP_ACCOUNTING_CALLS, (
         f"step-accounting hot path regressed: {one - 1} calls a step, "
         f"{STEP_ACCOUNTING_CALLS} recorded")
+    # What finish() reads of the interpreter probe (PR 60: six keys, one
+    # tuple read and no call) is inside that count.
+    assert {"interp_n", "interp_late_ms", "interp_late_max_ms",
+            "interp_held_n", "standstill_ms", "held_long_ms"} <= set(acc.last)
 
 
 FLIGHT_RECORDER_CALLS = 3   # record_enter, record_exit, _maybe_publish

@@ -21,6 +21,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import ray_tpu  # noqa: E402
 from ray_tpu.models.gpt import GPTConfig  # noqa: E402
+from ray_tpu.serve.replica import REPLY_SENT  # noqa: E402
 from ray_tpu.util import state  # noqa: E402
 
 CFG = GPTConfig(vocab_size=512, max_seq=128, d_model=64, n_layer=2,
@@ -103,6 +104,40 @@ def test_concurrent_streams_mixed_lengths_through_proxy(rt_llm):
         done = frames[-1]
         assert done["done"] and done["finish_reason"] == "length"
         assert len(toks) == n == done["num_tokens"]
+
+
+def test_a_first_tokens_way_in_and_out_is_timed_around_the_engine(rt_llm):
+    """One streamed request through the real proxy: ``proxy_ttft`` once,
+    the handler's arrival to the first frame on the socket, which holds
+    the engine's ``ttft``; ``stream_out`` once a reply that the loop
+    wrote (as many as ``proxy_flush``); and ``engine_stats`` carries the
+    process's CPU by thread and the probe's totals."""
+    from ray_tpu.serve import slo
+
+    _, serve = rt_llm
+    slo._reset_for_tests()
+    url = _deploy(serve, num_blocks=64, block_size=8, max_batch=4)
+    frames = _stream_http(url, {"prompt": [7, 8, 9], "max_tokens": 6})
+    assert frames[-1]["done"] and len(frames) == 7
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        hist = slo.phase_hist("LLMServer")
+        if "proxy_ttft" in hist and hist.get("tpot", {}).get("count"):
+            break
+        time.sleep(0.05)
+    assert hist["proxy_ttft"]["count"] == 1 == hist["ttft"]["count"]
+    assert hist["proxy_ttft"]["sum"] >= hist["ttft"]["sum"]
+    assert hist["stream_out"]["count"] == hist["proxy_flush"]["count"] >= 1
+    assert hist["stream_out"]["sum"] >= hist["proxy_flush"]["sum"]
+    assert {"stream_out", "proxy_ttft"} <= set(slo.PHASES)
+    stats = serve.get_app_handle("llm").options(
+        method_name="engine_stats").remote().result(timeout=60)
+    groups = stats["threads"]["by_group"]
+    assert {"serve-http", "llm-engine", "MainThread"} <= set(groups)
+    assert groups["llm-engine"]["cpu_s"] > 0.0
+    assert stats["threads"]["process_cpu_s"] > 0.0
+    assert stats["interp"]["n"] >= 1
+    assert stats["phase_hist"]["proxy_ttft"]["count"] == 1
 
 
 def test_ttft_tpot_quantiles_and_llm_timeseries(rt_llm):
@@ -352,6 +387,7 @@ def _read_all(rep, sids):
     while open_sids:
         reply = rep.stream_poll("me")
         assert reply, "streams that ended have their ends ready"
+        assert reply.pop(REPLY_SENT) <= time.time()
         for sid, (chunks, done, error) in reply.items():
             assert error is None
             frames[sid] += chunks
@@ -401,6 +437,7 @@ def test_a_steps_tokens_take_the_stream_condition_once_and_no_thread(
                 if e.get("deployment") == eng.name]
     assert (entry["handovers"], entry["tokens_handed"]) == (1, 4)
     reply = rep.stream_poll("me")
+    del reply[REPLY_SENT]
     assert sorted(reply) == sids
     assert all(len(chunks) == 1 and "token" in chunks[0] and not done
                for chunks, done, _ in reply.values())

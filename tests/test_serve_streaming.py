@@ -430,6 +430,7 @@ def test_grpc_drains_a_stream_through_the_same_path(rt):
 # thread, and polls, handle and proxy read it as they read a generator's.
 # ---------------------------------------------------------------------------
 from ray_tpu.serve.replica import (  # noqa: E402
+    REPLY_SENT,
     STREAM_MARKER,
     PushedStream,
     Replica,
@@ -539,6 +540,7 @@ def test_one_push_fills_many_streams_under_one_lock_with_one_wake_up():
     assert (cond.entered, cond.notified) == (1, 1)
     assert len({t for s in rep._streams.values() for _, t in s.ready}) == 1
     reply = rep.stream_poll("me")
+    assert reply.pop(REPLY_SENT) <= time.time()
     assert sorted(reply) == sids
     for k, sid in enumerate(sids):
         assert reply[sid] == ([{"tag": k, "i": i} for i in range(3)],
@@ -564,7 +566,9 @@ def test_what_is_handed_over_before_the_books_and_the_reader_is_kept():
     limit = stream.limit
     rep.stream_grant(sid, 10 ** 6, "me")
     assert stream.limit == limit
-    assert rep.stream_poll("me") == {
+    reply = rep.stream_poll("me")
+    del reply[REPLY_SENT]
+    assert reply == {
         sid: ([{"tag": "a", "i": i} for i in range(4)], False, None)}
 
 

@@ -154,6 +154,13 @@ def _check_interval(entry):
         assert 0.0 <= ms <= entry["device_ms_by"][kind]
     assert entry["gc_ms"] >= entry["gc_max_ms"] >= 0.0
     assert (entry["gc_gen"] is None) == (entry["gc_max_ms"] == 0.0)
+    # The interpreter probe's samples that ended inside the interval
+    # (PR 60): counts, their lateness, and the long ones by whose they
+    # were.
+    assert 0 <= entry["interp_held_n"] <= entry["interp_n"]
+    assert entry["interp_late_ms"] + 1e-9 >= entry["interp_late_max_ms"] >= 0.0
+    assert 0.0 <= entry["standstill_ms"] + entry["held_long_ms"] <= \
+        entry["interp_late_ms"] + 1e-9
 
 
 def _spin(seconds):
@@ -472,7 +479,7 @@ def test_phase_hist_counts_queue_ttft_and_stream_holds(read):
     chunk's hold is counted once."""
     from ray_tpu.serve import slo
     from ray_tpu.serve.llm import _LLMServer
-    from ray_tpu.serve.replica import STREAM_MARKER, Replica
+    from ray_tpu.serve.replica import REPLY_SENT, STREAM_MARKER, Replica
 
     slo._reset_for_tests()
     rep = Replica(_LLMServer, (CFG,), dict(
@@ -496,6 +503,7 @@ def test_phase_hist_counts_queue_ttft_and_stream_holds(read):
                 reply = {sid: (got, done, None)}
             else:
                 reply = rep.stream_poll("me")
+                del reply[REPLY_SENT]
             replies += 1
             for sid, (got, done, error) in reply.items():
                 assert error is None and sid in open_sids
@@ -574,8 +582,13 @@ def test_wrap_step_splits_the_device_span_and_report_carries_it():
         assert "train_mfu" not in r              # no peak on the CPU
     ring = _ring("train.step", t0)
     assert len(ring) == 3 and all(e["trial"] == "split_t" for e in ring)
+    # The probe's reading of a step reaches the report under the key the
+    # benchmark's train reader takes.
+    assert [r["train_standstill_ms"] for r in reports[1:]] == \
+        [e["standstill_ms"] for e in ring]
     for e in ring:
         _check_partition(e)
+        _check_interval(e)
         assert set(e["device_ms_by"]) == {"dispatch", "wait"}
         assert "train.report" in e["phases_ms"]
     perfmodel.clear_device_steps()
@@ -753,6 +766,28 @@ def test_idle_gaps_name_the_gap_between_steps_and_a_spans_halves():
     assert t["uncovered_s"] == pytest.approx(50 * ns)
 
 
+def test_idle_gaps_name_the_serving_thread_that_ran_in_a_gap():
+    """A device gap in which the engine's thread stood between two
+    steps while the proxy's loop wrote a reply's frames lies under
+    ``serve.flush`` for as long as that ran: the innermost span has the
+    instant, whichever thread it is on."""
+    from ray_tpu._private.profiler import idle_gaps
+
+    rows = [(D, "XLA Ops", "%op.0", 0, 100), (D, "XLA Ops", "%op.1", 600, 100),
+            (H, "llm-engine", "llm.step", 0, 150),
+            (H, "llm-engine", "llm.between", 150, 400),     # gap 100-600
+            (H, "serve-http", "serve.flush", 300, 120),
+            (H, "actor", "serve.stream_poll", 200, 60),
+            (H, "actor", "serve.handle_request", 700, 50)]  # under an op
+    t = idle_gaps(rows)
+    ns = 1e-9
+    assert t["idle_s"] == pytest.approx(500 * ns)
+    assert t["by_phase_s"] == pytest.approx({
+        "llm.step": 50 * ns, "serve.stream_poll": 60 * ns,
+        "serve.flush": 120 * ns, "llm.between": (50 + 40 + 130) * ns})
+    assert t["uncovered_s"] == pytest.approx(50 * ns)   # 550-600
+
+
 def test_device_steps_table_ends_with_the_longest_intervals():
     from ray_tpu._private.profiler import format_device_steps
 
@@ -767,7 +802,9 @@ def test_device_steps_table_ends_with_the_longest_intervals():
     slow = dict(ring[-1], interval_ms=99999.0, between_ms=900.0,
                 lock_wait_ms=12.5, idle_ms=800.0, gc_ms=30.0, gc_max_ms=30.0,
                 gc_gen=2, stall_ms=45.0, arrived=3,
-                cpu_ms=21.5,
+                cpu_ms=21.5, standstill_ms=1200.0, held_long_ms=0.0,
+                interp_n=3, interp_held_n=1, interp_late_ms=1200.5,
+                interp_late_max_ms=1200.0,
                 phases_ms=dict(ring[-1]["phases_ms"], **{"llm.emit": 18.25}))
     lines = format_device_steps(ring + [slow]).splitlines()
     head = next(i for i, ln in enumerate(lines)
@@ -778,7 +815,10 @@ def test_device_steps_table_ends_with_the_longest_intervals():
     assert table[0].split()[0] == "99999.0"
     assert "between 900.0 (lock 12.5, idle 800.0)" in table[0]
     assert "llm.emit 18.2" in table[0] or "llm.emit 18.3" in table[0]
-    assert "; gc 30.0 (gen 2); cpu 21.5, stall 45.0; " in table[0]
+    assert "; gc 30.0 (gen 2); cpu 21.5, stall 45.0; standstill 1200.0, " \
+        "held long 0.0; " in table[0]
+    (probe,) = [ln for ln in lines[:head] if "interpreter probe" in ln]
+    assert "stood still 1200.0 ms" in probe
     assert table[0].endswith(f"lanes {slow['lanes']}, chunk tokens "
                              f"{slow['prefill_tokens']}, arrived 3")
     d = slow["dispatch_ms_by"]["decode"]
@@ -811,8 +851,10 @@ def test_device_steps_table_splits_the_step_and_sums_the_counts():
          "device_ms": 8.0, "host_gap_ms": 2.0, "other_ms": 0.5,
          "device_ms_by": {"dispatch": 1.0, "wait": 7.0},
          "phases_ms": {"data.next_batch": 1.5}}])
-    head, phases, counts, gaps, *longest, train, train_phases = \
+    head, phases, counts, probe, gaps, *longest, train, train_phases = \
         text.splitlines()
+    assert probe.lstrip().startswith("interpreter probe: ") \
+        and "stood still" in probe
     assert head.startswith(f"  llm.step x {len(ring)} (table_test): ")
     assert "decode " in head and "prefill " in head
     assert "[dispatch " in head
@@ -862,6 +904,7 @@ def test_a_profiler_session_holds_the_step_and_its_phases_by_name():
     registry's names. Bounded: the session runs on a thread that must
     end in time."""
     from ray_tpu._private import profiler
+    from ray_tpu.serve.deployment import Router
 
     eng = LLMEngine(PARAMS, CFG, num_blocks=32, block_size=8, max_batch=4,
                     prefill_chunk_tokens=8)
@@ -880,6 +923,8 @@ def test_a_profiler_session_holds_the_step_and_its_phases_by_name():
                 eng.step()
                 gc.collect()        # a pass inside the session
                 _drain(eng)
+                # A serving thread's body, as the proxy's loop runs it.
+                Router("in_session")._flush([], time.time())
             finally:
                 jax.profiler.stop_trace()
             result["rows"], result["start_wall"] = \
@@ -906,5 +951,6 @@ def test_a_profiler_session_holds_the_step_and_its_phases_by_name():
             "llm.decode.wait", "llm.prefill.dispatch",
             "llm.prefill.wait"} <= host
     assert "llm.idle" not in host        # nobody slept: no loop
+    assert "serve.flush" in host         # the serving side's, by name
     # On the CPU backend no device plane exists: nothing to attribute.
     assert profiler.idle_gaps(result["rows"])["idle_s"] == 0.0

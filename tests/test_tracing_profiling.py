@@ -336,6 +336,15 @@ def test_cluster_device_profile_merges_processes(rt, tmp_path):
     assert len(with_steps) >= 2, (
         "expected accounted steps from >= 2 processes",
         {k: len(v["device_steps"]) for k, v in captured.items()})
+    # Every captured process says which of its threads had the cores
+    # over the window, as `rtpu profile --device` prints it.
+    from ray_tpu._private.profiler import format_thread_cpu
+
+    for source, prof in captured.items():
+        a, b = prof["threads"]
+        assert b["process_cpu_s"] >= a["process_cpu_s"], source
+        assert "MainThread" in b["by_group"], source
+        assert format_thread_cpu(a, b).lstrip().startswith("CPU by thread")
     # Single host: every node offset must be 0 by construction.
     assert offsets and all(off == 0.0 for off in offsets.values())
 
