@@ -67,7 +67,7 @@ from typing import Callable, Deque, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..models import pack_span, serving, step_columns
+from ..models import pack_span, served_params, serving, step_columns
 from ..util import perfmodel, tracing
 from .kv_cache import (BlockChain, PagedKVCache, PrefixPool, StatePool,
                        WindowPool, window_table_len)
@@ -270,7 +270,15 @@ class LLMEngine:
         # while a long prompt prefills.
         self.prefill_chunk_tokens = (None if prefill_chunk_tokens is None
                                      else int(prefill_chunk_tokens))
-        self.params = params
+        # The parameters as the two programs read them, made once here
+        # (the seam's ``Serving.at_rest``: GPT's float32 checkpoint
+        # leaves rounded to ``cfg.dtype`` now and not by every step);
+        # what was given is not kept. The count says the seam engaged.
+        self.params = served_params(params, cfg)
+        leaves = jax.tree_util.tree_leaves
+        self._params_cast = sum(
+            getattr(given, "dtype", None) != kept.dtype
+            for given, kept in zip(leaves(params), leaves(self.params)))
         # Fixed decode shapes — one compile: batch padded to max_batch,
         # tables padded to the worst-case blocks/sequence. Prefill
         # recompiles per length bucket (lengths are padded to a block
@@ -290,7 +298,7 @@ class LLMEngine:
         # step scores k+1 rows per lane (fixed q shape, one compile)
         # and the accepted prefix + one corrected/bonus token all land
         # in a single step. None is one row a lane and no proposer.
-        self._spec = make_spec(speculative, target_params=params,
+        self._spec = make_spec(speculative, target_params=self.params,
                                target_cfg=cfg)
         self._q_rows = 1 if self._spec is None else self._spec.k + 1
         # A kind of layer with a window has a pool of its own, in which
@@ -1613,6 +1621,12 @@ class LLMEngine:
             # compiled or interpreted, or "xla" where the model's chunk
             # has none.
             "chunk_attention": self._chunk_attention_mode(),
+            # The parameters at rest on the device, and how many leaves
+            # of the given tree the seam cast to get them (0: served as
+            # given).
+            "param_bytes_at_rest": sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(self.params)),
+            "param_leaves_cast": self._params_cast,
             "steps": self._steps,
             "waiting": len(self._waiting),
             "in_flight": len(self._active),

@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import served_params
+
 
 class Proposer:
     """Pluggable draft-token source: given the sequence's full token
@@ -127,7 +129,10 @@ class DraftProposer(Proposer):
     name = "draft"
 
     def __init__(self, params, cfg):
-        self.params = params
+        # As the engine keeps its own (the draft's forward rounds each
+        # weight as the served programs do): the target's tree, already
+        # so, comes back itself.
+        self.params = served_params(params, cfg)
         self.cfg = cfg
         # Process-wide program share (same rationale as the engine's
         # _jit_programs cache): drafts with equal cfg reuse one jit

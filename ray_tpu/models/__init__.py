@@ -16,7 +16,18 @@ pools (llm/kv_cache.py), the cost model (util/perfmodel.py) and the
 Serve deployment (serve/llm.py) know no model: they ask ``serving(cfg)``
 for what the configuration's own module says of it, a ``Serving``:
 
-  init      ``init(key, cfg)``: the parameters as served
+  init      ``init(key, cfg)``: the parameters, as a checkpoint of the
+            family holds them
+  at_rest   ``at_rest(params)``: the parameters as the two programs
+            READ them, which the engine makes once when it is built and
+            keeps in place of what it was given (``served_params``).
+            None where ``init``'s tree is that already (Laguna, Kimi,
+            Nemotron: ``cfg.dtype`` leaves). GPT's ``init`` makes a
+            trainer's float32 master weights and its programs round
+            each to ``cfg.dtype`` in front of its product, so its
+            ``at_rest`` does that rounding, once (models/gpt.py
+            ``params_at_rest``); a tree that is already as read comes
+            back itself
   step      the decode step, ``(params, packed, *pools, *window pools,
             q=, firsts=, cfg=)`` -> ``(logits, ids, *pools, *window
             pools)``. ``packed`` is ONE int32 array ``[max_batch, W]``,
@@ -143,6 +154,7 @@ class Serving:
     vocab_size: int
     counters: Tuple[str, ...] = ()
     state: Optional[StateKind] = None
+    at_rest: Optional[Callable] = None
 
 
 def pack_span(block_table, dest, ctx_len: int, last: int, *extra: int):
@@ -276,6 +288,14 @@ def unpack_step(packed, q: int, win_len: int = 0, firsts=None,
 def serving(cfg) -> Serving:
     """What the module of ``cfg``'s class says of serving it."""
     return importlib.import_module(type(cfg).__module__).serving(cfg)
+
+
+def served_params(params, cfg):
+    """``params`` as ``cfg``'s family keeps them while they are served
+    (``Serving.at_rest``): what an engine, or a draft model's proposer,
+    holds and hands its programs."""
+    at_rest = serving(cfg).at_rest
+    return params if at_rest is None else at_rest(params)
 
 
 from . import gpt, kimi_k2, laguna, nemotron_h, resnet  # noqa: E402,F401

@@ -150,6 +150,46 @@ def init(key, cfg: GPTConfig) -> dict:
     }
 
 
+# The leaves _layernorm multiplies in float32: every other leaf's every
+# use is ``.astype(cfg.dtype)``.
+NORM_SCALES = ("ln1", "ln2", "ln_f")
+
+
+def dtypes_at_rest(params, cfg: GPTConfig) -> dict:
+    """The dtype a leaf of ``params`` (arrays or their shapes) is served
+    in: ``cfg.dtype`` but for the norms' scales, which stay as given."""
+    dt = jnp.dtype(cfg.dtype)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: x.dtype if path[-1].key in NORM_SCALES else dt,
+        params)
+
+
+def params_at_rest(params, cfg: GPTConfig) -> dict:
+    """``params`` as the served programs read them (the seam's
+    ``Serving.at_rest``): every leaf but the norms' scales in
+    ``cfg.dtype``, rounded ONCE here and not by every step. ``init``'s
+    leaves are float32 (a trainer's master weights), and forward_step
+    and forward_prefill_chunk round each to ``cfg.dtype`` in front of
+    its product, so from a float32 tree both programs read and rewrote
+    all 124M parameters every step (1.3 ms of the chat cell's 9.6,
+    PERF.md section 6, PR 61); from this tree the same ``astype`` is no
+    operation and the products take the same bits. One program over
+    the whole tree (leaf by leaf is a compile a leaf: ``init``), each
+    committed leaf's sharding kept; a tree that is already so, as every
+    tree is where ``cfg.dtype`` is float32, is handed back itself."""
+    tree = jax.tree_util
+    wants = dtypes_at_rest(params, cfg)
+    if all(x.dtype == want for x, want in
+           zip(tree.tree_leaves(params), tree.tree_leaves(wants))):
+        return params
+    kept = tree.tree_map(
+        lambda x: x.sharding if getattr(x, "committed", False) else None,
+        params)
+    return jax.jit(
+        lambda p: tree.tree_map(lambda x, want: x.astype(want), p, wants),
+        out_shardings=kept)(params)
+
+
 def _layernorm(x, scale):
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
@@ -583,7 +623,7 @@ def cost_shape(cfg: GPTConfig) -> dict:
         "attn_windows": (),              # no layer with a window
         "num_params": n,
         "streamed_params": lambda rows: n,   # every weight, every step
-        "param_bytes": 4,                # f32 parameters
+        "param_bytes": jnp.dtype(cfg.dtype).itemsize,   # as served
         "kv_bytes_per_token": 2 * L * hk * d,   # k+v elements per token
         "m": m, "L": L,
     }
@@ -597,7 +637,9 @@ def serving(cfg: GPTConfig):
     full = keys_and_values("full", range(cfg.n_layer), cfg.kv_heads,
                            cfg.head_dim, None, cfg.dtype)
     return Serving(init=init, step=forward_step,
-                   chunk=forward_prefill_chunk, kinds=(full,),
+                   chunk=forward_prefill_chunk,
+                   at_rest=functools.partial(params_at_rest, cfg=cfg),
+                   kinds=(full,),
                    cost=cost_shape(cfg), max_seq=cfg.max_seq,
                    vocab_size=cfg.vocab_size, counters=COUNTERS)
 

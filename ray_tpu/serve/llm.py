@@ -30,6 +30,7 @@ and the final frame carries the decoded text when every token is a byte.
 from __future__ import annotations
 
 import gc
+import sys
 import threading
 import time
 from typing import Any, Optional
@@ -50,6 +51,16 @@ def decode(tokens) -> Optional[str]:
         return None
     return bytes(tokens).decode("utf-8", errors="replace")
 
+
+# The interpreter's switch interval in a process that serves a model.
+# The engine's thread and the serving threads (the handle's poller, the
+# proxy's loop, the actor threads) pass ONE interpreter between them
+# every step, and a thread that wakes with work asks the holder for it
+# only after this interval: at CPython's 5 ms, a quarter of a chat
+# step, a first token's way in and out through those threads grew by
+# 6-10 ms when the device step gave them 1.2 ms less of the interpreter
+# (PERF.md section 6, PR 61); at 1 ms it did not.
+SWITCH_INTERVAL_S = 0.001
 
 _freeze_lock = threading.Lock()
 _frozen = False
@@ -131,6 +142,8 @@ class _LLMServer:
         if isinstance(system_prompt, str):
             system_prompt = encode(system_prompt)
         self.system_prompt = [int(t) for t in (system_prompt or ())]
+        sys.setswitchinterval(min(sys.getswitchinterval(),
+                                  SWITCH_INTERVAL_S))
         # Warm is when as many requests as the engine has lanes have
         # finished since the process last built a program: whatever
         # warms a deployment up (a request a chunk length, a request a

@@ -10,6 +10,7 @@ both price against these exact formulas, so a drift here is a lie in
 every MFU number the system prints.
 """
 
+import functools
 import time
 
 import pytest
@@ -69,7 +70,11 @@ def test_decode_step_cost_hand_computed():
     # 2 MACs per weight per lane + 4*m*L per context position.
     assert c.flops == 2.0 * W_MATMUL * 3 + 4.0 * M * L * 600
     kvb = 2 * L * HK * D * 2  # k+v elements/token at bf16
-    assert c.hbm_bytes == N_PARAMS * 4 + 600 * kvb + 3 * kvb
+    # The weights stream as they are served: cfg.dtype, two bytes each
+    # (PR 61; float32 leaves priced at four are a caller's to ask for).
+    assert c.hbm_bytes == N_PARAMS * 2 + 600 * kvb + 3 * kvb
+    assert decode_step_cost(GPT2_SMALL, ctx, param_bytes=4).hbm_bytes \
+        == N_PARAMS * 4 + 600 * kvb + 3 * kvb
     assert c.tokens == 3
     # Batching amortizes the weight read: per-token HBM must drop.
     solo = decode_step_cost(GPT2_SMALL, [200])
@@ -85,7 +90,7 @@ def test_prefill_cost_hand_computed():
     assert c.flops == 2.0 * (W_MATMUL - head) * T + 2.0 * head \
         + 4.0 * M * L * T * (T + 1) / 2
     kvb = 2 * L * HK * D * 2
-    assert c.hbm_bytes == N_PARAMS * 4 + 2.0 * T * kvb
+    assert c.hbm_bytes == N_PARAMS * 2 + 2.0 * T * kvb
     assert c.tokens == T
 
 
@@ -306,21 +311,25 @@ def test_one_step_cost_prices_decode_and_verify_as_it_always_did():
     """decode_step_cost is the one step cost. With q_lens omitted it
     gives the figures it gave before the verify step's cost was folded
     into it; with q_lens, that cost's own (both pinned from the tree
-    before the merge); and q_lens of ones is q_lens omitted."""
+    before the merge, whose GPT weights were float32 at rest: four
+    bytes a parameter, asked for here); and q_lens of ones is q_lens
+    omitted. Since PR 61 the default is the served tree's two."""
     from ray_tpu.models.gpt import TINY
 
     def figures(c):
         return (c.flops, c.hbm_bytes, c.tokens)
 
+    f32 = functools.partial(decode_step_cost, param_bytes=4)
     ctx = [100, 200, 300]
-    assert figures(decode_step_cost(GPT2_SMALL, ctx)) == (
+    assert figures(f32(GPT2_SMALL, ctx)) == (
         763527168.0, 519724032.0, 3)
-    assert figures(decode_step_cost(GPT2_SMALL, ctx, [1, 1, 1])) == (
+    assert figures(f32(GPT2_SMALL, ctx, [1, 1, 1])) == (
         763527168.0, 519724032.0, 3)
-    assert figures(decode_step_cost(GPT2_SMALL, [104, 205, 301],
-                                    [5, 5, 1])) == (
+    assert figures(f32(GPT2_SMALL, [104, 205, 301], [5, 5, 1])) == (
         2785812480.0, 520387584.0, 11)
-    assert figures(decode_step_cost(TINY, [7, 33])) == (
+    assert figures(f32(TINY, [7, 33])) == (
         1875968.0, 1946112.0, 2)
-    assert figures(decode_step_cost(TINY, [9, 36], q_lens=[3, 4])) == (
+    assert figures(f32(TINY, [9, 36], q_lens=[3, 4])) == (
         6588416.0, 1956352.0, 7)
+    assert figures(decode_step_cost(GPT2_SMALL, ctx)) == (
+        763527168.0, 519724032.0 - 2 * GPT2_SMALL.num_params(), 3)

@@ -547,6 +547,33 @@ def test_a_request_without_a_sink_leaves_by_its_queue_and_is_forgotten():
     perfmodel.clear_device_steps()
 
 
+def test_a_serving_process_hands_the_interpreter_on_every_millisecond(
+        by_hand):
+    """A replica's engine thread and the serving threads share one
+    interpreter: building a deployment brings the process's switch
+    interval down to ``SWITCH_INTERVAL_S`` (PR 61: at CPython's 5 ms a
+    shorter device step cost a first token 6-10 ms), never up; a bare
+    engine leaves the process as it was."""
+    import sys
+
+    from ray_tpu.llm.engine import LLMEngine
+    from ray_tpu.serve import llm as serve_llm
+
+    before = sys.getswitchinterval()
+    try:
+        sys.setswitchinterval(0.005)
+        LLMEngine(_params(), CFG, **_POOL)
+        near = lambda s: pytest.approx(s, abs=2e-6)   # kept in whole us
+        assert sys.getswitchinterval() == near(0.005)
+        by_hand("interval_a")
+        assert sys.getswitchinterval() == near(serve_llm.SWITCH_INTERVAL_S)
+        sys.setswitchinterval(0.0002)
+        by_hand("interval_b")
+        assert sys.getswitchinterval() == near(0.0002)
+    finally:
+        sys.setswitchinterval(before)
+
+
 def test_the_replica_freezes_once_a_process_and_an_engine_never(
         by_hand, monkeypatch):
     """(g) The set-up's heap leaves the collector's sight once, when the

@@ -171,16 +171,28 @@ def test_flash_kernels_carry_their_names(one_chip, seq, names):
         assert name in text, name
 
 
+def _chat_cell_params(one_chip):
+    """GPT-2-small's parameters as the chat cell's engine keeps them
+    (PR 61: ``gpt.init``'s float32 leaves in the dtypes of
+    ``params_at_rest``, all bfloat16 but the three norm scales), as
+    shapes on the described chip."""
+    cfg = gpt.GPT2_SMALL
+    given = jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg))
+    return jax.tree_util.tree_map(
+        lambda leaf, dt: jax.ShapeDtypeStruct(leaf.shape, dt,
+                                              sharding=one_chip),
+        given, gpt.dtypes_at_rest(given, cfg))
+
+
 def _chat_cell_decode_lowered(one_chip):
     """The engine's own decode program lowered at the chat cell's shapes
-    (GPT-2-small, 64 lanes of one row, 2,560 blocks of 16)."""
+    (GPT-2-small, 64 lanes of one row, 2,560 blocks of 16) from the
+    parameters as served."""
     from ray_tpu.llm.engine import _jit_programs
 
     cfg = gpt.GPT2_SMALL
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    params = jax.tree_util.tree_map(
-        lambda leaf: S(leaf.shape, leaf.dtype),
-        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
+    params = _chat_cell_params(one_chip)
     B, i32 = CELL_B, jnp.int32
     pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
     decode = _jit_programs(cfg)[0]
@@ -312,6 +324,78 @@ def test_compiled_decode_program_copies_the_pool_no_more_than_it_did(
     assert _results(text, "dynamic-update-slice", "dynamic-slice",
                     "concatenate", "pad") == []
     assert chat_decode.memory_analysis().temp_size_in_bytes < 200e6
+
+
+PROJECTION = 12 * 768 * 768      # elements of one attention projection
+
+
+def _elements(shape):
+    """Elements of an HLO shape as printed, ``bf16[12,768,3072]``."""
+    import math
+
+    return math.prod(map(int, shape[shape.index("[") + 1:-1].split(",")))
+
+
+def _written(text):
+    """``text`` without the bodies of its fused computations: the
+    instructions left are those whose results are written to memory (a
+    fused body's by the ``fusion`` instruction that calls it)."""
+    import re
+
+    return re.sub(r"^%fused_computation[^\n]*\{\n.*?^\}\n", "", text,
+                  flags=re.M | re.S)
+
+
+def _weight_sized(text, params):
+    """(opcode, result shape) of every instruction of ``text`` outside a
+    fused body that converts, copies or fuses into a result with as
+    many elements as a leaf of ``params`` of an attention projection's
+    size or more (the chunk's scores and the pools are larger than a
+    projection too, and are nobody's weight)."""
+    import math
+
+    sizes = {math.prod(leaf.shape)
+             for leaf in jax.tree_util.tree_leaves(params)}
+    return [(op, shape) for op, shape in _results(
+        _written(text), "fusion", "convert", "copy", "transpose",
+        at_least=PROJECTION) if _elements(shape) in sizes]
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_served_programs_read_their_weights_as_stored(
+        request, one_chip, as_tpu, program):
+    """PR 61: from the parameters as served, compiled for the described
+    v5e at the chat cell's shapes, neither program converts or copies a
+    weight: nothing it writes to memory by a ``convert``, a ``copy``, a
+    ``transpose`` or a fusion has a weight's size, from an attention
+    projection's (12 x 768 x 768) up, and every entry parameter of that
+    size is ``bf16``. (From float32 leaves each program wrote a
+    ``bf16`` copy of seven leaves: with the float32 prefetch of ``wi``,
+    six of the chat cell's ten largest device operations, 1.3 ms of
+    every step; PERF.md section 6. Not counted: a fused body that
+    WIDENS a weight it reads, as the chunk's head on one row does, a
+    product on the vector unit, writes nothing; and the compiler's
+    cross-program prefetch, ``copy-start`` / ``copy-done`` of ONE
+    argument into faster memory, is a move it makes from either tree:
+    ``wte`` as stored now, ``wi`` in float32 before.) A compiler's
+    count, not a time."""
+    import re
+
+    c = request.getfixturevalue("chat_decode") if program == "decode" \
+        else _chat_cell_chunk_lowered(one_chip, 512, MAX_NB).compile()
+    text = c.as_text()
+    assert _weight_sized(text, _chat_cell_params(one_chip)) == []
+    if program == "decode":     # nor inside a fused body, there
+        assert _results(text, "convert", "copy", "copy-start",
+                        at_least=PROJECTION) == []
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    weights = [shape for shape in re.findall(
+        r"= (\w+\[[\d,]+\])\S* parameter\(", entry)
+        if _elements(shape) >= PROJECTION]
+    # seven weights (``wpe`` is smaller), then the two pools
+    assert len(weights) == 9 and all(
+        shape.startswith("bf16[") for shape in weights), weights
 
 
 @pytest.mark.parametrize("program", ["kv_scatter_blocks", "kv_copy_block"])
@@ -561,6 +645,20 @@ def test_laguna_chunk_program_pays_for_routed_experts_only(laguna_programs):
     assert flops < 1.5e12, flops       # XLA's own count, kernels aside
 
 
+def _chat_cell_chunk_lowered(one_chip, n, table):
+    """The engine's own chunk program lowered at the chat cell's shapes
+    from the parameters as served: ``n`` tokens behind a table of
+    ``table`` slots."""
+    from ray_tpu.llm.engine import _jit_programs
+
+    cfg = gpt.GPT2_SMALL
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
+    return _jit_programs(cfg)[1].lower(
+        _chat_cell_params(one_chip), S((1, n), jnp.int32), pool, pool,
+        S((table + n // BS + 2,), jnp.int32))
+
+
 @pytest.mark.parametrize("n, table", [(512, MAX_NB), (320, 0)],
                          ids=["behind_context", "cold_prompt"])
 def test_chat_cell_chunk_program_writes_its_span_in_place(one_chip, as_tpu,
@@ -574,17 +672,8 @@ def test_chat_cell_chunk_program_writes_its_span_in_place(one_chip, as_tpu,
     table); what comes back is one row of logits and its argmax, the
     head on that row alone (the parent wrote ``f32``/``bf16[1,512,
     50304]`` for one row's sake); temporaries under 256 MB."""
-    from ray_tpu.llm.engine import _jit_programs
-
-    cfg = gpt.GPT2_SMALL
-    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    params = jax.tree_util.tree_map(
-        lambda leaf: S(leaf.shape, leaf.dtype),
-        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
-    pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
-    c = _jit_programs(cfg)[1].lower(
-        params, S((1, n), jnp.int32), pool, pool,
-        S((table + n // BS + 2,), jnp.int32)).compile()
+    c = _chat_cell_chunk_lowered(one_chip, n, table).compile()
+    cfg, params = gpt.GPT2_SMALL, _chat_cell_params(one_chip)
     text = c.as_text()
     assert text.startswith("HloModule jit_llm_prefill_chunk")
     leaves = len(jax.tree_util.tree_leaves(params))
@@ -832,17 +921,7 @@ def test_gpt2_chunk_program_has_no_chunk_kernel(one_chip, as_tpu):
     """GPT-2's chunk keeps its XLA-made attention (ISSUE 38: 2.45 ms
     behind a 1,024-slot table, nothing to win): lowered for the TPU at
     the chat cell's shapes, its program holds no Mosaic call at all."""
-    from ray_tpu.llm.engine import _jit_programs
-
-    cfg = gpt.GPT2_SMALL
-    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    params = jax.tree_util.tree_map(
-        lambda leaf: S(leaf.shape, leaf.dtype),
-        jax.eval_shape(lambda: gpt.init(jax.random.key(0), cfg)))
-    pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), jnp.bfloat16)
-    text = _jit_programs(cfg)[1].lower(
-        params, S((1, 512), jnp.int32), pool, pool,
-        S((MAX_NB + 512 // BS + 2,), jnp.int32)).as_text()
+    text = _chat_cell_chunk_lowered(one_chip, 512, MAX_NB).as_text()
     assert "module @jit_llm_prefill_chunk " in text
     assert "tpu_custom_call" not in text and "chunk_attn" not in text
 
@@ -871,11 +950,7 @@ def _chunk_program_texts(one_chip):
             lambda leaf: S(leaf.shape, leaf.dtype),
             jax.eval_shape(lambda: init(jax.random.key(0), cfg)))
 
-    cfg = gpt.GPT2_SMALL
-    pool = S((cfg.n_layer, CELL_NB, BS, HKV * HD), bf16)
-    lowered = {"gpt": _jit_programs(cfg)[1].lower(
-        shapes(gpt.init, cfg), S((1, 512), i32), pool, pool,
-        S((MAX_NB + 512 // BS + 2,), i32))}
+    lowered = {"gpt": _chat_cell_chunk_lowered(one_chip, 512, MAX_NB)}
     for name, mod, cfg in (("laguna", laguna, test_laguna.TINY),
                            ("kimi", kimi_k2, test_kimi_k2.TINY)):
         model, n = serving(cfg), 64
@@ -913,7 +988,9 @@ def test_the_chunk_programs_are_what_they_were_before_the_step_queued_them(
             for name, text in texts.items()} == CHUNK_TEXT_AT_PR46
 
 
-CHUNK_TEXT_AT_PR46 = {"gpt": "99837368b616cd4b",
+# GPT-2's since PR 61, which lowers it from the parameters as served
+# (bfloat16 weights in: "99837368b616cd4b" from float32 leaves).
+CHUNK_TEXT_AT_PR46 = {"gpt": "8f3e1ee80c41bc30",
                       "laguna": "15e00d4b01149d99",
                       "kimi": "568d0ef5d4d34d94"}
 
