@@ -2,9 +2,12 @@
 Laguna (models/laguna.py, served: window and full attention layers,
 routed experts), Kimi-K2 (models/kimi_k2.py, served: latent
 attention over one pool of latent rows, a share of sigmoid-routed
-experts) and Nemotron-H (models/nemotron_h.py, served: state-space
+experts), Nemotron-H (models/nemotron_h.py, served: state-space
 layers that keep a fixed state a SEQUENCE beside one attention layer's
-keys and values, a share of not-gated experts in a latent space).
+keys and values, a share of not-gated experts in a latent space) and
+Xing4.0 (models/xing4.py, served: Kimi-K2's attention, router and
+experts on a residual path of four streams whose mixing weights are
+made from the token, ops/mhc.py).
 
 Models are pure-JAX functional: ``init(key, cfg)`` returns the param pytree;
 ``param_axes(cfg)`` returns the matching pytree of logical-axis annotations
@@ -298,4 +301,4 @@ def served_params(params, cfg):
     return params if at_rest is None else at_rest(params)
 
 
-from . import gpt, kimi_k2, laguna, nemotron_h, resnet  # noqa: E402,F401
+from . import gpt, kimi_k2, laguna, nemotron_h, resnet, xing4  # noqa: E402,F401

@@ -65,7 +65,7 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -335,6 +335,22 @@ def _block(x, p, cfg: KimiK2Config, attend, program: str):
     return x + out.reshape(b, r, m), sizes
 
 
+class Residual(NamedTuple):
+    """How the layers sit on the residual path, which the two programs
+    below take as given: ``open`` makes what the layers carry of the
+    embedded rows [b, r, m], ``block`` is one layer on it (``_block``'s
+    contract), ``close`` gives back rows [b, r, m] for the final norm.
+    ``PLAIN`` is this family's ``x <- x + F(norm(x))``; a family that
+    keeps the attention, the router and the experts and changes the
+    path (models/xing4.py) hands in its own."""
+    open: Callable
+    block: Callable
+    close: Callable
+
+
+PLAIN = Residual(lambda x: x, _block, lambda x: x)
+
+
 def _head(params, x, cfg: KimiK2Config):
     x = _rmsnorm(x, params["norm_f"], cfg.rms_norm_eps)
     return jnp.einsum("brm,mv->brv", x, params["head"])
@@ -366,7 +382,7 @@ def _counters(sizes, rows: int, cfg: KimiK2Config, q: int, in_runs):
 
 
 def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
-                 firsts=None):
+                 firsts=None, residual: Residual = PLAIN):
     """One decode step (models/gpt.py ``forward_step``'s contract) over
     ONE pool of latent rows ``[layers, num_blocks, block_size,
     row_width]``, in the absorbed form: each row's latent row is
@@ -374,7 +390,8 @@ def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
     queries, taken into the latent space, attend the pool as stored.
 
     Returns (logits [b, q, vocab], ids [b + 4, q] int32, pool): rows b
-    on of ``ids`` are ``COUNTERS``."""
+    on of ``ids`` are ``COUNTERS``. ``residual`` is the path the layers
+    sit on (``Residual``)."""
     from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
                                           paged_attention_latent)
     from . import unpack_step
@@ -383,7 +400,7 @@ def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
      slot_offsets, _) = unpack_step(packed, q, firsts=firsts)
     B, Q = tokens.shape
     nope, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    x = params["embed"][tokens]
+    x = residual.open(params["embed"][tokens])
     sizes = []
     for li, p in enumerate(params["layers"]):
 
@@ -402,10 +419,10 @@ def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
                     scale=cfg.softmax_scale, name="attn_latent")
             return jnp.einsum("brhc,chd->brhd", o_lat, w_uv)
 
-        x, s = _block(x, p, cfg, attend, "decode")
+        x, s = residual.block(x, p, cfg, attend, "decode")
         if s is not None:
             sizes.append(s)
-    logits = _head(params, x, cfg)
+    logits = _head(params, residual.close(x), cfg)
     ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     ids = jnp.concatenate([ids, _counters(
         sizes, B * Q, cfg, Q, kv_pages_in_runs_x1000(
@@ -451,12 +468,15 @@ def _chunk_attention(q_nope, q_rope, rows, ctx, ctx_len, w_ukv,
     return o.reshape(H, n, -1).transpose(1, 0, 2)   # [groups, hg, 1, n, v]
 
 
-def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config):
+def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config,
+                          residual: Residual = PLAIN):
     """One span of a prompt as one program (models/gpt.py
     ``forward_prefill_chunk``'s contract, over one latent pool):
     ``tokens`` [1, n], ``table`` = ``[block table | destination |
     ctx_len | last]``. Every layer reads the pool as it came in; the
-    span's latent rows are written after the last layer.
+    span's latent rows are written after the last layer. ``residual``
+    is the path the layers sit on (``Residual``); the head runs on the
+    one row that comes back, closed alone.
 
     Returns (row [vocab], id, pool)."""
     from ..llm.kv_cache import scatter_span
@@ -468,7 +488,7 @@ def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config):
     nb = block_table.shape[0]
     positions = jnp.minimum(ctx_len + jnp.arange(n, dtype=jnp.int32),
                             cfg.max_seq - 1)[None]
-    x = params["embed"][tokens]
+    x = residual.open(params["embed"][tokens])
     new = []
     for li, p in enumerate(params["layers"]):
 
@@ -481,10 +501,10 @@ def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config):
                                      ctx_len, p["w_ukv"], cfg)
             return o[None]
 
-        x, _ = _block(x, p, cfg, attend, "chunk")
+        x, _ = residual.block(x, p, cfg, attend, "chunk")
     pool, = scatter_span((pool,), (jnp.stack(new)[:, 0],), dest, last + 1)
-    row = _head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
-                cfg)[0, 0]
+    row = _head(params, residual.close(
+        jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)), cfg)[0, 0]
     return row, jnp.argmax(row).astype(jnp.int32), pool
 
 

@@ -136,10 +136,9 @@ def routed(h, p, cfg: KimiK2Config):
     return out
 
 
-def layer(x, p, cfg: KimiK2Config, l: int, positions):
-    """Layer ``l`` on the whole sequence x [T, m]; ``p`` that layer's
-    parameters in any dtype."""
-    p = _f32(p)
+def attention_sublayer(x, p, cfg: KimiK2Config, positions):
+    """The attention sublayer on x [T, m], its pre-norm inside and no
+    residual added: ``Attn(RMSNorm(x)) W_o``; ``p`` float32."""
     nope, rkv, eps = cfg.qk_nope_head_dim, cfg.kv_lora_rank, cfg.rms_norm_eps
     h = rmsnorm(x, p["ln1"], eps)
     c_q = rmsnorm(h @ p["w_dq"], p["q_norm"], eps)
@@ -155,11 +154,24 @@ def layer(x, p, cfg: KimiK2Config, l: int, positions):
         [kv[..., :nope], jnp.broadcast_to(k_rope, (x.shape[0], H,
                                                    k_rope.shape[-1]))], -1)
     o = attention(q, k, kv[..., nope:], softmax_scale(cfg))
-    x = x + jnp.einsum("thd,hdm->tm", o, p["w_o"])
-    h2 = rmsnorm(x, p["ln2"], eps)
+    return jnp.einsum("thd,hdm->tm", o, p["w_o"])
+
+
+def mlp_sublayer(x, p, cfg: KimiK2Config, l: int):
+    """Layer ``l``'s MLP sublayer on x [T, m], its pre-norm inside and
+    no residual added; ``p`` float32."""
+    h2 = rmsnorm(x, p["ln2"], cfg.rms_norm_eps)
     if not cfg.routed(l):
-        return x + swiglu(h2, p["w_gu"], p["w_down"])
-    return x + routed(h2, p, cfg) + swiglu(h2, p["s_gu"], p["s_down"])
+        return swiglu(h2, p["w_gu"], p["w_down"])
+    return routed(h2, p, cfg) + swiglu(h2, p["s_gu"], p["s_down"])
+
+
+def layer(x, p, cfg: KimiK2Config, l: int, positions):
+    """Layer ``l`` on the whole sequence x [T, m]; ``p`` that layer's
+    parameters in any dtype."""
+    p = _f32(p)
+    x = x + attention_sublayer(x, p, cfg, positions)
+    return x + mlp_sublayer(x, p, cfg, l)
 
 
 def head(x, params, cfg: KimiK2Config):

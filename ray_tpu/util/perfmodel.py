@@ -117,7 +117,10 @@ def _shape(cfg) -> dict:
     elements a token; and, only where the model's sequences keep a
     state, ``state_bytes_per_seq`` (a slot's bytes, moved in and out a
     lane a step), ``state_ops_per_row`` (a decode row's state update)
-    and ``scan_ops_per_row`` (a chunk's row in the chunked scan)."""
+    and ``scan_ops_per_row`` (a chunk's row in the chunked scan); and,
+    only where the residual path is more than an addition,
+    ``stream_bytes_per_row`` and ``stream_ops_per_row`` (what a row
+    moves and computes on that path in all layers: models/xing4.py)."""
     from ..models import serving
 
     return serving(cfg).cost
@@ -192,7 +195,8 @@ def decode_step_cost(cfg, context_lens: Sequence[int],
     # A layer that keeps a state a sequence costs a row the same at any
     # context and moves the lane's whole state in and out.
     flops = (2.0 * s["matmul_weights"] * n_rows + attn
-             + s.get("state_ops_per_row", 0.0) * n_rows)
+             + (s.get("state_ops_per_row", 0.0)
+                + s.get("stream_ops_per_row", 0.0)) * n_rows)
     kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
     if param_bytes is None:
         param_bytes = s["param_bytes"]
@@ -201,6 +205,7 @@ def decode_step_cost(cfg, context_lens: Sequence[int],
     hbm = (s["streamed_params"](n_rows) * param_bytes
            + total_ctx * kvb                 # context KV read per lane
            + n_rows * kvb                    # KV write per scored row
+           + n_rows * s.get("stream_bytes_per_row", 0)
            + 2.0 * len(context_lens) * s.get("state_bytes_per_seq", 0))
     return StepCost(flops, hbm, int(n_rows))
 
@@ -226,12 +231,14 @@ def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
              + 2.0 * s["head_weights"] + s["chunk_attn_per_ctx"] * seen
              + s["chunk_ctx_ops"] * ctx
              + sum(coef * min(seen, T * w) for coef, w in s["attn_windows"])
-             + s.get("scan_ops_per_row", 0.0) * T)
+             + (s.get("scan_ops_per_row", 0.0)
+                + s.get("stream_ops_per_row", 0.0)) * T)
     kvb = s["kv_bytes_per_token"] * kv_dtype_bytes
     if param_bytes is None:
         param_bytes = s["param_bytes"]
     # A span reads the sequence's state once and writes it once.
     hbm = (s["streamed_params"](T) * param_bytes + (2.0 * T + ctx) * kvb
+           + T * s.get("stream_bytes_per_row", 0)
            + 2.0 * s.get("state_bytes_per_seq", 0))
     return StepCost(flops, hbm, T)
 

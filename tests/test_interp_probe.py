@@ -249,5 +249,8 @@ def test_no_annotation_is_built_on_a_serving_thread_without_a_session():
         with mock.patch.object(perfmodel, "_session_open", lambda: True):
             Router("quiet")._flush([], None)
             rep.handle_request("__call__", (0,), {})
-        assert [c.args[0] for c in built.call_args_list] == [
+        # (A collector's pass that falls inside these two calls is an
+        # annotation of its own, ``py.gc``, and none of theirs.)
+        assert [c.args[0] for c in built.call_args_list
+                if c.args[0] != "py.gc"] == [
             "serve.flush", "serve.handle_request"]

@@ -154,10 +154,9 @@ def test_manifest_lists_the_cell_where_the_issue_says():
     for name in mine:
         assert by_name[name]["moves"] == "serve_tokens_per_s", name
         assert by_name[name]["workloads"][0] == CELL, name
-    # A shared reader holds the cell; what counts this configuration's
-    # own fields is read in its cell alone.
-    for name in mine[:3]:
-        assert by_name[name]["workloads"] == [CELL], name
+    # A shared reader holds the cell, first: the three latent readers
+    # count field names that another configuration of this block has
+    # too (PR 62's cell stands behind this one on their lists).
     for name in ("batch_occupancy_pct", "itl_p95_ms", "engine_host_gap_ms",
                  "kv_live_peak_pct", "decode_step_ms", "decode_device_ms",
                  "device_idle_pct.serve", "engine_schedule_ms",

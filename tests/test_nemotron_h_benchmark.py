@@ -170,7 +170,7 @@ def test_manifest_lists_the_cell_where_the_issue_says():
     assert config["reduced"] == list(REDUCED)
     assert config["file"] == f"benchmark/configs/{CONFIG}.json"
     assert config["source"] == _config()["source"]
-    assert sum(w["chips"] for w in m["workloads"]) == len(m["workloads"]) == 5
+    assert sum(w["chips"] for w in m["workloads"]) == len(m["workloads"]) >= 5
     e2e = {x["name"] for x in m["end_to_end"]
            if CELL in x.get("workloads", [CELL])}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
@@ -185,7 +185,10 @@ def test_manifest_lists_the_cell_where_the_issue_says():
                  "decode_lanes_pct", "stream_hold_ms", "chunk_attn_ms",
                  "moe_expert_ms", "moe_load_max", "moe_held_rows",
                  "callers_cpu_pct"):
-        assert by_name[name]["workloads"][-1] == CELL, name
+        # Last when PR 57 appended it; a later cell stands behind it.
+        after = by_name[name]["workloads"]
+        after = after[after.index(CELL) + 1:]
+        assert after in ([], ["xing4-serve-rag"]), name
     # Left to a ``benchmark`` PR: the issue names these by group only
     # ("the engine's"), or not at all (``attn_full_ms``), and tests
     # under benchmark/tests, which this kind of PR may not edit, pin

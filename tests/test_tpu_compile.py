@@ -1157,6 +1157,192 @@ def test_nemotron_chunk_program_scans_from_a_slot_into_a_slot(
     assert c.memory_analysis().temp_size_in_bytes < 250e6
 
 
+# -- Xing4.0 at the cell's shapes (benchmark/configs/xing4-29b-serve) ----------
+
+XING_BLOCKS, XING_MAX_SEQ, XING_LAYERS, XING_STREAMS = 19456, 4736, 7, 14336
+
+
+@pytest.mark.parametrize("rows", [64, 2048], ids=["decode_64", "chunk_2048"])
+def test_mhc_kernels_compile_at_the_published_widths(one_chip, rows):
+    """``mhc_pre`` and ``mhc_post`` alone: four streams of 3,584 as one
+    row of 14,336, ``Phi`` [14,336, 24], a decode step's 64 rows (one
+    block, padded to a lane tile for the transposes) and a chunk's
+    2,048 (eight grid steps of 256); both carry their names, which is
+    how the benchmark's readers find them, and ``mhc_post`` writes the
+    streams it was donated."""
+    from ray_tpu.ops import mhc
+
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    kw = dict(n=4, iters=20, eps=1e-6, norm_eps=1e-6)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        pre = _compile(lambda X, phi, a, b: mhc.mhc_pre(X, phi, a, b, **kw),
+                       S((rows, XING_STREAMS), bf16),
+                       S((XING_STREAMS, 24), bf16), S((3,), f32),
+                       S((24,), f32))
+        post = jax.jit(lambda X, y, coef: mhc.mhc_post(X, y, coef, n=4),
+                       donate_argnums=0).lower(
+            S((rows, XING_STREAMS), bf16), S((rows, 3584), bf16),
+            S((rows, 128), f32)).compile()
+    for name, c in (("mhc_pre", pre), ("mhc_post", post)):
+        (line,) = [l for _, l in _mosaic_lines(c.as_text())]
+        assert f"%{name}" in line.split(" = ")[0]
+    assert "input_output_alias={ {}: (0, {}" in post.as_text()
+    assert post.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.fixture(scope="module")
+def xing_programs(one_chip):
+    """The engine's own decode and chunk programs for the served cut of
+    Xing4.0-29B-A4B (7 layers: dense layer 0 and six routed ones, all 64
+    experts, the whole vocabulary), compiled for the described v5e at
+    the cell's shapes: 64 lanes over ONE latent pool of 19,456 blocks
+    of 16 rows of 640, a 2,048-token span behind a 4,736-token table.
+    ~35 s for the two."""
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.models import xing4
+
+    cfg = xing4.Xing4Config(num_hidden_layers=XING_LAYERS,
+                            first_k_dense_replace=1, max_seq=XING_MAX_SEQ)
+    assert cfg.row_width == KIMI_ROW
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: xing4.init(jax.random.key(0), cfg)))
+    B, i32 = CELL_B, jnp.int32
+    pool = S((XING_LAYERS, XING_BLOCKS, BS, KIMI_ROW), jnp.bfloat16)
+    max_nb = XING_MAX_SEQ // BS
+    decode, chunk = _jit_programs(cfg)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return {
+            "param_leaves": len(jax.tree_util.tree_leaves(params)),
+            "decode": decode.lower(
+                params, S((B, step_columns(1).table + max_nb), i32), pool,
+                q=1, firsts=S((B,), i32)).compile(),
+            "chunk": chunk.lower(
+                params, S((1, 2048), i32), pool,
+                S((max_nb + 2048 // BS + 2,), i32)).compile(),
+        }
+
+
+def _xing_config_file():
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "xing4-29b-serve.json")) as f:
+        return json.load(f)
+
+
+def test_xing_decode_program_mixes_the_streams_around_kimis_kernels(
+        xing_programs):
+    """The decode program at the cell's shapes: Kimi's absorbed kernel
+    once a layer and its grouped product twice a routed layer, and
+    around each of the 14 sublayers one ``mhc_pre`` and one ``mhc_post``
+    under their names; the ONE pool is donated and aliased to its
+    output, written by one in-place scatter a layer, nothing pool-sized
+    is copied; the ids come back with FIVE counter rows; the
+    temporaries are what the configuration's file says they are."""
+    c = xing_programs["decode"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert _mosaic_calls(text) == {
+        "attn_latent": 7, "moe_experts_decode": 12, "mhc_pre_decode": 14,
+        "mhc_post_decode": 14}
+    layer = XING_BLOCKS * BS * KIMI_ROW
+    assert _results(text, "copy", "transpose", "copy-start",
+                    "dynamic-update-slice", "concatenate", "pad",
+                    at_least=layer) == []
+    # One scatter a layer, in place (the compiler splits one of the
+    # seven in two: eight fusions, each on the aliased pool).
+    pool = f"bf16[{XING_LAYERS},{XING_BLOCKS},{BS},{KIMI_ROW}]"
+    scatters = _results(text, "scatter", at_least=layer)
+    assert set(scatters) == {("scatter", pool)} and 7 <= len(scatters) <= 8
+    assert _aliased(text) == {xing_programs["param_leaves"] + 1: 2}
+    entry = text[text.index("\nENTRY "):]
+    root = next(line for line in entry.splitlines()
+                if line.lstrip().startswith("ROOT "))
+    assert f"s32[{CELL_B + 5},1]" in root \
+        and f"bf16[{CELL_B},1,131072]" in root
+    temp = c.memory_analysis().temp_size_in_bytes
+    said = _xing_config_file()["programs_compiled_for_a_described_v5e"]
+    assert temp < 50e6
+    assert abs(temp - said["decode_temporaries_bytes"]) < 0.1 * temp
+
+
+def test_xing_chunk_program_writes_its_span_in_place(xing_programs):
+    """A 2,048-token chunk behind a 4,736-token table, ONE program:
+    ``chunk_attn`` once a layer, ``moe_experts_chunk`` twice a routed
+    layer, the two residual kernels around all 14 sublayers, each
+    ``mhc_post`` writing the 58.7 MB of streams it was handed (its
+    first operand is its result's buffer: no second copy of the streams
+    a sublayer); the pool donated, aliased and written by ONE in-place
+    scatter; the head on the one row that comes back; the temporaries
+    are what the configuration's file says they are, and leave the
+    13.87 GB of weights and pool their room."""
+    c = xing_programs["chunk"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_prefill_chunk")
+    assert _mosaic_calls(text) == {
+        "chunk_attn": 7, "moe_experts_chunk": 12, "mhc_pre_chunk": 14,
+        "mhc_post_chunk": 14}
+    layer = XING_BLOCKS * BS * KIMI_ROW
+    assert _results(text, "copy", "transpose", "copy-start", "dynamic-slice",
+                    "dynamic-update-slice", "concatenate", "pad",
+                    at_least=layer) == []
+    assert _results(text, "scatter", at_least=layer) == [
+        ("scatter", f"bf16[{XING_LAYERS},{XING_BLOCKS},{BS},{KIMI_ROW}]")]
+    assert _aliased(text) == {xing_programs["param_leaves"] + 1: 2}
+    assert "[2048,131072]" not in text and "[1,2048,131072]" not in text
+    # ONE copy the size of the streams, their opening as four copies of
+    # the embedded rows; after it every ``mhc_post`` overwrites what it
+    # is given.
+    assert len(_results(text, "copy", at_least=2048 * XING_STREAMS)) == 1
+    temp = c.memory_analysis().temp_size_in_bytes
+    said = _xing_config_file()["programs_compiled_for_a_described_v5e"]
+    assert temp < 500e6
+    assert abs(temp - said["chunk_2048_behind_context_temporaries_bytes"]) \
+        < 0.1 * temp
+
+
+def test_kimis_decode_program_is_what_it_was_before_it_took_its_residual(
+        one_chip, as_tpu):
+    """PR 62 hands ``kimi_k2.forward_step`` and
+    ``forward_prefill_chunk`` their residual path (``Residual``;
+    ``PLAIN`` for Kimi itself) so that models/xing4.py can run the same
+    loops on four streams. Kimi's own decode program, at its tiny test
+    shapes with one row a lane and with three, lowers for the TPU to the
+    text it lowered to at the parent commit (sha256, recorded there with
+    this function); its chunk program is held by
+    ``test_the_chunk_programs_are_what_they_were...`` above."""
+    import hashlib
+    import re
+
+    import test_kimi_k2
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.models import kimi_k2, serving
+
+    cfg = test_kimi_k2.TINY
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: kimi_k2.init(jax.random.key(0), cfg)))
+    model = serving(cfg)
+    pool = S((3, 32, BS, model.kinds[0].rows[0]), jnp.bfloat16)
+    got = {}
+    for q in (1, 3):
+        text = _jit_programs(cfg)[0].lower(
+            params, S((8, step_columns(q).table + model.max_seq // BS),
+                      jnp.int32), pool, q=q,
+            firsts=S((8,), jnp.int32)).as_text()
+        text = re.sub(r'backend_config = "[^\n]*?"(?=[,}\s])',
+                      'backend_config = "..."', text)
+        got[q] = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == {1: "9958dbb64fb52d27", 3: "211888ce6c170213"}
+
+
 # -- the stored kernels at head_dim 128 are what they were (PR 59) ------------
 
 STORED_KERNELS_AT_PR57 = {
