@@ -286,7 +286,10 @@ def _init_layer(key, cfg: KimiK2Config, routed: bool) -> dict:
 def _mlp(h2, p, cfg: KimiK2Config, program: str):
     """h2 [T, m] -> (out [T, m], the held experts' tokens [held] or
     None for a dense layer). The grouped product's kernel is
-    ``moe_experts_<program>`` on a device trace."""
+    ``moe_experts_<program>`` on a device trace, with ``_r<rows>``
+    behind it where the call's rows give an expert (of ALL
+    ``n_routed_experts``, which the call is told: ``w1`` holds a share)
+    32 or more and the product takes a taller tile than 16."""
     if "router" not in p:
         return _swiglu(h2, p["w_gu"], p["w_down"]), None
     with jax.named_scope("moe_route"):
@@ -296,7 +299,7 @@ def _mlp(h2, p, cfg: KimiK2Config, program: str):
     with jax.named_scope("moe_experts"):
         y, sizes = moe.routed_experts(
             h2, experts, weights, p["w1"], p["w2"], first=cfg.first_expert,
-            name=f"moe_experts_{program}")
+            n_experts=cfg.n_routed_experts, name=f"moe_experts_{program}")
     return y + _swiglu(h2, p["s_gu"], p["s_down"]), sizes
 
 

@@ -296,7 +296,9 @@ def _relu2(h, w1, w2):
 def _experts(h, p, cfg: NemotronHConfig, program: str):
     """h [T, m] -> (out [T, m], the held experts' tokens [held]). The
     grouped products' kernel is ``moe_experts_<program>`` on a device
-    trace; they run at the latent width."""
+    trace (``_r<rows>`` behind it where an expert of ALL
+    ``n_routed_experts``, which the call is told, expects 32 rows or
+    more: ops/moe.py ``tile_rows``); they run at the latent width."""
     with jax.named_scope("moe_route"):
         _, experts, weights = moe.route_sigmoid(
             h, p["router"], p["router_bias"], cfg.num_experts_per_tok,
@@ -304,8 +306,8 @@ def _experts(h, p, cfg: NemotronHConfig, program: str):
     with jax.named_scope("moe_experts"):
         y, sizes = moe.routed_experts(
             jnp.dot(h, p["w_dn"]), experts, weights, p["w1"], p["w2"],
-            first=cfg.first_expert, name=f"moe_experts_{program}",
-            activation="relu2")
+            first=cfg.first_expert, n_experts=cfg.n_routed_experts,
+            name=f"moe_experts_{program}", activation="relu2")
     return jnp.dot(y, p["w_up"]) + _relu2(h, p["s1"], p["s2"]), sizes
 
 

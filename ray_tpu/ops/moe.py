@@ -10,12 +10,20 @@ dropped):
             largest, renormalised to sum to ``scale``. ``route_sigmoid``
             is the other scoring served: a sigmoid an expert, chosen by
             score plus a learned bias, weighed by the score without it.
+  tile      the rows of a tile of the grouped product follow the rows
+            an expert is expected to get, ``T*k // n_experts`` over ALL
+            the experts the router scores (``tile_rows``; a static fact
+            of the call): 16 where an expert gets a handful (a decode
+            step, a short chunk), 64 where it gets 32 or more (a 512- to
+            2,048-token chunk at top-4 of 64). A weight tile pushed
+            into the MXU costs the same whatever rows follow it, so a
+            tall tile is that many fewer pushes and grid steps.
   plan      a counting sort of the T*k assignments by expert
             (``dispatch_plan``): each expert's rows are padded to whole
-            tiles of ``TILE_ROWS``, so a tile belongs to ONE expert. The
-            row buffer is ``T*k + E*(TILE_ROWS-1)`` rows, rounded up:
-            what the assignments need if every expert gets a ragged
-            tail. Tiles past the used ones are skipped.
+            tiles, so a tile belongs to ONE expert. The row buffer is
+            ``T*k + E*(tile-1)`` rows, rounded up: what the assignments
+            need if every expert gets a ragged tail. Tiles past the
+            used ones are skipped.
   product   ``grouped_matmul``: a Pallas kernel (``moe_experts...`` on
             a device trace) that walks the row tiles, takes each tile's
             expert from a scalar-prefetched table and multiplies the
@@ -58,11 +66,37 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 HIGHEST = jax.lax.Precision.HIGHEST
-# Rows of a tile of the grouped product: the sublane tile of a 16-bit
-# row buffer, the smallest Mosaic takes unpadded. A decode step gives an
-# expert ~2 rows, so a larger tile only adds padding rows there; no
-# second value has been measured.
-TILE_ROWS = 16
+# The least rows of a tile of the grouped product: the sublane tile of a
+# 16-bit row buffer, the smallest Mosaic takes unpadded. A decode step
+# gives an expert ~2 rows, so a larger tile only adds padding rows there.
+MIN_TILE_ROWS = 16
+# The tall tile, and the rows an expert must expect to be given it.
+# Settled on a v5e at Xing4.0's widths ([M, 3584] x [64, 3584, 2048] and
+# [M, 1024] x [64, 1024, 3584], top-4 of 64; PERF.md section 6, PR 63).
+# The whole chunk program of 7 layers, ms, by tile, at 32 / 64 / 96 /
+# 128 rows an expert (chunks of 512 / 1,024 / 1,536 / 2,048 tokens):
+#     16:  28.8  38.9  53.7  65.3
+#     32:  24.6  32.9  45.3  54.6
+#     64:  23.7  30.8  42.5  51.5
+#    128:  24.8  31.0  41.4  54.5
+# The two products of one layer alone, 2,048 tokens: 5.52 at 16, 2.94 at
+# 64, 2.79 at 128, 2.60 at 256 (their weights' bytes take 1.72). A tile
+# that holds ALL of an expert's rows is fastest in the kernel (the next
+# expert's weights are fetched under every tile, not under an expert's
+# last tile alone), but the row buffer is T*k + E*(tile-1) rows whatever
+# the routing, and what XLA does a buffer row (the gather, the
+# activation) costs more than the kernel gets back from 128 rows on.
+# From 192 to 512 rows an expert 64, 128 and 256 read within 3% of one
+# another. Below 32 rows an expert nothing was measured: no program of
+# the benchmark's other cells gives an expert more than 22.
+TALL_TILE_ROWS = 64
+TALL_TILE_FROM = 32
+# What a tall tile's blocks may take of VMEM, all double-buffered by the
+# pipeline: room for a weight block as wide as Xing4.0's widest (31 MB;
+# the grid walks the rows once a column block, and at a third of that
+# width the two products of a layer read 3.43 for 2.94). v5e has 128 MiB,
+# of which a kernel gets 16 unless it asks.
+_VMEM_BUDGET = 48 * 1024 * 1024
 
 
 @dataclass(frozen=True)
@@ -120,27 +154,38 @@ def route_sigmoid(x, wg, bias, k: int, scale: float = 1.0):
     return scores, experts.astype(jnp.int32), weights
 
 
-def plan_rows(n_assignments: int, n_experts: int) -> int:
+def tile_rows(n_assignments: int, n_experts: int) -> int:
+    """Rows of a tile of the grouped product, from the rows an expert
+    is expected to get: ``n_assignments`` (T * k) over the ``n_experts``
+    they are routed over (all of the model's, not a held share). 16
+    below 32 rows an expert, 64 from there on (the readings stand by
+    the constants)."""
+    if n_assignments // n_experts < TALL_TILE_FROM:
+        return MIN_TILE_ROWS
+    return TALL_TILE_ROWS
+
+
+def plan_rows(n_assignments: int, n_experts: int, tile: int) -> int:
     """Rows of the tile-aligned buffer: the assignments plus a ragged
-    tail an expert, in whole tiles. A bound, not a capacity."""
-    rows = n_assignments + n_experts * (TILE_ROWS - 1)
-    return -(-rows // TILE_ROWS) * TILE_ROWS
+    tail an expert held here, in whole tiles. A bound, not a capacity."""
+    rows = n_assignments + n_experts * (tile - 1)
+    return -(-rows // tile) * tile
 
 
-def dispatch_plan(experts, n_experts: int, first=0):
+def dispatch_plan(experts, n_experts: int, tile: int, first=0):
     """Counting sort of the assignments ``experts`` [T, k] by expert,
     for the ``n_experts`` experts held here (global ids ``first`` ..
     ``first + n_experts - 1``; an assignment to another expert is not
-    ours and gets no row).
+    ours and gets no row), in tiles of ``tile`` rows.
 
-    Returns ``(sizes [E], dest [T*k], src [M], tile_expert [M/tm],
+    Returns ``(sizes [E], dest [T*k], src [M], tile_expert [M/tile],
     n_used)``: tokens a held expert got; the buffer row of each
     assignment (M where it is not held: out of range); the token each
     buffer row copies (padding rows copy token 0: finite, never read
     back); the expert of each row tile; and how many tiles hold rows."""
     T, k = experts.shape
-    A, E, tm = T * k, n_experts, TILE_ROWS
-    M = plan_rows(A, E)
+    A, E, tm = T * k, n_experts, tile
+    M = plan_rows(A, E, tm)
     local = experts.reshape(A) - first
     onehot = local[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :]
     held = onehot.any(axis=1)
@@ -163,17 +208,37 @@ def dispatch_plan(experts, n_experts: int, first=0):
     return sizes, dest, src, jnp.minimum(tile_expert, E - 1), n_used
 
 
-def _tile_cols(k: int, n: int, itemsize: int) -> int:
-    """Columns of a weight block: about 2 MiB of one expert's weights
+def _tile_cols(k: int, n: int, itemsize: int, tile: int) -> int:
+    """Columns of a weight block, a multiple of 128 lanes that divides
+    n. Beside a 16-row tile: about 2 MiB of one expert's weights
     (double-buffered by the pipeline, well inside v5e's 16 MiB of
-    scoped VMEM), a multiple of 128 lanes that divides n."""
-    want = max(128, (2 * 1024 * 1024) // (k * itemsize) // 128 * 128)
+    scoped VMEM). Beside a tall tile the widest that ``_VMEM_BUDGET``
+    holds: the grid walks every row tile once a column block, and tall
+    tiles no longer hide that many readings of the rows."""
+    if tile == MIN_TILE_ROWS:
+        want = max(128, (2 * 1024 * 1024) // (k * itemsize) // 128 * 128)
+    else:
+        want = max(128, _tall_cols(k, itemsize, tile) // 128 * 128)
     if n <= want or n % 128:
         return n
     tn = want
     while n % tn:
         tn -= 128
     return tn
+
+
+def _vmem_bytes(k: int, tn: int, itemsize: int, tile: int) -> int:
+    """What one grid step's blocks take: the row tile, the weight block
+    and the result twice each (the pipeline's two buffers), and the
+    product in float32 before its cast."""
+    return (2 * itemsize * (tile * k + k * tn + tile * tn) + 4 * tile * tn)
+
+
+def _tall_cols(k: int, itemsize: int, tile: int) -> int:
+    """The most columns whose blocks ``_VMEM_BUDGET`` holds."""
+    fixed = _vmem_bytes(k, 0, itemsize, tile)
+    a_column = _vmem_bytes(k, 1, itemsize, tile) - fixed
+    return (_VMEM_BUDGET - fixed) // a_column
 
 
 def _gmm_kernel(tile_expert_ref, n_used_ref, x_ref, w_ref, o_ref):
@@ -194,10 +259,18 @@ def _gmm_kernel(tile_expert_ref, n_used_ref, x_ref, w_ref, o_ref):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_gmm(M: int, k: int, n: int, E: int, x_dtype, w_dtype,
+def _make_gmm(M: int, k: int, n: int, E: int, tm: int, x_dtype, w_dtype,
               out_dtype, interpret: bool, name: str):
-    tm = TILE_ROWS
-    tn = _tile_cols(k, n, jnp.dtype(w_dtype).itemsize)
+    itemsize = jnp.dtype(w_dtype).itemsize
+    tn = _tile_cols(k, n, itemsize, tm)
+    tall = {}
+    if tm > MIN_TILE_ROWS:
+        # The name says that the tall tile engaged; a 16-row call is
+        # the kernel it was, letter for letter.
+        name = f"{name}_r{tm}"
+        tall["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=max(
+                16 << 20, _vmem_bytes(k, tn, itemsize, tm) + (8 << 20)))
 
     def row_tile(j, i, tile_expert, n_used):
         return (jnp.maximum(jnp.minimum(i, n_used[0] - 1), 0), 0)
@@ -216,18 +289,20 @@ def _make_gmm(M: int, k: int, n: int, E: int, x_dtype, w_dtype,
     return pl.pallas_call(
         _gmm_kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, n), out_dtype),
-        interpret=interpret, name=name)
+        interpret=interpret, name=name, **tall)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def grouped_matmul(x, w, tile_expert, n_used, name: str = "moe_experts"):
     """``out[tile] = x[tile] @ w[tile_expert[tile]]`` for the first
     ``n_used`` row tiles of ``x`` [M, k], zeros after them; ``w`` is
-    [E, k, n]. A Pallas kernel, ``name`` on a device trace, run by the
-    Pallas interpreter on the CPU backend (tests)."""
+    [E, k, n]; a tile is ``M / len(tile_expert)`` rows. A Pallas
+    kernel, ``name`` on a device trace (``<name>_r<rows>`` where a tile
+    is more than 16 rows), run by the Pallas interpreter on the CPU
+    backend (tests)."""
     (M, k), (E, _, n) = x.shape, w.shape
-    call = _make_gmm(M, k, n, E, x.dtype, w.dtype, x.dtype,
-                     jax.default_backend() == "cpu", name)
+    call = _make_gmm(M, k, n, E, M // tile_expert.shape[0], x.dtype,
+                     w.dtype, x.dtype, jax.default_backend() == "cpu", name)
     return call(tile_expert, jnp.reshape(n_used, (1,)).astype(jnp.int32),
                 x, w)
 
@@ -241,10 +316,11 @@ def _gmm_bwd(name, res, g):
     """Plain XLA, tile by tile: the layer stays trainable, at the cost
     of a gathered copy of each tile's expert weights."""
     x, w, tile_expert, n_used = res
-    tiles = x.shape[0] // TILE_ROWS
+    tiles = tile_expert.shape[0]
+    tm = x.shape[0] // tiles                # the forward's tile
     live = (jnp.arange(tiles) < n_used)[:, None, None]
-    xt = x.reshape(tiles, TILE_ROWS, -1)
-    gt = jnp.where(live, g.reshape(tiles, TILE_ROWS, -1), 0)
+    xt = x.reshape(tiles, tm, -1)
+    gt = jnp.where(live, g.reshape(tiles, tm, -1), 0)
     dx = jnp.einsum("tmn,tkn->tmk", gt, w[tile_expert]).reshape(x.shape)
     dw = jnp.zeros_like(w).at[tile_expert].add(
         jnp.einsum("tmk,tmn->tkn", xt, gt).astype(w.dtype))
@@ -255,18 +331,22 @@ grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def routed_experts(x, experts, weights, w1, w2, *, first=0,
+                   n_experts: Optional[int] = None,
                    name: str = "moe_experts", activation: str = "swiglu"):
     """The grouped product and the combine: ``sum_j weights[t, j] *
     Expert_{experts[t, j]}(x[t])`` over the experts held here (``w1``
     [E, d, 2f], ``w2`` [E, f, d], global ids from ``first``), every
-    assignment computed; ``name`` is the grouped product's kernel on a
-    device trace. ``activation`` "relu2" is the expert that is not
-    gated, ``relu(x w1)^2 w2`` with ``w1`` [E, d, f]. Returns (y [T, d]
-    in x's dtype, sizes [E]: the tokens each held expert got)."""
+    assignment computed. ``n_experts`` is how many experts ``experts``
+    was routed over, where that is more than the E held here: the rows
+    of a tile follow what ONE of them expects (``tile_rows``). ``name``
+    is the grouped product's kernel on a device trace. ``activation``
+    "relu2" is the expert that is not gated, ``relu(x w1)^2 w2`` with
+    ``w1`` [E, d, f]. Returns (y [T, d] in x's dtype, sizes [E]: the
+    tokens each held expert got)."""
     T, k = experts.shape
     E, _, f2 = w1.shape
     sizes, dest, src, tile_expert, n_used = dispatch_plan(
-        experts, E, first)
+        experts, E, tile_rows(T * k, n_experts or E), first)
     rows = x[src]                                    # [M, d], by expert
     gu = grouped_matmul(rows, w1, tile_expert, n_used, name)
     if activation == "relu2":
@@ -308,7 +388,8 @@ def moe_apply(params, x, cfg: MoEConfig, *, ep_axis: Optional[str] = None):
         first = jax.lax.axis_index(ep_axis) * params["w1"].shape[0]
     probs, experts, weights = route(x, params["wg"], cfg.k, cfg.scale)
     y, sizes = routed_experts(x, experts, weights, params["w1"],
-                              params["w2"], first=first)
+                              params["w2"], first=first,
+                              n_experts=cfg.n_experts)
     if ep_axis:
         y = jax.lax.psum_scatter(y, ep_axis, scatter_dimension=0, tiled=True)
         sizes = jax.lax.all_gather(sizes, ep_axis, axis=0, tiled=True)

@@ -182,7 +182,7 @@ def test_moe_drops_no_token_under_skewed_routing():
     (the old fixed-capacity layer dropped most of them): every token
     still gets both of its experts, and its two weights sum to the
     scale."""
-    from ray_tpu.ops.moe import TILE_ROWS, dispatch_plan, route
+    from ray_tpu.ops.moe import dispatch_plan, route, tile_rows
 
     cfg = MoEConfig(d_model=8, d_ff=16, n_experts=8, k=2, scale=2.5)
     params = moe_init(jax.random.key(0), cfg)
@@ -191,10 +191,11 @@ def test_moe_drops_no_token_under_skewed_routing():
     _, experts, weights = route(x, params["wg"], cfg.k, cfg.scale)
     assert set(np.asarray(experts).ravel().tolist()) == {2, 5}
     assert jnp.abs(weights.sum(-1) - 2.5).max() < 1e-5
-    sizes, dest, _, _, n_used = dispatch_plan(experts, cfg.n_experts)
+    tile = tile_rows(96 * cfg.k, cfg.n_experts)     # what the call uses
+    sizes, dest, _, _, n_used = dispatch_plan(experts, cfg.n_experts, tile)
     assert sizes.tolist() == [0, 0, 96, 0, 0, 96, 0, 0]
     assert len(set(np.asarray(dest).tolist())) == 192     # a row each
-    assert int(n_used) == 2 * 96 // TILE_ROWS
+    assert int(n_used) == 2 * 96 // tile
     y, _ = moe_apply(params, x, cfg)
     ref = _moe_dense_reference(params, x, cfg)
     assert jnp.abs(y - ref).max() < 2e-5

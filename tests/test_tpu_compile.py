@@ -467,14 +467,16 @@ def laguna_programs(one_chip):
         lowered = decode.lower(
             params, S((B, step_columns(1, nbw).table + max_nb), i32),
             full, full, window, window, q=1, firsts=S((B,), i32))
+        low_chunk = chunk.lower(
+            params, S((1, 512), i32), full, full,
+            S((max_nb + 512 // BS + 2,), i32), window, window,
+            S((nbw + 1 + 512 // BS,), i32))
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode_kernels": _kernel_bodies(lowered.as_text()),
             "decode": lowered.compile(),
-            "chunk": chunk.lower(
-                params, S((1, 512), i32), full, full,
-                S((max_nb + 512 // BS + 2,), i32), window, window,
-                S((nbw + 1 + 512 // BS,), i32)).compile(),
+            "chunk": low_chunk.compile(),
+            **_as_lowered({"decode": lowered, "chunk": low_chunk}),
         }
 
 
@@ -503,6 +505,32 @@ def _kernel_bodies(lowered_text):
             found.append((name, hashlib.sha256(asm.encode())
                           .hexdigest()[:16]))
     return found
+
+
+def _text_sha(text):
+    """sha256 of a LOWERED program's text with each Mosaic call's
+    serialized body cut out: the body carries the path of the checkout
+    it was traced in (``_kernel_bodies`` reads the bodies)."""
+    import hashlib
+    import re
+
+    text = re.sub(r'backend_config = "[^\n]*?"(?=[,}\s])',
+                  'backend_config = "..."', text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _as_lowered(lowered: dict):
+    """What ``test_the_programs_that_keep_the_16_row_tile...`` holds of
+    a model's LOWERED programs: each one's text, and the grouped
+    product's kernels in it."""
+    texts = {name: low.as_text() for name, low in lowered.items()}
+    return {
+        "texts": {name: _text_sha(text) for name, text in texts.items()},
+        "moe_kernels": {name: sorted({
+            kernel for kernel in _kernel_bodies(text)
+            if kernel[0].startswith("moe_experts")})
+            for name, text in texts.items()},
+    }
 
 
 def _mosaic_lines(text):
@@ -738,17 +766,21 @@ def kimi_programs(one_chip):
     decode, chunk = _jit_programs(cfg)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        return {
-            "param_leaves": len(jax.tree_util.tree_leaves(params)),
+        lowered = {
             "decode": decode.lower(
                 params, S((B, step_columns(1).table + max_nb), i32), pool,
-                q=1, firsts=S((B,), i32)).compile(),
+                q=1, firsts=S((B,), i32)),
             "chunk": chunk.lower(
                 params, S((1, 512), i32), pool,
-                S((max_nb + 512 // BS + 2,), i32)).compile(),
+                S((max_nb + 512 // BS + 2,), i32)),
             "cold_chunk": chunk.lower(
                 params, S((1, 512), i32), pool,
-                S((512 // BS + 2,), i32)).compile(),
+                S((512 // BS + 2,), i32)),
+        }
+        return {
+            "param_leaves": len(jax.tree_util.tree_leaves(params)),
+            **{name: low.compile() for name, low in lowered.items()},
+            **_as_lowered(lowered),
         }
 
 
@@ -978,13 +1010,11 @@ def test_the_chunk_programs_are_what_they_were_before_the_step_queued_them(
     chunk lengths keep their compile-cache keys (ROADMAP A7: an
     XLA-only program's key survives any move of its source); whoever
     changes a chunk program records the new text knowingly."""
-    import hashlib
-
     texts = _chunk_program_texts(one_chip)
     assert "tpu_custom_call" not in texts["gpt"]
     assert 'kernel_name = "chunk_attn"' in texts["laguna"]
     assert 'kernel_name = "chunk_attn"' in texts["kimi"]
-    assert {name: hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert {name: _text_sha(text)
             for name, text in texts.items()} == CHUNK_TEXT_AT_PR46
 
 
@@ -1081,14 +1111,16 @@ def nemotron_programs(one_chip):
         lowered = decode.lower(
             params, S((B, step_columns(1, 0, True).table + max_nb), i32),
             kv, kv, *state, q=1, firsts=S((B,), i32))
+        # block table, 32 blocks written, ctx_len, last, two slots
+        low_chunk = chunk.lower(
+            params, S((1, 512), i32), kv, kv,
+            S((max_nb + 512 // BS + 4,), i32), *state)
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode_kernels": _kernel_bodies(lowered.as_text()),
             "decode": lowered.compile(),
-            # block table, 32 blocks written, ctx_len, last, two slots
-            "chunk": chunk.lower(
-                params, S((1, 512), i32), kv, kv,
-                S((max_nb + 512 // BS + 4,), i32), *state).compile(),
+            "chunk": low_chunk.compile(),
+            **_as_lowered({"decode": lowered, "chunk": low_chunk}),
         }
 
 
@@ -1216,14 +1248,16 @@ def xing_programs(one_chip):
     decode, chunk = _jit_programs(cfg)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = decode.lower(
+            params, S((B, step_columns(1).table + max_nb), i32), pool,
+            q=1, firsts=S((B,), i32))
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
-            "decode": decode.lower(
-                params, S((B, step_columns(1).table + max_nb), i32), pool,
-                q=1, firsts=S((B,), i32)).compile(),
+            "decode": lowered.compile(),
             "chunk": chunk.lower(
                 params, S((1, 2048), i32), pool,
                 S((max_nb + 2048 // BS + 2,), i32)).compile(),
+            **_as_lowered({"decode": lowered}),
         }
 
 
@@ -1274,8 +1308,9 @@ def test_xing_decode_program_mixes_the_streams_around_kimis_kernels(
 
 def test_xing_chunk_program_writes_its_span_in_place(xing_programs):
     """A 2,048-token chunk behind a 4,736-token table, ONE program:
-    ``chunk_attn`` once a layer, ``moe_experts_chunk`` twice a routed
-    layer, the two residual kernels around all 14 sublayers, each
+    ``chunk_attn`` once a layer, ``moe_experts_chunk_r64`` twice a
+    routed layer (2,048 rows give an expert 128: the tall tile, PR 63),
+    the two residual kernels around all 14 sublayers, each
     ``mhc_post`` writing the 58.7 MB of streams it was handed (its
     first operand is its result's buffer: no second copy of the streams
     a sublayer); the pool donated, aliased and written by ONE in-place
@@ -1286,7 +1321,7 @@ def test_xing_chunk_program_writes_its_span_in_place(xing_programs):
     text = c.as_text()
     assert text.startswith("HloModule jit_llm_prefill_chunk")
     assert _mosaic_calls(text) == {
-        "chunk_attn": 7, "moe_experts_chunk": 12, "mhc_pre_chunk": 14,
+        "chunk_attn": 7, "moe_experts_chunk_r64": 12, "mhc_pre_chunk": 14,
         "mhc_post_chunk": 14}
     layer = XING_BLOCKS * BS * KIMI_ROW
     assert _results(text, "copy", "transpose", "copy-start", "dynamic-slice",
@@ -1317,9 +1352,6 @@ def test_kimis_decode_program_is_what_it_was_before_it_took_its_residual(
     text it lowered to at the parent commit (sha256, recorded there with
     this function); its chunk program is held by
     ``test_the_chunk_programs_are_what_they_were...`` above."""
-    import hashlib
-    import re
-
     import test_kimi_k2
     from ray_tpu.llm.engine import _jit_programs
     from ray_tpu.models import kimi_k2, serving
@@ -1333,13 +1365,10 @@ def test_kimis_decode_program_is_what_it_was_before_it_took_its_residual(
     pool = S((3, 32, BS, model.kinds[0].rows[0]), jnp.bfloat16)
     got = {}
     for q in (1, 3):
-        text = _jit_programs(cfg)[0].lower(
+        got[q] = _text_sha(_jit_programs(cfg)[0].lower(
             params, S((8, step_columns(q).table + model.max_seq // BS),
                       jnp.int32), pool, q=q,
-            firsts=S((8,), jnp.int32)).as_text()
-        text = re.sub(r'backend_config = "[^\n]*?"(?=[,}\s])',
-                      'backend_config = "..."', text)
-        got[q] = hashlib.sha256(text.encode()).hexdigest()[:16]
+            firsts=S((8,), jnp.int32)).as_text())
     assert got == {1: "9958dbb64fb52d27", 3: "211888ce6c170213"}
 
 
@@ -1371,3 +1400,93 @@ def test_the_stored_kernels_at_head_dim_128_lower_to_what_they_did(
     stored = [(name, sha) for name, sha in programs["decode_kernels"]
               if name.startswith("attn_")]
     assert stored == STORED_KERNELS_AT_PR57[model]
+
+
+# -- the grouped product's tile follows the rows an expert gets (PR 63) -------
+
+XING_EXPERTS, XING_TOP_K, XING_HIDDEN, XING_EXPERT_FF = 64, 4, 3584, 1024
+
+
+@pytest.mark.parametrize("rows, tile", [
+    (64, 16), (512, 64), (1024, 64), (1536, 64), (2048, 64)],
+    ids=["decode_64", "chunk_512", "chunk_1024", "chunk_1536", "chunk_2048"])
+def test_the_grouped_product_compiles_at_the_tile_its_rows_give(
+        one_chip, as_tpu, rows, tile):
+    """Xing4.0's two grouped products at their published widths
+    (``[M, 3584] x [64, 3584, 2048]`` and ``[M, 1024] x [64, 1024,
+    3584]``), at the tile and the column block ``ops/moe.py`` gives the
+    decode step and each of the four chunk lengths the cell warms: the
+    chip's compiler takes every one (a tall tile asks for the VMEM its
+    whole-width weight block needs), and the kernel's name says which
+    tile ran: ``_r<tile>`` behind it where the tile is over 16, the name
+    it had where it is 16."""
+    from ray_tpu.ops import moe
+
+    assert moe.tile_rows(rows * XING_TOP_K, XING_EXPERTS) == tile
+    M = moe.plan_rows(rows * XING_TOP_K, XING_EXPERTS, tile)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    bf16 = jnp.bfloat16
+    name = "moe_experts_chunk" + (f"_r{tile}" if tile > 16 else "")
+    for k, n in ((XING_HIDDEN, 2 * XING_EXPERT_FF),
+                 (XING_EXPERT_FF, XING_HIDDEN)):
+        tn = moe._tile_cols(k, n, 2, tile)
+        assert n % tn == 0 and tn % 128 == 0
+        # A tall tile reads its rows once: the weight block is as wide
+        # as the weights.
+        assert (tn == n) == (tile > 16)
+        text = _compile(
+            lambda x, w, te, nu: moe.grouped_matmul(
+                x, w, te, nu, "moe_experts_chunk"),
+            S((M, k), bf16), S((XING_EXPERTS, k, n), bf16),
+            S((M // tile,), jnp.int32), S((), jnp.int32)).as_text()
+        assert [kernel.removeprefix("ROOT %")
+                for kernel, _ in _mosaic_lines(text)] == [name]
+
+
+PROGRAMS_AT_PR62 = {
+    "laguna": {
+        "texts": {"decode": "9f0541aab1ca182a", "chunk": "3a5682b240c7eecc"},
+        "moe_kernels": {
+            "decode": [("moe_experts_decode", "49d4c9f08194534c"),
+                       ("moe_experts_decode", "cf435d0b7ef311a9")],
+            "chunk": [("moe_experts_chunk", "1b3435937767bed7"),
+                      ("moe_experts_chunk", "8e203221df276585")]}},
+    "kimi": {
+        "texts": {"decode": "cd54fd8dd84d3a7a", "chunk": "1dfed06f04c4fac7",
+                  "cold_chunk": "85017cce77588407"},
+        "moe_kernels": {
+            "decode": [("moe_experts_decode", "3c9e044c4ce03286"),
+                       ("moe_experts_decode", "8281b9bf30d7ee6a")],
+            "chunk": [("moe_experts_chunk", "4c88f51ff0bb2351"),
+                      ("moe_experts_chunk", "b623716b1f0f63b4")],
+            "cold_chunk": [("moe_experts_chunk", "4c88f51ff0bb2351"),
+                           ("moe_experts_chunk", "b623716b1f0f63b4")]}},
+    "nemotron": {
+        "texts": {"decode": "850b26c9dd3c3703", "chunk": "7c2f03116a053fc2"},
+        "moe_kernels": {
+            "decode": [("moe_experts_decode", "350735f216903e16"),
+                       ("moe_experts_decode", "8f235c3e9216f1c5")],
+            "chunk": [("moe_experts_chunk", "1fc50472d12fabcc"),
+                      ("moe_experts_chunk", "c9c825b4f4513a98")]}},
+    "xing": {
+        "texts": {"decode": "2b345588f0123fd8"},
+        "moe_kernels": {
+            "decode": [("moe_experts_decode", "68302d0d3d17d5e6"),
+                       ("moe_experts_decode", "d84c3ecc194a7c8d")]}},
+}
+
+
+@pytest.mark.parametrize("model", ["laguna", "kimi", "nemotron", "xing"])
+def test_the_programs_that_keep_the_16_row_tile_are_what_they_were(
+        model, request):
+    """PR 63 makes the grouped product's tile a function of the call's
+    rows. Laguna's, Kimi's and Nemotron's decode and chunk programs and
+    Xing4.0's decode program give an expert 0.8-22 rows at the cells'
+    shapes and keep the 16-row tile: each LOWERS, for the TPU, to the
+    text it lowered to at the parent commit, and its grouped products
+    to the kernel bodies they were (sha256, recorded at PR 62's tree
+    with this function). So what PRs 32-62 measured of them stands;
+    whoever moves one records the new text knowingly."""
+    programs = request.getfixturevalue(f"{model}_programs")
+    assert {"texts": programs["texts"],
+            "moe_kernels": programs["moe_kernels"]} == PROGRAMS_AT_PR62[model]

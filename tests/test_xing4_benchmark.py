@@ -41,6 +41,9 @@ SHARED = ("attn_latent_ms", "attn_latent_roofline_pct", "latent_chunk_ms",
 # the device's busy time (PERF.md section 6, PR 62).
 NEW = ("mhc_chunk_ms", "mhc_chunk_roofline_pct", "mhc_decode_ms",
        "mhc_res_err", "moe_routed_roofline_pct", "moe_expert_chunk_ms")
+# Appended behind them since, in the cell alone (PR 63: whether the
+# grouped product's tall tile engaged).
+LATER = ("moe_chunk_wide_tile_pct",)
 
 
 def _config():
@@ -178,15 +181,19 @@ def test_manifest_lists_the_cell_where_the_issue_says():
            if CELL in x.get("workloads", [CELL])}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     by_name = {x["name"]: x for x in m["per_layer"]}
-    # The new entries are the last ones, in the cell alone.
-    assert tuple(x["name"] for x in m["per_layer"][-len(NEW):]) == NEW
-    for name in NEW:
+    # The new entries stand together in ISSUE 62's order, in the cell
+    # alone; what later PRs appended stands behind them.
+    names = [x["name"] for x in m["per_layer"]]
+    at = names.index(NEW[0])
+    assert tuple(names[at:at + len(NEW)]) == NEW
+    assert tuple(names[at + len(NEW):])[:len(LATER)] == LATER
+    for name in NEW + LATER:
         assert by_name[name]["workloads"] == [CELL], name
         assert by_name[name]["moves"] == "serve_tokens_per_s", name
     for name in SHARED:
         assert by_name[name]["workloads"][-1] == CELL, name
     mine = {x["name"] for x in m["per_layer"] if CELL in x["workloads"]}
-    assert mine == set(SHARED) | set(NEW)
+    assert mine == set(SHARED) | set(NEW) | set(LATER)
     for name in mine:
         assert os.path.isfile(os.path.join(
             ROOT, "benchmark", "layer_metrics",
