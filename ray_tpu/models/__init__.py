@@ -4,10 +4,14 @@ routed experts), Kimi-K2 (models/kimi_k2.py, served: latent
 attention over one pool of latent rows, a share of sigmoid-routed
 experts), Nemotron-H (models/nemotron_h.py, served: state-space
 layers that keep a fixed state a SEQUENCE beside one attention layer's
-keys and values, a share of not-gated experts in a latent space) and
+keys and values, a share of not-gated experts in a latent space),
 Xing4.0 (models/xing4.py, served: Kimi-K2's attention, router and
 experts on a residual path of four streams whose mixing weights are
-made from the token, ops/mhc.py).
+made from the token, ops/mhc.py) and Granite 4.0-H
+(models/granite_hybrid.py, served: a Mamba-2 or attention mixer AND a
+share of routed experts with a shared MLP in EVERY layer, so the pools'
+layers go by mixer kind; four muP multipliers, the attention's softmax
+scale among them, and a tied head).
 
 Models are pure-JAX functional: ``init(key, cfg)`` returns the param pytree;
 ``param_axes(cfg)`` returns the matching pytree of logical-axis annotations
@@ -25,8 +29,9 @@ for what the configuration's own module says of it, a ``Serving``:
             READ them, which the engine makes once when it is built and
             keeps in place of what it was given (``served_params``).
             None where ``init``'s tree is that already (Laguna, Kimi,
-            Nemotron: ``cfg.dtype`` leaves). GPT's ``init`` makes a
-            trainer's float32 master weights and its programs round
+            Nemotron, Xing4.0, Granite: ``cfg.dtype`` leaves). GPT's
+            ``init`` makes a trainer's float32 master weights and its
+            programs round
             each to ``cfg.dtype`` in front of its product, so its
             ``at_rest`` does that rounding, once (models/gpt.py
             ``params_at_rest``); a tree that is already as read comes
@@ -67,9 +72,11 @@ for what the configuration's own module says of it, a ``Serving``:
             (models/laguna.py: the table, its first block, the blocks
             the span is written to).
   state     what a SEQUENCE keeps, whatever its length, or None (the
-            three attention families): a ``StateKind``, which names the
-            layers that carry a state and the parts of one layer's
-            (shape and dtype: a state-space layer's recurrent state and
+            attention families): a ``StateKind``, which names the
+            layers that carry a state (where every layer also holds
+            something that keeps nothing, as Granite 4.0-H's expert
+            blocks, the layers whose MIXER carries one) and the parts
+            of one layer's (shape and dtype: a state-space layer's recurrent state and
             the last rows of its convolution's input). The cache
             manager holds them in pools of SLOTS, ``[layers, slots,
             *part]`` (llm/kv_cache.py ``StatePool``): a live lane has a
@@ -301,4 +308,5 @@ def served_params(params, cfg):
     return params if at_rest is None else at_rest(params)
 
 
-from . import gpt, kimi_k2, laguna, nemotron_h, resnet, xing4  # noqa: E402,F401
+from . import (gpt, granite_hybrid, kimi_k2, laguna,  # noqa: E402,F401
+               nemotron_h, resnet, xing4)

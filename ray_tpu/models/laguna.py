@@ -421,7 +421,7 @@ def forward_step(params, packed, k_pool, v_pool, k_win, v_win, *, q: int,
 
 
 def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len, base,
-                     window: Optional[int]):
+                     window: Optional[int], scale: Optional[float] = None):
     """A chunk's attention over [pool context ++ chunk] as the
     ``chunk_attn`` kernel (ops/pallas/chunk_attention.py): scores stay
     in VMEM under one online softmax, a KV head's group of query heads
@@ -432,7 +432,9 @@ def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len, base,
     at absolute position ctx_len + i. k_ctx, v_ctx [S, kv, d]: the
     sequence's gathered pool slots, slot s at absolute position
     base + s, real where that is below ctx_len. With a ``window`` a
-    query sees only keys less than ``window`` positions behind it."""
+    query sees only keys less than ``window`` positions behind it.
+    ``scale`` is the softmax scale where it is not ``d ** -0.5``
+    (models/granite_hybrid.py)."""
     from ..ops.pallas.chunk_attention import chunk_attention, padded_keys
 
     c, H, d = q.shape
@@ -446,7 +448,8 @@ def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len, base,
     o = chunk_attention(
         q.reshape(c, kv, H // kv, d).transpose(1, 2, 0, 3),
         head_major(k_ctx, k_tok), head_major(v_ctx, v_tok), ctx_len,
-        ctx_slots=S, scale=d ** -0.5, base=base, window=window)
+        ctx_slots=S, scale=d ** -0.5 if scale is None else scale, base=base,
+        window=window)
     return o.transpose(2, 0, 1, 3).reshape(c, H, d)     # [kv, g, c, d] ->
 
 

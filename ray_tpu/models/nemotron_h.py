@@ -248,21 +248,7 @@ def _init_layer(key, cfg: NemotronHConfig, letter: str) -> dict:
                  wv=_normal(next(k), (m, kv, d), dt),
                  wo=_normal(next(k), (H, d, m), dt))
     elif letter == MAMBA:
-        H = cfg.mamba_num_heads
-        step = jnp.exp(jax.random.uniform(
-            next(k), (H,), F32, jnp.log(cfg.time_step_min),
-            jnp.log(cfg.time_step_max)))
-        step = jnp.maximum(step, cfg.time_step_floor)
-        p.update(
-            w_in=_normal(next(k), (m, cfg.d_inner + cfg.conv_dim + H), dt),
-            conv_w=_normal(next(k), (cfg.conv_kernel, cfg.conv_dim), dt,
-                           CONV_STD),
-            conv_b=_normal(next(k), (cfg.conv_dim,), dt),
-            dt_bias=step + jnp.log(-jnp.expm1(-step)),
-            A_log=jnp.log(jax.random.uniform(next(k), (H,), F32, *A_RANGE)),
-            D=jnp.ones((H,), F32),
-            norm=jnp.ones((cfg.d_inner,), dt),
-            w_out=_normal(next(k), (cfg.d_inner, m), dt))
+        p.update(mamba_params(k, cfg))
     else:
         E, lat, f = (cfg.n_routed_experts, cfg.moe_latent_size,
                      cfg.moe_intermediate_size)
@@ -281,6 +267,28 @@ def _init_layer(key, cfg: NemotronHConfig, letter: str) -> dict:
             s1=_normal(next(k), (m, fs), dt),
             s2=_normal(next(k), (fs, m), dt))
     return p
+
+
+def mamba_params(k, cfg) -> dict:
+    """A Mamba-2 mixer's own parameters from the keys ``k`` yields
+    (six of them), by the family's initialiser (``init_layer``). ``cfg``
+    is any configuration with the names this module reads a Mamba-2
+    mixer by (models/granite_hybrid.py's has them too)."""
+    m, dt, H = cfg.hidden_size, cfg.dtype, cfg.mamba_num_heads
+    step = jnp.exp(jax.random.uniform(
+        next(k), (H,), F32, jnp.log(cfg.time_step_min),
+        jnp.log(cfg.time_step_max)))
+    step = jnp.maximum(step, cfg.time_step_floor)
+    return dict(
+        w_in=_normal(next(k), (m, cfg.d_inner + cfg.conv_dim + H), dt),
+        conv_w=_normal(next(k), (cfg.conv_kernel, cfg.conv_dim), dt,
+                       CONV_STD),
+        conv_b=_normal(next(k), (cfg.conv_dim,), dt),
+        dt_bias=step + jnp.log(-jnp.expm1(-step)),
+        A_log=jnp.log(jax.random.uniform(next(k), (H,), F32, *A_RANGE)),
+        D=jnp.ones((H,), F32),
+        norm=jnp.ones((cfg.d_inner,), dt),
+        w_out=_normal(next(k), (cfg.d_inner, m), dt))
 
 
 # ---------------------------------------------------------------------------
@@ -356,16 +364,103 @@ def _mamba_out(y, x, z, p, cfg: NemotronHConfig):
     return jnp.dot(y, p["w_out"])
 
 
+def attention_step(h, p, cfg, li: int, k_pool, v_pool, lanes, scale=None):
+    """The attention mixer of a decode step: h [B, 1, m] after the
+    pre-norm, the layer's index ``li`` in the pools, ``lanes`` =
+    (block tables, context lens, q lens, window starts, slot blocks,
+    slot offsets) -> (out [B, 1, m], k_pool, v_pool) with the lanes'
+    new rows written. No rotary. ``scale`` is the softmax scale where
+    it is not ``head_dim ** -0.5`` (models/granite_hybrid.py, which
+    calls the four mixer functions here with its own configuration)."""
+    from ..ops.pallas.paged_fetch import paged_attention_stored
+
+    block_tables, context_lens, q_lens, starts, slot_blocks, slot_offsets \
+        = lanes
+    B = h.shape[0]
+    kv, d = cfg.num_key_value_heads, cfg.head_dim
+    qh = jnp.einsum("brm,mhd->brhd", h, p["wq"])
+    k = jnp.einsum("brm,mhd->brhd", h, p["wk"]).reshape(B, 1, kv * d)
+    v = jnp.einsum("brm,mhd->brhd", h, p["wv"]).reshape(B, 1, kv * d)
+    k_pool = k_pool.at[li, slot_blocks, slot_offsets].set(k)
+    v_pool = v_pool.at[li, slot_blocks, slot_offsets].set(v)
+    H = qh.shape[2]
+    with jax.named_scope("attn_full"):
+        o = paged_attention_stored(
+            qh.reshape(B, 1, kv, H // kv, d), k_pool, v_pool, li,
+            block_tables, context_lens, q_lens, starts, name="attn_full",
+            scale=scale)
+    out = jnp.einsum("brhd,hdm->brm", o.reshape(B, 1, H, d), p["wo"])
+    return out, k_pool, v_pool
+
+
+def mamba_step(h, p, cfg, li: int, slots, s_pool, c_pool):
+    """The Mamba-2 mixer of a decode step: h [B, 1, m] -> (out [B, 1,
+    m], s_pool, c_pool) with the lanes' slots of layer ``li`` moved on
+    by one token, in place."""
+    z, xBC, dt = _mamba_in(h[:, 0], p, cfg)
+    rows = jnp.concatenate([c_pool[li, slots], xBC[:, None]], 1)
+    c_pool = c_pool.at[li, slots].set(rows[:, 1:])
+    xs, Bs, Cs, dt, A = _ssm_inputs(_convolved(rows, p), dt, p, cfg)
+    with jax.named_scope("ssm_update"):
+        y, s_pool = ssm.ssm_update(
+            s_pool, li, slots, jnp.exp(dt * A),
+            dt[..., None] * xs.astype(F32), Bs, Cs)
+    return _mamba_out(y, xs, z, p, cfg)[:, None], s_pool, c_pool
+
+
+def attention_chunk(h, p, cfg, li: int, k_pool, v_pool, block_table,
+                    ctx_len, scale=None):
+    """The attention mixer of a prefill span: h [1, n, m] against the
+    context the pools hold behind ``block_table`` -> (out [1, n, m], the
+    span's keys and values [1, n, kv, d], which the caller writes after
+    the last layer)."""
+    kv, d = cfg.num_key_value_heads, cfg.head_dim
+    slots = block_table.shape[0] * k_pool.shape[2]
+    qh = jnp.einsum("brm,mhd->brhd", h, p["wq"])
+    k = jnp.einsum("brm,mhd->brhd", h, p["wk"])
+    v = jnp.einsum("brm,mhd->brhd", h, p["wv"])
+    k_ctx = k_pool[li, block_table].reshape(slots, kv, d)
+    v_ctx = v_pool[li, block_table].reshape(slots, kv, d)
+    with jax.named_scope("attn_full"):
+        o = _chunk_attention(qh[0], k[0], v[0], k_ctx, v_ctx, ctx_len, 0,
+                             None, scale)
+    return jnp.einsum("brhd,hdm->brm", o[None], p["wo"]), k, v
+
+
+def mamba_chunk(h, p, cfg, li: int, span, s_pool, c_pool):
+    """The Mamba-2 mixer of a prefill span: h [1, n, m]; ``span`` = (slot
+    read, slot written, last real row, the real rows' mask [n, 1],
+    whether the span starts a sequence) -> (out [1, n, m], s_pool,
+    c_pool) with the state and the convolution rows at the span's end
+    in the slot written."""
+    src, dst, last, real, fresh = span
+    n, K = h.shape[1], cfg.conv_kernel
+    z, xBC, dt = _mamba_in(h[0], p, cfg)
+    prev = jnp.where(fresh, 0, c_pool[li, src])          # [K-1, conv]
+    rows = jnp.concatenate([prev, xBC])                  # [K-1+n, conv]
+    c_pool = c_pool.at[li, dst].set(
+        jax.lax.dynamic_slice_in_dim(rows, last + 1, K - 1))
+    window = jnp.stack([rows[i:i + n] for i in range(K)], 1)
+    xs, Bs, Cs, dt, A = _ssm_inputs(_convolved(window, p), dt, p, cfg)
+    with jax.named_scope("ssm_scan"):
+        y, S = ssm.ssd_scan(
+            xs, jnp.where(real, dt, 0.0), A, Bs, Cs,
+            jnp.where(fresh, 0.0, s_pool[li, src]), cfg.chunk_size)
+    s_pool = s_pool.at[li, dst].set(S)
+    return _mamba_out(y, xs, z, p, cfg)[None], s_pool, c_pool
+
+
 def _head(params, x, cfg: NemotronHConfig):
     x = _rmsnorm(x, params["norm_f"], cfg.layer_norm_epsilon)
     return jnp.einsum("brm,mv->brv", x, params["head"])
 
 
-def _pool_index(cfg: NemotronHConfig) -> list:
-    """layer -> its index among the layers of its letter: where its
-    rows or its state lie in the pools."""
+def _pool_index(kinds) -> list:
+    """layer -> its index among the layers of its kind (``kinds``: a
+    letter, or a mixer's name, a layer): where its rows or its state lie
+    in the pools."""
     seen, out = {}, []
-    for c in cfg.hybrid_override_pattern:
+    for c in kinds:
         out.append(seen.get(c, 0))
         seen[c] = out[-1] + 1
     return out
@@ -375,21 +470,21 @@ COUNTERS = ("moe_experts_hit", "moe_load_max_x1000", "moe_held_rows",
             "kv_pages_in_runs_x1000")
 
 
-def _counters(sizes, rows: int, cfg: NemotronHConfig, q: int, in_runs):
+def _counters(sizes, rows: int, n_experts: int, top_k: int, q: int,
+              in_runs):
     """The step's counter rows [4, q] int32 (``COUNTERS``), as
     models/kimi_k2.py counts them: held experts that got a token (an
     expert layer's mean), 1000 x the busiest held expert's tokens over
-    the DEPLOYMENT's mean an expert (the worst layer), the assignments
-    that fell on the held experts (a layer's mean), and 1000 x the share
-    of the batch's live cache pages the paged kernel fetches in whole
-    runs."""
+    the DEPLOYMENT's mean an expert (the worst layer: ``rows`` tokens,
+    ``top_k`` of ``n_experts`` each), the assignments that fell on the
+    held experts (a layer's mean), and 1000 x the share of the batch's
+    live cache pages the paged kernel fetches in whole runs."""
     counts = jnp.zeros((3,), jnp.int32)
     if sizes:
         s = jnp.stack(sizes)                              # [layers, held]
         counts = jnp.stack([
             (s > 0).sum() // len(sizes),
-            (s.max() * (1000 * cfg.n_routed_experts))
-            // (rows * cfg.num_experts_per_tok),
+            (s.max() * (1000 * n_experts)) // (rows * top_k),
             s.sum() // len(sizes)])
     return jnp.broadcast_to(
         jnp.append(counts, in_runs)[:, None],
@@ -414,8 +509,7 @@ def forward_step(params, packed, k_pool, v_pool, s_pool, c_pool, *, q: int,
 
     Returns (logits [b, 1, vocab], ids [b + 4, 1] int32, k_pool, v_pool,
     s_pool, c_pool): rows b on of ``ids`` are ``COUNTERS``."""
-    from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
-                                          paged_attention_stored)
+    from ..ops.pallas.paged_fetch import kv_pages_in_runs_x1000
     from . import step_state_slots, unpack_step
 
     if q != 1:
@@ -424,36 +518,20 @@ def forward_step(params, packed, k_pool, v_pool, s_pool, c_pool, *, q: int,
      slot_offsets, _) = unpack_step(packed, q, firsts=firsts, state=True)
     slots = step_state_slots(packed, q)
     B = tokens.shape[0]
-    kv, d = cfg.num_key_value_heads, cfg.head_dim
-    no_start = jnp.zeros_like(context_lens)
+    lanes = (block_tables, context_lens, q_lens,
+             jnp.zeros_like(context_lens), slot_blocks, slot_offsets)
     eps = cfg.layer_norm_epsilon
     x = params["embed"][tokens]                          # [B, 1, m]
     sizes = []
-    for li, p in zip(_pool_index(cfg), params["layers"]):
+    for li, p in zip(_pool_index(cfg.hybrid_override_pattern),
+                     params["layers"]):
         h = _rmsnorm(x, p["ln"], eps)
         if "wq" in p:
-            qh = jnp.einsum("brm,mhd->brhd", h, p["wq"])
-            k = jnp.einsum("brm,mhd->brhd", h, p["wk"]).reshape(B, 1, kv * d)
-            v = jnp.einsum("brm,mhd->brhd", h, p["wv"]).reshape(B, 1, kv * d)
-            k_pool = k_pool.at[li, slot_blocks, slot_offsets].set(k)
-            v_pool = v_pool.at[li, slot_blocks, slot_offsets].set(v)
-            H = qh.shape[2]
-            with jax.named_scope("attn_full"):
-                o = paged_attention_stored(
-                    qh.reshape(B, 1, kv, H // kv, d), k_pool, v_pool, li,
-                    block_tables, context_lens, q_lens, no_start,
-                    name="attn_full")
-            out = jnp.einsum("brhd,hdm->brm", o.reshape(B, 1, H, d), p["wo"])
+            out, k_pool, v_pool = attention_step(h, p, cfg, li, k_pool,
+                                                 v_pool, lanes)
         elif "w_in" in p:
-            z, xBC, dt = _mamba_in(h[:, 0], p, cfg)
-            rows = jnp.concatenate([c_pool[li, slots], xBC[:, None]], 1)
-            c_pool = c_pool.at[li, slots].set(rows[:, 1:])
-            xs, Bs, Cs, dt, A = _ssm_inputs(_convolved(rows, p), dt, p, cfg)
-            with jax.named_scope("ssm_update"):
-                y, s_pool = ssm.ssm_update(
-                    s_pool, li, slots, jnp.exp(dt * A),
-                    dt[..., None] * xs.astype(F32), Bs, Cs)
-            out = _mamba_out(y, xs, z, p, cfg)[:, None]
+            out, s_pool, c_pool = mamba_step(h, p, cfg, li, slots, s_pool,
+                                             c_pool)
         else:
             out, s = _experts(h[:, 0], p, cfg, "decode")
             out = out[:, None]
@@ -462,7 +540,8 @@ def forward_step(params, packed, k_pool, v_pool, s_pool, c_pool, *, q: int,
     logits = _head(params, x, cfg)
     ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     ids = jnp.concatenate([ids, _counters(
-        sizes, B, cfg, 1, kv_pages_in_runs_x1000(
+        sizes, B, cfg.n_routed_experts, cfg.num_experts_per_tok, 1,
+        kv_pages_in_runs_x1000(
             block_tables, context_lens, k_pool, v_pool,
             score_rows=cfg.num_attention_heads))])
     return logits, ids, k_pool, v_pool, s_pool, c_pool
@@ -490,42 +569,21 @@ def forward_prefill_chunk(params, tokens, k_pool, v_pool, table, s_pool,
     bs = k_pool.shape[2]
     block_table, dest, ctx_len, last, src, dst = unpack_span(
         table, n, bs, extra=2)
-    nb = block_table.shape[0]
-    kv, d, K = cfg.num_key_value_heads, cfg.head_dim, cfg.conv_kernel
     eps = cfg.layer_norm_epsilon
-    real = (jnp.arange(n) <= last)[:, None]
-    fresh = ctx_len == 0
+    span = (src, dst, last, (jnp.arange(n) <= last)[:, None], ctx_len == 0)
     x = params["embed"][tokens]                          # [1, n, m]
     new_k, new_v = [], []
-    for li, p in zip(_pool_index(cfg), params["layers"]):
+    for li, p in zip(_pool_index(cfg.hybrid_override_pattern),
+                     params["layers"]):
         h = _rmsnorm(x, p["ln"], eps)
         if "wq" in p:
-            qh = jnp.einsum("brm,mhd->brhd", h, p["wq"])
-            k = jnp.einsum("brm,mhd->brhd", h, p["wk"])
-            v = jnp.einsum("brm,mhd->brhd", h, p["wv"])
-            k_ctx = k_pool[li, block_table].reshape(nb * bs, kv, d)
-            v_ctx = v_pool[li, block_table].reshape(nb * bs, kv, d)
-            with jax.named_scope("attn_full"):
-                o = _chunk_attention(qh[0], k[0], v[0], k_ctx, v_ctx,
-                                     ctx_len, 0, None)
+            out, k, v = attention_chunk(h, p, cfg, li, k_pool, v_pool,
+                                        block_table, ctx_len)
             new_k.append(k)
             new_v.append(v)
-            out = jnp.einsum("brhd,hdm->brm", o[None], p["wo"])
         elif "w_in" in p:
-            z, xBC, dt = _mamba_in(h[0], p, cfg)
-            prev = jnp.where(fresh, 0, c_pool[li, src])      # [K-1, conv]
-            rows = jnp.concatenate([prev, xBC])              # [K-1+n, conv]
-            c_pool = c_pool.at[li, dst].set(
-                jax.lax.dynamic_slice_in_dim(rows, last + 1, K - 1))
-            window = jnp.stack([rows[i:i + n] for i in range(K)], 1)
-            xs, Bs, Cs, dt, A = _ssm_inputs(_convolved(window, p), dt, p,
-                                            cfg)
-            with jax.named_scope("ssm_scan"):
-                y, S = ssm.ssd_scan(
-                    xs, jnp.where(real, dt, 0.0), A, Bs, Cs,
-                    jnp.where(fresh, 0.0, s_pool[li, src]), cfg.chunk_size)
-            s_pool = s_pool.at[li, dst].set(S)
-            out = _mamba_out(y, xs, z, p, cfg)[None]
+            out, s_pool, c_pool = mamba_chunk(h, p, cfg, li, span, s_pool,
+                                              c_pool)
         else:
             out, _ = _experts(h[0], p, cfg, "chunk")
             out = out[None]

@@ -50,7 +50,9 @@ add is the other shards'. models/laguna.py serves this layer on one
 chip with every expert local; models/kimi_k2.py serves one chip's
 share of it (``routed_experts(..., first=)`` with 12 of 384 experts),
 as models/nemotron_h.py does with not-gated experts in a latent space
-(64 of 512, 22 a token).
+(64 of 512, 22 a token) and models/granite_hybrid.py with gated ones at
+the full width in every layer (36 of 72, 10 a token, ``route`` at scale
+1: a softmax over the chosen logits).
 """
 
 from __future__ import annotations

@@ -1,7 +1,9 @@
 """Paged decode attention over a pool AS STORED, by kernels that fetch
 their own pages: keys and values in rows of ``kv_heads * head_dim``
 (``attn_full`` / ``attn_window``, heads of 128: models/laguna.py,
-models/nemotron_h.py; ``paged_decode``, 12 heads of 64: models/gpt.py)
+models/nemotron_h.py, models/granite_hybrid.py, whose softmax scale is
+its own and not ``head_dim ** -0.5``: the stored kernel takes the scale
+as the latent one does; ``paged_decode``, 12 heads of 64: models/gpt.py)
 and ``attn_latent`` (latent attention in the absorbed form, rows of
 640; models/kimi_k2.py). Every decode and verify step of every served
 model attends here; there is no other paged kernel.
@@ -387,7 +389,7 @@ def _stored_kernel(*refs, layer: int | None, block_size: int, pages: int,
 def _make_stored_call(b: int, hkv: int, group: int, d: int,
                       layer: int | None, num_blocks: int, block_size: int,
                       max_nb: int, q_dtype, p_dtype, interpret: bool,
-                      q_len: int, name: str):
+                      q_len: int, name: str, scale: float):
     """``layer`` ``None``: the call takes the layer, ``[1]`` int32,
     before the tables."""
     rows = q_len * group
@@ -399,7 +401,7 @@ def _make_stored_call(b: int, hkv: int, group: int, d: int,
     call = _fetching_call(
         functools.partial(_stored_kernel, layer=layer,
                           block_size=block_size, pages=pages, run=run,
-                          n_blocks=n_blocks, scale=d ** -0.5, group=group,
+                          n_blocks=n_blocks, scale=scale, group=group,
                           hkv=hkv, d=d),
         name=name, interpret=interpret, b=b, n_blocks=n_blocks,
         # (layer,) tables, context lens, q lens, starts, run flags
@@ -421,6 +423,7 @@ def _make_stored_call(b: int, hkv: int, group: int, d: int,
 
 def paged_attention_stored(q, k_pool, v_pool, layer, block_tables,
                            context_lens, q_lens, starts, *, name: str,
+                           scale: float | None = None,
                            interpret: bool | None = None):
     """Attention of a decode step (``q_len`` 1) or a speculative verify
     step (``q_len`` rows a lane in one pass) over block-paged keys and
@@ -452,6 +455,12 @@ def paged_attention_stored(q, k_pool, v_pool, layer, block_tables,
         below (zeros will do at ``q_len`` 1 only: row i of a verify
         step would miss the table's first i keys).
       name: the kernel's name on a device trace.
+      scale: the softmax scale, applied to the float32 scores inside
+        the kernel; ``head_dim ** -0.5`` where none is given (GPT-2,
+        Laguna, Nemotron-H). A model whose scale is another number
+        (Granite 4.0-H's ``attention_multiplier``) says it here: a
+        query scaled in front of the kernel would be rounded to the
+        served dtype once more than the model's equations round it.
 
     Returns ``[batch, q_len, kv_heads, group, head_dim]`` in q's dtype.
     """
@@ -465,7 +474,8 @@ def paged_attention_stored(q, k_pool, v_pool, layer, block_tables,
     call = _make_stored_call(b, hkv, group, d,
                              int(layer) if static else None, num_blocks,
                              block_size, block_tables.shape[1], q.dtype,
-                             k_pool.dtype, interpret, q_len, name)
+                             k_pool.dtype, interpret, q_len, name,
+                             d ** -0.5 if scale is None else float(scale))
     traced = () if static else (jnp.asarray(layer, jnp.int32).reshape(1),)
     qf = q.transpose(0, 2, 1, 3, 4).reshape(b, hkv, q_len * group, d)
     out = call(block_tables.astype(jnp.int32),
@@ -476,7 +486,7 @@ def paged_attention_stored(q, k_pool, v_pool, layer, block_tables,
 
 def paged_attention_stored_reference(q, k_pool, v_pool, layer: int,
                                      block_tables, context_lens, q_lens,
-                                     starts):
+                                     starts, scale: float | None = None):
     """Pure-jnp ground truth of ``paged_attention_stored``: materialize
     the gather, dense masked softmax, float32. Tests only."""
     b, q_len, hkv, group, d = q.shape
@@ -487,7 +497,7 @@ def paged_attention_stored_reference(q, k_pool, v_pool, layer: int,
 
     k, v = rows(k_pool), rows(v_pool)
     s = jnp.einsum("bqhgd,bshd->bqhgs", q.astype(jnp.float32),
-                   k) * (d ** -0.5)
+                   k) * (d ** -0.5 if scale is None else scale)
     k_pos = jnp.arange(k.shape[1])[None, None, None, None, :]
     ctx = context_lens[:, None, None, None, None]
     qi = jnp.arange(q_len)[None, :, None, None, None]

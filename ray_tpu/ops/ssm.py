@@ -8,8 +8,17 @@ width, ``N`` the state size) and a token moves it by
 with ``A`` a negative scalar a head, ``dt_t`` a positive scalar a head a
 token, ``x_t`` [P], and ``B_t``, ``C_t`` [N] shared by the ``H / G``
 heads of a group. (The ``D x`` skip, the gate and the convolution in
-front are the model's, models/nemotron_h.py.) Everything here is
-float32: the state is what a sequence carries for thousands of tokens.
+front are the model's, models/nemotron_h.py and
+models/granite_hybrid.py.) Everything here is float32: the state is
+what a sequence carries for thousands of tokens.
+
+**A group wider than a block.** Both kernels take their heads a block
+at a time (``SCAN_HEADS`` of a span's, ``HALF`` of a decode step's). A
+block holds whole groups where a group is no wider than it (Nemotron-3:
+8 groups of 16), and lies inside ONE group where a group is wider
+(Granite 4.0-H: one group of 128): then the blocks of a group read the
+same ``B`` and ``C`` rows, which their index maps name by the group and
+not by the block, and each keeps its own heads' state.
 
   ssd_scan     a span of a prompt: the chunked form (state-space
                duality, Dao & Gu 2024): blocks of ``chunk`` tokens, a
@@ -18,10 +27,12 @@ float32: the state is what a sequence carries for thousands of tokens.
                initial state comes in and the final one goes out. Rows
                with ``dt`` = 0 leave the state as it is, which is how a
                span is padded. A Pallas kernel (``ssm_scan`` on a
-               device trace): a grid step is one group of heads in one
-               block, the blocks of a group in order with the group's
-               state resident in VMEM between them, so a span's state
-               is read once and written once.
+               device trace): a grid step is one block of a group's
+               heads (the whole group where it has no more than
+               ``SCAN_HEADS``) in one block of rows, the blocks of rows
+               in order with those heads' state resident in VMEM
+               between them, so a span's state is read once and written
+               once.
   ssm_update   one token a lane of a decode batch, IN PLACE in the pool
                of state slots ``[layers, slots, H, P, N]``: a Pallas
                kernel (``ssm_update`` on a device trace) that takes each
@@ -50,6 +61,11 @@ LANES, HALF = 128, 64
 # The two kernels' names on a device trace (the benchmark's readers find
 # them by these).
 SCAN_KERNEL, UPDATE_KERNEL = "ssm_scan", "ssm_update"
+# Heads of a group a grid step of the scan takes: a step holds their
+# ``x dt``, outputs and states (two copies) in VMEM and unrolls over
+# them. 16 heads of 64 at a block of 256 rows are 1 + 1 + 2 x 0.5 MiB,
+# doubled by the pipeline: inside the 16 MiB a kernel is given.
+SCAN_HEADS = 16
 
 
 def _blocks(x, dt, A, B, C, chunk: int):
@@ -76,9 +92,9 @@ def _dot(a, b, dims):
 
 def _scan_kernel(xdt_ref, cum_ref, b_ref, c_ref, s0_ref, y_ref, s_ref, *,
                  heads: int):
-    """One group's ``heads`` heads in one block of l rows. ``s_ref`` is
-    the group's state, resident across the group's blocks: the initial
-    state at the first, the final one when the last has run."""
+    """``heads`` heads of one group in one block of l rows. ``s_ref`` is
+    those heads' state, resident across their blocks of rows: the
+    initial state at the first, the final one when the last has run."""
     @pl.when(pl.program_id(1) == 0)
     def _first():
         s_ref[...] = s0_ref[...]
@@ -100,24 +116,41 @@ def _scan_kernel(xdt_ref, cum_ref, b_ref, c_ref, s0_ref, y_ref, s_ref, *,
         # The block's whole sum, as a row beside ``rows`` and as a
         # column beside the state (a 1 x 1 value broadcasts neither way).
         end = at_t[l - 1:l, :P]
-        s_ref[j] = (jnp.exp(at_s[:P, l - 1:l]) * S
+        # (Mosaic takes that column at a lane offset inside the FIRST
+        # lane tile only; a longer block takes the sum as a row as wide
+        # as the state.)
+        whole = at_s[:P, l - 1:l] if l <= LANES else at_t[l - 1:l, :S.shape[1]]
+        s_ref[j] = (jnp.exp(whole) * S
                     + _dot(xd * jnp.exp(end - rows), Bm, ((0,), (0,))))
 
 
+def _scan_heads(J: int) -> int:
+    """Heads of a group of ``J`` that a grid step of the scan takes."""
+    if J > SCAN_HEADS and J % SCAN_HEADS:
+        raise ValueError(f"a group of {J} heads does not cut into blocks "
+                         f"of {SCAN_HEADS}")
+    return min(J, SCAN_HEADS)
+
+
 @functools.lru_cache(maxsize=None)
-def _make_scan(c: int, l: int, G: int, J: int, P: int, N: int,
+def _make_scan(c: int, l: int, G: int, K: int, J: int, P: int, N: int,
                interpret: bool):
+    """``G`` groups in ``K`` blocks of ``J`` heads each: the grid's
+    first axis walks the G x K head blocks, and block ``g`` reads group
+    ``g // K``'s ``B`` and ``C``."""
     by_block = lambda *shape: pl.BlockSpec(
         (None, None, *shape), lambda g, i: (g, i) + (0,) * len(shape))
+    by_group = by_block if K == 1 else lambda *shape: pl.BlockSpec(
+        (None, None, *shape), lambda g, i: (g // K, i) + (0,) * len(shape))
     state = pl.BlockSpec((None, J, P, N), lambda g, i: (g, 0, 0, 0))
     return pl.pallas_call(
         functools.partial(_scan_kernel, heads=J),
-        grid=(G, c),
-        in_specs=[by_block(J, l, P), by_block(J, l), by_block(l, N),
-                  by_block(l, N), state],
+        grid=(G * K, c),
+        in_specs=[by_block(J, l, P), by_block(J, l), by_group(l, N),
+                  by_group(l, N), state],
         out_specs=[by_block(J, l, P), state],
-        out_shape=[jax.ShapeDtypeStruct((G, c, J, l, P), F32),
-                   jax.ShapeDtypeStruct((G, J, P, N), F32)],
+        out_shape=[jax.ShapeDtypeStruct((G * K, c, J, l, P), F32),
+                   jax.ShapeDtypeStruct((G * K, J, P, N), F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name=SCAN_KERNEL)
@@ -128,18 +161,22 @@ def ssd_scan(x, dt, A, B, C, S0, chunk: int):
     [H], B and C [n, G, N], S0 [H, P, N] -> (y [n, H, P], S [H, P, N]
     after the last row), all float32. ``n`` need not be a multiple of
     ``chunk``: the tail is padded with ``dt`` = 0. The kernel wants a
-    head no wider than a block is long (``P <= chunk``)."""
+    head no wider than a block is long (``P <= chunk``), and of a block
+    longer than a lane tile a state no wider either."""
     n, H, P = x.shape
     G, N = B.shape[1:]
-    if P > chunk:
-        raise ValueError(f"head width {P} over the block length {chunk}")
+    if P > chunk or (chunk > LANES and N > chunk):
+        raise ValueError(f"head width {P} or state {N} over the block "
+                         f"length {chunk}")
     xdt, cum, B, C = _blocks(x, dt, A, B, C, chunk)
-    c, l, J = xdt.shape[0], chunk, H // G
-    call = _make_scan(c, l, G, J, P, N, jax.default_backend() == "cpu")
-    y, S = call(xdt.transpose(2, 0, 3, 1, 4),           # [G, c, J, l, P]
-                cum.transpose(2, 0, 3, 1),              # [G, c, J, l]
+    c, l, J = xdt.shape[0], chunk, _scan_heads(H // G)
+    K = H // G // J
+    call = _make_scan(c, l, G, K, J, P, N, jax.default_backend() == "cpu")
+    # A group's heads lie side by side, so its K blocks of J do too.
+    y, S = call(xdt.reshape(c, l, G * K, J, P).transpose(2, 0, 3, 1, 4),
+                cum.reshape(c, l, G * K, J).transpose(2, 0, 3, 1),
                 B.transpose(2, 0, 1, 3), C.transpose(2, 0, 1, 3),
-                S0.astype(F32).reshape(G, J, P, N))
+                S0.astype(F32).reshape(G * K, J, P, N))
     return (y.transpose(1, 3, 0, 2, 4).reshape(c * l, H, P)[:n],
             S.reshape(H, P, N))
 
@@ -168,9 +205,10 @@ def ssm_recurrence(x, dt, A, B, C, S0):
 
 def _head_block(H: int, G: int) -> int:
     """Heads a grid step updates: as many as the column block has lanes
-    for (``HALF``), in whole groups."""
+    for (``HALF``): whole groups, or a part of ONE group that is wider
+    than that."""
     hb = min(H, HALF)
-    if H % hb or hb % (H // G):
+    if H % hb or (hb % (H // G) and (H // G) % hb):
         raise ValueError(f"{H} heads in {G} groups do not cut into blocks "
                          f"of {hb}")
     return hb
@@ -201,7 +239,17 @@ def _make_update(b: int, L: int, slots: int, H: int, P: int, N: int,
     hg, nk = H // G, H // hb
     small = lambda rows, width: pl.BlockSpec(
         (None, None, rows, width), lambda i, k, slots_ref: (i, k, 0, 0))
-    cols, group_rows = small(P, LANES), small(hb // hg, N)
+    cols = small(P, LANES)
+    if hg <= hb:
+        group_rows = small(hb // hg, N)
+    else:
+        # A group wider than a block: its hg // hb blocks read its ONE
+        # row of ``B`` and of ``C`` ([b, G, 1, N]).
+        wide = hg // hb
+        group_rows = pl.BlockSpec(
+            (None, None, 1, N),
+            lambda i, k, slots_ref: (i, k // wide, 0, 0))
+        hg = hb
     state = pl.BlockSpec((None, None, hb, P, N),
                          lambda i, k, slots_ref: (layer, slots_ref[i], k,
                                                   0, 0))
@@ -238,7 +286,9 @@ def ssm_update(pool, layer: int, slots, decay, dtx, B, C):
         dtx.astype(F32).reshape(b, nk, hb, P).transpose(0, 1, 3, 2))
     cols = cols.at[..., HALF:HALF + hb].set(jnp.broadcast_to(
         decay.astype(F32).reshape(b, nk, 1, hb), (b, nk, P, hb)))
-    by_block = lambda a: a.astype(F32).reshape(b, nk, G // nk, N)
+    # A block's groups' rows; a group wider than a block keeps its own.
+    by_block = lambda a: a.astype(F32).reshape(
+        *((b, nk, G // nk) if G >= nk else (b, G, 1)), N)
     call = _make_update(b, L, n_slots, H, P, N, G, int(layer),
                         jax.default_backend() == "cpu")
     y, pool = call(slots.astype(jnp.int32), cols, by_block(B), by_block(C),

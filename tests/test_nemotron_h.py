@@ -59,13 +59,18 @@ def test_init_makes_what_num_params_counts(tiny):
         == cfg.num_params()
 
 
-@pytest.mark.parametrize("n,chunk", [(13, 8), (16, 8), (5, 8), (40, 16),
-                                     (1, 8)])
-def test_chunked_scan_equals_the_token_recurrence(n, chunk):
+@pytest.mark.parametrize("n,chunk,H,G", [
+    (13, 8, 4, 2), (16, 8, 4, 2), (5, 8, 4, 2), (40, 16, 4, 2), (1, 8, 4, 2),
+    # ONE group of 128 heads (models/granite_hybrid.py): wider than the
+    # scan's block of heads, whose blocks then read one B and one C.
+    (20, 8, 128, 1), (9, 8, 32, 2),
+    # A block of 256 rows, longer than a lane tile.
+    (300, 256, 32, 1), (256, 256, 4, 2)])
+def test_chunked_scan_equals_the_token_recurrence(n, chunk, H, G):
     """From a NON-ZERO initial state, at span lengths that are no
     multiple of the chunk: padding rows have dt 0 and move nothing."""
     rng = np.random.default_rng(n)
-    H, G, P, N = 4, 2, 8, 16
+    P, N = 8, 16
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     x, B, C, S0 = f(n, H, P), f(n, G, N), f(n, G, N), f(H, P, N)
     dt = jnp.asarray(rng.uniform(0.01, 0.5, (n, H)), jnp.float32)
@@ -73,13 +78,29 @@ def test_chunked_scan_equals_the_token_recurrence(n, chunk):
     y_want, S_want = ssm.ssm_recurrence(x, dt, A, B, C, S0)
     # The kernel, under the Pallas interpreter here.
     y, S = ssm.ssd_scan(x, dt, A, B, C, S0, chunk)
-    assert np.abs(y - y_want).max() < 2e-5
+    assert np.abs(y - y_want).max() < 2e-5 * max(1, chunk // 32)
     assert np.abs(S - S_want).max() < 2e-5
 
 
-def test_update_kernel_moves_the_lanes_slots_and_no_other():
+def test_a_group_of_heads_cuts_into_the_kernels_blocks():
+    assert ssm._head_block(128, 8) == ssm._head_block(128, 1) == 64
+    assert ssm._head_block(4, 2) == 4
+    assert ssm._scan_heads(16) == ssm._scan_heads(128) == 16
+    assert ssm._scan_heads(2) == 2
+    for H, G in ((96, 1), (192, 2)):        # neither whole groups nor a part
+        with pytest.raises(ValueError, match="do not cut"):
+            ssm._head_block(H, G)
+    with pytest.raises(ValueError, match="does not cut"):
+        ssm._scan_heads(24)
+
+
+@pytest.mark.parametrize("H,G", [(4, 2), (128, 1), (128, 8), (128, 2)])
+def test_update_kernel_moves_the_lanes_slots_and_no_other(H, G):
+    """Whole groups in a block of heads (4 in 2; 128 in 8, Nemotron-3's)
+    and a group wider than a block (128 in 1, Granite 4.0-H's; in 2,
+    a block a group)."""
     rng = np.random.default_rng(0)
-    H, G, P, N, L, slots, b = 4, 2, 8, 16, 2, 6, 4
+    P, N, L, slots, b = 8, 16, 2, 6, 4
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     pool = f(L, slots, H, P, N)
     lanes = jnp.asarray([2, 0, 5, 0], jnp.int32)    # two padded: scratch

@@ -176,7 +176,11 @@ def test_manifest_lists_the_cell_where_the_issue_says():
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     by_name = {x["name"]: x for x in m["per_layer"]}
     for name in NEW:
-        assert by_name[name]["workloads"] == [CELL], name
+        # In the cell alone when PR 57 added them; since PR 64 a second
+        # model names the two kernels and keeps a state, and reads its
+        # shares of a roofline from files of its own.
+        assert by_name[name]["workloads"] == [CELL] + (
+            [] if "roofline" in name else ["granite4hs-serve-chat"]), name
         assert by_name[name]["moves"] == "serve_tokens_per_s", name
     for name in ("batch_occupancy_pct", "itl_p95_ms", "itl_p99_long_ms",
                  "engine_host_gap_ms", "kv_live_peak_pct",
@@ -185,10 +189,11 @@ def test_manifest_lists_the_cell_where_the_issue_says():
                  "decode_lanes_pct", "stream_hold_ms", "chunk_attn_ms",
                  "moe_expert_ms", "moe_load_max", "moe_held_rows",
                  "callers_cpu_pct"):
-        # Last when PR 57 appended it; a later cell stands behind it.
+        # Last when PR 57 appended it; later cells stand behind it.
         after = by_name[name]["workloads"]
         after = after[after.index(CELL) + 1:]
-        assert after in ([], ["xing4-serve-rag"]), name
+        assert after in (["granite4hs-serve-chat"],
+                         ["xing4-serve-rag", "granite4hs-serve-chat"]), name
     # Left to a ``benchmark`` PR: the issue names these by group only
     # ("the engine's"), or not at all (``attn_full_ms``), and tests
     # under benchmark/tests, which this kind of PR may not edit, pin

@@ -1189,6 +1189,146 @@ def test_nemotron_chunk_program_scans_from_a_slot_into_a_slot(
     assert c.memory_analysis().temp_size_in_bytes < 250e6
 
 
+# -- Granite 4.0-H Small at the cell's shapes (granite4-h-small-serve) ---------
+
+GRANITE_BLOCKS, GRANITE_SLOTS, GRANITE_MAX_SEQ = 12288, 97, 2816
+GRANITE_STATE = f"f32[9,{GRANITE_SLOTS},128,64,128]"
+GRANITE_CONV = f"bf16[9,{GRANITE_SLOTS},3,8448]"
+
+
+@pytest.fixture(scope="module")
+def granite_programs(one_chip):
+    """The engine's own decode and chunk programs for the served share
+    of granite-4.0-h-small (one period of 10 layers, 36 of 72 experts,
+    half the vocabulary), compiled for the described v5e at the cell's
+    shapes: 64 lanes, keys and values of ONE attention layer in 12,288
+    blocks of 16 rows of 1,024, and the nine Mamba-2 layers' state in 97
+    slots (3.7 GB in float32); a 1,024-token span behind a 2,816-token
+    table. ONE group of 128 heads: two blocks of 64 a lane in the
+    update, eight blocks of 16 a block of 256 rows in the scan."""
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.models import granite_hybrid as gh
+
+    cfg = gh.GraniteHybridConfig(
+        num_hidden_layers=10, vocab_size=50176, experts_held=36,
+        layer_types=gh.GraniteHybridConfig().layer_types[10:20],
+        max_seq=GRANITE_MAX_SEQ)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda leaf: S(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: gh.init(jax.random.key(0), cfg)))
+    B, i32 = CELL_B, jnp.int32
+    kv = S((1, GRANITE_BLOCKS, BS, 1024), jnp.bfloat16)
+    state = (S((9, GRANITE_SLOTS, 128, 64, 128), jnp.float32),
+             S((9, GRANITE_SLOTS, 3, 8448), jnp.bfloat16))
+    max_nb = GRANITE_MAX_SEQ // BS
+    decode, chunk = _jit_programs(cfg)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = decode.lower(
+            params, S((B, step_columns(1, 0, True).table + max_nb), i32),
+            kv, kv, *state, q=1, firsts=S((B,), i32))
+        low_chunk = chunk.lower(
+            params, S((1, 1024), i32), kv, kv,
+            S((max_nb + 1024 // BS + 4,), i32), *state)
+        return {"param_leaves": len(jax.tree_util.tree_leaves(params)),
+                "decode": lowered.compile(), "chunk": low_chunk.compile()}
+
+
+def _granite_state_sized(text, *opcodes):
+    """``_results`` at the size of ONE layer of the state pool, in its
+    float32 (the one attention layer's keys are as many elements again,
+    in bfloat16, and are written by one in-place scatter)."""
+    return [(op, shape) for op, shape in _results(
+        text, *opcodes, at_least=GRANITE_SLOTS * 128 * 64 * 128)
+        if shape.startswith("f32[")]
+
+
+def test_granite_decode_program_moves_its_states_in_place(granite_programs):
+    """The decode program at the cell's shapes and the published
+    widths: the state update once a Mamba-2 layer under its name
+    (``ssm_update`` x 9), the stored paged call once (``attn_full``),
+    the grouped product twice an expert block, which EVERY layer has
+    (``moe_experts_decode`` x 20): how the benchmark's readers find
+    them. All four pools are donated and aliased to their outputs; the
+    3.7 GB state pool is an operand of the nine kernels and of nothing
+    else that makes a pool-sized result. The tied head reads the
+    embedding as it lies: no transposed copy of it."""
+    c = granite_programs["decode"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert _mosaic_calls(text) == {"ssm_update": 9, "attn_full": 1,
+                                   "moe_experts_decode": 20}
+    assert _granite_state_sized(text, "copy", "transpose", "copy-start",
+                                "gather", "scatter", "dynamic-slice",
+                                "dynamic-update-slice", "concatenate", "pad",
+                                "fusion") == []
+    for line in [l for n, l in _mosaic_lines(text) if n == "ssm_update"]:
+        assert GRANITE_STATE in line.split("custom-call(")[0]
+    n = granite_programs["param_leaves"]
+    assert _aliased(text) == {n + 1: 2, n + 2: 3, n + 3: 4, n + 4: 5}
+    assert _entry_parameters(text) == n + 6
+    entry = text[text.index("\nENTRY "):]
+    root = next(line for line in entry.splitlines()
+                if line.lstrip().startswith("ROOT "))
+    assert f"s32[{CELL_B + 4},1]" in root and f"bf16[{CELL_B},1,50176]" \
+        in root and GRANITE_STATE in root and GRANITE_CONV in root
+    assert _results(text, "copy", "transpose",
+                    at_least=50176 * 4096) == []
+    assert c.memory_analysis().temp_size_in_bytes < 200e6
+
+
+def test_granite_chunk_program_scans_from_a_slot_into_a_slot(
+        granite_programs):
+    """A 1,024-token span behind a 2,816-token table, ONE program:
+    ``chunk_attn`` for the one attention layer, the grouped product
+    twice an expert block in TALL tiles (1,024 x 10 / 72 = 142 rows an
+    expert: ``moe_experts_chunk_r64`` x 20), the chunked scan once a
+    Mamba-2 layer (``ssm_scan`` x 9) at the published block of 256
+    rows; each reads ONE slot of the state pool and writes one; all
+    four pools aliased; the head runs on the one row that comes back."""
+    c = granite_programs["chunk"]
+    text = c.as_text()
+    assert text.startswith("HloModule jit_llm_prefill_chunk")
+    assert _mosaic_calls(text) == {"moe_experts_chunk_r64": 20,
+                                   "chunk_attn": 1, "ssm_scan": 9}
+    assert _granite_state_sized(text, "copy", "transpose", "copy-start",
+                                "gather", "scatter", "concatenate",
+                                "pad") == []
+    n = granite_programs["param_leaves"]
+    assert _aliased(text) == {n + 1: 2, n + 2: 3, n + 4: 4, n + 5: 5}
+    assert "[1024,50176]" not in text and "[1,1024,50176]" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
+@pytest.mark.parametrize("H, G, l, rows", [(128, 1, 256, 1024),
+                                           (128, 1, 256, 256),
+                                           (128, 8, 128, 512)])
+def test_the_state_space_kernels_compile_whatever_a_groups_width(
+        one_chip, as_tpu, H, G, l, rows):
+    """``ssd_scan`` and ``ssm_update`` alone at the two cells' widths
+    (heads of 64, state 128): ONE group of 128 heads at the published
+    block of 256 rows (Granite 4.0-H: a group wider than either
+    kernel's block of heads) and 8 groups of 16 at 128 (Nemotron-3).
+    The kernels keep their names."""
+    from ray_tpu.ops import ssm
+
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    text = _compile(
+        lambda x, dt, A, B, C, S0: ssm.ssd_scan(x, dt, A, B, C, S0, l),
+        S((rows, H, 64)), S((rows, H)), S((H,)), S((rows, G, 128)),
+        S((rows, G, 128)), S((H, 64, 128))).as_text()
+    assert _mosaic_calls(text) == {"ssm_scan": 1}
+    text = _compile(
+        lambda pool, slots, decay, dtx, B, C: ssm.ssm_update(
+            pool, 1, slots, decay, dtx, B, C),
+        S((2, 9, H, 64, 128)), S((CELL_B,), jnp.int32), S((CELL_B, H)),
+        S((CELL_B, H, 64)), S((CELL_B, G, 128)),
+        S((CELL_B, G, 128))).as_text()
+    assert _mosaic_calls(text) == {"ssm_update": 1}
+
+
 # -- Xing4.0 at the cell's shapes (benchmark/configs/xing4-29b-serve) ----------
 
 XING_BLOCKS, XING_MAX_SEQ, XING_LAYERS, XING_STREAMS = 19456, 4736, 7, 14336

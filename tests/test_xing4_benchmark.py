@@ -44,6 +44,8 @@ NEW = ("mhc_chunk_ms", "mhc_chunk_roofline_pct", "mhc_decode_ms",
 # Appended behind them since, in the cell alone (PR 63: whether the
 # grouped product's tall tile engaged).
 LATER = ("moe_chunk_wide_tile_pct",)
+# The cell PR 64 appended behind this one.
+LATER_CELL = "granite4hs-serve-chat"
 
 
 def _config():
@@ -167,9 +169,11 @@ def test_pool_and_traffic_are_what_the_issue_names():
 
 def test_manifest_lists_the_cell_where_the_issue_says():
     m = _manifest()
-    assert m["workloads"][-1]["name"] == CELL
-    assert m["configs"][-1]["name"] == CONFIG
-    cell, config = m["workloads"][-1], m["configs"][-1]
+    # Last when PR 62 appended them; a later cell stands behind.
+    assert [w["name"] for w in m["workloads"]][5:] in (
+        [CELL], [CELL, LATER_CELL])
+    assert [c["name"] for c in m["configs"]][5] == CONFIG
+    cell, config = m["workloads"][5], m["configs"][5]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (CONFIG, "rag-closed-64", 1)
     assert config["reduced"] == list(REDUCED)
@@ -188,10 +192,13 @@ def test_manifest_lists_the_cell_where_the_issue_says():
     assert tuple(names[at:at + len(NEW)]) == NEW
     assert tuple(names[at + len(NEW):])[:len(LATER)] == LATER
     for name in NEW + LATER:
-        assert by_name[name]["workloads"] == [CELL], name
+        # PR 64's cell names ``moe_experts_chunk`` kernels too.
+        assert by_name[name]["workloads"] in (
+            [CELL], [CELL, LATER_CELL]), name
         assert by_name[name]["moves"] == "serve_tokens_per_s", name
     for name in SHARED:
-        assert by_name[name]["workloads"][-1] == CELL, name
+        after = by_name[name]["workloads"]
+        assert after[after.index(CELL) + 1:] in ([], [LATER_CELL]), name
     mine = {x["name"] for x in m["per_layer"] if CELL in x["workloads"]}
     assert mine == set(SHARED) | set(NEW) | set(LATER)
     for name in mine:
