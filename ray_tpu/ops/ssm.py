@@ -13,12 +13,12 @@ models/granite_hybrid.py.) Everything here is float32: the state is
 what a sequence carries for thousands of tokens.
 
 **A group wider than a block.** Both kernels take their heads a block
-at a time (``SCAN_HEADS`` of a span's, ``HALF`` of a decode step's). A
-block holds whole groups where a group is no wider than it (Nemotron-3:
-8 groups of 16), and lies inside ONE group where a group is wider
-(Granite 4.0-H: one group of 128): then the blocks of a group read the
-same ``B`` and ``C`` rows, which their index maps name by the group and
-not by the block, and each keeps its own heads' state.
+at a time (``SCAN_HEADS`` of a span's, ``UPDATE_HEADS`` of a decode
+step's). A block holds whole groups where a group is no wider than it
+(Nemotron-3: 8 groups of 16), and lies inside ONE group where a group
+is wider (Granite 4.0-H: one group of 128): then the blocks of a group
+read the same ``B`` and ``C`` rows, which their index maps name by the
+group and not by the block, and each keeps its own heads' state.
 
   ssd_scan     a span of a prompt: the chunked form (state-space
                duality, Dao & Gu 2024): blocks of ``chunk`` tokens, a
@@ -40,13 +40,24 @@ not by the block, and each keeps its own heads' state.
                slot's state block by block, writes it back where it was
                (``input_output_aliases``) and hands back ``y``. A step
                moves each live state once in and once out and nothing
-               else of the pool. On the CPU the Pallas interpreter runs
-               the same kernel (tests).
+               else of the pool. A grid step holds ``UPDATE_HEADS``
+               heads of one lane (2 MiB each way at heads of 64 by a
+               state of 128) and has to stay under what those bytes
+               take, ~15 cycles a vreg of state: so a head's decay is
+               a scalar, ``dt x`` goes in as it lies and is stood up as
+               columns once a grid step, and ``y`` is summed down the
+               sublanes of a tile's transpose and leaves lane-dense:
+               two passes of the cross-lane unit a vreg (the ``dt x``
+               column's broadcast, the transpose), which fit under the
+               block's copies; a third does not (~5 ns each on a v5e
+               against 6.4 us a block of 512 vregs). On the CPU the
+               Pallas interpreter runs the same kernel (tests).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +66,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
-# Lanes of the kernel's column block: a head's ``dt x`` column sits at
-# lane j, its decay at lane HALF + j.
-LANES, HALF = 128, 64
+# Lanes of a vector register, and the heads of a lane that a grid step
+# of the update takes.
+LANES, UPDATE_HEADS = 128, 64
 # The two kernels' names on a device trace (the benchmark's readers find
 # them by these).
 SCAN_KERNEL, UPDATE_KERNEL = "ssm_scan", "ssm_update"
@@ -204,31 +215,47 @@ def ssm_recurrence(x, dt, A, B, C, S0):
 
 
 def _head_block(H: int, G: int) -> int:
-    """Heads a grid step updates: as many as the column block has lanes
-    for (``HALF``): whole groups, or a part of ONE group that is wider
-    than that."""
-    hb = min(H, HALF)
+    """Heads a grid step updates (``UPDATE_HEADS`` of a lane's): whole
+    groups, or a part of ONE group that is wider than that."""
+    hb = min(H, UPDATE_HEADS)
     if H % hb or (hb % (H // G) and (H // G) % hb):
         raise ValueError(f"{H} heads in {G} groups do not cut into blocks "
                          f"of {hb}")
     return hb
 
 
-def _update_kernel(slots_ref, cols_ref, b_ref, c_ref, s_ref, y_ref, o_ref,
-                   *, hb: int, hg: int):
+def _tile_heads(hb: int, P: int) -> int:
+    """Heads of a block whose rows fill a lane tile (two of 64): their
+    outputs leave the kernel as ONE row of ``LANES``."""
+    return math.gcd(hb, max(1, LANES // P))
+
+
+def _update_kernel(slots_ref, decay_ref, dtx_ref, b_ref, c_ref, s_ref, y_ref,
+                   o_ref, *, hb: int, hg: int, th: int):
     """One lane's ``hb`` heads: ``S <- decay S + (dt x) B^T``, ``y = S
-    C``. The columns block [P, 128] has head j's ``dt x`` [P] at lane j
-    and its decay (the same value down the column) at lane HALF + j, so
-    a head's two columns broadcast along the state's lanes as they
-    are."""
+    C``, a tile of ``th`` heads at a time. Beside the state's own two
+    products and a sum, a vreg of state costs ONE pass of the cross-lane
+    unit each way: its ``dt x`` column broadcast along the state's
+    lanes, and its part of the tile's transpose. A head's decay is a
+    scalar (SMEM), splat where it is used. ``dt x`` comes as it lies,
+    a row a tile, and is stood up as columns by one small transpose a
+    grid step. ``y`` sums a tile's ``S C`` products [th * P, N] down
+    the sublanes of their transpose, plain adds, and leaves as the
+    tile's row, lane-dense."""
     del slots_ref
-    cols = cols_ref[...]
-    for j in range(hb):
-        g = j // hg
-        new = (cols[:, HALF + j:HALF + j + 1] * s_ref[j]
-               + cols[:, j:j + 1] * b_ref[g:g + 1, :])
-        o_ref[j] = new
-        y_ref[:, j:j + 1] = jnp.sum(new * c_ref[g:g + 1, :], axis=1,
+    i, k = pl.program_id(0), pl.program_id(1)
+    P = s_ref.shape[1]
+    cols = dtx_ref[...].T               # tile m's dt x [th * P] down lane m
+    for m in range(hb // th):
+        tile = []
+        for r in range(th):
+            j = m * th + r
+            g = j // hg
+            new = (decay_ref[i, k * hb + j] * s_ref[j]
+                   + cols[r * P:(r + 1) * P, m:m + 1] * b_ref[g:g + 1, :])
+            o_ref[j] = new
+            tile.append(new * c_ref[g:g + 1, :])
+        y_ref[m:m + 1, :] = jnp.sum(jnp.concatenate(tile, axis=0).T, axis=0,
                                     keepdims=True)
 
 
@@ -236,10 +263,11 @@ def _update_kernel(slots_ref, cols_ref, b_ref, c_ref, s_ref, y_ref, o_ref,
 def _make_update(b: int, L: int, slots: int, H: int, P: int, N: int,
                  G: int, layer: int, interpret: bool):
     hb = _head_block(H, G)
+    th = _tile_heads(hb, P)
     hg, nk = H // G, H // hb
     small = lambda rows, width: pl.BlockSpec(
-        (None, None, rows, width), lambda i, k, slots_ref: (i, k, 0, 0))
-    cols = small(P, LANES)
+        (None, None, rows, width), lambda i, k, *_: (i, k, 0, 0))
+    tile_rows = small(hb // th, th * P)
     if hg <= hb:
         group_rows = small(hb // hg, N)
     else:
@@ -247,23 +275,22 @@ def _make_update(b: int, L: int, slots: int, H: int, P: int, N: int,
         # row of ``B`` and of ``C`` ([b, G, 1, N]).
         wide = hg // hb
         group_rows = pl.BlockSpec(
-            (None, None, 1, N),
-            lambda i, k, slots_ref: (i, k // wide, 0, 0))
+            (None, None, 1, N), lambda i, k, *_: (i, k // wide, 0, 0))
         hg = hb
     state = pl.BlockSpec((None, None, hb, P, N),
-                         lambda i, k, slots_ref: (layer, slots_ref[i], k,
-                                                  0, 0))
+                         lambda i, k, slots_ref, _: (layer, slots_ref[i], k,
+                                                     0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(b, nk),
-        in_specs=[cols, group_rows, group_rows, state],
-        out_specs=[cols, state])
+        num_scalar_prefetch=2, grid=(b, nk),
+        in_specs=[tile_rows, group_rows, group_rows, state],
+        out_specs=[tile_rows, state])
     return pl.pallas_call(
-        functools.partial(_update_kernel, hb=hb, hg=hg),
+        functools.partial(_update_kernel, hb=hb, hg=hg, th=th),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, nk, P, LANES), F32),
+        out_shape=[jax.ShapeDtypeStruct((b, nk, hb // th, th * P), F32),
                    jax.ShapeDtypeStruct((L, slots, H, P, N), F32)],
-        # (slots, cols, B, C, pool) -> (y, pool): the pool in place.
-        input_output_aliases={4: 1},
+        # (slots, decay, dt x, B, C, pool) -> (y, pool): the pool in place.
+        input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret, name=UPDATE_KERNEL)
@@ -279,21 +306,18 @@ def ssm_update(pool, layer: int, slots, decay, dtx, B, C):
     b, G = B.shape[:2]
     hb = _head_block(H, G)
     nk = H // hb
-    # Head j of block k: its dt x down lane j, its decay down lane
-    # HALF + j.
-    cols = jnp.zeros((b, nk, P, LANES), F32)
-    cols = cols.at[..., :hb].set(
-        dtx.astype(F32).reshape(b, nk, hb, P).transpose(0, 1, 3, 2))
-    cols = cols.at[..., HALF:HALF + hb].set(jnp.broadcast_to(
-        decay.astype(F32).reshape(b, nk, 1, hb), (b, nk, P, hb)))
+    # ``dt x`` goes in and ``y`` comes out as they lie: a block's heads
+    # a tile a row.
+    tiles = (b, nk, -1, _tile_heads(hb, P) * P)
     # A block's groups' rows; a group wider than a block keeps its own.
     by_block = lambda a: a.astype(F32).reshape(
         *((b, nk, G // nk) if G >= nk else (b, G, 1)), N)
     call = _make_update(b, L, n_slots, H, P, N, G, int(layer),
                         jax.default_backend() == "cpu")
-    y, pool = call(slots.astype(jnp.int32), cols, by_block(B), by_block(C),
+    y, pool = call(slots.astype(jnp.int32), decay.astype(F32),
+                   dtx.astype(F32).reshape(tiles), by_block(B), by_block(C),
                    pool)
-    return y[..., :hb].transpose(0, 1, 3, 2).reshape(b, H, P), pool
+    return y.reshape(b, H, P), pool
 
 
 def ssm_update_reference(pool, layer: int, slots, decay, dtx, B, C):

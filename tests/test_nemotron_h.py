@@ -94,27 +94,62 @@ def test_a_group_of_heads_cuts_into_the_kernels_blocks():
         ssm._scan_heads(24)
 
 
-@pytest.mark.parametrize("H,G", [(4, 2), (128, 1), (128, 8), (128, 2)])
-def test_update_kernel_moves_the_lanes_slots_and_no_other(H, G):
+def _update_case(H, G, P, N, slots, lanes, seed=0):
+    """A pool of 2 layers and ``ssm_update``'s operands for ``lanes``
+    (slot 0: a padded lane)."""
+    rng = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    b = len(lanes)
+    pool = f(2, slots, H, P, N)
+    decay = jnp.asarray(rng.uniform(0.5, 1, (b, H)), jnp.float32)
+    return pool, jnp.asarray(lanes, jnp.int32), decay, f(b, H, P), \
+        f(b, G, N), f(b, G, N)
+
+
+@pytest.mark.parametrize("H,G,P,N,slots,lanes", [
+    (4, 2, 8, 16, 6, (2, 0, 5, 0)), (128, 1, 8, 16, 6, (2, 0, 5, 0)),
+    (128, 8, 8, 16, 6, (2, 0, 5, 0)), (128, 2, 8, 16, 6, (2, 0, 5, 0)),
+    # The cells' own block: heads of 64 by a state of 128, 8 groups of
+    # 16 (Nemotron-3) and ONE of 128 (Granite 4.0-H); and a lane alone.
+    (128, 8, 64, 128, 4, (3, 0, 1)), (128, 1, 64, 128, 4, (3, 0, 1)),
+    (128, 8, 64, 128, 4, (2,)), (4, 2, 8, 16, 6, (4,))],
+    ids=["4_in_2", "128_in_1", "128_in_8", "128_in_2", "cell_8_groups",
+         "cell_1_group", "cell_one_lane", "one_lane"])
+def test_update_kernel_moves_the_lanes_slots_and_no_other(H, G, P, N, slots,
+                                                          lanes):
     """Whole groups in a block of heads (4 in 2; 128 in 8, Nemotron-3's)
     and a group wider than a block (128 in 1, Granite 4.0-H's; in 2,
-    a block a group)."""
-    rng = np.random.default_rng(0)
-    P, N, L, slots, b = 8, 16, 2, 6, 4
-    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
-    pool = f(L, slots, H, P, N)
-    lanes = jnp.asarray([2, 0, 5, 0], jnp.int32)    # two padded: scratch
-    decay = jnp.asarray(rng.uniform(0.5, 1, (b, H)), jnp.float32)
-    dtx, B, C = f(b, H, P), f(b, G, N), f(b, G, N)
-    y, out = ssm.ssm_update(pool, 1, lanes, decay, dtx, B, C)
-    y_want, want = ssm.ssm_update_reference(pool, 1, lanes, decay, dtx, B, C)
-    live = np.array([0, 2])
-    assert np.abs(y - y_want)[live].max() < 1e-5
-    for l in range(L):
+    a block a group), at the tests' small heads and at the cells'."""
+    pool, at, decay, dtx, B, C = _update_case(H, G, P, N, slots, lanes)
+    y, out = ssm.ssm_update(pool, 1, at, decay, dtx, B, C)
+    y_want, want = ssm.ssm_update_reference(pool, 1, at, decay, dtx, B, C)
+    live = np.flatnonzero(np.asarray(lanes))
+    assert y.shape == y_want.shape and y.dtype == jnp.float32
+    assert np.abs(y - y_want)[live].max() < 1e-5 * max(1, N // 16)
+    for l in range(2):
         for s in range(1, slots):       # slot 0 is scratch
             assert np.abs(out[l, s] - want[l, s]).max() < 1e-5, (l, s)
     assert np.array_equal(out[0], pool[0])          # the other layer
-    assert np.array_equal(out[1, [1, 3, 4]], pool[1, [1, 3, 4]])
+    rest = sorted(set(range(1, slots)) - set(lanes))
+    assert np.array_equal(out[1, rest], pool[1, rest])
+
+
+@pytest.mark.parametrize("H,G,P,N", [(4, 2, 8, 16), (128, 8, 64, 128),
+                                     (128, 1, 64, 128)])
+def test_the_state_written_is_bit_for_bit_the_three_elementwise_steps(
+        H, G, P, N):
+    """``S <- decay S + (dt x) B^T`` is a product, a product and a sum
+    an element, in that order, whatever the kernel does around them: a
+    live lane's slot comes back BIT-EQUAL to the plain form's (compiled
+    as the interpreter compiles the kernel: the same fused arithmetic).
+    Only ``y`` is summed in another order, and holds to 1e-5 a term."""
+    pool, at, decay, dtx, B, C = _update_case(H, G, P, N, 4, (3, 0, 1),
+                                              seed=1)
+    y, out = ssm.ssm_update(pool, 0, at, decay, dtx, B, C)
+    y_want, want = jax.jit(ssm.ssm_update_reference, static_argnums=1)(
+        pool, 0, at, decay, dtx, B, C)
+    assert np.array_equal(out[0, [3, 1]], want[0, [3, 1]])
+    assert np.abs(y - y_want)[[0, 2]].max() < 1e-5 * max(1, N // 16)
 
 
 def test_not_gated_expert_through_the_grouped_product_equals_a_dense_loop():

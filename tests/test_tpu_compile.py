@@ -559,6 +559,29 @@ def _kernel_operands(text, name):
             for kernel, line in _mosaic_lines(text) if kernel == name]
 
 
+def _operand_shapes(text, name):
+    """The operands' shapes (as printed: ``f32[64,2,32,128]{3,2,1,0}``)
+    of each Mosaic call whose result is ``name``, a list a call."""
+    import re
+
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+)", text,
+                               re.MULTILINE))
+    return [[shape_of[operand] for operand in call]
+            for call in _kernel_operands(text, name)]
+
+
+def _column_blocks(text, name):
+    """Operands of the Mosaic calls ``name`` that are a column block
+    ``[b, nk, P, 128]`` of the cells' heads of 64: what ``ssm_update``
+    was handed before PR 65 (``dt x`` and the decay stood up as lane
+    columns by the program around it, 4.2 MB written and read a layer;
+    since then ``dt x`` goes in as it lies, a row two heads, and the
+    decay as scalars)."""
+    return [shape for call in _operand_shapes(text, name) for shape in call
+            if shape.startswith((f"f32[{CELL_B},1,64,128]",
+                                 f"f32[{CELL_B},2,64,128]"))]
+
+
 def _fusions_of(text, shape):
     """How many fusions of a compiled program give ``shape``."""
     import re
@@ -1153,6 +1176,7 @@ def test_nemotron_decode_program_moves_its_states_in_place(
                              "fusion") == []
     for line in [l for n, l in _mosaic_lines(text) if n == "ssm_update"]:
         assert NEMO_STATE in line.split("custom-call(")[0]     # comes back
+    assert _column_blocks(text, "ssm_update") == []
     # params' leaves, the packed array, then the four pools: outputs 2-5
     # behind the logits and the ids; and ``firsts`` from the device.
     n = nemotron_programs["param_leaves"]
@@ -1265,6 +1289,7 @@ def test_granite_decode_program_moves_its_states_in_place(granite_programs):
                                 "fusion") == []
     for line in [l for n, l in _mosaic_lines(text) if n == "ssm_update"]:
         assert GRANITE_STATE in line.split("custom-call(")[0]
+    assert _column_blocks(text, "ssm_update") == []
     n = granite_programs["param_leaves"]
     assert _aliased(text) == {n + 1: 2, n + 2: 3, n + 3: 4, n + 4: 5}
     assert _entry_parameters(text) == n + 6
@@ -1602,7 +1627,10 @@ PROGRAMS_AT_PR62 = {
             "cold_chunk": [("moe_experts_chunk", "4c88f51ff0bb2351"),
                            ("moe_experts_chunk", "b623716b1f0f63b4")]}},
     "nemotron": {
-        "texts": {"decode": "850b26c9dd3c3703", "chunk": "7c2f03116a053fc2"},
+        # decode: re-recorded by PR 65 (``ssm_update``'s operands: the decay
+        # a scalar-prefetch array, ``dt x`` as it lies); the chunk program
+        # and the grouped products' bodies are PR 62's.
+        "texts": {"decode": "b7177c031df3bed4", "chunk": "7c2f03116a053fc2"},
         "moe_kernels": {
             "decode": [("moe_experts_decode", "350735f216903e16"),
                        ("moe_experts_decode", "8f235c3e9216f1c5")],
