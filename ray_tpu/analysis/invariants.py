@@ -32,6 +32,14 @@ I410  silent alert/incident transition — every alert-engine incident
       timeline (``rtpu incident show``, the ``slo_breach`` ledger
       emission) quietly diverges from what the burn-rate evaluator
       actually decided.
+I411  an import against the layers — ``ops <- models <- llm <-
+      serve``: a lower package that imports a higher one (at any depth
+      of nesting: inside a function the arrow is true only at call
+      time, which is how it hid), or a module of ``models/`` that takes
+      a sibling's underscore name (``from .laguna import _rmsnorm``,
+      ``other._counters(...)``): what two families share lives in a shared
+      module under a public name, so the next family reads an
+      interface and not another family's privates.
 
 Adding a new invariant lint = appending a row to the right table (or a
 new table + ~10-line checker below). New site families go through this
@@ -363,6 +371,42 @@ FLIGHTREC_SITE_TABLES = (
 )
 
 
+#: The packages' layers, ``ops <- models <- llm <- serve``: (a
+#: package's path, the packages no module under it may import, why).
+LAYER_TABLES = (
+    ("ray_tpu/ops", ("ray_tpu.models", "ray_tpu.llm", "ray_tpu.serve"),
+     "a kernel or an op knows no model, engine or deployment"),
+    ("ray_tpu/models", ("ray_tpu.llm", "ray_tpu.serve"),
+     "a model family is below the engine that serves it: what both "
+     "read of a pool's layout lives in models/seam.py"),
+    ("ray_tpu/llm", ("ray_tpu.serve",),
+     "the generation engine knows no deployment"),
+)
+#: Packages whose modules take nothing of one another by an underscore
+#: name: a shared part has a public name in a shared module.
+PRIVATE_IMPORT_PACKAGES = ("ray_tpu/models",)
+
+
+def imports(module: Module):
+    """(node, absolute dotted module, imported names) of every import
+    statement of a module, at any depth of nesting; a relative import
+    resolved against the module's package."""
+    package = module.relpath.split("/")[:-1]
+    for node in ast.walk(module.tree):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                yield node, alias.name, ()
+        elif isinstance(node, ast.ImportFrom):
+            base = package[:len(package) - (node.level - 1)] \
+                if node.level else []
+            full = ".".join(base + ([node.module] if node.module else []))
+            yield node, full, tuple(node.names)
+
+
+def _within(dotted: str, package: str) -> bool:
+    return dotted == package or dotted.startswith(package + ".")
+
+
 # ---------------------------------------------------------------------------
 # Checkers
 # ---------------------------------------------------------------------------
@@ -510,3 +554,63 @@ class SilentAlertTransition(_TableChecker):
     severity = "P0"
     tables = ALERT_SITE_TABLES
     mode = "method_call"
+
+
+@register
+class ImportAgainstTheLayers(Checker):
+    id = "I411"
+    family = "invariants"
+    severity = "P0"
+    scope = "repo"
+
+    def _finding(self, module, node, message):
+        return Finding(
+            checker=self.id, family=self.family, severity="P0",
+            path=module.relpath, line=node.lineno, col=node.col_offset,
+            symbol="", snippet=module.segment(node), message=message)
+
+    def check_repo(self, ctx: Context) -> Iterable[Finding]:
+        layers = ctx.config.get("I411_tables", LAYER_TABLES)
+        private = ctx.config.get("I411_private", PRIVATE_IMPORT_PACKAGES)
+        under = lambda module, path: module.relpath.startswith(path + "/")
+        for module in ctx.modules:
+            banned = [(path, b, why) for path, names, why in layers
+                      if under(module, path) for b in names]
+            package = next((p for p in private if under(module, p)), None)
+            if not banned and package is None:
+                continue
+            found = list(imports(module))
+            for node, full, names in found:
+                # ``from .. import llm`` names the package too.
+                reached = [full] + [f"{full}.{a.name}" for a in names]
+                hit = next((row for row in banned
+                            if any(_within(r, row[1]) for r in reached)),
+                           None)
+                if hit:
+                    yield self._finding(
+                        module, node, f"{hit[0]}/ imports {hit[1]}: {hit[2]}")
+            if package is None:
+                continue
+            dotted = package.replace("/", ".")
+            is_private = lambda name: name.startswith("_") \
+                and not name.startswith("__")
+            why = (f"a sibling's private name; what two modules of "
+                   f"{package}/ share has a public name in a shared module")
+            siblings = set()        # names bound to modules of the package
+            for node, full, names in found:
+                if not _within(full, dotted):
+                    continue
+                for a in names:
+                    if full == dotted:      # ``from . import kimi_k2``
+                        siblings.add(a.asname or a.name)
+                    elif is_private(a.name):
+                        yield self._finding(
+                            module, node, f"takes {a.name} from {full}: {why}")
+            for node in ast.walk(module.tree):
+                if isinstance(node, ast.Attribute) \
+                        and isinstance(node.value, ast.Name) \
+                        and node.value.id in siblings \
+                        and is_private(node.attr):
+                    yield self._finding(
+                        module, node,
+                        f"reaches {node.value.id}.{node.attr}: {why}")

@@ -12,7 +12,7 @@ itself, built from the repo's own layers:
   * ops/pallas/paged_fetch.py — decode-attention kernels that copy
                             their pages out of the pools as stored,
                             by block table (interpret mode on CPU)
-  * models/__init__.py   — the serving seam: a model's step and chunk
+  * models/seam.py       — the serving seam: a model's step and chunk
                             functions, cache and cost descriptions
   * models/gpt.py, models/laguna.py — forward_step /
                             forward_prefill_chunk: a model's layer

@@ -67,10 +67,11 @@ from typing import Callable, Deque, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..models import pack_span, served_params, serving, step_columns
+from ..models import (pack_span, served_params, serving, step_columns,
+                      window_table_len)
 from ..util import perfmodel, tracing
 from .kv_cache import (BlockChain, PagedKVCache, PrefixPool, StatePool,
-                       WindowPool, window_table_len)
+                       WindowPool)
 from .sampling import accept_draws, is_greedy, sample, verify_tokens
 from .spec import make_spec
 
@@ -246,7 +247,7 @@ class LLMEngine:
         self.cfg = cfg
         # What the model's module says of serving it: the two programs,
         # the kinds of layer its cache has, its costs (the seam,
-        # models/__init__.py). Nothing below names a model's fields.
+        # models/seam.py). Nothing below names a model's fields.
         self.model = serving(cfg)
         self.name = name
         self.max_batch = int(max_batch)
@@ -343,7 +344,7 @@ class LLMEngine:
         self._window_live = 0         # window blocks lanes hold, last step
         self._counters = {}           # the step program's own, last step
         # The decode program's ONE host array, kept for the engine's
-        # lifetime (models/__init__.py ``step_columns``): a RUNNING
+        # lifetime (models/seam.py ``step_columns``): a RUNNING
         # request holds a lane's row of it; a block id goes into the
         # row where the block is granted and out where it is given
         # back, a step writes a lane's ``head`` columns (its rows'

@@ -25,15 +25,15 @@ masked writes need no bounds branch. The allocator never hands it out.
 Writes are functional jnp scatters under jit with the pool donated —
 XLA aliases the buffers so steady-state decode does not copy the pool.
 Both served programs make their own (a decode step its rows, a prefill
-chunk its span through ``scatter_span``): no write is dispatched from
-the host between them.
+chunk its span through models/seam.py ``scatter_span``): no write is
+dispatched from the host between them.
 The shape is what lets it: the indexed dimensions (layer, block, offset)
 are the major ones, which is how XLA's scatter wants them, and a row of
 ``kv_heads * head_dim`` is a whole number of 128-lane tiles at every
 served width (768 = 6 x 128 at GPT-2-small), so the TPU runtime keeps
 the array at rest row-major and unpadded and a write lands where it
 is. The shape comes from the model's cache description (the serving
-seam, models/__init__.py): a KIND of layer says what a token leaves
+seam, models/seam.py): a KIND of layer says what a token leaves
 there (``LayerKind.rows``), one pool an entry, ``[layers of the kind,
 num_blocks, block_size, row width]``: keys and values are two pools of
 ``kv_heads * head_dim``, latent attention ONE pool of the token's
@@ -69,43 +69,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models import serving
-
-
-def scatter_span(pools, spans, ids, rows=None):
-    """THE pool write of a prefill span, a pure function: the chunk
-    program (a model's ``forward_prefill_chunk``) calls it on the pools
-    it was donated, and ``PagedKVCache.write_prefill`` through the
-    jitted ``kv_scatter_blocks`` below.
-
-    ``pools``: a kind's pools (``LayerKind.rows``: keys and values, or
-    one pool of latent rows), each ``[L, NB, BS, W_i]``. ``spans``: the
-    span's rows for each pool, in the pool's own order: any shape that
-    flattens to ``[L, T, W_i]`` will do (``[L, T, kv_heads, head_dim]``,
-    whole blocks ``[L, nb, BS, W_i]``), T <= len(ids) * BS. ``ids``
-    [nb] int32: the blocks written, in the span's order. The rows from
-    ``rows`` on (a traced scalar or an int; None = T) and the tail
-    past T are written as ZEROS, masked by context_lens at read time,
-    so a pool's contents do not depend on what a chunk was padded
-    with. Several ids may name the scratch block 0 (a window kind's
-    blocks that slid out before they were written): which of them
-    lands there is nobody's business, the block is never read
-    unmasked. One in-place scatter a pool when the pools are donated:
-    the indexed dimension is the pool's major one after the layers.
-    Returns the pools, written, as a tuple."""
-    n = ids.shape[0] * pools[0].shape[2]
-
-    def blocks(x, pool):
-        L, _, bs, W = pool.shape
-        x = x.reshape(L, -1, W)
-        if n > x.shape[1]:
-            x = jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))
-        if rows is not None:
-            x = jnp.where((jnp.arange(n) < rows)[None, :, None], x, 0)
-        return x.reshape(L, -1, bs, W).astype(pool.dtype)
-
-    return tuple(pool.at[:, ids].set(blocks(x, pool))
-                 for pool, x in zip(pools, spans))
+from ..models import scatter_span, serving
 
 
 # A device trace's ``XLA Modules`` line names each program after its
@@ -140,14 +104,6 @@ def _copy_program(n: int):
 # src, dst)``.
 kv_scatter_blocks = _scatter_program(2)
 kv_copy_block = _copy_program(2)
-
-
-def window_table_len(window: int, block_size: int, rows: int = 1) -> int:
-    """Most blocks a lane holds of a kind of layer with a window while
-    ``rows`` new tokens are written (``WindowPool``: the window, one
-    block's worth of positions of slack, wherever they start in a
-    block): window / block_size + 2 for one row."""
-    return -(-(window - 1 + rows) // block_size) + 2
 
 
 class PagedKVCache:

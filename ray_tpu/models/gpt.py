@@ -17,7 +17,6 @@ native workload.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 from dataclasses import dataclass
 from typing import Any, Optional
@@ -28,7 +27,13 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import NEG_INF, causal_attention
-from ..parallel.sharding import DEFAULT_RULES, logical_to_mesh_axes
+from ..ops.flash_attention import flash_attention
+from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
+                                      paged_attention_stored)
+from ..parallel.sharding import (DEFAULT_RULES, logical_to_mesh_axes,
+                                 shard_like)
+from .seam import (Serving, keys_and_values, scatter_span, unpack_span,
+                   unpack_step)
 
 
 @dataclass(frozen=True)
@@ -83,6 +88,7 @@ class GPTConfig:
         """Training FLOPs/token ≈ 6*N + attention term (delegates to
         util/perfmodel.py — the shared cost model the live
         llm_mfu/train_mfu telemetry series also price against)."""
+        # In the function: util/perfmodel.py imports this package.
         from ..util import perfmodel
 
         return perfmodel.train_flops_per_token(self)
@@ -250,8 +256,6 @@ def _causal_sublayer(h, p, cfg: GPTConfig, mesh, rules):
         # residuals == the weight-grad einsum inputs). (A fused qkv
         # concat-matmul was measured SLOWER — the per-layer concat breaks
         # XLA's cast/einsum fusion — so the three einsums stay separate.)
-        from ..ops.flash_attention import flash_attention
-
         q = jnp.einsum("bsm,mhd->bhsd", h, p["wq"].astype(dt))
         kk = jnp.einsum("bsm,mhd->bhsd", h, p["wk"].astype(dt))
         v = jnp.einsum("bsm,mhd->bhsd", h, p["wv"].astype(dt))
@@ -377,8 +381,6 @@ def _paged_sublayer(h, p, layer, k_pool, v_pool, block_tables, context_lens,
     (ops/pallas/paged_fetch.py, under the name ``paged_decode``) copies
     its pages out of the stacked pools itself, so the step makes no
     other pass over them. Carries the updated pools out of the layer."""
-    from ..ops.pallas.paged_fetch import paged_attention_stored
-
     dt = cfg.dtype
     B, Q = h.shape[:2]
     hkv, group = cfg.kv_heads, cfg.n_head // cfg.kv_heads
@@ -422,7 +424,7 @@ def forward_step(params, packed, k_pool, v_pool, *, q: int,
 
     Args:
       packed: [b, W] int32, the step's whole bookkeeping in ONE array
-        that the engine keeps current (models/__init__.py
+        that the engine keeps current (models/seam.py
         ``step_columns``), taken apart here by static slices into:
       tokens / positions: [b, q] int32 — each row's token and absolute
         position. Rows past q_lens[lane], and every row of a padded
@@ -442,7 +444,7 @@ def forward_step(params, packed, k_pool, v_pool, *, q: int,
       q: rows a lane, a Python int (a shape, not a value).
       firsts: [b] int32 or None — a lane's row-0 token where not
         negative, decided on the device by the chunk program queued
-        before this step (models/__init__.py ``unpack_step``).
+        before this step (models/seam.py ``unpack_step``).
 
     Returns (logits [b, q, vocab], ids [b + 1, q] int32, k_pool,
     v_pool): rows 0..b-1 of ``ids`` are the argmax of each logits row
@@ -450,9 +452,6 @@ def forward_step(params, packed, k_pool, v_pool, *, q: int,
     row), so a greedy lane's tokens are decided here and the host
     fetches ids, not logits; row b is ``COUNTERS``.
     """
-    from ..ops.pallas.paged_fetch import kv_pages_in_runs_x1000
-    from . import unpack_step
-
     (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
      slot_offsets, _) = unpack_step(packed, q, firsts=firsts)
     x = _embed(params, tokens, positions, cfg)
@@ -568,7 +567,7 @@ def forward_prefill_chunk(params, tokens, k_pool, v_pool, table,
       k_pool / v_pool: [L, num_blocks, block_size, kv_heads * head_dim]
         (donate these in the caller's jit, as the decode step's).
       table: int32 ``[block table (nb) | destination (n / block_size) |
-        ctx_len | last]`` (models/__init__.py ``pack_span``). The block
+        ctx_len | last]`` (models/seam.py ``pack_span``). The block
         table is
         0-padded like decode's. It may be EMPTY (nb = 0, with ctx_len
         0): a span with no resident context attends over itself alone,
@@ -581,16 +580,13 @@ def forward_prefill_chunk(params, tokens, k_pool, v_pool, table,
         zeros. Token i sits at position min(ctx_len + i, max_seq - 1).
 
     Every layer READS the pools as they came in; the span is written
-    after the last layer (``llm/kv_cache.py`` ``scatter_span``), so a
+    after the last layer (models/seam.py ``scatter_span``), so a
     destination block may be one the block table still names.
 
     Returns (row [vocab]: the logits of token ``last``; id: their
     argmax, int32, which a greedy request takes as its token; k_pool,
     v_pool). The head runs on that one row.
     """
-    from ..llm.kv_cache import scatter_span
-    from . import unpack_span
-
     n = tokens.shape[1]
     block_table, dest, ctx_len, last = unpack_span(table, n,
                                                    k_pool.shape[2])
@@ -630,10 +626,8 @@ def cost_shape(cfg: GPTConfig) -> dict:
 
 
 def serving(cfg: GPTConfig):
-    """This model behind the serving seam (models/__init__.py): one
+    """This model behind the serving seam (models/seam.py): one
     kind of layer, every layer keeps every token."""
-    from . import Serving, keys_and_values
-
     full = keys_and_values("full", range(cfg.n_layer), cfg.kv_heads,
                            cfg.head_dim, None, cfg.dtype)
     return Serving(init=init, step=forward_step,
@@ -706,6 +700,7 @@ def make_train_step(cfg: GPTConfig, optimizer, mesh: Optional[Mesh] = None,
         updates, opt_state = optimizer.update(
             grads, state["opt_state"], state["params"]
         )
+        # In the function: only a trainer needs optax installed.
         import optax
 
         params = optax.apply_updates(state["params"], updates)
@@ -732,8 +727,6 @@ def shard_state(state, mesh: Mesh, cfg: GPTConfig, rules=None):
     moments mirror params *by tree structure* (see parallel.sharding
     shard_like), so wq/wk/wv — equal shapes, different specs — stay correct.
     """
-    from ..parallel.sharding import shard_like
-
     pspec = params_pspecs(cfg, rules)
     params = jax.tree_util.tree_map(
         lambda leaf, spec: jax.device_put(leaf, NamedSharding(mesh, spec)),

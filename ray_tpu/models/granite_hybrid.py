@@ -22,13 +22,14 @@ float32 reference of these equations.
   two attention kernels are handed. NO positional embedding
   (``position_embedding_type`` ``nope``: the state-space layers carry
   position); keys are cached as projected.
-``mamba``: the Mamba-2 mixer of models/nemotron_h.py (its docstring has
-  the equations) with ``mamba_n_groups`` groups of heads: at the
-  published ONE group all 128 heads read the same ``B_t`` and ``C_t``
-  and the gated norm runs over all of ``d_inner``. That module's mixer
-  functions (``mamba_step``, ``mamba_chunk``, and ``attention_step``,
-  ``attention_chunk`` handed the scale) are called, not copied; a group
-  wider than a block of heads is ops/ssm.py's to run.
+``mamba``: the Mamba-2 mixer of models/mamba2.py (its docstring has the
+  equations) with ``mamba_n_groups`` groups of heads: at the published
+  ONE group all 128 heads read the same ``B_t`` and ``C_t`` and the
+  gated norm runs over all of ``d_inner``. That module's ``mamba_step``
+  and ``mamba_chunk``, and models/layers.py's ``attention_step`` and
+  ``attention_chunk`` handed the scale, are called, as models/
+  nemotron_h.py calls them; a group wider than a block of heads is
+  ops/ssm.py's to run.
 ``Experts``: ``l = u W_r`` over all ``num_local_experts`` in float32;
   the ``num_experts_per_tok`` largest; ``w = softmax`` over those
   logits (ops/moe.py ``route`` at scale 1: a softmax over all, the
@@ -42,7 +43,7 @@ MIXER kind: an attention layer a token's keys and values (the seam's
 ``kinds``), a Mamba-2 layer a state ``S`` [heads, head width, state] in
 float32 and the last ``mamba_d_conv - 1`` rows of pre-convolution
 ``xBC`` (the seam's ``state``). The pools' layer indices count the
-layers of one mixer kind (``nemotron_h._pool_index`` over
+layers of one mixer kind (models/layers.py ``pool_index`` over
 ``layer_types``); the experts, which keep nothing, are in all of them.
 
 **Precision as served:** ``cfg.dtype`` (bfloat16) weights, activations,
@@ -69,8 +70,13 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import moe
-from . import nemotron_h as nh
-from .laguna import _rmsnorm, _swiglu
+from ..ops.pallas.paged_fetch import kv_pages_in_runs_x1000
+from .layers import (COUNTERS, attention_chunk, attention_params,
+                     attention_step, counters, held_experts, normal,
+                     pool_index, rmsnorm, swiglu)
+from .mamba2 import Mamba2, mamba_chunk, mamba_params, mamba_step
+from .seam import (Serving, StateKind, keys_and_values, scatter_span,
+                   step_state_slots, unpack_span, unpack_step)
 
 MAMBA, ATTENTION = "mamba", "attention"
 F32 = jnp.float32
@@ -146,21 +152,21 @@ class GraniteHybridConfig:
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
 
-    # The names models/nemotron_h.py's mixer functions read a
-    # configuration by (its own are another publisher's keys).
-    mamba_num_heads = property(lambda self: self.mamba_n_heads)
-    mamba_head_dim = property(lambda self: self.mamba_d_head)
-    n_groups = property(lambda self: self.mamba_n_groups)
-    ssm_state_size = property(lambda self: self.mamba_d_state)
-    conv_kernel = property(lambda self: self.mamba_d_conv)
+    @property
+    def mamba(self) -> Mamba2:
+        """This family's mixer, as models/mamba2.py reads one (no
+        time-step range is published: the record's own)."""
+        return Mamba2(
+            hidden_size=self.hidden_size, heads=self.mamba_n_heads,
+            head_dim=self.mamba_d_head, groups=self.mamba_n_groups,
+            state=self.mamba_d_state, conv_kernel=self.mamba_d_conv,
+            chunk_size=self.mamba_chunk_size, eps=self.rms_norm_eps,
+            dtype=self.dtype)
+
+    # The scan's block length under the name benchmark/
+    # reference_nemotron_h.py reads it by (``_span_fn``, which
+    # benchmark/reference_granite_hybrid.py runs on this configuration).
     chunk_size = property(lambda self: self.mamba_chunk_size)
-    # The Mamba-2 initialiser's time-step range, which this config does
-    # not give: Nemotron-H's published one.
-    time_step_min, time_step_max, time_step_floor = 0.001, 0.1, 1e-4
-    layer_norm_epsilon = property(lambda self: self.rms_norm_eps)
-    d_inner = property(lambda self: self.mamba_n_heads * self.mamba_d_head)
-    conv_dim = property(lambda self: self.d_inner
-                        + 2 * self.mamba_n_groups * self.mamba_d_state)
 
     def mixer_params(self, kind: str) -> int:
         """Parameters of one mixer of a kind, without its pre-norm."""
@@ -169,10 +175,7 @@ class GraniteHybridConfig:
             H, kv, d = (self.num_attention_heads, self.num_key_value_heads,
                         self.head_dim)
             return 2 * m * H * d + 2 * m * kv * d
-        H = self.mamba_n_heads
-        return (m * (self.d_inner + self.conv_dim + H)
-                + (self.mamba_d_conv + 1) * self.conv_dim + 3 * H
-                + self.d_inner + self.d_inner * m)
+        return self.mamba.num_params
 
     @property
     def expert_params(self) -> int:
@@ -211,9 +214,8 @@ EMBED_STD = 0.004
 def init(key, cfg: GraniteHybridConfig) -> dict:
     """Seeded random parameters in ``cfg.dtype`` (normal, std 0.02, the
     tied matrix ``EMBED_STD``; norms 1; a Mamba-2 mixer's own by
-    models/nemotron_h.py's initialiser), a layer at a time. A routed
-    expert's weights depend on the key and the expert's GLOBAL id alone,
-    so every share of one model holds slices of the same experts.
+    models/mamba2.py's initialiser), a layer at a time, a routed
+    expert's by its GLOBAL id (models/layers.py ``held_experts``).
     ``embed`` is the one tied matrix."""
     return {
         **_init_ends(jax.random.fold_in(key, 1 << 20), cfg),
@@ -225,8 +227,7 @@ def init(key, cfg: GraniteHybridConfig) -> dict:
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _init_ends(key, cfg: GraniteHybridConfig) -> dict:
     m = cfg.hidden_size
-    return {"embed": nh._normal(key, (cfg.vocab_size, m), cfg.dtype,
-                                EMBED_STD),
+    return {"embed": normal(key, (cfg.vocab_size, m), cfg.dtype, EMBED_STD),
             "norm_f": jnp.ones((m,), cfg.dtype)}
 
 
@@ -237,27 +238,21 @@ def init_layer(key, cfg: GraniteHybridConfig, l: int) -> dict:
 
 @functools.partial(jax.jit, static_argnames=("cfg", "kind"))
 def _init_layer(key, cfg: GraniteHybridConfig, kind: str) -> dict:
-    m, dt, normal = cfg.hidden_size, cfg.dtype, nh._normal
+    m, dt = cfg.hidden_size, cfg.dtype
     k = iter(jax.random.split(key, 12))
     p = {"ln_a": jnp.ones((m,), dt), "ln_b": jnp.ones((m,), dt)}
     if kind == ATTENTION:
-        H, kv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                    cfg.head_dim)
-        p.update(wq=normal(next(k), (m, H, d), dt),
-                 wk=normal(next(k), (m, kv, d), dt),
-                 wv=normal(next(k), (m, kv, d), dt),
-                 wo=normal(next(k), (H, d, m), dt))
+        p.update(attention_params(k, m, cfg.num_attention_heads,
+                                  cfg.num_key_value_heads, cfg.head_dim, dt))
     else:
-        p.update(nh.mamba_params(k, cfg))
+        p.update(mamba_params(k, cfg.mamba))
     f, fs = cfg.intermediate_size, cfg.shared_intermediate_size
     k1, k2 = next(k), next(k)
-    held = cfg.first_expert + jnp.arange(cfg.experts_held)
+    share = (cfg.first_expert, cfg.experts_held)
     p.update(
         router=normal(next(k), (m, cfg.num_local_experts), dt),
-        w1=jax.vmap(lambda e: normal(
-            jax.random.fold_in(k1, e), (m, 2 * f), dt))(held),
-        w2=jax.vmap(lambda e: normal(
-            jax.random.fold_in(k2, e), (f, m), dt))(held),
+        w1=held_experts(k1, *share, (m, 2 * f), dt),
+        w2=held_experts(k2, *share, (f, m), dt),
         s_gu=normal(next(k), (m, 2 * fs), dt),
         s_down=normal(next(k), (fs, m), dt))
     return p
@@ -282,7 +277,7 @@ def _experts(u, p, cfg: GraniteHybridConfig, program: str):
             u, experts, weights, p["w1"], p["w2"], first=cfg.first_expert,
             n_experts=cfg.num_local_experts, name=f"moe_experts_{program}")
     with jax.named_scope("shared_mlp"):
-        return y + _swiglu(u, p["s_gu"], p["s_down"]), sizes
+        return y + swiglu(u, p["s_gu"], p["s_down"]), sizes
 
 
 def _add(x, out, cfg: GraniteHybridConfig):
@@ -298,12 +293,9 @@ def _embed(params, tokens, cfg: GraniteHybridConfig):
 
 def _head(params, x, cfg: GraniteHybridConfig):
     """The tied head: the embedding's rows as they lie."""
-    x = _rmsnorm(x, params["norm_f"], cfg.rms_norm_eps)
+    x = rmsnorm(x, params["norm_f"], cfg.rms_norm_eps)
     logits = jnp.einsum("brm,vm->brv", x, params["embed"])
     return (logits.astype(F32) / cfg.logits_scaling).astype(x.dtype)
-
-
-COUNTERS = nh.COUNTERS
 
 
 # ---------------------------------------------------------------------------
@@ -322,9 +314,6 @@ def forward_step(params, packed, k_pool, v_pool, s_pool, c_pool, *, q: int,
     Returns (logits [b, 1, vocab], ids [b + 4, 1] int32, k_pool, v_pool,
     s_pool, c_pool): rows b on of ``ids`` are ``COUNTERS``, the expert
     blocks of ALL layers counted."""
-    from ..ops.pallas.paged_fetch import kv_pages_in_runs_x1000
-    from . import step_state_slots, unpack_step
-
     if q != 1:
         raise ValueError("a state is moved one token a step: q must be 1")
     (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
@@ -333,26 +322,25 @@ def forward_step(params, packed, k_pool, v_pool, s_pool, c_pool, *, q: int,
     B = tokens.shape[0]
     lanes = (block_tables, context_lens, q_lens,
              jnp.zeros_like(context_lens), slot_blocks, slot_offsets)
-    eps = cfg.rms_norm_eps
+    eps, mx = cfg.rms_norm_eps, cfg.mamba
     x = _embed(params, tokens, cfg)                      # [B, 1, m]
     sizes = []
-    for li, p in zip(nh._pool_index(cfg.layer_types), params["layers"]):
-        h = _rmsnorm(x, p["ln_a"], eps)
+    for li, p in zip(pool_index(cfg.layer_types), params["layers"]):
+        h = rmsnorm(x, p["ln_a"], eps)
         if "wq" in p:
-            out, k_pool, v_pool = nh.attention_step(
-                h, p, cfg, li, k_pool, v_pool, lanes,
-                cfg.attention_multiplier)
+            out, k_pool, v_pool = attention_step(
+                h, p, li, k_pool, v_pool, lanes, cfg.attention_multiplier)
         else:
-            out, s_pool, c_pool = nh.mamba_step(h, p, cfg, li, slots,
-                                                s_pool, c_pool)
+            out, s_pool, c_pool = mamba_step(h, p, mx, li, slots, s_pool,
+                                             c_pool)
         x = _add(x, out, cfg)
-        out, s = _experts(_rmsnorm(x, p["ln_b"], eps)[:, 0], p, cfg,
+        out, s = _experts(rmsnorm(x, p["ln_b"], eps)[:, 0], p, cfg,
                           "decode")
         sizes.append(s)
         x = _add(x, out[:, None], cfg)
     logits = _head(params, x, cfg)
     ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    ids = jnp.concatenate([ids, nh._counters(
+    ids = jnp.concatenate([ids, counters(
         sizes, B, cfg.num_local_experts, cfg.num_experts_per_tok, 1,
         kv_pages_in_runs_x1000(
             block_tables, context_lens, k_pool, v_pool,
@@ -370,30 +358,27 @@ def forward_prefill_chunk(params, tokens, k_pool, v_pool, table, s_pool,
     are written after the last layer).
 
     Returns (row [vocab], id, k_pool, v_pool, s_pool, c_pool)."""
-    from ..llm.kv_cache import scatter_span
-    from . import unpack_span
-
     n = tokens.shape[1]
     bs = k_pool.shape[2]
     block_table, dest, ctx_len, last, src, dst = unpack_span(
         table, n, bs, extra=2)
-    eps = cfg.rms_norm_eps
+    eps, mx = cfg.rms_norm_eps, cfg.mamba
     span = (src, dst, last, (jnp.arange(n) <= last)[:, None], ctx_len == 0)
     x = _embed(params, tokens, cfg)                      # [1, n, m]
     new_k, new_v = [], []
-    for li, p in zip(nh._pool_index(cfg.layer_types), params["layers"]):
-        h = _rmsnorm(x, p["ln_a"], eps)
+    for li, p in zip(pool_index(cfg.layer_types), params["layers"]):
+        h = rmsnorm(x, p["ln_a"], eps)
         if "wq" in p:
-            out, k, v = nh.attention_chunk(
-                h, p, cfg, li, k_pool, v_pool, block_table, ctx_len,
+            out, k, v = attention_chunk(
+                h, p, li, k_pool, v_pool, block_table, ctx_len,
                 cfg.attention_multiplier)
             new_k.append(k)
             new_v.append(v)
         else:
-            out, s_pool, c_pool = nh.mamba_chunk(h, p, cfg, li, span,
-                                                 s_pool, c_pool)
+            out, s_pool, c_pool = mamba_chunk(h, p, mx, li, span, s_pool,
+                                              c_pool)
         x = _add(x, out, cfg)
-        out, _ = _experts(_rmsnorm(x, p["ln_b"], eps)[0], p, cfg, "chunk")
+        out, _ = _experts(rmsnorm(x, p["ln_b"], eps)[0], p, cfg, "chunk")
         x = _add(x, out[None], cfg)
     k_pool, v_pool = scatter_span(
         (k_pool, v_pool), (jnp.stack(new_k)[:, 0], jnp.stack(new_v)[:, 0]),
@@ -417,8 +402,6 @@ def cost_shape(cfg: GraniteHybridConfig) -> dict:
     tied matrix once, as the head."""
     m, E, k, held = (cfg.hidden_size, cfg.num_local_experts,
                      cfg.num_experts_per_tok, cfg.experts_held)
-    H, P, G, N = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups,
-                  cfg.mamba_d_state)
     L = cfg.num_hidden_layers
     n_attn, n_mamba = (len(cfg.layers_of(t)) for t in (ATTENTION, MAMBA))
     always = cfg.num_params(experts=0)
@@ -442,28 +425,18 @@ def cost_shape(cfg: GraniteHybridConfig) -> dict:
         "param_bytes": cfg.dtype.itemsize,
         "kv_bytes_per_token": 2 * n_attn * cfg.num_key_value_heads
         * cfg.head_dim,
-        "state_ops_per_row": 4.0 * n_mamba * H * P * N,
-        "scan_ops_per_row": n_mamba * (
-            2.0 * cfg.mamba_chunk_size * (G * N + H * P) + 4.0 * H * P * N),
+        **cfg.mamba.cost(n_mamba),
         "state_bytes_per_seq": state_kind(cfg).slot_bytes,
         "m": m, "L": L,
     }
 
 
 def state_kind(cfg: GraniteHybridConfig):
-    """What a sequence keeps in the Mamba-2 layers: ``S`` in float32 and
-    the convolution's last rows in the served dtype."""
-    from . import StateKind
-
-    return StateKind(cfg.layers_of(MAMBA), (
-        ((cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state),
-         jnp.dtype(F32)),
-        ((cfg.mamba_d_conv - 1, cfg.conv_dim), cfg.dtype)))
+    """What a sequence keeps in the Mamba-2 layers."""
+    return StateKind(cfg.layers_of(MAMBA), cfg.mamba.state_parts)
 
 
 def serving(cfg: GraniteHybridConfig):
-    from . import Serving, keys_and_values
-
     full = keys_and_values("full", cfg.layers_of(ATTENTION),
                            cfg.num_key_value_heads, cfg.head_dim, None,
                            cfg.dtype)

@@ -34,7 +34,7 @@ padded to whole 128-lane tiles (576 -> 640): a minor dimension that is
 not whole tiles gets an at-rest layout from the TPU runtime that no
 reader or writer wants (PERF.md section 6, PR 31 and PR 34), and padded
 by hand the row costs what the runtime would have made it cost. Behind
-the serving seam (models/__init__.py) that is a kind of layer with one
+the serving seam (models/seam.py) that is a kind of layer with one
 pool.
 
 **Two paths over it.** A decode step attends in the ABSORBED form: a
@@ -63,7 +63,6 @@ the exchange.
 from __future__ import annotations
 
 import functools
-import math
 from dataclasses import dataclass
 from typing import Any, Callable, NamedTuple
 
@@ -71,7 +70,12 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import moe
-from .laguna import _rmsnorm, _rotary, _swiglu
+from ..ops.pallas.chunk_attention import chunk_attention, padded_keys
+from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
+                                      paged_attention_latent)
+from .layers import (COUNTERS, ROUTER_BIAS_STD, counters, head, held_experts,
+                     init_ends, normal, rmsnorm, rotary, swiglu, yarn_mscale)
+from .seam import LayerKind, Serving, scatter_span, unpack_span, unpack_step
 
 # Heads whose keys and values a chunk makes at a time: 18,432 keys of
 # 128 and as many values are 151 MB a group (all 64 heads: 0.60 GB).
@@ -154,14 +158,14 @@ class KimiK2Config:
     @property
     def softmax_scale(self) -> float:
         r = dict(self.rope_scaling)
-        head = self.qk_nope_head_dim + self.qk_rope_head_dim
-        return head ** -0.5 * yarn_mscale(r["factor"],
+        width = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return width ** -0.5 * yarn_mscale(r["factor"],
                                           r["mscale_all_dim"]) ** 2
 
     @property
     def rope(self) -> tuple:
         """The rotary dims' ``rope_parameters`` group as
-        models/laguna.py ``rope_inv_freq`` takes it."""
+        models/layers.py ``rope_inv_freq`` takes it."""
         r = dict(self.rope_scaling)
         return (("rope_type", "yarn"), ("rope_theta", self.rope_theta),
                 ("factor", r["factor"]),
@@ -194,46 +198,20 @@ class KimiK2Config:
         return n
 
 
-def yarn_mscale(factor: float, m: float) -> float:
-    """YaRN's attention scale: ``0.1 m ln(factor) + 1`` above factor 1."""
-    return 1.0 if factor <= 1 else 0.1 * m * math.log(factor) + 1.0
-
-
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
 
-# The router's correction bias is drawn from the seed at this size
-# (``assumed``: the published one is learned): sigmoid scores of a
-# token's 8th and 9th expert of 384 lie ~0.005 apart, so a bias of this
-# size changes the chosen set for most tokens, and "choose by s + b,
-# weigh by s" is exercised.
-ROUTER_BIAS_STD = 0.02
-
 
 def init(key, cfg: KimiK2Config) -> dict:
     """Seeded random parameters in ``cfg.dtype`` (normal, std 0.02;
-    norms 1), a layer at a time (``init_layer``). A routed expert's
-    weights depend on the key and the expert's GLOBAL id alone, so every
-    share of one model holds slices of the same experts."""
+    norms 1), a layer at a time (``init_layer``), a routed expert's by
+    its GLOBAL id (models/layers.py ``held_experts``)."""
     return {
-        **_init_ends(jax.random.fold_in(key, 1 << 20), cfg),
+        **init_ends(jax.random.fold_in(key, 1 << 20), cfg),
         "layers": [init_layer(key, cfg, l)
                    for l in range(cfg.num_hidden_layers)],
     }
-
-
-def _normal(key, shape, dtype, std=0.02):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _init_ends(key, cfg: KimiK2Config) -> dict:
-    m, V = cfg.hidden_size, cfg.vocab_size
-    ke, kh = jax.random.split(key)
-    return {"embed": _normal(ke, (V, m), cfg.dtype),
-            "head": _normal(kh, (m, V), cfg.dtype),
-            "norm_f": jnp.ones((m,), cfg.dtype)}
 
 
 def init_layer(key, cfg: KimiK2Config, l: int) -> dict:
@@ -251,30 +229,27 @@ def _init_layer(key, cfg: KimiK2Config, routed: bool) -> dict:
     p = {
         "ln1": jnp.ones((m,), dt), "ln2": jnp.ones((m,), dt),
         "q_norm": jnp.ones((rq,), dt), "kv_norm": jnp.ones((rkv,), dt),
-        "w_dq": _normal(next(k), (m, rq), dt),
-        "w_uq": _normal(next(k), (rq, H, nope + rope), dt),
-        "w_dkv": _normal(next(k), (m, rkv + rope), dt),
+        "w_dq": normal(next(k), (m, rq), dt),
+        "w_uq": normal(next(k), (rq, H, nope + rope), dt),
+        "w_dkv": normal(next(k), (m, rkv + rope), dt),
         # [k_nope | v] side by side: W_uk and W_uv of the absorbed form.
-        "w_ukv": _normal(next(k), (rkv, H, nope + dv), dt),
-        "w_o": _normal(next(k), (H, dv, m), dt),
+        "w_ukv": normal(next(k), (rkv, H, nope + dv), dt),
+        "w_o": normal(next(k), (H, dv, m), dt),
     }
     if not routed:
         f = cfg.intermediate_size
-        p["w_gu"] = _normal(next(k), (m, 2 * f), dt)
-        p["w_down"] = _normal(next(k), (f, m), dt)
+        p["w_gu"] = normal(next(k), (m, 2 * f), dt)
+        p["w_down"] = normal(next(k), (f, m), dt)
         return p
     E, f = cfg.n_routed_experts, cfg.moe_intermediate_size
     fs = cfg.n_shared_experts * f
-    p["router"] = _normal(next(k), (m, E), dt)
-    p["router_bias"] = _normal(next(k), (E,), jnp.float32, ROUTER_BIAS_STD)
-    k1, k2 = next(k), next(k)
-    held = cfg.first_expert + jnp.arange(cfg.experts_held)
-    p["w1"] = jax.vmap(lambda e: _normal(
-        jax.random.fold_in(k1, e), (m, 2 * f), dt))(held)
-    p["w2"] = jax.vmap(lambda e: _normal(
-        jax.random.fold_in(k2, e), (f, m), dt))(held)
-    p["s_gu"] = _normal(next(k), (m, 2 * fs), dt)
-    p["s_down"] = _normal(next(k), (fs, m), dt)
+    p["router"] = normal(next(k), (m, E), dt)
+    p["router_bias"] = normal(next(k), (E,), jnp.float32, ROUTER_BIAS_STD)
+    share = (cfg.first_expert, cfg.experts_held)
+    p["w1"] = held_experts(next(k), *share, (m, 2 * f), dt)
+    p["w2"] = held_experts(next(k), *share, (f, m), dt)
+    p["s_gu"] = normal(next(k), (m, 2 * fs), dt)
+    p["s_down"] = normal(next(k), (fs, m), dt)
     return p
 
 
@@ -283,7 +258,7 @@ def _init_layer(key, cfg: KimiK2Config, routed: bool) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _mlp(h2, p, cfg: KimiK2Config, program: str):
+def mlp(h2, p, cfg: KimiK2Config, program: str):
     """h2 [T, m] -> (out [T, m], the held experts' tokens [held] or
     None for a dense layer). The grouped product's kernel is
     ``moe_experts_<program>`` on a device trace, with ``_r<rows>``
@@ -291,7 +266,7 @@ def _mlp(h2, p, cfg: KimiK2Config, program: str):
     ``n_routed_experts``, which the call is told: ``w1`` holds a share)
     32 or more and the product takes a taller tile than 16."""
     if "router" not in p:
-        return _swiglu(h2, p["w_gu"], p["w_down"]), None
+        return swiglu(h2, p["w_gu"], p["w_down"]), None
     with jax.named_scope("moe_route"):
         _, experts, weights = moe.route_sigmoid(
             h2, p["router"], p["router_bias"], cfg.num_experts_per_tok,
@@ -300,7 +275,7 @@ def _mlp(h2, p, cfg: KimiK2Config, program: str):
         y, sizes = moe.routed_experts(
             h2, experts, weights, p["w1"], p["w2"], first=cfg.first_expert,
             n_experts=cfg.n_routed_experts, name=f"moe_experts_{program}")
-    return y + _swiglu(h2, p["s_gu"], p["s_down"]), sizes
+    return y + swiglu(h2, p["s_gu"], p["s_down"]), sizes
 
 
 def _project(h, p, positions, cfg: KimiK2Config):
@@ -309,20 +284,20 @@ def _project(h, p, positions, cfg: KimiK2Config):
     rope] rotated, the rows' latent rows [b, r, row_width] as the cache
     keeps them)."""
     nope, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    c_q = _rmsnorm(jnp.dot(h, p["w_dq"]), p["q_norm"], cfg.rms_norm_eps)
+    c_q = rmsnorm(jnp.dot(h, p["w_dq"]), p["q_norm"], cfg.rms_norm_eps)
     q = jnp.einsum("brc,chd->brhd", c_q, p["w_uq"])
-    q_rope = _rotary(q[..., nope:], positions, cfg.rope,
+    q_rope = rotary(q[..., nope:], positions, cfg.rope,
                      cfg.qk_rope_head_dim)
     ckv = jnp.dot(h, p["w_dkv"])
-    c_kv = _rmsnorm(ckv[..., :rkv], p["kv_norm"], cfg.rms_norm_eps)
-    k_rope = _rotary(ckv[..., None, rkv:], positions, cfg.rope,
+    c_kv = rmsnorm(ckv[..., :rkv], p["kv_norm"], cfg.rms_norm_eps)
+    k_rope = rotary(ckv[..., None, rkv:], positions, cfg.rope,
                      cfg.qk_rope_head_dim)[..., 0, :]
     pad = jnp.zeros(ckv.shape[:-1] + (cfg.row_width - cfg.latent_width,),
                     ckv.dtype)
     return q[..., :nope], q_rope, jnp.concatenate([c_kv, k_rope, pad], -1)
 
 
-def _block(x, p, cfg: KimiK2Config, attend, program: str):
+def block(x, p, cfg: KimiK2Config, attend, program: str):
     """The one layer, on [batch, rows, m]: norm -> latent attention ->
     residual -> norm -> MLP -> residual. ``attend(h, p)`` is the mode's
     attention sublayer on the normed rows: it projects them
@@ -330,18 +305,18 @@ def _block(x, p, cfg: KimiK2Config, attend, program: str):
     and returns o [b, r, H, v]. Returns (x, the held experts' tokens or
     None)."""
     b, r, m = x.shape
-    h = _rmsnorm(x, p["ln1"], cfg.rms_norm_eps)
+    h = rmsnorm(x, p["ln1"], cfg.rms_norm_eps)
     o = attend(h, p)
     x = x + jnp.einsum("brhd,hdm->brm", o, p["w_o"])
-    h2 = _rmsnorm(x, p["ln2"], cfg.rms_norm_eps)
-    out, sizes = _mlp(h2.reshape(b * r, m), p, cfg, program)
+    h2 = rmsnorm(x, p["ln2"], cfg.rms_norm_eps)
+    out, sizes = mlp(h2.reshape(b * r, m), p, cfg, program)
     return x + out.reshape(b, r, m), sizes
 
 
 class Residual(NamedTuple):
     """How the layers sit on the residual path, which the two programs
     below take as given: ``open`` makes what the layers carry of the
-    embedded rows [b, r, m], ``block`` is one layer on it (``_block``'s
+    embedded rows [b, r, m], ``block`` is one layer on it (``block``'s
     contract), ``close`` gives back rows [b, r, m] for the final norm.
     ``PLAIN`` is this family's ``x <- x + F(norm(x))``; a family that
     keeps the attention, the router and the experts and changes the
@@ -351,37 +326,7 @@ class Residual(NamedTuple):
     close: Callable
 
 
-PLAIN = Residual(lambda x: x, _block, lambda x: x)
-
-
-def _head(params, x, cfg: KimiK2Config):
-    x = _rmsnorm(x, params["norm_f"], cfg.rms_norm_eps)
-    return jnp.einsum("brm,mv->brv", x, params["head"])
-
-
-COUNTERS = ("moe_experts_hit", "moe_load_max_x1000", "moe_held_rows",
-            "kv_pages_in_runs_x1000")
-
-
-def _counters(sizes, rows: int, cfg: KimiK2Config, q: int, in_runs):
-    """The step's counter rows [4, q] int32 (``COUNTERS``): held
-    experts that got a token (a routed layer's mean), 1000 x the busiest
-    held expert's tokens over the DEPLOYMENT's mean an expert (rows x
-    experts a token / all routed experts; the worst layer), the
-    assignments that fell on the held experts (a layer's mean), and
-    ``in_runs``: 1000 x the share of the batch's live cache pages that
-    the paged kernel fetches in whole runs."""
-    moe = jnp.zeros((3,), jnp.int32)
-    if sizes:
-        s = jnp.stack(sizes)                              # [layers, held]
-        moe = jnp.stack([
-            (s > 0).sum() // len(sizes),
-            (s.max() * (1000 * cfg.n_routed_experts))
-            // (rows * cfg.num_experts_per_tok),
-            s.sum() // len(sizes)])
-    return jnp.broadcast_to(
-        jnp.append(moe, in_runs)[:, None],
-        (len(COUNTERS), q)).astype(jnp.int32)
+PLAIN = Residual(lambda x: x, block, lambda x: x)
 
 
 def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
@@ -395,10 +340,6 @@ def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
     Returns (logits [b, q, vocab], ids [b + 4, q] int32, pool): rows b
     on of ``ids`` are ``COUNTERS``. ``residual`` is the path the layers
     sit on (``Residual``)."""
-    from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
-                                          paged_attention_latent)
-    from . import unpack_step
-
     (tokens, positions, block_tables, context_lens, q_lens, slot_blocks,
      slot_offsets, _) = unpack_step(packed, q, firsts=firsts)
     B, Q = tokens.shape
@@ -425,12 +366,12 @@ def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
         x, s = residual.block(x, p, cfg, attend, "decode")
         if s is not None:
             sizes.append(s)
-    logits = _head(params, residual.close(x), cfg)
+    logits = head(params, residual.close(x), cfg.rms_norm_eps)
     ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    ids = jnp.concatenate([ids, _counters(
-        sizes, B * Q, cfg, Q, kv_pages_in_runs_x1000(
-            block_tables, context_lens, pool,
-            score_rows=Q * cfg.num_attention_heads))])
+    ids = jnp.concatenate([ids, counters(
+        sizes, B * Q, cfg.n_routed_experts, cfg.num_experts_per_tok, Q,
+        kv_pages_in_runs_x1000(block_tables, context_lens, pool,
+                               score_rows=Q * cfg.num_attention_heads))])
     return logits, ids, pool
 
 
@@ -447,8 +388,6 @@ def _chunk_attention(q_nope, q_rope, rows, ctx, ctx_len, w_ukv,
     [n, W]: the span's own latent rows (query i at position
     ctx_len + i); ctx [S, W]: the sequence's gathered pool slots, slot
     s at position s, real below ctx_len. Returns [n, H, v]."""
-    from ..ops.pallas.chunk_attention import chunk_attention, padded_keys
-
     n, H, nope = q_nope.shape
     rkv, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     S, dt = ctx.shape[0], q_nope.dtype
@@ -482,9 +421,6 @@ def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config,
     one row that comes back, closed alone.
 
     Returns (row [vocab], id, pool)."""
-    from ..llm.kv_cache import scatter_span
-    from . import unpack_span
-
     n = tokens.shape[1]
     bs = pool.shape[2]
     block_table, dest, ctx_len, last = unpack_span(table, n, bs)
@@ -506,8 +442,9 @@ def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config,
 
         x, _ = residual.block(x, p, cfg, attend, "chunk")
     pool, = scatter_span((pool,), (jnp.stack(new)[:, 0],), dest, last + 1)
-    row = _head(params, residual.close(
-        jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)), cfg)[0, 0]
+    row = head(params, residual.close(
+        jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)),
+        cfg.rms_norm_eps)[0, 0]
     return row, jnp.argmax(row).astype(jnp.int32), pool
 
 
@@ -566,8 +503,6 @@ def cost_shape(cfg: KimiK2Config) -> dict:
 
 
 def serving(cfg: KimiK2Config):
-    from . import LayerKind, Serving
-
     latent = LayerKind("full", tuple(range(cfg.num_hidden_layers)),
                        (cfg.row_width,), None, cfg.dtype)
     return Serving(init=init, step=forward_step,

@@ -33,7 +33,7 @@ from .kimi_k2 import KimiK2Config
 F32 = jnp.float32
 
 
-def _f32(p):
+def f32(p):
     return jax.tree_util.tree_map(lambda a: a.astype(F32), p)
 
 
@@ -169,7 +169,7 @@ def mlp_sublayer(x, p, cfg: KimiK2Config, l: int):
 def layer(x, p, cfg: KimiK2Config, l: int, positions):
     """Layer ``l`` on the whole sequence x [T, m]; ``p`` that layer's
     parameters in any dtype."""
-    p = _f32(p)
+    p = f32(p)
     x = x + attention_sublayer(x, p, cfg, positions)
     return x + mlp_sublayer(x, p, cfg, l)
 

@@ -29,7 +29,7 @@ Keys are stored AFTER rotary, so a cached key needs no position again
 and a window layer's table needs no order. ``models/laguna_ref.py`` is
 the plain float32 reference of the same equations.
 
-Behind the serving seam (models/__init__.py) the cache has two kinds:
+Behind the serving seam (models/seam.py) the cache has two kinds:
 "full" layers keep every token, "window" layers the blocks that cover
 a sequence's last ``sliding_window`` tokens. The layers are unrolled,
 the kinds interleaved as published; layer i of a kind lives at index i
@@ -43,7 +43,7 @@ table's slots and attends over [them ++ the chunk] in the
 ``chunk_attn`` kernel (ops/pallas/chunk_attention.py), both kinds.
 
 The window kind's part of a decode step's bookkeeping, in a lane's row
-of the step's one packed array (models/__init__.py ``step_columns``):
+of the step's one packed array (models/seam.py ``step_columns``):
 its table (``window_table_len`` entries: the blocks from the window's
 oldest on), that first block's index in the sequence, and a slot block
 a row, where each row's K/V is written. A chunk takes an int32 array of
@@ -56,13 +56,18 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..ops import moe
+from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
+                                      paged_attention_stored)
+from .layers import (head, init_ends, normal, pool_index, rmsnorm, rotary,
+                     span_attention, swiglu)
+from .seam import (Serving, keys_and_values, scatter_span, unpack_span,
+                   unpack_step, window_table_len)
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -139,58 +144,6 @@ class LagunaConfig:
 
 
 # ---------------------------------------------------------------------------
-# Rotary positions
-# ---------------------------------------------------------------------------
-
-
-def rope_inv_freq(rope, head_dim: int):
-    """(inverse frequencies [rot/2] float64, rotated dims, cos/sin
-    scale) of one ``rope_parameters`` group. ``yarn`` blends, per
-    frequency, the interpolated (1 / (factor * base^(2i/rot))) and the
-    extrapolated (1 / base^(2i/rot)) frequency by a linear ramp between
-    the dims whose wavelength fits ``beta_fast`` and ``beta_slow``
-    turns into the original context."""
-    r = dict(rope)
-    rot = int(head_dim * r.get("partial_rotary_factor", 1.0))
-    base = float(r["rope_theta"])
-    pos = base ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
-    if r.get("rope_type", "default") == "default":
-        return 1.0 / pos, rot, 1.0
-    if r["rope_type"] != "yarn":
-        raise ValueError(f"rope_type {r['rope_type']!r}")
-    orig = r["original_max_position_embeddings"]
-
-    def correction_dim(turns):
-        return rot * math.log(orig / (turns * 2 * math.pi)) \
-            / (2 * math.log(base))
-
-    low = max(math.floor(correction_dim(r["beta_fast"])), 0)
-    high = min(math.ceil(correction_dim(r["beta_slow"])), rot - 1)
-    if low == high:
-        high += 0.001
-    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
-                   / (high - low), 0.0, 1.0)
-    inv = (1.0 / (r["factor"] * pos)) * ramp + (1.0 / pos) * (1.0 - ramp)
-    return inv, rot, float(r["attention_factor"])
-
-
-def _rotary(x, positions, rope, head_dim: int):
-    """Rotate the leading ``rot`` dims of x [..., heads, d] at
-    ``positions`` (x's leading dims); float32 angles, x's dtype out."""
-    inv, rot, scale = rope_inv_freq(rope, head_dim)
-    ang = positions[..., None].astype(jnp.float32) \
-        * jnp.asarray(inv, jnp.float32)
-    cos = (jnp.cos(ang) * scale)[..., None, :]
-    sin = (jnp.sin(ang) * scale)[..., None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2, rest = (x32[..., :rot // 2], x32[..., rot // 2:rot],
-                    x32[..., rot:])
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
-        axis=-1).astype(x.dtype)
-
-
-# ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
 
@@ -202,23 +155,10 @@ def init(key, cfg: LagunaConfig) -> dict:
     shape is one compiled program (a layer's tensors made leaf by leaf
     took 42 s for this model's 3.9 B parameters on the chip)."""
     return {
-        **_init_ends(jax.random.fold_in(key, 1 << 20), cfg),
+        **init_ends(jax.random.fold_in(key, 1 << 20), cfg),
         "layers": [init_layer(key, cfg, l)
                    for l in range(cfg.num_hidden_layers)],
     }
-
-
-def _normal(key, shape, std, dtype):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _init_ends(key, cfg: LagunaConfig) -> dict:
-    m, V = cfg.hidden_size, cfg.vocab_size
-    ke, kh = jax.random.split(key)
-    return {"embed": _normal(ke, (V, m), 0.02, cfg.dtype),
-            "head": _normal(kh, (m, V), 0.02, cfg.dtype),
-            "norm_f": jnp.ones((m,), cfg.dtype)}
 
 
 def init_layer(key, cfg: LagunaConfig, l: int) -> dict:
@@ -231,29 +171,29 @@ def init_layer(key, cfg: LagunaConfig, l: int) -> dict:
 @functools.partial(jax.jit, static_argnames=("cfg", "h", "mlp"))
 def _init_layer(key, cfg: LagunaConfig, h: int, mlp: str) -> dict:
     m, d, kv = cfg.hidden_size, cfg.head_dim, cfg.num_key_value_heads
-    dt, std = cfg.dtype, 0.02
-    out_std = std / math.sqrt(2 * cfg.num_hidden_layers)
+    dt = cfg.dtype
+    out_std = 0.02 / math.sqrt(2 * cfg.num_hidden_layers)
     k = iter(jax.random.split(key, 12))
     p = {
         "ln1": jnp.ones((m,), dt), "ln2": jnp.ones((m,), dt),
-        "wq": _normal(next(k), (m, h, d), std, dt),
-        "wk": _normal(next(k), (m, kv, d), std, dt),
-        "wv": _normal(next(k), (m, kv, d), std, dt),
-        "wg": _normal(next(k), (m, h), std, dt),
-        "wo": _normal(next(k), (h, d, m), out_std, dt),
+        "wq": normal(next(k), (m, h, d), dt),
+        "wk": normal(next(k), (m, kv, d), dt),
+        "wv": normal(next(k), (m, kv, d), dt),
+        "wg": normal(next(k), (m, h), dt),
+        "wo": normal(next(k), (h, d, m), dt, out_std),
     }
     if mlp == DENSE:
         f = cfg.intermediate_size
-        p["w_gu"] = _normal(next(k), (m, 2 * f), std, dt)
-        p["w_down"] = _normal(next(k), (f, m), out_std, dt)
+        p["w_gu"] = normal(next(k), (m, 2 * f), dt)
+        p["w_down"] = normal(next(k), (f, m), dt, out_std)
     else:
         E, f = cfg.num_experts, cfg.moe_intermediate_size
         fs = cfg.shared_expert_intermediate_size
-        p["router"] = _normal(next(k), (m, E), std, dt)
-        p["w1"] = _normal(next(k), (E, m, 2 * f), std, dt)
-        p["w2"] = _normal(next(k), (E, f, m), out_std, dt)
-        p["s_gu"] = _normal(next(k), (m, 2 * fs), std, dt)
-        p["s_down"] = _normal(next(k), (fs, m), out_std, dt)
+        p["router"] = normal(next(k), (m, E), dt)
+        p["w1"] = normal(next(k), (E, m, 2 * f), dt)
+        p["w2"] = normal(next(k), (E, f, m), dt, out_std)
+        p["s_gu"] = normal(next(k), (m, 2 * fs), dt)
+        p["s_down"] = normal(next(k), (fs, m), dt, out_std)
     return p
 
 
@@ -262,27 +202,13 @@ def _init_layer(key, cfg: LagunaConfig, h: int, mlp: str) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    out = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
-    return (out * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def _swiglu(h, w_gu, w_down):
-    gu = jnp.dot(h, w_gu)
-    f = gu.shape[-1] // 2
-    act = (jax.nn.silu(gu[..., :f].astype(jnp.float32))
-           * gu[..., f:].astype(jnp.float32)).astype(h.dtype)
-    return jnp.dot(act, w_down)
-
-
 def _mlp(h2, p, cfg: LagunaConfig, program: str):
     """h2 [T, m] -> (out [T, m], (experts hit, busiest expert's tokens)
     or None for a dense layer). The grouped product's kernel is
     ``moe_experts_<program>`` on a device trace, so a reader can tell a
     decode step's from a chunk's by name."""
     if "router" not in p:
-        return _swiglu(h2, p["w_gu"], p["w_down"]), None
+        return swiglu(h2, p["w_gu"], p["w_down"]), None
     with jax.named_scope("moe_route"):
         _, experts, weights = moe.route(
             h2, p["router"], cfg.num_experts_per_tok,
@@ -290,7 +216,7 @@ def _mlp(h2, p, cfg: LagunaConfig, program: str):
     with jax.named_scope("moe_experts"):
         y, sizes = moe.routed_experts(h2, experts, weights, p["w1"], p["w2"],
                                       name=f"moe_experts_{program}")
-    out = y + _swiglu(h2, p["s_gu"], p["s_down"])
+    out = y + swiglu(h2, p["s_gu"], p["s_down"])
     return out, ((sizes > 0).sum().astype(jnp.int32), sizes.max())
 
 
@@ -301,7 +227,7 @@ def _block(x, p, cfg: LagunaConfig, attend, program: str):
     [b, r, kv, d], keeps k and v where the mode keeps them, and returns
     o [b, r, H, d]. Returns (x, routing counts or None)."""
     b, r, m = x.shape
-    h = _rmsnorm(x, p["ln1"], cfg.rms_norm_eps)
+    h = rmsnorm(x, p["ln1"], cfg.rms_norm_eps)
     q = jnp.einsum("brm,mhd->brhd", h, p["wq"])
     k = jnp.einsum("brm,mhd->brhd", h, p["wk"])
     v = jnp.einsum("brm,mhd->brhd", h, p["wv"])
@@ -310,24 +236,15 @@ def _block(x, p, cfg: LagunaConfig, attend, program: str):
     o = attend(q, k, v)
     o = (o.astype(jnp.float32) * gate[..., None]).astype(x.dtype)
     x = x + jnp.einsum("brhd,hdm->brm", o, p["wo"])
-    h2 = _rmsnorm(x, p["ln2"], cfg.rms_norm_eps)
+    h2 = rmsnorm(x, p["ln2"], cfg.rms_norm_eps)
     out, counts = _mlp(h2.reshape(b * r, m), p, cfg, program)
     return x + out.reshape(b, r, m), counts
 
 
 def _kind_index(cfg: LagunaConfig):
     """layer -> (is_window, index in its kind's pool)."""
-    seen = {FULL: 0, SLIDING: 0}
-    out = []
-    for t in cfg.layer_types:
-        out.append((t == SLIDING, seen[t]))
-        seen[t] += 1
-    return out
-
-
-def _head(params, x, cfg: LagunaConfig):
-    x = _rmsnorm(x, params["norm_f"], cfg.rms_norm_eps)
-    return jnp.einsum("brm,mv->brv", x, params["head"])
+    return [(t == SLIDING, i) for t, i in
+            zip(cfg.layer_types, pool_index(cfg.layer_types))]
 
 
 def _counters(counts, n_assignments: int, n_experts: int, q: int, in_runs):
@@ -359,11 +276,6 @@ def forward_step(params, packed, k_pool, v_pool, k_win, v_win, *, q: int,
 
     Returns (logits [b, q, vocab], ids [b + 3, q] int32, k_pool, v_pool,
     k_win, v_win): rows b on of ``ids`` are ``COUNTERS``."""
-    from ..llm.kv_cache import window_table_len
-    from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
-                                          paged_attention_stored)
-    from . import unpack_step
-
     B, Q = packed.shape[0], q
     kv, d = cfg.num_key_value_heads, cfg.head_dim
     bs = k_pool.shape[2]
@@ -382,8 +294,8 @@ def forward_step(params, packed, k_pool, v_pool, k_win, v_win, *, q: int,
 
         def attend(q, k, v, window=window, li=li, rope=rope):
             nonlocal k_pool, v_pool, k_win, v_win
-            q = _rotary(q, positions, rope, d)
-            k = _rotary(k, positions, rope, d).reshape(B, Q, kv * d)
+            q = rotary(q, positions, rope, d)
+            k = rotary(k, positions, rope, d).reshape(B, Q, kv * d)
             v = v.reshape(B, Q, kv * d)
             H = q.shape[2]
             qg = q.reshape(B, Q, kv, H // kv, d)
@@ -406,7 +318,7 @@ def forward_step(params, packed, k_pool, v_pool, k_win, v_win, *, q: int,
         x, c = _block(x, p, cfg, attend, "decode")
         if c is not None:
             counts.append(c)
-    logits = _head(params, x, cfg)
+    logits = head(params, x, cfg.rms_norm_eps)
     ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     # The full kind's layer with the most query heads has the most score
     # rows, so the fewest pages a step: the run size no full layer
@@ -418,39 +330,6 @@ def forward_step(params, packed, k_pool, v_pool, k_win, v_win, *, q: int,
         kv_pages_in_runs_x1000(block_tables, context_lens, k_pool, v_pool,
                                score_rows=Q * full_heads))])
     return logits, ids, k_pool, v_pool, k_win, v_win
-
-
-def _chunk_attention(q, k_tok, v_tok, k_ctx, v_ctx, ctx_len, base,
-                     window: Optional[int], scale: Optional[float] = None):
-    """A chunk's attention over [pool context ++ chunk] as the
-    ``chunk_attn`` kernel (ops/pallas/chunk_attention.py): scores stay
-    in VMEM under one online softmax, a KV head's group of query heads
-    in one tile, context blocks past ``ctx_len`` neither read nor
-    multiplied.
-
-    q [c, H, d]; k_tok, v_tok [c, kv, d]: the chunk, whose query i sits
-    at absolute position ctx_len + i. k_ctx, v_ctx [S, kv, d]: the
-    sequence's gathered pool slots, slot s at absolute position
-    base + s, real where that is below ctx_len. With a ``window`` a
-    query sees only keys less than ``window`` positions behind it.
-    ``scale`` is the softmax scale where it is not ``d ** -0.5``
-    (models/granite_hybrid.py)."""
-    from ..ops.pallas.chunk_attention import chunk_attention, padded_keys
-
-    c, H, d = q.shape
-    S, kv = k_ctx.shape[:2]
-    pad = jnp.zeros((padded_keys(S + c) - S - c, kv, d), q.dtype)
-
-    def head_major(ctx, tok):                   # -> [kv, keys, d]
-        return jnp.concatenate([ctx.astype(q.dtype), tok, pad],
-                               axis=0).transpose(1, 0, 2)
-
-    o = chunk_attention(
-        q.reshape(c, kv, H // kv, d).transpose(1, 2, 0, 3),
-        head_major(k_ctx, k_tok), head_major(v_ctx, v_tok), ctx_len,
-        ctx_slots=S, scale=d ** -0.5 if scale is None else scale, base=base,
-        window=window)
-    return o.transpose(2, 0, 1, 3).reshape(c, H, d)     # [kv, g, c, d] ->
 
 
 def _chunk_layers(params, tokens, positions, k_pool, v_pool, block_table,
@@ -473,8 +352,8 @@ def _chunk_layers(params, tokens, positions, k_pool, v_pool, block_table,
         rope = cfg.rope_sliding if window else cfg.rope_full
 
         def attend(q, k, v, window=window, li=li, rope=rope):
-            q = _rotary(q, pos, rope, d)
-            k = _rotary(k, pos, rope, d)
+            q = rotary(q, pos, rope, d)
+            k = rotary(k, pos, rope, d)
             pool_k, pool_v, table, base = (
                 (k_win, v_win, win_table, win_base) if window
                 else (k_pool, v_pool, block_table, 0))
@@ -482,7 +361,7 @@ def _chunk_layers(params, tokens, positions, k_pool, v_pool, block_table,
             k_ctx = pool_k[li, table].reshape(slots, kv, d)
             v_ctx = pool_v[li, table].reshape(slots, kv, d)
             with jax.named_scope("attn_window" if window else "attn_full"):
-                o = _chunk_attention(
+                o = span_attention(
                     q[0], k[0], v[0], k_ctx, v_ctx, ctx_len, base,
                     cfg.sliding_window if window else None)
             new[window][0].append(k)
@@ -515,9 +394,6 @@ def forward_prefill_chunk(params, tokens, k_pool, v_pool, table,
     in and the span is written after the last layer.
 
     Returns (row [vocab], id, k_pool, v_pool, k_win, v_win)."""
-    from ..llm.kv_cache import scatter_span
-    from . import unpack_span
-
     n = tokens.shape[1]
     bs = k_pool.shape[2]
     block_table, dest, ctx_len, last = unpack_span(table, n, bs)
@@ -531,8 +407,8 @@ def forward_prefill_chunk(params, tokens, k_pool, v_pool, table,
                                   dest, last + 1)
     k_win, v_win = scatter_span((k_win, v_win), (kw[:, 0], vw[:, 0]),
                                 win[nbw + 1:], last + 1)
-    row = _head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
-                cfg)[0, 0]
+    row = head(params, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1),
+               cfg.rms_norm_eps)[0, 0]
     return (row, jnp.argmax(row).astype(jnp.int32), k_pool, v_pool,
             k_win, v_win)
 
@@ -594,8 +470,6 @@ def cost_shape(cfg: LagunaConfig) -> dict:
 
 
 def serving(cfg: LagunaConfig):
-    from . import Serving, keys_and_values
-
     kv, d = cfg.num_key_value_heads, cfg.head_dim
     kinds = (keys_and_values("full", cfg.layers_of(FULL), kv, d, None,
                              cfg.dtype),

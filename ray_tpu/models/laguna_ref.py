@@ -22,7 +22,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .laguna import DENSE, SLIDING, LagunaConfig, rope_inv_freq
+from .laguna import DENSE, SLIDING, LagunaConfig
+from .layers import rope_inv_freq
 
 F32 = jnp.float32
 

@@ -42,7 +42,7 @@ another ``Residual`` handed in: the streams are carried as ONE row
 two calls of ``mhc_pre`` and two of ``mhc_post`` (the Pallas kernels of
 ops/mhc.py; ``mhc_pre_decode``, ``mhc_post_chunk`` and so on on a device
 trace) around Kimi's
-``_project`` + attention path and Kimi's ``_mlp``. The cache is Kimi's
+``_project`` + attention path and Kimi's ``mlp``. The cache is Kimi's
 kind: one pool of latent rows of ``row_width``. The decode step's
 counters are Kimi's four and ``mhc_res_err_x1e6``: 1e6 x the largest
 distance from 1 of any row or column sum of any ``H_res`` of the step.
@@ -66,7 +66,7 @@ import jax.numpy as jnp
 from ..ops import mhc
 from . import kimi_k2
 from .kimi_k2 import KimiK2Config
-from .laguna import _rmsnorm
+from .layers import init_ends, normal, rmsnorm
 
 
 @dataclass(frozen=True)
@@ -134,7 +134,7 @@ def init(key, cfg: Xing4Config) -> dict:
     """Seeded random parameters: Kimi's (models/kimi_k2.py ``init``),
     and a layer's two sublayers' ``hc_attn`` / ``hc_mlp``."""
     return {
-        **kimi_k2._init_ends(jax.random.fold_in(key, 1 << 20), cfg),
+        **init_ends(jax.random.fold_in(key, 1 << 20), cfg),
         "layers": [init_layer(key, cfg, l)
                    for l in range(cfg.num_hidden_layers)],
     }
@@ -151,13 +151,11 @@ def _init_mhc(key, cfg: Xing4Config) -> dict:
     def one(key):
         kp, ka, kb = jax.random.split(key, 3)
         return {
-            "phi": kimi_k2._normal(
+            "phi": normal(
                 kp, (cfg.hc_mult * cfg.hidden_size, cfg.hc_outputs),
                 cfg.dtype),
-            "a": MHC_A_MEAN + kimi_k2._normal(ka, (3,), jnp.float32,
-                                              MHC_A_STD),
-            "b": kimi_k2._normal(kb, (cfg.hc_outputs,), jnp.float32,
-                                 MHC_B_STD)}
+            "a": MHC_A_MEAN + normal(ka, (3,), jnp.float32, MHC_A_STD),
+            "b": normal(kb, (cfg.hc_outputs,), jnp.float32, MHC_B_STD)}
 
     ka, km = jax.random.split(key)
     return {"hc_attn": one(ka), "hc_mlp": one(km)}
@@ -202,13 +200,13 @@ def _residual(cfg: Xing4Config, slabs=None) -> kimi_k2.Residual:
 
     def block(X, p, cfg, attend, program):
         def attention(h):
-            o = attend(_rmsnorm(h, p["ln1"], eps), p)
+            o = attend(rmsnorm(h, p["ln1"], eps), p)
             return jnp.einsum("brhd,hdm->brm", o, p["w_o"]), None
 
         def mlp(h):
             b, r, m = h.shape
-            out, sizes = kimi_k2._mlp(
-                _rmsnorm(h, p["ln2"], eps).reshape(b * r, m), p, cfg,
+            out, sizes = kimi_k2.mlp(
+                rmsnorm(h, p["ln2"], eps).reshape(b * r, m), p, cfg,
                 program)
             return out.reshape(b, r, m), sizes
 

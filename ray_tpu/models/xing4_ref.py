@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from . import kimi_k2_ref
-from .kimi_k2_ref import F32, _f32
+from .kimi_k2_ref import F32, f32
 from .xing4 import Xing4Config
 
 
@@ -68,7 +68,7 @@ def around(X, hc, F, cfg: Xing4Config):
 def layer(X, p, cfg: Xing4Config, l: int, positions):
     """Layer ``l`` on the whole sequence's streams X [T, n, C]; ``p``
     that layer's parameters in any dtype."""
-    p = _f32(p)
+    p = f32(p)
     X = around(X, p["hc_attn"], lambda h: kimi_k2_ref.attention_sublayer(
         h, p, cfg, positions), cfg)
     return around(X, p["hc_mlp"], lambda h: kimi_k2_ref.mlp_sublayer(

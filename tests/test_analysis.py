@@ -502,6 +502,52 @@ def test_i410_real_table_names_live_sites():
     assert not rep.findings, [f.message for f in rep.findings]
 
 
+def test_i411_catches_an_import_against_the_layers(tmp_path):
+    """``ops <- models <- llm <- serve``, by the real tables: an import
+    of a higher package from a lower one is a finding at ANY depth of
+    nesting, and so is a sibling's underscore name inside ``models/``,
+    imported or reached through the module. What points down, a public
+    name, a module's own private and a dunder are clean."""
+    rep = lint(tmp_path, {
+        "ray_tpu/ops/kernel.py": """\
+            from ..models import serving            # finding: ops -> models
+            def f():
+                import ray_tpu.serve.llm            # finding: ops -> serve
+            """,
+        "ray_tpu/models/fam.py": """\
+            from ..ops import moe                   # down: clean
+            from .shared import rmsnorm, __all__    # public, dunder: clean
+            from .other import _rmsnorm, head       # finding: _rmsnorm
+            from . import other as ot, shared
+            from .. import llm                      # finding: models -> llm
+            def chunk():
+                def inner():
+                    from ..llm.kv_cache import scatter_span   # finding
+                return ot._counters(1) + ot.counters(1)       # finding: 1
+            def _mine(): return _mine
+            """,
+        "ray_tpu/models/other.py": "def _counters(x): return x\n",
+        "ray_tpu/llm/engine.py": """\
+            from ..models import serving            # down: clean
+            def g():
+                from ..serve import deployment      # finding: llm -> serve
+            """,
+        "ray_tpu/serve/llm.py": "from ..llm import engine\n",
+    }, select="I411")
+    got = sorted((f.path, f.line) for f in rep.findings)
+    assert got == [("ray_tpu/llm/engine.py", 3),
+                   ("ray_tpu/models/fam.py", 3),
+                   ("ray_tpu/models/fam.py", 5),
+                   ("ray_tpu/models/fam.py", 8),
+                   ("ray_tpu/models/fam.py", 9),
+                   ("ray_tpu/ops/kernel.py", 1),
+                   ("ray_tpu/ops/kernel.py", 3)], got
+    private = [f.message for f in rep.findings if "private" in f.message]
+    assert len(private) == 2 and "_rmsnorm" in private[0] \
+        and "ot._counters" in private[1]
+    assert all(f.severity == "P0" for f in rep.findings)
+
+
 def test_i403_catches_a_gaugeless_queue_mutation(tmp_path):
     tables = (("svc.py", "_gauge_queues", ("enq", "deq"), "why"),)
     rep = lint(tmp_path, {"svc.py": """\

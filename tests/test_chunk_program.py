@@ -15,6 +15,7 @@ them in another order, 1e-7 apart in float32 and a last place of
 bfloat16); the id against the argmax of the row it came with, exactly.
 """
 
+import functools
 import importlib
 
 import jax
@@ -24,7 +25,7 @@ import pytest
 
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.llm.kv_cache import PagedKVCache
-from ray_tpu.models import gpt, laguna, unpack_span
+from ray_tpu.models import gpt, laguna, layers, unpack_span
 
 BS = 8
 GPT_F32 = gpt.GPTConfig(vocab_size=128, max_seq=64, d_model=64, n_layer=2,
@@ -70,11 +71,14 @@ def _layers_then_head(cfg):
     """The chunk as it was, from the model's own parts: one program,
     every row through the head, the pools read only."""
     mod = importlib.import_module(type(cfg).__module__)
+    # GPT-2's tied head is its own; Laguna's is models/layers.py's.
+    head = functools.partial(gpt._head, cfg=cfg) if mod is gpt else \
+        functools.partial(layers.head, eps=cfg.rms_norm_eps)
 
     @jax.jit
     def run(params, *args):
         x, *kv = mod._chunk_layers(params, *args, cfg=cfg)
-        return (mod._head(params, x, cfg), *kv)
+        return (head(params, x), *kv)
 
     return run
 

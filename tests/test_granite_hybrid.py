@@ -16,7 +16,8 @@ import pytest
 
 from ray_tpu.llm.engine import FINISHED, LLMEngine
 from ray_tpu.models import (granite_hybrid as gh, granite_hybrid_ref as ref,
-                            nemotron_h as nh, pack_span, pack_step, serving)
+                            layers, mamba2, nemotron_h as nh, pack_span,
+                            pack_step, serving)
 from ray_tpu.ops import moe, ssm
 from ray_tpu.util import perfmodel
 
@@ -46,8 +47,8 @@ def test_parameter_count_reproduces_the_models_name():
     cfg = gh.GraniteHybridConfig()
     assert cfg.layers_of("attention") == (5, 15, 25, 35)
     assert len(cfg.layers_of("mamba")) == 36
-    assert cfg.head_dim == 128 and cfg.d_inner == 8192 \
-        and cfg.conv_dim == 8448
+    assert cfg.head_dim == 128 and cfg.mamba.d_inner == 8192 \
+        and cfg.mamba.conv_dim == 8448
     assert round(cfg.mixer_params("mamba") / 1e4) == 10229      # 102.29 M
     assert round(cfg.mixer_params("attention") / 1e4) == 4194   # 41.94 M
     assert cfg.expert_params == 9437184
@@ -157,7 +158,7 @@ def test_prefill_in_unequal_spans_then_decode_equals_the_reference(tiny,
     assert 1e-3 < np.abs(want).max() < 1.0
     pools = [jnp.zeros((1, 32, BS, 32)), jnp.zeros((1, 32, BS, 32)),
              jnp.full((2, 4, 128, 2, 16), 7.0),     # the last tenant's
-             jnp.full((2, 4, 3, cfg.conv_dim), 7.0)]
+             jnp.full((2, 4, 3, cfg.mamba.conv_dim), 7.0)]
     table, upto, slot = list(range(1, 9)), 0, 2
     for i, c in enumerate(spans):
         dst = 3 if i == 1 else slot         # the second span moves slots
@@ -194,41 +195,63 @@ def test_each_multiplier_is_read_by_both_sides(tiny, field, value, moves):
     base = np.asarray(ref.forward(params, seq, cfg))
     assert (np.abs(want - base).max() > 2e-7) == moves
     pools = [jnp.zeros((1, 8, BS, 32)), jnp.zeros((1, 8, BS, 32)),
-             jnp.zeros((2, 2, 128, 2, 16)), jnp.zeros((2, 2, 3, cfg.conv_dim))]
+             jnp.zeros((2, 2, 128, 2, 16)),
+             jnp.zeros((2, 2, 3, cfg.mamba.conv_dim))]
     row, _ = _chunk(other, params, seq, [1, 2, 3], 0, 24, 1, 1, pools)
     assert np.abs(row - want[23]).max() < 1e-5 * np.abs(want).max()
 
 
 def test_the_mamba_mixer_is_nemotrons_called_not_copied():
-    """The module writes no mixer and no kernel of its own: it calls
-    models/nemotron_h.py's four mixer functions, which read its
-    configuration by the names they read their own by."""
+    """The module writes no mixer, kernel call or counters of its own:
+    the mixer functions it calls are models/mamba2.py's and
+    models/layers.py's own objects, the ones Nemotron-H calls, and the
+    two families describe their mixers to them by one record
+    (``Mamba2``), each from its own published field names."""
     import inspect
 
+    for name in ("mamba_step", "mamba_chunk", "mamba_params"):
+        assert getattr(gh, name) is getattr(nh, name) \
+            is getattr(mamba2, name), name
+    for name in ("attention_step", "attention_chunk", "pool_index",
+                 "counters", "COUNTERS", "normal", "rmsnorm"):
+        assert getattr(gh, name) is getattr(nh, name) \
+            is getattr(layers, name), name
     src = inspect.getsource(gh)
-    for called in ("nh.mamba_step(", "nh.mamba_chunk(", "nh.attention_step(",
-                   "nh.attention_chunk(", "nh.mamba_params(",
-                   "nh._pool_index(", "nh._counters(", "moe.route(",
-                   "moe.routed_experts("):
+    for called in ("mamba_step(", "mamba_chunk(", "attention_step(",
+                   "attention_chunk(", "mamba_params(", "pool_index(",
+                   "counters(", "moe.route(", "moe.routed_experts("):
         assert called in src, called
     for written in ("pallas_call", "softplus", "ssd_scan", "ssm_update(",
-                    "paged_attention_stored", "_chunk_attention"):
+                    "paged_attention_stored", "span_attention",
+                    "def mamba_", "def attention", "def counters",
+                    "def pool_index", "import nemotron_h", "nh."):
         assert written not in src, written
-    cfg = gh.GraniteHybridConfig()
-    theirs = nh.NemotronHConfig()
-    for name in ("mamba_num_heads", "mamba_head_dim", "ssm_state_size",
-                 "conv_kernel", "d_inner", "layer_norm_epsilon"):
-        assert getattr(cfg, name) == getattr(theirs, name), name
-    assert (cfg.n_groups, theirs.n_groups) == (1, 8)
-    assert (cfg.chunk_size, theirs.chunk_size) == (256, 128)
-    assert ssm._head_block(cfg.mamba_n_heads, cfg.mamba_n_groups) == 64
+    ours = gh.GraniteHybridConfig().mamba
+    theirs = nh.NemotronHConfig().mamba
+    assert isinstance(ours, mamba2.Mamba2) and type(theirs) is type(ours)
+    same = dict(hidden_size=4096, heads=128, head_dim=64, state=128,
+                conv_kernel=4, eps=1e-5, dtype=jnp.dtype("bfloat16"),
+                time_step=(0.001, 0.1, 1e-4))
+    for name, value in same.items():
+        assert getattr(ours, name) == getattr(theirs, name) == value, name
+    assert (ours.d_inner, theirs.d_inner) == (8192, 8192)
+    assert (ours.groups, theirs.groups) == (1, 8)
+    assert (ours.chunk_size, theirs.chunk_size) == (256, 128)
+    assert (ours.conv_dim, theirs.conv_dim) == (8448, 10240)
+    assert ssm._head_block(ours.heads, ours.groups) == 64
+    # No name on the configuration that only a mixer function read.
+    # (``chunk_size`` stays: benchmark/reference_nemotron_h.py reads it.)
+    for alias in ("mamba_num_heads", "mamba_head_dim", "n_groups",
+                  "ssm_state_size", "conv_kernel", "layer_norm_epsilon",
+                  "time_step_min", "time_step_max", "time_step_floor"):
+        assert not hasattr(gh.GraniteHybridConfig, alias), alias
 
 
 def test_the_seam_says_what_a_sequence_keeps():
     s = serving(TINY)
     assert s.state.layers == (0, 2) and s.kinds[0].layers == (1,)
     assert [shape for shape, _ in s.state.parts] \
-        == [(128, 2, 16), (3, TINY.conv_dim)]
+        == [(128, 2, 16), (3, TINY.mamba.conv_dim)]
     assert s.state.parts[0][1] == jnp.float32       # S, whatever the dtype
     assert s.kinds[0].rows == (32, 32) and s.counters == nh.COUNTERS
     assert s.vocab_size == 256 and s.max_seq == 128 and s.at_rest is None

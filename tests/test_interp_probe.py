@@ -66,15 +66,20 @@ def _sleep(stop):
 
 def test_a_spinning_python_thread_holds_the_interpreter_for_the_probe():
     """A thread that never blocks gives the interpreter up only when
-    asked, a switch interval after the probe woke: nearly every sample
-    is held, and late by about that interval."""
-    n, held, late = _beside(_spin, 1.0)
-    switch = sys.getswitchinterval()
-    assert held > 0.8, (n, held, late)
-    assert 0.5 * switch < late, (n, late, switch)
-    # Beside five other workers the OS adds its own; alone it reads
-    # 5.2 ms at a 5 ms interval.
-    assert min(_beside(_spin, 0.5)[2] for _ in range(3)) < 6 * switch
+    asked, a switch interval after the probe woke; a sleeping one never
+    has it. What a neighbour's load adds, it adds to both, so the
+    assertion is their ORDER in one try of three (a level here, 80%
+    held and under six switch intervals late, was the neighbours'
+    reading, not the probe's: ROADMAP.md C11)."""
+    tries = []
+    for _ in range(3):
+        _, held, late = _beside(_spin)
+        _, idle_held, idle_late = _beside(_sleep)
+        tries.append((held, idle_held, late, idle_late))
+        if held > idle_held and late > idle_late:
+            return
+    pytest.fail(f"no try of three had the spinning thread's held share "
+                f"and lateness above the sleeping one's: {tries}")
 
 
 @pytest.mark.parametrize("body", [_hash, _sleep], ids=["hashlib", "sleep"])
@@ -90,14 +95,19 @@ def test_a_step_entry_carries_what_the_probe_saw_since_the_last_one():
     acc = StepAccounting()
     acc.begin()
     acc.add_device(1e-3)
-    acc.finish()
+    # Totals on BOTH sides of each finish: the probe samples on while
+    # this thread waits for a core, so the entry's count lies between
+    # the inner and the outer difference.
     a = perfmodel.interp_totals()
-    time.sleep(5 * perfmodel.INTERP_PERIOD_S)
+    acc.finish()
+    a_in = perfmodel.interp_totals()
+    time.sleep(10 * perfmodel.INTERP_PERIOD_S)
     acc.begin()
     acc.add_device(1e-3)
+    b_in = perfmodel.interp_totals()
     out = acc.finish()
     b = perfmodel.interp_totals()
-    assert 3 <= b["n"] - a["n"] - 1 <= out["interp_n"] <= b["n"] - a["n"] + 1
+    assert 3 <= b_in["n"] - a_in["n"] <= out["interp_n"] <= b["n"] - a["n"]
     assert out["interp_late_ms"] >= out["interp_late_max_ms"] >= 0.0
     assert 0 <= out["interp_held_n"] <= out["interp_n"]
     assert out["standstill_ms"] + out["held_long_ms"] <= \
@@ -170,24 +180,39 @@ def test_the_thread_table_groups_by_name_and_only_grows():
                               name=name)
              for name in ("actor-1a2b3c4d_0", "actor-1a2b3c4d_1",
                           "llm-engine-LLMServer", "serve-feed-7")]
+    # Threads that tests before this one left in the worker (an engine's
+    # daemon loop outlives ``serve.shutdown()``: ROADMAP.md C12) are in
+    # the table too: count what THIS test starts.
+    before = profiler.thread_cpu()["by_group"]
     for t in named:
         t.start()
     try:
         time.sleep(0.1)
-        a = profiler.thread_cpu()
+        # The process's clock on BOTH sides of each reading: a reading
+        # opens a file a task while the spinners run on, so the table's
+        # growth lies between the inner and the outer difference,
+        # however long a loaded box makes a reading take.
         cpu0 = time.process_time()
-        time.sleep(0.8)
-        b = profiler.thread_cpu()
+        a = profiler.thread_cpu()
         cpu1 = time.process_time()
+        # A window in the process's own seconds, not the wall's.
+        deadline = time.monotonic() + 30
+        while time.process_time() - cpu1 < 0.5 and time.monotonic() < deadline:
+            time.sleep(0.05)
+        cpu2 = time.process_time()
+        b = profiler.thread_cpu()
+        cpu3 = time.process_time()
     finally:
         stop.set()
         for t in named:
             t.join(timeout=30)
     groups = b["by_group"]
-    assert groups["actor"]["threads"] == 2
-    assert groups["llm-engine"]["threads"] == 1
+    started = lambda g: groups[g]["threads"] \
+        - before.get(g, {"threads": 0})["threads"]
+    assert started("actor") == 2
+    assert started("llm-engine") == 1
     assert groups["MainThread"]["threads"] == 1
-    assert groups["other"]["threads"] >= 1          # serve-feed-7
+    assert started("other") >= 1                    # serve-feed-7
     assert "native" not in groups or groups["native"]["threads"] >= 0
     for group, now in groups.items():
         was = a["by_group"].get(group, {"cpu_s": 0.0})
@@ -196,14 +221,16 @@ def test_the_thread_table_groups_by_name_and_only_grows():
     def total(t):
         return sum(g["cpu_s"] for g in t["by_group"].values())
 
-    grew, clock = total(b) - total(a), cpu1 - cpu0
-    assert clock > 0.3
-    assert grew == pytest.approx(clock, rel=0.08, abs=0.03), (grew, clock)
+    grew, inner, outer = total(b) - total(a), cpu2 - cpu1, cpu3 - cpu0
+    assert inner >= 0.5
+    # A running task's record lags the process's clock by up to a tick
+    # (10 ms) a thread, at either reading.
+    assert inner - 0.06 <= grew <= outer + 0.06, (inner, grew, outer)
     # The spinners took it between them; where the kernel keeps
     # schedstat the wait for a core is a number too.
     spun = sum(groups[g]["cpu_s"] - a["by_group"][g]["cpu_s"]
                for g in ("actor", "llm-engine", "other"))
-    assert spun > 0.6 * clock
+    assert spun > 0.6 * grew
     if os.path.exists("/proc/self/schedstat"):
         assert groups["actor"]["wait_s"] is not None
     # Ended threads keep what they had used: no group shrinks.

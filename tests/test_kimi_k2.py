@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm.engine import LLMEngine
-from ray_tpu.models import kimi_k2, kimi_k2_ref, laguna, serving
+from ray_tpu.models import kimi_k2, kimi_k2_ref, layers, serving
 from ray_tpu.ops import moe
 from ray_tpu.ops.pallas import paged_fetch
 
@@ -243,7 +243,7 @@ def test_the_seeds_router_bias_changes_the_chosen_set(params):
 
 def test_yarn_frequencies_and_softmax_scale_are_the_closed_forms():
     cfg = kimi_k2.KimiK2Config()         # the published numbers
-    inv, rot, cs = laguna.rope_inv_freq(cfg.rope, cfg.qk_rope_head_dim)
+    inv, rot, cs = layers.rope_inv_freq(cfg.rope, cfg.qk_rope_head_dim)
     d, base, factor, orig = 64, 50000.0, 64.0, 4096
     assert rot == d and cs == 1.0       # mscale / mscale_all_dim = 1
     low = math.floor(d * math.log(orig / (32 * 2 * math.pi))
@@ -301,7 +301,7 @@ def test_the_shares_routed_parts_add_up_to_the_uncut_layer():
             np.testing.assert_array_equal(p["w1"],
                                           full["w1"][first:first + 4])
             np.testing.assert_array_equal(p["router"], full["router"])
-            out, sizes = kimi_k2._mlp(h2, p, share, "decode")
+            out, sizes = kimi_k2.mlp(h2, p, share, "decode")
             shared = kimi_k2_ref.swiglu(h2, p["s_gu"], p["s_down"])
             total = total + (out - shared)
             held_rows += int(sizes.sum())

@@ -17,8 +17,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm.engine import LLMEngine
-from ray_tpu.llm.kv_cache import WindowPool, window_table_len
-from ray_tpu.models import laguna, laguna_ref, serving
+from ray_tpu.llm.kv_cache import WindowPool
+from ray_tpu.models import (laguna, laguna_ref, layers, serving,
+                            window_table_len)
 
 KINDS = ("full_attention", "sliding_attention", "sliding_attention",
          "sliding_attention", "full_attention")
@@ -349,7 +350,7 @@ def test_yarn_frequencies_equal_the_closed_form():
     cfg = laguna.LagunaConfig(
         num_hidden_layers=1, num_attention_heads_per_layer=(48,),
         layer_types=("full_attention",), mlp_layer_types=("dense",))
-    inv, rot, scale = laguna.rope_inv_freq(cfg.rope_full, 128)
+    inv, rot, scale = layers.rope_inv_freq(cfg.rope_full, 128)
     assert rot == 64 and scale == pytest.approx(1.4158883083359672)
     theta = 500000.0
     d = lambda t: 64 * math.log(4096 / (2 * math.pi * t)) \
@@ -365,7 +366,7 @@ def test_yarn_frequencies_equal_the_closed_form():
     assert inv[0] == 1.0 and inv[31] == pytest.approx(
         theta ** (-62 / 64) / 64)
     # Sliding layers: every dim, unscaled, theta 10000.
-    inv_s, rot_s, scale_s = laguna.rope_inv_freq(cfg.rope_sliding, 128)
+    inv_s, rot_s, scale_s = layers.rope_inv_freq(cfg.rope_sliding, 128)
     assert rot_s == 128 and scale_s == 1.0
     assert inv_s[1] == pytest.approx(10000.0 ** (-2 / 128))
 
@@ -374,7 +375,7 @@ def test_rotary_of_the_served_path_equals_the_references():
     x = jax.random.normal(jax.random.key(5), (7, 3, 16))
     pos = jnp.arange(7) * 13
     for rope in (TINY.rope_full, TINY.rope_sliding):
-        got = laguna._rotary(x, pos, rope, 16)
+        got = layers.rotary(x, pos, rope, 16)
         want = laguna_ref.rotary(x, pos, rope, 16)
         assert jnp.abs(got - want).max() < 1e-6
 

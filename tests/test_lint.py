@@ -73,6 +73,10 @@ def test_invariant_site_tables_still_bind():
                    inv.FLIGHTREC_SITE_TABLES, inv.SPEC_SITE_TABLES):
         for path, _needle, _entries, _why in tables:
             assert (REPO_ROOT / path).is_file(), path
+    for path, _banned, _why in inv.LAYER_TABLES:
+        assert (REPO_ROOT / path).is_dir(), path
+    for path in inv.PRIVATE_IMPORT_PACKAGES:
+        assert (REPO_ROOT / path).is_dir(), path
 
 
 def test_json_schema_is_stable(report):

@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import gpt, step_columns
+from ray_tpu.models import gpt, step_columns, window_table_len
 from ray_tpu.ops.pallas.flash import flash_attention_pallas
 from ray_tpu.ops.pallas.paged_fetch import paged_attention_stored
 from ray_tpu.parallel import MeshSpec
@@ -448,7 +448,6 @@ def laguna_programs(one_chip):
     64 lanes, a full pool of 22,528 blocks (2 layers), a window pool of
     4,096 (3 layers), tables for 9,216 tokens. ~25 s for the pair."""
     from ray_tpu.llm.engine import _jit_programs
-    from ray_tpu.llm.kv_cache import window_table_len
     from ray_tpu.models import laguna
 
     cfg = _laguna_cell()
@@ -983,18 +982,15 @@ def test_gpt2_chunk_program_has_no_chunk_kernel(one_chip, as_tpu):
 
 # -- the programs of a step, queued back to back (PR 47) ----------------------
 
-def _chunk_program_texts(one_chip):
+@pytest.fixture(scope="module")
+def chunk_program_texts(one_chip):
     """The three models' chunk programs lowered for the TPU (the chat
     cell's 512-token chunk behind context; Laguna's and Kimi's at their
-    tiny test shapes, a 64-token chunk behind context), each Mosaic
-    call's serialized body cut out: it carries the path of the checkout
-    it was traced in."""
-    import re
-
+    tiny test shapes, a 64-token chunk behind context); ``_text_sha``
+    cuts each Mosaic call's serialized body out."""
     import test_kimi_k2
     import test_laguna
     from ray_tpu.llm.engine import _jit_programs
-    from ray_tpu.llm.kv_cache import window_table_len
     from ray_tpu.models import kimi_k2, laguna, serving
 
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -1005,25 +1001,33 @@ def _chunk_program_texts(one_chip):
             lambda leaf: S(leaf.shape, leaf.dtype),
             jax.eval_shape(lambda: init(jax.random.key(0), cfg)))
 
-    lowered = {"gpt": _chat_cell_chunk_lowered(one_chip, 512, MAX_NB)}
-    for name, mod, cfg in (("laguna", laguna, test_laguna.TINY),
-                           ("kimi", kimi_k2, test_kimi_k2.TINY)):
-        model, n = serving(cfg), 64
-        pools = [[S((len(kind.layers), 32, BS, width), bf16)
-                  for width in kind.rows] for kind in model.kinds]
-        args = [*pools[0], S((model.max_seq // BS + n // BS + 2,), i32)]
-        if len(pools) > 1:
-            nbw = window_table_len(model.kinds[1].window, BS, 1)
-            args += [*pools[1], S((nbw + 1 + n // BS,), i32)]
-        lowered[name] = _jit_programs(cfg)[1].lower(
-            shapes(mod.init, cfg), S((1, n), i32), *args)
-    return {name: re.sub(r'backend_config = "[^\n]*?"(?=[,}\s])',
-                         'backend_config = "..."', low.as_text())
-            for name, low in lowered.items()}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = {"gpt": _chat_cell_chunk_lowered(one_chip, 512, MAX_NB)}
+        for name, mod, cfg in (("laguna", laguna, test_laguna.TINY),
+                               ("kimi", kimi_k2, test_kimi_k2.TINY)):
+            model, n = serving(cfg), 64
+            pools = [[S((len(kind.layers), 32, BS, width), bf16)
+                      for width in kind.rows] for kind in model.kinds]
+            args = [*pools[0], S((model.max_seq // BS + n // BS + 2,), i32)]
+            if len(pools) > 1:
+                nbw = window_table_len(model.kinds[1].window, BS, 1)
+                args += [*pools[1], S((nbw + 1 + n // BS,), i32)]
+            lowered[name] = _jit_programs(cfg)[1].lower(
+                shapes(mod.init, cfg), S((1, n), i32), *args)
+    return {name: low.as_text() for name, low in lowered.items()}
 
 
+# GPT-2's since PR 61, which lowers it from the parameters as served
+# (bfloat16 weights in: "99837368b616cd4b" from float32 leaves).
+CHUNK_TEXT_AT_PR46 = {"gpt": "8f3e1ee80c41bc30",
+                      "laguna": "15e00d4b01149d99",
+                      "kimi": "568d0ef5d4d34d94"}
+
+
+@pytest.mark.parametrize("model", sorted(CHUNK_TEXT_AT_PR46))
 def test_the_chunk_programs_are_what_they_were_before_the_step_queued_them(
-        one_chip, as_tpu):
+        chunk_program_texts, model):
     """PR 47 hands a finishing prompt's first token to the decode
     program on the device and touches no chunk program: ``Serving.chunk``,
     ``pack_span`` and the three models' chunk functions lower, for the
@@ -1033,19 +1037,48 @@ def test_the_chunk_programs_are_what_they_were_before_the_step_queued_them(
     chunk lengths keep their compile-cache keys (ROADMAP A7: an
     XLA-only program's key survives any move of its source); whoever
     changes a chunk program records the new text knowingly."""
-    texts = _chunk_program_texts(one_chip)
-    assert "tpu_custom_call" not in texts["gpt"]
-    assert 'kernel_name = "chunk_attn"' in texts["laguna"]
-    assert 'kernel_name = "chunk_attn"' in texts["kimi"]
-    assert {name: _text_sha(text)
-            for name, text in texts.items()} == CHUNK_TEXT_AT_PR46
+    text = chunk_program_texts[model]
+    assert ('kernel_name = "chunk_attn"' in text) == (model != "gpt")
+    assert ("tpu_custom_call" in text) == (model != "gpt")
+    assert _text_sha(text) == CHUNK_TEXT_AT_PR46[model]
 
 
-# GPT-2's since PR 61, which lowers it from the parameters as served
-# (bfloat16 weights in: "99837368b616cd4b" from float32 leaves).
-CHUNK_TEXT_AT_PR46 = {"gpt": "8f3e1ee80c41bc30",
-                      "laguna": "15e00d4b01149d99",
-                      "kimi": "568d0ef5d4d34d94"}
+# The served programs PR 66's move of the families' shared parts runs
+# through and no table above holds: lowered at the cells' shapes by this
+# file's fixtures, sha256 of the text (``_text_sha``), recorded on PR
+# 65's tree with the test below. ``PROGRAMS_AT_PR62`` has the rest.
+SERVED_TEXT_AT_PR65 = {
+    ("decode", "gpt"): "ca9e1a33518210aa",
+    ("decode", "granite"): "3d881707e540edb1",
+    ("chunk", "granite"): "290aecb22f3fd387",
+    ("chunk", "xing"): "5c9be7b5d7ee8776",
+}
+
+
+@pytest.mark.parametrize("program, model", [
+    ("chunk", "nemotron"), ("chunk", "granite"), ("chunk", "xing"),
+    ("decode", "gpt"), ("decode", "laguna"), ("decode", "kimi"),
+    ("decode", "nemotron"), ("decode", "granite"), ("decode", "xing")])
+def test_the_served_programs_are_what_they_were_under_five_forward_passes(
+        program, model, request, one_chip):
+    """PR 66 gives what the families share a module of its own
+    (models/seam.py, layers.py, mamba2.py) and changes no program the
+    chip runs: the three chunk programs the test above does not hold
+    and every family's decode program lower, for the TPU at the cells'
+    shapes, to the text they lowered to on the parent tree, where the
+    hashes were recorded before the first function moved. The text
+    carries no source locations, so code that only moves keeps its
+    hash; an operation added, dropped, reordered or renamed does not."""
+    if model == "gpt":
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            got = _text_sha(_chat_cell_decode_lowered(one_chip).as_text())
+    else:
+        programs = request.getfixturevalue(f"{model}_programs")
+        got = programs["chunk_text"] if (program, model) == ("chunk", "xing") \
+            else programs["texts"][program]
+    held = SERVED_TEXT_AT_PR65.get((program, model))
+    assert got == (held or PROGRAMS_AT_PR62[model]["texts"][program])
 
 
 def test_the_placing_program_compiles_once_whatever_the_lane():
@@ -1256,7 +1289,8 @@ def granite_programs(one_chip):
             params, S((1, 1024), i32), kv, kv,
             S((max_nb + 1024 // BS + 4,), i32), *state)
         return {"param_leaves": len(jax.tree_util.tree_leaves(params)),
-                "decode": lowered.compile(), "chunk": low_chunk.compile()}
+                "decode": lowered.compile(), "chunk": low_chunk.compile(),
+                **_as_lowered({"decode": lowered, "chunk": low_chunk})}
 
 
 def _granite_state_sized(text, *opcodes):
@@ -1416,12 +1450,15 @@ def xing_programs(one_chip):
         lowered = decode.lower(
             params, S((B, step_columns(1).table + max_nb), i32), pool,
             q=1, firsts=S((B,), i32))
+        low_chunk = chunk.lower(
+            params, S((1, 2048), i32), pool,
+            S((max_nb + 2048 // BS + 2,), i32))
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode": lowered.compile(),
-            "chunk": chunk.lower(
-                params, S((1, 2048), i32), pool,
-                S((max_nb + 2048 // BS + 2,), i32)).compile(),
+            "chunk": low_chunk.compile(),
+            # Apart from ``texts``: PR 63 moved this program knowingly.
+            "chunk_text": _text_sha(low_chunk.as_text()),
             **_as_lowered({"decode": lowered}),
         }
 

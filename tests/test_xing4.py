@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm.engine import LLMEngine, _jit_programs
-from ray_tpu.models import (kimi_k2, pack_step, serving, xing4, xing4_ref)
+from ray_tpu.models import (kimi_k2, layers, pack_step, serving, xing4,
+                            xing4_ref)
 from ray_tpu.ops import mhc, moe
 
 # Records every logits row an engine decides a token from, {rid: [row, ...]}.
@@ -205,19 +206,26 @@ def test_streams_open_as_copies_and_close_by_a_sum():
 def test_kimis_forward_pass_is_called_not_copied():
     """The two programs are Kimi's functions with another ``Residual``;
     the attention paths, the MLP, the head and the counters are Kimi's
-    own objects."""
+    own objects, under Kimi's public names (``Xing4Config`` IS a
+    ``KimiK2Config``), and what Kimi takes from models/layers.py this
+    module takes from there too."""
     import inspect
 
     src = inspect.getsource(xing4)
     for name in ("kimi_k2.forward_step(", "kimi_k2.forward_prefill_chunk(",
-                 "kimi_k2._mlp(", "kimi_k2.cost_shape(",
-                 "kimi_k2.init_layer(", "kimi_k2._init_ends("):
+                 "kimi_k2.mlp(", "kimi_k2.cost_shape(",
+                 "kimi_k2.init_layer(", "kimi_k2.Residual(",
+                 "kimi_k2.COUNTERS", "init_ends("):
         assert name in src, name
-    for copied in ("def _project", "def _chunk_attention", "def _head",
-                   "def _counters", "def _mlp", "paged_attention_latent"):
+    for copied in ("\ndef _project", "\ndef _chunk_attention", "\ndef head",
+                   "\ndef counters", "\ndef mlp", "\ndef init_ends",
+                   "\ndef rmsnorm", "paged_attention_latent", "kimi_k2._"):
         assert copied not in src, copied
+    for name in ("init_ends", "normal", "rmsnorm"):
+        assert getattr(xing4, name) is getattr(kimi_k2, name) \
+            is getattr(layers, name), name
     assert issubclass(xing4.Xing4Config, kimi_k2.KimiK2Config)
-    assert kimi_k2.PLAIN.block is kimi_k2._block
+    assert kimi_k2.PLAIN.block is kimi_k2.block
 
 
 # -- the seam ----------------------------------------------------------------
