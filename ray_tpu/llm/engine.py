@@ -67,8 +67,8 @@ from typing import Callable, Deque, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..models import (pack_span, served_params, serving, step_columns,
-                      window_table_len)
+from ..models import (pack_span, pack_spans, served_params, serving,
+                      step_columns, window_table_len)
 from ..util import perfmodel, tracing
 from .kv_cache import (BlockChain, PagedKVCache, PrefixPool, StatePool,
                        WindowPool)
@@ -152,16 +152,35 @@ class Request:
 
 
 @dataclass
+class _Span:
+    """A span of a prompt that the step's budget has bought and no
+    program carries yet (``LLMEngine._run_prefills``)."""
+    req: Request
+    seq: List[int]              # the request's tokens, prompt and output
+    t0: float                   # wall clock where the span was cut
+    upto: int                   # tokens resident before it
+    c: int                      # its tokens, and the rows that pad them
+    pad: int                    # to whole blocks
+    total: int                  # the prompt's tokens
+    snap_at: int                # where a state's snapshot is due, or 0
+
+
+@dataclass
 class _Chunk:
-    """A prefill chunk the step has dispatched and not yet seen done:
-    what it owes the host, settled in ``LLMEngine._settle``."""
+    """A span of a prompt in a chunk program the step has dispatched
+    and not yet seen done: what it owes the host, settled in
+    ``LLMEngine._settle``. The spans that rode in ONE program share its
+    device span, its results and its row of the log, and lie side by
+    side in ``_pending``."""
     req: Optional[Request]      # None: preempted with the chunk in flight
-    span: "perfmodel._Program"  # its device span, open
-    result: jax.Array           # the prompt's last id (greedy) or row
+    span: "perfmodel._Program"  # its program's device span, open
+    result: jax.Array           # its program's ids (greedy) or rows, and
+    index: Optional[int]        # its own row of them (None: they are its)
     done: bool                  # the prompt ends with this chunk
-    log: list                   # its row of the step's ``prefill_chunks``
+    log: list                   # its program's row of ``prefill_chunks``
     t0: float                   # wall clock at its start, and the span's
-    upto: int                   # prompt tokens resident after it, of
+    tokens: int                 # prompt tokens it computed, and how many
+    upto: int                   # are resident after it, of
     total: int                  # so many: the request's ``llm.prefill``
     handed: bool = False        # its lane is taken; its id goes to it
 
@@ -172,12 +191,14 @@ _KERNEL_MODES: dict = {}
 
 
 @jax.jit
-def _place_first(firsts, lane, tok):
+def _place_first(firsts, lane, tok, at=None):
     """``firsts`` (``Serving.step``) with a chunk program's argmax id
     at a lane: three device arrays, the lane a traced scalar, so ONE
     tiny program whatever the lane, and no value crosses to the host
-    between the chunk and the decode step queued behind it."""
-    return firsts.at[lane].set(tok)
+    between the chunk and the decode step queued behind it. Where the
+    program carried several spans ``tok`` holds an id a span and ``at``
+    is the span's index, a device array too."""
+    return firsts.at[lane].set(tok if at is None else tok[at])
 
 
 def _i32(*shape):
@@ -288,6 +309,10 @@ class LLMEngine:
         # above.
         self.max_nb = self.kv.blocks_for_tokens(self.model.max_seq)
         self._decode, self._prefill_chunk = _jit_programs(cfg)
+        # The spans ONE call of the family's chunk program takes (the
+        # seam's ``Serving.chunk_spans``), read here once: a step's
+        # spans are dispatched in groups of up to so many.
+        self._chunk_spans = int(self.model.chunk_spans)
         if self.model.state is not None and speculative is not None:
             raise ValueError(
                 "speculative decoding is refused for a model whose "
@@ -372,6 +397,10 @@ class LLMEngine:
         self._no_firsts = jax.numpy.full((self.max_batch,), -1, np.int32)
         self._lane_ids = list(jax.numpy.arange(self.max_batch,
                                                dtype=np.int32))
+        # ... and a span's index into its program's ids, where a
+        # program carries several.
+        self._span_ids = list(jax.numpy.arange(self._chunk_spans,
+                                               dtype=np.int32))
         self._pending: List[_Chunk] = []    # dispatched, not seen done
 
         self._lock = threading.Lock()
@@ -403,6 +432,7 @@ class LLMEngine:
 
         self._idle_decay = GaugeIdleDecay()
         self._prefill_chunks = 0      # chunk dispatches (whole=1 chunk)
+        self._prefill_spans = 0       # the spans they carried
         self._kv_util_peak = 0.0      # high-water pool utilization
         # Device-step accounting: every step's dispatch->block_until_ready
         # span is timed apart from the host work around it and priced by
@@ -417,7 +447,8 @@ class LLMEngine:
         self._step_perf = perfmodel.StepAccounting(between="llm.between")
         self._arrived = 0       # add_request calls since the last ring entry
         self._preempt_count = 0       # preemptions, all steps
-        self._chunk_log: List[list] = []    # this step's prefill chunks
+        self._chunk_log: List[list] = []    # this step's chunk programs
+        self._span_log: List[list] = []     # and the spans of each
         # lanes, context tokens, decode tokens, lanes decided on the device
         self._counts = (0, 0, 0, 0)
         # Output tokens by where they were decided: a program's own
@@ -795,9 +826,9 @@ class LLMEngine:
         return False
 
     def _run_prefills(self):
-        """Prefill newly admitted requests one sequence at a time
-        (prompt lengths are ragged; padding to a block multiple bounds
-        recompiles to max_seq/block_size variants).
+        """Prefill newly admitted requests (prompt lengths are ragged;
+        padding to a block multiple bounds recompiles to
+        max_seq/block_size variants).
 
         Two refinements over run-the-whole-prompt:
           * the prefix-cached span was skipped at admission —
@@ -808,28 +839,38 @@ class LLMEngine:
             requests, the cursor carrying over — decode lanes keep
             emitting a token every step under long-prompt arrivals.
 
-        A chunk is ONE dispatch of the chunk program (``Serving.chunk``),
-        which is donated the pools, writes the span's K/V into them and
-        returns the last row's logits and argmax; the host builds two
-        arrays before it. The chunk is DISPATCHED here, not awaited:
-        the pools come back as futures and go into the next program,
-        so the device runs the step's programs in dispatch order with
-        no host between them, and what a chunk owes the host waits in
-        ``_pending`` until the decode program is queued too
-        (``_settle``; a chunk that is alone in flight is settled before
-        the decode step is built, ``_run_decode``). A greedy prompt
-        that ends here takes its lane at once, and its first token can
-        reach the decode program on the device (``firsts``): the host
-        knows everything else of the new lane. Only a result the host
-        must have before it can build the decode step is fetched here:
-        the logits row of a request that samples, and a first token
-        the proposer is to continue from.
+        This loop cuts the budget into SPANS, one a request; the spans
+        ride in chunk programs (``_dispatch_spans``), as many in one as
+        the family's program takes (``Serving.chunk_spans``): the tail
+        of one prompt's body and the head of the next are ONE program,
+        which reads the weights once. A group is dispatched when it is
+        full, when the next span's context blocks would not fit the
+        program's one table of ``max_nb`` blocks beside the group's,
+        when a span's result has to be fetched before the decode step
+        can be built, and where the loop ends. A family whose program
+        takes one span gets groups of one, and is dispatched span by
+        span as it always was.
+
+        A program is DISPATCHED here, not awaited: the pools come back
+        as futures and go into the next program, so the device runs
+        the step's programs in dispatch order with no host between
+        them, and what a chunk owes the host waits in ``_pending``
+        until the decode program is queued too (``_settle``; a chunk
+        that is alone in flight is settled before the decode step is
+        built, ``_run_decode``). A greedy prompt that ends here takes
+        its lane at once, and its first token can reach the decode
+        program on the device (``firsts``): the host knows everything
+        else of the new lane. Only a result the host must have before
+        it can build the decode step is fetched here: the logits row of
+        a request that samples, and a first token the proposer is to
+        continue from.
         """
         prefills = [r for r in self._active if r.state == PREFILL]
         self._last_prefill_count = len(prefills)
         bs = self.kv.block_size
         budget = self.prefill_chunk_tokens
         perf = self._step_perf
+        group: List[_Span] = []     # bought, and in no program yet
         for req in prefills:
             with perf.phase("llm.prefill.host"):
                 t0 = time.time()
@@ -865,180 +906,245 @@ class LLMEngine:
                     c = snap_at - upto
                 if budget is not None:
                     budget -= c
-                pad = -c % bs
-                # Span [upto, upto+c) attending resident context (earlier
-                # chunks and/or prefix-cache hits). Two host arrays a
-                # chunk (each is a hand-over of the interpreter lock
-                # beside the serving threads): the tokens, and the
-                # table the chunk reads with the blocks it writes, its
-                # context length and its last real row behind it
-                # (``pack_span``). A span from the prompt's start has
-                # no context: an empty table, and it attends over itself
-                # alone.
-                toks = np.zeros((1, c + pad), np.int32)
-                toks[0, :c] = seq[upto:upto + c]
-                read = np.zeros((self.max_nb if upto else 0,), np.int32)
-                if upto:
+                span = _Span(req, seq, t0, upto, c, -c % bs, T, snap_at)
+                # The contexts of a program's spans lie end to end in
+                # ONE table of max_nb blocks: two spans behind long
+                # documents do not fit one, and go in a program each.
+                fits = not group or sum(
+                    -(-s.upto // bs) for s in group + [span]) <= self.max_nb
+            if not fits:
+                self._dispatch_spans(group)
+                group = []
+            group.append(span)
+            # The sampler needs the row, the proposer the token, before
+            # the decode step can be built.
+            fetch_now = upto + c >= T and (not req.greedy
+                                           or self._spec is not None)
+            if len(group) == self._chunk_spans or fetch_now:
+                self._dispatch_spans(group)
+                group = []
+                if fetch_now:
+                    self._settle()
+        if group:
+            self._dispatch_spans(group)
+
+    def _dispatch_spans(self, group: List[_Span]):
+        """ONE dispatch of the chunk program (``Serving.chunk``) for
+        the spans of ``group`` (one span where the family's program
+        takes one): it is donated the pools, writes the spans' rows
+        into them and returns, a span, the last row's logits and their
+        argmax. Two host arrays a program (each is a hand-over of the
+        interpreter lock beside the serving threads): the tokens, and
+        the table the program reads with the blocks it writes and each
+        span's lengths behind it (``pack_span``; ``pack_spans`` where
+        the program takes several). A span from the prompt's start has
+        no context: alone in its program it has an empty table, and
+        attends over itself alone. One ``_Chunk`` a span goes to
+        ``_pending``; they share the program's device span, which stays
+        open (the wait for it comes when the step's programs are all
+        queued), and its row of the step's ``prefill_chunks``."""
+        perf = self._step_perf
+        bs = self.kv.block_size
+        several = self._chunk_spans > 1
+        with perf.phase("llm.prefill.host"):
+            n = sum(s.c + s.pad for s in group)
+            toks = np.zeros((1, n), np.int32)
+            row = 0
+            for s in group:
+                toks[0, row:row + s.c] = s.seq[s.upto:s.upto + s.c]
+                row += s.c + s.pad
+
+            def written(s):     # the blocks a span's rows go to
+                return s.req.block_table[
+                    s.upto // bs:(s.upto + s.c + s.pad) // bs]
+
+            window = ()
+            if several:
+                table = pack_spans(
+                    [(s.req.block_table[:-(-s.upto // bs)], written(s),
+                      s.upto, s.c) for s in group],
+                    self.max_nb, bs, self._chunk_spans)
+            else:
+                (s,), req = group, group[0].req
+                read = np.zeros((self.max_nb if s.upto else 0,), np.int32)
+                if s.upto:
                     read[:len(req.block_table)] = req.block_table
-                b0 = upto // bs
                 # A sequence with a state: the slot its span starts from
                 # (a parked snapshot's behind a prefix hit, else its
                 # own) and its own, which the span's end state goes to.
                 state = () if self.states is None else (
                     req.state_slot if req.state_from is None
                     else req.state_from, req.state_slot)
-                table = pack_span(
-                    read, req.block_table[b0:b0 + (c + pad) // bs],
-                    upto, c - 1, *state)
-                done = upto + c >= T
-                window = ()
+                table = pack_span(read, written(s), s.upto, s.c - 1, *state)
                 if self.kv_window is not None:
                     window = (*self.kv_window.pools,
-                              self._slide_window(req, upto, c, pad))
+                              self._slide_window(req, s.upto, s.c, s.pad))
                 elif self.states is not None:
                     window = self.states.pools
-            # ONE program, which writes the chunk's K/V into the pools
-            # it is donated. Its span stays open: the wait for it comes
-            # when the step's programs are all queued.
-            with perf.dispatch("llm.prefill.device") as span:
-                row, tok, *pools = self._prefill_chunk(
-                    self.params, toks, *self.kv.pools, table, *window)
-            with perf.phase("llm.pools"):
-                # Here, under its name, and not when this function
-                # returns: the window kind's old arrays live on in
-                # ``window``.
-                self._take_back(pools)
-                del window, pools
-            fetch_now = False
-            with perf.phase("llm.prefill.host"):
-                req.prefilled_upto = upto + c
-                req.context_len = req.prefilled_upto
-                self._prefill_chunks += 1
+        with perf.dispatch("llm.prefill.device") as program:
+            rows, ids, *pools = self._prefill_chunk(
+                self.params, toks, *self.kv.pools, table, *window)
+        with perf.phase("llm.pools"):
+            # Here, under its name, and not when this function
+            # returns: the window kind's old arrays live on in
+            # ``window``.
+            self._take_back(pools)
+            del window, pools
+        with perf.phase("llm.prefill.host"):
+            self._prefill_chunks += 1
+            self._prefill_spans += len(group)
+            # A PROGRAM's row: [positions computed (padded to whole
+            # blocks, as priced), context tokens resident before them
+            # (both summed over its spans), ms, of them the host's
+            # dispatch]: the last two once the program is seen done.
+            log = [n, sum(s.upto for s in group), 0.0, 0.0]
+            self._chunk_log.append(log)
+            self._span_log.append([s.c + s.pad for s in group])
+            for i, s in enumerate(group):
+                req, upto = s.req, s.upto + s.c
+                req.prefilled_upto = req.context_len = upto
                 # Only the UNCACHED span is priced: ctx_tokens covers
                 # what was skipped or ran in earlier chunks, keeping MFU
-                # honest.
+                # honest. Attention is a span's; the weights are read
+                # once a program, for all its rows.
                 perf.add_cost(perfmodel.prefill_cost(
-                    self.cfg, c + pad, ctx_tokens=upto))
-                # [positions computed (padded to whole blocks, as
-                # priced), context tokens resident before them, ms, of
-                # them the host's dispatch]: the last two once the
-                # chunk is seen done.
-                log = [c + pad, upto, 0.0, 0.0]
-                self._chunk_log.append(log)
+                    self.cfg, s.c + s.pad, ctx_tokens=s.upto,
+                    weight_rows=0 if i else n))
+                done = upto >= s.total
                 # What the host will fetch of it: the program's argmax
                 # id for a greedy request whose prompt ends here, that
                 # row of logits for one that samples; of a mid-prompt
                 # chunk nothing, its id only says the program is done.
                 chunk = _Chunk(
-                    req, span, tok if req.greedy or not done else row,
-                    done, log, t0, upto + c, T)
+                    req, program, ids if req.greedy or not done else rows,
+                    i if several else None, done, log, s.t0, s.c, upto,
+                    s.total)
                 self._pending.append(chunk)
-                if done:
-                    if self._prefix:
-                        # Index the prompt's chunks for later arrivals
-                        # (shared system prompts hit from here on): a
-                        # program that reads these blocks is queued
-                        # behind the one that writes them.
-                        self.kv.register(seq, req.block_table, req.chain)
-                        if self.kv_window is not None:
-                            self.kv_window.register_tail(
-                                seq, req.window_table, req.window_first,
-                                req.chain)
-                    if not req.greedy or self._spec is not None:
-                        # The sampler needs the row, the proposer the
-                        # token, before the decode step can be built.
-                        fetch_now = True
-                    elif len(req.output) + 1 < req.max_tokens:
-                        # The lane's position, slot and tables are
-                        # host facts: it is taken now, and the decode
-                        # step can be built before the token is seen.
-                        self._activate(req)
-                        chunk.handed = True
-                    # else its first token ends it by length: it never
-                    # takes a lane, and finishes where it is settled.
-            if self.states is not None:
-                if req.state_from is not None:
-                    # The program that reads the parked snapshot is
-                    # queued: it is the lane's own state from here on.
-                    with perf.phase("llm.state_restore"):
-                        self.states.read(req.state_from)
-                        req.state_from = None
-                if upto + c == snap_at:
-                    # Behind the span that left the state there, before
-                    # the program that moves it on.
-                    with perf.phase("llm.state_snapshot"):
-                        self.states.snapshot(
-                            req.chain.reach(seq, snap_at)
-                            .keys[snap_at // bs - 1], snap_at,
-                            req.state_slot)
-            if fetch_now:
-                self._settle()
+                if not done:
+                    continue
+                if self._prefix:
+                    # Index the prompt's chunks for later arrivals
+                    # (shared system prompts hit from here on): a
+                    # program that reads these blocks is queued
+                    # behind the one that writes them.
+                    self.kv.register(s.seq, req.block_table, req.chain)
+                    if self.kv_window is not None:
+                        self.kv_window.register_tail(
+                            s.seq, req.window_table, req.window_first,
+                            req.chain)
+                if req.greedy and self._spec is None \
+                        and len(req.output) + 1 < req.max_tokens:
+                    # The lane's position, slot and tables are host
+                    # facts: it is taken now, and the decode step can
+                    # be built before the token is seen. (A request
+                    # that samples, or feeds a proposer, is settled
+                    # first; one whose first token ends it by length
+                    # never takes a lane, and finishes where it is
+                    # settled.)
+                    self._activate(req)
+                    chunk.handed = True
+        if self.states is not None:
+            (s,), req = group, group[0].req
+            if req.state_from is not None:
+                # The program that reads the parked snapshot is
+                # queued: it is the lane's own state from here on.
+                with perf.phase("llm.state_restore"):
+                    self.states.read(req.state_from)
+                    req.state_from = None
+            if s.upto + s.c == s.snap_at:
+                # Behind the span that left the state there, before
+                # the program that moves it on.
+                with perf.phase("llm.state_snapshot"):
+                    self.states.snapshot(
+                        req.chain.reach(s.seq, s.snap_at)
+                        .keys[s.snap_at // bs - 1], s.snap_at,
+                        req.state_slot)
 
     def _settle(self):
-        """Collect what the dispatched chunks owe the host, in the
-        device's order. For each: the wait for its result, which closes
-        its device span (a ``device_get`` of a prompt's last id or row
-        returns when THAT chunk is done, whatever is queued behind it);
-        its row of the step's chunk log; a prompt's first (or
-        first-since-resume) token, decided and emitted then and there,
-        so its TTFT is stamped when its chunk is done and not at the
-        step's end; the request's ``llm.prefill`` span. A request that
-        was preempted with its chunk in flight is owed nothing."""
+        """Collect what the dispatched chunk programs owe the host, in
+        the device's order. For each PROGRAM: the one wait for it,
+        which closes its device span (a ``device_get`` of its ids or
+        rows returns when THAT program is done, whatever is queued
+        behind it), and its row of the step's chunk log. For each of
+        its spans: a prompt's first (or first-since-resume) token,
+        decided and emitted then and there, so its TTFT is stamped when
+        its chunk is done and not at the step's end; the request's
+        ``llm.prefill`` span. A request that was preempted with its
+        chunk in flight is owed nothing."""
         perf = self._step_perf
-        for chunk in self._pending:
-            req = chunk.req
-            fetch = chunk.done and req is not None
-            with chunk.span.waiting():
-                if fetch:
-                    first = jax.device_get(chunk.result)
+        pending, self._pending = self._pending, []
+        for program, chunks in itertools.groupby(pending,
+                                                 key=lambda ch: ch.span):
+            chunks = list(chunks)
+            # What the host needs of the program: its ids (the greedy
+            # prompts that end in it), its rows (those that sample),
+            # each in one fetch.
+            wanted = {id(ch.result): ch.result for ch in chunks
+                      if ch.done and ch.req is not None}
+            results, got = list(wanted.values()), {}
+            with program.waiting():
+                if results:
+                    got = dict(zip(wanted, jax.device_get(results)))
                 else:
-                    jax.block_until_ready(chunk.result)
-            device_s = chunk.span.seconds
-            chunk.log[2:] = [device_s * 1e3,
-                             chunk.span.dispatch_seconds * 1e3]
-            if req is None:
-                continue
-            if fetch and req.greedy:
-                self._decided["device"] += 1
-            elif fetch:
-                # Sampled on the host at the request's absolute
-                # position (keyed by (seed, position) alone).
-                with perf.phase("llm.sample"):
-                    self._decided["host"] += 1
-                    first = sample(
-                        first, temperature=req.temperature,
-                        top_k=req.top_k, seed=req.seed,
-                        position=len(req.prompt) + len(req.output))
-            with perf.phase("llm.emit"):
-                if fetch:
-                    if req.lane is None:
-                        self._event(req, RUNNING)
-                    # A first token that ends the request (a stop
-                    # token, its length) gives back the lane it was
-                    # handed ahead of time, if any: the row that lane
-                    # ran in this step is dropped with it.
-                    if not self._emit_token(req, first) \
-                            and req.lane is None:
-                        self._take_lane(req)
-                if req.trace_ctx is not None:
-                    dur = time.time() - chunk.t0
-                    tracing.emit("llm.prefill", req.trace_ctx, chunk.t0,
-                                 dur,
-                                 {"rid": req.rid,
-                                  "tokens": chunk.upto - chunk.log[1],
-                                  "upto": chunk.upto, "total": chunk.total,
-                                  "cached": req.cached_tokens,
-                                  "done": chunk.done,
-                                  "resumed": bool(req.preemptions),
-                                  "device_ms": round(device_s * 1e3, 3),
-                                  "host_ms": round(
-                                      max(dur - device_s, 0.0) * 1e3, 3)})
-        self._pending.clear()
+                    jax.block_until_ready(chunks[0].result)
+            device_s = program.seconds
+            chunks[0].log[2:] = [device_s * 1e3,
+                                 program.dispatch_seconds * 1e3]
+            for chunk in chunks:
+                self._settle_chunk(chunk, got, device_s)
         # Then and there: a first token does not sit out the wait for
         # the decode program.
         if self._outbox:
             with perf.phase("llm.emit"):
                 self._hand_over()
+
+    def _settle_chunk(self, chunk: _Chunk, got: dict, device_s: float):
+        """One span of a settled program (``_settle``): ``got`` holds
+        the program's fetched results by the array's id."""
+        perf = self._step_perf
+        req = chunk.req
+        if req is None:
+            return
+        fetch = chunk.done
+        if fetch:
+            first = got[id(chunk.result)]
+            if chunk.index is not None:
+                first = first[chunk.index]
+        if fetch and req.greedy:
+            self._decided["device"] += 1
+        elif fetch:
+            # Sampled on the host at the request's absolute
+            # position (keyed by (seed, position) alone).
+            with perf.phase("llm.sample"):
+                self._decided["host"] += 1
+                first = sample(
+                    first, temperature=req.temperature,
+                    top_k=req.top_k, seed=req.seed,
+                    position=len(req.prompt) + len(req.output))
+        with perf.phase("llm.emit"):
+            if fetch:
+                if req.lane is None:
+                    self._event(req, RUNNING)
+                # A first token that ends the request (a stop
+                # token, its length) gives back the lane it was
+                # handed ahead of time, if any: the row that lane
+                # ran in this step is dropped with it.
+                if not self._emit_token(req, first) \
+                        and req.lane is None:
+                    self._take_lane(req)
+            if req.trace_ctx is not None:
+                dur = time.time() - chunk.t0
+                tracing.emit("llm.prefill", req.trace_ctx, chunk.t0,
+                             dur,
+                             {"rid": req.rid,
+                              "tokens": chunk.tokens,
+                              "upto": chunk.upto, "total": chunk.total,
+                              "cached": req.cached_tokens,
+                              "done": chunk.done,
+                              "resumed": bool(req.preemptions),
+                              "device_ms": round(device_s * 1e3, 3),
+                              "host_ms": round(
+                                  max(dur - device_s, 0.0) * 1e3, 3)})
 
     def _take_back(self, pools):
         """The pools a program was donated, as it returned them written:
@@ -1309,7 +1415,8 @@ class LLMEngine:
                 if chunk.handed and chunk.req is not None:
                     firsts = _place_first(
                         firsts, self._lane_ids[chunk.req.lane],
-                        chunk.result)
+                        chunk.result, *(() if chunk.index is None
+                                        else (self._span_ids[chunk.index],)))
         # ONE host array goes in beside the parameters and the pools:
         # the kept one, copied in the call (a write after it, a lane
         # given back while the chunks are settled, is not seen), and
@@ -1450,7 +1557,7 @@ class LLMEngine:
         with self._lock:
             perf.lock_waited(t_lock)
             perf.begin()
-            self._chunk_log = []
+            self._chunk_log, self._span_log = [], []
             self._counts = (0, 0, 0, 0)
             self._counters = {}
             self._inputs_written = 0
@@ -1502,7 +1609,10 @@ class LLMEngine:
                        "context_tokens": context_tokens,
                        "decode_tokens": decode_tokens,
                        "prefill_tokens": sum(c[0] for c in chunks),
+                       # A row a chunk PROGRAM, and beside it the
+                       # rows of each span the program carried.
                        "prefill_chunks": chunks,
+                       "prefill_spans": self._span_log,
                        "waiting": len(self._waiting),
                        "preempted": self._preempt_count - preempted0,
                        # Elements of the decode program's kept array
@@ -1595,8 +1705,10 @@ class LLMEngine:
                 # the kind's array: its table, first, one block
                 window += (_i32(self._win_len + 2),)
             # the table, one block written, ctx_len, last (and a state's
-            # two slots)
-            table = self.max_nb + 3 + 2 * (self.states is not None)
+            # two slots); or four numbers a span (``pack_spans``)
+            table = self.max_nb + 1 + (
+                4 * self._chunk_spans if self._chunk_spans > 1
+                else 2 + 2 * (self.states is not None))
             traced = chunk.trace(
                 params, _i32(1, self.kv.block_size), *self._pool_specs,
                 _i32(table), *window)
@@ -1643,7 +1755,10 @@ class LLMEngine:
             # What the model's sequences keep: the slots' counters.
             **({} if self.states is None else self.states.stats()),
             "tokens_per_s": self.tokens_per_s(),
+            # Chunk programs dispatched, and the spans they carried
+            # (equal where the family's program takes one span).
             "prefill_chunks": self._prefill_chunks,
+            "prefill_spans": self._prefill_spans,
             # The step program's own counters (``kv_pages_in_runs``, a
             # model's expert counts) where the newest step decoded.
             **self._step_counters(),

@@ -38,9 +38,9 @@ import importlib
 from . import (gpt, granite_hybrid, kimi_k2, laguna,  # noqa: F401
                nemotron_h, resnet, xing4)
 from .seam import (LayerKind, Serving, StateKind, StepColumns,  # noqa: F401
-                   keys_and_values, pack_span, pack_step, scatter_span,
-                   step_columns, step_state_slots, unpack_span, unpack_step,
-                   window_table_len)
+                   keys_and_values, pack_span, pack_spans, pack_step,
+                   scatter_span, span_rows, step_columns, step_state_slots,
+                   unpack_span, unpack_spans, unpack_step, window_table_len)
 
 
 @functools.lru_cache(maxsize=64)

@@ -75,11 +75,17 @@ from ..ops.pallas.paged_fetch import (kv_pages_in_runs_x1000,
                                       paged_attention_latent)
 from .layers import (COUNTERS, ROUTER_BIAS_STD, counters, head, held_experts,
                      init_ends, normal, rmsnorm, rotary, swiglu, yarn_mscale)
-from .seam import LayerKind, Serving, scatter_span, unpack_span, unpack_step
+from .seam import (LayerKind, Serving, scatter_span, span_rows, unpack_spans,
+                   unpack_step)
 
 # Heads whose keys and values a chunk makes at a time: 18,432 keys of
 # 128 and as many values are 151 MB a group (all 64 heads: 0.60 GB).
 HEAD_GROUP = 16
+# Spans ONE chunk program takes (``Serving.chunk_spans``): the tail of
+# one prompt's body, the head of the next, and so on as far as a step's
+# budget goes. The weights are read, and every kernel's fixed part is
+# paid, once for all of them (PERF.md section 6, PR 67).
+CHUNK_SPANS = 4
 
 
 @dataclass(frozen=True)
@@ -375,19 +381,21 @@ def forward_step(params, packed, pool, *, q: int, cfg: KimiK2Config,
     return logits, ids, pool
 
 
-def _chunk_attention(q_nope, q_rope, rows, ctx, ctx_len, w_ukv,
+def _chunk_attention(q_nope, q_rope, rows, ctx, seen, w_ukv,
                      cfg: KimiK2Config):
-    """A span's attention over [pool context ++ span], the UP-PROJECTING
-    form: the latent rows of context and span go through ``W_ukv`` to a
-    head's keys and values, ``HEAD_GROUP`` heads at a time (a plain XLA
-    product), and each group attends in the ``chunk_attn`` kernel
-    (ops/pallas/chunk_attention.py): scores stay in VMEM under one
-    online softmax, the one rotary key a token rides as the part of a
-    key every head shares, context blocks past ``ctx_len`` are neither
-    read nor multiplied. q_nope [n, H, nope], q_rope [n, H, rope]; rows
-    [n, W]: the span's own latent rows (query i at position
-    ctx_len + i); ctx [S, W]: the sequence's gathered pool slots, slot
-    s at position s, real below ctx_len. Returns [n, H, v]."""
+    """The attention of a program's rows over [pool context ++ the
+    rows], the UP-PROJECTING form: the latent rows of context and spans
+    go through ``W_ukv`` to a head's keys and values, ``HEAD_GROUP``
+    heads at a time (a plain XLA product), and each group attends in
+    the ``chunk_attn`` kernel (ops/pallas/chunk_attention.py): scores
+    stay in VMEM under one online softmax, the one rotary key a token
+    rides as the part of a key every head shares, context blocks no row
+    of a query block sees are neither read nor multiplied. q_nope [n,
+    H, nope], q_rope [n, H, rope]; rows [n, W]: the rows' own latent
+    rows; ctx [S, W]: the gathered pool slots of the ONE table;
+    ``seen`` = (lo, hi, first), int32 [n] each: row i sees the slots
+    [lo_i, hi_i) and the rows first_i..i (its own sequence's context
+    and its own span). Returns [n, H, v]."""
     n, H, nope = q_nope.shape
     rkv, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     S, dt = ctx.shape[0], q_nope.dtype
@@ -400,8 +408,9 @@ def _chunk_attention(q_nope, q_rope, rows, ctx, ctx_len, w_ukv,
         q, w = args                     # [hg, n, nope + rope], [hg, rkv, .]
         k = jnp.einsum("sc,hcd->hsd", c_kv, w[..., :nope])
         v = jnp.einsum("sc,hcd->hsd", c_kv, w[..., nope:])
-        return chunk_attention(q[:, None], k, v, ctx_len, ctx_slots=S,
-                               scale=cfg.softmax_scale, k_shared=k_rope)
+        return chunk_attention(q[:, None], k, v, 0, ctx_slots=S,
+                               scale=cfg.softmax_scale, k_shared=k_rope,
+                               rows=seen)
 
     o = jax.lax.map(group, (
         jnp.concatenate([q_nope, q_rope], -1).transpose(1, 0, 2)
@@ -412,21 +421,34 @@ def _chunk_attention(q_nope, q_rope, rows, ctx, ctx_len, w_ukv,
 
 def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config,
                           residual: Residual = PLAIN):
-    """One span of a prompt as one program (models/gpt.py
-    ``forward_prefill_chunk``'s contract, over one latent pool):
-    ``tokens`` [1, n], ``table`` = ``[block table | destination |
-    ctx_len | last]``. Every layer reads the pool as it came in; the
-    span's latent rows are written after the last layer. ``residual``
-    is the path the layers sit on (``Residual``); the head runs on the
-    one row that comes back, closed alone.
+    """Up to ``CHUNK_SPANS`` spans, of as many prompts, as ONE program
+    (the seam's ``chunk`` where ``chunk_spans`` > 1, over one latent
+    pool): ``tokens`` [1, n], the spans' rows end to end, each in whole
+    blocks; ``table`` = ``pack_spans``' ``[context table | destination
+    blocks | CHUNK_SPANS x (first row, first context slot, ctx_len,
+    rows)]``. A row sits at its own sequence's position and attends its
+    own sequence's context slots and its own span's rows up to itself
+    (``span_rows``, ``chunk_attn``'s per-row description); everything
+    else of a layer is a row's own (projections, the residual path,
+    the router and the experts, the norms) and runs on the n rows as
+    they lie. Every layer reads the pool as it came in; the rows'
+    latent rows are written after the last layer, zeros for a span's
+    padding. ``residual`` is the path the layers sit on (``Residual``);
+    the head runs on each span's last real row, closed alone. The
+    program's shapes are n's and the table's length (none at all for a
+    lone span from its prompt's start): a lone span is one span used,
+    and how several divide the rows is data.
 
-    Returns (row [vocab], id, pool)."""
+    Returns (rows [CHUNK_SPANS, vocab], ids [CHUNK_SPANS], pool); what a
+    span not used gets is the program's last row's."""
     n = tokens.shape[1]
     bs = pool.shape[2]
-    block_table, dest, ctx_len, last = unpack_span(table, n, bs)
+    block_table, dest, spans = unpack_spans(table, n, bs, CHUNK_SPANS)
     nb = block_table.shape[0]
-    positions = jnp.minimum(ctx_len + jnp.arange(n, dtype=jnp.int32),
-                            cfg.max_seq - 1)[None]
+    first, slot, ctx_len, real = span_rows(spans, n)
+    at = ctx_len + jnp.arange(n, dtype=jnp.int32) - first
+    positions = jnp.minimum(at, cfg.max_seq - 1)[None]
+    seen = (slot, slot + ctx_len, first)
     x = residual.open(params["embed"][tokens])
     new = []
     for li, p in enumerate(params["layers"]):
@@ -437,15 +459,14 @@ def forward_prefill_chunk(params, tokens, pool, table, cfg: KimiK2Config,
             ctx = pool[li, block_table].reshape(nb * bs, pool.shape[3])
             with jax.named_scope("attn_latent_chunk"):
                 o = _chunk_attention(q_nope[0], q_rope[0], rows[0], ctx,
-                                     ctx_len, p["w_ukv"], cfg)
+                                     seen, p["w_ukv"], cfg)
             return o[None]
 
         x, _ = residual.block(x, p, cfg, attend, "chunk")
-    pool, = scatter_span((pool,), (jnp.stack(new)[:, 0],), dest, last + 1)
-    row = head(params, residual.close(
-        jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)),
-        cfg.rms_norm_eps)[0, 0]
-    return row, jnp.argmax(row).astype(jnp.int32), pool
+    pool, = scatter_span((pool,), (jnp.stack(new)[:, 0],), dest, real)
+    last = jnp.clip(spans[:, 0] + spans[:, 3] - 1, 0, n - 1)
+    rows = head(params, residual.close(x[:, last]), cfg.rms_norm_eps)[0]
+    return rows, jnp.argmax(rows, axis=-1).astype(jnp.int32), pool
 
 
 # ---------------------------------------------------------------------------
@@ -508,4 +529,5 @@ def serving(cfg: KimiK2Config):
     return Serving(init=init, step=forward_step,
                    chunk=forward_prefill_chunk, kinds=(latent,),
                    cost=cost_shape(cfg), max_seq=cfg.max_seq,
-                   vocab_size=cfg.vocab_size, counters=COUNTERS)
+                   vocab_size=cfg.vocab_size, counters=COUNTERS,
+                   chunk_spans=CHUNK_SPANS)

@@ -40,7 +40,25 @@ for what the configuration's own module says of it, a ``Serving``:
             back the logits of the span's last real token and their
             argmax. ``table`` is one int32 array, ``[block table |
             destination blocks | ctx_len | last]`` (``pack_span``): a
-            chunk's whole bookkeeping in one hand-over
+            chunk's whole bookkeeping in one hand-over.
+            Where ``chunk_spans`` is more than 1 the program takes up
+            to that many spans, of as many sequences, in ONE call:
+            ``tokens`` [1, n] are the spans' rows end to end, each in
+            whole blocks; ``table`` is ``pack_spans``' array, ``[ONE
+            context table: the spans' context blocks end to end |
+            destination blocks | chunk_spans x (first row, first
+            context slot, ctx_len, rows)]``; it hands back ``(rows
+            [chunk_spans, vocab], ids [chunk_spans], *pools)``, the
+            head on each span's last real row. Its shapes follow from
+            n and from whether there is a table at all, never from how
+            the spans divide the rows
+  chunk_spans  the spans one call of ``chunk`` takes (1: one, and
+            ``pack_span``). The engine reads it once, when it is built,
+            and fills a program with the spans a step's budget buys
+            (llm/engine.py ``_run_prefills``). The latent family takes
+            4 (models/kimi_k2.py); the field, and ``pack_span`` with it,
+            go when the other families' chunks take several too
+            (ROADMAP A3 (c))
   kinds     the cache description: one ``LayerKind`` a kind of layer,
             which says what a token leaves in the cache there: how many
             pools the kind has and how wide a row of each is
@@ -150,6 +168,7 @@ class Serving:
     counters: Tuple[str, ...] = ()
     state: Optional[StateKind] = None
     at_rest: Optional[Callable] = None
+    chunk_spans: int = 1
 
 
 def pack_span(block_table, dest, ctx_len: int, last: int, *extra: int):
@@ -175,6 +194,63 @@ def unpack_span(table, n: int, block_size: int, extra: int = 0):
     four = (table[:nb], table[nb:nb + nd], table[-2 - extra],
             table[-1 - extra])
     return four + tuple(table[-extra:]) if extra else four
+
+
+def pack_spans(spans, max_nb: int, block_size: int, chunk_spans: int):
+    """The bookkeeping of a chunk program that carries several spans
+    (``Serving.chunk_spans`` > 1), as its ONE int32 array. ``spans``:
+    up to ``chunk_spans`` of ``(context blocks, destination blocks,
+    ctx_len, rows)`` in the order their rows lie in ``tokens``: the
+    blocks that hold the span's ``ctx_len`` tokens of context (none
+    for a span from its prompt's start), the blocks its rows are
+    written to (one a block of the span, padding included), its real
+    rows. Returns ``[context table | destination blocks | chunk_spans
+    x (first row, first context slot, ctx_len, rows)]``: the table is
+    ``max_nb`` blocks, the spans' context blocks end to end and zeros
+    behind them (the caller sees that they fit), or NO blocks for a
+    lone span from its prompt's start, as ``pack_span``'s is; a span
+    not used has no rows and starts where the rows end. So the array's
+    length says how long the table is, given the rows
+    (``unpack_spans``), and nothing else about the spans is a shape."""
+    bs = block_size
+    table = np.zeros((max_nb if len(spans) > 1 or len(spans[0][0]) else 0,),
+                     np.int32)
+    dest, quads, row, nb = [], np.zeros((chunk_spans, 4), np.int32), 0, 0
+    for s, (context, blocks, ctx_len, rows) in enumerate(spans):
+        table[nb:nb + len(context)] = context
+        dest.extend(blocks)
+        quads[s] = row, nb * bs, ctx_len, rows
+        row += len(blocks) * bs
+        nb += len(context)
+    quads[len(spans):, 0] = row
+    return np.concatenate([table, dest, quads.reshape(-1)], dtype=np.int32)
+
+
+def unpack_spans(table, n: int, block_size: int, chunk_spans: int):
+    """``pack_spans``' array, inside the program, by static slices:
+    ``(context table [nb], destination blocks [n / block_size], spans
+    [chunk_spans, 4])``, a span's row ``(first row, first context
+    slot, ctx_len, rows)``."""
+    nd = n // block_size
+    nb = table.shape[0] - nd - 4 * chunk_spans
+    return (table[:nb], table[nb:nb + nd],
+            table[nb + nd:].reshape(chunk_spans, 4))
+
+
+def span_rows(spans, n: int):
+    """What each of a packed program's ``n`` rows is, from
+    ``unpack_spans``' ``spans``: ``(first, slot, ctx_len, real)``,
+    int32 ``[n]`` the first three (the first row of the row's span,
+    its first context slot, its context length) and bool ``[n]`` the
+    last (a row of the span's ``rows``, not of its padding). Row ``i``
+    sits at position ``ctx_len + i - first`` of its sequence and sees
+    the context slots ``[slot, slot + ctx_len)`` and the rows
+    ``first..i``."""
+    i = jnp.arange(n, dtype=jnp.int32)
+    # Spans lie in row order; one that is not used starts at n.
+    of = (i[:, None] >= spans[None, :, 0]).sum(-1) - 1
+    first, slot, ctx_len, rows = (spans[of, c] for c in range(4))
+    return first, slot, ctx_len, i - first < rows
 
 
 class StepColumns(NamedTuple):
@@ -289,8 +365,10 @@ def scatter_span(pools, spans, ids, rows=None):
     flattens to ``[L, T, W_i]`` will do (``[L, T, kv_heads, head_dim]``,
     whole blocks ``[L, nb, BS, W_i]``), T <= len(ids) * BS. ``ids``
     [nb] int32: the blocks written, in the span's order. The rows from
-    ``rows`` on (a traced scalar or an int; None = T) and the tail
-    past T are written as ZEROS, masked by context_lens at read time,
+    ``rows`` on (a traced scalar or an int; None = T; or a bool
+    ``[len(ids) * BS]``, which rows are real, where a program carries
+    several spans and each has a tail) and the tail past T are written
+    as ZEROS, masked by context_lens at read time,
     so a pool's contents do not depend on what a chunk was padded
     with. Several ids may name the scratch block 0 (a window kind's
     blocks that slid out before they were written): which of them
@@ -306,7 +384,8 @@ def scatter_span(pools, spans, ids, rows=None):
         if n > x.shape[1]:
             x = jnp.pad(x, ((0, 0), (0, n - x.shape[1]), (0, 0)))
         if rows is not None:
-            x = jnp.where((jnp.arange(n) < rows)[None, :, None], x, 0)
+            real = rows if jnp.ndim(rows) else jnp.arange(n) < rows
+            x = jnp.where(real[None, :, None], x, 0)
         return x.reshape(L, -1, bs, W).astype(pool.dtype)
 
     return tuple(pool.at[:, ids].set(blocks(x, pool))

@@ -237,7 +237,7 @@ def forward_step(params, packed, pool, *, q: int, cfg: Xing4Config,
 
 
 def forward_prefill_chunk(params, tokens, pool, table, cfg: Xing4Config):
-    """One span of a prompt as one program:
+    """Up to ``kimi_k2.CHUNK_SPANS`` spans as one program:
     ``kimi_k2.forward_prefill_chunk`` on the four-stream path."""
     return kimi_k2.forward_prefill_chunk(params, tokens, pool, table, cfg,
                                          residual=_residual(cfg))

@@ -212,7 +212,8 @@ def decode_step_cost(cfg, context_lens: Sequence[int],
 
 def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
                  kv_dtype_bytes: int = 2,
-                 param_bytes: Optional[int] = None) -> StepCost:
+                 param_bytes: Optional[int] = None,
+                 weight_rows: Optional[int] = None) -> StepCost:
     """Prefill of a T-token span whose first ``ctx_tokens`` of context
     already sit in the KV pool (prefix-cache hit or an earlier chunk of
     a chunked prefill — those spans are NOT priced here, so MFU stays
@@ -222,7 +223,10 @@ def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
     attention term is ctx*T + T*(T+1)/2 contexts. The vocabulary head
     runs on ONE row, the span's last token (the chunk program hands
     back that row alone). HBM adds one read of the resident context's
-    KV on top of the span's own write+read."""
+    KV on top of the span's own write+read. A program that carries
+    several spans reads the weights once for all their rows: the span
+    that is charged them says how many rows that is (``weight_rows``;
+    None: its own), the others 0."""
     s = _shape(cfg)
     T = int(n_tokens)
     ctx = int(ctx_tokens)
@@ -237,7 +241,9 @@ def prefill_cost(cfg, n_tokens: int, *, ctx_tokens: int = 0,
     if param_bytes is None:
         param_bytes = s["param_bytes"]
     # A span reads the sequence's state once and writes it once.
-    hbm = (s["streamed_params"](T) * param_bytes + (2.0 * T + ctx) * kvb
+    weights = 0.0 if weight_rows == 0 else s["streamed_params"](
+        T if weight_rows is None else int(weight_rows))
+    hbm = (weights * param_bytes + (2.0 * T + ctx) * kvb
            + T * s.get("stream_bytes_per_row", 0)
            + 2.0 * s.get("state_bytes_per_seq", 0))
     return StepCost(flops, hbm, T)

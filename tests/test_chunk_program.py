@@ -289,3 +289,145 @@ def test_a_chunk_length_never_seen_compiles_exactly_one_program(params,
         assert len(compiled) == 2, compiled
     finally:
         monitoring.unregister_event_duration_listener(listener)
+
+
+# -- several spans in ONE program: the latent family (PR 67) ------------------
+
+def _latent(name):
+    import test_kimi_k2
+    import test_xing4
+
+    return {"kimi": test_kimi_k2.TINY, "xing": test_xing4.TINY}[name]
+
+
+@pytest.fixture(scope="module")
+def latent_params():
+    made = {}
+
+    def get(name):
+        if name not in made:
+            cfg = _latent(name)
+            mod = importlib.import_module(type(cfg).__module__)
+            made[name] = mod.init(jax.random.key(0), cfg)
+        return made[name]
+
+    return get
+
+
+def _spans_program(cfg):
+    """(the family's chunk program as the engine holds it, a function
+    that runs ``spans`` through it as ONE call). A span is (tokens, the
+    sequence's block table, tokens resident before it)."""
+    from ray_tpu.llm.engine import _jit_programs
+    from ray_tpu.models import pack_spans, serving
+
+    model = serving(cfg)
+    program = _jit_programs(cfg)[1]
+
+    def run(params, pool, spans):
+        toks, packed = [], []
+        for tokens, table, upto in spans:
+            c = len(tokens)
+            pad = -c % BS
+            toks += list(tokens) + [0] * pad
+            packed.append((table[:-(-upto // BS)],
+                           table[upto // BS:(upto + c + pad) // BS], upto, c))
+        rows, ids, pool = program(
+            params, np.asarray([toks], np.int32), jnp.array(pool, copy=True),
+            pack_spans(packed, model.max_seq // BS, BS, model.chunk_spans))
+        return (np.asarray(rows, np.float32)[:len(spans)],
+                np.asarray(ids)[:len(spans)], pool)
+
+    return program, run
+
+
+def _three_sequences(cfg, params, run, seed=11):
+    """Three prompts with their own block tables in a pool of noise:
+    ``a`` has 16 tokens resident, ``b`` none, ``c`` 24 (written here, a
+    program each). Returns (pool, the three next spans): 16 rows behind
+    16, 24 rows from the start, 13 rows (padded to 16) behind 24."""
+    from ray_tpu.models import serving
+
+    rng = np.random.default_rng(seed)
+    seqs = [rng.integers(0, 256, n).tolist() for n in (40, 24, 37)]
+    tables = [list(range(1 + 8 * i, 9 + 8 * i)) for i in range(3)]
+    width = serving(cfg).kinds[0].rows[0]
+    pool = jnp.asarray(rng.standard_normal(
+        (cfg.num_hidden_layers, 32, BS, width)), jnp.float32)
+    for seq, table, upto in ((seqs[0], tables[0], 16),
+                             (seqs[2], tables[2], 24)):
+        *_, pool = run(params, pool, [(seq[:upto], table, 0)])
+    return pool, [(seqs[0][16:32], tables[0], 16),
+                  (seqs[1], tables[1], 0),
+                  (seqs[2][24:], tables[2], 24)]
+
+
+@pytest.mark.parametrize("name", ["kimi", "xing"])
+def test_three_spans_in_one_program_equal_three_programs(latent_params,
+                                                         name):
+    """``[a | b | c]`` as ONE program against the three spans run one
+    after another, from the same pool of noise: the same three ids;
+    each span's row of logits, and the latent rows it left in the pool,
+    to float32 rounding (measured 1.3e-6 on values of a few units: the
+    CPU's products sum a row in an order that follows the program's
+    row count, 56 here and 16 or 24 there, and a row's attention sums
+    its keys in the order they lie in the program; a key that is not a
+    row's own would move its logits by 1e-1); the zeroed tails of
+    ragged spans and every block no span names, bit for bit. The
+    middle span starts its prompt and the others lie behind context;
+    the last is ragged; rows 16..39 lie across the kernel's query
+    blocks at this size."""
+    cfg, params = _latent(name), latent_params(name)
+    _, run = _spans_program(cfg)
+    pool, spans = _three_sequences(cfg, params, run)
+    rows, ids, packed = run(params, pool, spans)
+    want_rows, want_ids, serial = [], [], pool
+    for span in spans:
+        r, i, serial = run(params, serial, [span])
+        want_rows.append(r[0])
+        want_ids.append(i[0])
+    assert ids.tolist() == want_ids
+    assert np.abs(rows - np.stack(want_rows)).max() < ROW_TOL["float32"]
+    got, want = np.asarray(packed), np.asarray(serial)
+    assert np.abs(got - want).max() < 4e-6
+    untouched = np.setdiff1d(np.arange(32), [1, 2, 3, 4, 9, 10, 11, 17, 18,
+                                             19, 20, 21])
+    assert np.array_equal(got[:, untouched], np.asarray(pool)[:, untouched])
+    # the ragged span's 13 rows end in block 21: rows 5.. of it are zeros
+    assert not got[:, 21, 5:].any() and got[:, 21, :5].any(-1).all()
+
+
+@pytest.mark.parametrize("name", ["kimi", "xing"])
+def test_a_length_never_seen_compiles_one_program_however_it_is_divided(
+        latent_params, name):
+    """The packed program's shapes are its rows' and its table's: 72
+    rows behind context compile ONCE, as three spans, as two, as one,
+    and 72 rows that are one span from a prompt's start (no table) once
+    more. What the benchmark's warm-up compiles with single requests of
+    every length is what a window of packed steps runs."""
+    from jax import monitoring
+
+    cfg, params = _latent(name), latent_params(name)
+    _, run = _spans_program(cfg)
+    pool, spans = _three_sequences(cfg, params, run)
+    compiled = []
+
+    def listener(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(event)
+
+    seq = np.random.default_rng(12).integers(0, 256, 88).tolist()
+    table = list(range(24, 32)) + [5, 6, 7]
+    monitoring.register_event_duration_secs_listener(listener)
+    try:
+        run(params, pool, spans + [(seq[:16], table, 0)])   # 16+24+16+16
+        assert len(compiled) == 1, compiled
+        run(params, pool, [(seq[:56], table, 0), spans[0]])     # 56 + 16
+        *_, held = run(params, pool, [(seq[:16], table, 0)])
+        compiled.clear()                # (16 rows with no table: seen)
+        run(params, held, [(seq[16:], table, 16)])      # 72 behind 16
+        assert compiled == []
+        run(params, pool, [(seq[:72], table, 0)])       # 72, no table
+        assert len(compiled) == 1, compiled
+    finally:
+        monitoring.unregister_event_duration_listener(listener)

@@ -195,7 +195,9 @@ def test_manifest_lists_the_cell_where_the_issue_says():
            if CELL in x.get("workloads", [CELL])}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
     by_name = {x["name"]: x for x in m["per_layer"]}
-    assert [x["name"] for x in m["per_layer"][-3:]] == list(NEW)
+    # Membership and their own order, not position: later PRs append.
+    assert [x["name"] for x in m["per_layer"] if x["name"] in NEW] \
+        == list(NEW)
     for name in NEW:
         assert by_name[name]["workloads"] == [CELL], name
         assert by_name[name]["moves"] == "serve_tokens_per_s", name

@@ -96,6 +96,46 @@ def test_engine_logits_equal_the_plain_reference(params, budget):
     assert np.abs(got - want).max() < LOGIT_TOL
 
 
+@pytest.mark.parametrize("bodies", [(24, 16, 8), (21, 13)],
+                         ids=["whole_blocks", "ragged"])
+def test_spans_packed_in_one_program_equal_the_plain_reference(params,
+                                                               bodies):
+    """Bodies behind two cached prefixes (40 tokens and 16) arrive
+    together and fill ONE step's budget: their spans ride in ONE chunk
+    program (the seam's ``chunk_spans`` 4), each row at its own
+    sequence's positions, seeing its own prefix's slots of the one
+    table and its own span. Every logits row each request samples from
+    equals the reference's full forward pass of THAT request alone, and
+    the tokens are those of the request sent alone."""
+    from ray_tpu.util import perfmodel
+
+    eng = _engine(params, prefill_chunk_tokens=64)
+    prefixes = [_prompt(0, 40), _prompt(5, 16)]
+    for p in prefixes:
+        eng.add_request(p, max_tokens=1)
+        _drain(eng)
+    rows = _logits_of(eng)
+    sent = [dict(prompt=prefixes[i % 2] + _prompt(10 + i, n), max_tokens=6,
+                 temperature=0.7 * (i == 0), seed=3)
+            for i, n in enumerate(bodies)]
+    # The sampler rides last: its row is fetched at once, which closes
+    # the program it rides in.
+    reqs = [eng.add_request(**r) for r in sent[::-1]][::-1]
+    eng.step()
+    entry = perfmodel.device_step_events()[-1]
+    assert entry["prefill_spans"] == [[-(-n // BS) * BS for n in bodies][::-1]]
+    assert [r.cached_tokens for r in reqs] \
+        == [len(prefixes[i % 2]) // BS * BS for i in range(len(bodies))]
+    _drain(eng)
+    for req, r in zip(reqs, sent):
+        want = _reference_rows(params, r["prompt"], req.output)
+        assert np.abs(np.stack(rows[req.rid]) - want).max() < LOGIT_TOL
+        alone = _engine(params, prefill_chunk_tokens=64)
+        a = alone.add_request(**r)
+        _drain(alone)
+        assert a.output == req.output
+
+
 def test_a_lane_beside_seven_others_equals_itself_alone(params):
     prompt = _prompt(1, 41)
     alone = _engine(params, max_batch=8, num_blocks=128)

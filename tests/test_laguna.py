@@ -70,11 +70,16 @@ def _logits_of(eng):
         return out
 
     def on_settle():
-        # The chunks not yet settled are the last ones dispatched.
-        for ch, row in zip(eng._pending, last[-len(eng._pending):]):
+        # The programs not yet settled are the last ones dispatched; a
+        # program that carried several spans hands back a row a span.
+        spans = list(dict.fromkeys(id(ch.span) for ch in eng._pending))
+        of = dict(zip(spans, last[len(last) - len(spans):]))
+        for ch in eng._pending:
             if ch.done and ch.req is not None:
-                rows.setdefault(ch.req.rid, []).append(
-                    np.asarray(jax.device_get(row), np.float32))
+                row = of[id(ch.span)]
+                rows.setdefault(ch.req.rid, []).append(np.asarray(
+                    jax.device_get(row if ch.index is None
+                                   else row[ch.index]), np.float32))
         last.clear()
         return settle()
 

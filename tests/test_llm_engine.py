@@ -1210,3 +1210,178 @@ def test_the_chat_drivers_set_up_replayed():
     assert any(ref.rid in s for s in steps)
     for h, req in zip([ref] + sent, [reference] + firsts + tails):
         assert h.output == _alone(req, cfg, params, **engine), h.rid
+
+
+# -- a step's spans in ONE chunk program (PR 67) ------------------------------
+
+def _latent_family():
+    """Kimi's tiny configuration (``Serving.chunk_spans`` 4) and its
+    parameters, made once."""
+    import test_kimi_k2
+
+    if not hasattr(_latent_family, "made"):
+        from ray_tpu.models import kimi_k2
+
+        _latent_family.made = (test_kimi_k2.TINY, kimi_k2.init(
+            jax.random.PRNGKey(0), test_kimi_k2.TINY))
+    return _latent_family.made
+
+
+def _bodies(cfg, params, reqs, **engine):
+    """``reqs`` added together to one engine and stepped ONCE. Returns
+    (engine, handles, the chunk programs the step dispatched, each as
+    the rows of its spans)."""
+    engine = {"num_blocks": 64, "block_size": 8, "max_batch": 4, **engine}
+    eng = LLMEngine(params, cfg, **engine)
+    hs = [eng.add_request(**r) for r in reqs]
+    programs, real = [], eng._prefill_chunk
+
+    def spy(p, toks, *rest):
+        programs.append(toks.shape[1])
+        return real(p, toks, *rest)
+
+    eng._prefill_chunk = spy
+    eng.step()
+    from ray_tpu.util import perfmodel
+
+    entry = perfmodel.device_step_events()[-1]
+    assert [row[0] for row in entry["prefill_chunks"]] == programs
+    assert [sum(spans) for spans in entry["prefill_spans"]] == programs
+    return eng, hs, entry["prefill_spans"]
+
+
+_THREE = [dict(prompt=list(range(1, 1 + n)), max_tokens=4)
+          for n in (16, 24, 8)]
+
+
+def _case_three_bodies_fill_one_program_of_a_family_that_takes_four():
+    cfg, params = _latent_family()
+    eng, hs, spans = _bodies(cfg, params, _THREE, prefill_chunk_tokens=48)
+    # ONE program carried the three spans, the decode program behind it.
+    assert spans == [[16, 24, 8]] and _queued(eng) == (2, 2)
+    assert eng.stats()["prefill_chunks"] == 1
+    assert eng.stats()["prefill_spans"] == 3
+    # Three chunks in flight shared it: each prompt's first token went
+    # to its lane on the device, and each decoded in that very step.
+    assert [len(h.output) for h in hs] == [2, 2, 2]
+    _drain(eng)
+    one_at_a_time = []
+    for r in _THREE:
+        alone = LLMEngine(params, cfg, num_blocks=64, block_size=8,
+                          max_batch=4, prefill_chunk_tokens=48)
+        h = alone.add_request(**r)
+        _drain(alone)
+        assert alone.stats()["prefill_spans"] == 1
+        one_at_a_time.append(h.output)
+    assert [h.output for h in hs] == one_at_a_time
+
+
+def _case_three_bodies_are_three_programs_of_a_family_that_takes_one():
+    eng, hs, spans = _bodies(CFG, PARAMS, _THREE, prefill_chunk_tokens=48)
+    assert spans == [[16], [24], [8]] and _queued(eng) == (4, 4)
+    assert eng.stats()["prefill_chunks"] == eng.stats()["prefill_spans"] == 3
+    _drain(eng)
+    assert [h.output for h in hs] == [_alone(r) for r in _THREE]
+
+
+def _case_contexts_that_do_not_fit_one_table_ride_in_two_programs():
+    cfg, params = _latent_family()
+    # max_seq 160 in blocks of 8: a table of 20 blocks. Two documents
+    # of 96 tokens (12 blocks each) are resident; a question behind
+    # each is a span whose context does not fit the table beside the
+    # other's, and a third behind a short one rides with the second.
+    docs = [list(range(1, 97)), list(range(101, 197)), list(range(9, 25))]
+    eng = LLMEngine(params, cfg, num_blocks=96, block_size=8, max_batch=4,
+                    prefill_chunk_tokens=96)
+    for d in docs:
+        eng.add_request(prompt=d, max_tokens=1)
+        _drain(eng)
+    asks = [dict(prompt=d + [7, 8, 9, 10, 11], max_tokens=3) for d in docs]
+    hs = [eng.add_request(**r) for r in asks]
+    eng.step()
+    from ray_tpu.util import perfmodel
+
+    entry = perfmodel.device_step_events()[-1]
+    assert [h.cached_tokens for h in hs] == [96, 96, 16]
+    assert entry["prefill_spans"] == [[8], [8, 8]]
+    assert [row[:2] for row in entry["prefill_chunks"]] \
+        == [[8, 96], [16, 96 + 16]]
+    _drain(eng)
+    for h, r in zip(hs, asks):
+        alone = LLMEngine(params, cfg, num_blocks=96, block_size=8,
+                          max_batch=4, prefill_chunk_tokens=96)
+        a = alone.add_request(**r)
+        _drain(alone)
+        assert h.output == a.output
+
+
+def _case_preempted_with_the_packed_program_in_flight():
+    cfg, params = _latent_family()
+    # ``_case_preempted_with_its_chunk_in_flight``'s pool, in a family
+    # whose program takes both new prompts: ``new`` is the victim while
+    # the ONE program that carries its span and ``mid``'s is in flight.
+    old = dict(prompt=[1, 2, 3, 4, 5, 6, 7], max_tokens=12)
+    mid = dict(prompt=[10, 11, 12, 13, 14, 15, 16, 17], max_tokens=6)
+    new = dict(prompt=[20, 21, 22, 23, 24, 25, 26, 27], max_tokens=6)
+    eng = LLMEngine(params, cfg, num_blocks=6, block_size=8)
+    a = eng.add_request(**old)
+    eng.step()
+    m, b = eng.add_request(**mid), eng.add_request(**new)
+    seen, settle = [], eng._settle
+
+    def on_settle():
+        seen.extend(eng._pending)
+        return settle()
+
+    eng._settle = on_settle
+    eng.step()
+    assert b.preemptions == 1 and b.state == PREEMPTED
+    kept, dropped = seen
+    assert kept.span is dropped.span and kept.log is dropped.log
+    assert (kept.req, kept.index, dropped.req, dropped.index) \
+        == (m, 0, None, 1)
+    assert b.output == [] and b.lane is None and len(m.output) == 2
+    assert eng._pending == [] and _queued(eng) == (2, 2)
+    assert [row[:2] for row in eng._chunk_log] == [[16, 0]]
+    assert eng._span_log == [[8, 8]]
+    _drain(eng)
+    for h, r in ((a, old), (m, mid), (b, new)):
+        alone = LLMEngine(params, cfg, num_blocks=6, block_size=8)
+        x = alone.add_request(**r)
+        _drain(alone)
+        assert h.output == x.output
+    assert eng.kv.num_free == eng.kv.capacity
+
+
+def _case_a_sampler_closes_the_group_it_rides_in():
+    cfg, params = _latent_family()
+    sampled = dict(_THREE[1], temperature=0.8, seed=3, top_k=20)
+    eng, hs, spans = _bodies(cfg, params, [_THREE[0], sampled, _THREE[2]],
+                             prefill_chunk_tokens=48)
+    # The sampler's row must reach the host before the decode step is
+    # built: its span closes the program it rides in with the span
+    # before it, and the third goes in a program of its own.
+    assert spans == [[16, 24], [8]] and _queued(eng) == (3, 1)
+    assert eng.stats()["tokens_decided_on_host"] == 2
+    _drain(eng)
+    alone = LLMEngine(params, cfg, num_blocks=64, block_size=8, max_batch=4,
+                      prefill_chunk_tokens=48)
+    x = alone.add_request(**sampled)
+    _drain(alone)
+    assert hs[1].output == x.output
+
+
+@pytest.mark.parametrize("case", [
+    _case_three_bodies_fill_one_program_of_a_family_that_takes_four,
+    _case_three_bodies_are_three_programs_of_a_family_that_takes_one,
+    _case_contexts_that_do_not_fit_one_table_ride_in_two_programs,
+    _case_preempted_with_the_packed_program_in_flight,
+    _case_a_sampler_closes_the_group_it_rides_in,
+], ids=lambda f: f.__name__[len("_case_"):])
+def test_a_steps_spans_ride_in_as_few_programs_as_the_family_takes(case):
+    """The loop that cuts a step's budget into spans is one; what a
+    span rides in follows what the engine can see: the seam's
+    ``chunk_spans``, whether the contexts fit one table, whether a
+    result has to be fetched at once. Every path gives the tokens the
+    requests get one at a time."""
+    case()

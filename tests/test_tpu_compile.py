@@ -792,12 +792,14 @@ def kimi_programs(one_chip):
             "decode": decode.lower(
                 params, S((B, step_columns(1).table + max_nb), i32), pool,
                 q=1, firsts=S((B,), i32)),
+            # ``pack_spans``' array: four numbers a span behind the
+            # table and the blocks written (PR 67).
             "chunk": chunk.lower(
                 params, S((1, 512), i32), pool,
-                S((max_nb + 512 // BS + 2,), i32)),
+                S((max_nb + 512 // BS + 4 * kimi_k2.CHUNK_SPANS,), i32)),
             "cold_chunk": chunk.lower(
                 params, S((1, 512), i32), pool,
-                S((512 // BS + 2,), i32)),
+                S((512 // BS + 4 * kimi_k2.CHUNK_SPANS,), i32)),
         }
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
@@ -861,7 +863,9 @@ def test_kimi_chunk_program_writes_its_span_in_place(kimi_programs, which):
     pool is donated, aliased and
     written by ONE in-place scatter after every layer has read it; the
     routed experts are the ``moe_experts_chunk`` kernel; the head runs
-    on the one row that comes back."""
+    on the four rows that come back, one a span the program can carry
+    (PR 67: its table is ``pack_spans``', and ``chunk_attn`` is the
+    variant that takes a description of each row)."""
     c = kimi_programs[which]
     text = c.as_text()
     assert text.startswith("HloModule jit_llm_prefill_chunk")
@@ -915,8 +919,12 @@ def _f32_ending_in(text, *widths):
     (16, 1, 512, 192, 128, 64, KIMI_MAX_SEQ, None),
     (16, 1, 128, 192, 128, 64, KIMI_MAX_SEQ, None),
     (16, 1, 384, 192, 128, 0, 0, None),
+    (16, 1, 512, 192, 128, 64, KIMI_MAX_SEQ, "rows"),
+    (16, 1, 2048, 192, 128, 64, 4736, "rows"),      # XING_MAX_SEQ
+    (16, 1, 1536, 192, 128, 64, 0, "rows"),
 ], ids=["laguna_g6", "laguna_g8_320", "laguna_window", "kimi_group",
-        "kimi_group_128", "wide_keys_no_table"])
+        "kimi_group_128", "wide_keys_no_table", "kimi_group_described",
+        "xing_group_2048_described", "no_table_described"])
 def test_chunk_attention_kernel_compiles_at_the_cells_shapes(
         one_chip, kvh, g, n, dk, dv, ds, slots, window):
     """``chunk_attn`` alone, for the described v5e: Laguna's grouped
@@ -924,23 +932,29 @@ def test_chunk_attention_kernel_compiles_at_the_cells_shapes(
     table, a group of 16 of Kimi's heads (keys of 128 + a shared 64,
     values of 128) behind 17,408 slots, and keys 192 wide with no table
     at all. ``ctx_len`` and ``base`` are operands: one compile serves
-    every context."""
+    every context. And the variant that takes a description of each
+    row (``window`` "rows" here; PR 67), alone, at the latent family's
+    shapes: Kimi's group behind its table, Xing4.0's 2,048 rows (two
+    query blocks) behind its 4,736 slots, and with no table."""
     from ray_tpu.ops.pallas.chunk_attention import (chunk_attention,
                                                     padded_keys)
 
     S = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dt, sharding=one_chip)
     keys = padded_keys(slots + n)
+    described = window == "rows"
 
-    def attend(q, k, v, shared, ctx_len, base):
+    def attend(q, k, v, shared, ctx_len, base, *rows):
         return chunk_attention(
             q, k, v, ctx_len, ctx_slots=slots, scale=dk ** -0.5,
-            k_shared=shared if ds else None, base=base, window=window,
+            k_shared=shared if ds else None, base=base,
+            window=None if described else window, rows=rows or None,
             interpret=False)
 
     c = _compile(attend, S((kvh, g, n, dk)), S((kvh, keys, dk - ds)),
                  S((kvh, keys, dv)), S((keys, max(ds, 1))),
-                 S((), jnp.int32), S((), jnp.int32))
+                 S((), jnp.int32), S((), jnp.int32),
+                 *[S((n,), jnp.int32)] * (3 * described))
     calls = [line for line in c.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1 and "%chunk_attn" in calls[0].split("=")[0]
@@ -1009,7 +1023,9 @@ def chunk_program_texts(one_chip):
             model, n = serving(cfg), 64
             pools = [[S((len(kind.layers), 32, BS, width), bf16)
                       for width in kind.rows] for kind in model.kinds]
-            args = [*pools[0], S((model.max_seq // BS + n // BS + 2,), i32)]
+            spans = 4 * model.chunk_spans if model.chunk_spans > 1 else 2
+            args = [*pools[0],
+                    S((model.max_seq // BS + n // BS + spans,), i32)]
             if len(pools) > 1:
                 nbw = window_table_len(model.kinds[1].window, BS, 1)
                 args += [*pools[1], S((nbw + 1 + n // BS,), i32)]
@@ -1019,10 +1035,13 @@ def chunk_program_texts(one_chip):
 
 
 # GPT-2's since PR 61, which lowers it from the parameters as served
-# (bfloat16 weights in: "99837368b616cd4b" from float32 leaves).
+# (bfloat16 weights in: "99837368b616cd4b" from float32 leaves). Kimi's
+# re-recorded by PR 67, on its tree: the latent family's chunk program
+# takes several spans (``pack_spans``' table, a description of each row
+# for ``chunk_attn``, a head row a span); "568d0ef5d4d34d94" before.
 CHUNK_TEXT_AT_PR46 = {"gpt": "8f3e1ee80c41bc30",
                       "laguna": "15e00d4b01149d99",
-                      "kimi": "568d0ef5d4d34d94"}
+                      "kimi": "6ca702de31f7ef6b"}
 
 
 @pytest.mark.parametrize("model", sorted(CHUNK_TEXT_AT_PR46))
@@ -1051,7 +1070,9 @@ SERVED_TEXT_AT_PR65 = {
     ("decode", "gpt"): "ca9e1a33518210aa",
     ("decode", "granite"): "3d881707e540edb1",
     ("chunk", "granite"): "290aecb22f3fd387",
-    ("chunk", "xing"): "5c9be7b5d7ee8776",
+    # Re-recorded by PR 67, on its tree (it is Kimi's chunk program,
+    # which takes several spans now); "5c9be7b5d7ee8776" before.
+    ("chunk", "xing"): "2a211bec042c5200",
 }
 
 
@@ -1432,7 +1453,7 @@ def xing_programs(one_chip):
     of 16 rows of 640, a 2,048-token span behind a 4,736-token table.
     ~35 s for the two."""
     from ray_tpu.llm.engine import _jit_programs
-    from ray_tpu.models import xing4
+    from ray_tpu.models import kimi_k2, xing4
 
     cfg = xing4.Xing4Config(num_hidden_layers=XING_LAYERS,
                             first_k_dense_replace=1, max_seq=XING_MAX_SEQ)
@@ -1452,7 +1473,7 @@ def xing_programs(one_chip):
             q=1, firsts=S((B,), i32))
         low_chunk = chunk.lower(
             params, S((1, 2048), i32), pool,
-            S((max_nb + 2048 // BS + 2,), i32))
+            S((max_nb + 2048 // BS + 4 * kimi_k2.CHUNK_SPANS,), i32))
         return {
             "param_leaves": len(jax.tree_util.tree_leaves(params)),
             "decode": lowered.compile(),
@@ -1516,7 +1537,8 @@ def test_xing_chunk_program_writes_its_span_in_place(xing_programs):
     ``mhc_post`` writing the 58.7 MB of streams it was handed (its
     first operand is its result's buffer: no second copy of the streams
     a sublayer); the pool donated, aliased and written by ONE in-place
-    scatter; the head on the one row that comes back; the temporaries
+    scatter; the head on the four rows that come back (one a span the
+    program can carry, PR 67); the temporaries
     are what the configuration's file says they are, and leave the
     13.87 GB of weights and pool their room."""
     c = xing_programs["chunk"]
@@ -1654,8 +1676,12 @@ PROGRAMS_AT_PR62 = {
             "chunk": [("moe_experts_chunk", "1b3435937767bed7"),
                       ("moe_experts_chunk", "8e203221df276585")]}},
     "kimi": {
-        "texts": {"decode": "cd54fd8dd84d3a7a", "chunk": "1dfed06f04c4fac7",
-                  "cold_chunk": "85017cce77588407"},
+        # chunk and cold_chunk: re-recorded by PR 67, on its tree (the
+        # program takes several spans; "1dfed06f04c4fac7" and
+        # "85017cce77588407" before); the decode program and the grouped
+        # products' bodies are PR 62's.
+        "texts": {"decode": "cd54fd8dd84d3a7a", "chunk": "b4ea62119eb14f97",
+                  "cold_chunk": "9152b619dfa5b6bb"},
         "moe_kernels": {
             "decode": [("moe_experts_decode", "3c9e044c4ce03286"),
                        ("moe_experts_decode", "8281b9bf30d7ee6a")],

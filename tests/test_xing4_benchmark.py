@@ -44,6 +44,9 @@ NEW = ("mhc_chunk_ms", "mhc_chunk_roofline_pct", "mhc_decode_ms",
 # Appended behind them since, in the cell alone (PR 63: whether the
 # grouped product's tall tile engaged).
 LATER = ("moe_chunk_wide_tile_pct",)
+# ... and behind every cell's entries, with Kimi's cell (PR 67: the
+# spans a chunk program carried).
+APPENDED = ("chunk_spans_per_program",)
 # The cell PR 64 appended behind this one.
 LATER_CELL = "granite4hs-serve-chat"
 
@@ -200,7 +203,9 @@ def test_manifest_lists_the_cell_where_the_issue_says():
         after = by_name[name]["workloads"]
         assert after[after.index(CELL) + 1:] in ([], [LATER_CELL]), name
     mine = {x["name"] for x in m["per_layer"] if CELL in x["workloads"]}
-    assert mine == set(SHARED) | set(NEW) | set(LATER)
+    assert mine == set(SHARED) | set(NEW) | set(LATER) | set(APPENDED)
+    for name in APPENDED:
+        assert by_name[name]["workloads"] == [CELL, "kimi-k25-serve-docs"]
     for name in mine:
         assert os.path.isfile(os.path.join(
             ROOT, "benchmark", "layer_metrics",
